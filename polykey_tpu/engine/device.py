@@ -1,0 +1,135 @@
+"""What the process actually runs on: device identity, memory, compiles.
+
+A serving process that silently fell back to the CPU, or to a jnp path
+where a kernel was expected, produces numbers that look like device
+numbers and are not. Everything here exists so the engine can SAY what
+it runs on (engine_stats, the `engine initialized` log line) and so the
+entry points can refuse a platform nobody asked for:
+
+- `require_accelerator` — POLYKEY_BACKEND=tpu serves from a TPU or not
+  at all (JAX falls back to the CPU with a warning when libtpu finds no
+  chip).
+- `device_identity` / `device_memory` — platform, device_kind, device
+  count and the chip's roofline row; per-device bytes in use and peak.
+- `compile_counts` — executables built in this process and how many of
+  them came out of the persistent compilation cache, from JAX's own
+  monitoring events (a benchmark window expects zero new ones).
+- `mosaic_calls` / `collective_ops` — Mosaic custom calls in a lowered
+  step and collectives in its compiled HLO: the evidence that a served
+  executable contains the Pallas kernels (not the gate functions'
+  opinion that it should) and, on a mesh, really talks over ICI.
+"""
+
+from __future__ import annotations
+
+import os
+import threading
+
+import jax
+
+from .roofline import detect_chip
+
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_census_lock = threading.Lock()
+_census = {"installed": False, "executables": 0, "cache_hits": 0}
+
+
+def install_compile_census() -> None:
+    """Start counting XLA compiles (idempotent; listeners are process-
+    wide and stay for the process's life)."""
+    with _census_lock:
+        if _census["installed"]:
+            return
+        _census["installed"] = True
+
+    def on_duration(event: str, duration_secs: float, **kwargs) -> None:
+        if event == _BACKEND_COMPILE_EVENT:
+            with _census_lock:
+                _census["executables"] += 1
+
+    def on_event(event: str, **kwargs) -> None:
+        if event == _CACHE_HIT_EVENT:
+            with _census_lock:
+                _census["cache_hits"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
+def compile_counts() -> dict:
+    """Executables built since install_compile_census: `executables`
+    counts every one, `cache_hits` those loaded from the persistent
+    cache, `fresh_compiles` the rest (the ones XLA actually compiled)."""
+    with _census_lock:
+        built, hits = _census["executables"], _census["cache_hits"]
+    return {
+        "executables": built,
+        "cache_hits": hits,
+        "fresh_compiles": built - hits,
+    }
+
+
+def device_identity() -> dict:
+    """Platform as JAX reports it, plus the roofline row it maps to
+    (None off-TPU; an unknown TPU kind raises — roofline.detect_chip)."""
+    devices = jax.devices()
+    chip = detect_chip()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "chip": chip.name if chip is not None else None,
+    }
+
+
+def require_accelerator() -> dict:
+    """The serving entry point's platform gate; returns device_identity().
+
+    JAX falls back to the CPU with a warning when no TPU is visible, and
+    an engine would then serve — slowly, under a TPU backend's name. A
+    non-TPU platform is a start-up error unless JAX_PLATFORMS=cpu was
+    set explicitly (tests, compose, the smoke's tiny rehearsal)."""
+    identity = device_identity()
+    explicit_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+    if identity["platform"] != "tpu" and not explicit_cpu:
+        raise RuntimeError(
+            "POLYKEY_BACKEND=tpu but JAX initialized platform "
+            f"{identity['platform']!r} ({identity['device_kind']}, "
+            f"{identity['device_count']} device(s)): no TPU is visible to "
+            "this process. Set JAX_PLATFORMS=cpu to serve from the CPU on "
+            "purpose."
+        )
+    return identity
+
+
+def device_memory(devices) -> list:
+    """Per-device allocator readings where the backend reports them
+    (TPU does; the CPU backend returns None → empty list)."""
+    out = []
+    for d in devices:
+        stats = d.memory_stats()
+        if stats:
+            out.append({
+                "id": d.id,
+                "bytes_in_use": stats.get("bytes_in_use", 0),
+                "peak_bytes_in_use": stats.get("peak_bytes_in_use", 0),
+                "bytes_limit": stats.get("bytes_limit", 0),
+            })
+    return out
+
+
+def mosaic_calls(lowered) -> int:
+    """Mosaic (Pallas TPU) custom calls in a `jax.stages.Lowered` step."""
+    return lowered.as_text().count("tpu_custom_call")
+
+
+def collective_ops(compiled) -> int:
+    """Cross-device collectives in a compiled executable's HLO."""
+    text = compiled.as_text()
+    return sum(
+        text.count(f" {op}(") + text.count(f" {op}-start(")
+        for op in ("all-reduce", "all-gather", "reduce-scatter",
+                   "all-to-all", "collective-permute")
+    )

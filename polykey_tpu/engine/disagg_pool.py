@@ -252,6 +252,25 @@ class DisaggPool:
         if tiers is None and workers is None:
             raise ValueError("DisaggPool needs a POLYKEY_DISAGG spec or "
                              "an explicit worker list")
+        if workers is None:
+            # Spawn mode starts P+D processes that each build an engine on
+            # the default devices. A TPU belongs to ONE process: the second
+            # worker could never get the chip and the coordinator would
+            # wait out ready_timeout_s with the workers' stderr discarded.
+            # Refuse up front instead (DEPLOY.md "One process per chip").
+            from .device import device_identity
+
+            identity = device_identity()
+            if identity["platform"] == "tpu":
+                raise RuntimeError(
+                    f"POLYKEY_DISAGG={config.disagg!r} spawns "
+                    f"{sum(tiers)} worker processes, but a TPU belongs to "
+                    f"one process at a time ({identity['device_count']} x "
+                    f"{identity['device_kind']} on this host, all taken by "
+                    "the first to start). Disaggregated tiers run on the "
+                    "CPU backend only for now; on a TPU host use "
+                    "POLYKEY_REPLICAS (one process, one engine per chip)."
+                )
         recorder = obs.recorder if obs is not None else None
         pool = cls(config, health=health, logger=logger, recorder=recorder)
         pool._seed = seed

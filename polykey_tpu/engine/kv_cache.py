@@ -32,34 +32,32 @@ import numpy as np
 from flax import struct
 
 from ..models.config import ModelConfig
+from .config import CHECKOUT
 
-_NATIVE_PATHS = (
-    os.path.join(os.path.dirname(__file__), "..", "..", "build",
-                 "libblock_allocator.so"),
-    "build/libblock_allocator.so",
-)
+# `make native` output, located from the package — never from the current
+# directory, where a library from some other checkout could ride along.
+_NATIVE_PATH = os.path.join(CHECKOUT, "build", "libblock_allocator.so")
 
 
 def _load_native() -> Optional[ctypes.CDLL]:
-    for path in _NATIVE_PATHS:
-        if os.path.exists(path):
-            lib = ctypes.CDLL(os.path.abspath(path))
-            lib.pk_allocator_new.restype = ctypes.c_void_p
-            lib.pk_allocator_new.argtypes = [ctypes.c_int32]
-            lib.pk_allocator_free.argtypes = [ctypes.c_void_p]
-            lib.pk_num_free.restype = ctypes.c_int32
-            lib.pk_num_free.argtypes = [ctypes.c_void_p]
-            lib.pk_alloc.restype = ctypes.c_int32
-            lib.pk_alloc.argtypes = [
-                ctypes.c_void_p, ctypes.c_int32,
-                ctypes.POINTER(ctypes.c_int32),
-            ]
-            lib.pk_retain.restype = ctypes.c_int32
-            lib.pk_retain.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-            lib.pk_release.restype = ctypes.c_int32
-            lib.pk_release.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-            return lib
-    return None
+    if not os.path.exists(_NATIVE_PATH):
+        return None
+    lib = ctypes.CDLL(_NATIVE_PATH)
+    lib.pk_allocator_new.restype = ctypes.c_void_p
+    lib.pk_allocator_new.argtypes = [ctypes.c_int32]
+    lib.pk_allocator_free.argtypes = [ctypes.c_void_p]
+    lib.pk_num_free.restype = ctypes.c_int32
+    lib.pk_num_free.argtypes = [ctypes.c_void_p]
+    lib.pk_alloc.restype = ctypes.c_int32
+    lib.pk_alloc.argtypes = [
+        ctypes.c_void_p, ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32),
+    ]
+    lib.pk_retain.restype = ctypes.c_int32
+    lib.pk_retain.argtypes = [ctypes.c_void_p, ctypes.c_int32]
+    lib.pk_release.restype = ctypes.c_int32
+    lib.pk_release.argtypes = [ctypes.c_void_p, ctypes.c_int32]
+    return lib
 
 
 class AllocationError(RuntimeError):

@@ -292,8 +292,10 @@ class ReplicaPool:
             kv_dir = config.kv_state_dir
             if kv_dir:
                 kv_dir = os.path.join(kv_dir, f"kv-replica-{i}")
+            # replica + replicas together tell the engine which device
+            # slice is its own (engine.py "Replica placement").
             rep_cfg = dataclasses.replace(
-                config, replica=i, kv_state_dir=kv_dir,
+                config, replica=i, replicas=n, kv_state_dir=kv_dir,
             )
             shim = _ReplicaHealth(pool, i)
             engine = InferenceEngine(

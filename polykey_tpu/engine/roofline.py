@@ -64,27 +64,26 @@ CHIP_SPECS = {
 
 def detect_chip() -> Optional[ChipSpec]:
     """Map jax.devices()[0].device_kind to a ChipSpec; None off-TPU (a
-    CPU run has no meaningful roofline — mbu/mfu stay null there, but the
-    per-token byte/FLOP geometry is still emitted). Only kinds this table
-    actually knows map to a spec: an unknown v5 variant (or any future
-    chip) returns None rather than silently grading against v5p's
-    2765 GB/s roofline (ADVICE r5)."""
-    try:
-        import jax
+    CPU run has no roofline — mbu/mfu stay null there, but the per-token
+    byte/FLOP geometry is still emitted). A TPU whose kind this table
+    does not know RAISES: grading against another chip's peaks (or
+    silently not grading) would put a wrong or missing ceiling under
+    every device number, so the table gains a row first."""
+    import jax
 
-        d = jax.devices()[0]
-        if d.platform != "tpu":
-            return None
-        kind = d.device_kind.lower()
-        if "v5 lite" in kind or "v5e" in kind:
-            return CHIP_SPECS["tpu-v5e"]
-        if "v5p" in kind:
-            return CHIP_SPECS["tpu-v5p"]
-    except Exception:
-        # No devices / unqueryable backend: roofline annotation is
-        # optional context, None disables it without failing the bench.
+    d = jax.devices()[0]
+    if d.platform != "tpu":
         return None
-    return None
+    kind = d.device_kind.lower()
+    if "v5 lite" in kind or "v5e" in kind:
+        return CHIP_SPECS["tpu-v5e"]
+    if "v5p" in kind:
+        return CHIP_SPECS["tpu-v5p"]
+    raise ValueError(
+        f"TPU device_kind {d.device_kind!r} has no entry in "
+        f"roofline.CHIP_SPECS (known: {sorted(CHIP_SPECS)}); add its "
+        "published peaks before serving or measuring on it"
+    )
 
 
 def _bytes_per_el(dtype: str) -> float:

@@ -64,13 +64,24 @@ class ByteTokenizer:
         contain replacement characters mid-character.
         """
         data = state + bytes(i - 3 for i in ids if 3 <= i < 259)
-        # Find the longest decodable prefix (max 3 trailing continuation bytes).
-        for cut in range(len(data), max(len(data) - 4, -1), -1):
-            try:
-                return data[:cut].decode("utf-8"), data[cut:]
-            except UnicodeDecodeError:
-                continue
-        return "", data
+        # Hold back only a trailing sequence that more bytes could still
+        # complete (a lead byte with too few continuations after it).
+        # Everything ahead of it decodes now; a byte that can never be
+        # valid (a random-init model emits 0xFD freely) becomes U+FFFD
+        # exactly as in decode() — holding it would hold every later byte
+        # too, and the stream would stay empty while the tail grew.
+        keep = 0
+        for back in range(1, min(3, len(data)) + 1):
+            byte = data[-back]
+            if byte >= 0xC0:                     # a lead byte
+                need = 2 if byte < 0xE0 else 3 if byte < 0xF0 else 4
+                if byte < 0xF8 and need > back:
+                    keep = back
+                break
+            if byte < 0x80:                      # ASCII ends any sequence
+                break
+        head = data[:len(data) - keep]
+        return head.decode("utf-8", errors="replace"), data[len(head):]
 
 
 class HFTokenizer:

@@ -43,7 +43,7 @@ Points (consumed by engine/engine.py unless noted):
                      device call; trips the watchdog when it exceeds
                      `watchdog_timeout_s`).
 - ``slow-step``    — same site, meant small and recurring (degraded
-                     device / contended tunnel).
+                     device / contended host).
 - ``alloc-fail``   — raise AllocationError at page allocation
                      (pool exhaustion → admission backpressure).
 - ``prefill-error``— raise RuntimeError inside the prefill dispatch

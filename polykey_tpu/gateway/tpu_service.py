@@ -34,6 +34,7 @@ import time
 from typing import Iterator, Optional
 
 from ..engine.config import EngineConfig, enable_persistent_compile_cache
+from ..engine.device import require_accelerator
 from ..engine.engine import (
     DEADLINE_MSG,
     EngineDeadError,
@@ -201,11 +202,13 @@ class TpuService(Service):
         from .security import SecretStore
 
         config = EngineConfig.from_env()
-        # Durable XLA compile cache at the SERVER entrypoint (not in the
-        # engine constructor: embedders and tests shouldn't have global
-        # jax config mutated under them). Restarts skip the 20-40 s/step
-        # TPU recompiles; POLYKEY_COMPILE_CACHE=0 opts out.
+        # Persistent XLA compile cache at the SERVER entrypoint (not in
+        # the engine constructor: embedders and tests shouldn't have
+        # global jax config mutated under them), before the first jit.
         enable_persistent_compile_cache()
+        # Where the backend is chosen is where a missing chip is refused:
+        # POLYKEY_BACKEND=tpu never serves from JAX's silent CPU fallback.
+        identity = require_accelerator()
         if config.disagg:
             # Disaggregated tiers (ISSUE 13): POLYKEY_DISAGG="PxD"
             # spawns prefill/decode worker PROCESSES behind the
@@ -247,6 +250,7 @@ class TpuService(Service):
         if logger is not None:
             logger.info(
                 "engine initialized",
+                **identity,
                 model=config.model,
                 replicas=config.replicas,
                 slots=config.max_decode_slots,

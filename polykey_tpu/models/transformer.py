@@ -61,60 +61,77 @@ def init_cache(
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
-def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
-    """Random-init parameter pytree with layers stacked for scan."""
-    k_embed, k_layers, k_head = jax.random.split(key, 3)
-
+def _norm_init(cfg: ModelConfig, dtype) -> jax.Array:
     # rms_norm computes gain = offset + w (offset 1.0 for the Gemma storage
     # convention, models with scale_embeddings). Init w so the effective
     # gain is 1 — zero gains would make every hidden state identically
     # zero at init, turning random-init tests vacuous.
     norm_offset = 1.0 if cfg.scale_embeddings else 0.0
-    norm_init = jnp.full((cfg.hidden_size,), 1.0 - norm_offset, dtype)
+    return jnp.full((cfg.hidden_size,), 1.0 - norm_offset, dtype)
 
-    def one_layer(k: jax.Array) -> dict:
-        k_attn, k_mlp = jax.random.split(k)
-        layer = {
-            "attn": init_attention_params(k_attn, cfg, dtype),
-            "ln1": norm_init,
-            "ln2": norm_init,
-        }
-        if cfg.is_moe:
-            k_router, k_experts = jax.random.split(k_mlp)
-            layer["router"] = (
-                jax.random.normal(k_router, (cfg.hidden_size, cfg.num_experts), dtype)
-                * cfg.hidden_size**-0.5
+
+def init_layer_params(k: jax.Array, cfg: ModelConfig, dtype) -> dict:
+    """One transformer block's random-init params (no stacked axis)."""
+    norm_init = _norm_init(cfg, dtype)
+    k_attn, k_mlp = jax.random.split(k)
+    layer = {
+        "attn": init_attention_params(k_attn, cfg, dtype),
+        "ln1": norm_init,
+        "ln2": norm_init,
+    }
+    if cfg.is_moe:
+        k_router, k_experts = jax.random.split(k_mlp)
+        layer["router"] = (
+            jax.random.normal(k_router, (cfg.hidden_size, cfg.num_experts), dtype)
+            * cfg.hidden_size**-0.5
+        )
+        layer["experts"] = jax.vmap(
+            lambda kk: init_mlp_params(
+                kk, cfg.hidden_size, cfg.intermediate_size, dtype
             )
-            layer["experts"] = jax.vmap(
-                lambda kk: init_mlp_params(
-                    kk, cfg.hidden_size, cfg.intermediate_size, dtype
-                )
-            )(jax.random.split(k_experts, cfg.num_experts))
-        else:
-            layer["mlp"] = init_mlp_params(
-                k_mlp, cfg.hidden_size, cfg.intermediate_size, dtype
-            )
-        if cfg.use_post_norms:
-            layer["post_ln1"] = norm_init
-            layer["post_ln2"] = norm_init
-        return layer
+        )(jax.random.split(k_experts, cfg.num_experts))
+    else:
+        layer["mlp"] = init_mlp_params(
+            k_mlp, cfg.hidden_size, cfg.intermediate_size, dtype
+        )
+    if cfg.use_post_norms:
+        layer["post_ln1"] = norm_init
+        layer["post_ln2"] = norm_init
+    return layer
 
-    layers = jax.vmap(one_layer)(jax.random.split(k_layers, cfg.num_layers))
 
-    params = {
+def init_top_params(
+    k_embed: jax.Array, k_head: jax.Array, cfg: ModelConfig, dtype
+) -> dict:
+    """The params outside the layer stack: embed, final norm, lm_head."""
+    top = {
         "embed": jax.random.normal(
             k_embed, (cfg.vocab_size, cfg.hidden_size), dtype
         )
         * cfg.hidden_size**-0.5,
-        "layers": layers,
-        "final_norm": norm_init,
+        "final_norm": _norm_init(cfg, dtype),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = (
+        top["lm_head"] = (
             jax.random.normal(k_head, (cfg.hidden_size, cfg.vocab_size), dtype)
             * cfg.hidden_size**-0.5
         )
-    return params
+    return top
+
+
+def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
+    """Random-init parameter pytree with layers stacked for scan.
+
+    Materializes the whole tree in `dtype` on the default device — right
+    for tests, training and small models. The serving engine starts from
+    parallel/sharding.init_sharded_params instead, which draws the same
+    values layer by layer straight into their final dtype and sharding.
+    """
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
+    layers = jax.vmap(lambda k: init_layer_params(k, cfg, dtype))(
+        jax.random.split(k_layers, cfg.num_layers)
+    )
+    return {**init_top_params(k_embed, k_head, cfg, dtype), "layers": layers}
 
 
 def _moe_mlp(layer_params: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -199,6 +216,65 @@ def _run_stack(params, cfg: ModelConfig, tokens, positions, kv_scanned, attend):
     )
     x = rms_norm(x, params["final_norm"], eps, norm_offset)
     return x, new_k, new_v
+
+
+def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged, attend):
+    """The stack over the paged KV pools: embed → scan(layer body) → final
+    norm, with the stacked pools in the scan CARRY; returns (hidden,
+    updated paged).
+
+    Scanning the pools as xs/ys (the way _run_stack scans a contiguous
+    cache) makes XLA build the updated stack in a second full-size buffer
+    — on a v5e the prefill step of 8B-int8 at default geometry asked for
+    two extra 2.00G AllocateBuffer temporaries next to the 4G donated pool
+    and did not fit. In the carry, each layer slices its own pools out,
+    runs `attend` on them exactly as before, and writes them back with a
+    dynamic-update-slice that XLA performs in place on the donated buffer,
+    so the step holds one layer's copy instead of a whole second pool.
+
+    The per-layer copy in and out remains (and the kernels' head-folding
+    reshape is a relayout on TPU, not a bitcast): a pool stored folded and
+    addressed by page id across layers would need neither — PERF.md
+    "Where the time goes".
+    """
+    norm_offset = 1.0 if cfg.scale_embeddings else 0.0
+    if paged.quantized:
+        # int8 KV: the per-layer cache operand is a (values, scales)
+        # pair; the write/read ops dispatch on the pair form.
+        pools = ((paged.k, paged.ks), (paged.v, paged.vs))
+    else:
+        pools = (paged.k, paged.v)
+
+    x = embed_tokens(params, cfg, tokens)
+
+    def body(carry, scanned):
+        x, pools = carry
+        layer_params, layer_idx = scanned
+        kc, vc = jax.tree.map(
+            lambda pool: jax.lax.dynamic_index_in_dim(
+                pool, layer_idx, 0, keepdims=False
+            ),
+            pools,
+        )
+        x, kc, vc = apply_layer(
+            layer_params, layer_idx, x, positions, cfg, attend, kc, vc
+        )
+        pools = jax.tree.map(
+            lambda pool, layer: jax.lax.dynamic_update_index_in_dim(
+                pool, layer, layer_idx, 0
+            ),
+            pools, (kc, vc),
+        )
+        return (x, pools), None
+
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    (x, (kc, vc)), _ = jax.lax.scan(
+        body, (x, pools), (params["layers"], layer_ids)
+    )
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, norm_offset)
+    if paged.quantized:
+        return x, type(paged)(k=kc[0], v=vc[0], ks=kc[1], vs=vc[1])
+    return x, type(paged)(k=kc, v=vc)
 
 
 def make_causal_attend(cfg: ModelConfig, positions: jax.Array):
@@ -322,21 +398,7 @@ def forward_paged(
         )
         return ctx, kc, vc
 
-    if paged.quantized:
-        # int8 KV: the per-layer cache operand is a (values, scales)
-        # pair; the write/read ops dispatch on the pair form and the
-        # scale pools ride the same scan/donation plumbing.
-        kv_scanned = ((paged.k, paged.ks), (paged.v, paged.vs))
-        x, new_k, new_v = _run_stack(
-            params, cfg, tokens, positions, kv_scanned, attend
-        )
-        return x, type(paged)(
-            k=new_k[0], v=new_v[0], ks=new_k[1], vs=new_v[1]
-        )
-    x, new_k, new_v = _run_stack(
-        params, cfg, tokens, positions, (paged.k, paged.v), attend
-    )
-    return x, type(paged)(k=new_k, v=new_v)
+    return _run_paged_stack(params, cfg, tokens, positions, paged, attend)
 
 
 def forward_ragged(
@@ -377,8 +439,7 @@ def forward_ragged(
     T = tokens.shape[0]
     pos_row = positions.reshape(T, 1)
 
-    data_pool = paged.k[0] if paged.quantized else paged.k
-    Hk, D = data_pool.shape[2], data_pool.shape[3]
+    Hk, D = paged.k.shape[-2:]
     # The ragged kernel runs un-shard_mapped (GSPMD cannot partition an
     # opaque pallas_call, and no shard_map wrapping exists for the flat
     # stream yet): ANY mesh extent > 1 — tp included — routes to the
@@ -415,19 +476,10 @@ def forward_ragged(
             )
         return ctx[None], kc, vc
 
-    if paged.quantized:
-        kv_scanned = ((paged.k, paged.ks), (paged.v, paged.vs))
-        x, new_k, new_v = _run_stack(
-            params, cfg, tokens[None], positions[None], kv_scanned, attend
-        )
-        return x[0], type(paged)(
-            k=new_k[0], v=new_v[0], ks=new_k[1], vs=new_v[1]
-        )
-    x, new_k, new_v = _run_stack(
-        params, cfg, tokens[None], positions[None], (paged.k, paged.v),
-        attend
+    x, paged = _run_paged_stack(
+        params, cfg, tokens[None], positions[None], paged, attend
     )
-    return x[0], type(paged)(k=new_k, v=new_v)
+    return x[0], paged
 
 
 def make_sp_override(

@@ -28,7 +28,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import attention, make_attention_mask
-from ..compat import shard_map, tpu_compiler_params
 
 _NEG_INF = -1e30
 # Lane width: the m/l scratch rows are (bq, 128) with the statistic
@@ -186,7 +185,7 @@ def _flash_bhsd(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -311,7 +310,7 @@ def flash_attention(
 
         w = (jnp.zeros((1,), jnp.int32) if window is None
              else jnp.asarray(window, jnp.int32).reshape(1))
-        sm = shard_map(
+        sm = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(

@@ -18,7 +18,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..compat import shard_map
 
 
 def paged_gather_kv(
@@ -254,7 +253,7 @@ def _write_decode_kernel(writes, page_ids, offsets, mesh):
             off = jax.lax.all_gather(off, "dp", axis=0, tiled=True)
         return paged_write_rows_kernel(pools_l, rows_l, pid, off)
 
-    sm = shard_map(
+    sm = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(

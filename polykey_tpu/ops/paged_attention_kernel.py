@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from ..compat import shard_map, tpu_compiler_params
 
 _NEG_INF = -1e30
 
@@ -354,7 +353,7 @@ def _decode_call(
             jax.ShapeDtypeStruct((B, Hq, _STAT_MINOR), jnp.float32),
             jax.ShapeDtypeStruct((B, Hq, _STAT_MINOR), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -366,6 +365,20 @@ def _decode_call(
         *operands,
     )
     return acc, m[..., :1], l[..., :1]
+
+
+# What Mosaic says to every int8-KV stage (decode read, ragged read, write)
+# on TPU v5e with jax 0.9.0 / libtpu 0.0.34 (scripts/tpu_kernel_check.py,
+# 2026-09-26): the scale pages are [ps, Hk] slabs with Hk (8 or 16) in the
+# lane dimension, and a DMA slice must be a multiple of the 128-lane tile.
+# The engine refuses kv_dtype="int8" at start on TPU with this message
+# rather than serve it from the gather path; the fix (scales laid out with
+# positions in lanes, or pre-gathered per sequence) is its own change.
+INT8_KV_MOSAIC_ERROR = (
+    "Mosaic failed to compile TPU kernel: Slice shape along dimension 2 "
+    "must be aligned to tiling (128), but is 8 (the [page_size, "
+    "num_kv_heads] scale-page DMA of the int8-KV read and write kernels)"
+)
 
 
 def use_quantized_paged_kernel(num_kv_heads: int, head_dim: int) -> bool:
@@ -516,7 +529,7 @@ def paged_attention_decode(
             (P(None, None, "tp", None), P(None, None, "tp"))
             if quantized else P(None, None, "tp", None)
         )
-        sm = shard_map(
+        sm = jax.shard_map(
             inner_sm,
             mesh=mesh,
             in_specs=(

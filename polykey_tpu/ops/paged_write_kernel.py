@@ -125,7 +125,7 @@ def paged_write_rows_kernel(
             folded_pools.append(p)
             folded_rows.append(r.reshape(B, 1, p.shape[2]).astype(p.dtype))
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     row_specs = [
         pl.BlockSpec(fr.shape, lambda *_: (0, 0, 0),
                      memory_space=pltpu.VMEM)

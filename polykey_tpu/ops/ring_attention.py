@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
 
 _NEG_INF = -1e30
 
@@ -169,7 +168,7 @@ def ring_attention_spmd(
         logit_softcap=logit_softcap,
         window=window,
     )
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, pos_spec, pos_spec),

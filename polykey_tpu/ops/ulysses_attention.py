@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .attention import attention
-from ..compat import shard_map
 
 
 def ulysses_attention(
@@ -111,7 +110,7 @@ def ulysses_attention_spmd(
         logit_softcap=logit_softcap,
         window=window,
     )
-    return shard_map(
+    return jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, pos_spec, pos_spec),

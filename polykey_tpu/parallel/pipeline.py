@@ -49,7 +49,6 @@ from ..models.transformer import (
     make_causal_attend,
 )
 from ..models.layers import rms_norm
-from ..compat import shard_map
 
 
 def pipeline_forward(
@@ -161,7 +160,7 @@ def _staged(cfg: ModelConfig, mesh: Mesh, M: int, B: int, T: int):
     # Partial-manual shard_map (manual pp, auto dp/tp/ep) only traces under
     # jit — eager mode rejects out_specs that leave auto axes unmentioned.
     # The jit is inlined when callers are already tracing (train_step).
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(P("pp"), P(), P()),

@@ -17,9 +17,11 @@ tp ∈ {1,2,4,8}).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
@@ -120,6 +122,88 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh, params_tree=None):
 def shard_params(params: dict, cfg: ModelConfig, mesh: Mesh) -> dict:
     """Place a param pytree onto the mesh under the TP/PP/EP specs."""
     return jax.device_put(params, param_shardings(cfg, mesh, params))
+
+
+def init_sharded_params(
+    key: jax.Array, cfg: ModelConfig, mesh: Mesh, dtype,
+    quantize_bits: Optional[int] = None,
+) -> dict:
+    """Random-init params born in their final dtype and sharding — the
+    serving engine's start when no checkpoint is given.
+
+    `shard_params(quantize_params(init_params(key, cfg, dtype)))` lands
+    the whole `dtype` tree on one device first: 16 GB of bf16 for an 8B
+    model on a 16 GB chip, whatever the mesh or the quantization. Here
+    each layer is drawn (and quantized) alone on the mesh's first device
+    and written into the preallocated, already-sharded layer stack by a
+    donated in-place update, so a device holds its shard of the final
+    tree plus one layer of temporaries.
+
+    The values are init_params' bit for bit: a layer's draws depend only
+    on its key, quantization reduces within a layer, and generation stays
+    op-by-op like init_params itself — inside one jit XLA folds
+    `(sqrt2 * erfinv(u)) * scale` and divisions by constants into
+    differently-rounded forms.
+    """
+    from ..models.quant import quantize_embed, quantize_linears
+    from ..models.transformer import init_layer_params, init_top_params
+
+    def one_layer(k):
+        layer = init_layer_params(k, cfg, dtype)
+        return quantize_linears(layer, quantize_bits) if quantize_bits else layer
+
+    def top_params(k_embed, k_head):
+        top = init_top_params(k_embed, k_head, cfg, dtype)
+        if quantize_bits:
+            embed = quantize_embed(top["embed"])
+            top = {**quantize_linears(top, quantize_bits), "embed": embed}
+        return top
+
+    L = cfg.num_layers
+    with jax.default_device(mesh.devices.flat[0]):
+        k_embed, k_layers, k_head = jax.random.split(key, 3)
+        layer_keys = jax.random.split(k_layers, L)
+        stack_shape = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((L, *x.shape), x.dtype),
+            jax.eval_shape(one_layer, layer_keys[0]),
+        )
+        shardings = param_shardings(cfg, mesh, {
+            **jax.eval_shape(top_params, k_embed, k_head),
+            "layers": stack_shape,
+        })
+        stack_sh = shardings.pop("layers")
+        # Top first: the embedding's f32 quantization temporaries are the
+        # largest of the whole init, and nothing else is resident yet.
+        top = jax.device_put(top_params(k_embed, k_head), shardings)
+        stack = jax.tree.map(
+            lambda x, sh: jnp.zeros(x.shape, x.dtype, device=sh),
+            stack_shape, stack_sh,
+        )
+        leaves, treedef = jax.tree.flatten(stack_sh)
+        put_layer = _put_layer_jit(treedef, tuple(leaves))
+        for idx in range(L):
+            stack = put_layer(
+                stack, one_layer(layer_keys[idx]), jnp.int32(idx)
+            )
+    return {**top, "layers": stack}
+
+
+@functools.lru_cache(maxsize=8)
+def _put_layer_jit(treedef, shardings: tuple):
+    """The donated in-place write of one layer into the stacked tree,
+    pinned to the stack's shardings. Cached per sharding tree so every
+    engine built on the same mesh reuses one traced, compiled function
+    (a fresh jit per engine cost a retrace + compile each time)."""
+    def put_layer(stack, layer, idx):
+        return jax.tree.map(
+            lambda s, x: jax.lax.dynamic_update_index_in_dim(s, x, idx, 0),
+            stack, layer,
+        )
+
+    return jax.jit(
+        put_layer, donate_argnums=0,
+        out_shardings=jax.tree.unflatten(treedef, shardings),
+    )
 
 
 def paged_kv_sharding(mesh: Mesh) -> NamedSharding:

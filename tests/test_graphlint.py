@@ -162,7 +162,7 @@ def test_gl003_activation_upcast_does_not_fire():
 
 
 def test_gl003_fires_on_f64_anywhere():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2.0)(jnp.zeros((8,)))
     findings = dtype_findings("fixture", jaxpr, set(), bf16_path=False)
@@ -172,7 +172,7 @@ def test_gl003_fires_on_f64_anywhere():
 
 def test_gl003_walks_nested_jaxprs():
     # The f64 hides inside a scan body — the walk must descend.
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def fn(x):
             def body(carry, _):
                 return carry + x.astype(jnp.float64).sum(), None

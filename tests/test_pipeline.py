@@ -108,13 +108,6 @@ def test_pipeline_gradients_match_unsharded():
     )
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="legacy jax.experimental.shard_map cannot differentiate the "
-           "partial-manual (auto=) pipeline under SPMD on this jax "
-           "(PartitionId UNIMPLEMENTED at grad time); forward-path pp "
-           "equivalence is still covered above",
-)
 def test_train_step_improves_under_pp():
     """Full 3D train step: dp=2 x pp=2 x tp=2 — the pipeline composes with
     data and tensor parallelism (tp stays GSPMD-automatic inside stages)."""

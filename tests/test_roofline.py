@@ -179,26 +179,28 @@ def test_grade_resident_fraction_extends_without_breaking_replay():
     assert "hbm_resident_fraction" not in g_cpu
 
 
-def test_detect_chip_unknown_kind_returns_none(monkeypatch):
-    """An unknown v5 variant (or any unrecognized kind) must NOT grade
-    against the v5p roofline (ADVICE r5): only explicit v5e/v5p kinds
-    map; everything else returns None and the scorecard degrades to
-    geometry-only."""
+def test_detect_chip_unknown_tpu_kind_raises(monkeypatch):
+    """Only explicit v5e/v5p kinds map. An unknown TPU kind must neither
+    grade against another chip's roofline (ADVICE r5: old code silently
+    picked v5p) nor silently skip grading: it raises until the table has
+    its peaks. Off-TPU stays None."""
     import jax as _jax
 
     class _Dev:
-        def __init__(self, kind):
-            self.platform = "tpu"
+        def __init__(self, kind, platform="tpu"):
+            self.platform = platform
             self.device_kind = kind
 
     for kind, expected in (
         ("TPU v5 lite", "tpu-v5e"),
         ("TPU v5e", "tpu-v5e"),
         ("TPU v5p", "tpu-v5p"),
-        ("TPU v5x-mystery", None),   # old code: silently v5p
-        ("TPU v6e", None),
-        ("warp-drive", None),
     ):
         monkeypatch.setattr(_jax, "devices", lambda k=kind: [_Dev(k)])
-        got = detect_chip()
-        assert (got.name if got else None) == expected, kind
+        assert detect_chip().name == expected, kind
+    for kind in ("TPU v5x-mystery", "TPU v6e", "warp-drive"):
+        monkeypatch.setattr(_jax, "devices", lambda k=kind: [_Dev(k)])
+        with pytest.raises(ValueError, match="CHIP_SPECS"):
+            detect_chip()
+    monkeypatch.setattr(_jax, "devices", lambda: [_Dev("cpu", "cpu")])
+    assert detect_chip() is None

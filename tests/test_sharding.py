@@ -112,3 +112,40 @@ def test_tp_actually_shards_weights():
 def test_mesh_validation():
     with pytest.raises(ValueError):
         create_mesh(MeshConfig(tp=3), jax.devices()[:8])
+
+
+@pytest.mark.parametrize("cfg, mesh_config, dtype, bits", [
+    (TINY_LLAMA, MeshConfig(pp=2, tp=2), jnp.bfloat16, 8),
+    (TINY_MIXTRAL, MeshConfig(ep=2, tp=2), jnp.float32, 4),
+])
+def test_init_sharded_params_is_init_params_bit_for_bit(
+        cfg, mesh_config, dtype, bits):
+    """The engine's start (each layer drawn alone, straight into its final
+    dtype and sharding) yields exactly the tree the old path built by
+    materialising everything first: same values to the last bit, same
+    dtypes, same shardings — int8 and int4 trees, dense and MoE, pp/ep/tp
+    meshes (the unquantized tree is the same draws without the last step).
+    (float32 would expose a jitted generation: XLA folds
+    (sqrt2·erfinv(u))·scale and x/127 into differently-rounded forms.)"""
+    from polykey_tpu.models.quant import quantize_params
+    from polykey_tpu.parallel.sharding import init_sharded_params
+
+    n = (mesh_config.dp * mesh_config.pp * mesh_config.sp
+         * mesh_config.ep * mesh_config.tp)
+    mesh = create_mesh(mesh_config, devices=jax.devices()[:n])
+    key = jax.random.PRNGKey(3)
+    want = init_params(key, cfg, dtype)
+    if bits:
+        want = quantize_params(want, cfg, bits=bits)
+    want = shard_params(want, cfg, mesh)
+    got = init_sharded_params(key, cfg, mesh, dtype, quantize_bits=bits)
+
+    want_leaves, want_def = jax.tree.flatten(want)
+    got_leaves, got_def = jax.tree.flatten(got)
+    assert got_def == want_def
+    for g, w in zip(got_leaves, want_leaves):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.sharding == w.sharding
+        assert np.array_equal(
+            np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32))
+        )

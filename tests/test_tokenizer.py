@@ -37,6 +37,22 @@ def test_incremental_decode_splits_multibyte():
     assert state == b""
 
 
+def test_incremental_decode_replaces_bytes_that_never_complete():
+    """A random-init model emits any byte, 0xFD included — a lead byte no
+    valid UTF-8 sequence starts with. It must stream as U+FFFD (what
+    decode() yields) instead of being held forever with every later byte
+    behind it: the held tail stays within one incomplete sequence."""
+    tok = ByteTokenizer()
+    ids = [3 + b for b in b"\xfdab\xe2\x86\x92c\xff\xe2\x86"]
+    state, out = b"", []
+    for i in ids:
+        chunk, state = tok.decode_incremental([i], state)
+        assert len(state) <= 3
+        out.append(chunk)
+    assert "".join(out) == "\ufffdab→c\ufffd"
+    assert state == b"\xe2\x86"       # an incomplete tail still waits
+
+
 def test_load_tokenizer_byte():
     tok = load_tokenizer("byte")
     assert isinstance(tok, ByteTokenizer)
