@@ -19,7 +19,8 @@ the engine's seed, prompts from a seed here.
 It fails unless the platform is a TPU from the roofline table, every
 request returned the tokens it asked for with Usage filled, no engine
 restarted, no request failed, decode blocks were dispatched, and the
-warmed prefill and decode executables contain Mosaic custom calls. The
+warmed prefill and decode executables carry the Mosaic custom calls of the
+flash, paged-decode and KV-write kernels, by name. The
 last stdout line is then {"ok": true, "device": {...}}; on any failure
 the exit code is non-zero and no such line is printed. It prints counts,
 sizes and start-up seconds — never a rate. `--tiny` runs the same code
@@ -49,6 +50,13 @@ STOP_TIMEOUT_S = 60.0
 LONG_PROMPT_CHARS = 160      # byte tokenizer: > the 128-token bucket
 CONCURRENT = 6
 REQUESTS = 2 + CONCURRENT  # one unary, one streamed, the concurrent handful
+# The Pallas kernels on the default path, by the name their pallas_call
+# gives the Mosaic custom call; engine_stats lists the names found in the
+# lowered text of the warmed prefill and decode steps.
+SERVED_KERNELS = {
+    "prefill": ("flash_attention",),
+    "decode": ("paged_attention_decode", "paged_kv_write"),
+}
 
 
 class SmokeFailure(Exception):
@@ -92,10 +100,6 @@ def server_env(args, port: int) -> dict:
         "LISTEN_ADDR": f"127.0.0.1:{port}",
         "PYTHONPATH": ROOT + os.pathsep + env.get("PYTHONPATH", ""),
     })
-    # Persist every executable, however quick its compile, so that a
-    # second run in the same checkout compiles nothing (JAX's own knob;
-    # an outer setting wins).
-    env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     if args.tiny:
         env["JAX_PLATFORMS"] = "cpu"
         env["POLYKEY_DTYPE"] = "float32"
@@ -231,8 +235,11 @@ def check_stats(args, last: dict, asked: int) -> dict:
               f"device_kind {device['kind']!r} is not in the roofline table")
         for eng in engines:
             calls = eng["warmup_mosaic_calls"]
-            check(calls.get("prefill", 0) > 0 and calls.get("decode", 0) > 0,
-                  f"served executables lack Mosaic custom calls: {calls}")
+            for step, kernels in SERVED_KERNELS.items():
+                missing = set(kernels) - set(calls.get(step, {}))
+                check(not missing,
+                      f"the served {step} executable lacks the Mosaic "
+                      f"kernel(s) {sorted(missing)}: {calls}")
     # The unary reply has no Usage, so its length is checked here: decode
     # blocks emit every token but a request's first, which prefill samples.
     completed = total(engines, "requests_completed")
@@ -281,7 +288,7 @@ def report(args, device: dict, first: dict, last: dict, asked: int) -> None:
               f"warmup_executables={comp.get('executables', 0)} "
               f"from_cache={comp.get('cache_hits', 0)} "
               f"fresh={comp.get('fresh_compiles', 0)} "
-              f"mosaic_calls={_ints(eng['warmup_mosaic_calls'])} "
+              f"mosaic_calls={_kernels(eng['warmup_mosaic_calls'])} "
               f"collectives={_ints(eng['warmup_collectives'])}")
     serving = (int(head["compiles"]["executables"])
                - int(warm[0]["compiles"]["executables"]))
@@ -299,6 +306,12 @@ def report(args, device: dict, first: dict, last: dict, asked: int) -> None:
 
 def _ints(mapping: dict) -> dict:
     return {k: int(v) for k, v in mapping.items()}
+
+
+def _kernels(calls: dict) -> dict:
+    """{step: {kernel name: call sites}}, in a fixed order."""
+    return {step: dict(sorted(_ints(calls[step]).items()))
+            for step in sorted(calls)}
 
 
 def stop_server(proc: subprocess.Popen, log_path: str) -> None:

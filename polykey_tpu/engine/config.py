@@ -51,16 +51,23 @@ def enable_persistent_compile_cache() -> Optional[str]:
     <checkout>/.jax_cache (git-ignored). JAX binds the directory at the
     first compile, so every process entry that compiles (gateway server,
     disagg worker, chip_smoke.py, bench.py, the kernel check) calls this
-    before its first jit. JAX's own variables tune the rest
-    (JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS, ...).
+    before its first jit.
+
+    Every executable is persisted, however quick its compile: a serving
+    warm-up builds a handful of sub-second ones (slot-state merges, the
+    host-tier gather) that JAX's default 1 s threshold would recompile at
+    every start. JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS from outside
+    wins; JAX's other variables tune the rest.
     """
     if os.environ.get("POLYKEY_COMPILE_CACHE", "1") == "0":
         return None
+    import jax
+
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", _CHECKOUT_CACHE)
     return _CHECKOUT_CACHE
 

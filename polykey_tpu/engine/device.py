@@ -14,15 +14,18 @@ entry points can refuse a platform nobody asked for:
 - `compile_counts` — executables built in this process and how many of
   them came out of the persistent compilation cache, from JAX's own
   monitoring events (a benchmark window expects zero new ones).
-- `mosaic_calls` / `collective_ops` — Mosaic custom calls in a lowered
-  step and collectives in its compiled HLO: the evidence that a served
-  executable contains the Pallas kernels (not the gate functions'
-  opinion that it should) and, on a mesh, really talks over ICI.
+- `mosaic_calls` / `collective_ops` — Mosaic custom calls, by kernel
+  name, in a lowered step and collectives in its compiled HLO: the
+  evidence that a served executable contains the Pallas kernels (not the
+  gate functions' opinion that it should) and, on a mesh, really talks
+  over ICI.
 """
 
 from __future__ import annotations
 
+import collections
 import os
+import re
 import threading
 
 import jax
@@ -31,6 +34,10 @@ from .roofline import detect_chip
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+# One per Mosaic custom call in StableHLO text:
+#   stablehlo.custom_call @tpu_custom_call(...) {..., kernel_name = "flash_attention"}
+_KERNEL_NAME = re.compile(r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"')
 
 _census_lock = threading.Lock()
 _census = {"installed": False, "executables": 0, "cache_hits": 0}
@@ -120,9 +127,12 @@ def device_memory(devices) -> list:
     return out
 
 
-def mosaic_calls(lowered) -> int:
-    """Mosaic (Pallas TPU) custom calls in a `jax.stages.Lowered` step."""
-    return lowered.as_text().count("tpu_custom_call")
+def mosaic_calls(lowered) -> dict:
+    """Mosaic (Pallas TPU) custom calls in a `jax.stages.Lowered` step, by
+    kernel name (the `name=` of the pallas_call; each custom call carries
+    it as its `kernel_name` attribute) -> number of call sites. A layer
+    scan's body is lowered once, so a kernel every layer runs counts 1."""
+    return dict(collections.Counter(_KERNEL_NAME.findall(lowered.as_text())))
 
 
 def collective_ops(compiled) -> int:

@@ -708,12 +708,24 @@ class InferenceEngine:
                 f"slices={config.num_slices} needs {n_devices} "
                 f"devices, have {len(all_devices)}"
             )
-        # Replica placement: replica i owns device slice i when the host
-        # has a slice for every replica. With fewer devices the replicas
-        # share the first slice (several engines on one chip divide its
-        # HBM) — stated in DEPLOY.md and logged, never silent.
+        # Replica placement: one of N pooled replicas (replicas > 1) owns
+        # device slice i when the host has a slice for every replica. With
+        # fewer devices the replicas share the first slice (several
+        # engines on one chip divide its HBM) — stated in DEPLOY.md and
+        # logged, never silent. An engine that is not in a pool takes the
+        # first slice whatever its `replica` says: a disagg worker's
+        # `replica` is its index within its tier (a fault-targeting and
+        # label identity, engine/worker.py), and that worker is the only
+        # engine in its process.
         first = 0
-        if config.replicas * n_devices <= len(all_devices):
+        if config.replicas > 1 and config.replica >= config.replicas:
+            raise ValueError(
+                f"replica={config.replica} is not one of "
+                f"replicas={config.replicas}"
+            )
+        if config.replicas > 1 and (
+            config.replicas * n_devices <= len(all_devices)
+        ):
             first = config.replica * n_devices
         elif config.replicas > 1 and logger is not None:
             logger.warn(
@@ -778,6 +790,21 @@ class InferenceEngine:
             raise ValueError(
                 "kv_dtype=int8 (POLYKEY_KV_DTYPE) does not lower on TPU "
                 f"yet: {INT8_KV_MOSAIC_ERROR}"
+            )
+        # Ragged dispatch on/off. POLYKEY_DISABLE_RAGGED is the
+        # operational kill-switch (wins over config/env enablement — the
+        # POLYKEY_DISABLE_PAGED_KERNEL pattern): a ragged regression must
+        # be containable by falling back to the bucketed executables
+        # without a config rollout.
+        self._ragged = config.ragged_dispatch and os.environ.get(
+            "POLYKEY_DISABLE_RAGGED", ""
+        ).lower() not in ("1", "true")
+        if self._ragged and self._identity["platform"] == "tpu":
+            from ..ops.paged_write_kernel import RAGGED_WRITE_MOSAIC_ERROR
+
+            raise ValueError(
+                "ragged_dispatch (POLYKEY_RAGGED) does not run on TPU yet: "
+                f"{RAGGED_WRITE_MOSAIC_ERROR}"
             )
         data_sh = paged_kv_sharding(self.mesh)
         if self._kv_quantized:
@@ -1000,14 +1027,8 @@ class InferenceEngine:
         # --- Ragged dispatch (ISSUE 12): admissions + chunk advancement
         # become token-range appends into ONE flat mixed prefill+decode
         # dispatch (_ragged_fn) whenever prefill work exists; pure-decode
-        # iterations keep the K-step block path. POLYKEY_DISABLE_RAGGED
-        # is the operational kill-switch (wins over config/env
-        # enablement — the POLYKEY_DISABLE_PAGED_KERNEL pattern): a
-        # ragged regression must be containable by falling back to the
-        # bucketed executables without a config rollout.
-        self._ragged = config.ragged_dispatch and os.environ.get(
-            "POLYKEY_DISABLE_RAGGED", ""
-        ).lower() not in ("1", "true")
+        # iterations keep the K-step block path (self._ragged is decided
+        # above, next to the TPU refusals).
         self._jit_ragged = None
         if self._ragged:
             # Static prefill-stream width: the per-iteration token
@@ -2866,9 +2887,15 @@ class InferenceEngine:
 
     def _warm_call(self, step: str, fn, *args, **kwargs):
         """One warm-up dispatch of a served step ("prefill" / "decode").
-        The first of each kind is also lowered, so stats() can say from
-        the executable itself — not from the gate functions — whether it
-        carries Mosaic kernels and, on a mesh, collectives."""
+        The first of each kind is also inspected, so stats() can say from
+        the executable itself — not from the gate functions — which
+        Mosaic kernels it carries and, on a mesh, how many collectives.
+
+        This builds nothing twice: `fn.lower(...)` and `.compile()` go
+        through the jit's own lowering cache, so the dispatch below finds
+        the executable already built and serves from the very object that
+        was inspected (tests/test_device.py pins that on this JAX: one
+        backend compile for lower + compile + call)."""
         if step not in self._warm_kernels:
             lowered = fn.lower(*args, **kwargs)
             # polylint: disable=ML002(keyed by step kind: "prefill" / "decode", written at warm-up only)

@@ -421,10 +421,13 @@ def forward_ragged(
     Position-wise compute (embed, norms, projections, RoPE, MLP) runs on
     the stream as a [1, T] batch — per-row math identical to the
     bucketed paths. KV writes go through paged_write's per-token path
-    (one batch row per token: B=T, T=1 — the decode write shape, so the
-    TPU write kernel serves it unchanged); attention goes through
-    ragged_paged_attention (kernel on TPU, per-token gather fallback
-    elsewhere — the bit-identity reference). Padding rows carry
+    (one batch row per token: B=T, T=1 — the decode write shape: the XLA
+    scatter off-TPU; on TPU that shape selects the decode write kernel,
+    which is NOT valid here — a chunk's tokens share pages — so the
+    engine refuses ragged dispatch on TPU until this step has a write of
+    its own, ops/paged_write_kernel.RAGGED_WRITE_MOSAIC_ERROR); attention
+    goes through ragged_paged_attention (kernel on TPU, per-token gather
+    fallback elsewhere — the bit-identity reference). Padding rows carry
     position 0 and all-garbage table rows: they write to and attend over
     the reserved garbage page, exactly like inactive decode lanes.
 

@@ -196,6 +196,7 @@ def _flash_bhsd(
             transcendentals=B * Hq * Tp * Sp,
         ),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v, q_positions, window)
 
 

@@ -357,6 +357,7 @@ def _decode_call(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="paged_attention_decode",
     )(
         page_tables.astype(jnp.int32),
         positions.astype(jnp.int32),

@@ -53,6 +53,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# Why the engine refuses ragged dispatch on TPU (engine.py, next to the
+# int8-KV refusal). forward_ragged writes its flat token stream through
+# this kernel as B = stream-width single-row lanes. Two things stop that:
+# the kernel holds one page buffer and one DMA semaphore per lane per pool
+# and wave, so the default 520-token stream asks for more semaphore memory
+# than the core has (Mosaic's message below, 8B-int8 smoke on TPU v5e,
+# jax 0.9.0 / libtpu 0.0.34, 2026-09-26); and a narrower stream that did
+# compile would lose writes, because a prefill chunk's tokens share pages
+# and every lane of a wave writes back its own copy of the whole page (the
+# "active lanes never share a page" invariant above holds for decode
+# lanes only). The ragged step needs a write that groups rows by page.
+RAGGED_WRITE_MOSAIC_ERROR = (
+    "RESOURCE_EXHAUSTED: Allocation (size=4160) would exceed memory "
+    "(size=2048) :: #allocation4 [shape = 's32[1040]{0}', space=sflag, "
+    "size = 0x1040, tag = 'scratch operand'] :: paged_kv_write (the KV "
+    "write kernel takes one DMA semaphore and one page buffer per stream "
+    "token, and rows of one prefill chunk share pages)"
+)
+
+
 def _make_kernel(n_pools: int, B: int, ps: int):
     """Kernel body over `n_pools` (rows, pool_in, pool_out, buf, 2 sems)
     groups; arity varies with the pool list, so the body is built here."""
@@ -151,6 +171,7 @@ def paged_write_rows_kernel(
         # pids=0 offs=1 rows=2..2+n-1 pools=2+n..2+2n-1.
         input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret,
+        name="paged_kv_write",
     )(
         page_ids.astype(jnp.int32),
         offsets.astype(jnp.int32),

@@ -391,6 +391,7 @@ def _ragged_call(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(
         seq_starts.astype(jnp.int32),
         seq_lens.astype(jnp.int32),

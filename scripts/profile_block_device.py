@@ -8,8 +8,10 @@ dispatch M blocks chained, sync once on the final packed tokens, and
 report (wall_2M - wall_M) / M per block. The sync is np.asarray of the
 small [K, B] output.
 
-Variants: kernel vs gather attention path, K=16 vs K=1 (fixed-vs-marginal
-split), donation on vs off (pool-copy cost).
+Variants: K=16 vs K=1 (fixed-vs-marginal split), donation on vs off
+(pool-copy cost), all on the attention path the environment selects (the
+output says which; POLYKEY_DISABLE_PAGED_KERNEL=1 from outside gives the
+gather path).
 
 Usage: python scripts/profile_block_device.py [model] [batch] [ctx] [K]
 """
@@ -82,11 +84,7 @@ def main():
                "platform": dev.platform, "pool_gb": round(pool_gb, 2),
                "kv": "int8" if kv_int8 else "bf16"}
 
-    def run_variant(name, steps, donate, kernel):
-        if kernel:
-            os.environ.pop("POLYKEY_DISABLE_PAGED_KERNEL", None)
-        else:
-            os.environ["POLYKEY_DISABLE_PAGED_KERNEL"] = "1"
+    def run_variant(name, steps, donate):
         jit_kw = dict(static_argnames=(
             "cfg", "greedy", "steps", "eos_id", "candidates", "mesh"))
         if donate:
@@ -120,14 +118,19 @@ def main():
             f"(wall M4={w4*1000:.0f} M8={w8*1000:.0f})")
         return round(per_block, 1), pool
 
-    results["block_kernel_ms"], paged = run_variant(
-        f"K={K} kernel donate", K, True, True)
-    results["block_gather_ms"], paged = run_variant(
-        f"K={K} gather donate", K, True, False)
-    results["block_k1_kernel_ms"], paged = run_variant(
-        "K=1 kernel donate", 1, True, True)
+    # Whichever attention path the environment selects, named in the
+    # output; POLYKEY_DISABLE_PAGED_KERNEL=1 from outside gives the gather
+    # path (nothing in code sets a kill switch).
+    from polykey_tpu.ops.paged_attention_kernel import use_paged_kernel
+
+    results["paged_kernel"] = use_paged_kernel(cfg.num_kv_heads, cfg.head_dim)
+    path = "kernel" if results["paged_kernel"] else "gather"
+    results["block_ms"], paged = run_variant(
+        f"K={K} {path} donate", K, True)
+    results["block_k1_ms"], paged = run_variant(
+        f"K=1 {path} donate", 1, True)
     results["block_nodonate_ms"], paged = run_variant(
-        f"K={K} kernel NO-donate", K, False, True)
+        f"K={K} {path} NO-donate", K, False)
 
     print(json.dumps(results), flush=True)
 

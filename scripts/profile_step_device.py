@@ -13,7 +13,9 @@ benchmark's trace reduction exists.
 
 Components, at serving geometry (defaults: llama-1b-bench, B=32, ctx=512):
 - HBM bandwidth floor: one full read of every param byte per iteration;
-- forward_paged decode, Pallas kernel path vs gather path;
+- forward_paged decode on the attention path the environment selects
+  (the output says which; POLYKEY_DISABLE_PAGED_KERNEL=1 from outside
+  gives the gather path);
 - unembed, unembed+argmax.
 
 Usage: python scripts/profile_step_device.py [model] [batch] [ctx]
@@ -123,16 +125,17 @@ def main():
         t = (tok + x) % 97 + 1
         return forward_paged(p, cfg, t, pos, pg, ptbl)[0]
 
-    os.environ.pop("POLYKEY_DISABLE_PAGED_KERNEL", None)
-    results["fwd_kernel_ms"] = timed(
-        "forward_paged kernel", fwd,
-        params, tokens, positions, paged, page_tables)
+    # Whichever attention path the environment selects, named in the
+    # output. For the kernel-vs-gather comparison run the script twice,
+    # the second time with POLYKEY_DISABLE_PAGED_KERNEL=1 set outside:
+    # nothing in code sets a kill switch.
+    from polykey_tpu.ops.paged_attention_kernel import use_paged_kernel
 
-    os.environ["POLYKEY_DISABLE_PAGED_KERNEL"] = "1"
-    results["fwd_gather_ms"] = timed(
-        "forward_paged gather", fwd,
+    results["paged_kernel"] = use_paged_kernel(cfg.num_kv_heads, cfg.head_dim)
+    results["fwd_ms"] = timed(
+        "forward_paged "
+        + ("kernel" if results["paged_kernel"] else "gather"), fwd,
         params, tokens, positions, paged, page_tables)
-    os.environ.pop("POLYKEY_DISABLE_PAGED_KERNEL", None)
 
     h = jnp.ones((B, cfg.hidden_size), jnp.bfloat16)
     results["unembed_ms"] = timed(

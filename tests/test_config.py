@@ -187,6 +187,10 @@ def _record_config_updates(monkeypatch):
     return calls
 
 
+_MIN_SECS = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+_PERSIST_ALL = ("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
 def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
     """JAX_COMPILATION_CACHE_DIR set => JAX already reads it; the program
     reports it and writes no cache directory of its own."""
@@ -194,9 +198,10 @@ def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
 
     monkeypatch.delenv("POLYKEY_COMPILE_CACHE", raising=False)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.delenv(_MIN_SECS, raising=False)
     calls = _record_config_updates(monkeypatch)
     assert ec.enable_persistent_compile_cache() == str(tmp_path)
-    assert calls == []
+    assert calls == [_PERSIST_ALL]
 
 
 def test_compile_cache_defaults_to_checkout(monkeypatch):
@@ -208,29 +213,42 @@ def test_compile_cache_defaults_to_checkout(monkeypatch):
 
     monkeypatch.delenv("POLYKEY_COMPILE_CACHE", raising=False)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv(_MIN_SECS, raising=False)
     calls = _record_config_updates(monkeypatch)
     checkout = os.path.dirname(os.path.dirname(
         os.path.abspath(polykey_tpu.__file__)))
     want = os.path.join(checkout, ".jax_cache")
     assert ec.enable_persistent_compile_cache() == want
     assert ec.enable_persistent_compile_cache() == want   # stable
-    assert set(calls) == {("jax_compilation_cache_dir", want)}
+    assert set(calls) == {("jax_compilation_cache_dir", want), _PERSIST_ALL}
+
+
+def test_compile_cache_threshold_from_outside_wins(monkeypatch, tmp_path):
+    """The program persists every executable (a warm start compiles
+    nothing) unless JAX's own threshold variable says otherwise."""
+    import polykey_tpu.engine.config as ec
+
+    monkeypatch.delenv("POLYKEY_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv(_MIN_SECS, "2.5")
+    calls = _record_config_updates(monkeypatch)
+    assert ec.enable_persistent_compile_cache() == str(tmp_path)
+    assert calls == []
 
 
 def test_compile_cache_written_where_placed(tmp_path):
     """End to end in a fresh process: with the directory placed from
-    outside, a compile after enable_persistent_compile_cache() lands its
-    entry there (JAX's own variables lower the persistence thresholds)."""
+    outside, a sub-second compile after enable_persistent_compile_cache()
+    lands its entry there (JAX's default threshold would skip it)."""
     import subprocess
     import sys
 
     env = dict(
         os.environ, JAX_PLATFORMS="cpu",
         JAX_COMPILATION_CACHE_DIR=str(tmp_path),
-        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
-        JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
     )
     env.pop("POLYKEY_COMPILE_CACHE", None)
+    env.pop(_MIN_SECS, None)
     code = (
         "import polykey_tpu.engine.config as ec\n"
         "print(ec.enable_persistent_compile_cache())\n"

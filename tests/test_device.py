@@ -76,6 +76,12 @@ def test_replica_i_takes_device_slice_i():
 
     assert _devices_of(replica(3, 4)) == [3]
     assert _devices_of(replica(1, 2, tp=2)) == [2, 3]
+    # Not in a pool: `replica` is only an identity (a disagg worker's
+    # index within its tier, engine/worker.py) and the engine takes the
+    # first slice — on a one-device host slice 1 would be empty.
+    assert _devices_of(replica(1, 1)) == [0]
+    with pytest.raises(ValueError, match="not one of replicas=2"):
+        InferenceEngine(replica(2, 2))
 
     class Log:
         warned = []
@@ -100,6 +106,26 @@ def test_replica_pool_passes_the_pool_size_down():
     finally:
         pool.shutdown()
     assert devices == [[0], [1]]
+
+
+@pytest.mark.parametrize("option, message", [
+    (dict(kv_dtype="int8"), "POLYKEY_KV_DTYPE.*aligned to tiling"),
+    (dict(ragged_dispatch=True), "POLYKEY_RAGGED.*RESOURCE_EXHAUSTED"),
+])
+def test_options_whose_kernels_fail_on_tpu_are_refused_at_start(
+    monkeypatch, option, message,
+):
+    """An option whose Pallas path does not compile (or is not valid) on
+    the chip stops the engine at start on TPU with the compiler's message
+    — it never serves from a slower path under the option's name. On the
+    CPU the same configs build (every other test uses them)."""
+    monkeypatch.setattr(
+        "polykey_tpu.engine.engine.device_identity", lambda: {
+            "platform": "tpu", "device_kind": "TPU v5 lite",
+            "device_count": 1, "chip": "tpu-v5e",
+        })
+    with pytest.raises(ValueError, match=message):
+        InferenceEngine(dataclasses.replace(TINY, **option))
 
 
 def test_disagg_spawn_is_refused_on_a_tpu_host(monkeypatch):
@@ -132,3 +158,58 @@ def test_compile_census_counts_executables():
     assert after["executables"] == before["executables"] + 1
     assert after["fresh_compiles"] == (
         after["executables"] - after["cache_hits"])
+
+
+def test_inspecting_a_step_builds_it_once():
+    """engine._warm_call lowers and (on a mesh) compiles the first prefill
+    and decode step to read their kernels and collectives, then dispatches
+    the jitted function. That must cost ONE backend compile: the AOT path
+    and the dispatch share the jit's lowering cache, so what was inspected
+    is what serves. A JAX that stops sharing would double the two largest
+    compiles of every multi-chip start — this pins it."""
+    from functools import partial
+
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("tp",))
+    w = jax.device_put(jnp.ones((64, 96)), NamedSharding(mesh, P("tp", None)))
+    x = jax.device_put(jnp.ones((8, 64)), NamedSharding(mesh, P(None, "tp")))
+
+    @partial(jax.jit, static_argnames=("scale",), donate_argnums=(0,))
+    def step(x, w, scale):
+        return jnp.tanh(x @ w) * scale
+
+    device.install_compile_census()
+    before = device.compile_counts()["executables"]
+    lowered = step.lower(x, w, scale=3)
+    assert device.mosaic_calls(lowered) == {}
+    assert device.collective_ops(lowered.compile()) >= 1
+    step(x, w, scale=3).block_until_ready()
+    assert device.compile_counts()["executables"] == before + 1
+
+
+def test_mosaic_calls_names_the_kernels():
+    """A step lowered for TPU names each Pallas kernel it carries (the
+    pallas_call's `name=`), counted per call site — a scan body once."""
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def double(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2
+
+    def call(x):
+        return pl.pallas_call(
+            double, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            name="double_rows",
+        )(x)
+
+    def step(x):
+        def layer(carry, _):
+            return call(carry) + call(carry), None
+        return jax.lax.scan(layer, x, None, length=3)[0]
+
+    x = jnp.ones((8, 128), jnp.float32)
+    lowered = jax.jit(step).trace(x).lower(lowering_platforms=("tpu",))
+    assert device.mosaic_calls(lowered) == {"double_rows": 2}

@@ -539,3 +539,33 @@ def test_forward_ragged_gather_under_mesh(monkeypatch):
     )
     assert hidden.shape == (T, cfg.hidden_size)
     assert calls["kernel"] == 0
+
+
+@pytest.mark.parametrize("kv_dtype", [None, jnp.int8])
+def test_forward_ragged_gates_on_head_geometry(monkeypatch, kv_dtype):
+    """The kernel gate sees (num_kv_heads, head_dim) for fp and int8 pools
+    alike: the LAST two axes of the stacked 5-D [L, N, ps, Hk, D] pool,
+    not axes 2,3 (page_size, Hk) — gated on those, the fp ragged kernel
+    can never be chosen on TPU."""
+    from polykey_tpu.engine.kv_cache import init_paged_kv
+    from polykey_tpu.models.config import get_config
+    from polykey_tpu.models.transformer import forward_ragged, init_params
+
+    seen = []
+    monkeypatch.setattr(
+        "polykey_tpu.ops.ragged_paged_attention_kernel.use_ragged_kernel",
+        lambda Hk, D: seen.append((Hk, D)) or False,
+    )
+    cfg = get_config("tiny-llama")
+    params = init_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    paged = init_paged_kv(cfg, 16, 8, jnp.float32, kv_dtype=kv_dtype)
+    T, P = 8, 4
+    zeros = jnp.zeros((T,), jnp.int32)
+    ones = jnp.asarray([1, 1], jnp.int32)
+    forward_ragged(
+        params, cfg, zeros, zeros, paged, jnp.zeros((T, P), jnp.int32),
+        jnp.asarray([0, 1], jnp.int32), ones, ones,
+        jnp.zeros((2, P), jnp.int32),
+    )
+    assert cfg.num_kv_heads != 8                 # page_size: tells them apart
+    assert seen == [(cfg.num_kv_heads, cfg.head_dim)]
