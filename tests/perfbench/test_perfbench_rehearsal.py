@@ -1,0 +1,68 @@
+"""Each cell's command end to end on the CPU at toy size (`--tiny`): the
+same processes, sockets, traffic generator, readers and last-line contract
+as on the chip; platform=cpu, and never a device metric."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from perfbench_paths import ROOT
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    MANIFEST = json.load(f)
+DEVICE_METRICS = {m["name"] for m in MANIFEST["per_layer"]
+                  if m["source"] == "device_trace"}
+
+
+def run_cell(root: str, cell: str, trace: int, seed: int = 2147483659):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(root, "perfbench", "run.py"),
+         "--workload", cell, "--seed", str(seed), "--seconds", "3",
+         "--trace", str(trace), "--tiny"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600,
+    )
+    return proc
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+def test_cell_rehearses_on_the_cpu(cell):
+    entry = next(w for w in MANIFEST["workloads"] if w["name"] == cell)
+    proc = run_cell(ROOT, cell, trace=0)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > entry_clients(entry)
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] == entry["chips"]
+    wanted = {m["name"] for m in MANIFEST["end_to_end"]
+              if "workloads" not in m or cell in m["workloads"]}
+    assert set(line["metrics"]) == wanted
+    assert all(m["value"] > 0 for m in line["metrics"].values())
+    assert line["reference"]["ok"] and line["reference"]["tokens"] == 32
+    samples = os.path.join(ROOT, "perfbench", "out", cell,
+                           "seed2147483659.trace0.samples.json.gz")
+    assert os.path.exists(samples)
+
+
+def entry_clients(entry: dict) -> int:
+    path = os.path.join(ROOT, "perfbench", "traffic", entry["traffic"] + ".json")
+    with open(path) as f:
+        return json.load(f)["clients"]
+
+
+def test_traced_rehearsal_reports_counts_but_no_device_metric():
+    cell = MANIFEST["workloads"][0]["name"]
+    proc = run_cell(ROOT, cell, trace=1)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True
+    assert not set(line["metrics"]) & DEVICE_METRICS
+    assert "busy_s" not in line["device"] and "breakdown" not in line
+    assert line["metrics"]["compiles_in_window"]["value"] == 0
+    assert 0 < line["metrics"]["avg_lanes"]["value"] <= 16
