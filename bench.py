@@ -209,10 +209,6 @@ def bench_engine(
             return prompt_fn()
         return "".join(chr(c) for c in rng.integers(97, 123, prompt_len))
 
-    # Loop-trace counters are cheap and make occupancy visible in the
-    # artifact (avg live lanes per dispatched block — the number that
-    # caught the admission-policy bug).
-    os.environ.setdefault("POLYKEY_LOOP_TRACE", "1")
     engine = InferenceEngine(engine_cfg, params=params, draft_params=draft_params)
     try:
         # Shape compiles happen in __init__ (compile_warmup=True); this
@@ -275,7 +271,7 @@ def bench_engine(
         # retiring slots empty for several blocks — measured 5/32 lanes).
         # Snapshot the always-on occupancy tracker around JUST this loop
         # so avg_lanes reflects the saturated run, not warmup/probe
-        # blocks (ISSUE 4: measured lanes, not the loop-trace opt-in).
+        # blocks (ISSUE 4: measured lanes from the always-on tracker).
         acc0 = engine.metrics.lanes_snapshot()
         timings, errors = [], []
         elapsed = run_closed_loop(

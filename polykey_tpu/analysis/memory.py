@@ -161,8 +161,6 @@ INTERNAL_KNOBS: dict[str, str] = {
     "POLYKEY_PROFILE_QUANT":
         "bench profiler quantization override (bench.py only)",
     "POLYKEY_PROFILE_KV": "bench profiler KV override (bench.py only)",
-    "POLYKEY_LOOP_TRACE":
-        "engine-loop trace dump for dispatch debugging (tests/bench)",
     "POLYKEY_FAULTS":
         "chaos fault-injection spec (faults.py); test/soak harness "
         "surface, never an operator knob",

@@ -69,7 +69,7 @@ import numpy as np
 from ..analysis import schedwitness as _schedwitness
 from ..faults import get_injector
 from ..models.config import ModelConfig, get_config
-from ..obs.timeline import TimelineRecorder
+from ..obs.timeline import TimelineRecorder, phase
 from ..models.transformer import forward_paged, forward_ragged, unembed
 from ..parallel.mesh import MeshConfig, create_mesh
 from ..parallel.sharding import (
@@ -595,9 +595,11 @@ class _InflightBlock(NamedTuple):
     block's dispatch sequence number — at process time,
     engine._dispatch_seq - seq is the OBSERVED lookahead (how many newer
     blocks were dispatched before this one's readback), the number the
-    loop-trace regression test pins. `gap_ms` (the host gap preceding
+    dispatch-order regression test pins. `gap_ms` (the host gap preceding
     this dispatch) and `live` (slot indices active at dispatch) carry
-    the device-time attribution inputs to process time (ISSUE 10)."""
+    the device-time attribution inputs to process time (ISSUE 10);
+    `steps` is the block's device steps per lane, which the lane-step
+    outcome counters need for a dead block that is never read."""
 
     kind: str
     data: object
@@ -605,6 +607,7 @@ class _InflightBlock(NamedTuple):
     seq: int = 0
     gap_ms: float = 0.0
     live: tuple = ()
+    steps: int = 0
 
 
 class EngineDeadError(RuntimeError):
@@ -1231,7 +1234,7 @@ class InferenceEngine:
         # Flight-deck timeline (ISSUE 10): the promoted pipeline ring —
         # typed, bounded, always-on events for both frontiers plus slot
         # lifecycle, exported as Perfetto JSON (/debug/timeline). The
-        # loop-trace regression test asserts dispatch-N+1-before-
+        # dispatch-order regression test asserts dispatch-N+1-before-
         # process-N on it. timeline_capacity=0 disables it entirely:
         # no ring allocated, every emission site one `is None` branch —
         # obs-off engines pay nothing (the memory-discipline contract
@@ -1291,12 +1294,6 @@ class InferenceEngine:
         self._stop = threading.Event()
         self.dead: Optional[str] = None
         self.last_progress = time.monotonic()
-
-        self._trace_acc = (
-            {"iters": 0}
-            if os.environ.get("POLYKEY_LOOP_TRACE", "") == "1"
-            else None
-        )
 
         self._thread = threading.Thread(
             target=self._run, name="polykey-engine", daemon=True
@@ -1650,23 +1647,27 @@ class InferenceEngine:
 
     # -- engine thread ------------------------------------------------------
 
+    def _phase(self, name: str, **attrs) -> phase:
+        """One engine phase (obs.timeline.phase): a `polykey/<name>`
+        annotation on the profiler's clock plus the always-on per-phase
+        accumulators of whichever EngineMetrics this engine holds now
+        (a supervised restart hands the old one to the new engine)."""
+        return phase(self.metrics, name, **attrs)
+
+    def _process_due(self, target: int, floor: int) -> bool:
+        """The processed frontier's rule: the oldest in-flight block is
+        due while more than `target` are queued, or more than `floor`
+        and its packed copy has already landed."""
+        queued = len(self._inflight_q)
+        return queued > target or (
+            queued > floor and self._block_ready(self._inflight_q[0])
+        )
+
     def _run(self) -> None:
-        # POLYKEY_LOOP_TRACE=1 (read once at CONSTRUCTION — engine
-        # __init__ sets _trace_acc, so a caller toggling the env after
-        # the constructor returns cannot race this thread): accumulate
-        # wall time per loop phase and print a summary to stderr every
-        # 100 iterations — the tool that found the r03 host-side
-        # serialization (PERF.md). Near-zero cost when off.
-        trace = self._trace_acc is not None
-        tacc: dict = self._trace_acc if trace else {"iters": 0}
-
-        def _t() -> float:
-            return time.monotonic() if trace else 0.0
-
-        def _acc(key: str, t0: float) -> None:
-            if trace:
-                tacc[key] = tacc.get(key, 0.0) + (time.monotonic() - t0)
-
+        # Loop phases (ISSUE 26): each stretch of this loop that does
+        # work runs inside `self._phase(...)`. A phase is entered only
+        # when there is something for it to do: the idle loop turns at
+        # 20 Hz and must not fill a capture with empty spans.
         # Heap-witness heartbeat (memlint ML006): bound once outside the
         # loop; heartbeat() self-throttles to ~1 Hz and is a no-op
         # unless POLYKEY_HEAP_WITNESS armed the witness at import.
@@ -1675,13 +1676,6 @@ class InferenceEngine:
         try:
             while not self._stop.is_set():
                 _heap_heartbeat()
-                if trace:
-                    tacc["iters"] += 1
-                    if tacc["iters"] % 100 == 0:
-                        import sys as _sys
-
-                        print(f"[loop-trace] {tacc}", file=_sys.stderr,
-                              flush=True)
                 if self.dead is not None:  # watchdog tripped while we were out
                     self._fail_all(self.dead)
                     return
@@ -1700,12 +1694,13 @@ class InferenceEngine:
                 # (History: the old `limit=1 if active` admission policy
                 # equilibrated occupancy at ~max_new/K lanes — measured
                 # 5/32 live lanes and 230 tok/s where full slots give
-                # ~2,000; r03 loop-trace, PERF.md.)
+                # ~2,000; r03, PERF.md.)
                 decode_live = bool(self._active.any())
                 budget = self._prefill_budget if decode_live else None
-                t0 = _t()
-                worked, spent = self._admit(budget=budget)
-                _acc("admit", t0)
+                worked, spent = False, 0
+                if not self._submit.empty():
+                    with self._phase("admit"):
+                        worked, spent = self._admit(budget=budget)
                 if self._host_kv is not None:
                     # Restore frontier (ISSUE 15): issue host→device
                     # page scatters for faulting slots BEFORE this
@@ -1713,10 +1708,8 @@ class InferenceEngine:
                     # ride ahead of need on the donation chain, budgeted
                     # like interleaved prefill so they cannot stall live
                     # decode beyond host_kv_restore_slots uploads.
-                    t0 = _t()
                     if self._issue_restores():
                         worked = True
-                    _acc("restore", t0)
                 if self._ragged:
                     # Ragged mode: admissions only REGISTER (token-range
                     # appends happen in _dispatch_step's batch builder,
@@ -1724,13 +1717,11 @@ class InferenceEngine:
                     # accounting) — no separate chunk dispatch exists.
                     chunked = 0
                 else:
-                    t0 = _t()
                     remaining = (
                         None if budget is None else max(0, budget - spent)
                     )
                     chunked = self._advance_chunked_prefills(remaining)
                     if chunked:
-                        _acc("chunk", t0)
                         worked = True
                     self.metrics.on_prefill_interleave(
                         spent + chunked, decode_live
@@ -1755,24 +1746,10 @@ class InferenceEngine:
                 if self._active.any() or (
                     self._ragged and self._has_pending_prefill()
                 ):
-                    t0 = _t()
-                    block = self._dispatch_step()
-                    _acc("dispatch", t0)
+                    with self._phase("dispatch"):
+                        block = self._dispatch_step()
                     if block is not None:
                         self._inflight_q.append(block)
-                        if trace:
-                            tacc["blocks"] = tacc.get("blocks", 0) + 1
-                            tacc["max_depth"] = max(
-                                tacc.get("max_depth", 0), self._depth_target
-                            )
-                            tacc["disp_steps"] = (
-                                tacc.get("disp_steps", 0)
-                                + self._last_dispatch_steps
-                            )
-                            tacc["disp_lanes"] = (
-                                tacc.get("disp_lanes", 0)
-                                + int(self._active.sum())
-                            )
                         dispatched = True
                         worked = True
                 if _schedwitness.installed() and self._active.any():
@@ -1783,9 +1760,9 @@ class InferenceEngine:
                     _schedwitness.note(
                         "decode", lanes if dispatched else [], lanes
                     )
-                t0 = _t()
-                self._resolve_prefills()
-                _acc("resolve", t0)
+                if self._has_unresolved():
+                    with self._phase("resolve"):
+                        self._resolve_prefills()
                 # Processed frontier: drain down to depth-1 queued blocks
                 # (depth counts the dispatch in hand, so depth 1 reads the
                 # block it just dispatched — synchronous — and depth 2
@@ -1798,19 +1775,20 @@ class InferenceEngine:
                 # it now would re-serialize dispatch-then-read, and the
                 # whole point of the pipeline is that block N's readback
                 # happens AFTER block N+1's dispatch (the happens-before
-                # the loop-trace test pins). Idle iterations (floor 0)
+                # the dispatch-order test pins). Idle iterations (floor 0)
                 # collapse the pipeline completely.
                 target = max(0, self._depth_target - 1) if dispatched else 0
                 floor = 1 if (dispatched and self._depth > 1) else 0
-                t0 = _t()
-                while self._inflight_q and (
-                    len(self._inflight_q) > target
-                    or (len(self._inflight_q) > floor
-                        and self._block_ready(self._inflight_q[0]))
-                ):
-                    self._process_step(self._inflight_q.popleft())
+                while self._process_due(target, floor):
+                    head = self._inflight_q[0]
+                    with self._phase(
+                        "process", seq=head.seq,
+                        lookahead=self._dispatch_seq - head.seq,
+                    ):
+                        # Popped only here: between the pop and the
+                        # block's on_process_block nothing else runs.
+                        self._process_step(self._inflight_q.popleft())
                     worked = True
-                _acc("process", t0)
                 # SLO signal plane (ISSUE 11): ring sample at block
                 # boundaries — idle iterations reach here too at ~20 Hz
                 # (the low-rate fallback timer). Time-gated inside to
@@ -1827,8 +1805,11 @@ class InferenceEngine:
                     # inter-dispatch gap as device-busy, which only
                     # holds while dispatches tile the device schedule).
                     self.metrics.on_dispatch_idle()
-                    self._resolve_prefills(block=True)
-                    self._wake.wait(timeout=0.05)
+                    if self._has_unresolved():
+                        with self._phase("resolve"):
+                            self._resolve_prefills(block=True)
+                    with self._phase("idle_wait"):
+                        self._wake.wait(timeout=0.05)
                     self._wake.clear()
                     # Idle time is not a stall: only the engine thread itself
                     # may refresh the stall clock (a submit() reset would let
@@ -1872,7 +1853,6 @@ class InferenceEngine:
         (_advance_chunked_prefills). Returns (admitted_any, spent)."""
         admitted = False
         spent = 0
-        trace = getattr(self, "_trace_acc", None)
         groups: dict[int, list] = {}    # bucket → [(slot_idx, slot, ids)]
         try:
             while budget is None or spent < budget:
@@ -1880,14 +1860,12 @@ class InferenceEngine:
                     i for i, s in enumerate(self._slots) if s is None
                 ]
                 if not free_slots:
-                    if trace is not None:
-                        trace["adm_noslot"] = trace.get("adm_noslot", 0) + 1
+                    if not self._submit.empty():
+                        self.metrics.on_admit_deferred("no_slot")
                     return admitted, spent
                 try:
                     request = self._submit.get_nowait()
                 except queue.Empty:
-                    if trace is not None:
-                        trace["adm_empty"] = trace.get("adm_empty", 0) + 1
                     return admitted, spent
                 if request.cancelled.is_set():
                     continue
@@ -1904,8 +1882,6 @@ class InferenceEngine:
                             free_slots[0], self._trace_id_of(request),
                             request.timings.prompt_tokens,
                         )
-                    if trace is not None:
-                        trace["adm_ok"] = trace.get("adm_ok", 0) + 1
                     if prep is not None:
                         bucket = prep[0]
                         # Budget charge = the bucket width (known only
@@ -1921,14 +1897,17 @@ class InferenceEngine:
                 except AllocationError:
                     # Pool exhausted: put it back and let running requests
                     # finish. FIFO fairness over throughput.
-                    if trace is not None:
-                        trace["adm_alloc"] = trace.get("adm_alloc", 0) + 1
+                    self.metrics.on_admit_deferred("no_pages")
                     self._requeue_front(request)
                     return admitted, spent
                 except Exception as e:
                     request.out.put(("error", f"admission failed: {e}"))
                     self.metrics.on_finish(request.timings, failed=True,
                                            trace_id=self._trace_id_of(request))
+            if not self._submit.empty():
+                # This iteration's prefill budget is spent: whoever still
+                # waits does so behind the next decode block.
+                self.metrics.on_admit_deferred("budget")
             return admitted, spent
         finally:
             for bucket, group in groups.items():
@@ -2195,10 +2174,15 @@ class InferenceEngine:
             put(starts), put(last_rel), put(tables), put(seeds),
             put(temp), put(top_p), put(top_k),
         )
+        real = sum(len(ids) for _, _, ids, _ in group)
         try:
             if self._faults is not None:
                 self._faults.maybe_raise("prefill-error", replica=self.replica_id, tier=self._tier)
-            with jax.profiler.TraceAnnotation("polykey/prefill"):
+            with self._phase("prefill", bucket=bucket, rows=n_pad * bucket,
+                             tokens=real):
+                issued = time.monotonic()
+                for _, slot, _, _ in group:
+                    slot.request.timings.prefill_dispatched = issued
                 if self._spec:
                     # Spec burst admissions batch exactly like plain ones
                     # (spec_prefill_fn is N-row); both pools prefill in
@@ -2230,9 +2214,7 @@ class InferenceEngine:
             return
         # Padding-waste accounting: the group computed n_pad × bucket
         # token rows for Σ len(ids) real prompt tokens.
-        self.metrics.on_padding_tokens(
-            n_pad * bucket, sum(len(ids) for _, _, ids, _ in group)
-        )
+        self.metrics.on_prefill_rows(n_pad * bucket, real)
         for r, (slot_idx, slot, _, _) in enumerate(group):
             if self.timeline is not None:
                 self.timeline.prefill(slot_idx, bucket, True)
@@ -2380,7 +2362,8 @@ class InferenceEngine:
         )
         self._depth_target = self._depth
         self._last_dispatch_steps = 1
-        gap_ms = self.metrics.on_dispatch(lanes, 1, slots=B)
+        gap_ms = self.metrics.on_dispatch(lanes, 1, slots=B,
+                                          depth=self._depth_target)
         # Padding-waste accounting: the device computes W prefill rows
         # of which `useful` carry real prompt tokens (decode rows are
         # charged by on_dispatch's slots/lanes split).
@@ -2394,7 +2377,9 @@ class InferenceEngine:
                     "prefill-error", replica=self.replica_id,
                     tier=self._tier,
                 )
-            with jax.profiler.TraceAnnotation("polykey/ragged"):
+            with self._phase("ragged", seq=self._dispatch_seq + 1,
+                             lanes=lanes, steps=1, tokens=useful):
+                self._stamp_final_ranges(ranges)
                 (packed_dev, last_dev, seq_dev, act_dev, first_dev,
                  self.paged) = self._jit_ragged(
                     self.params, self.model_cfg, self.paged,
@@ -2451,8 +2436,16 @@ class InferenceEngine:
                 s.filled += take
         return _InflightBlock(
             "plain", packed_dev, self._snapshot_requests(),
-            self._dispatch_seq, gap_ms, live,
+            self._dispatch_seq, gap_ms, live, 1,
         )
+
+    def _stamp_final_ranges(self, ranges: list) -> None:
+        """Ragged dispatch: the requests whose prompt this dispatch
+        completes get their `prefill_dispatched` stamp."""
+        issued = time.monotonic()
+        for _i, s, take in ranges:
+            if s.filled + take >= len(s.pending):
+                s.request.timings.prefill_dispatched = issued
 
     def _dispatch_ragged_spec(self, ranges: list):
         """ONE flat mixed dispatch serving prefill chunks AND spec verify
@@ -2500,7 +2493,8 @@ class InferenceEngine:
         self._last_dispatch_steps = 1
         # A spec round's scan length is gamma draft steps + one verify —
         # the step weight that makes its lane-seconds comparable.
-        gap_ms = self.metrics.on_dispatch(lanes, gamma + 1, slots=B)
+        gap_ms = self.metrics.on_dispatch(lanes, gamma + 1, slots=B,
+                                          depth=self._depth_target)
         # Padding-waste accounting covers the PREFILL region only: the
         # B·(gamma+1) verify rows are charged by on_dispatch's
         # steps-weighted lane accounting, same as a bucketed spec round.
@@ -2514,7 +2508,9 @@ class InferenceEngine:
                     "prefill-error", replica=self.replica_id,
                     tier=self._tier,
                 )
-            with jax.profiler.TraceAnnotation("polykey/ragged_spec"):
+            with self._phase("ragged_spec", seq=self._dispatch_seq + 1,
+                             lanes=lanes, steps=gamma + 1, tokens=useful):
+                self._stamp_final_ranges(ranges)
                 (packed_dev, last_dev, seq_dev, act_dev, ewma_dev,
                  dial_dev, first_dev, self.paged,
                  self.d_paged) = self._jit_ragged_spec(
@@ -2589,7 +2585,7 @@ class InferenceEngine:
                 s.filled += take
         return _InflightBlock(
             "spec", packed_dev, self._snapshot_requests(),
-            self._dispatch_seq, gap_ms, live,
+            self._dispatch_seq, gap_ms, live, gamma + 1,
         )
 
     def _compile_warmup(self) -> None:
@@ -2932,7 +2928,12 @@ class InferenceEngine:
         )
         if self._faults is not None:
             self._faults.maybe_raise("prefill-error", replica=self.replica_id, tier=self._tier)
-        with jax.profiler.TraceAnnotation("polykey/prefill"):
+        width = int(tokens.shape[1])
+        with self._phase("prefill", bucket=width, rows=width,
+                         tokens=last_rel + 1):
+            # The last chunk's stamp is the one that stays: the dispatch
+            # that completes the prompt.
+            request.timings.prefill_dispatched = time.monotonic()
             if self._spec:
                 first_token, self.paged, self.d_paged = self._jit_spec_prefill(
                     self.params, self.draft_params,
@@ -3040,6 +3041,12 @@ class InferenceEngine:
         self._lane_ewma[slot_idx] = 1.0
         self._lane_gamma[slot_idx] = max(self._gamma_max, 1)
 
+    def _has_unresolved(self) -> bool:
+        """A prefilled slot still waits for its first token's delivery."""
+        return any(
+            s is not None and s.token_dev is not None for s in self._slots
+        )
+
     def _resolve_prefills(self, block: bool = False) -> None:
         """Deliver first tokens whose async D2H copies have landed (all of
         them when `block=True`). Activation already happened at merge time;
@@ -3080,25 +3087,37 @@ class InferenceEngine:
             self._export_handoff(slot_idx, slot, token)
             return
         self._last_tokens[slot_idx] = token
-        request.timings.first_token = time.monotonic()
-        slot.last_emit = request.timings.first_token
+        self._note_first_token(slot_idx, slot, prompt_tokens=slot.prompt_len)
+        request.out.put(("token", token))
+        self._maybe_finish(slot_idx, token)
+
+    def _note_first_token(self, slot_idx: int, slot: _Slot,
+                          **prefill_attrs) -> None:
+        """A request's first token is in hand (bucketed, batched, chunked
+        and ragged prefill all funnel through _resolve_slot; a handoff
+        resume comes from _admit_resume): stamp it, file the three TTFT
+        phases, and give a traced request its `prefill_wait` (admitted,
+        tokenized, held on the host), `prefill` (dispatched: device queue,
+        the prefill, readback, this resolve) and open `decode` spans."""
+        request = slot.request
+        timings = request.timings
+        timings.first_token = time.monotonic()
+        slot.last_emit = timings.first_token
+        self.metrics.on_first_token(timings)
         if self.timeline is not None:
             self.timeline.slot_start(slot_idx, self._trace_id_of(request))
         if request.trace is not None:
-            # Prefill phase: admission tokenize through first-token
-            # delivery (covers bucketed, batched, and chunked prefill —
-            # all funnel through this resolve).
+            dispatched = timings.prefill_dispatched or timings.prefill_start
             request.trace.child(
-                "prefill",
-                start=request.timings.prefill_start,
-                end=request.timings.first_token,
-                prompt_tokens=slot.prompt_len,
+                "prefill_wait", start=timings.prefill_start, end=dispatched,
+            )
+            request.trace.child(
+                "prefill", start=dispatched, end=timings.first_token,
+                **prefill_attrs,
             )
             slot.decode_span = request.trace.child(
-                "decode", start=request.timings.first_token
+                "decode", start=timings.first_token
             )
-        request.out.put(("token", token))
-        self._maybe_finish(slot_idx, token)
 
     def _export_handoff(self, slot_idx: int, slot: _Slot,
                         token: int) -> None:
@@ -3211,6 +3230,7 @@ class InferenceEngine:
             # _host_crossing: the padded page payload rides up as one
             # deliberate upload (the handoff's whole point).
             with _host_crossing("handoff-restore"):
+                request.timings.prefill_dispatched = time.monotonic()
                 self.paged = self._jit_kv_restore(self.paged, *operands)
         except Exception as e:
             self.allocator.release_all(pages)
@@ -3258,20 +3278,8 @@ class InferenceEngine:
                 "handoff_restore", slot=slot_idx, pages=n_kv,
                 seq_len=seq_len,
             )
-        request.timings.first_token = time.monotonic()
-        slot.last_emit = request.timings.first_token
-        if self.timeline is not None:
-            self.timeline.slot_start(slot_idx, self._trace_id_of(request))
-        if request.trace is not None:
-            request.trace.child(
-                "prefill",
-                start=request.timings.prefill_start,
-                end=request.timings.first_token,
-                prompt_tokens=prompt_len, handoff=True,
-            )
-            slot.decode_span = request.trace.child(
-                "decode", start=request.timings.first_token
-            )
+        self._note_first_token(slot_idx, slot, prompt_tokens=prompt_len,
+                               handoff=True)
         request.out.put(("token", token))
         self._maybe_finish(slot_idx, token)
         return None
@@ -3366,7 +3374,8 @@ class InferenceEngine:
             if slot.request.cancelled.is_set():
                 self._finish(i, error="cancelled")
                 continue
-            self._restore_slot_pages(i, slot)
+            with self._phase("restore"):
+                self._restore_slot_pages(i, slot)
             issued += 1
             served.append(i)
         self._restore_rr.advance(B)
@@ -3540,7 +3549,8 @@ class InferenceEngine:
                 self._chunk_rr.reanchor(i)
                 self._note_sched_frontier("prefill", served)
                 return spent
-            charged = self._prefill_one_chunk(i)
+            with self._phase("chunk"):
+                charged = self._prefill_one_chunk(i)
             if charged:
                 served.append(i)
             spent += charged
@@ -3585,7 +3595,7 @@ class InferenceEngine:
         if self.timeline is not None:
             self.timeline.prefill(slot_idx, take, final)
         # The chunk window is C tokens wide; `take` carried real ones.
-        self.metrics.on_padding_tokens(C, take)
+        self.metrics.on_prefill_rows(C, take)
         if final:
             # The final chunk's sampled token activates the lane (on-device
             # merge; the host delivers it to the client once its async copy
@@ -3701,10 +3711,11 @@ class InferenceEngine:
             # lane-seconds comparable to a plain K-step block's.
             lanes = int(act.sum())
             gap_ms = self.metrics.on_dispatch(
-                lanes, self._gamma + 1, slots=len(self._slots)
+                lanes, self._gamma + 1, slots=len(self._slots),
+                depth=self._depth_target,
             )
             live = tuple(int(i) for i in np.flatnonzero(act))
-            data = self._dispatch_spec(dev, spec_candidates)
+            data = self._dispatch_spec(dev, spec_candidates, lanes)
             self._dispatch_seq += 1
             if self.timeline is not None:
                 self.timeline.dispatch(
@@ -3713,7 +3724,7 @@ class InferenceEngine:
                 )
             return _InflightBlock(
                 "spec", data, self._snapshot_requests(), self._dispatch_seq,
-                gap_ms, live,
+                gap_ms, live, self._gamma + 1,
             )
         # Static variant: an all-greedy batch (the benchmark mode) skips
         # sample_dynamic's [B, vocab] sort and all RNG work. At most two
@@ -3746,9 +3757,12 @@ class InferenceEngine:
             blocks_needed,
         )
         lanes = int(act.sum())
-        gap_ms = self.metrics.on_dispatch(lanes, steps, slots=len(self._slots))
+        gap_ms = self.metrics.on_dispatch(
+            lanes, steps, slots=len(self._slots), depth=self._depth_target
+        )
         live = tuple(int(i) for i in np.flatnonzero(act))
-        with jax.profiler.TraceAnnotation("polykey/decode"):
+        with self._phase("decode", seq=self._dispatch_seq + 1, lanes=lanes,
+                         steps=steps):
             (packed_dev, last_dev, seq_dev, act_dev,
              self.paged) = self._jit_decode(
                 self.params,
@@ -3791,7 +3805,7 @@ class InferenceEngine:
             )
         return _InflightBlock(
             "plain", packed_dev, self._snapshot_requests(), self._dispatch_seq,
-            gap_ms, live,
+            gap_ms, live, steps,
         )
 
     def _eff_top_k(self, request: GenRequest) -> int:
@@ -3881,19 +3895,28 @@ class InferenceEngine:
         seq = block[3] if len(block) > 3 else self._dispatch_seq
         gap_ms = block[4] if len(block) > 4 else 0.0
         live = block[5] if len(block) > 5 else ()
+        # Lane-step outcomes are counted for blocks that say how many
+        # steps they ran (every _InflightBlock the engine builds; a bare
+        # legacy tuple's 0 steps count as nothing).
+        steps = block[6] if len(block) > 6 else 0
+        slots = len(self._slots)
         # Observed lookahead: blocks dispatched after this one, before its
         # readback — ≥1 is the overlap the pipeline exists for; 0 is the
         # synchronous depth-1 shape. Recorded for every processed block
-        # (the loop-trace test and engine_stats read it).
+        # (the dispatch-order test and engine_stats read it).
         lookahead = self._dispatch_seq - seq
         queued_after = len(self._inflight_q)
         if kind == "spec":
             # Spec rounds always sync: their device-computed acceptance
             # stats feed the gamma-tuning dial even when every occupant is
             # gone by processing time.
-            self._process_spec(data, reqs, lookahead, seq=seq,
-                               gap_ms=gap_ms, live=live,
-                               queued_after=queued_after)
+            emitted = self._process_spec(data, reqs, lookahead, seq=seq,
+                                         gap_ms=gap_ms, live=live,
+                                         queued_after=queued_after)
+            # gamma+1 lane-steps a lane (the weight on_dispatch gave the
+            # round): rejected draft positions delivered nothing and land
+            # in overshoot with the steps past a stream's end.
+            self.metrics.on_lane_steps(emitted, len(live), slots, steps)
             return
         if not any(
             s is not None and s.request is reqs[i]
@@ -3903,15 +3926,17 @@ class InferenceEngine:
             # drained / all cancelled). Nothing to emit — skip the sync
             # entirely so the drain costs no host↔device roundtrip (no
             # stall is recorded: nothing was read; no device time is
-            # attributed: every lane's request already finished).
+            # attributed: every lane's request already finished). Its
+            # live lanes' steps all delivered nothing: overshoot.
             self.metrics.on_process_block(lookahead, None)
+            self.metrics.on_lane_steps(0, len(live), slots, steps)
             if self.timeline is not None:
                 now = time.monotonic()
                 self.timeline.process(seq, now, now, None, lookahead,
                                       queued_after, 0.0)
             return
         t_sync = time.monotonic()
-        with _host_crossing("block-packed"):
+        with self._phase("readback_wait"), _host_crossing("block-packed"):
             # polylint: disable=PL001(block resolve point; one packed D2H read per block), PL008(process-side read; reachable from dispatch only via the ragged merge's dev-dirty cold path, behind a full pipeline drain)
             packed = np.asarray(data)     # [K, B]; blocks until block done
         # Host stall: how long the processed frontier blocked waiting for
@@ -3965,6 +3990,8 @@ class InferenceEngine:
                     break
             self._note_block_done(slot, before)
         self.metrics.on_step(emitted)
+        # Lane-step outcomes, known only now.
+        self.metrics.on_lane_steps(emitted, len(live), slots, steps)
         if self.timeline is not None:
             self.timeline.process(seq, t_sync, time.monotonic(), stall_ms,
                                   lookahead, queued_after, busy_ms)
@@ -4008,14 +4035,16 @@ class InferenceEngine:
                 request.timings.device_ms += share
         return busy
 
-    def _dispatch_spec(self, dev: dict, candidates: int = 0):
+    def _dispatch_spec(self, dev: dict, candidates: int = 0,
+                       lanes: int = 0):
         """Dispatch one draft/verify round (spec_decode.py). `candidates`
         is 0 when every active row has top_p >= 1 — the round then skips
         all truncation work (plain softmax dists). The round is fully
         device-resident (ISSUE 19): acceptance stats and the per-lane
         gamma dial ride the packed matrix's stat columns, so the block
         boundary costs ONE D2H read, same as a plain block."""
-        with jax.profiler.TraceAnnotation("polykey/spec_decode"):
+        with self._phase("spec_decode", seq=self._dispatch_seq + 1,
+                         lanes=lanes, steps=self._gamma + 1):
             (packed_dev, new_last, new_seq, new_active, new_ewma,
              new_gamma, self.paged, self.d_paged) = self._jit_spec_decode(
                 self.params, self.draft_params,
@@ -4061,16 +4090,16 @@ class InferenceEngine:
 
     def _process_spec(self, data, reqs, lookahead: int = 0, seq: int = 0,
                       gap_ms: float = 0.0, live: tuple = (),
-                      queued_after: int = 0) -> None:
+                      queued_after: int = 0) -> int:
         """Sync a spec round; emits each row's packed prefix (-1 padded —
-        device-truncated). Acceptance stats AND the per-lane gamma dial
+        device-truncated) and returns how many tokens it emitted. Acceptance stats AND the per-lane gamma dial
         come FROM the device inside the same packed matrix (ISSUE 19:
         spec_decode._accept_merge owns truncation, the untruncated n_acc,
         and the dial update) — ONE D2H read per round, exactly like a
         plain block's packed readback."""
         packed_dev = data
         t_sync = time.monotonic()
-        with _host_crossing("spec-packed"):
+        with self._phase("readback_wait"), _host_crossing("spec-packed"):
             # polylint: disable=PL001(spec-round resolve point; the ONE packed D2H read carries tokens, counts, and the gamma dial), PL008(process-side read; dispatch reaches it only via the merge drain cold path)
             packed = np.asarray(packed_dev)  # [B, gamma+1+SPEC_STAT_COLS]
         stall_ms = (time.monotonic() - t_sync) * 1e3
@@ -4162,6 +4191,7 @@ class InferenceEngine:
                 else self._gamma_low
             )
             self._gamma = min(rung, self._gamma_cap)
+        return emitted
 
     def _maybe_finish(self, slot_idx: int, token: int) -> None:
         slot = self._slots[slot_idx]

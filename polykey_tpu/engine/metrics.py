@@ -21,6 +21,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from ..obs.histogram import Histogram
+from ..obs.timeline import PHASES
 
 # Bucket bounds for the live-lanes-per-block histogram: lane counts are
 # small integers bounded by max_decode_slots, so a fixed power-of-two-ish
@@ -37,6 +38,11 @@ LANE_BUCKETS = (1, 2, 4, 8, 12, 16, 24, 32, 48, 64, 96, 128, 192, 256,
 class RequestTimings:
     enqueued: float = field(default_factory=time.monotonic)
     prefill_start: float = 0.0
+    # Host time at which the prefill dispatch that completes the prompt
+    # was issued (the group dispatch of a bucketed prompt, the last chunk
+    # of a chunked one): splits the time after admission into "held on
+    # the host" and "device queue + prefill + readback" (ISSUE 26).
+    prefill_dispatched: float = 0.0
     first_token: float = 0.0
     finished: float = 0.0
     prompt_tokens: int = 0
@@ -53,6 +59,16 @@ class RequestTimings:
         if self.first_token and self.enqueued:
             return (self.first_token - self.enqueued) * 1e3
         return 0.0
+
+    def ttft_phases_s(self) -> tuple[float, float, float]:
+        """(queue, prefill_wait, first_token) seconds: three phases that
+        partition enqueued -> first_token exactly. A path that stamped
+        no admission or no dispatch gives that phase's time to the next
+        one, so the sum stays `ttft_ms`."""
+        admitted = self.prefill_start or self.enqueued
+        dispatched = self.prefill_dispatched or admitted
+        return (admitted - self.enqueued, dispatched - admitted,
+                self.first_token - dispatched)
 
     @property
     def tokens_per_sec(self) -> float:
@@ -95,9 +111,8 @@ class EngineMetrics:
         self._window_tokens = 0
         self.tokens_per_sec = 0.0
         # Occupancy tracker (ISSUE 4): always-on per-dispatch live-lane
-        # accounting, replacing the opt-in POLYKEY_LOOP_TRACE counters as
-        # the source of truth for avg_lanes. One locked add per dispatched
-        # block (the engine loop runs a handful of dispatches per second
+        # accounting, the source of truth for avg_lanes. One locked add
+        # per dispatched block (the loop runs a handful a second
         # at steady state — negligible next to the device call it rides):
         #   blocks_dispatched — decode blocks / spec rounds dispatched
         #   lanes_dispatched  — Σ live lanes at dispatch (block-weighted)
@@ -132,6 +147,47 @@ class EngineMetrics:
         # ratio — the number the ragged path exists to raise.
         self.tokens_dispatched_total = 0
         self.tokens_useful_total = 0
+        # Engine phases, request phases and lane-step outcomes
+        # (ISSUE 26), all written by the ENGINE THREAD ONLY. The phase
+        # accumulators and deferral counts take plain adds with no lock
+        # (several a loop iteration); the per-request and per-block
+        # counters take the one lock the per-block counters above
+        # already take. snapshot() copies.
+        #
+        # phase_seconds / phase_count: time.monotonic() seconds and
+        # entries per engine phase (obs.timeline.PHASES; one `with
+        # phase(...)` site each). Loop phases never nest in one another,
+        # so their sum is the engine thread's time.
+        self.phase_seconds = dict.fromkeys(PHASES, 0.0)
+        self.phase_count = dict.fromkeys(PHASES, 0)
+        # Requests whose first token resolved: seconds in the three
+        # phases of RequestTimings.ttft_phases_s (they partition
+        # Usage.ttft_ms), and how many requests.
+        self.ttft_phase_seconds = {
+            "queue": 0.0, "prefill_wait": 0.0, "first_token": 0.0,
+        }
+        self.ttft_phase_count = 0
+        # Each time _admit leaves a waiting request where it is, by
+        # reason: no free slot, no pages (AllocationError, requeued), or
+        # the interleaved-prefill budget of this iteration spent.
+        self.admit_deferred = {"no_slot": 0, "no_pages": 0, "budget": 0}
+        # Decode lane-steps by OUTCOME, counted when a block is
+        # processed (on_lane_steps): delivered + overshoot + dead =
+        # sum of slots x steps over processed blocks. Plain blocks,
+        # ragged blocks (steps = 1) and spec rounds (steps = gamma + 1;
+        # a rejected draft position is a lane-step that delivered
+        # nothing and lands in overshoot) all keep the identity.
+        self.decode_lane_steps_delivered = 0
+        self.decode_lane_steps_overshoot = 0
+        self.decode_lane_steps_dead = 0
+        # Prefill rows computed vs real prompt tokens, bucketed groups
+        # and chunks only (on_prefill_rows). The ragged dispatches mix
+        # decode and prefill rows in one stream and stay on the
+        # tokens_dispatched / tokens_useful pair alone.
+        self.prefill_rows_dispatched = 0
+        self.prefill_rows_useful = 0
+        # Deepest in-flight target any dispatch ran with (on_dispatch).
+        self.depth_target_max = 0
         # Lookahead pipeline accounting (ISSUE 6): per processed block,
         # the OBSERVED lookahead (blocks dispatched after it, before its
         # readback — ≥1 means the dispatch frontier ran ahead of the
@@ -247,18 +303,68 @@ class EngineMetrics:
             self.tokens_dispatched_total += dispatched
             self.tokens_useful_total += useful
 
+    def on_prefill_rows(self, dispatched: int, useful: int) -> None:
+        """One bucketed-group or chunk prefill dispatch: `dispatched`
+        rows computed (n_pad x bucket, or the chunk width) for `useful`
+        real prompt tokens. Feeds the prefill-only pair and, as before,
+        the mixed padding-waste pair."""
+        with self._lock:
+            self.prefill_rows_dispatched += dispatched
+            self.prefill_rows_useful += useful
+            self.tokens_dispatched_total += dispatched
+            self.tokens_useful_total += useful
+
+    def on_phase(self, name: str, seconds: float) -> None:
+        """One engine phase ended (obs.timeline.phase). A name outside
+        obs.timeline.PHASES is a KeyError: the table is the contract."""
+        self.phase_seconds[name] += seconds
+        self.phase_count[name] += 1
+
+    def on_first_token(self, timings: RequestTimings) -> None:
+        """A request's first token resolved: file its three TTFT phases."""
+        queue, wait, first = timings.ttft_phases_s()
+        acc = self.ttft_phase_seconds
+        with self._lock:
+            acc["queue"] += queue
+            acc["prefill_wait"] += wait
+            acc["first_token"] += first
+            self.ttft_phase_count += 1
+
+    def on_admit_deferred(self, reason: str) -> None:
+        self.admit_deferred[reason] += 1
+
+    def on_lane_steps(self, delivered: int, live: int, slots: int,
+                      steps: int) -> None:
+        """One processed block of `slots` x `steps` lane-steps, `live`
+        lanes live at dispatch, `delivered` tokens put on request
+        queues from it. What the live lanes did not deliver is
+        overshoot: steps after a stream's end (the first -1 of its
+        column, a finish mid-block, a whole lookahead block of a stream
+        that had already ended, a dead block skipped unread) or of a
+        cancelled or expired lane. A block that does not say its steps
+        (0) is not counted at all."""
+        if steps <= 0:
+            return
+        with self._lock:
+            self.decode_lane_steps_delivered += delivered
+            self.decode_lane_steps_overshoot += live * steps - delivered
+            self.decode_lane_steps_dead += (slots - live) * steps
+
     def on_dispatch(self, lanes: int, steps: int,
-                    slots: int = 0) -> float:
+                    slots: int = 0, depth: int = 0) -> float:
         """One decode block (or spec round) dispatched with `lanes` live
         decode lanes for `steps` device steps. Returns the counted
         dispatch gap in ms (0.0 for the first dispatch or an idle-capped
         gap) — the attribution window the engine charges to the block.
         `slots` (the static batch width) feeds the padding-waste
         counters: the device computes slots×steps token rows of which
-        lanes×steps are useful."""
+        lanes×steps are useful. `depth` is the in-flight target the
+        dispatch ran with (its running maximum is kept)."""
         now = time.monotonic()
         counted_gap = 0.0
         with self._lock:
+            if depth > self.depth_target_max:
+                self.depth_target_max = depth
             if slots > 0:
                 self.tokens_dispatched_total += slots * steps
                 self.tokens_useful_total += lanes * steps
@@ -493,6 +599,25 @@ class EngineMetrics:
                           / self.tokens_dispatched_total, 4)
                     if self.tokens_dispatched_total else None
                 ),
+                # ISSUE 26: engine phases, request phases, admission
+                # deferrals, lane-step outcomes (see __init__).
+                "phase_seconds": {
+                    k: round(v, 6) for k, v in self.phase_seconds.items()
+                },
+                "phase_count": dict(self.phase_count),
+                "ttft_phase_seconds": {
+                    k: round(v, 6)
+                    for k, v in self.ttft_phase_seconds.items()
+                },
+                "ttft_phase_count": self.ttft_phase_count,
+                "admit_deferred": dict(self.admit_deferred),
+                "decode_lane_steps_delivered":
+                    self.decode_lane_steps_delivered,
+                "decode_lane_steps_overshoot":
+                    self.decode_lane_steps_overshoot,
+                "decode_lane_steps_dead": self.decode_lane_steps_dead,
+                "prefill_rows_dispatched": self.prefill_rows_dispatched,
+                "prefill_rows_useful": self.prefill_rows_useful,
                 "blocks_processed": self.blocks_processed,
                 "lookahead_observed_max": self.lookahead_max,
                 "lookahead_observed_mean": (

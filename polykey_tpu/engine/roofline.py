@@ -208,7 +208,7 @@ def grade(model: str, dtype: str, quantize: bool, quantize_bits: int,
     Always emits the per-token geometry (bytes_per_token, flops_per_token
     at the measured occupancy/context); emits mbu/mfu/prefill_mfu_at_ttft
     only when a chip roofline applies (None on CPU). avg_lanes is the
-    measured mean live decode lanes per dispatched block (loop trace);
+    measured mean live decode lanes per dispatched block (the occupancy tracker);
     pass None when unmeasured — the scorecard then assumes full occupancy
     of `assumed_lanes` and SAYS so (avg_lanes_source), rather than
     silently grading against an occupancy never observed. draft_model

@@ -153,6 +153,39 @@ _ENGINE_FAMILIES: tuple = (
     ("hist", "polykey_kv_restore_ms",
      "Per-fault restore latency, ms: host gather + upload + scatter "
      "dispatch for one faulting slot's pages.", "kv_restore_hist"),
+    # Engine phases, request phases and lane-step outcomes (ISSUE 26).
+    # "labeled" families render one sample per entry of the snapshot
+    # dict `key`, under the label named after the family's kind suffix.
+    ("labeled:phase", "polykey_engine_phase_seconds_total",
+     "Engine-thread seconds per loop phase (obs.timeline.PHASES; the "
+     "same spans a profiler capture holds as polykey/<phase>).",
+     "phase_seconds"),
+    ("labeled:phase", "polykey_engine_phase_entries_total",
+     "Times each engine phase ran.", "phase_count"),
+    ("labeled:phase", "polykey_ttft_phase_seconds_total",
+     "Seconds to first token by phase (queue, prefill_wait, "
+     "first_token: they partition TTFT), summed over the requests "
+     "polykey_ttft_phase_requests_total counts.", "ttft_phase_seconds"),
+    ("counter", "polykey_ttft_phase_requests_total",
+     "Requests whose first token resolved.", "ttft_phase_count"),
+    ("labeled:reason", "polykey_admit_deferred_total",
+     "Times admission left a waiting request in the queue, by reason "
+     "(no_slot, no_pages, budget).", "admit_deferred"),
+    ("counter", "polykey_decode_lane_steps_delivered_total",
+     "Decode lane-steps whose token reached a request (counted when "
+     "the block is processed).", "decode_lane_steps_delivered"),
+    ("counter", "polykey_decode_lane_steps_overshoot_total",
+     "Lane-steps of lanes live at dispatch that delivered nothing "
+     "(past a stream's end, cancelled, dead block).",
+     "decode_lane_steps_overshoot"),
+    ("counter", "polykey_decode_lane_steps_dead_total",
+     "Lane-steps of slots with no live lane at dispatch.",
+     "decode_lane_steps_dead"),
+    ("counter", "polykey_prefill_rows_dispatched_total",
+     "Prefill rows computed by bucketed groups and chunks (padding "
+     "included).", "prefill_rows_dispatched"),
+    ("counter", "polykey_prefill_rows_useful_total",
+     "Real prompt tokens among those rows.", "prefill_rows_useful"),
 )
 
 _SPEC_FAMILIES: tuple = (
@@ -161,6 +194,20 @@ _SPEC_FAMILIES: tuple = (
     ("polykey_spec_drafts_accepted_total",
      "Speculative draft tokens accepted.", "drafts_accepted"),
 )
+
+
+def _labeled_lines(kind: str, name: str, help_text: str, key: str,
+                   members: list) -> list[str]:
+    """A counter family with one sample per entry of the snapshot dict
+    `key`, labeled `<kind suffix>=<entry>`; `members` is
+    [(labels, snap)]. A snapshot without the dict (an older worker)
+    renders the header alone."""
+    label = kind.partition(":")[2]
+    lines = render_header(name, help_text, "counter")
+    for labels, snap in members:
+        for entry, value in (snap.get(key) or {}).items():
+            lines.append(render_sample(name, {**labels, label: entry}, value))
+    return lines
 
 
 # One label-set's samples of a histogram family (header emitted once by
@@ -341,6 +388,8 @@ def _disagg_lines(pool) -> list[str]:
                         name, {**labels, "kind": fault_kind},
                         snap.get(f"kv_page_faults_{fault_kind}", 0),
                     ))
+        elif kind.startswith("labeled:"):
+            lines += _labeled_lines(kind, name, help_text, key, members)
         elif kind == "hist":
             if name not in _DISAGG_HISTS:
                 continue    # bucket counts for these don't cross the wire
@@ -514,6 +563,11 @@ def engine_collector(engine_or_provider):
                     lines += _histogram_samples(
                         name, labels, getattr(engine.metrics, key)
                     )
+            elif kind.startswith("labeled:"):
+                lines += _labeled_lines(
+                    kind, name, help_text, key,
+                    [(labels, snap) for labels, _engine, snap in members],
+                )
             else:
                 lines += render_header(name, help_text, kind)
                 for labels, _engine, snap in members:

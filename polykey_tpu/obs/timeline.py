@@ -25,7 +25,7 @@ landed. This module is that missing picture:
   trace id. A replica pool exports one Perfetto "process" per replica.
 
 The schedule becomes evidence: the recorded event order is what the
-loop-trace regression test pins (dispatch N+1 happens-before process N),
+dispatch-order regression test pins (dispatch N+1 happens-before process N),
 and the committed `perf/timeline_*.json` artifacts let a reviewer SEE
 the ≥2-deep overlap instead of trusting a ratio.
 """
@@ -66,6 +66,73 @@ EVENT_FIELDS: dict[str, tuple[str, ...]] = {
     # profiler captures. `attrs` is a small dict.
     "note": ("note_kind", "attrs"),
 }
+
+# Engine phases (ISSUE 26): every name the `phase` primitive below may be
+# given, with its level and what it covers. "loop" phases are entered by
+# the engine loop itself and never inside one another, so their seconds
+# add up to the engine thread's time; "nested" ones run inside a loop
+# phase (a device dispatch call, the blocking readback) and are already
+# counted there. COMPONENTS.md §13 lists this table and the exporter
+# renders `polykey_engine_phase_seconds_total{phase=...}` from it, so a
+# new phase is one entry + one `with` site (EngineMetrics.on_phase
+# refuses a name that is not here).
+PHASE_PREFIX = "polykey/"
+PHASES: dict[str, tuple[str, str]] = {
+    "admit": ("loop", "dequeue, tokenize, allocate pages, dispatch the "
+              "bucketed prefill groups (only while a request waits)"),
+    "restore": ("loop", "one faulting slot's host->device page scatter"),
+    "chunk": ("loop", "one chunk of one long prompt's prefill"),
+    "dispatch": ("loop", "_dispatch_step: one decode block, spec round "
+                 "or ragged dispatch, slot-state upkeep included"),
+    "resolve": ("loop", "first tokens whose copies landed: read, stamp, "
+                "hand to the client (only while one is pending)"),
+    "process": ("loop", "one in-flight block: readback, emit, finish"),
+    "idle_wait": ("loop", "nothing to do: waiting on the wake event"),
+    "prefill": ("nested", "the prefill program's dispatch call (bucketed "
+                "group or chunk), inside admit / chunk"),
+    "decode": ("nested", "the decode block's dispatch call"),
+    "ragged": ("nested", "the ragged mixed dispatch call"),
+    "ragged_spec": ("nested", "the ragged spec dispatch call"),
+    "spec_decode": ("nested", "the spec round's dispatch call"),
+    "readback_wait": ("nested", "np.asarray on a block's packed tokens: "
+                      "blocks until the device finished the block"),
+}
+LOOP_PHASES = tuple(n for n, (level, _) in PHASES.items() if level == "loop")
+
+_TraceAnnotation = None
+
+
+class phase:
+    """The engine's one span primitive: `with phase(metrics, "admit"):`.
+
+    Enters a ``jax.profiler.TraceAnnotation("polykey/<name>", **attrs)``
+    — so a capture started by `engine_profile` holds the span on the
+    device planes' clock; with no capture running the annotation is
+    inert and `attrs` are never formatted — and on exit adds the elapsed
+    ``time.monotonic()`` and 1 to the always-on per-phase accumulators
+    (`EngineMetrics.on_phase`). Engine thread only."""
+
+    __slots__ = ("_metrics", "_name", "_annotation", "_t0")
+
+    def __init__(self, metrics, name: str, **attrs):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            # Lazy: obs/ stays importable without JAX (the gateway's
+            # mock backend, the benchmark's load generator).
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._metrics = metrics
+        self._name = name
+        self._annotation = _TraceAnnotation(PHASE_PREFIX + name, **attrs)
+
+    def __enter__(self) -> "phase":
+        self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        elapsed = time.monotonic() - self._t0
+        self._annotation.__exit__(*exc)
+        self._metrics.on_phase(self._name, elapsed)
 
 
 class TimelineRecorder:

@@ -849,7 +849,8 @@ def main() -> int:
             failures.append("engine_stats has no last_trace")
         else:
             names = {c["name"] for c in dict(stats["last_trace"])["children"]}
-            for phase in ("queue_wait", "prefill", "decode", "detokenize"):
+            for phase in ("queue_wait", "prefill_wait", "prefill", "decode",
+                          "detokenize"):
                 if phase not in names:
                     failures.append(f"last_trace missing {phase} span")
 

@@ -544,7 +544,8 @@ def test_traced_request_span_tree(engine):
         trace = dict(stats["last_trace"])
         assert trace["attrs"]["tool"] == "llm_generate"
         children = {c["name"]: dict(c) for c in trace["children"]}
-        for phase in ("queue_wait", "prefill", "decode", "detokenize"):
+        for phase in ("queue_wait", "prefill_wait", "prefill", "decode",
+                      "detokenize"):
             assert phase in children, f"missing {phase} span"
         # decode carries per-block children with token counts.
         blocks = children["decode"].get("children", [])
@@ -556,7 +557,8 @@ def test_traced_request_span_tree(engine):
         # for RPC framing + scheduler jitter on busy CI hosts).
         phase_ms = sum(
             children[p]["duration_ms"]
-            for p in ("queue_wait", "prefill", "decode", "detokenize")
+            for p in ("queue_wait", "prefill_wait", "prefill", "decode",
+                      "detokenize")
         )
         assert phase_ms <= trace["duration_ms"] * 1.05
         assert phase_ms >= trace["duration_ms"] * 0.5
@@ -698,25 +700,16 @@ def test_adaptive_block_solo_vs_loaded():
     static_cfg = dataclasses.replace(cfg, adaptive_block=False)
 
     def run_solo(config):
-        import os as _os
-
-        prior = _os.environ.get("POLYKEY_LOOP_TRACE")
-        _os.environ["POLYKEY_LOOP_TRACE"] = "1"
-        try:
-            eng = InferenceEngine(config)
-        finally:
-            if prior is None:
-                _os.environ.pop("POLYKEY_LOOP_TRACE", None)
-            else:
-                _os.environ["POLYKEY_LOOP_TRACE"] = prior
+        eng = InferenceEngine(config)
         try:
             r = GenRequest(prompt="adaptive probe", max_new_tokens=12)
             eng.submit(r)
             tokens, done, error = _collect(r)
             assert error is None and done is not None
-            acc = eng._trace_acc or {}
+            # The always-on accumulators: the deepest in-flight target
+            # any dispatch ran with.
             return (tokens, eng._last_dispatch_steps, eng._depth_target,
-                    acc.get("max_depth", 0))
+                    eng.metrics.depth_target_max)
         finally:
             eng.shutdown()
 
@@ -912,16 +905,7 @@ def test_admission_keeps_slots_occupied():
         decode_block_steps=8,
         lookahead_blocks=2,
     )
-    import os as _os
-
-    # The engine latches the trace flag at CONSTRUCTION (engine.__init__
-    # sets _trace_acc), so popping right after the constructor returns
-    # cannot race the engine thread.
-    _os.environ["POLYKEY_LOOP_TRACE"] = "1"
-    try:
-        engine = InferenceEngine(cfg)
-    finally:
-        _os.environ.pop("POLYKEY_LOOP_TRACE", None)
+    engine = InferenceEngine(cfg)
     try:
         sem = threading.Semaphore(cfg.max_decode_slots * 2)
         done = threading.Semaphore(0)
@@ -943,10 +927,10 @@ def test_admission_keeps_slots_occupied():
         for _ in range(n_req):
             assert done.acquire(timeout=120.0)
 
-        acc = engine._trace_acc or {}
-        blocks = acc.get("blocks", 0)
+        # The always-on occupancy accumulators (block-weighted).
+        blocks = engine.metrics.blocks_dispatched
         assert blocks > 0
-        avg_lanes = acc.get("disp_lanes", 0) / blocks
+        avg_lanes = engine.metrics.lanes_dispatched / blocks
         # Ramp/tail blocks drag the average below the slot count; 60% is
         # comfortably above the broken policy's ~max_new/K = 8... which
         # equals the slot count here, so ALSO bound total blocks: the
