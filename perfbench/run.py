@@ -197,6 +197,43 @@ def request_faults(record: dict) -> list:
     return faults
 
 
+def check_text(name: str, value: int, limit: int) -> str:
+    return f"{name} {value} (limit {limit})"
+
+
+def decide_correct(stats_ready: dict, stats_close: dict, stats_end: dict,
+                   requests: list, reference: dict | None) -> dict:
+    """Whether the run's outputs are correct; `checks` holds each number
+    compared beside its limit, `why` the checks that failed.
+
+    `requests_failed` includes cancellations, and the engine counts one
+    when it next processes a block. So failures inside the window are read
+    at its close (`stats_close`), while every client still streams; from
+    then to `stats_end` the count may rise by no more than the streams the
+    harness itself cancelled. A restart at any time is a fault.
+    """
+    sent = [r for r in requests if r["send"] is not None]
+    faults = [(r["client"], r["index"], request_faults(r)) for r in sent]
+    faults = [f for f in faults if f[2]]
+    cancelled = sum(r["error"] == "cancelled" for r in sent)
+    failed = [int(s.get("requests_failed", 0))
+              for s in (stats_ready, stats_close, stats_end)]
+    checks = {
+        "request_faults": (len(faults), 0),
+        "engine_restarts": (int(stats_end.get("engine_restarts", 0)), 0),
+        "failed_in_window": (failed[1] - failed[0], 0),
+        "failed_after_close": (failed[2] - failed[1], cancelled),
+    }
+    why = [check_text(name, *pair) for name, pair in checks.items()
+           if not 0 <= pair[0] <= pair[1]]
+    if reference is None:
+        why.append("no verdict of the plain reference")
+    elif not reference["ok"]:
+        why.append("the served sample disagrees with the plain reference")
+    return {"correct": not why, "why": why, "checks": checks,
+            "attempted": len(sent), "faults": faults}
+
+
 class Context:
     """What a metric reader may read (perfbench/metrics/<name>.py)."""
 
@@ -376,6 +413,9 @@ def run(args) -> dict:
             reference = json.load(f)
 
     requests = [sample] + loop.records
+    verdict = decide_correct(stats_ready, stats_close, stats_end, requests,
+                             reference)
+    checks = verdict["checks"]
     samples = {
         "meta": {
             "workload": args.workload, "seed": args.seed,
@@ -385,20 +425,21 @@ def run(args) -> dict:
             "device_kind": stats_ready["device_kind"], "devices": used,
             "generator_late_ms_max": 1000.0 * max(loop.late_s, default=0.0),
             "traced": traced,
+            "failed_in_window": checks["failed_in_window"][0],
+            "failed_after_close": checks["failed_after_close"][0],
+            "cancelled_by_harness": checks["failed_after_close"][1],
+            "why_incorrect": verdict["why"],
         },
         "requests": requests,
     }
     with gzip.open(os.path.join(out_dir, f"{tag}.samples.json.gz"), "wt") as f:
         json.dump(samples, f)
-
-    sent = [r for r in requests if r["send"] is not None]
-    faults = [(r["client"], r["index"], request_faults(r)) for r in sent]
-    faults = [f for f in faults if f[2]]
-    restarts = int(stats_end.get("engine_restarts", 0))
-    engine_failed = (int(stats_end.get("requests_failed", 0))
-                     - int(stats_ready.get("requests_failed", 0)))
-    correct = (not faults and restarts == 0 and engine_failed == 0
-               and reference is not None and reference["ok"])
+    print("perfbench: " + ", ".join(
+        check_text(name, *pair) for name, pair in checks.items()),
+        file=sys.stderr)
+    for reason in verdict["why"]:
+        print(f"perfbench: not correct: {reason}", file=sys.stderr)
+    faults = verdict["faults"]
 
     trace = None
     if args.trace and platform == "tpu":
@@ -425,11 +466,11 @@ def run(args) -> dict:
     device = {"platform": platform, "kind": stats_ready["device_kind"],
               "count": used, "memory_peak_bytes": max(memory, default=0)}
     result = {
-        "correct": bool(correct), "attempted": len(sent), "failed": len(faults),
-        "metrics": metrics, "device": device,
+        "correct": verdict["correct"], "attempted": verdict["attempted"],
+        "failed": len(faults), "metrics": metrics, "device": device,
         "generator_late_ms_max": samples["meta"]["generator_late_ms_max"],
         "reference": reference, "faults": faults[:5],
-        "engine_restarts": restarts,
+        "engine_restarts": checks["engine_restarts"][0],
     }
     if trace is not None:
         device["busy_s"] = trace["busy_s"]

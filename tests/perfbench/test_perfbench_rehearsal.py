@@ -2,14 +2,20 @@
 same processes, sockets, traffic generator, readers and last-line contract
 as on the chip; platform=cpu, and never a device metric."""
 
+import argparse
+import gzip
 import json
 import os
 import subprocess
 import sys
+import time
 
 import pytest
 
 from perfbench_paths import ROOT
+
+import loadgen
+import run
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
@@ -66,3 +72,31 @@ def test_traced_rehearsal_reports_counts_but_no_device_metric():
     assert "busy_s" not in line["device"] and "breakdown" not in line
     assert line["metrics"]["compiles_in_window"]["value"] == 0
     assert 0 < line["metrics"]["avg_lanes"]["value"] <= 16
+
+
+def test_cancellations_counted_after_the_close_leave_the_run_correct(monkeypatch):
+    """The race of ISSUE 27 made certain: the end reading is taken twenty
+    25 ms block periods after the harness cancelled its streams, so the
+    engine HAS counted them as failed, and the run is still correct."""
+    stop = loadgen.ClosedLoop.stop
+
+    def stop_then_let_the_engine_count(self):
+        stop(self)
+        time.sleep(0.5)
+
+    monkeypatch.setattr(loadgen.ClosedLoop, "stop",
+                        stop_then_let_the_engine_count)
+    cell, seed = "mistral-7b.decode-saturated", 2147483693
+    result = run.run(argparse.Namespace(
+        workload=cell, seed=seed, seconds=3.0, trace=0, tiny=True))
+    assert result["correct"] is True and result["failed"] == 0
+    assert set(result) == {
+        "correct", "attempted", "failed", "metrics", "device",
+        "generator_late_ms_max", "reference", "faults", "engine_restarts"}
+    path = os.path.join(ROOT, "perfbench", "out", cell,
+                        f"seed{seed}.trace0.samples.json.gz")
+    with gzip.open(path, "rt") as f:
+        meta = json.load(f)["meta"]
+    assert meta["failed_in_window"] == 0 and meta["why_incorrect"] == []
+    # What the clause before this PR would have read as a failed request.
+    assert 0 < meta["failed_after_close"] <= meta["cancelled_by_harness"]
