@@ -16,20 +16,47 @@ drops nothing either (perfbench/run.py SAMPLE_*).
 
 The comparison is on logits, not on sampled tokens (random weights: the
 largest logit changes on rounding): the reference is teacher-forced with
-the served tokens, and at every generated position the served token's
-reference logit must be within `max_margin` of the reference's best logit
-over the ids the narrowed head allows, and at least `min_exact_share` of
-the tokens must be the reference's exact argmax. Both limits are the
-configuration file's ("reference" group), with the measurements behind
-them in PERF.md section 4. Logits here have a standard deviation of about
-1 and the best candidates lie ~0.3 apart, so a path that dropped a layer,
-a head or the rotary embedding picks among 95 ids at random: margins of
-2-3 and an exact share near 1%. The dense model is held to 0.25 (measured
-0.0, all tokens exact): half a mantissa less would fail it. The MoE model
-cannot be held that tightly: with random weights a router's top-2 choice
-flips on bf16 rounding in some of the 32 x 56 (layer, token) decisions,
-and each flip swaps an expert's whole output (measured margins up to 0.42,
-9-13 of 16 exact).
+the served tokens, and every generated position yields one margin, the
+reference's best logit over the ids the narrowed head allows minus the
+served token's reference logit (0 where the served token is the
+reference's argmax). `judge` decides from those margins alone, by four
+clauses whose limits are the configuration file's ("reference" group; the
+readings behind them are in PERF.md section 4):
+
+- at most `max_outliers` tokens (absent: 0) have a margin over `max_margin`;
+- the mean margin is at most `max_mean_margin` (absent: no limit);
+- at least `min_exact_share` of the tokens are the reference's exact argmax;
+- every served id lies inside the narrowed head.
+
+Logits here have a standard deviation of about 1 and the best candidates
+lie ~0.3 apart. The dense model is held to 0.25 with no outlier allowed
+(measured: 28-32 of 32 exact, largest margin 0.051): half a mantissa less
+would fail it. The MoE model cannot be held by its LARGEST margin: with
+random weights a router's top-2 choice flips on bf16 rounding near a tie in
+some of the 32 x 56 (layer, token) decisions, each flip swaps an expert's
+whole output, and the one token it lands on can read a margin of the order
+of the logits' spread (measured over 60+ seeds: 17-32 of 32 exact, mean
+margin up to 0.125, largest single margin 0.95). That is sound behaviour of
+bf16 serving against a float32 reference, and it touches a token or two. So
+Mixtral's file allows two outliers over 1.0 and holds the mean, which one
+flipped token moves by 1/32 of its margin and an error on every token moves
+whole.
+
+What the 32 tokens can see was measured with controls laid over this
+reference at the cell's own size, on six seeds
+(tests/perfbench/reference_controls.py; readings in PERF.md section 4).
+Refused on every seed, by this rule as by the one before: another seed's
+tree (17-32 of 32 margins over 1.0, mean 1.6-3.4, at most 3 exact),
+attention switched off or one KV head zeroed in every layer (13-32
+outliers, mean 0.95-3.6), one expert per token instead of two (mean
+0.33-1.4, at most 12 exact), int4 weights (mean 0.27-0.74). NOT seen on
+most seeds, by either rule: a fault in ONE of the 32 layers (the layer
+skipped, one KV head zeroed there), the rotary embedding off, every
+expert's output scaled by 1.02. Random weights attend almost evenly, one
+layer is a thirty-second of the residual stream, and the served path's own
+bf16 and routing noise is as large: their readings lie inside the sound
+range, and a limit on the largest margin refused them only on the seeds
+where one token happened to pass 1.0, as it refused sound runs.
 """
 
 from __future__ import annotations
@@ -37,7 +64,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-
 
 
 def f32(w):
@@ -115,28 +141,57 @@ def forward(params, cfg, tokens) -> np.ndarray:
     return np.asarray(logits)
 
 
+def judge(margins: list, outside_head: int, limits: dict) -> dict:
+    """The verdict on one served sample from its per-token margins and the
+    count of served ids outside the narrowed head; see the module text.
+    `limits` is the configuration file's "reference" group. `why` names
+    each clause that failed, in words, and is empty when `ok`."""
+    threshold = limits["max_margin"]
+    max_outliers = limits.get("max_outliers", 0)
+    max_mean = limits.get("max_mean_margin")
+    min_exact = limits["min_exact_share"] * len(margins)
+    outliers = sum(m > threshold for m in margins)
+    exact = sum(m <= 0.0 for m in margins)
+    mean = float(np.mean(margins))
+    # Each number compared beside its limit; a clause that failed -> `why`.
+    clauses = [
+        (outliers <= max_outliers,
+         f"outliers {outliers} (limit {max_outliers}, margins over "
+         f"{threshold:g}; largest {max(margins):.4g})"),
+        (max_mean is None or mean <= max_mean,
+         f"mean_margin {mean:.4g} (limit {max_mean})"),
+        (exact >= min_exact,
+         f"exact {exact} (at least {min_exact:g} of {len(margins)})"),
+        (outside_head == 0, f"outside_head {outside_head} (limit 0)"),
+    ]
+    why = [text for held, text in clauses if not held]
+    return {
+        "ok": not why,
+        "why": why,
+        "checks": ", ".join(text for _, text in clauses),
+        "tokens": len(margins),
+        "exact": exact,
+        "outliers": outliers,
+        "max_outliers": max_outliers,
+        "max_margin": max(margins),
+        "tolerance": threshold,
+        "mean_margin": mean,
+        "max_mean_margin": max_mean,
+        "outside_head": outside_head,
+        "margins": margins,
+    }
+
+
 def compare(params, cfg, sample: dict, limits: dict) -> dict:
-    """Teacher-force the reference with the served tokens; see module doc.
-    `limits` is the configuration file's "reference" group."""
-    tolerance, min_exact = limits["max_margin"], limits["min_exact_share"]
+    """Teacher-force the reference with the served tokens and judge the
+    margins; see module doc."""
     prompt, served = sample["prompt_ids"], sample["output_ids"]
     allowed = np.zeros(cfg.vocab_size, bool)
     allowed[sample["allowed_first"]:sample["allowed_last"] + 1] = True
     logits = forward(params, cfg, prompt + served[:-1])
     rows = logits[len(prompt) - 1:]
-    margins, exact = [], 0
-    for row, token in zip(rows, served):
-        best = float(np.max(np.where(allowed, row, -np.inf)))
-        margins.append(best - float(row[token]))
-        exact += int(margins[-1] <= 0.0)
-    return {
-        "ok": bool(max(margins) <= tolerance
-                   and exact >= min_exact * len(served)
-                   and all(allowed[t] for t in served)),
-        "tokens": len(served),
-        "exact": exact,
-        "max_margin": max(margins),
-        "mean_margin": float(np.mean(margins)),
-        "tolerance": tolerance,
-        "logit_std": float(np.std(rows[:, allowed])),
-    }
+    margins = [float(np.max(np.where(allowed, row, -np.inf))) - float(row[token])
+               for row, token in zip(rows, served)]
+    outside_head = sum(not allowed[t] for t in served)
+    return {**judge(margins, outside_head, limits),
+            "logit_std": float(np.std(rows[:, allowed]))}

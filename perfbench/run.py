@@ -229,7 +229,9 @@ def decide_correct(stats_ready: dict, stats_close: dict, stats_end: dict,
     if reference is None:
         why.append("no verdict of the plain reference")
     elif not reference["ok"]:
-        why.append("the served sample disagrees with the plain reference")
+        why.append("the served sample disagrees with the plain reference: "
+                   + "; ".join(reference.get("why") or
+                               [reference.get("error", "no clause named")]))
     return {"correct": not why, "why": why, "checks": checks,
             "attempted": len(sent), "faults": faults}
 
@@ -429,6 +431,7 @@ def run(args) -> dict:
             "failed_after_close": checks["failed_after_close"][0],
             "cancelled_by_harness": checks["failed_after_close"][1],
             "why_incorrect": verdict["why"],
+            "reference_margins": (reference or {}).get("margins"),
         },
         "requests": requests,
     }
@@ -437,6 +440,8 @@ def run(args) -> dict:
     print("perfbench: " + ", ".join(
         check_text(name, *pair) for name, pair in checks.items()),
         file=sys.stderr)
+    if reference is not None and "checks" in reference:
+        print("perfbench: reference: " + reference["checks"], file=sys.stderr)
     for reason in verdict["why"]:
         print(f"perfbench: not correct: {reason}", file=sys.stderr)
     faults = verdict["faults"]

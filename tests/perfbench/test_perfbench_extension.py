@@ -105,9 +105,14 @@ def test_refuses_without_the_system_under_test(tmp_path):
     assert proc.stdout.strip() == ""
 
 
-def test_refuses_an_unknown_cell_and_a_cpu_pin_without_tiny():
+def test_refuses_an_unknown_cell_and_a_cpu_pin_without_tiny(tmp_path):
+    # In a copy: a run clears its cell's out/ directory of the last sample
+    # and verdict, which a rehearsal on another test worker may be using.
+    root = copy_benchmark(tmp_path)
+    os.symlink(os.path.join(ROOT, "polykey_tpu"),
+               os.path.join(root, "polykey_tpu"))
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    base = [sys.executable, os.path.join(ROOT, "perfbench", "run.py"),
+    base = [sys.executable, os.path.join(root, "perfbench", "run.py"),
             "--seed", "1", "--seconds", "1", "--trace", "0"]
     unknown = subprocess.run(base + ["--workload", "no.such-cell"],
                              capture_output=True, text=True, env=env)

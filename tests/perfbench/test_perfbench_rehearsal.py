@@ -16,6 +16,7 @@ from perfbench_paths import ROOT
 
 import loadgen
 import run
+from test_perfbench_extension import copy_benchmark
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
@@ -50,10 +51,15 @@ def test_cell_rehearses_on_the_cpu(cell):
               if "workloads" not in m or cell in m["workloads"]}
     assert set(line["metrics"]) == wanted
     assert all(m["value"] > 0 for m in line["metrics"].values())
-    assert line["reference"]["ok"] and line["reference"]["tokens"] == 32
+    reference = line["reference"]
+    assert reference["ok"] and reference["tokens"] == 32
+    assert reference["why"] == [] and len(reference["margins"]) == 32
+    # Each number the reference clause compares, beside its limit (stderr).
+    assert "perfbench: reference: outliers 0 (limit " in proc.stderr
     samples = os.path.join(ROOT, "perfbench", "out", cell,
                            "seed2147483659.trace0.samples.json.gz")
-    assert os.path.exists(samples)
+    with gzip.open(samples, "rt") as f:
+        assert json.load(f)["meta"]["reference_margins"] == reference["margins"]
 
 
 def entry_clients(entry: dict) -> int:
@@ -100,3 +106,35 @@ def test_cancellations_counted_after_the_close_leave_the_run_correct(monkeypatch
     assert meta["failed_in_window"] == 0 and meta["why_incorrect"] == []
     # What the clause before this PR would have read as a failed request.
     assert 0 < meta["failed_after_close"] <= meta["cancelled_by_harness"]
+
+
+def test_a_control_laid_over_the_reference_names_its_clause(tmp_path):
+    """A run made incorrect on purpose: in a copy of the benchmark the
+    plain reference loses its rotary embedding (a disagreement is
+    symmetric, and no file of the program may change). The whole run is
+    driven; `correct` comes out false, and the clauses that failed are in
+    the result line's `reference.why`, on stderr and in the samples' meta."""
+    root = copy_benchmark(tmp_path)
+    os.symlink(os.path.join(ROOT, "polykey_tpu"),
+               os.path.join(root, "polykey_tpu"))
+    with open(os.path.join(root, "perfbench", "reference.py"), "a") as f:
+        f.write("\n\ndef rotary(x, positions, theta):\n    return x\n")
+    cell, seed = "mixtral-8x7b-tp4.chat-closed", 2147483777
+    proc = run_cell(root, cell, trace=0, seed=seed)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False and line["failed"] == 0
+    reference = line["reference"]
+    assert not reference["ok"] and len(reference["margins"]) == 32
+    assert reference["why"], reference
+    assert any("mean_margin" in clause for clause in reference["why"])
+    last = proc.stderr.strip().splitlines()[-1]
+    assert last.startswith("perfbench: not correct: the served sample "
+                           "disagrees with the plain reference: ")
+    assert all(clause in last for clause in reference["why"])
+    path = os.path.join(root, "perfbench", "out", cell,
+                        f"seed{seed}.trace0.samples.json.gz")
+    with gzip.open(path, "rt") as f:
+        meta = json.load(f)["meta"]
+    assert meta["reference_margins"] == reference["margins"]
+    assert meta["why_incorrect"] == [last.split("not correct: ", 1)[1]]
