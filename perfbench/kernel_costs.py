@@ -6,11 +6,29 @@ every function returns what ONE chip must do when the model is split `tp`
 ways over heads and feed-forward columns (tp = 1: the whole model).
 Padding, recomputation and relayouts do not count: these are the least
 bytes and operations the mathematics asks for.
+
+The formulas below reckon a GQA decoder with dense or Mixtral-style
+SwiGLU blocks. A configuration of another architecture names a module of
+its own, perfbench/costs/<file>.py (contract: docstring of
+perfbench/run.py); a reader that works for every configuration goes
+through `for_spec`.
 """
 
 from __future__ import annotations
 
+import sys
+
+import extension
+
 DTYPE_BYTES = {"bfloat16": 2, "float32": 4, "int8": 1}
+
+
+def for_spec(spec: dict):
+    """The module that reckons this configuration's bytes and operations:
+    the file its "costs" key names, or this one."""
+    if "costs" not in spec:
+        return sys.modules[__name__]
+    return extension.load("costs", spec["costs"])
 
 
 def _dims(spec: dict) -> dict:

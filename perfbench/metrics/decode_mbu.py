@@ -9,5 +9,5 @@ def read(ctx):
     live = counters.live_tokens(ctx)
     if not step_ms or live is None:
         return None
-    needed = kernel_costs.decode_step_bytes(ctx.spec, live)
+    needed = kernel_costs.for_spec(ctx.spec).decode_step_bytes(ctx.spec, live)
     return 100.0 * needed / (step_ms / 1000.0) / ctx.peaks["hbm_bytes_per_s"]

@@ -1,5 +1,9 @@
 """The plain reference of both configurations, and the comparison with it.
 
+(A configuration of another architecture brings its own `forward` as
+perfbench/references/<file>.py, named in its "reference" group; `compare`
+and `judge` below decide for it too. Contract: docstring of perfbench/run.py.)
+
 The forward pass of a Llama-style decoder (RMSNorm, rotary embedding in the
 rotate-half convention, grouped-query causal attention, SwiGLU) and of its
 Mixtral variant (top-k routed experts, gates softmaxed over the k chosen)
@@ -182,13 +186,14 @@ def judge(margins: list, outside_head: int, limits: dict) -> dict:
     }
 
 
-def compare(params, cfg, sample: dict, limits: dict) -> dict:
+def compare(params, cfg, sample: dict, limits: dict, forward_fn=None) -> dict:
     """Teacher-force the reference with the served tokens and judge the
-    margins; see module doc."""
+    margins; see module doc. `forward_fn`: the `forward` of the
+    configuration's own reference module (absent: the one above)."""
     prompt, served = sample["prompt_ids"], sample["output_ids"]
     allowed = np.zeros(cfg.vocab_size, bool)
     allowed[sample["allowed_first"]:sample["allowed_last"] + 1] = True
-    logits = forward(params, cfg, prompt + served[:-1])
+    logits = (forward_fn or forward)(params, cfg, prompt + served[:-1])
     rows = logits[len(prompt) - 1:]
     margins = [float(np.max(np.where(allowed, row, -np.inf))) - float(row[token])
                for row, token in zip(rows, served)]

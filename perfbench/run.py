@@ -15,6 +15,60 @@ Set-up (process start -> window open) = engine construction and seeded
 weights, loading or compiling every executable the cell's shapes use, one
 sample request for the comparison with the plain reference, and the
 clients' ramp. The window opens once every client's first request streams.
+
+WHAT A CONFIGURATION MAY BRING (the extension contract). A model whose
+block is not the GQA decoder the harness was written around brings code
+of its own as NEW files, each named by an optional key of its
+configs/<name>.json and loaded by that name (perfbench/extension.py),
+never by an `if` on a model. A file that names none of them is run
+exactly as before these keys existed.
+
+  "adapter": "<file>.py" -> perfbench/adapters/<file>.py, loaded by
+    server_child.py. It may define any of these; one it leaves out is the
+    harness's own function of that purpose:
+      model_config(spec, tiny) -> the package's ModelConfig
+          (absent: server_child.model_config_from, HF Mistral/Mixtral keys)
+      engine_config(spec, tiny) -> the package's EngineConfig
+          (absent: server_child.engine_config_from, the "engine" group)
+      weights(spec, tiny, engine_config, model_cfg, seed) -> the parameter
+          tree, or None for the engine's own seeded init (absent:
+          server_child.made_weights; weights.hashed_int8(..., ones=<names>)
+          fills the leaves it is told are norm gains with ones)
+      release(engine) -> None: drop what the engine holds on the device
+          besides its parameters, before the float32 reference runs
+          (absent: server_child.release_pools, the two paged pools)
+    `spec` is the configuration's JSON, `tiny` the CPU rehearsal; the
+    sizes of the rehearsal are the adapter's to read from spec["tiny"].
+  "reference": {"module": "<file>.py", <limits>} ->
+    perfbench/references/<file>.py, the configuration's plain reference:
+      forward(params, model_cfg, tokens) -> float32 logits [T, vocab]
+    in plain jax.numpy, float32, precision "highest", importing nothing of
+    the package, reading the served tree (reference.f32 reads an int8
+    leaf). The teacher forcing, the margins and `judge` of
+    perfbench/reference.py decide `correct` from it under the group's
+    limits, as for every configuration. A module that defines
+      compare(params, model_cfg, sample, limits) -> reference.judge's dict
+    replaces the teacher forcing too. model_cfg.vocab_size of a sliced
+    vocabulary is the slice. Absent: reference.forward. The verdict names
+    the module that gave it ("module" in the result's `reference`).
+  "costs": "<file>.py" -> perfbench/costs/<file>.py, the least bytes and
+    operations of this architecture, from shapes, for one chip:
+      decode_step_bytes(spec, live_tokens) -> bytes one decode step must
+          read (read by metrics/decode_mbu.py in every cell)
+      <kernel>(spec, ...) -> {"bytes": .., "flops": ..} of one call, one
+          function for each name in "kernels", called by that kernel's
+          reader metrics/<kernel>_roofline.py with the shapes it reads;
+          kernel_costs.roofline_seconds turns it into the least time.
+    It runs in this process, which never imports JAX: plain Python.
+    Absent: perfbench/kernel_costs.py itself (kernel_costs.for_spec).
+  "kernels": ["<name>", ...] -> device operations whose name starts with
+    <name> are summed under trace["kernels"][<name>] by trace_reduce.py,
+    after the four kernels it knows (first match wins, those four first,
+    so no configuration takes time from another's reader).
+
+A metric with no "workloads" list in BENCHMARK.json is read in every cell
+that reports the metric it moves, a new configuration's too: give a
+reader of one kernel or one layer type the list of the cells that have it.
 """
 
 from __future__ import annotations
@@ -25,7 +79,6 @@ T_START = time.monotonic()
 
 import argparse  # noqa: E402
 import gzip  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -39,6 +92,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
+
+import extension  # noqa: E402
 
 START_TIMEOUT_S = 1100.0     # a first run compiles every executable
 RAMP_TIMEOUT_S = 120.0
@@ -244,12 +299,10 @@ class Context:
 
 
 def read_metric(name: str, ctx: Context):
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"perfbench_metric_{name}", path)
-    if spec is None or not os.path.exists(path):
-        raise BenchFailure(f"no reader perfbench/metrics/{name}.py")
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
+    try:
+        module = extension.load("metrics", f"{name}.py")
+    except (FileNotFoundError, ValueError):
+        raise BenchFailure(f"no reader perfbench/metrics/{name}.py") from None
     return module.read(ctx)
 
 
@@ -258,13 +311,14 @@ def metrics_for(manifest: dict, cell: str, group: str) -> list:
             if "workloads" not in m or cell in m["workloads"]]
 
 
-def reduce_trace(trace_dir: str, out_path: str) -> dict | None:
+def reduce_trace(trace_dir: str, out_path: str, kernels: list) -> dict | None:
     """The reduction runs in a process of its own, on the CPU, after the
-    server has released the chip."""
+    server has released the chip. `kernels`: the configuration's own
+    kernel names, summed beside the four trace_reduce.py knows."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(HERE, "trace_reduce.py"),
-         trace_dir, out_path],
+         trace_dir, out_path, *kernels],
         env=env, capture_output=True, text=True, timeout=300,
     )
     if proc.returncode != 0:
@@ -430,6 +484,11 @@ def run(args) -> dict:
             "failed_in_window": checks["failed_in_window"][0],
             "failed_after_close": checks["failed_after_close"][0],
             "cancelled_by_harness": checks["failed_after_close"][1],
+            # Every run's own count (the metric of that name is a traced
+            # run's): a stall in a cold run's window is or is not a compile.
+            "compiles_in_window": read_metric(
+                "compiles_in_window",
+                Context(stats_open=stats_open, stats_close=stats_close)),
             "why_incorrect": verdict["why"],
             "reference_margins": (reference or {}).get("margins"),
         },
@@ -448,7 +507,9 @@ def run(args) -> dict:
 
     trace = None
     if args.trace and platform == "tpu":
-        trace = reduce_trace(trace_dir, os.path.join(out_dir, f"{tag}.trace.json"))
+        trace = reduce_trace(trace_dir,
+                             os.path.join(out_dir, f"{tag}.trace.json"),
+                             spec.get("kernels", []))
         if trace is None or not trace.get("busy_s", 0) > 0:
             raise BenchFailure("the traced window holds no device operation")
     ctx = Context(

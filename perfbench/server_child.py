@@ -24,6 +24,10 @@ reference (perfbench/reference.py) on the same weights and writes
 `<out>/reference.json`.
 
 Only this process touches JAX: the parent never imports it.
+
+A configuration whose model these functions do not fit names an adapter
+of its own and a reference module of its own: `hook` and `run_reference`
+below, under the contract in the docstring of perfbench/run.py.
 """
 
 from __future__ import annotations
@@ -39,6 +43,16 @@ import threading
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+import extension  # noqa: E402
+
+
+def hook(spec: dict, name: str, default):
+    """The function `name` of the configuration's adapter
+    (perfbench/adapters/<spec["adapter"]>), or the harness's own."""
+    if "adapter" not in spec:
+        return default
+    return getattr(extension.load("adapters", spec["adapter"]), name, default)
 
 
 def engine_settings(spec: dict, tiny: bool) -> dict:
@@ -148,22 +162,41 @@ def narrow_head(engine) -> None:
     engine.params = {**engine.params, "lm_head": narrowed}
 
 
-def run_reference(engine, out_dir: str, limits: dict) -> None:
-    sample_path = os.path.join(out_dir, "sample.json")
-    if not os.path.exists(sample_path):
-        return
-    import reference
-
-    with open(sample_path) as f:
-        sample = json.load(f)
-    # The engine has stopped: its KV pools make room for the reference's
-    # float32 temporaries.
+def release_pools(engine) -> None:
+    """The engine has stopped: its KV pools make room for the reference's
+    float32 temporaries."""
     for pool in ("paged", "d_paged"):
         if hasattr(engine, pool):
             setattr(engine, pool, None)
-    try:
+
+
+def compare_with_reference(engine, sample: dict, limits: dict) -> dict:
+    """The verdict of the plain reference: perfbench/reference.py, or the
+    module the "reference" group names (its `compare`, or its `forward`
+    under the shared teacher forcing and `judge`)."""
+    import reference
+
+    if "module" not in limits:
+        return reference.compare(engine.params, engine.model_cfg, sample,
+                                 limits)
+    module = extension.load("references", limits["module"])
+    if hasattr(module, "compare"):
+        result = module.compare(engine.params, engine.model_cfg, sample, limits)
+    else:
         result = reference.compare(engine.params, engine.model_cfg, sample,
-                                   limits)
+                                   limits, forward_fn=module.forward)
+    return {**result, "module": limits["module"]}
+
+
+def run_reference(engine, out_dir: str, spec: dict) -> None:
+    sample_path = os.path.join(out_dir, "sample.json")
+    if not os.path.exists(sample_path):
+        return
+    with open(sample_path) as f:
+        sample = json.load(f)
+    try:
+        hook(spec, "release", release_pools)(engine)
+        result = compare_with_reference(engine, sample, spec["reference"])
     except Exception as e:      # reported as an incorrect run, not a lost one
         result = {"ok": False, "error": f"{type(e).__name__}: {e}"[:500]}
     with open(os.path.join(out_dir, "reference.json"), "w") as f:
@@ -193,9 +226,10 @@ def main(argv=None) -> int:
     from polykey_tpu.obs import Observability
 
     logger = Logger(level="info")
-    model_cfg = model_config_from(spec, args.tiny)
-    MODEL_REGISTRY[model_cfg.name] = model_cfg
-    config = engine_config_from(spec, args.tiny)
+    model_cfg = hook(spec, "model_config", model_config_from)(spec, args.tiny)
+    config = hook(spec, "engine_config", engine_config_from)(spec, args.tiny)
+    # Over any preset of the package, under the name the engine looks up.
+    MODEL_REGISTRY[config.model] = model_cfg
 
     enable_persistent_compile_cache()
     identity = require_accelerator()
@@ -207,7 +241,9 @@ def main(argv=None) -> int:
     # JAX keys are 32-bit: fold a driver-sized seed into that range.
     seed = args.seed % (2**31 - 1)
     engine = InferenceEngine(
-        config, params=made_weights(spec, args.tiny, config, model_cfg, seed),
+        config,
+        params=hook(spec, "weights", made_weights)(
+            spec, args.tiny, config, model_cfg, seed),
         health=health, logger=logger, seed=seed,
     )
     narrow_head(engine)
@@ -230,7 +266,7 @@ def main(argv=None) -> int:
     server.stop(grace=2).wait()
     live = service.engine
     service.close()
-    run_reference(live, args.out, spec["reference"])
+    run_reference(live, args.out, spec)
     logger.info("server stopped")
     return 0
 

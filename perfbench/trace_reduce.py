@@ -1,6 +1,9 @@
 """From a jax.profiler capture to the numbers the per-layer readers use.
 
-    python3 perfbench/trace_reduce.py <trace dir> <out.json>
+    python3 perfbench/trace_reduce.py <trace dir> <out.json> [<kernel name> ...]
+
+(The further names are a configuration's own "kernels", summed beside the
+four of KERNELS; contract: docstring of perfbench/run.py.)
 
 Two stages, so that the second can be checked on a small recorded trace
 (tests/perfbench/data/): `extract` reads the `.xplane.pb` with nothing but
@@ -19,7 +22,9 @@ over the chips used):
   ops                 per "<program>/<operation>": total_s, count — leaf
                       operations only (an operation that contains others,
                       such as a while loop, is left out of the sums)
-  kernels             per Pallas kernel name: total_s, count
+  kernels             per kernel name (KERNELS, then the names given): an
+                      operation counts under the first name it starts
+                      with: total_s, count
   collective_s        time in collective operations
   collective_exposed_s  the part of it with no other operation running
   device_ops          the ten largest entries of `ops`
@@ -165,7 +170,7 @@ def _owner(modules: list, start: float) -> str:
     return "no_program"
 
 
-def reduce_plane(plane: dict) -> dict:
+def reduce_plane(plane: dict, kernels: tuple = KERNELS) -> dict:
     by_line = {line["name"]: line["events"] for line in plane["lines"]}
     ops = by_line.get(OPS_LINE, [])
     modules = sorted(by_line.get(MODULES_LINE, []), key=lambda e: e[1])
@@ -197,7 +202,7 @@ def reduce_plane(plane: dict) -> dict:
         entry = out["ops"].setdefault(key, {"total_s": 0.0, "count": 0})
         entry["total_s"] += dur / 1e9
         entry["count"] += 1
-        for kernel in KERNELS:
+        for kernel in kernels:
             if name.startswith(kernel):
                 k = out["kernels"].setdefault(
                     kernel, {"total_s": 0.0, "count": 0, "by_program": {}})
@@ -222,8 +227,10 @@ def name_gap(gap, annotations: list) -> str:
     return best
 
 
-def reduce(extracted: dict) -> dict:
-    planes = [p for p in map(reduce_plane, extracted["planes"]) if p]
+def reduce(extracted: dict, more_kernels=()) -> dict:
+    kernels = KERNELS + tuple(k for k in more_kernels if k not in KERNELS)
+    planes = [p for p in (reduce_plane(plane, kernels)
+                          for plane in extracted["planes"]) if p]
     if not planes:
         return {"busy_s": 0.0, "window_s": 0.0, "planes": 0}
     n = len(planes)
@@ -274,7 +281,7 @@ def main(argv) -> int:
     with gzip.open(re.sub(r"\.json$", "", out_path) + ".events.json.gz",
                    "wt") as f:
         json.dump(extracted, f)
-    reduced = reduce(extracted)
+    reduced = reduce(extracted, argv[3:])
     reduced["structure"] = extracted["structure"]
     with open(out_path, "w") as f:
         json.dump(reduced, f)

@@ -19,6 +19,9 @@ The values are not the package quantizer's (a normal draw rounded to its
 per-channel absmax); they are int8 weights of the same shapes, scales and
 spread. Speed does not depend on them, and the plain reference reads the
 same tree.
+
+A configuration's adapter (contract: docstring of perfbench/run.py) calls
+`hashed_int8(..., ones=NORMS + (<its own norm gains' leaf names>,))`.
 """
 
 from __future__ import annotations
@@ -60,9 +63,39 @@ def _uniform(shape, dtype, salt: int, std: float):
     return ((2.0 * unit - 1.0) * (3.0 ** 0.5) * std).astype(dtype)
 
 
-def fill_function(cfg, mesh, dtype, seed: int):
+def filled(shapes, seed: int, ones=NORMS):
+    """The tree of `shapes` (ShapeDtypeStructs, and QuantizedTensors of
+    them) filled from the seed: an int8 leaf as the module text says; a
+    leaf whose name is in `ones` (a norm's gain) with ones; any other
+    leaf uniform with the spread fan_in ** -0.5, where fan_in is the
+    last axis but one, or the only axis of a one-dimensional leaf (a
+    bias)."""
+    from polykey_tpu.models.quant import QuantizedTensor
+
+    is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=is_q)
+    out = []
+    for number, (path, leaf) in enumerate(leaves):
+        name = str(getattr(path[-1], "key", path[-1]))
+        salt = seed * 1000003 + number * 7919
+        if is_q(leaf):
+            q = leaf.q
+            fan_in = q.shape[-1] if name == "embed" else q.shape[-2]
+            scale = fan_in ** -0.5 / UNIFORM_INT8_STD
+            out.append(leaf.replace(
+                q=_int8(q.shape, salt),
+                s=jnp.full(leaf.s.shape, scale, leaf.s.dtype)))
+        elif name in ones:
+            out.append(jnp.ones(leaf.shape, leaf.dtype))
+        else:
+            fan_in = leaf.shape[-2] if leaf.ndim > 1 else leaf.shape[0]
+            out.append(_uniform(leaf.shape, leaf.dtype, salt, fan_in ** -0.5))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def fill_function(cfg, mesh, dtype, seed: int, ones=NORMS):
     """The jitted, argument-less function that makes the whole tree."""
-    from polykey_tpu.models.quant import QuantizedTensor, quantize_params
+    from polykey_tpu.models.quant import quantize_params
     from polykey_tpu.models.transformer import init_params
     from polykey_tpu.parallel.sharding import param_shardings
 
@@ -70,31 +103,12 @@ def fill_function(cfg, mesh, dtype, seed: int):
         lambda: quantize_params(
             init_params(jax.random.PRNGKey(0), cfg, dtype), cfg)
     )
-    shardings = param_shardings(cfg, mesh, shapes)
-    is_q = lambda x: isinstance(x, QuantizedTensor)  # noqa: E731
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=is_q)
 
     def fill():
-        out = []
-        for number, (path, leaf) in enumerate(leaves):
-            name = str(getattr(path[-1], "key", path[-1]))
-            salt = seed * 1000003 + number * 7919
-            if is_q(leaf):
-                q = leaf.q
-                fan_in = q.shape[-1] if name == "embed" else q.shape[-2]
-                scale = fan_in ** -0.5 / UNIFORM_INT8_STD
-                out.append(leaf.replace(
-                    q=_int8(q.shape, salt),
-                    s=jnp.full(leaf.s.shape, scale, leaf.s.dtype)))
-            elif name in NORMS:
-                out.append(jnp.ones(leaf.shape, leaf.dtype))
-            else:
-                out.append(_uniform(leaf.shape, leaf.dtype, salt,
-                                    leaf.shape[-2] ** -0.5))
-        return jax.tree_util.tree_unflatten(treedef, out)
+        return filled(shapes, seed, ones)
 
-    return jax.jit(fill, out_shardings=shardings)
+    return jax.jit(fill, out_shardings=param_shardings(cfg, mesh, shapes))
 
 
-def hashed_int8(cfg, mesh, dtype, seed: int) -> dict:
-    return fill_function(cfg, mesh, dtype, seed)()
+def hashed_int8(cfg, mesh, dtype, seed: int, ones=NORMS) -> dict:
+    return fill_function(cfg, mesh, dtype, seed, ones)()

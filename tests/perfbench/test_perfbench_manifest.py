@@ -6,7 +6,9 @@ import re
 
 import pytest
 
-from perfbench_paths import BENCH, ROOT
+from perfbench_paths import BENCH, DATA, ROOT
+
+import trace_reduce
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -59,6 +61,53 @@ def test_config_entry_and_file(config):
                 "trace_seconds", "reference"):
         assert key in spec
     assert any(w["config"] == config["name"] for w in MANIFEST["workloads"])
+
+
+def named_code_faults(spec: dict, bench: str) -> list:
+    """What is wrong with the code a configuration's file names (the
+    extension contract in the docstring of perfbench/run.py)."""
+    named = {"adapters": spec.get("adapter"), "costs": spec.get("costs"),
+             "references": spec["reference"].get("module")}
+    faults = [f"no file perfbench/{folder}/{file}"
+              for folder, file in named.items()
+              if file is not None
+              and not os.path.isfile(os.path.join(bench, folder, file))]
+    kernels = spec.get("kernels", [])
+    faults += [f"kernel name {k!r} is not a name" for k in kernels
+               if not NAME.match(k)]
+    taken = list(trace_reduce.KERNELS) + kernels
+    faults += [f"kernel name {k!r} appears twice" for k in set(taken)
+               if taken.count(k) > 1]
+    if kernels and not spec.get("costs"):
+        faults.append("kernels named, but no costs module to reckon them")
+    return faults
+
+
+@pytest.mark.parametrize("config", MANIFEST["configs"], ids=lambda c: c["name"])
+def test_config_names_only_code_that_is_there(config):
+    with open(os.path.join(ROOT, config["file"])) as f:
+        spec = json.load(f)
+    assert named_code_faults(spec, BENCH) == []
+
+
+def test_named_code_that_is_missing_or_named_twice_is_found(tmp_path):
+    with open(os.path.join(DATA, "own_code", "config.json")) as f:
+        spec = json.load(f)
+    # Not installed here: the three files it names are not under perfbench/.
+    assert named_code_faults(spec, BENCH) == [
+        "no file perfbench/adapters/own_code.py",
+        "no file perfbench/costs/own_code.py",
+        "no file perfbench/references/own_code.py"]
+    for folder in ("adapters", "costs", "references"):
+        os.makedirs(tmp_path / folder)
+        (tmp_path / folder / "own_code.py").write_text("")
+    assert named_code_faults(spec, str(tmp_path)) == []
+    twice = {**spec, "kernels": ["reshape_squeeze", "flash_attention"]}
+    assert named_code_faults(twice, str(tmp_path)) == [
+        "kernel name 'flash_attention' appears twice"]
+    alone = {k: v for k, v in twice.items() if k != "costs"}
+    assert "kernels named, but no costs module to reckon them" in \
+        named_code_faults(alone, str(tmp_path))
 
 
 @pytest.mark.parametrize("cell", MANIFEST["workloads"], ids=lambda w: w["name"])

@@ -16,7 +16,7 @@ from perfbench_paths import ROOT
 
 import loadgen
 import run
-from test_perfbench_extension import copy_benchmark
+from test_perfbench_extension import checkout_with_the_program
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     MANIFEST = json.load(f)
@@ -114,9 +114,7 @@ def test_a_control_laid_over_the_reference_names_its_clause(tmp_path):
     symmetric, and no file of the program may change). The whole run is
     driven; `correct` comes out false, and the clauses that failed are in
     the result line's `reference.why`, on stderr and in the samples' meta."""
-    root = copy_benchmark(tmp_path)
-    os.symlink(os.path.join(ROOT, "polykey_tpu"),
-               os.path.join(root, "polykey_tpu"))
+    root = checkout_with_the_program(tmp_path)
     with open(os.path.join(root, "perfbench", "reference.py"), "a") as f:
         f.write("\n\ndef rotary(x, positions, theta):\n    return x\n")
     cell, seed = "mixtral-8x7b-tp4.chat-closed", 2147483777
