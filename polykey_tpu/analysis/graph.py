@@ -939,9 +939,7 @@ class GraphEnv:
             # nothing — it is a read, the pool stays.)
             P = cfg.pages_per_seq
             zk = np.zeros(
-                (engine.model_cfg.num_layers, P, cfg.page_size,
-                 engine.model_cfg.num_kv_heads,
-                 engine.model_cfg.head_dim),
+                (engine.model_cfg.num_layers, P, *engine.paged.k.shape[2:]),
                 engine.paged.k.dtype,
             )
             yield (
@@ -1495,7 +1493,7 @@ class ShapeLayoutContracts(GraphCheck):
         # Paged decode DMA kernel: folded lane dim Hk*D = 128.
         N, ps, P = 8, 16, 4
         q = jnp.zeros((2, Hq, D), jnp.float32)
-        kp = jnp.zeros((N, ps, Hk, D), jnp.float32)
+        kp = jnp.zeros((N, ps, Hk * D), jnp.float32)
         tables = jnp.zeros((2, P), jnp.int32)
         positions = jnp.zeros((2,), jnp.int32)
         window = jnp.zeros((1,), jnp.int32)
@@ -1510,7 +1508,7 @@ class ShapeLayoutContracts(GraphCheck):
              ((2, Hq, 1), "float32"), ((2, Hq, 1), "float32")],
         ))
         # int8-KV variant: (values, scales) pairs, scales [N, ps, Hk].
-        kq = jnp.zeros((N, ps, Hk, D), jnp.int8)
+        kq = jnp.zeros((N, ps, Hk * D), jnp.int8)
         scales = jnp.zeros((N, ps, Hk), jnp.bfloat16)
         findings.extend(abstract_contract(
             "ops.paged_attention_kernel._decode_call[int8]",
@@ -1552,7 +1550,7 @@ class ShapeLayoutContracts(GraphCheck):
             cfg = get_config(model)
             Hq, Hk, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
             q = jnp.zeros((T, Hq, D), jnp.float32)
-            kp = jnp.zeros((N, ps, Hk, D), jnp.float32)
+            kp = jnp.zeros((N, ps, Hk * D), jnp.float32)
             findings.extend(abstract_contract(
                 f"ops.ragged_paged_attention_kernel[{model}]",
                 lambda *args, D=D: ragged_mod._ragged_call(
@@ -1566,7 +1564,7 @@ class ShapeLayoutContracts(GraphCheck):
         cfg = get_config("tiny-llama")
         Hq, Hk, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         q = jnp.zeros((T, Hq, D), jnp.bfloat16)
-        kq = jnp.zeros((N, ps, Hk, D), jnp.int8)
+        kq = jnp.zeros((N, ps, Hk * D), jnp.int8)
         scales = jnp.zeros((N, ps, Hk), jnp.bfloat16)
         findings.extend(abstract_contract(
             "ops.ragged_paged_attention_kernel[int8]",
@@ -1590,8 +1588,8 @@ class ShapeLayoutContracts(GraphCheck):
                     interpret=False, token_tile=TT,
                 ),
                 jnp.zeros((T + 3, Hq, D), jnp.bfloat16),
-                jnp.zeros((N, ps, Hk, D), jnp.bfloat16),
-                jnp.zeros((N, ps, Hk, D), jnp.bfloat16),
+                jnp.zeros((N, ps, Hk * D), jnp.bfloat16),
+                jnp.zeros((N, ps, Hk * D), jnp.bfloat16),
                 tables, starts, lens, kvs, window,
             )
         except ValueError:
@@ -1621,11 +1619,7 @@ class ShapeLayoutContracts(GraphCheck):
         from ..models.config import get_config
         from ..models.transformer import init_params
         from ..parallel.mesh import MeshConfig, create_mesh
-        from ..parallel.sharding import (
-            paged_kv_scale_sharding,
-            paged_kv_sharding,
-            param_shardings,
-        )
+        from ..parallel.sharding import paged_kv_sharding, param_shardings
 
         findings: list[Finding] = []
         n_devices = len(jax.devices())
@@ -1679,12 +1673,10 @@ class ShapeLayoutContracts(GraphCheck):
                         f"kv_pool[{model}/{mesh_name}]",
                         tuple(leaf.shape), kv_sh,
                     ))
-                scale_sh = paged_kv_scale_sharding(mesh)
                 for leaf in jax.tree_util.tree_leaves(scale_pool):
-                    sh = kv_sh if leaf.ndim == 5 else scale_sh
                     findings.extend(sharding_divisibility(
                         f"kv_scale_pool[{model}/{mesh_name}]",
-                        tuple(leaf.shape), sh,
+                        tuple(leaf.shape), kv_sh,
                     ))
         return findings
 

@@ -92,7 +92,9 @@ from .kv_cache import (
     KVHandoffState,
     KVWireError,
     PagedKV,
+    fold_heads,
     init_paged_kv,
+    unfold_heads,
 )
 from .metrics import EngineMetrics, RequestTimings
 from .prefix_cache import TIER_DEVICE, TIER_HOST
@@ -511,7 +513,10 @@ def _retire_lane_fn(last_tokens, seq_lens, page_tables, active, caps, slot):
 
 def _kv_restore_fn(paged: PagedKV, idx, k, v):
     """Scatter handed-off page contents into the pool at the target's
-    own page ids (ISSUE 13 decode-side restore). `idx`/`k`/`v` are
+    own page ids (ISSUE 13 decode-side restore). `k`/`v` arrive in the
+    stored layout, [L, P, page_size, Hk·D]: the host folds its
+    [..., Hk, D] pages (kv_cache.fold_heads, a view) before the upload,
+    so nothing is relaid out on the device. `idx`/`k`/`v` are
     padded to a FIXED width (pages_per_seq) so one compiled executable
     serves every handoff size — pad rows target the reserved garbage
     page 0, whose contents are never read (inactive lanes write it
@@ -535,9 +540,10 @@ def _kv_restore_quant_fn(paged: PagedKV, idx, k, v, ks, vs):
 def _kv_gather_fn(paged: PagedKV, idx):
     """Gather page contents out of the pool for host-tier eviction
     (ISSUE 15) — the read half of the fixed-width gather/scatter pair
-    whose write half is `_kv_restore_fn`. `idx` is padded to
-    pages_per_seq (pad rows read the reserved garbage page 0 and are
-    discarded host-side), so ONE compiled executable serves every spill
+    whose write half is `_kv_restore_fn`; pages leave in the stored
+    layout and the host unfolds them (kv_cache.unfold_heads). `idx` is
+    padded to pages_per_seq (pad rows read the reserved garbage page 0
+    and are discarded host-side), so ONE compiled executable serves every spill
     batch — the GL001 discipline. Read-only: the pool is NOT donated
     (the gathered copy leaves, the pool stays), so in-flight decode
     blocks are unaffected and the copy observes the donation-chain
@@ -809,16 +815,13 @@ class InferenceEngine:
                 "ragged_dispatch (POLYKEY_RAGGED) does not run on TPU yet: "
                 f"{RAGGED_WRITE_MOSAIC_ERROR}"
             )
-        data_sh = paged_kv_sharding(self.mesh)
+        pool_sh = paged_kv_sharding(self.mesh)
         if self._kv_quantized:
-            from ..parallel.sharding import paged_kv_scale_sharding
-
-            scale_sh = paged_kv_scale_sharding(self.mesh)
             self._pool_sharding = PagedKV(
-                k=data_sh, v=data_sh, ks=scale_sh, vs=scale_sh
+                k=pool_sh, v=pool_sh, ks=pool_sh, vs=pool_sh
             )
         else:
-            self._pool_sharding = PagedKV(k=data_sh, v=data_sh)
+            self._pool_sharding = PagedKV(k=pool_sh, v=pool_sh)
         self._repl = NamedSharding(self.mesh, PartitionSpec())
         # Sequence-parallel prefill: the window's token axis shards over
         # sp, spreading prefill compute across chips; the page pools are
@@ -2865,13 +2868,15 @@ class InferenceEngine:
             idx0 = np.zeros((P,), np.int32)
             jax.block_until_ready(self._jit_kv_gather(self.paged, put(idx0)))
             zk = np.zeros(
-                (self.model_cfg.num_layers, P, cfg.page_size,
-                 self.model_cfg.num_kv_heads, self.model_cfg.head_dim),
+                (self.model_cfg.num_layers, P, *self.paged.k.shape[2:]),
                 self.paged.k.dtype,
             )
             operands = [put(idx0), put(zk), put(np.zeros_like(zk))]
             if self._kv_quantized:
-                zs = np.zeros(zk.shape[:-1], jnp.dtype(jnp.bfloat16))
+                zs = np.zeros(
+                    (self.model_cfg.num_layers, P, *self.paged.ks.shape[2:]),
+                    self.paged.ks.dtype,
+                )
                 operands += [put(zs), put(np.zeros_like(zs))]
             self.paged = self._jit_kv_restore(self.paged, *operands)
         jax.block_until_ready(self.paged)
@@ -3140,6 +3145,9 @@ class InferenceEngine:
                 k = np.asarray(jnp.take(self.paged.k, idx, axis=1))
                 # polylint: disable=PL008(handoff export gather; prefill_only cold path)
                 v = np.asarray(jnp.take(self.paged.v, idx, axis=1))
+                # The wire format keeps the heads apart (kv_cache.py).
+                k = unfold_heads(k, self.model_cfg.head_dim)
+                v = unfold_heads(v, self.model_cfg.head_dim)
                 ks = vs = None
                 if self.paged.quantized:
                     # polylint: disable=PL008(handoff export gather; prefill_only cold path)
@@ -3224,7 +3232,8 @@ class InferenceEngine:
 
         try:
             put = partial(jax.device_put, device=self._repl)
-            operands = [put(idx), put(_pad(state.k)), put(_pad(state.v))]
+            operands = [put(idx), put(fold_heads(_pad(state.k))),
+                        put(fold_heads(_pad(state.v)))]
             if self._kv_quantized:
                 operands += [put(_pad(state.ks)), put(_pad(state.vs))]
             # _host_crossing: the padded page payload rides up as one
@@ -3413,7 +3422,7 @@ class InferenceEngine:
                 vs[:, r] = hvs
         try:
             put = partial(jax.device_put, device=self._repl)
-            operands = [put(idx), put(k), put(v)]
+            operands = [put(idx), put(fold_heads(k)), put(fold_heads(v))]
             if self._kv_quantized:
                 operands += [put(ks), put(vs)]
             # _host_crossing: the page payload rides up as one
@@ -3475,6 +3484,9 @@ class InferenceEngine:
             k = np.asarray(outs[0])
             # polylint: disable=PL008(spill gather read, same cold path)
             v = np.asarray(outs[1])
+            # The host tier keeps the heads apart (kv_cache.HostKVPool).
+            k = unfold_heads(k, self.model_cfg.head_dim)
+            v = unfold_heads(v, self.model_cfg.head_dim)
             ks = vs = None
             if self._kv_quantized:
                 # polylint: disable=PL008(spill gather read, same cold path)

@@ -6,9 +6,19 @@ fixed-size pages inside one preallocated pool per layer, so sequences grow
 without reallocation or fragmentation, and the decode batch is composed by
 page-table indirection rather than copying.
 
-Layout (per K and V):  [num_layers, num_pages, page_size, num_kv_heads,
-head_dim]. The trailing (page_size·num_kv_heads, head_dim) footprint of one
-page is contiguous in HBM — what the Pallas decode kernel DMAs per grid step.
+Stored layout (per K and V), stated here once and pointed to elsewhere:
+[num_layers, num_pages, page_size, num_kv_heads · head_dim] — heads FOLDED
+into the last (lane) dimension, head-major, so a `tp` shard of that
+dimension is whole heads and one page is a contiguous, 128-lane-aligned
+[page_size, Hk·D] slab: what both Pallas kernels DMA, with no reshape of a
+pool anywhere (under the TPU's tiled layout splitting the last dimension is
+a relayout of the whole pool, not a bitcast). The model step views the stack
+as [L·N, page_size, Hk·D] (a merge of leading dimensions only) and addresses
+page (layer, page) as `layer · N + page` — models/transformer.py
+`_run_paged_stack`. int8-KV scale pools are [L, N, page_size, Hk]. The host
+tier (HostKVPool) and the handoff wire format (KVHandoffState) keep the
+heads apart, [..., Hk, D]; `fold_heads` / `unfold_heads` convert page-sized
+host arrays at that boundary.
 
 The allocator is host-side bookkeeping: the C++ implementation
 (native/block_allocator.cc, loaded via ctypes) with a pure-Python fallback of
@@ -139,7 +149,8 @@ class BlockAllocator:
 
 @struct.dataclass
 class PagedKV:
-    """Device-side page pools: k/v [L, num_pages, page_size, Hk, D].
+    """Device-side page pools: k/v [L, num_pages, page_size, Hk·D] (heads
+    folded into lanes — the stored layout, module docstring).
 
     With int8 KV (EngineConfig.kv_dtype="int8") k/v hold int8 values and
     ks/vs hold per-(token, head) bf16 scales [L, num_pages, page_size, Hk]
@@ -174,15 +185,28 @@ def init_paged_kv(
 ) -> PagedKV:
     """`kv_dtype=jnp.int8` builds quantized pools (+ bf16 scale pools);
     None keeps the full-precision layout in `dtype`."""
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, num_pages, page_size,
+             cfg.num_kv_heads * cfg.head_dim)
     if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
-        sshape = shape[:-1]
+        sshape = shape[:-1] + (cfg.num_kv_heads,)
         return PagedKV(
             k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
             ks=jnp.zeros(sshape, jnp.bfloat16),
             vs=jnp.zeros(sshape, jnp.bfloat16),
         )
     return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+def fold_heads(pages):
+    """[..., Hk, D] → [..., Hk·D]: host-tier / wire pages into the stored
+    layout (a view on a contiguous numpy array)."""
+    return pages.reshape(*pages.shape[:-2], -1)
+
+
+def unfold_heads(pages, head_dim: int):
+    """[..., Hk·D] → [..., Hk, D]: gathered pool pages into the host-tier /
+    wire layout."""
+    return pages.reshape(*pages.shape[:-1], -1, head_dim)
 
 
 def kv_pool_bytes(
@@ -207,7 +231,7 @@ def host_kv_page_bytes(
 
 class HostKVPool:
     """Second KV tier in host RAM (ISSUE 15): preallocated numpy pools
-    mirroring the device layout per page — k/v [L, capacity, page_size,
+    of pages with the heads kept apart — k/v [L, capacity, page_size,
     Hk, D] (+ ks/vs scale pools [L, capacity, page_size, Hk] for int8)
     — holding COLD pages spilled from the device pool by the prefix
     cache. Pages here are never computed against: they exist to be
@@ -322,9 +346,9 @@ class KVWireError(RuntimeError):
 class KVHandoffState:
     """One request's prefill-complete KV state, host-side.
 
-    Arrays use the pool layout with the page axis restricted to this
-    request's pages in block-table order: k/v are
-    [L, n_pages, page_size, Hk, D]; ks/vs (int8 pools only) are
+    Arrays hold this request's pages in block-table order with the heads
+    kept apart (the wire format; it did not move when the device pools
+    were folded): k/v are [L, n_pages, page_size, Hk, D]; ks/vs (int8 pools only) are
     [L, n_pages, page_size, Hk]. `prompt_ids` is the tokenized (and
     possibly tail-truncated) prompt — positions 0..prompt_len-1 are the
     ones the pages hold KV for. `first_token` was sampled at position

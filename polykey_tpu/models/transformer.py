@@ -220,61 +220,57 @@ def _run_stack(params, cfg: ModelConfig, tokens, positions, kv_scanned, attend):
 
 def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged, attend):
     """The stack over the paged KV pools: embed → scan(layer body) → final
-    norm, with the stacked pools in the scan CARRY; returns (hidden,
+    norm, with the WHOLE stacked pools in the scan carry; returns (hidden,
     updated paged).
 
-    Scanning the pools as xs/ys (the way _run_stack scans a contiguous
-    cache) makes XLA build the updated stack in a second full-size buffer
-    — on a v5e the prefill step of 8B-int8 at default geometry asked for
-    two extra 2.00G AllocateBuffer temporaries next to the 4G donated pool
-    and did not fit. In the carry, each layer slices its own pools out,
-    runs `attend` on them exactly as before, and writes them back with a
-    dynamic-update-slice that XLA performs in place on the donated buffer,
-    so the step holds one layer's copy instead of a whole second pool.
+    The pools are stored [L, N, page_size, Hk·D] (engine/kv_cache.py) and
+    carried as [L·N, page_size, Hk·D] — a merge of leading dimensions, a
+    bitcast under any tiling. `attend(layer_idx, q, k, v, kc, vc)` gets that
+    whole stack as kc / vc ((values, scales) pairs for int8 KV) and
+    addresses page (layer, page) as `layer_idx · N + page`
+    (`_layer_tables`): the write kernel aliases the stack, the XLA scatters
+    update it in place in the carry, the decode kernel DMAs single pages out
+    of it. No layer's pool is ever sliced out, copied or written back, so a
+    step moves the rows and pages it touches and nothing else
+    (tests/test_paged_layout.py holds that in the compiled step).
 
-    The per-layer copy in and out remains (and the kernels' head-folding
-    reshape is a relayout on TPU, not a bitcast): a pool stored folded and
-    addressed by page id across layers would need neither — PERF.md
-    "Where the time goes".
-    """
+    Not scanned as xs/ys (the way _run_stack scans a contiguous cache):
+    that makes XLA build the updated stack in a second full-size buffer."""
     norm_offset = 1.0 if cfg.scale_embeddings else 0.0
     if paged.quantized:
-        # int8 KV: the per-layer cache operand is a (values, scales)
-        # pair; the write/read ops dispatch on the pair form.
-        pools = ((paged.k, paged.ks), (paged.v, paged.vs))
+        # int8 KV: the cache operand is a (values, scales) pair; the
+        # write/read ops dispatch on the pair form.
+        stored = ((paged.k, paged.ks), (paged.v, paged.vs))
     else:
-        pools = (paged.k, paged.v)
+        stored = (paged.k, paged.v)
+    pools = jax.tree.map(lambda p: p.reshape(-1, *p.shape[2:]), stored)
 
     x = embed_tokens(params, cfg, tokens)
 
     def body(carry, scanned):
-        x, pools = carry
+        x, (kc, vc) = carry
         layer_params, layer_idx = scanned
-        kc, vc = jax.tree.map(
-            lambda pool: jax.lax.dynamic_index_in_dim(
-                pool, layer_idx, 0, keepdims=False
-            ),
-            pools,
-        )
         x, kc, vc = apply_layer(
             layer_params, layer_idx, x, positions, cfg, attend, kc, vc
         )
-        pools = jax.tree.map(
-            lambda pool, layer: jax.lax.dynamic_update_index_in_dim(
-                pool, layer, layer_idx, 0
-            ),
-            pools, (kc, vc),
-        )
-        return (x, pools), None
+        return (x, (kc, vc)), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    (x, (kc, vc)), _ = jax.lax.scan(
+    (x, pools), _ = jax.lax.scan(
         body, (x, pools), (params["layers"], layer_ids)
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, norm_offset)
+    kc, vc = jax.tree.map(lambda p, like: p.reshape(like.shape), pools, stored)
     if paged.quantized:
-        return x, type(paged)(k=kc[0], v=vc[0], ks=kc[1], vs=vc[1])
-    return x, type(paged)(k=kc, v=vc)
+        return x, paged.replace(k=kc[0], v=vc[0], ks=kc[1], vs=vc[1])
+    return x, paged.replace(k=kc, v=vc)
+
+
+def _layer_tables(paged, layer_idx, tables: jax.Array) -> jax.Array:
+    """Page ids of one layer within the stack `_run_paged_stack` carries:
+    page p of layer l lies at l · num_pages + p (the reserved garbage page 0
+    becomes the layer's own garbage page)."""
+    return tables + layer_idx * paged.num_pages
 
 
 def make_causal_attend(cfg: ModelConfig, positions: jax.Array):
@@ -384,13 +380,14 @@ def forward_paged(
     decode = tokens.shape[1] == 1
 
     def attend(layer_idx, q, k, v, kc, vc):
-        kc, vc = paged_write(kc, vc, k, v, page_tables, positions, mesh=mesh)
+        tables = _layer_tables(paged, layer_idx, page_tables)
+        kc, vc = paged_write(kc, vc, k, v, tables, positions, mesh=mesh)
         # Single-token steps take the DMA decode kernel (reads only valid
         # pages); prefill buckets take the gather path (wide T amortizes
         # the window materialization, and flash covers contiguous prefill).
         op = paged_attention_decode if decode else paged_attention
         ctx = op(
-            q, kc, vc, page_tables, positions,
+            q, kc, vc, tables, positions,
             scale=cfg.q_scale,
             logit_softcap=cfg.attn_logit_softcap,
             window=_layer_window(cfg, layer_idx),
@@ -442,7 +439,7 @@ def forward_ragged(
     T = tokens.shape[0]
     pos_row = positions.reshape(T, 1)
 
-    Hk, D = paged.k.shape[-2:]
+    Hk, D = cfg.num_kv_heads, cfg.head_dim
     # The ragged kernel runs un-shard_mapped (GSPMD cannot partition an
     # opaque pallas_call, and no shard_map wrapping exists for the flat
     # stream yet): ANY mesh extent > 1 — tp included — routes to the
@@ -457,22 +454,25 @@ def forward_ragged(
 
     def attend(layer_idx, q, k, v, kc, vc):
         # One batch row per token: the decode write shape (T==1 path).
+        tok_tables = _layer_tables(paged, layer_idx, token_tables)
         kc, vc = paged_write(
             kc, vc,
             k.reshape(T, 1, *k.shape[2:]), v.reshape(T, 1, *v.shape[2:]),
-            token_tables, pos_row, mesh=mesh,
+            tok_tables, pos_row, mesh=mesh,
         )
         window = _layer_window(cfg, layer_idx)
         if kernel_ok:
             ctx = ragged_paged_attention(
-                q[0], kc, vc, page_tables, seq_starts, seq_lens, kv_lens,
+                q[0], kc, vc,
+                _layer_tables(paged, layer_idx, page_tables),
+                seq_starts, seq_lens, kv_lens,
                 scale=cfg.q_scale,
                 logit_softcap=cfg.attn_logit_softcap,
                 window=window, force_kernel=True,
             )
         else:
             ctx = ragged_gather_attention(
-                q[0], kc, vc, token_tables, positions,
+                q[0], kc, vc, tok_tables, positions,
                 scale=cfg.q_scale,
                 logit_softcap=cfg.attn_logit_softcap,
                 window=window,

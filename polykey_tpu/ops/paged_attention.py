@@ -10,6 +10,12 @@ gather with per-page DMA so only valid pages move.
 Page-table convention (engine/kv_cache.py): page_tables[b, j] is the page id
 holding positions [j*page_size, (j+1)*page_size); unused tail entries point
 at the reserved garbage page 0 and are excluded by the position mask.
+
+Pools arrive in the stored layout (engine/kv_cache.py): [N, page_size, Hk·D],
+heads folded into the last dimension. Every path here folds or unfolds the
+ROWS it writes or the pages it gathered — never a pool. N may be the whole
+stack's L·num_pages with the caller's page ids offset by layer · num_pages
+(models/transformer.py `_run_paged_stack`).
 """
 
 from __future__ import annotations
@@ -21,24 +27,25 @@ import jax.numpy as jnp
 
 
 def paged_gather_kv(
-    k_pages: jax.Array,       # [num_pages, page_size, Hk, D]
+    k_pages: jax.Array,       # [num_pages, page_size, Hk·D]
     v_pages: jax.Array,
     page_tables: jax.Array,   # [B, P] int32
-    ) -> tuple[jax.Array, jax.Array]:
+    head_dim: int,
+) -> tuple[jax.Array, jax.Array]:
     """Materialize [B, P*page_size, Hk, D] K/V windows from the pools."""
     B, P = page_tables.shape
-    _, page_size, Hk, D = k_pages.shape
-    k = k_pages[page_tables]  # [B, P, page_size, Hk, D]
+    page_size = k_pages.shape[1]
+    k = k_pages[page_tables]  # [B, P, page_size, Hk·D]
     v = v_pages[page_tables]
     return (
-        k.reshape(B, P * page_size, Hk, D),
-        v.reshape(B, P * page_size, Hk, D),
+        k.reshape(B, P * page_size, -1, head_dim),
+        v.reshape(B, P * page_size, -1, head_dim),
     )
 
 
 def paged_attention(
     q: jax.Array,             # [B, T, Hq, D]
-    k_pages: jax.Array,       # [num_pages, page_size, Hk, D]
+    k_pages: jax.Array,       # [num_pages, page_size, Hk·D]
     v_pages: jax.Array,
     page_tables: jax.Array,   # [B, P]
     q_positions: jax.Array,   # [B, T] absolute positions of the queries
@@ -65,15 +72,15 @@ def paged_attention(
         # fuses into the window consumers, and the pool-side HBM read
         # stays int8.
         (kq, ks_pool), (vq, vs_pool) = k_pages, v_pages
-        k, v = paged_gather_kv(kq, vq, page_tables)
+        k, v = paged_gather_kv(kq, vq, page_tables, q.shape[-1])
         B, P = page_tables.shape
-        ps, Hk = kq.shape[1], kq.shape[2]
+        ps, Hk = ks_pool.shape[1], ks_pool.shape[2]
         ks = ks_pool[page_tables].reshape(B, P * ps, Hk)
         vs = vs_pool[page_tables].reshape(B, P * ps, Hk)
         k = dequantize_kv(k, ks, q.dtype)
         v = dequantize_kv(v, vs, q.dtype)
     else:
-        k, v = paged_gather_kv(k_pages, v_pages, page_tables)
+        k, v = paged_gather_kv(k_pages, v_pages, page_tables, q.shape[-1])
     return flash_attention(
         q, k, v, q_positions,
         scale=scale, logit_softcap=logit_softcap, window=window, mesh=mesh,
@@ -103,7 +110,7 @@ def dequantize_kv(values: jax.Array, scales: jax.Array, dtype) -> jax.Array:
 
 
 def paged_write(
-    k_pages,                  # [num_pages, page_size, Hk, D], or a
+    k_pages,                  # [num_pages, page_size, Hk·D], or a
                               # (values, scales) pair for int8 KV pools
     v_pages,
     k_new: jax.Array,         # [B, T, Hk, D]
@@ -132,17 +139,22 @@ def paged_write(
     - otherwise: the per-token XLA scatter.
     """
     quantized = isinstance(k_pages, tuple)
+    Hk, D = k_new.shape[2], k_new.shape[3]
+
+    def fold(rows):           # [B, T, Hk, D] → [B, T, Hk·D], the pool's rows
+        return rows.reshape(*rows.shape[:2], Hk * D)
+
+    # (pool, rows) pairs sharing one (page, offset) index layout.
     if quantized:
         (kq, ks_pool), (vq, vs_pool) = k_pages, v_pages
         k8, k_s = quantize_kv_rows(k_new)
         v8, v_s = quantize_kv_rows(v_new)
-        # (pool, rows) pairs sharing one (page, offset) index layout.
-        writes = [(kq, k8), (vq, v8),
+        writes = [(kq, fold(k8)), (vq, fold(v8)),
                   (ks_pool, k_s.astype(ks_pool.dtype)),
                   (vs_pool, v_s.astype(vs_pool.dtype))]
         data_pool = kq
     else:
-        writes = [(k_pages, k_new), (v_pages, v_new)]
+        writes = [(k_pages, fold(k_new)), (v_pages, fold(v_new))]
         data_pool = k_pages
 
     page_size = data_pool.shape[1]
@@ -163,12 +175,11 @@ def paged_write(
             use_quantized_paged_kernel,
         )
 
-        Hk, D = data_pool.shape[2], data_pool.shape[3]
         pp = mesh.shape.get("pp", 1) if mesh is not None else 1
         gate = use_quantized_paged_kernel if quantized else use_paged_kernel
         if gate(Hk, D) and pp == 1:
             return repack(_write_decode_kernel(
-                writes, page_ids[:, 0], offsets[:, 0], mesh,
+                writes, page_ids[:, 0], offsets[:, 0], mesh, Hk,
             ))
 
     def token_scatter(pools):
@@ -205,15 +216,16 @@ def paged_write(
     return repack(token_scatter(pools_in))
 
 
-def _write_decode_kernel(writes, page_ids, offsets, mesh):
+def _write_decode_kernel(writes, page_ids, offsets, mesh, Hk):
     """Dispatch the Pallas write kernel over (pool, rows) pairs, under
     shard_map when the mesh shards batch (dp) or heads (tp). Pools are
     replicated over dp/sp, so every replica must apply every lane's
     write: the dp-local updates all-gather (tiny — B rows) before the
     kernel writes the full batch into the local head shard. Mirrors
-    paged_attention_decode's specs. Data pools are [N, ps, Hk, D]; int8
-    KV adds scale pools [N, ps, Hk] — the head axis is last there, so
-    its tp spec sits on the final dim."""
+    paged_attention_decode's specs. Data pools are [N, ps, Hk·D], int8
+    KV adds scale pools [N, ps, Hk], rows [B, 1, ·] alike: tp shards the
+    last dimension of all of them (heads are major in the fold, so a
+    shard is Hk/tp whole heads)."""
     from .paged_write_kernel import paged_write_rows_kernel
 
     pools = [p for p, _ in writes]
@@ -222,7 +234,7 @@ def _write_decode_kernel(writes, page_ids, offsets, mesh):
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if dp <= 1 and tp <= 1:
         return paged_write_rows_kernel(pools, rows, page_ids, offsets)
-    B, Hk = rows[0].shape[0], rows[0].shape[2]
+    B = rows[0].shape[0]
     if B % dp or Hk % tp:
         # Same curated error as the read kernel (paged_attention_kernel
         # .py) — never let uneven sharding surface as an opaque shard_map
@@ -234,14 +246,8 @@ def _write_decode_kernel(writes, page_ids, offsets, mesh):
 
     from jax.sharding import PartitionSpec as Pspec
 
-    def pool_spec(p):
-        # head axis: dim 2 of [N, ps, Hk, D]; dim 2 (last) of [N, ps, Hk]
-        return (Pspec(None, None, "tp", None) if p.ndim == 4
-                else Pspec(None, None, "tp"))
-
-    def row_spec(r):
-        return (Pspec("dp", None, "tp", None) if r.ndim == 4
-                else Pspec("dp", None, "tp"))
+    pool_spec = Pspec(None, None, "tp")
+    row_spec = Pspec("dp", None, "tp")
 
     def inner(pools_l, rows_l, pid, off):
         if dp > 1:
@@ -257,12 +263,12 @@ def _write_decode_kernel(writes, page_ids, offsets, mesh):
         inner,
         mesh=mesh,
         in_specs=(
-            [pool_spec(p) for p in pools],
-            [row_spec(r) for r in rows],
+            [pool_spec] * len(pools),
+            [row_spec] * len(rows),
             Pspec("dp"),
             Pspec("dp"),
         ),
-        out_specs=tuple(pool_spec(p) for p in pools),
+        out_specs=(pool_spec,) * len(pools),
         check_vma=False,
     )
     return sm(pools, rows, page_ids, offsets)

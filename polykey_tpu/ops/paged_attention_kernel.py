@@ -57,7 +57,9 @@ def _kernel(
     rng_ref,       # [2] int32 page sub-range [rlo, rhi) — CP shard's slice
     # then, positionally (arity varies with `quantized`):
     # inputs: q [1, Hq, D] VMEM block; k/v pages [N, ps, Hk·D] HBM
-    #         (heads folded into lanes; manual DMA); quantized adds
+    #         (the stored layout, heads in lanes; manual DMA; N may be
+    #         the whole stack's L·num_pages, the table's ids offset by
+    #         the layer); quantized adds
     #         ks/vs scale pages [N, ps, Hk] HBM (bf16)
     # outputs: unnormalized online-softmax state — the wrapper
     #         normalizes, or merges across CP shards first (acc/l scale
@@ -271,7 +273,7 @@ _STAT_MINOR = 128   # lane width for the m/l stat outputs (tile-aligned)
 )
 def _decode_call(
     q: jax.Array,             # [B, Hq, D]
-    k_pages,                  # [N, ps, Hk, D], or (values, scales) pairs
+    k_pages,                  # [N, ps, Hk·D], or (values, scales) pairs
     v_pages,                  #   for int8 KV (scales [N, ps, Hk] bf16)
     page_tables: jax.Array,   # [B, P] int32
     positions: jax.Array,     # [B] int32
@@ -286,22 +288,23 @@ def _decode_call(
     """Returns UNNORMALIZED online-softmax state (acc [B,Hq,D] f32,
     m [B,Hq,1], l [B,Hq,1]) over the pages in `page_range` — the caller
     normalizes, or first merges partial states across context-parallel
-    shards (acc/l scale by exp(m - m_global))."""
+    shards (acc/l scale by exp(m - m_global)).
+
+    The pools are taken as they are stored (engine/kv_cache.py: heads
+    folded into lanes, every page DMA 128-aligned for any head_dim) and
+    stay in HBM (`pl.ANY`): nothing here reshapes or copies a pool."""
     quantized = isinstance(k_pages, tuple)
     if quantized:
         (k_pages, ks_pages), (v_pages, vs_pages) = k_pages, v_pages
     B, Hq, D = q.shape
-    N, ps, Hk, _ = k_pages.shape
+    _, ps, folded = k_pages.shape
+    Hk = folded // D
     P = page_tables.shape[1]
     if pages_per_block <= 0:
         # Target ~128 positions per block (one MXU tile of rows) with all
         # of a block's page DMAs in flight together; bounded by the table.
         pages_per_block = max(1, min(P, 128 // ps if ps <= 128 else 1))
     G = min(pages_per_block, P)
-    # Fold heads into the lane dimension: [N, ps, Hk·D] keeps every DMA
-    # slice 128-aligned regardless of head_dim (a contiguous reshape).
-    k_pages = k_pages.reshape(N, ps, Hk * D)
-    v_pages = v_pages.reshape(N, ps, Hk * D)
 
     kernel = functools.partial(
         _kernel,
@@ -321,8 +324,8 @@ def _decode_call(
         any_spec,
     ]
     scratch = [
-        pltpu.VMEM((2, G, ps, Hk * D), k_pages.dtype),
-        pltpu.VMEM((2, G, ps, Hk * D), k_pages.dtype),
+        pltpu.VMEM((2, G, ps, folded), k_pages.dtype),
+        pltpu.VMEM((2, G, ps, folded), k_pages.dtype),
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
@@ -412,7 +415,7 @@ def use_paged_kernel(num_kv_heads: int, head_dim: int) -> bool:
 
 def paged_attention_decode(
     q: jax.Array,             # [B, 1, Hq, D] (single decode step)
-    k_pages: jax.Array,       # [N, ps, Hk, D]
+    k_pages: jax.Array,       # [N, ps, Hk·D] (or int8 (values, scales))
     v_pages: jax.Array,
     page_tables: jax.Array,   # [B, P]
     q_positions: jax.Array,   # [B, 1] absolute positions
@@ -431,8 +434,9 @@ def paged_attention_decode(
 
     With a mesh whose dp/tp/sp extents exceed 1, the kernel runs under
     shard_map: batch (and page tables/positions) shard over dp, heads
-    over tp — the engine's layout (parallel/sharding.py: pools
-    P(None, None, 'tp', None), decode batch over dp). GSPMD cannot
+    over tp — the engine's layout (parallel/sharding.py: the pools'
+    folded last dimension over tp, whole heads a shard; decode batch
+    over dp). GSPMD cannot
     partition an opaque pallas_call, so without this it would all-gather
     the head-sharded pools. Attention is embarrassingly parallel over
     batch and (GQA-aligned) heads, so each shard runs the same kernel on
@@ -445,7 +449,8 @@ def paged_attention_decode(
     quantized = isinstance(k_pages, tuple)
     B = q.shape[0]
     data_pool = k_pages[0] if quantized else k_pages
-    Hk, D = data_pool.shape[2], data_pool.shape[3]
+    D = q.shape[3]
+    Hk = data_pool.shape[2] // D
 
     gate = use_quantized_paged_kernel if quantized else use_paged_kernel
     if not (force_kernel or interpret or gate(Hk, D)):
@@ -524,11 +529,11 @@ def paged_attention_decode(
             return _normalize(acc, l, q2.dtype)
 
         # Quantized pools are (values, scales) pairs: per-arg specs are
-        # pytrees matching that structure (scale pools [N, ps, Hk]
-        # head-shard on their LAST dim).
+        # pytrees matching that structure. Data [N, ps, Hk·D] and scale
+        # pools [N, ps, Hk] both head-shard on their last dimension.
         pool_spec = (
-            (P(None, None, "tp", None), P(None, None, "tp"))
-            if quantized else P(None, None, "tp", None)
+            (P(None, None, "tp"), P(None, None, "tp"))
+            if quantized else P(None, None, "tp")
         )
         sm = jax.shard_map(
             inner_sm,

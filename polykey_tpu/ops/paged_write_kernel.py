@@ -26,9 +26,12 @@ donates the pool through every dispatch).
 
 The kernel is generic over a LIST of (pool, rows) writes sharing one
 (page, offset) index layout: the fp path writes [k, v] data pools
-([N, ps, Hk*D] folded — heads into lanes, exactly like the read kernel
-ops/paged_attention_kernel.py); the int8-KV path adds the bf16 scale
-pools [N, ps, Hk] in the same waves.
+([N, ps, Hk*D] — the stored layout of engine/kv_cache.py, heads folded
+into lanes, taken as it lies: no pool is reshaped here or on return);
+the int8-KV path adds the bf16 scale pools [N, ps, Hk] in the same
+waves. N is whatever the caller's page ids address — the model step
+passes the whole stack, [L·num_pages, ps, Hk*D], with ids offset by
+layer · num_pages, so the aliased output IS the donated stacked pool.
 
 Garbage-page collisions are intended: inactive lanes all target page 0
 (engine convention, engine.py "Inactive slots"); several lanes then RMW
@@ -121,8 +124,8 @@ def _make_kernel(n_pools: int, B: int, ps: int):
 
 
 def paged_write_rows_kernel(
-    pools: list,              # data [N, ps, Hk, D] and/or scale [N, ps, Hk]
-    rows: list,               # matching [B, 1, Hk, D] / [B, 1, Hk]
+    pools: list,              # data [N, ps, Hk*D] and/or scale [N, ps, Hk]
+    rows: list,               # matching [B, 1, Hk*D] / [B, 1, Hk]
     page_ids: jax.Array,      # [B] int32
     offsets: jax.Array,       # [B] int32
     *,
@@ -133,28 +136,17 @@ def paged_write_rows_kernel(
     n = len(pools)
     B = rows[0].shape[0]
     ps = pools[0].shape[1]
-
-    folded_pools, folded_rows, shapes = [], [], []
-    for p, r in zip(pools, rows):
-        shapes.append(p.shape)
-        if p.ndim == 4:
-            N, _, Hk, D = p.shape
-            folded_pools.append(p.reshape(N, ps, Hk * D))
-            folded_rows.append(r.reshape(B, 1, Hk * D).astype(p.dtype))
-        else:
-            folded_pools.append(p)
-            folded_rows.append(r.reshape(B, 1, p.shape[2]).astype(p.dtype))
+    rows = [r.astype(p.dtype) for p, r in zip(pools, rows)]
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     row_specs = [
-        pl.BlockSpec(fr.shape, lambda *_: (0, 0, 0),
-                     memory_space=pltpu.VMEM)
-        for fr in folded_rows
+        pl.BlockSpec(r.shape, lambda *_: (0, 0, 0), memory_space=pltpu.VMEM)
+        for r in rows
     ]
     outs = pl.pallas_call(
         _make_kernel(n, B, ps),
         out_shape=tuple(
-            jax.ShapeDtypeStruct(fp.shape, fp.dtype) for fp in folded_pools
+            jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -162,8 +154,7 @@ def paged_write_rows_kernel(
             in_specs=row_specs + [any_spec] * n,
             out_specs=[any_spec] * n,
             scratch_shapes=(
-                [pltpu.VMEM((B, ps, fp.shape[2]), fp.dtype)
-                 for fp in folded_pools]
+                [pltpu.VMEM((B, ps, p.shape[2]), p.dtype) for p in pools]
                 + [pltpu.SemaphoreType.DMA((B,))] * (2 * n)
             ),
         ),
@@ -175,26 +166,7 @@ def paged_write_rows_kernel(
     )(
         page_ids.astype(jnp.int32),
         offsets.astype(jnp.int32),
-        *folded_rows,
-        *folded_pools,
+        *rows,
+        *pools,
     )
-    return tuple(o.reshape(sh) for o, sh in zip(outs, shapes))
-
-
-def paged_write_decode_kernel(
-    k_pages: jax.Array,       # [N, ps, Hk, D]
-    v_pages: jax.Array,
-    k_new: jax.Array,         # [B, 1, Hk, D] — single decode token per lane
-    v_new: jax.Array,
-    page_ids: jax.Array,      # [B] int32
-    offsets: jax.Array,       # [B] int32
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """The fp two-pool case (kept as the named entry point the kernel
-    check and tests exercise)."""
-    kp, vp = paged_write_rows_kernel(
-        [k_pages, v_pages], [k_new, v_new], page_ids, offsets,
-        interpret=interpret,
-    )
-    return kp, vp
+    return tuple(outs)

@@ -292,7 +292,7 @@ def _ragged_kernel(
 )
 def _ragged_call(
     q: jax.Array,             # [T, Hq, D] flat token stream (tile-padded)
-    k_pages,                  # [N, ps, Hk, D], or (values, scales) pairs
+    k_pages,                  # [N, ps, Hk·D], or (values, scales) pairs
     v_pages,                  #   for int8 KV (scales [N, ps, Hk] bf16)
     page_tables: jax.Array,   # [S, P] int32
     seq_starts: jax.Array,    # [S] int32
@@ -314,7 +314,8 @@ def _ragged_call(
     if quantized:
         (k_pages, ks_pages), (v_pages, vs_pages) = k_pages, v_pages
     T, Hq, D = q.shape
-    N, ps, Hk, _ = k_pages.shape
+    _, ps, folded = k_pages.shape          # stored layout: Hk·D in lanes
+    Hk = folded // D
     S, P = page_tables.shape
     TT = token_tile
     if T % TT:
@@ -340,11 +341,6 @@ def _ragged_call(
          jnp.maximum(tile_hi, tile_lo).astype(jnp.int32)], axis=1
     )                                                      # [nT, 2]
 
-    # Fold heads into lanes: [N, ps, Hk·D] keeps DMA slices 128-aligned
-    # for any head_dim (contiguous reshape — decode-kernel layout).
-    k_pages = k_pages.reshape(N, ps, Hk * D)
-    v_pages = v_pages.reshape(N, ps, Hk * D)
-
     kernel = functools.partial(
         _ragged_kernel,
         scale=scale,
@@ -363,8 +359,8 @@ def _ragged_call(
         any_spec,
     ]
     scratch = [
-        pltpu.VMEM((2, G, ps, Hk * D), k_pages.dtype),
-        pltpu.VMEM((2, G, ps, Hk * D), k_pages.dtype),
+        pltpu.VMEM((2, G, ps, folded), k_pages.dtype),
+        pltpu.VMEM((2, G, ps, folded), k_pages.dtype),
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
@@ -424,7 +420,7 @@ def use_ragged_kernel(num_kv_heads: int, head_dim: int) -> bool:
 
 def ragged_gather_attention(
     q: jax.Array,             # [T, Hq, D] flat token stream
-    k_pages,                  # [N, ps, Hk, D] or int8 (values, scales)
+    k_pages,                  # [N, ps, Hk·D] or int8 (values, scales)
     v_pages,
     token_tables: jax.Array,  # [T, P] int32 — each token's table row
     q_positions: jax.Array,   # [T] int32 absolute positions
@@ -451,7 +447,7 @@ def ragged_gather_attention(
 
 def ragged_paged_attention(
     q: jax.Array,             # [T, Hq, D] flat token stream (tile-padded)
-    k_pages,                  # [N, ps, Hk, D] or int8 (values, scales)
+    k_pages,                  # [N, ps, Hk·D] or int8 (values, scales)
     v_pages,
     page_tables: jax.Array,   # [S, P] int32 per-sequence tables
     seq_starts: jax.Array,    # [S] int32 row range starts (ascending)
@@ -473,7 +469,8 @@ def ragged_paged_attention(
     them)."""
     quantized = isinstance(k_pages, tuple)
     data_pool = k_pages[0] if quantized else k_pages
-    Hk, D = data_pool.shape[2], data_pool.shape[3]
+    D = q.shape[2]
+    Hk = data_pool.shape[2] // D
     if window is None:
         win = jnp.zeros((1,), jnp.int32)
     else:

@@ -207,19 +207,15 @@ def _put_layer_jit(treedef, shardings: tuple):
 
 
 def paged_kv_sharding(mesh: Mesh) -> NamedSharding:
-    """Page pools [L, N, page_size, Hk, D]: heads shard over tp.
+    """Page pools [L, N, page_size, Hk·D] (stored layout: engine/kv_cache.py)
+    and the int8-KV scale pools [L, N, page_size, Hk]: the last dimension
+    shards over tp. Heads are major in the fold, so a shard is Hk/tp whole
+    heads in both.
 
     Pages are *not* dp-sharded: any decode slot may hold any page, so the
     pool replicates over dp (each dp replica serves its own slot subset with
     its own pool in the dp>1 serving layout).
     """
-    return NamedSharding(mesh, P("pp", None, None, "tp", None))
-
-
-def paged_kv_scale_sharding(mesh: Mesh) -> NamedSharding:
-    """int8-KV scale pools [L, N, page_size, Hk]: same placement as the
-    data pools (paged_kv_sharding) with the head axis LAST — kept beside
-    it so the two specs cannot drift apart."""
     return NamedSharding(mesh, P("pp", None, None, "tp"))
 
 

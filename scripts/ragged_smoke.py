@@ -49,8 +49,9 @@ def kernel_smoke() -> None:
     kv_lens = np.array([37, 20, 5, 48], np.int32)
     starts = np.concatenate([[0], np.cumsum(seq_lens)[:-1]]).astype(np.int32)
     T = 24
-    kp = jnp.asarray(rng.normal(size=(N, ps, Hk, D)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(N, ps, Hk, D)), jnp.float32)
+    # Pools in the stored layout (engine/kv_cache.py): heads folded.
+    kp = jnp.asarray(rng.normal(size=(N, ps, Hk * D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(N, ps, Hk * D)), jnp.float32)
     tables = rng.integers(1, N, size=(4, P)).astype(np.int32)
     q = jnp.asarray(rng.normal(size=(T, Hq, D)), jnp.float32)
     rows = np.arange(T)
@@ -75,8 +76,9 @@ def kernel_smoke() -> None:
     assert err < 2e-5, f"ragged kernel vs gather: max err {err}"
     log(f"kernel fp parity OK (max err {err:.2e})")
 
-    k8, ks = quantize_kv_rows(kp)
-    v8, vs = quantize_kv_rows(vp)
+    k8, ks = quantize_kv_rows(kp.reshape(N, ps, Hk, D))
+    v8, vs = quantize_kv_rows(vp.reshape(N, ps, Hk, D))
+    k8, v8 = k8.reshape(kp.shape), v8.reshape(vp.shape)
     out_q = ragged_paged_attention(
         q, (k8, ks), (v8, vs), jnp.asarray(tables), jnp.asarray(starts),
         jnp.asarray(seq_lens), jnp.asarray(kv_lens),

@@ -87,8 +87,9 @@ def paged_inputs(B, Hq, Hk, D, P, dtype, seed=0):
     N = B * P + 1
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (B, 1, Hq, D), dtype)
-    kp = jax.random.normal(kk, (N, PS, Hk, D), dtype)
-    vp = jax.random.normal(kv, (N, PS, Hk, D), dtype)
+    # Pools in the stored layout (engine/kv_cache.py): heads folded.
+    kp = jax.random.normal(kk, (N, PS, Hk * D), dtype)
+    vp = jax.random.normal(kv, (N, PS, Hk * D), dtype)
     positions = np.linspace(5, P * PS - 1, B).astype(np.int32).reshape(B, 1)
     tables = np.zeros((B, P), np.int32)
     page = 1
@@ -99,11 +100,18 @@ def paged_inputs(B, Hq, Hk, D, P, dtype, seed=0):
     return q, kp, vp, jnp.asarray(tables), jnp.asarray(positions)
 
 
+def quantized_pool(pool, D):
+    """A folded fp pool [N, PS, Hk·D] as the int8 (values, scales) pair:
+    values folded alike, scales [N, PS, Hk]."""
+    from polykey_tpu.engine.kv_cache import fold_heads, unfold_heads
+    from polykey_tpu.ops.paged_attention import quantize_kv_rows
+
+    values, scales = quantize_kv_rows(unfold_heads(pool, D))
+    return fold_heads(values), scales
+
+
 def check_decode(quantized: bool) -> None:
-    from polykey_tpu.ops.paged_attention import (
-        paged_attention,
-        quantize_kv_rows,
-    )
+    from polykey_tpu.ops.paged_attention import paged_attention
     from polykey_tpu.ops.paged_attention_kernel import paged_attention_decode
 
     for label, Hq, Hk, D, softcap, window in GEOMETRIES:
@@ -118,8 +126,8 @@ def check_decode(quantized: bool) -> None:
                 q, kp, vp, tables, positions, **KERNEL, **kw)
             return assert_close(got, want, 8e-2)
 
-        def int8(kp=kp, vp=vp, kw=kw):
-            kq, vq = quantize_kv_rows(kp), quantize_kv_rows(vp)
+        def int8(kp=kp, vp=vp, kw=kw, D=D):
+            kq, vq = quantized_pool(kp, D), quantized_pool(vp, D)
             want = paged_attention(q, kq, vq, tables, positions, **kw)
             got = paged_attention_decode(
                 q, kq, vq, tables, positions, **KERNEL, **kw)
@@ -174,26 +182,25 @@ def check_write(quantized: bool) -> None:
             pools, rows, page_ids, offsets,
             interpret=KERNEL.get("interpret", False))
         for pool, row, out in zip(pools, rows, got):
-            want = pool.at[page_ids, offsets].set(
-                row.reshape(LANES, *row.shape[2:]))
+            want = pool.at[page_ids, offsets].set(row[:, 0])
             if not bool(jnp.array_equal(out, want)):
                 raise AssertionError("written pool differs from the scatter")
         return "equal"
 
     for label, _, Hk, D, _, _ in GEOMETRIES:
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
-        kp = jax.random.normal(k1, (N, PS, Hk, D), jnp.bfloat16)
-        kn = jax.random.normal(k2, (LANES, 1, Hk, D), jnp.bfloat16)
+        kp = jax.random.normal(k1, (N, PS, Hk * D), jnp.bfloat16)
+        kn = jax.random.normal(k2, (LANES, 1, Hk * D), jnp.bfloat16)
 
         def fp(kp=kp, kn=kn):
             return compare([kp, kp * 0.5], [kn, kn + 1])
 
         def int8(Hk=Hk, D=D, k3=k3, k2=k2):
             k8p = jnp.asarray(np.random.default_rng(1).integers(
-                -127, 128, (N, PS, Hk, D)), jnp.int8)
+                -127, 128, (N, PS, Hk * D)), jnp.int8)
             ksp = jax.random.normal(k3, (N, PS, Hk), jnp.bfloat16)
             k8r = jnp.asarray(np.random.default_rng(2).integers(
-                -127, 128, (LANES, 1, Hk, D)), jnp.int8)
+                -127, 128, (LANES, 1, Hk * D)), jnp.int8)
             ksr = jax.random.normal(k2, (LANES, 1, Hk), jnp.bfloat16)
             return compare([k8p, -k8p, ksp, ksp * 0.5],
                            [k8r, -k8r, ksr, ksr + 1])
@@ -206,7 +213,6 @@ def check_ragged() -> None:
     """Flat streams at the 8B and 1B head shapes: 32 decode singles, one
     512-token prefill range, and both at once (the engine's B + W
     layout, decode rows first)."""
-    from polykey_tpu.ops.paged_attention import quantize_kv_rows
     from polykey_tpu.ops.ragged_paged_attention_kernel import (
         ragged_gather_attention,
         ragged_paged_attention,
@@ -228,8 +234,8 @@ def check_ragged() -> None:
                 N = S * TABLE + 1
                 kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
                 q = jax.random.normal(kq, (T, Hq, D), jnp.bfloat16)
-                kp = jax.random.normal(kk, (N, PS, Hk, D), jnp.bfloat16)
-                vp = jax.random.normal(kv, (N, PS, Hk, D), jnp.bfloat16)
+                kp = jax.random.normal(kk, (N, PS, Hk * D), jnp.bfloat16)
+                vp = jax.random.normal(kv, (N, PS, Hk * D), jnp.bfloat16)
                 tables = (1 + np.arange(S * TABLE, dtype=np.int32)
                           ).reshape(S, TABLE)
                 starts = np.arange(S, dtype=np.int32)
@@ -247,7 +253,7 @@ def check_ragged() -> None:
                 ]).astype(np.int32)
                 pools = (kp, vp)
                 if quantized:
-                    pools = (quantize_kv_rows(kp), quantize_kv_rows(vp))
+                    pools = (quantized_pool(kp, D), quantized_pool(vp, D))
                 kw = dict(scale=D ** -0.5)
                 # The reference gathers a whole 4k window per token:
                 # 64 tokens at a time keeps it inside HBM.

@@ -75,8 +75,9 @@ def test_flash_fallback_off_tpu_matches():
 def _paged_case(B, Hq, Hk, D, ps, P, positions):
     N = B * P + 1
     q = jax.random.normal(jax.random.PRNGKey(0), (B, 1, Hq, D), jnp.float32)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (N, ps, Hk, D), jnp.float32)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (N, ps, Hk, D), jnp.float32)
+    # Pools in the stored layout (engine/kv_cache.py): heads folded.
+    kp = jax.random.normal(jax.random.PRNGKey(1), (N, ps, Hk * D), jnp.float32)
+    vp = jax.random.normal(jax.random.PRNGKey(2), (N, ps, Hk * D), jnp.float32)
     pts = np.zeros((B, P), np.int32)
     page = 1
     for b in range(B):
@@ -151,8 +152,8 @@ def test_paged_decode_kernel_shard_mapped_on_mesh():
     ref = paged_attention(q, kp, vp, pt, pos, scale=0.125)
 
     q_s = jax.device_put(q, NamedSharding(mesh, P("dp", None, "tp", None)))
-    kp_s = jax.device_put(kp, NamedSharding(mesh, P(None, None, "tp", None)))
-    vp_s = jax.device_put(vp, NamedSharding(mesh, P(None, None, "tp", None)))
+    kp_s = jax.device_put(kp, NamedSharding(mesh, P(None, None, "tp")))
+    vp_s = jax.device_put(vp, NamedSharding(mesh, P(None, None, "tp")))
     pt_s = jax.device_put(pt, NamedSharding(mesh, P("dp", None)))
     pos_s = jax.device_put(pos, NamedSharding(mesh, P("dp", None)))
 
@@ -188,9 +189,9 @@ def test_paged_decode_kernel_context_parallel(softcap, win):
 
     rep = NamedSharding(mesh, P())
     out = paged_attention_decode(
-        jax.device_put(q, NamedSharding(mesh, P(None, None, "tp", None))),
-        jax.device_put(kp, NamedSharding(mesh, P(None, None, "tp", None))),
-        jax.device_put(vp, NamedSharding(mesh, P(None, None, "tp", None))),
+        jax.device_put(q, NamedSharding(mesh, P(None, None, "tp"))),
+        jax.device_put(kp, NamedSharding(mesh, P(None, None, "tp"))),
+        jax.device_put(vp, NamedSharding(mesh, P(None, None, "tp"))),
         jax.device_put(pt, rep), jax.device_put(pos, rep),
         scale=0.125, logit_softcap=softcap, window=w,
         interpret=True, mesh=mesh,
@@ -220,8 +221,8 @@ def test_flash_kernel_shard_mapped_on_mesh():
     )
 
     q_s = jax.device_put(q, NamedSharding(mesh, P(None, "sp", "tp", None)))
-    k_s = jax.device_put(k, NamedSharding(mesh, P(None, None, "tp", None)))
-    v_s = jax.device_put(v, NamedSharding(mesh, P(None, None, "tp", None)))
+    k_s = jax.device_put(k, NamedSharding(mesh, P(None, None, "tp")))
+    v_s = jax.device_put(v, NamedSharding(mesh, P(None, None, "tp")))
     pos_s = jax.device_put(qpos, NamedSharding(mesh, P(None, "sp")))
 
     out = flash_attention(
@@ -293,9 +294,9 @@ def test_pp_mesh_routes_to_gather_path(monkeypatch):
         "polykey_tpu.ops.paged_attention.paged_attention", spy
     )
     out = pak.paged_attention_decode(
-        jax.device_put(q, NamedSharding(mesh, P_(None, None, "tp", None))),
-        jax.device_put(kp, NamedSharding(mesh, P_(None, None, "tp", None))),
-        jax.device_put(vp, NamedSharding(mesh, P_(None, None, "tp", None))),
+        jax.device_put(q, NamedSharding(mesh, P_(None, None, "tp"))),
+        jax.device_put(kp, NamedSharding(mesh, P_(None, None, "tp"))),
+        jax.device_put(vp, NamedSharding(mesh, P_(None, None, "tp"))),
         jax.device_put(pt, NamedSharding(mesh, P_())),
         jax.device_put(pos, NamedSharding(mesh, P_())),
         scale=0.125, interpret=True, mesh=mesh,
@@ -311,14 +312,15 @@ def test_paged_decode_kernel_quantized_matches_gather(win):
     the V side) vs the quantized gather path. Both dequantize with the
     same stored bf16 scales, so agreement is fp-tolerance, not
     quantization-tolerance."""
+    from polykey_tpu.engine.kv_cache import fold_heads, unfold_heads
     from polykey_tpu.ops.paged_attention import quantize_kv_rows
 
     q, kp, vp, pt, pos = _paged_case(
         4, 8, 2, 64, 16, 8, [[5], [37], [63], [100]]
     )
-    k8, ks = quantize_kv_rows(kp)
-    v8, vs = quantize_kv_rows(vp)
-    kq, vq = (k8, ks), (v8, vs)
+    k8, ks = quantize_kv_rows(unfold_heads(kp, 64))
+    v8, vs = quantize_kv_rows(unfold_heads(vp, 64))
+    kq, vq = (fold_heads(k8), ks), (fold_heads(v8), vs)
     ref = paged_attention(q, kq, vq, pt, pos, scale=0.125,
                           window=None if win is None else jnp.int32(win))
     out = paged_attention_decode(

@@ -8,6 +8,7 @@ import pytest
 from polykey_tpu.engine.kv_cache import (
     AllocationError,
     BlockAllocator,
+    fold_heads,
     init_paged_kv,
 )
 from polykey_tpu.models.config import TINY_LLAMA
@@ -75,14 +76,14 @@ def test_unique_pages(allocator_factory):
 
 def test_paged_write_and_gather_roundtrip():
     Hk, D, page_size = 2, 4, 4
-    pools = jnp.zeros((8, page_size, Hk, D), dtype=jnp.float32)
+    pools = jnp.zeros((8, page_size, Hk * D), dtype=jnp.float32)
     # One sequence using pages [3, 5]: positions 0..7.
     page_tables = jnp.array([[3, 5]], dtype=jnp.int32)
     positions = jnp.arange(8, dtype=jnp.int32)[None, :]
     k_new = jax.random.normal(jax.random.PRNGKey(0), (1, 8, Hk, D))
     v_new = jax.random.normal(jax.random.PRNGKey(1), (1, 8, Hk, D))
     k_pages, v_pages = paged_write(pools, pools, k_new, v_new, page_tables, positions)
-    k_out, v_out = paged_gather_kv(k_pages, v_pages, page_tables)
+    k_out, v_out = paged_gather_kv(k_pages, v_pages, page_tables, D)
     np.testing.assert_allclose(np.asarray(k_out[0]), np.asarray(k_new[0]))
     np.testing.assert_allclose(np.asarray(v_out[0]), np.asarray(v_new[0]))
 
@@ -147,8 +148,8 @@ def _scatter_reference(k_pages, v_pages, k_new, v_new, page_tables, positions):
     page_ids = page_tables[bi, positions // ps]
     offsets = positions % ps
     return (
-        k_pages.at[page_ids, offsets].set(k_new),
-        v_pages.at[page_ids, offsets].set(v_new),
+        k_pages.at[page_ids, offsets].set(fold_heads(k_new)),
+        v_pages.at[page_ids, offsets].set(fold_heads(v_new)),
     )
 
 
@@ -199,7 +200,7 @@ def test_paged_write_decode_kernel_interpret_matches_scatter():
     """The Pallas DMA write kernel (interpret mode on CPU) must match the
     scatter for a decode step, including the garbage-page-0 convention
     (inactive lanes all target page 0 — any value may land there)."""
-    from polykey_tpu.ops.paged_write_kernel import paged_write_decode_kernel
+    from polykey_tpu.ops.paged_write_kernel import paged_write_rows_kernel
 
     B, P = 4, 3
     start = np.array([5, 16, 31, 47])
@@ -208,8 +209,9 @@ def test_paged_write_decode_kernel_interpret_matches_scatter():
     bi = jnp.arange(B, dtype=jnp.int32)[:, None]
     page_ids = pt[bi, pos // ps][:, 0]
     offsets = (pos % ps)[:, 0]
-    got_k, got_v = paged_write_decode_kernel(
-        kp, vp, kn, vn, page_ids, offsets, interpret=True
+    got_k, got_v = paged_write_rows_kernel(
+        [kp, vp], [fold_heads(kn), fold_heads(vn)], page_ids, offsets,
+        interpret=True,
     )
     want_k, want_v = _scatter_reference(kp, vp, kn, vn, pt, pos)
     np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
@@ -242,7 +244,8 @@ def test_paged_write_mesh_kernel_path_matches_scatter(monkeypatch):
         partial(pwk.paged_write_rows_kernel, interpret=True),
     )
     got_k, got_v = pa._write_decode_kernel(
-        [(kp, kn), (vp, vn)], page_ids, offsets, mesh
+        [(kp, fold_heads(kn)), (vp, fold_heads(vn))], page_ids, offsets,
+        mesh, TINY_LLAMA.num_kv_heads,
     )
     want_k, want_v = _scatter_reference(kp, vp, kn, vn, pt, pos)
     np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
@@ -312,11 +315,11 @@ def test_paged_write_rows_kernel_with_scale_pools():
     B, P, ps, Hk, D = 4, 3, 16, 4, 32
     N = 1 + B * P
     rng = np.random.default_rng(5)
-    kq = jnp.asarray(rng.integers(-127, 128, (N, ps, Hk, D)), jnp.int8)
+    kq = jnp.asarray(rng.integers(-127, 128, (N, ps, Hk * D)), jnp.int8)
     vq = kq * -1
     ks = jnp.asarray(rng.normal(size=(N, ps, Hk)), jnp.bfloat16)
     vs = ks + 1
-    k8 = jnp.asarray(rng.integers(-127, 128, (B, 1, Hk, D)), jnp.int8)
+    k8 = jnp.asarray(rng.integers(-127, 128, (B, 1, Hk * D)), jnp.int8)
     v8 = -k8
     ksr = jnp.asarray(rng.normal(size=(B, 1, Hk)), jnp.bfloat16)
     vsr = ksr * 2
@@ -328,6 +331,5 @@ def test_paged_write_rows_kernel_with_scale_pools():
         interpret=True,
     )
     for pool, rows, got in zip([kq, vq, ks, vs], [k8, v8, ksr, vsr], outs):
-        want = pool.at[page_ids, offsets].set(
-            rows.reshape(B, *rows.shape[2:]))
+        want = pool.at[page_ids, offsets].set(rows[:, 0])
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

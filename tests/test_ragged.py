@@ -60,8 +60,9 @@ def _ragged_case(seed, seq_lens, kv_lens, *, N=32, ps=8, Hk=2, Hq=4,
     starts = np.concatenate([[0], np.cumsum(seq_lens)[:-1]]).astype(np.int32)
     used = int(seq_lens.sum())
     T = -(-used // pad_to) * pad_to
-    kp = jnp.asarray(rng.normal(size=(N, ps, Hk, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(N, ps, Hk, D)), dtype)
+    # Pools in the stored layout (engine/kv_cache.py): heads folded.
+    kp = jnp.asarray(rng.normal(size=(N, ps, Hk * D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(N, ps, Hk * D)), dtype)
     tables = rng.integers(1, N, size=(S, P)).astype(np.int32)
     q = jnp.asarray(rng.normal(size=(T, Hq, D)), dtype)
     rows = np.arange(T)
@@ -139,8 +140,11 @@ def test_ragged_kernel_quantized_matches_gather():
     the int8 gather path tightly, and the fp gather loosely (bounded
     quantization error)."""
     case = _ragged_case(6, seq_lens=[1, 11, 4], kv_lens=[37, 20, 30])
-    k8, ks = quantize_kv_rows(case["kp"])
-    v8, vs = quantize_kv_rows(case["vp"])
+    from polykey_tpu.engine.kv_cache import fold_heads, unfold_heads
+
+    k8, ks = quantize_kv_rows(unfold_heads(case["kp"], 32))
+    v8, vs = quantize_kv_rows(unfold_heads(case["vp"], 32))
+    k8, v8 = fold_heads(k8), fold_heads(v8)
     out_k = ragged_paged_attention(
         case["q"], (k8, ks), (v8, vs), case["tables"],
         case["starts"], case["lens"], case["kvs"],
