@@ -11,8 +11,7 @@ BUILD_DIR := build
 	kernel-check chip-smoke bench-tokenizer metrics-smoke \
 	obs-smoke chaos-smoke print-chaos occupancy-smoke occupancy-soak \
 	failover-smoke failover-soak timeline-capture perf-gate \
-	perf-gate-reference flightwatch ragged-smoke ragged-soak \
-	spec-smoke \
+	perf-gate-reference flightwatch spec-smoke \
 	disagg-smoke disagg-soak hostkv-smoke hostkv-soak \
 	autopilot-smoke autopilot-soak \
 	postmortem postmortem-smoke
@@ -114,17 +113,10 @@ occupancy-smoke: ## Poisson-load occupancy soak at CI scale (gated >= 0.7 + sche
 	$(PYTHON) -m polykey_tpu.analysis sched --only SL006 \
 	  --witness /tmp/polykey-sched-witness-occupancy
 
-# Ragged dispatch (ISSUE 12): the interpret-mode kernel path (fp +
-# int8) and the engine's greedy bit-identity vs the bucketed path are
-# exercised on every commit; the A/B soak below is the padding-waste
-# acceptance measurement.
-ragged-smoke: ## Ragged kernel interpret parity + engine bit-identity vs bucketed
-	JAX_PLATFORMS=cpu $(PYTHON) scripts/ragged_smoke.py
-
 # Speculative rounds (ISSUE 19): the fused accept/merge core's
-# jit-vs-eager parity plus engine greedy bit-identity across plain,
-# spec-on-bucketed, and spec-on-ragged at lookahead depths 1 and 2.
-spec-smoke: ## Accept/merge interpret parity + spec-on-ragged bit-identity vs bucketed/plain
+# jit-vs-eager parity plus engine greedy bit-identity between the plain
+# and the speculative engine at lookahead depths 1 and 2.
+spec-smoke: ## Accept/merge interpret parity + spec bit-identity vs plain
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/spec_smoke.py
 
 # Host-memory KV tier (ISSUE 15): sticky multi-turn sessions at 1.5x
@@ -146,11 +138,6 @@ hostkv-soak: ## The 12-session / 4-turn acceptance drill (writes perf/)
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/occupancy_soak.py --host-kv \
 	  --slots 8 \
 	  --out perf/hostkv_soak_$$(date -u +%Y%m%d_%H%M%S).json
-
-ragged-soak: ## 48-slot A/B soak: bucketed vs ragged padding waste (writes perf/)
-	JAX_PLATFORMS=cpu $(PYTHON) scripts/occupancy_soak.py \
-	  --slots 48 --duration 45 --ramp 15 --ab-ragged --min-occupancy 0.7 \
-	  --out perf/ragged_soak_$$(date -u +%Y%m%d_%H%M%S).json
 
 # Timestamped output so a rerun never clobbers a committed, cited
 # acceptance artifact (the script's date-only default would).
@@ -328,8 +315,8 @@ memlint: ## Memory & capacity contract analysis (stdlib-only)
 # The fifth analysis tier (ISSUE 20): scheduler liveness & fairness
 # contracts — progress floors on budget-bounded dispatch loops (SL001),
 # round-robin cursor discipline with starved-first re-anchoring
-# (SL002), restore→prefill→decode frontier ordering (SL003),
-# bounded-wait queues (SL004), and ragged quota conservation (SL005).
+# (SL002), restore→prefill→decode frontier ordering (SL003), and
+# bounded-wait queues (SL004).
 # Stdlib-only AST; the runtime starvation witness (SL006) rides
 # occupancy-smoke, disagg-smoke, and autopilot-smoke.
 schedlint: ## Scheduler liveness & fairness contract analysis (stdlib-only)
@@ -368,7 +355,7 @@ scan: ## Security scan (Trivy fs over the tree + lockfile, CRITICAL/HIGH gate)
 	  --scanners vuln,secret \
 	  --severity CRITICAL,HIGH
 
-ci-check: ## Run the CI pipeline locally: lint+polylint+racelint+graphlint+memlint+schedlint, chaos, failover, disagg(+lock/heap/sched-witness gates), postmortem, occupancy(+sched-witness gate), ragged, hostkv(+heap-witness gate), autopilot(+analysis-all gate), obs, perf-gate, tests, native(+asan), scan
+ci-check: ## Run the CI pipeline locally: lint+polylint+racelint+graphlint+memlint+schedlint, chaos, failover, disagg(+lock/heap/sched-witness gates), postmortem, occupancy(+sched-witness gate), spec, hostkv(+heap-witness gate), autopilot(+analysis-all gate), obs, perf-gate, tests, native(+asan), scan
 	@$(MAKE) lint
 	@$(MAKE) racelint
 	@$(MAKE) graphlint
@@ -379,7 +366,6 @@ ci-check: ## Run the CI pipeline locally: lint+polylint+racelint+graphlint+memli
 	@$(MAKE) disagg-smoke
 	@$(MAKE) postmortem-smoke
 	@$(MAKE) occupancy-smoke
-	@$(MAKE) ragged-smoke
 	@$(MAKE) spec-smoke
 	@$(MAKE) hostkv-smoke
 	@$(MAKE) autopilot-smoke

@@ -329,9 +329,8 @@ def bench_engine(
             "total_tokens": total_tokens,
             "elapsed_s": round(elapsed, 2),
             # Padding-waste accounting over the saturated window (ISSUE
-            # 12), first-class: token rows computed vs useful — the
-            # ratio the ragged dispatch raises (bucket/pad-group padding
-            # on the bucketed path, dead decode lanes on both).
+            # 12), first-class: token rows computed vs useful
+            # (bucket/pad-group padding, dead decode lanes).
             "tokens_dispatched": sat_dispatched,
             "tokens_useful": sat_useful,
             "tokens_useful_fraction": (

@@ -56,9 +56,8 @@ empty, mandatory-reason suppressions, content-hashed fingerprints):
   budget-bounded dispatch loop has a statically provable progress
   floor, every round-robin cursor advances or re-anchors
   (starved-first) on every consumption path, the restore→prefill→
-  decode frontier order holds per iteration, consumed queues pair with
-  an admission bound or shed path, and ragged per-range accounting
-  sums exactly to the dispatch width. Stdlib-only, with an opt-in
+  decode frontier order holds per iteration, and consumed queues pair
+  with an admission bound or shed path. Stdlib-only, with an opt-in
   runtime starvation witness (``schedwitness.py``,
   POLYKEY_SCHED_WITNESS=1) that records per-slot wait ages and
   consecutive-skip counts at dispatch boundaries and merges them into

@@ -25,9 +25,8 @@ Stdlib-only like this tier.
 ``python -m polykey_tpu.analysis sched`` dispatches to the fifth tier
 (schedlint, analysis/sched.py): scheduler liveness & fairness contracts
 — progress floors on budget-bounded dispatch loops, round-robin cursor
-discipline, frontier ordering, bounded-wait queues, ragged quota
-conservation, and the runtime starvation-witness merge. Stdlib-only
-like this tier.
+discipline, frontier ordering, bounded-wait queues, and the runtime
+starvation-witness merge. Stdlib-only like this tier.
 
 ``python -m polykey_tpu.analysis all`` runs all five tiers with one
 aggregate exit code (and one merged JSON object under ``--json``).

@@ -542,8 +542,6 @@ class GraphEnv:
         self.logs: list[str] = []
         self._plain = None
         self._spec = None
-        self._ragged = None
-        self._spec_ragged = None
         self._hostkv = None
         self._bf16 = None
         self._train = None
@@ -608,45 +606,6 @@ class GraphEnv:
             self._bf16 = InferenceEngine(config)
         return self._bf16
 
-    def ragged_engine(self):
-        """Warmed CPU engine with the ragged dispatch path on (ISSUE
-        12): same geometry as the plain engine, so GL001 can compare
-        the two executable censuses directly — the ragged path's whole
-        claim is that the per-bucket prefill variants collapse into one
-        resident executable."""
-        if self._ragged is None:
-            import dataclasses
-
-            from ..engine.engine import InferenceEngine
-
-            self.logs.append("building ragged CPU engine (warmup)")
-            config = dataclasses.replace(
-                self._base_config(), ragged_dispatch=True,
-            )
-            self._ragged = InferenceEngine(config)
-        return self._ragged
-
-    def spec_ragged_engine(self):
-        """Warmed CPU engine with BOTH the draft model and the ragged
-        dispatch path on (ISSUE 19): the unified spec×ragged path's
-        whole claim is that gamma-token verify windows ride the flat
-        stream as ordinary ranges — GL001 asserts the path adds zero
-        post-warmup executables at both lookahead depths, and GL004's
-        census pins its sanctioned-crossing set to the bucketed spec
-        engine's (the unification must not mint new crossings)."""
-        if self._spec_ragged is None:
-            import dataclasses
-
-            from ..engine.engine import InferenceEngine
-
-            self.logs.append("building spec x ragged CPU engine (warmup)")
-            config = dataclasses.replace(
-                self._base_config(), draft_model="tiny-llama",
-                spec_gamma=2, ragged_dispatch=True,
-            )
-            self._spec_ragged = InferenceEngine(config)
-        return self._spec_ragged
-
     def hostkv_engine(self):
         """Warmed CPU engine with the host KV tier active (ISSUE 15):
         a deliberately TIGHT device pool + an aggressive resident
@@ -675,8 +634,6 @@ class GraphEnv:
         yield "engine.plain", self.plain_engine()
         if self.profile != "smoke":
             yield "engine.spec", self.spec_engine()
-            yield "engine.ragged", self.ragged_engine()
-            yield "engine.spec_ragged", self.spec_ragged_engine()
             yield "engine.hostkv", self.hostkv_engine()
 
     def jit_handles(self, engine) -> dict[str, object]:
@@ -689,25 +646,6 @@ class GraphEnv:
         if engine._spec:
             handles["_jit_spec_prefill"] = engine._jit_spec_prefill
             handles["_jit_spec_decode"] = engine._jit_spec_decode
-        if engine._ragged:
-            # The bucketed prefill handle is deliberately never compiled
-            # in ragged mode — it is census-asserted EMPTY instead (the
-            # cold-handle check would misread an intentional zero).
-            del handles["_jit_prefill"]
-            handles["_jit_ragged"] = engine._jit_ragged
-            if engine._spec:
-                # Unified path (ISSUE 19): admissions ride the ragged
-                # stream, so the bucketed spec prefill never compiles
-                # either (census-watched like _jit_prefill); the plain
-                # ragged handle only holds the gate-fail fallback, which
-                # is warmed (and reachable) only without the top-p
-                # prefilter on sampled-warm builds.
-                del handles["_jit_spec_prefill"]
-                handles["_jit_ragged_spec"] = engine._jit_ragged_spec
-                cfg = engine.config
-                if not (cfg.warm_sampled_variants
-                        and cfg.top_p_candidates == 0):
-                    del handles["_jit_ragged"]
         if engine._host_kv is not None:
             # The host tier's fixed-width gather/scatter pair (ISSUE
             # 15): warmed at construction, and a spill or page fault
@@ -817,52 +755,18 @@ class GraphEnv:
                 # The per-lane gamma dial donates alongside the slot
                 # state (ISSUE 19): it advances on device every round.
                 dial = (dev["accept_ewma"], dev["gamma_lane"])
-                if engine._ragged:
-                    # Unified path: the prefill site IS the mixed
-                    # spec×ragged dispatch — audit ITS donations (both
-                    # pools + slot state + dial) instead of the bucketed
-                    # spec prefill it never compiles.
-                    from ..engine.engine import ragged_zero_operands
-
-                    B = cfg.max_decode_slots
-                    gmax = engine._gamma_max
-                    pre = ragged_zero_operands(
-                        B, engine._ragged_spec_width[gmax],
-                        cfg.pages_per_seq,
-                    )
-                    yield (
-                        f"{engine_label}._jit_ragged_spec",
-                        partial(
-                            engine._jit_ragged_spec.lower,
-                            engine.params, engine.draft_params,
-                            engine.model_cfg, engine.draft_cfg,
-                            engine.paged, engine.d_paged,
-                            dev["last_tokens"], dev["seq_lens"],
-                            dev["page_tables"], dev["active"],
-                            dev["caps"], dev["seeds"],
-                            dev["temperature"], dev["top_p"],
-                            dev["top_k"], *dial, *pre,
-                            gamma=gmax,
-                            eos_id=engine.tokenizer.eos_id,
-                            gamma_low=engine._gamma_low,
-                            gamma_max=engine._gamma_max,
-                            greedy=True, candidates=0, mesh=engine.mesh,
-                        ),
-                        count_big_leaves((pools, slot_state, dial)),
-                    )
-                else:
-                    yield (
-                        f"{engine_label}._jit_spec_prefill",
-                        partial(
-                            engine._jit_spec_prefill.lower,
-                            engine.params, engine.draft_params,
-                            engine.model_cfg, engine.draft_cfg,
-                            engine.paged, engine.d_paged, *window,
-                            greedy=True, candidates=cfg.top_p_candidates,
-                            mesh=engine.mesh,
-                        ),
-                        count_big_leaves(pools),
-                    )
+                yield (
+                    f"{engine_label}._jit_spec_prefill",
+                    partial(
+                        engine._jit_spec_prefill.lower,
+                        engine.params, engine.draft_params,
+                        engine.model_cfg, engine.draft_cfg,
+                        engine.paged, engine.d_paged, *window,
+                        greedy=True, candidates=cfg.top_p_candidates,
+                        mesh=engine.mesh,
+                    ),
+                    count_big_leaves(pools),
+                )
                 yield (
                     f"{engine_label}._jit_spec_decode",
                     partial(
@@ -881,29 +785,6 @@ class GraphEnv:
                         candidates=0, mesh=engine.mesh,
                     ),
                     count_big_leaves((pools, slot_state, dial)),
-                )
-            elif engine._ragged:
-                # The ragged engine's prefill site IS the mixed ragged
-                # dispatch: audit its donations (pool + slot state)
-                # instead of the bucketed prefill it never compiles.
-                from ..engine.engine import ragged_zero_operands
-
-                B = cfg.max_decode_slots
-                W = engine._ragged_width
-                pre = ragged_zero_operands(B, W, cfg.pages_per_seq)
-                yield (
-                    f"{engine_label}._jit_ragged",
-                    partial(
-                        engine._jit_ragged.lower,
-                        engine.params, engine.model_cfg, engine.paged,
-                        dev["last_tokens"], dev["seq_lens"],
-                        dev["page_tables"], dev["active"], dev["caps"],
-                        dev["seeds"], dev["temperature"], dev["top_p"],
-                        dev["top_k"], *pre,
-                        greedy=True, eos_id=engine.tokenizer.eos_id,
-                        candidates=cfg.top_p_candidates, mesh=engine.mesh,
-                    ),
-                    count_big_leaves((engine.paged, slot_state)),
                 )
             else:
                 yield (
@@ -1015,37 +896,12 @@ class GraphEnv:
               dev["page_tables"], dev["active"], dev["caps"], dev["seeds"],
               dev["temperature"], dev["top_p"], dev["top_k"])
             yield (f"engine.{label}._decode_fn", decode, weight_shapes, bf16)
-            # Ragged mixed dispatch (ISSUE 12): traced at the function
-            # level (no ragged engine needed — the dtype/callback
-            # contracts are properties of the graph, not the warmup).
-            # W pads B+W to the kernel's TOKEN_TILE, same rule as the
-            # engine's _ragged_width — a misaligned stream would trace
-            # only because the gather fallback serves off-TPU, and
-            # would crash the kernel path wherever it engages.
-            from ..engine.engine import ragged_zero_operands
-            from ..ops.ragged_paged_attention_kernel import TOKEN_TILE
-
-            B = cfg.max_decode_slots
-            W = 16 + (-(B + 16)) % TOKEN_TILE
-            pre = ragged_zero_operands(B, W, cfg.pages_per_seq)
-            ragged = jax.make_jaxpr(
-                lambda params, paged, *rest: engine_mod._ragged_fn(
-                    params, model_cfg, paged, *rest,
-                    greedy=False, eos_id=eng.tokenizer.eos_id,
-                    candidates=cfg.top_p_candidates, mesh=mesh,
-                )
-            )(eng.params, eng.paged, dev["last_tokens"], dev["seq_lens"],
-              dev["page_tables"], dev["active"], dev["caps"], dev["seeds"],
-              dev["temperature"], dev["top_p"], dev["top_k"], *pre)
-            yield (f"engine.{label}._ragged_fn", ragged, weight_shapes, bf16)
 
     def close(self) -> None:
-        for engine in (self._plain, self._spec, self._ragged,
-                       self._spec_ragged, self._hostkv, self._bf16):
+        for engine in (self._plain, self._spec, self._hostkv, self._bf16):
             if engine is not None:
                 engine.shutdown()
-        self._plain = self._spec = self._ragged = None
-        self._spec_ragged = self._hostkv = self._bf16 = None
+        self._plain = self._spec = self._hostkv = self._bf16 = None
         self._jaxprs = None
 
 
@@ -1070,7 +926,6 @@ class RecompileStability(GraphCheck):
 
     def run(self, env: GraphEnv) -> list[Finding]:
         findings: list[Finding] = []
-        census: dict = {}
         for label, engine in env.engines():
             handles = env.jit_handles(engine)
             mix = env.request_mix(sampled=engine.config.warm_sampled_variants)
@@ -1093,100 +948,14 @@ class RecompileStability(GraphCheck):
                 finally:
                     e._depth = configured
 
-            if engine._ragged:
-                # The bucketed prefill handle is census-watched ACROSS
-                # the ragged sweep: jit executable caches are shared
-                # between engine instances with identical jit params
-                # (the plain engine's warmup already populated this
-                # one), so "gone" is a delta claim — serving through
-                # the ragged engine must never compile a bucketed
-                # variant — not an absolute-zero claim.
-                prefill_before = engine._jit_prefill._cache_size()
-                if engine._spec:
-                    # Same delta claim for the bucketed spec prefill on
-                    # the unified path (ISSUE 19): admissions ride the
-                    # ragged stream, never the spec prefill buckets.
-                    spec_prefill_before = (
-                        engine._jit_spec_prefill._cache_size()
-                    )
             found, sizes = recompile_findings(label, handles, sweep)
-            if engine._ragged:
-                sizes["_jit_prefill(bucketed)"] = (
-                    prefill_before, engine._jit_prefill._cache_size()
-                )
-                if engine._spec:
-                    sizes["_jit_spec_prefill(bucketed)"] = (
-                        spec_prefill_before,
-                        engine._jit_spec_prefill._cache_size(),
-                    )
             findings.extend(found)
-            census[label] = (engine, sizes)
             env.logs.append(
                 f"GL001 {label} (depths 1+2): " + ", ".join(
                     f"{n}={b}->{a}" for n, (b, a) in sorted(sizes.items())
                 )
             )
-        findings.extend(self.census_findings(census, env))
         return findings
-
-    @staticmethod
-    def census_findings(census: dict, env) -> list[Finding]:
-        """Variant-census comparison (ISSUE 12): with the ragged path
-        on, the per-bucket prefill executables must be GONE (the
-        bucketed handle compiled nothing) and the post-warmup executable
-        census must be STRICTLY smaller than the bucketed engine's at
-        identical geometry — one resident ragged executable replacing
-        buckets × pad-groups × greedy variants."""
-        findings: list[Finding] = []
-        pairs = [
-            # (ragged-mode label, bucketed baseline, watched-gone handles)
-            ("engine.ragged", "engine.plain", ("_jit_prefill",)),
-            ("engine.spec_ragged", "engine.spec",
-             ("_jit_prefill", "_jit_spec_prefill")),
-        ]
-        for ragged_label, plain_label, gone_handles in pairs:
-            if ragged_label not in census or plain_label not in census:
-                continue
-            _, plain_sizes = census[plain_label]
-            _, ragged_sizes = census[ragged_label]
-            watched = []
-            for name in gone_handles:
-                before, after = ragged_sizes.pop(
-                    f"{name}(bucketed)", (0, 0)
-                )
-                watched.append((name, before, after))
-                if after > before:
-                    findings.append(graph_finding(
-                        "GL001", f"graph:{ragged_label}",
-                        f"{ragged_label}:{name}:not-gone",
-                        f"the {ragged_label} engine compiled "
-                        f"{after - before} bucketed {name} "
-                        "executable(s) during its sweep — the ragged "
-                        "path exists to make the per-bucket variants "
-                        "unreachable, so any compile here means a code "
-                        "path leaked back to the bucket table",
-                    ))
-            plain_total = sum(a for _, a in plain_sizes.values())
-            ragged_total = sum(a for _, a in ragged_sizes.values())
-            if ragged_total >= plain_total:
-                findings.append(graph_finding(
-                    "GL001", f"graph:{ragged_label}",
-                    f"{ragged_label}:census-not-smaller",
-                    f"{ragged_label} executable census {ragged_total} "
-                    "is not strictly smaller than the bucketed "
-                    f"{plain_label} engine's {plain_total} at identical "
-                    "geometry — the single resident ragged executable "
-                    "must REPLACE the per-bucket prefill variants, not "
-                    "add to them",
-                ))
-            env.logs.append(
-                f"GL001 census: {plain_label}={plain_total} "
-                f"{ragged_label}={ragged_total} (" + ", ".join(
-                    f"{n} {b}->{a}" for n, b, a in watched
-                ) + ")"
-            )
-        return findings
-
 
 # -- GL002: donation audit ----------------------------------------------------
 
@@ -1265,9 +1034,7 @@ _BASE_CROSSINGS = frozenset({
 })
 SANCTIONED_CROSSINGS: dict[str, frozenset] = {
     "engine.plain": _BASE_CROSSINGS | {"block-packed"},
-    "engine.ragged": _BASE_CROSSINGS | {"block-packed"},
     "engine.spec": _BASE_CROSSINGS | {"spec-packed"},
-    "engine.spec_ragged": _BASE_CROSSINGS | {"spec-packed"},
     "engine.hostkv": _BASE_CROSSINGS | {
         "block-packed", "kv-evict-gather", "kv-fault-restore",
     },
@@ -1521,87 +1288,6 @@ class ShapeLayoutContracts(GraphCheck):
             [((2, Hq, D), "float32"),
              ((2, Hq, 1), "float32"), ((2, Hq, 1), "float32")],
         ))
-        findings.extend(self._ragged_contracts())
-        return findings
-
-    def _ragged_contracts(self) -> list[Finding]:
-        """Ragged kernel (ISSUE 12) geometry/layout contracts, abstract:
-        the mixed-stream kernel traces clean across the served model
-        matrix's (Hk, D) geometries (page-group divisibility included —
-        P deliberately NOT a multiple of G, the ceil arithmetic the
-        grid must handle), the int8-KV variant honors the same output
-        contract, and the token-tile alignment gate has teeth (a
-        misaligned stream must be refused loudly, never silently
-        mis-tiled)."""
-        import jax.numpy as jnp
-
-        from ..models.config import get_config
-        from ..ops import ragged_paged_attention_kernel as ragged_mod
-
-        findings: list[Finding] = []
-        TT = ragged_mod.TOKEN_TILE
-        T, S, P, N, ps = 2 * TT, 4, 5, 16, 8      # P % G != 0 by design
-        starts = jnp.asarray([0, 1, 9, 12], jnp.int32)
-        lens = jnp.asarray([1, 8, 3, 2], jnp.int32)
-        kvs = jnp.asarray([24, 8, 11, 33], jnp.int32)
-        tables = jnp.zeros((S, P), jnp.int32)
-        window = jnp.zeros((1,), jnp.int32)
-        for model in self.MODELS:
-            cfg = get_config(model)
-            Hq, Hk, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-            q = jnp.zeros((T, Hq, D), jnp.float32)
-            kp = jnp.zeros((N, ps, Hk * D), jnp.float32)
-            findings.extend(abstract_contract(
-                f"ops.ragged_paged_attention_kernel[{model}]",
-                lambda *args, D=D: ragged_mod._ragged_call(
-                    *args, scale=D ** -0.5, logit_softcap=None,
-                    interpret=False, pages_per_block=2, token_tile=TT,
-                ),
-                (q, kp, kp, tables, starts, lens, kvs, window),
-                [((T, Hq, D), "float32")],
-            ))
-        # int8-KV variant: (values, scales) pairs, scales [N, ps, Hk].
-        cfg = get_config("tiny-llama")
-        Hq, Hk, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        q = jnp.zeros((T, Hq, D), jnp.bfloat16)
-        kq = jnp.zeros((N, ps, Hk * D), jnp.int8)
-        scales = jnp.zeros((N, ps, Hk), jnp.bfloat16)
-        findings.extend(abstract_contract(
-            "ops.ragged_paged_attention_kernel[int8]",
-            lambda q2, kv, sc, *rest: ragged_mod._ragged_call(
-                q2, (kv, sc), (kv, sc), *rest,
-                scale=D ** -0.5, logit_softcap=None, interpret=False,
-                pages_per_block=2, token_tile=TT,
-            ),
-            (q, kq, scales, tables, starts, lens, kvs, window),
-            [((T, Hq, D), "float32")],
-        ))
-        # Token-tile alignment teeth: T not a multiple of token_tile
-        # must raise — a silently mis-tiled stream would attribute
-        # tokens to the wrong sequences.
-        import jax
-
-        try:
-            jax.eval_shape(
-                lambda *args: ragged_mod._ragged_call(
-                    *args, scale=1.0, logit_softcap=None,
-                    interpret=False, token_tile=TT,
-                ),
-                jnp.zeros((T + 3, Hq, D), jnp.bfloat16),
-                jnp.zeros((N, ps, Hk * D), jnp.bfloat16),
-                jnp.zeros((N, ps, Hk * D), jnp.bfloat16),
-                tables, starts, lens, kvs, window,
-            )
-        except ValueError:
-            pass
-        else:
-            findings.append(graph_finding(
-                "GL005", "graph:ops.ragged_paged_attention_kernel",
-                "ragged:tile-alignment-toothless",
-                "a token stream that is not a multiple of token_tile "
-                "traced clean — the alignment gate lost its teeth and a "
-                "misaligned stream would silently mis-tile",
-            ))
         return findings
 
     def _gate_consistency(self) -> list[Finding]:

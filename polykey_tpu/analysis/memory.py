@@ -19,7 +19,7 @@ byte against the jax-backed allocator):
     pool and its int8 scale planes (``roofline.kv_pool_bytes_split``),
     the draft model's pool under speculation, plus first-order peak
     transients for every warmed jit executable (prefill at the largest
-    bucket, decode/ragged at full slots, spec at gamma+1 positions,
+    bucket, decode at full slots, spec at gamma+1 positions,
     gather/restore staging at one full sequence of pages). Donation
     credits come from the same alias map GL002 audits: executables that
     donate ``paged`` reuse the pool in place, so the ledger counts it
@@ -135,7 +135,6 @@ SERVED_MATRIX: tuple[dict, ...] = (
 DONATED_EXECUTABLES = {
     "prefill": ("paged",),
     "decode": ("paged", "last_tokens", "seq_lens", "active"),
-    "ragged": ("paged",),
     "spec_prefill": ("t_paged", "d_paged"),
     "spec_decode": ("t_paged", "d_paged"),
     "kv_restore": ("paged",),
@@ -289,7 +288,6 @@ def build_ledger(cfg, chip_name: str, n_chips: int,
     transients = {
         "prefill": stream(max_bucket, mcfg) + vocab * 4.0,
         "decode": stream(slots, mcfg) + slots * vocab * 4.0,
-        "ragged": stream(max_bucket + slots, mcfg) + slots * vocab * 4.0,
     }
     if dcfg is not None:
         spec_tokens = slots * (cfg.spec_gamma + 1.0)

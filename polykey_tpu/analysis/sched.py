@@ -2,9 +2,8 @@
 
 The engine loop's scheduling invariants — the interleaved-prefill
 progress floor, the starved-first round-robin cursors, the
-restore→prefill→decode frontier order, deadline-disciplined queues,
-ragged token-range quotas — were enforced only by scattered regression
-tests and comments. ROADMAP item 1 (SLO-class-weighted scheduling) is
+restore→prefill→decode frontier order, deadline-disciplined queues —
+were enforced only by scattered regression tests and comments. ROADMAP item 1 (SLO-class-weighted scheduling) is
 about to multiply every one of them by a traffic-class dimension, so
 this tier turns them into contracts in the ``SL`` namespace alongside
 PL/GL/CL/ML, with the same committed-empty baseline
@@ -33,25 +32,15 @@ PL/GL/CL/ML, with the same committed-empty baseline
     Inside one engine-loop iteration (the ``while not
     self._stop.is_set()`` loop), restores issue before chunked
     prefills, which issue before the decode dispatch — verified from
-    first-call order in the loop body. The ragged batch builder and the
-    chunk advancer must skip faulting slots (``restore_pages is not
-    None`` → continue): a faulting lane joins no dispatch until the
-    restore frontier owns it.
+    first-call order in the loop body. The chunk advancer must skip
+    faulting slots (``restore_pages is not None`` → continue): a
+    faulting lane joins no dispatch until the restore frontier owns it.
 
 ``SL004`` bounded wait
     Every queue/deque a long-lived (lock-holding / serve-loop) class
     consumes must pair with an admission bound (bounded constructor or
     a ``len()``/``qsize()`` comparison) or a shed/deadline-drop path in
     a consuming method — no unboundedly deferrable work class.
-
-``SL005`` quota conservation
-    ``_build_ragged_batch`` must clip every range to the remaining
-    dispatch width (a ``W - spent`` term inside ``min``), charge the
-    budget with exactly the appended range width, and exit on ``>=``
-    (overshoot bounded by one range); ``_ragged_prefill_operands`` must
-    advance its write offset, its useful-token count, and the per-range
-    length vector by the SAME width, so the ranges sum exactly to the
-    dispatch offset.
 
 ``SL006`` observed starvation (``--witness``)
     Merges runtime starvation-witness summaries
@@ -105,18 +94,12 @@ ENGINE_REL = "polykey_tpu/engine/engine.py"
 
 # The engine-loop methods whose first-call order IS the frontier
 # contract: restores ride ahead of chunked prefills, which ride ahead
-# of the decode dispatch (in ragged mode the prefill frontier lives
-# inside _dispatch_step's batch builder — after restores, before the
-# decode lanes of the same flat dispatch, by construction).
+# of the decode dispatch. If the engine renames one the contract is STALE
+# (SL000), not silently green.
 ORDERED_FRONTIERS = (
     "_issue_restores", "_advance_chunked_prefills", "_dispatch_step",
 )
 
-# Functions whose existence the SL003/SL005 contracts anchor on; if the
-# engine renames them the contract is STALE (SL000), not silently green.
-_CONTRACT_ANCHORS = ORDERED_FRONTIERS + (
-    "_build_ragged_batch", "_ragged_prefill_operands",
-)
 
 # SL006 gates. Engine-loop iterations are milliseconds; the progress
 # floor + round-robin bound any eligible slot's wait to ~B iterations,
@@ -501,8 +484,8 @@ class FrontierOrderRule(Rule):
     id = "SL003"
     name = "frontier-ordering"
     description = ("restore -> prefill -> decode issue order per "
-                   "engine-loop iteration; ragged builder and chunk "
-                   "advancer skip faulting slots")
+                   "engine-loop iteration; the chunk advancer skips "
+                   "faulting slots")
 
     def applies(self, rel: str) -> bool:
         return rel.startswith("polykey_tpu/")
@@ -536,8 +519,7 @@ class FrontierOrderRule(Rule):
         if not mentions_restore:
             return
         for fn in _functions(ctx.tree):
-            if fn.name not in ("_build_ragged_batch",
-                               "_advance_chunked_prefills"):
+            if fn.name != "_advance_chunked_prefills":
                 continue
             guarded = False
             for n in ast.walk(fn):
@@ -669,119 +651,12 @@ class BoundedWaitRule(Rule):
 
 
 # ---------------------------------------------------------------------------
-# SL005: ragged quota conservation
-# ---------------------------------------------------------------------------
-
-
-class QuotaRule(Rule):
-    id = "SL005"
-    name = "quota-conservation"
-    description = ("ragged builder charges the budget with exactly the "
-                   "appended range widths; operand builder sums ranges "
-                   "to the dispatch offset")
-
-    def check(self, ctx: FileContext) -> Iterator[Finding]:
-        for fn in _functions(ctx.tree):
-            if fn.name == "_build_ragged_batch":
-                yield from self._check_builder(ctx, fn)
-            if fn.name == "_ragged_prefill_operands":
-                yield from self._check_operands(ctx, fn)
-
-    def _check_builder(self, ctx: FileContext,
-                       fn: ast.FunctionDef) -> Iterator[Finding]:
-        # The accumulator: `spent += take` where `take` is also the
-        # appended range width — budget charge == dispatched width.
-        charge: Optional[tuple[str, str]] = None  # (acc, width)
-        for n in ast.walk(fn):
-            if isinstance(n, ast.AugAssign) and isinstance(n.op, ast.Add) \
-                    and isinstance(n.target, ast.Name) \
-                    and isinstance(n.value, ast.Name):
-                charge = (n.target.id, n.value.id)
-        if charge is None:
-            yield ctx.finding(
-                "SL005", fn,
-                "_build_ragged_batch does not charge an accumulator "
-                "with the range width — the budget cannot conserve "
-                "tokens it never counts")
-            return
-        acc, width = charge
-        appended = any(
-            isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
-            and n.func.attr == "append"
-            and any(isinstance(e, ast.Name) and e.id == width
-                    for a in n.args for e in ast.walk(a))
-            for n in ast.walk(fn))
-        if not appended:
-            yield ctx.finding(
-                "SL005", fn,
-                f"_build_ragged_batch charges `{acc} += {width}` but "
-                f"never appends `{width}` to the range list — charged "
-                "tokens and dispatched tokens drift apart")
-        clipped = any(
-            isinstance(n, ast.Call) and _terminal(n.func) == "min"
-            and any(isinstance(e, ast.BinOp) and isinstance(e.op, ast.Sub)
-                    and isinstance(e.right, ast.Name) and e.right.id == acc
-                    for a in n.args for e in ast.walk(a))
-            for n in ast.walk(fn))
-        if not clipped:
-            yield ctx.finding(
-                "SL005", fn,
-                f"_build_ragged_batch does not clip the range width to "
-                f"the remaining dispatch width (no `W - {acc}` term "
-                "inside min) — the last range can overflow the stream")
-        # The budget exit must compare with >= so the overshoot is
-        # bounded by ONE range (the progress floor's worth), never two.
-        strict_only = False
-        for n in ast.walk(fn):
-            if isinstance(n, ast.Compare) and len(n.ops) == 1 \
-                    and isinstance(n.left, ast.Name) and n.left.id == acc:
-                if isinstance(n.ops[0], ast.GtE):
-                    strict_only = False
-                    break
-                if isinstance(n.ops[0], ast.Gt):
-                    strict_only = True
-        if strict_only:
-            yield ctx.finding(
-                "SL005", fn,
-                f"_build_ragged_batch's budget exit uses `{acc} >` "
-                "instead of `>=` — tokens dispatched per iteration can "
-                "exceed budget + floor by a full extra range")
-
-    def _check_operands(self, ctx: FileContext,
-                        fn: ast.FunctionDef) -> Iterator[Finding]:
-        # One width name must advance the write offset, the useful
-        # count, and the per-range length vector — the identity that
-        # makes sum(rng_len) == final offset == dispatched width.
-        aug: dict[str, set[str]] = {}
-        sub_assigned: set[str] = set()
-        for n in ast.walk(fn):
-            if isinstance(n, ast.AugAssign) and isinstance(n.op, ast.Add) \
-                    and isinstance(n.target, ast.Name) \
-                    and isinstance(n.value, ast.Name):
-                aug.setdefault(n.value.id, set()).add(n.target.id)
-            if isinstance(n, ast.Assign) and len(n.targets) == 1 \
-                    and isinstance(n.targets[0], ast.Subscript) \
-                    and isinstance(n.value, ast.Name):
-                sub_assigned.add(n.value.id)
-        ok = any(len(targets) >= 2 and width in sub_assigned
-                 for width, targets in aug.items())
-        if not ok:
-            yield ctx.finding(
-                "SL005", fn,
-                "_ragged_prefill_operands must advance its write "
-                "offset, its useful-token count, and a per-range length "
-                "row by the SAME width variable — otherwise the token "
-                "ranges no longer sum to the dispatch offset and a "
-                "range silently under/over-writes the stream")
-
-
-# ---------------------------------------------------------------------------
 # SL006: observed starvation (runtime witness merge)
 # ---------------------------------------------------------------------------
 
 _FRONTIER_ANCHORS = {
     "restore": "def _issue_restores",
-    "prefill": "def _build_ragged_batch",
+    "prefill": "def _advance_chunked_prefills",
     "decode": "def _dispatch_step",
 }
 
@@ -894,7 +769,7 @@ class WitnessStarvationRule(_ProjectRule):
 
 SCHED_RULES: list[Rule] = [
     ProgressFloorRule(), CursorRule(), FrontierOrderRule(),
-    BoundedWaitRule(), QuotaRule(), WitnessStarvationRule(),
+    BoundedWaitRule(), WitnessStarvationRule(),
 ]
 
 
@@ -904,19 +779,19 @@ SCHED_RULES: list[Rule] = [
 
 
 def _stale_contract_findings(ctx: FileContext) -> list[Finding]:
-    """SL000 when the engine no longer carries the anchors SL003/SL005
+    """SL000 when the engine no longer carries the anchors SL003
     verify against — a renamed frontier method must fail loud, not let
     the contract silently stop checking anything."""
     have = {n.name for n in _functions(ctx.tree)}
     findings: list[Finding] = []
-    for name in _CONTRACT_ANCHORS:
+    for name in ORDERED_FRONTIERS:
         if name not in have:
             findings.append(Finding(
                 rule="SL000", path=ctx.rel, line=1,
                 message=f"frontier contract anchor {name}() is gone "
                         "from the engine — the scheduler contract is "
-                        "stale; update ORDERED_FRONTIERS/"
-                        "_CONTRACT_ANCHORS in analysis/sched.py"))
+                        "stale; update ORDERED_FRONTIERS in "
+                        "analysis/sched.py"))
     if not any(isinstance(n, ast.While) and _is_engine_loop(n)
                for n in ast.walk(ctx.tree)):
         findings.append(Finding(

@@ -1,6 +1,6 @@
 """Runtime starvation witness: the dynamic half of schedlint.
 
-Static liveness analysis (analysis/sched.py, SL001–SL005) proves the
+Static liveness analysis (analysis/sched.py, SL001–SL004) proves the
 *shape* of the scheduler's fairness machinery — every budgeted loop has
 a progress floor, every round-robin cursor advances, the frontiers
 issue in order. It cannot prove that under a real mixed load no lane

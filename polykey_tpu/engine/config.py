@@ -120,28 +120,6 @@ class EngineConfig:
     # should fill all slots at once). POLYKEY_PREFILL_BUDGET.
     prefill_budget: int = 0
 
-    # Ragged dispatch (ISSUE 12, PAPERS.md "Ragged Paged Attention"):
-    # admissions and chunk advancement become token-range appends into
-    # ONE flat mixed prefill+decode dispatch per engine-loop iteration
-    # (all live decode lanes' single tokens + up to ~prefill_budget
-    # prefill tokens), replacing the per-bucket prefill executables
-    # ({1,2,4,8} pads × buckets × greedy variants) and the separate
-    # chunk dispatch with a single resident ragged executable (≤2
-    # greedy variants). Steady-state decode (no prefill work) keeps the
-    # K-step block path, so the PR 6 lookahead pipeline and its
-    # amortization are untouched. Attention rides the ragged Pallas
-    # kernel on TPU (ops/ragged_paged_attention_kernel.py) and its
-    # per-token gather fallback off-TPU — the bit-identity reference:
-    # greedy streams match the bucketed path token-for-token.
-    # POLYKEY_RAGGED=1 enables; POLYKEY_DISABLE_RAGGED=1 is the
-    # operational kill-switch (wins over config/env enablement, the
-    # POLYKEY_DISABLE_PAGED_KERNEL pattern). Requires dp=sp=pp=1.
-    # Composes with speculative decoding (ISSUE 19): gamma-token verify
-    # windows ride the flat stream as ordinary per-sequence ranges, so
-    # one mixed dispatch serves prefill chunks, decode lanes, AND spec
-    # verify lanes.
-    ragged_dispatch: bool = False
-
     # Automatic prefix caching (engine/prefix_cache.py): requests sharing a
     # page-aligned prompt prefix reuse its KV pages and prefill only the
     # suffix. prefix_cache_pages caps the cache's own page references
@@ -446,7 +424,6 @@ class EngineConfig:
             default_max_new_tokens=_env_int(
                 "POLYKEY_DEFAULT_MAX_NEW_TOKENS", cls.default_max_new_tokens
             ),
-            ragged_dispatch=_env_bool("POLYKEY_RAGGED"),
             # The host tier's spill source is the prefix cache, so
             # enabling the tier enables the cache (validate() enforces
             # the pairing for programmatic configs).
@@ -634,18 +611,6 @@ class EngineConfig:
             )
         if self.prefill_chunk < 0:
             raise ValueError("prefill_chunk must be >= 0 (0 → max bucket)")
-        if self.ragged_dispatch:
-            # Speculative engines ride the same flat stream since
-            # ISSUE 19: verify windows are ordinary per-sequence ranges,
-            # so draft models compose with ragged_dispatch (the old
-            # refusal is gone).
-            if self.dp * self.num_slices > 1 or self.sp > 1 or self.pp > 1:
-                raise ValueError(
-                    "ragged_dispatch serves tp-at-most meshes: the flat "
-                    "token stream does not shard over dp/sp/pp (got "
-                    f"dp={self.dp}×slices={self.num_slices}, sp={self.sp}, "
-                    f"pp={self.pp})"
-                )
         if self.prefill_budget < 0:
             raise ValueError(
                 "prefill_budget must be >= 0 (0 → 2 x prefill chunk)"

@@ -1646,7 +1646,6 @@ def _config_env(config: EngineConfig) -> dict:
         "POLYKEY_DEFAULT_MAX_NEW_TOKENS": str(
             config.default_max_new_tokens
         ),
-        "POLYKEY_RAGGED": flag if config.ragged_dispatch else "0",
         "POLYKEY_PREFIX_CACHE": flag if config.prefix_cache else "0",
         "POLYKEY_PREFIX_CACHE_PAGES": str(config.prefix_cache_pages),
         # Host-memory KV tier (ISSUE 15): a programmatic pool with the

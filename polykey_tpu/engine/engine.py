@@ -70,7 +70,7 @@ from ..analysis import schedwitness as _schedwitness
 from ..faults import get_injector
 from ..models.config import ModelConfig, get_config
 from ..obs.timeline import TimelineRecorder, phase
-from ..models.transformer import forward_paged, forward_ragged, unembed
+from ..models.transformer import forward_paged, unembed
 from ..parallel.mesh import MeshConfig, create_mesh
 from ..parallel.sharding import (
     init_sharded_params,
@@ -222,7 +222,7 @@ class _Slot:
     prompt_ids: Optional[np.ndarray] = None  # for prefix-cache insertion
     # Host-KV page faults (ISSUE 15): [(key, host_page, chain_index)]
     # for prefix pages whose contents sit in the host tier. While set,
-    # the slot is FAULTING — it joins no prefill/ragged dispatch — until
+    # the slot is FAULTING — it joins no prefill dispatch — until
     # the engine loop's restore frontier issues its scatter
     # (_issue_restores), after which the donation chain orders the page
     # contents ahead of every dispatch that could read them. The slot
@@ -357,97 +357,6 @@ def _decode_fn(
     return packed, last, seq, act, paged
 
 
-def _ragged_fn(
-    params, cfg: ModelConfig, paged,
-    last_tokens, seq_lens, page_tables, active, caps, seeds, temperature,
-    top_p, top_k,
-    pre_tokens, pre_pos, pre_table_idx, pre_tables,
-    pre_range_start, pre_range_len, pre_range_kv, pre_range_table,
-    pre_sample_idx, pre_sample_pos, pre_seeds, pre_temp, pre_top_p,
-    pre_top_k,
-    *, greedy: bool, eos_id: int, candidates: int = 0, mesh=None,
-):
-    """ONE ragged dispatch for mixed prefill+decode (ISSUE 12): every
-    decode lane advances exactly one step AND up to `W` prefill tokens
-    (admission prompts and chunk advancement, appended as token ranges
-    by the host-side batch builder) prefill — through a single flat
-    [B+W]-token forward (models/transformer.forward_ragged; ragged
-    Pallas kernel on TPU, per-token gather fallback elsewhere).
-
-    Layout: flat rows [0, B) are the decode lanes' single tokens (row b
-    = slot b, position seq_lens[b]-1 — inactive lanes compute masked
-    garbage through their garbage tables exactly as in _decode_fn);
-    rows [B, B+W) are the prefill stream. `pre_table_idx[w]` maps each
-    prefill row to its owning slot's HOST-side page table in
-    `pre_tables` [B, P] (index B → an all-garbage row: padding tokens
-    write to and attend over the reserved page 0, like inactive lanes).
-    `pre_range_*` [B] describe the appended ranges for the ragged
-    kernel's per-sequence metadata (ascending flat offsets; unused rows
-    are empty ranges past the stream end).
-
-    Sampling mirrors the bucketed paths EXACTLY (bit-identity):
-    - decode rows sample with position key seq_lens (the position the
-      new token lands at), advance seq/active with the same EOS/cap
-      stopping as _decode_fn, and return the same packed [1, B] emit
-      row a steps=1 decode block would — so the result rides the
-      lookahead pipeline's _process_step unchanged;
-    - per slot b, `pre_sample_idx[b]` names the prefill-stream row
-      whose hidden state samples that slot's FIRST token at position
-      key `pre_sample_pos[b]` (= prompt_len, matching _prefill_fn's
-      start + last_rel + 1); the host merges only final-chunk slots,
-      the other rows' draws are discarded.
-    """
-    B = last_tokens.shape[0]
-    W = pre_tokens.shape[0]
-    dec_pos = jnp.maximum(seq_lens - 1, 0)
-    tokens = jnp.concatenate([last_tokens, pre_tokens])          # [B+W]
-    positions = jnp.concatenate([dec_pos, pre_pos])
-    garbage_row = jnp.zeros_like(pre_tables[:1])
-    tables_ext = jnp.concatenate([pre_tables, garbage_row])      # [B+1, P]
-    token_tables = jnp.concatenate(
-        [page_tables, tables_ext[pre_table_idx]]
-    )                                                            # [B+W, P]
-    # Ragged sequence metadata (kernel path): B decode singles then the
-    # prefill ranges, starts ascending (unused ranges sit past the end).
-    rng_starts = jnp.concatenate([
-        jnp.arange(B, dtype=jnp.int32), B + pre_range_start,
-    ])
-    rng_lens = jnp.concatenate([
-        jnp.ones((B,), jnp.int32), pre_range_len,
-    ])
-    rng_kv = jnp.concatenate([
-        jnp.maximum(seq_lens, 1), pre_range_kv,
-    ])
-    seq_tables = jnp.concatenate(
-        [page_tables, tables_ext[pre_range_table]]
-    )                                                            # [2B, P]
-
-    hidden, paged = forward_ragged(
-        params, cfg, tokens, positions, paged, token_tables,
-        rng_starts, rng_lens, rng_kv, seq_tables, mesh=mesh,
-    )
-
-    # Decode rows: one _decode_fn step, verbatim semantics.
-    logits = unembed(params, cfg, hidden[:B])                    # [B, V]
-    dec = sample_tail(
-        logits, seeds, seq_lens, temperature, top_p, top_k, greedy,
-        candidates,
-    )
-    dec = jnp.where(active, dec, 0)
-    new_seq = seq_lens + active.astype(jnp.int32)
-    cont = active & (dec != eos_id) & (new_seq < caps)
-    packed = jnp.where(active, dec, -1)[None, :]                 # [1, B]
-
-    # Prefill first tokens: one row per slot (garbage for slots without
-    # a final chunk this dispatch — the host never reads those).
-    rows = hidden[B + jnp.clip(pre_sample_idx, 0, W - 1)]        # [B, H]
-    first = sample_tail(
-        unembed(params, cfg, rows), pre_seeds, pre_sample_pos,
-        pre_temp, pre_top_p, pre_top_k, greedy, candidates,
-    )
-    return packed, dec, new_seq, cont, first, paged
-
-
 def _merge_lane_fn(
     last_tokens, seq_lens, page_tables, active, caps, temperature, top_p,
     top_k, seeds, tokens_vec, row, slot, seq_len, cap, temp, tp, tk,
@@ -557,31 +466,6 @@ def _kv_gather_quant_fn(paged: PagedKV, idx):
     return (
         jnp.take(paged.k, idx, axis=1), jnp.take(paged.v, idx, axis=1),
         jnp.take(paged.ks, idx, axis=1), jnp.take(paged.vs, idx, axis=1),
-    )
-
-
-def ragged_zero_operands(B: int, W: int, P: int) -> tuple:
-    """The 14 positional prefill operands of `_ragged_fn`, all-zero /
-    all-garbage (no ranges, no sample rows) — the SINGLE builder for
-    every synthetic ragged call (engine warmup, graphlint's donation
-    audit and jaxpr trace). The operands are positionally typed int32/
-    float32 arrays, so hand-built copies that drift from the signature
-    would trace clean and compute garbage; build them here only."""
-    return (
-        np.zeros((W,), np.int32),            # pre_tokens
-        np.zeros((W,), np.int32),            # pre_pos
-        np.full((W,), B, np.int32),          # pre_table_idx → garbage row
-        np.zeros((B, P), np.int32),          # pre_tables
-        np.full((B,), W, np.int32),          # pre_range_start → past end
-        np.zeros((B,), np.int32),            # pre_range_len
-        np.zeros((B,), np.int32),            # pre_range_kv
-        np.full((B,), B, np.int32),          # pre_range_table → garbage
-        np.zeros((B,), np.int32),            # pre_sample_idx
-        np.zeros((B,), np.int32),            # pre_sample_pos
-        np.zeros((B, 2), np.int32),          # pre_seeds
-        np.zeros((B,), np.float32),          # pre_temp
-        np.ones((B,), np.float32),           # pre_top_p
-        np.zeros((B,), np.int32),            # pre_top_k
     )
 
 
@@ -800,21 +684,6 @@ class InferenceEngine:
                 "kv_dtype=int8 (POLYKEY_KV_DTYPE) does not lower on TPU "
                 f"yet: {INT8_KV_MOSAIC_ERROR}"
             )
-        # Ragged dispatch on/off. POLYKEY_DISABLE_RAGGED is the
-        # operational kill-switch (wins over config/env enablement — the
-        # POLYKEY_DISABLE_PAGED_KERNEL pattern): a ragged regression must
-        # be containable by falling back to the bucketed executables
-        # without a config rollout.
-        self._ragged = config.ragged_dispatch and os.environ.get(
-            "POLYKEY_DISABLE_RAGGED", ""
-        ).lower() not in ("1", "true")
-        if self._ragged and self._identity["platform"] == "tpu":
-            from ..ops.paged_write_kernel import RAGGED_WRITE_MOSAIC_ERROR
-
-            raise ValueError(
-                "ragged_dispatch (POLYKEY_RAGGED) does not run on TPU yet: "
-                f"{RAGGED_WRITE_MOSAIC_ERROR}"
-            )
         pool_sh = paged_kv_sharding(self.mesh)
         if self._kv_quantized:
             self._pool_sharding = PagedKV(
@@ -1030,37 +899,6 @@ class InferenceEngine:
         )
         self._last_dispatch_steps = 0    # observability (bench step_costs)
 
-        # --- Ragged dispatch (ISSUE 12): admissions + chunk advancement
-        # become token-range appends into ONE flat mixed prefill+decode
-        # dispatch (_ragged_fn) whenever prefill work exists; pure-decode
-        # iterations keep the K-step block path (self._ragged is decided
-        # above, next to the TPU refusals).
-        self._jit_ragged = None
-        if self._ragged:
-            # Static prefill-stream width: the per-iteration token
-            # budget, floored at one chunk and padded so the full flat
-            # stream (B + W) tiles the ragged kernel's token_tile. ONE
-            # width ⇒ one resident executable per greedy variant — the
-            # census collapse GL001 asserts.
-            from ..ops.ragged_paged_attention_kernel import TOKEN_TILE
-
-            W = max(self._prefill_budget, self._chunk)
-            W += (-(B + W)) % TOKEN_TILE
-            self._ragged_width = W
-            self._jit_ragged = jax.jit(
-                _ragged_fn,
-                static_argnames=(
-                    "cfg", "greedy", "eos_id", "candidates", "mesh",
-                ),
-                donate_argnames=(
-                    "paged", "last_tokens", "seq_lens", "active",
-                ),
-                out_shardings=(
-                    self._dp_steps, self._dp_vec, self._dp_vec,
-                    self._dp_vec, self._repl, self._pool_sharding,
-                ),
-            )
-
         # --- Speculative decoding: draft model + its own page pool, same
         # page tables (position → (page, offset) is model-independent).
         self._spec = config.draft_model is not None
@@ -1088,11 +926,7 @@ class InferenceEngine:
         # are what drive the dial.
         self._accept_ewma = 1.0          # optimistic start: full gamma
         if self._spec:
-            from .spec_decode import (
-                ragged_spec_fn,
-                spec_decode_fn,
-                spec_prefill_fn,
-            )
+            from .spec_decode import spec_decode_fn, spec_prefill_fn
 
             self.draft_cfg = get_config(config.draft_model)
             if self.draft_cfg.vocab_size != self.model_cfg.vocab_size:
@@ -1152,40 +986,6 @@ class InferenceEngine:
                     self._pool_sharding, self._pool_sharding,
                 ),
             )
-            self._jit_ragged_spec = None
-            if self._ragged:
-                # Spec×ragged unification (ISSUE 19 tentpole b): gamma-
-                # token verify windows ride the flat ragged stream as
-                # ordinary per-sequence ranges, so ONE mixed dispatch
-                # serves prefill chunks AND spec verify lanes. The flat
-                # stream is B·(γ+1)+W tokens, so the tile-aligned prefill
-                # width W is per-gamma (each ladder rung is its own
-                # compile anyway).
-                from ..ops.ragged_paged_attention_kernel import TOKEN_TILE
-
-                W0 = max(self._prefill_budget, self._chunk)
-                self._ragged_spec_width = {
-                    g: W0 + (-(B * (g + 1) + W0)) % TOKEN_TILE
-                    for g in sorted({self._gamma_low, self._gamma_max})
-                }
-                self._jit_ragged_spec = jax.jit(
-                    ragged_spec_fn,
-                    static_argnames=(
-                        "t_cfg", "d_cfg", "gamma", "eos_id", "gamma_low",
-                        "gamma_max", "greedy", "candidates", "mesh",
-                    ),
-                    donate_argnames=(
-                        "t_paged", "d_paged",
-                        "last_tokens", "seq_lens", "active",
-                        "accept_ewma", "gamma_lane",
-                    ),
-                    out_shardings=(
-                        self._dp_mat, self._dp_vec, self._dp_vec,
-                        self._dp_vec, self._dp_vec, self._dp_vec,
-                        self._repl,
-                        self._pool_sharding, self._pool_sharding,
-                    ),
-                )
 
         # Host mirrors of per-slot device state (engine thread only). They
         # are the source of truth at slot transitions (admit/finish mark
@@ -1447,12 +1247,8 @@ class InferenceEngine:
     def set_prefill_budget(self, tokens: int) -> int:
         """Interleaved-prefill token budget per loop iteration. Floored
         at one chunk (the knob bounds stall length, it must never
-        deadlock a long prompt); in ragged mode capped at the
-        compile-static prefill-stream width — the executable cannot
-        carry more prefill tokens than it was built for."""
+        deadlock a long prompt)."""
         tokens = max(int(tokens), self._chunk)
-        if self._ragged:
-            tokens = min(tokens, self._ragged_width)
         self._prefill_budget = tokens
         return tokens
 
@@ -1558,19 +1354,12 @@ class InferenceEngine:
                 "inflight_blocks": len(self._inflight_q),
                 "prefill_budget": self._prefill_budget,
                 # Lookahead pipeline (ISSUE 6): configured depth (env
-                # override included), the live adaptive target, and the
-                # host-stall/overlap numbers ride the metrics snapshot
-                # (host_stall_ms_p50, lookahead_observed_*).
+                # override included); the host-stall/overlap numbers
+                # ride the metrics snapshot (host_stall_ms_p50,
+                # lookahead_observed_*).
                 "lookahead_depth": self._depth,
-                "lookahead_target": self._depth_target,
-                # Ragged dispatch (ISSUE 12): whether the single-
-                # executable mixed prefill+decode path is live, and its
-                # static prefill-stream width.
-                "ragged": self._ragged,
             }
         )
-        if self._ragged:
-            snap["ragged_width"] = self._ragged_width
         if snap.get("avg_lanes") is not None:
             # Measured occupancy fraction: step-weighted mean live lanes
             # over the slot count (the ≥0.8 target ISSUE 4 soaks against).
@@ -1713,22 +1502,15 @@ class InferenceEngine:
                     # decode beyond host_kv_restore_slots uploads.
                     if self._issue_restores():
                         worked = True
-                if self._ragged:
-                    # Ragged mode: admissions only REGISTER (token-range
-                    # appends happen in _dispatch_step's batch builder,
-                    # which owns the budget and the interleave
-                    # accounting) — no separate chunk dispatch exists.
-                    chunked = 0
-                else:
-                    remaining = (
-                        None if budget is None else max(0, budget - spent)
-                    )
-                    chunked = self._advance_chunked_prefills(remaining)
-                    if chunked:
-                        worked = True
-                    self.metrics.on_prefill_interleave(
-                        spent + chunked, decode_live
-                    )
+                remaining = (
+                    None if budget is None else max(0, budget - spent)
+                )
+                chunked = self._advance_chunked_prefills(remaining)
+                if chunked:
+                    worked = True
+                self.metrics.on_prefill_interleave(
+                    spent + chunked, decode_live
+                )
                 if self._dev_dirty and self._inflight_q:
                     # Rare full transition (init/recovery): a mirror upload
                     # may never rewind live device state, so the whole
@@ -1746,15 +1528,11 @@ class InferenceEngine:
                 # _process_step. Spec rounds carry the same device-side
                 # stop, so both block kinds pipeline alike.
                 dispatched = False
-                if self._active.any() or (
-                    self._ragged and self._has_pending_prefill()
-                ):
+                if self._active.any():
                     with self._phase("dispatch"):
-                        block = self._dispatch_step()
-                    if block is not None:
-                        self._inflight_q.append(block)
-                        dispatched = True
-                        worked = True
+                        self._inflight_q.append(self._dispatch_step())
+                    dispatched = True
+                    worked = True
                 if _schedwitness.installed() and self._active.any():
                     # Decode boundary: a dispatched block serves every
                     # active lane (flat batch); active lanes with no
@@ -2101,17 +1879,6 @@ class InferenceEngine:
             self._slots[slot_idx] = slot
             return None
 
-        if self._ragged:
-            # Ragged mode: EVERY prompt registers as a pending token
-            # range — admissions and chunk advancement are the same
-            # operation (token-range appends into the next ragged
-            # dispatch's flat stream; _build_ragged_batch). A prefix-
-            # cache hit just starts the range at the cached offset.
-            slot.pending = ids
-            slot.filled = len(matched) * cfg.page_size
-            self._slots[slot_idx] = slot
-            return None
-
         if matched:
             # Prefill only the suffix. A bucket-sized suffix rides the
             # batched bucket path at its own width (a hit must not cost
@@ -2223,374 +1990,6 @@ class InferenceEngine:
                 self.timeline.prefill(slot_idx, bucket, True)
             self._merge_slot(slot_idx, slot, toks_dev, r)
 
-    def _has_pending_prefill(self) -> bool:
-        return any(
-            s is not None and s.pending is not None for s in self._slots
-        )
-
-    def _build_ragged_batch(self) -> list:
-        """Collect the next ragged dispatch's token ranges: round-robin
-        from the `_chunk_rr` cursor over slots with pending prompt
-        tokens, one range of up to a chunk per slot, until the prefill
-        budget (while decode lanes are live) or the stream width W is
-        spent — the same fairness + progress-floor semantics as
-        _advance_chunked_prefills (the first range always proceeds; the
-        budget is a soft bound at range granularity). Returns
-        [(slot_idx, slot, take)]; empty means no prefill work this
-        iteration (steady-state decode keeps the K-step block path)."""
-        W = self._ragged_width
-        if self._spec and self._jit_ragged_spec is not None:
-            # Spec engines may route these ranges through the per-gamma
-            # tile-aligned spec stream, whose prefill width can sit up to
-            # a tile short of the plain one — build to the tightest so a
-            # batch fits whichever executable the spec gate picks.
-            W = min(W, min(self._ragged_spec_width.values()))
-        decode_live = bool(self._active.any())
-        budget = min(self._prefill_budget, W) if decode_live else W
-        ranges: list = []
-        spent = 0
-        B = len(self._slots)
-        starved = None
-        for i in self._chunk_rr.scan(B):
-            s = self._slots[i]
-            if s is None or s.pending is None:
-                continue
-            if s.restore_pages is not None:
-                continue   # faulting: waits for the restore frontier
-            if s.request.cancelled.is_set():
-                self._finish(i, error="cancelled")
-                continue
-            if self._deadline_expired(s.request):
-                # Expired mid-prefill: remaining ranges never dispatch.
-                self.metrics.on_deadline_expired("prefill")
-                self._finish(i, error=f"{DEADLINE_MSG} during prefill")
-                continue
-            if spent >= budget and ranges:
-                starved = i     # goes first next iteration
-                break
-            take = min(self._chunk, len(s.pending) - s.filled, W - spent)
-            if take <= 0:
-                if ranges:
-                    starved = i
-                break
-            ranges.append((i, s, take))
-            spent += take
-        if starved is not None:
-            self._chunk_rr.reanchor(starved)
-        else:
-            self._chunk_rr.advance(B)
-        self._note_sched_frontier("prefill", [i for i, _s, _t in ranges])
-        return ranges
-
-    def _ragged_prefill_operands(self, ranges: list, W: int):
-        """Build the 14 `pre_*` numpy operands of a ragged dispatch
-        (stream width W) from the batch builder's token ranges — shared
-        by the plain ragged dispatch and the spec×ragged one (ISSUE 19)
-        so the operand layout cannot drift between them. Returns
-        (operands, useful, smp_temp): the positional operand tuple, the
-        real-token count (padding-waste accounting), and the sampled-
-        this-dispatch temperature vector (feeds the batch-greedy key)."""
-        cfg = self.config
-        B = cfg.max_decode_slots
-        P = cfg.pages_per_seq
-        pre_tokens = np.zeros((W,), np.int32)
-        pre_pos = np.zeros((W,), np.int32)
-        pre_tidx = np.full((W,), B, np.int32)     # B → garbage table row
-        pre_tables = np.zeros((B, P), np.int32)
-        rng_start = np.full((B,), W, np.int32)    # unused → past the end
-        rng_len = np.zeros((B,), np.int32)
-        rng_kv = np.zeros((B,), np.int32)
-        rng_tidx = np.full((B,), B, np.int32)
-        smp_idx = np.zeros((B,), np.int32)
-        smp_pos = np.zeros((B,), np.int32)
-        smp_seeds = np.zeros((B, 2), np.int32)
-        smp_temp = np.zeros((B,), np.float32)
-        smp_top_p = np.ones((B,), np.float32)
-        smp_top_k = np.zeros((B,), np.int32)
-        off = 0
-        useful = 0
-        for r, (i, s, take) in enumerate(ranges):
-            pre_tokens[off:off + take] = s.pending[s.filled:s.filled + take]
-            pre_pos[off:off + take] = np.arange(s.filled, s.filled + take)
-            pre_tidx[off:off + take] = i
-            pre_tables[i] = s.table[0]
-            rng_start[r] = off
-            rng_len[r] = take
-            rng_kv[r] = s.filled + take
-            rng_tidx[r] = i
-            if s.filled + take >= len(s.pending):
-                # Final range: sample this slot's first token from its
-                # last prefill row at position key prompt_len — exactly
-                # _prefill_fn's start + last_rel + 1.
-                smp_idx[i] = off + take - 1
-                smp_pos[i] = s.filled + take
-                smp_seeds[i] = s.seed_row
-                smp_temp[i] = s.request.temperature
-                smp_top_p[i] = s.request.top_p
-                smp_top_k[i] = self._eff_top_k(s.request)
-            off += take
-            useful += take
-        operands = (
-            pre_tokens, pre_pos, pre_tidx, pre_tables,
-            rng_start, rng_len, rng_kv, rng_tidx,
-            smp_idx, smp_pos, smp_seeds, smp_temp, smp_top_p, smp_top_k,
-        )
-        return operands, useful, smp_temp
-
-    def _dispatch_ragged(self, ranges: list):
-        """ONE flat mixed prefill+decode dispatch (ISSUE 12): the token
-        ranges from _build_ragged_batch plus every decode lane's single
-        token, through the resident ragged executable. Returns an
-        _InflightBlock whose packed [1, B] decode emissions ride the
-        lookahead pipeline's _process_step unchanged (None on a
-        contained prefill failure — the caller falls through to the
-        plain paths)."""
-        cfg = self.config
-        W = self._ragged_width
-        B = cfg.max_decode_slots
-        (pre_tokens, pre_pos, pre_tidx, pre_tables, rng_start, rng_len,
-         rng_kv, rng_tidx, smp_idx, smp_pos, smp_seeds, smp_temp,
-         smp_top_p, smp_top_k), useful, _ = (
-            self._ragged_prefill_operands(ranges, W)
-        )
-
-        dev = self._dev
-        act = self._active
-        lanes = int(act.sum())
-        # Static greedy variant, batch-keyed like the other dispatch
-        # paths: all live decode lanes AND all sampled-this-dispatch
-        # prefill rows greedy (non-final rows default 0.0 → neutral).
-        greedy = bool(np.all(self._temperature[act] == 0.0)) and bool(
-            np.all(smp_temp == 0.0)
-        )
-        self._depth_target = self._depth
-        self._last_dispatch_steps = 1
-        gap_ms = self.metrics.on_dispatch(lanes, 1, slots=B,
-                                          depth=self._depth_target)
-        # Padding-waste accounting: the device computes W prefill rows
-        # of which `useful` carry real prompt tokens (decode rows are
-        # charged by on_dispatch's slots/lanes split).
-        self.metrics.on_padding_tokens(W, useful)
-        self.metrics.on_prefill_interleave(useful, lanes > 0)
-        live = tuple(int(i) for i in np.flatnonzero(act))
-        put = partial(jax.device_put, device=self._repl)
-        try:
-            if self._faults is not None:
-                self._faults.maybe_raise(
-                    "prefill-error", replica=self.replica_id,
-                    tier=self._tier,
-                )
-            with self._phase("ragged", seq=self._dispatch_seq + 1,
-                             lanes=lanes, steps=1, tokens=useful):
-                self._stamp_final_ranges(ranges)
-                (packed_dev, last_dev, seq_dev, act_dev, first_dev,
-                 self.paged) = self._jit_ragged(
-                    self.params, self.model_cfg, self.paged,
-                    dev["last_tokens"], dev["seq_lens"],
-                    dev["page_tables"], dev["active"], dev["caps"],
-                    dev["seeds"], dev["temperature"], dev["top_p"],
-                    dev["top_k"],
-                    put(pre_tokens), put(pre_pos), put(pre_tidx),
-                    put(pre_tables),
-                    put(rng_start), put(rng_len), put(rng_kv),
-                    put(rng_tidx),
-                    put(smp_idx), put(smp_pos), put(smp_seeds),
-                    put(smp_temp), put(smp_top_p), put(smp_top_k),
-                    greedy=greedy, eos_id=self.tokenizer.eos_id,
-                    candidates=self.config.top_p_candidates,
-                    mesh=self.mesh,
-                )
-                dev["last_tokens"] = last_dev
-                dev["seq_lens"] = seq_dev
-                dev["active"] = act_dev
-        except Exception as e:
-            # Contain to the ranged slots (each must be finished or its
-            # client hangs — the prefill-group containment contract);
-            # the conservative dirty flag re-folds mirrors next
-            # iteration. Decode lanes keep their state: the failure
-            # (fault injection raises before dispatch) never advanced
-            # them.
-            for i, s, _take in ranges:
-                if self._slots[i] is s:
-                    self._finish(i, error=f"prefill failed: {e}")
-            self._dev_dirty = True
-            return None
-        try:
-            packed_dev.copy_to_host_async()
-        except Exception:
-            # Best-effort copy hint only (same as the block dispatch).
-            pass
-        self._dispatch_seq += 1
-        if self.timeline is not None:
-            self.timeline.dispatch(
-                self._dispatch_seq, "ragged", lanes, 1, gap_ms
-            )
-        for i, s, take in ranges:
-            final = s.filled + take >= len(s.pending)
-            if self.timeline is not None:
-                self.timeline.prefill(i, take, final)
-            if final:
-                # The sampled first token (row i of the ragged call's
-                # first-token vector, still device-resident) activates
-                # the lane via the usual merge — it joins the NEXT
-                # dispatch, exactly like a bucketed admission.
-                self._merge_slot(i, s, first_dev, i)
-            else:
-                s.filled += take
-        return _InflightBlock(
-            "plain", packed_dev, self._snapshot_requests(),
-            self._dispatch_seq, gap_ms, live, 1,
-        )
-
-    def _stamp_final_ranges(self, ranges: list) -> None:
-        """Ragged dispatch: the requests whose prompt this dispatch
-        completes get their `prefill_dispatched` stamp."""
-        issued = time.monotonic()
-        for _i, s, take in ranges:
-            if s.filled + take >= len(s.pending):
-                s.request.timings.prefill_dispatched = issued
-
-    def _dispatch_ragged_spec(self, ranges: list):
-        """ONE flat mixed dispatch serving prefill chunks AND spec verify
-        lanes (ISSUE 19 tentpole b): each live decode lane contributes a
-        gamma+1 verify window to the flat stream as an ordinary per-
-        sequence range, alongside the prompt-chunk ranges — the spec
-        formulation of _dispatch_ragged. Returns an
-        _InflightBlock("spec", …) whose packed matrix rides the same
-        once-per-block D2H as a bucketed spec round (None on a contained
-        prefill failure)."""
-        cfg = self.config
-        gamma = self._gamma
-        W = self._ragged_spec_width[gamma]
-        B = cfg.max_decode_slots
-        (pre_tokens, pre_pos, pre_tidx, pre_tables, rng_start, rng_len,
-         rng_kv, rng_tidx, smp_idx, smp_pos, smp_seeds, smp_temp,
-         smp_top_p, smp_top_k), useful, _ = (
-            self._ragged_prefill_operands(ranges, W)
-        )
-
-        dev = self._dev
-        act = self._active
-        lanes = int(act.sum())
-        # Static greedy variant, batch-keyed like the plain ragged path:
-        # all live decode lanes AND all sampled-this-dispatch prefill
-        # rows greedy. The candidates variant follows the caller's spec
-        # gate: all-untruncated batches skip truncation work entirely
-        # (greedy=True implies all-untruncated, so (True, C>0) never
-        # compiles — mirrored in warmup's reachable-variant list).
-        greedy = bool(np.all(self._temperature[act] == 0.0)) and bool(
-            np.all(smp_temp == 0.0)
-        )
-        all_untruncated = bool(np.all(
-            ((self._top_p[act] >= 1.0) & (self._top_k[act] <= 0))
-            | (self._temperature[act] == 0.0)
-        ))
-        spec_candidates = (
-            0 if all_untruncated else self.config.top_p_candidates
-        )
-        # Spec rounds land >= 1 token per round, so `remaining` rounds
-        # always suffice (same tail-work cap as the bucketed spec path).
-        self._depth_target = min(
-            self._depth, max(1, self._remaining_budget(act))
-        )
-        self._last_dispatch_steps = 1
-        # A spec round's scan length is gamma draft steps + one verify —
-        # the step weight that makes its lane-seconds comparable.
-        gap_ms = self.metrics.on_dispatch(lanes, gamma + 1, slots=B,
-                                          depth=self._depth_target)
-        # Padding-waste accounting covers the PREFILL region only: the
-        # B·(gamma+1) verify rows are charged by on_dispatch's
-        # steps-weighted lane accounting, same as a bucketed spec round.
-        self.metrics.on_padding_tokens(W, useful)
-        self.metrics.on_prefill_interleave(useful, lanes > 0)
-        live = tuple(int(i) for i in np.flatnonzero(act))
-        put = partial(jax.device_put, device=self._repl)
-        try:
-            if self._faults is not None:
-                self._faults.maybe_raise(
-                    "prefill-error", replica=self.replica_id,
-                    tier=self._tier,
-                )
-            with self._phase("ragged_spec", seq=self._dispatch_seq + 1,
-                             lanes=lanes, steps=gamma + 1, tokens=useful):
-                self._stamp_final_ranges(ranges)
-                (packed_dev, last_dev, seq_dev, act_dev, ewma_dev,
-                 dial_dev, first_dev, self.paged,
-                 self.d_paged) = self._jit_ragged_spec(
-                    self.params, self.draft_params,
-                    self.model_cfg, self.draft_cfg,
-                    self.paged, self.d_paged,
-                    dev["last_tokens"], dev["seq_lens"],
-                    dev["page_tables"], dev["active"], dev["caps"],
-                    dev["seeds"], dev["temperature"], dev["top_p"],
-                    dev["top_k"],
-                    dev["accept_ewma"], dev["gamma_lane"],
-                    put(pre_tokens), put(pre_pos), put(pre_tidx),
-                    put(pre_tables),
-                    put(rng_start), put(rng_len), put(rng_kv),
-                    put(rng_tidx),
-                    put(smp_idx), put(smp_pos), put(smp_seeds),
-                    put(smp_temp), put(smp_top_p), put(smp_top_k),
-                    gamma=gamma, eos_id=self.tokenizer.eos_id,
-                    gamma_low=self._gamma_low, gamma_max=self._gamma_max,
-                    greedy=greedy, candidates=spec_candidates,
-                    mesh=self.mesh,
-                )
-                dev["last_tokens"] = last_dev
-                dev["seq_lens"] = seq_dev
-                dev["active"] = act_dev
-                dev["accept_ewma"] = ewma_dev
-                dev["gamma_lane"] = dial_dev
-        except Exception as e:
-            # Same containment contract as _dispatch_ragged: finish the
-            # ranged slots, mark mirrors dirty, let the caller fall
-            # through. Decode lanes keep their state.
-            for i, s, _take in ranges:
-                if self._slots[i] is s:
-                    self._finish(i, error=f"prefill failed: {e}")
-            self._dev_dirty = True
-            return None
-        try:
-            packed_dev.copy_to_host_async()
-        except Exception:
-            # Best-effort copy hint only (same as the block dispatch).
-            pass
-        if self.config.spec_host_sync:
-            # A/B instrumentation (scripts/occupancy_soak.py --ab-spec):
-            # emulate the pre-ISSUE-19 host-loop spec round — three
-            # synchronous readbacks per round on the device-resident
-            # math, so the A/B isolates the crossing schedule, not the
-            # arithmetic. Each timed read lands in the host-stall
-            # accounting (metrics.on_spec_host_sync). Never enabled in
-            # production.
-            for _ in range(3):
-                t_sync = time.monotonic()
-                with _host_crossing("spec-host-sync"):
-                    # polylint: disable=PL001(spec_host_sync A/B emulation of the pre-ISSUE-19 host-loop round; off in production), PL008(the blocking dispatch-side read IS the measured subject here)
-                    np.asarray(packed_dev)
-                self.metrics.on_spec_host_sync(
-                    (time.monotonic() - t_sync) * 1e3
-                )
-        self._dispatch_seq += 1
-        if self.timeline is not None:
-            self.timeline.dispatch(
-                self._dispatch_seq, "spec", lanes, gamma + 1, gap_ms
-            )
-        for i, s, take in ranges:
-            final = s.filled + take >= len(s.pending)
-            if self.timeline is not None:
-                self.timeline.prefill(i, take, final)
-            if final:
-                # Same merge-activation as the plain ragged path; the
-                # spec merge additionally resets the lane's gamma dial.
-                self._merge_slot(i, s, first_dev, i)
-            else:
-                s.filled += take
-        return _InflightBlock(
-            "spec", packed_dev, self._snapshot_requests(),
-            self._dispatch_seq, gap_ms, live, gamma + 1,
-        )
-
     def _compile_warmup(self) -> None:
         """Pre-compile the greedy prefill group shapes and the greedy
         decode block (or spec round) against the reserved garbage page.
@@ -2613,107 +2012,7 @@ class InferenceEngine:
         self._upload_slot_state()
         dev = self._dev
         zrow = np.zeros((cfg.pages_per_seq,), np.int32)
-        if self._ragged:
-            # Ragged mode: the per-bucket prefill executables never
-            # compile — ONE ragged executable per greedy variant serves
-            # every admission and chunk shape (the census collapse GL001
-            # asserts). The lane merge warms against the ragged call's
-            # own first-token output (committedness is part of the jit
-            # key, same rule as the bucketed warmup below).
-            W = self._ragged_width
-            put = partial(jax.device_put, device=self._repl)
-            pre = tuple(
-                put(a) for a in
-                ragged_zero_operands(B, W, cfg.pages_per_seq)
-            )
-            first_dev = None
-            if self._spec:
-                # Unified spec×ragged path (ISSUE 19): one executable per
-                # (gamma rung, greedy/candidates variant). Reachable
-                # variants only — greedy=True implies an all-greedy batch,
-                # which is all-untruncated, which dispatches candidates=0.
-                spec_variants = [(True, 0)]
-                if warm_sampled:
-                    spec_variants.append((False, 0))
-                    if cfg.top_p_candidates > 0:
-                        spec_variants.append((False, cfg.top_p_candidates))
-                for greedy, cand in spec_variants:
-                    for gamma in sorted({self._gamma_low, self._gamma_max}):
-                        pre_g = tuple(
-                            put(a) for a in ragged_zero_operands(
-                                B, self._ragged_spec_width[gamma],
-                                cfg.pages_per_seq,
-                            )
-                        )
-                        (_, dev["last_tokens"], dev["seq_lens"],
-                         dev["active"], dev["accept_ewma"],
-                         dev["gamma_lane"], first_dev, self.paged,
-                         self.d_paged) = self._warm_call(
-                            "prefill", self._jit_ragged_spec,
-                            self.params, self.draft_params,
-                            self.model_cfg, self.draft_cfg,
-                            self.paged, self.d_paged,
-                            dev["last_tokens"], dev["seq_lens"],
-                            dev["page_tables"], dev["active"], dev["caps"],
-                            dev["seeds"], dev["temperature"], dev["top_p"],
-                            dev["top_k"], dev["accept_ewma"],
-                            dev["gamma_lane"], *pre_g,
-                            gamma=gamma, eos_id=self.tokenizer.eos_id,
-                            gamma_low=self._gamma_low,
-                            gamma_max=self._gamma_max,
-                            greedy=greedy, candidates=cand, mesh=self.mesh,
-                        )
-                if warm_sampled and cfg.top_p_candidates == 0:
-                    # Gate-fail fallback with prefill ranges in hand: a
-                    # truncated sampled row (only possible variant:
-                    # greedy=False, candidates=0) rides the PLAIN ragged
-                    # dispatch. With the prefilter on, the gate never
-                    # fails and _jit_ragged is unreachable entirely.
-                    (_, dev["last_tokens"], dev["seq_lens"], dev["active"],
-                     first_dev, self.paged) = self._warm_call(
-                        "prefill", self._jit_ragged,
-                        self.params, self.model_cfg, self.paged,
-                        dev["last_tokens"], dev["seq_lens"],
-                        dev["page_tables"], dev["active"], dev["caps"],
-                        dev["seeds"], dev["temperature"], dev["top_p"],
-                        dev["top_k"], *pre,
-                        greedy=False, eos_id=self.tokenizer.eos_id,
-                        candidates=0, mesh=self.mesh,
-                    )
-            else:
-                for greedy in greedy_variants:
-                    (_, dev["last_tokens"], dev["seq_lens"], dev["active"],
-                     first_dev, self.paged) = self._warm_call(
-                        "prefill", self._jit_ragged,
-                        self.params, self.model_cfg, self.paged,
-                        dev["last_tokens"], dev["seq_lens"],
-                        dev["page_tables"], dev["active"], dev["caps"],
-                        dev["seeds"], dev["temperature"], dev["top_p"],
-                        dev["top_k"], *pre,
-                        greedy=greedy, eos_id=self.tokenizer.eos_id,
-                        candidates=self.config.top_p_candidates,
-                        mesh=self.mesh,
-                    )
-            merge_args = (
-                dev["last_tokens"], dev["seq_lens"],
-                dev["page_tables"], dev["active"], dev["caps"],
-                dev["temperature"], dev["top_p"], dev["top_k"],
-                dev["seeds"],
-                first_dev, np.int32(0), np.int32(0),
-                np.int32(1), np.int32(2), np.float32(0.0),
-                np.float32(1.0), np.int32(0), zrow,
-                np.zeros((2,), np.int32),
-            )
-            if self._spec:
-                self._jit_merge(
-                    *merge_args, dev["accept_ewma"], dev["gamma_lane"],
-                    np.int32(self._gamma_max),
-                    eos_id=self.tokenizer.eos_id, spec=True,
-                )
-            else:
-                self._jit_merge(*merge_args, eos_id=self.tokenizer.eos_id)
-        bucket_list = () if self._ragged else cfg.prefill_buckets
-        for bucket in bucket_list:
+        for bucket in cfg.prefill_buckets:
             for n in pads:
                 window = (
                     jax.device_put(
@@ -3098,8 +2397,8 @@ class InferenceEngine:
 
     def _note_first_token(self, slot_idx: int, slot: _Slot,
                           **prefill_attrs) -> None:
-        """A request's first token is in hand (bucketed, batched, chunked
-        and ragged prefill all funnel through _resolve_slot; a handoff
+        """A request's first token is in hand (bucketed, batched and
+        chunked prefill all funnel through _resolve_slot; a handoff
         resume comes from _admit_resume): stamp it, file the three TTFT
         phases, and give a traced request its `prefill_wait` (admitted,
         tokenized, held on the host), `prefill` (dispatched: device queue,
@@ -3619,27 +2918,32 @@ class InferenceEngine:
         return C
 
     def _upload_slot_state(self) -> None:
+        # A COPY of each mirror goes up, never the mirror: on the CPU
+        # backend device_put of a 64-byte-aligned numpy array is
+        # zero-copy, and a dispatch issued on this state runs later — it
+        # would read the mirror writes of a merge made in between (a
+        # lane active beside a sequence length still 0 emits one token
+        # of garbage; tests/test_engine_streams.py).
+        def put(mirror, sharding):
+            return jax.device_put(mirror.copy(), sharding)
+
         self._dev = {
-            "last_tokens": jax.device_put(self._last_tokens, self._dp_vec),
-            "seq_lens": jax.device_put(self._seq_lens, self._dp_vec),
-            "page_tables": jax.device_put(self._page_tables, self._dp_mat),
-            "active": jax.device_put(self._active, self._dp_vec),
-            "caps": jax.device_put(self._caps, self._dp_vec),
-            "temperature": jax.device_put(self._temperature, self._dp_vec),
-            "top_p": jax.device_put(self._top_p, self._dp_vec),
-            "top_k": jax.device_put(self._top_k, self._dp_vec),
-            "seeds": jax.device_put(self._seeds, self._dp_mat),
+            "last_tokens": put(self._last_tokens, self._dp_vec),
+            "seq_lens": put(self._seq_lens, self._dp_vec),
+            "page_tables": put(self._page_tables, self._dp_mat),
+            "active": put(self._active, self._dp_vec),
+            "caps": put(self._caps, self._dp_vec),
+            "temperature": put(self._temperature, self._dp_vec),
+            "top_p": put(self._top_p, self._dp_vec),
+            "top_k": put(self._top_k, self._dp_vec),
+            "seeds": put(self._seeds, self._dp_mat),
         }
         if self._spec:
             # Per-lane gamma dial (ISSUE 19): device-resident like the
             # rest of the slot state; the mirrors were refreshed from the
             # last processed round's packed stat columns.
-            self._dev["accept_ewma"] = jax.device_put(
-                self._lane_ewma, self._dp_vec
-            )
-            self._dev["gamma_lane"] = jax.device_put(
-                self._lane_gamma, self._dp_vec
-            )
+            self._dev["accept_ewma"] = put(self._lane_ewma, self._dp_vec)
+            self._dev["gamma_lane"] = put(self._lane_gamma, self._dp_vec)
         self._dev_dirty = False
 
     def _dispatch_step(self):
@@ -3679,34 +2983,6 @@ class InferenceEngine:
         spec_on = self._spec and (
             self.config.top_p_candidates > 0 or all_untruncated
         )
-        if self._ragged:
-            # Ragged mode (ISSUE 12): any pending prefill work rides ONE
-            # mixed dispatch with the decode lanes' single tokens; pure-
-            # decode iterations fall through to the K-step block (or spec
-            # round) below (the PR 6 amortization is untouched at steady
-            # state). Spec engines (ISSUE 19): the same mixed dispatch
-            # carries the verify windows — prefill chunks, plain decode
-            # lanes, and gamma-token spec lanes in ONE ragged call; the
-            # gate-fail fallback (no prefilter + truncated sampled row)
-            # keeps the plain ragged dispatch, trading acceptance, never
-            # correctness.
-            ranges = self._build_ragged_batch()
-            if ranges:
-                block = (
-                    self._dispatch_ragged_spec(ranges)
-                    if spec_on else self._dispatch_ragged(ranges)
-                )
-                if block is not None:
-                    return block
-            if not self._active.any():
-                # Prefill-only iteration that dispatched nothing (e.g.
-                # contained failure): no decode block to fall through to.
-                return None
-            # A contained failure may have retired lanes; refresh the
-            # active view for the lane counts below (the spec gate only
-            # ever loses truncated rows to a retirement, so `spec_on`
-            # stays valid).
-            act = self._active
         dev = self._dev
         if spec_on:
             spec_candidates = (
@@ -3949,7 +3225,7 @@ class InferenceEngine:
             return
         t_sync = time.monotonic()
         with self._phase("readback_wait"), _host_crossing("block-packed"):
-            # polylint: disable=PL001(block resolve point; one packed D2H read per block), PL008(process-side read; reachable from dispatch only via the ragged merge's dev-dirty cold path, behind a full pipeline drain)
+            # polylint: disable=PL001(block resolve point; one packed D2H read per block)
             packed = np.asarray(data)     # [K, B]; blocks until block done
         # Host stall: how long the processed frontier blocked waiting for
         # this block's copy to land — ~0 when lookahead hid the roundtrip,
@@ -4112,7 +3388,7 @@ class InferenceEngine:
         packed_dev = data
         t_sync = time.monotonic()
         with self._phase("readback_wait"), _host_crossing("spec-packed"):
-            # polylint: disable=PL001(spec-round resolve point; the ONE packed D2H read carries tokens, counts, and the gamma dial), PL008(process-side read; dispatch reaches it only via the merge drain cold path)
+            # polylint: disable=PL001(spec-round resolve point; the ONE packed D2H read carries tokens, counts, and the gamma dial)
             packed = np.asarray(packed_dev)  # [B, gamma+1+SPEC_STAT_COLS]
         stall_ms = (time.monotonic() - t_sync) * 1e3
         # Stat columns (spec_decode.SPEC_STAT_COLS): per-lane accepted /

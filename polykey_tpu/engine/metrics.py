@@ -88,8 +88,6 @@ class EngineMetrics:
         self.requests_failed = 0
         self.tokens_generated = 0
         self.decode_steps = 0
-        self.ttft_ms_sum = 0.0
-        self.ttft_ms_count = 0
         # Latency histograms (observe() is internally locked; kept outside
         # self._lock so a scrape rendering them never contends the step
         # loop's counter lock).
@@ -141,10 +139,8 @@ class EngineMetrics:
         # Decode blocks charge slots×steps dispatched / lanes×steps
         # useful (dead-lane padding); bucketed prefill charges the
         # padded group width (n_pad × bucket, or the chunk width C) vs
-        # the real token count; the ragged dispatch charges its static
-        # stream width vs the tokens appended. tokens_useful /
-        # tokens_dispatched is the occupancy-soak's padding-waste
-        # ratio — the number the ragged path exists to raise.
+        # the real token count. tokens_useful / tokens_dispatched is
+        # the occupancy-soak's padding-waste ratio.
         self.tokens_dispatched_total = 0
         self.tokens_useful_total = 0
         # Engine phases, request phases and lane-step outcomes
@@ -173,17 +169,15 @@ class EngineMetrics:
         self.admit_deferred = {"no_slot": 0, "no_pages": 0, "budget": 0}
         # Decode lane-steps by OUTCOME, counted when a block is
         # processed (on_lane_steps): delivered + overshoot + dead =
-        # sum of slots x steps over processed blocks. Plain blocks,
-        # ragged blocks (steps = 1) and spec rounds (steps = gamma + 1;
-        # a rejected draft position is a lane-step that delivered
-        # nothing and lands in overshoot) all keep the identity.
+        # sum of slots x steps over processed blocks. Plain blocks
+        # and spec rounds (steps = gamma + 1; a rejected draft position
+        # is a lane-step that delivered nothing and lands in overshoot)
+        # both keep the identity.
         self.decode_lane_steps_delivered = 0
         self.decode_lane_steps_overshoot = 0
         self.decode_lane_steps_dead = 0
         # Prefill rows computed vs real prompt tokens, bucketed groups
-        # and chunks only (on_prefill_rows). The ragged dispatches mix
-        # decode and prefill rows in one stream and stay on the
-        # tokens_dispatched / tokens_useful pair alone.
+        # and chunks (on_prefill_rows).
         self.prefill_rows_dispatched = 0
         self.prefill_rows_useful = 0
         # Deepest in-flight target any dispatch ran with (on_dispatch).
@@ -297,7 +291,7 @@ class EngineMetrics:
 
     def on_padding_tokens(self, dispatched: int, useful: int) -> None:
         """Token rows computed vs useful for one prefill dispatch
-        (bucketed group / chunk / ragged stream) — the padding-waste
+        (bucketed group / chunk) — the padding-waste
         counters the occupancy soak diffs."""
         with self._lock:
             self.tokens_dispatched_total += dispatched
@@ -533,9 +527,6 @@ class EngineMetrics:
                             dur if self._service_ewma_s == 0.0
                             else 0.8 * self._service_ewma_s + 0.2 * dur
                         )
-            if ttft > 0:
-                self.ttft_ms_sum += ttft
-                self.ttft_ms_count += 1
         if ttft > 0:
             self.ttft_hist.observe(ttft, trace_id=trace_id)
         if timings.device_ms > 0:
@@ -544,11 +535,6 @@ class EngineMetrics:
 
     def snapshot(self) -> dict:
         with self._lock:
-            mean_ttft = (
-                self.ttft_ms_sum / self.ttft_ms_count
-                if self.ttft_ms_count
-                else 0.0
-            )
             # The throughput window only advances inside on_step, so on an
             # idle engine the last busy window's rate would be reported
             # forever (now also scraped as polykey_tokens_per_sec —
@@ -583,7 +569,6 @@ class EngineMetrics:
                 "tokens_generated": self.tokens_generated,
                 "decode_steps": self.decode_steps,
                 "tokens_per_sec": round(self.tokens_per_sec, 2),
-                "mean_ttft_ms": round(mean_ttft, 2),
                 "blocks_dispatched": self.blocks_dispatched,
                 "lane_steps": self.lane_steps,
                 "steps_dispatched": self.steps_dispatched,
@@ -593,7 +578,7 @@ class EngineMetrics:
                 "tokens_dispatched": self.tokens_dispatched_total,
                 "tokens_useful": self.tokens_useful_total,
                 # Fraction of computed token rows that were useful work
-                # (1 − padding waste) — the dial the ragged path raises.
+                # (1 − padding waste).
                 "tokens_useful_fraction": (
                     round(self.tokens_useful_total
                           / self.tokens_dispatched_total, 4)
@@ -666,10 +651,6 @@ class EngineMetrics:
             p50, p95 = self.host_stall_hist.percentiles(50, 95)
             snap["host_stall_ms_p50"] = round(p50, 2)
             snap["host_stall_ms_p95"] = round(p95, 2)
-        if self.device_ms_hist.count:
-            p50, p95 = self.device_ms_hist.percentiles(50, 95)
-            snap["request_device_ms_p50"] = round(p50, 2)
-            snap["request_device_ms_p95"] = round(p95, 2)
         if self.kv_restore_hist.count:
             p50, p95 = self.kv_restore_hist.percentiles(50, 95)
             snap["kv_restore_ms_p50"] = round(p50, 2)

@@ -53,16 +53,6 @@ gates on the whole batch) — so spec-enabled engines guarantee greedy
 exactness and distributional reproducibility, not draw-for-draw
 batch-independence; plain engines guarantee the full contract.
 
-`ragged_spec_fn` lifts the spec×ragged exclusion (ISSUE 19 tentpole b):
-the gamma+1-token verify windows ride the flat ragged token stream as
-ordinary per-sequence ranges in the scalar-prefetch metadata — rows
-[0, B·(gamma+1)) are the verify windows, rows [B·(gamma+1), +W) the
-prefill stream — so ONE mixed dispatch serves prefill chunks AND spec
-verify lanes. The draft model runs its own ragged forward over the SAME
-flat stream: for verify rows that is the draft-cache sync rewrite, for
-prefill rows it is the draft-cache prompt prefill — one pass does both
-jobs the bucketed path needed spec_prefill_fn + a window rewrite for.
-
 All functions are pure; the engine jits them with its mesh out_shardings.
 """
 
@@ -72,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
-from ..models.transformer import forward_paged, forward_ragged, unembed
+from ..models.transformer import forward_paged, unembed
 from .sampling import (
     _row_categorical,
     lane_keys,
@@ -120,51 +110,16 @@ def _lane_tagger(seeds):
     return tagged
 
 
-def _draft_scan(
-    d_params, d_cfg, d_paged, last_tokens, pos, page_tables, greedy_row,
-    temp, eff_top_p, eff_top_k, tagged, gamma, candidates, mesh,
-):
-    """Draft gamma tokens autoregressively (bandwidth-light model).
-
-    Returns (d_paged, drafts [B, gamma], d_dists [B, gamma, V])."""
-
-    def draft_step(carry, _):
-        d_paged, tok, p = carry
-        hidden, d_paged = forward_paged(
-            d_params, d_cfg, tok[:, None], p[:, None], d_paged, page_tables,
-            mesh=mesh,
-        )
-        logits = unembed(d_params, d_cfg, hidden[:, 0])   # [B, V]
-        dist = (
-            truncated_dist(logits, temp, eff_top_p, eff_top_k, candidates)
-            if candidates
-            else jax.nn.softmax(logits / temp[:, None], axis=-1)
-        )
-        sampled = _row_categorical(
-            tagged(p + 1, 101), jnp.log(jnp.maximum(dist, 1e-20))
-        )
-        nxt = jnp.where(
-            greedy_row, jnp.argmax(logits, axis=-1).astype(jnp.int32), sampled
-        )
-        return (d_paged, nxt, p + 1), (nxt, dist)
-
-    (d_paged, _, _), (drafts, d_dists) = jax.lax.scan(
-        draft_step, (d_paged, last_tokens, pos), None, length=gamma
-    )
-    drafts = drafts.T                                     # [B, gamma]
-    d_dists = jnp.swapaxes(d_dists, 0, 1)                 # [B, gamma, V]
-    return d_paged, drafts, d_dists
-
-
 def _accept_merge(
     t_logits, drafts, d_dists, last_tokens, seq_lens, active, caps,
     accept_ewma, gamma_lane, pos, greedy_row, temp, eff_top_p, eff_top_k,
     tagged, *, gamma: int, gamma_low: int, gamma_max: int, eos_id: int,
     candidates: int,
 ):
-    """The fused accept/merge core (ISSUE 19 tentpole a) — shared by
-    spec_decode_fn (bucketed) and ragged_spec_fn so the acceptance math,
-    truncation, and the gamma dial cannot drift between dispatch modes.
+    """The fused accept/merge core of spec_decode_fn (ISSUE 19 tentpole
+    a): acceptance math, truncation and the gamma dial, callable on its
+    own logits and drafts (scripts/spec_smoke.py holds it to a host
+    reference).
 
     Acceptance: exact-match for greedy rows, rejection sampling else
     (shared math: models/speculative.py rejection_accept /
@@ -331,7 +286,7 @@ def spec_decode_fn(
     gamma: int, eos_id: int, gamma_low: int | None = None,
     gamma_max: int | None = None, candidates: int = 0, mesh=None,
 ):
-    """One draft/verify round for the whole slot batch (bucketed path).
+    """One draft/verify round for the whole slot batch.
 
     Returns (packed [B, gamma+1+SPEC_STAT_COLS] — emit token id within
     each row's emitted prefix, -1 beyond it, then the stat columns, so
@@ -357,10 +312,32 @@ def spec_decode_fn(
     eff_top_p = jnp.where(greedy_row, 1.0, top_p)         # [B]
     eff_top_k = jnp.where(greedy_row, 0, top_k)           # [B]
 
-    d_paged, drafts, d_dists = _draft_scan(
-        d_params, d_cfg, d_paged, last_tokens, pos, page_tables, greedy_row,
-        temp, eff_top_p, eff_top_k, tagged, gamma, candidates, mesh,
+    # --- Draft gamma tokens autoregressively (bandwidth-light model). ---
+    def draft_step(carry, _):
+        d_paged, tok, p = carry
+        hidden, d_paged = forward_paged(
+            d_params, d_cfg, tok[:, None], p[:, None], d_paged, page_tables,
+            mesh=mesh,
+        )
+        logits = unembed(d_params, d_cfg, hidden[:, 0])   # [B, V]
+        dist = (
+            truncated_dist(logits, temp, eff_top_p, eff_top_k, candidates)
+            if candidates
+            else jax.nn.softmax(logits / temp[:, None], axis=-1)
+        )
+        sampled = _row_categorical(
+            tagged(p + 1, 101), jnp.log(jnp.maximum(dist, 1e-20))
+        )
+        nxt = jnp.where(
+            greedy_row, jnp.argmax(logits, axis=-1).astype(jnp.int32), sampled
+        )
+        return (d_paged, nxt, p + 1), (nxt, dist)
+
+    (d_paged, _, _), (drafts, d_dists) = jax.lax.scan(
+        draft_step, (d_paged, last_tokens, pos), None, length=gamma
     )
+    drafts = drafts.T                                     # [B, gamma]
+    d_dists = jnp.swapaxes(d_dists, 0, 1)                 # [B, gamma, V]
 
     # --- Verify: ONE target forward over [prev, drafts] (gamma+1 wide —
     # prefill-shaped MXU work instead of gamma bandwidth-bound steps). -----
@@ -388,131 +365,4 @@ def spec_decode_fn(
     return (
         packed, new_last, new_seq_lens, new_active, new_ewma,
         new_gamma_lane, t_paged, d_paged,
-    )
-
-
-def ragged_spec_fn(
-    t_params, d_params, t_cfg: ModelConfig, d_cfg: ModelConfig,
-    t_paged, d_paged,
-    last_tokens, seq_lens, page_tables, active, caps, seeds, temperature,
-    top_p, top_k, accept_ewma, gamma_lane,
-    pre_tokens, pre_pos, pre_table_idx, pre_tables,
-    pre_range_start, pre_range_len, pre_range_kv, pre_range_table,
-    pre_sample_idx, pre_sample_pos, pre_seeds, pre_temp, pre_top_p,
-    pre_top_k,
-    *, gamma: int, eos_id: int, gamma_low: int | None = None,
-    gamma_max: int | None = None, greedy: bool = False,
-    candidates: int = 0, mesh=None,
-):
-    """ONE ragged dispatch for mixed prefill + SPEC VERIFY lanes (ISSUE 19
-    tentpole b — the lifted spec×ragged exclusion): every decode lane runs
-    a full draft/verify round AND up to `W` prefill tokens advance, in one
-    flat ragged forward per model.
-
-    Layout: flat rows [0, B·(gamma+1)) are the verify windows ([prev,
-    drafts] per lane, lane-major — lane b's window is rows b·(gamma+1)..);
-    rows [B·(gamma+1), +W) are the prefill stream, with the same
-    `pre_*` operand contract as engine._ragged_fn (pre_table_idx == B →
-    the all-garbage table row; unused ranges sit past the stream end).
-    The verify windows enter the ragged sequence metadata as ordinary
-    per-sequence ranges: starts b·(gamma+1), length gamma+1, kv frontier
-    max(seq_lens,1)+gamma — gamma-token speculation IS just a ragged
-    range, which is the whole point.
-
-    The draft model's ragged forward runs over the SAME flat stream:
-    verify rows give the draft-cache sync rewrite (the bucketed path's
-    post-scan window forward), prefill rows give the draft-cache prompt
-    prefill (the bucketed path's spec_prefill_fn second forward) — one
-    pass, both jobs.
-
-    Sampling mirrors the bucketed paths EXACTLY: verify lanes use the
-    shared _accept_merge core (greedy rows reproduce the target's greedy
-    chain bit-for-bit), and per slot b `pre_sample_idx[b]` names the
-    prefill-stream row whose hidden state samples that slot's FIRST token
-    at position key `pre_sample_pos[b]`, exactly as in _ragged_fn (the
-    host merges only final-chunk slots; other rows' draws are discarded).
-
-    Returns (packed [B, gamma+1+SPEC_STAT_COLS], new_last, new_seq_lens,
-    new_active, new_ewma, new_gamma_lane, first [B], t_paged, d_paged).
-    """
-    if gamma_low is None:
-        gamma_low = gamma
-    if gamma_max is None:
-        gamma_max = gamma
-    B = last_tokens.shape[0]
-    W = pre_tokens.shape[0]
-    G1 = gamma + 1
-    pos = jnp.maximum(seq_lens - 1, 0)
-    greedy_row = temperature == 0.0                       # [B]
-    temp = jnp.maximum(temperature, 1e-6)                 # [B]
-    tagged = _lane_tagger(seeds)
-    eff_top_p = jnp.where(greedy_row, 1.0, top_p)         # [B]
-    eff_top_k = jnp.where(greedy_row, 0, top_k)           # [B]
-
-    # Draft proposals: the same bandwidth-light autoregressive scan as the
-    # bucketed path (the draft runs B×1 paged steps — its work is not
-    # range-shaped; only the WIDE forwards ride the ragged stream).
-    d_paged, drafts, d_dists = _draft_scan(
-        d_params, d_cfg, d_paged, last_tokens, pos, page_tables, greedy_row,
-        temp, eff_top_p, eff_top_k, tagged, gamma, candidates, mesh,
-    )
-
-    # --- Flat stream: B verify windows then the prefill stream. -----------
-    window = jnp.concatenate([last_tokens[:, None], drafts], axis=1)
-    w_pos = pos[:, None] + jnp.arange(G1, dtype=jnp.int32)[None, :]
-    tokens = jnp.concatenate([window.reshape(-1), pre_tokens])   # [B·G1+W]
-    positions = jnp.concatenate([w_pos.reshape(-1), pre_pos])
-    garbage_row = jnp.zeros_like(pre_tables[:1])
-    tables_ext = jnp.concatenate([pre_tables, garbage_row])      # [B+1, P]
-    token_tables = jnp.concatenate([
-        jnp.repeat(page_tables, G1, axis=0), tables_ext[pre_table_idx],
-    ])                                                           # [B·G1+W, P]
-    # Ragged sequence metadata: B verify ranges then the prefill ranges,
-    # starts ascending (unused prefill ranges sit past the stream end).
-    rng_starts = jnp.concatenate([
-        jnp.arange(B, dtype=jnp.int32) * G1, B * G1 + pre_range_start,
-    ])
-    rng_lens = jnp.concatenate([
-        jnp.full((B,), G1, jnp.int32), pre_range_len,
-    ])
-    rng_kv = jnp.concatenate([
-        jnp.maximum(seq_lens, 1) + gamma, pre_range_kv,
-    ])
-    seq_tables = jnp.concatenate(
-        [page_tables, tables_ext[pre_range_table]]
-    )                                                            # [2B, P]
-
-    hidden, t_paged = forward_ragged(
-        t_params, t_cfg, tokens, positions, t_paged, token_tables,
-        rng_starts, rng_lens, rng_kv, seq_tables, mesh=mesh,
-    )
-    t_logits = unembed(
-        t_params, t_cfg, hidden[: B * G1].reshape(B, G1, -1)
-    )                                                     # [B, gamma+1, V]
-    # Draft ragged forward over the same stream: window sync + prompt
-    # prefill in one pass (see module docstring).
-    _, d_paged = forward_ragged(
-        d_params, d_cfg, tokens, positions, d_paged, token_tables,
-        rng_starts, rng_lens, rng_kv, seq_tables, mesh=mesh,
-    )
-
-    packed, new_last, new_seq_lens, new_active, new_ewma, new_gamma_lane = (
-        _accept_merge(
-            t_logits, drafts, d_dists, last_tokens, seq_lens, active, caps,
-            accept_ewma, gamma_lane, pos, greedy_row, temp, eff_top_p,
-            eff_top_k, tagged, gamma=gamma, gamma_low=gamma_low,
-            gamma_max=gamma_max, eos_id=eos_id, candidates=candidates,
-        )
-    )
-
-    # Prefill first tokens: one row per slot, _ragged_fn verbatim (garbage
-    # for slots without a final chunk this dispatch — never read).
-    rows = hidden[B * G1 + jnp.clip(pre_sample_idx, 0, W - 1)]   # [B, H]
-    first = sample_tail(
-        unembed(t_params, t_cfg, rows), pre_seeds, pre_sample_pos,
-        pre_temp, pre_top_p, pre_top_k, greedy, candidates,
-    )
-    return (
-        packed, new_last, new_seq_lens, new_active, new_ewma,
-        new_gamma_lane, first, t_paged, d_paged,
     )

@@ -398,93 +398,6 @@ def forward_paged(
     return _run_paged_stack(params, cfg, tokens, positions, paged, attend)
 
 
-def forward_ragged(
-    params: dict,
-    cfg: ModelConfig,
-    tokens: jax.Array,               # [T] int32 flat token stream
-    positions: jax.Array,            # [T] int32 absolute positions
-    paged,                           # engine.kv_cache.PagedKV
-    token_tables: jax.Array,         # [T, P] int32 per-TOKEN table rows
-    seq_starts: jax.Array,           # [S] int32 ragged range starts
-    seq_lens: jax.Array,             # [S] int32 new-token counts
-    kv_lens: jax.Array,              # [S] int32 KV lengths (new incl.)
-    page_tables: jax.Array,          # [S, P] int32 per-SEQUENCE tables
-    mesh=None,
-):
-    """Forward pass over a RAGGED flat token stream (ISSUE 12): mixed
-    prefill and decode tokens from many sequences in ONE dispatch, each
-    attending over its own paged-KV window.
-
-    Position-wise compute (embed, norms, projections, RoPE, MLP) runs on
-    the stream as a [1, T] batch — per-row math identical to the
-    bucketed paths. KV writes go through paged_write's per-token path
-    (one batch row per token: B=T, T=1 — the decode write shape: the XLA
-    scatter off-TPU; on TPU that shape selects the decode write kernel,
-    which is NOT valid here — a chunk's tokens share pages — so the
-    engine refuses ragged dispatch on TPU until this step has a write of
-    its own, ops/paged_write_kernel.RAGGED_WRITE_MOSAIC_ERROR); attention
-    goes through ragged_paged_attention (kernel on TPU, per-token gather
-    fallback elsewhere — the bit-identity reference). Padding rows carry
-    position 0 and all-garbage table rows: they write to and attend over
-    the reserved garbage page, exactly like inactive decode lanes.
-
-    Returns (hidden [T, H], updated paged)."""
-    from ..ops.paged_attention import paged_write
-    from ..ops.ragged_paged_attention_kernel import (
-        ragged_gather_attention,
-        ragged_paged_attention,
-        use_ragged_kernel,
-    )
-
-    T = tokens.shape[0]
-    pos_row = positions.reshape(T, 1)
-
-    Hk, D = cfg.num_kv_heads, cfg.head_dim
-    # The ragged kernel runs un-shard_mapped (GSPMD cannot partition an
-    # opaque pallas_call, and no shard_map wrapping exists for the flat
-    # stream yet): ANY mesh extent > 1 — tp included — routes to the
-    # gather path, whose gathers/scatters GSPMD partitions as-is. A
-    # shard_mapped tp ragged kernel is first-hardware-window work.
-    kernel_ok = use_ragged_kernel(Hk, D) and (
-        mesh is None
-        or all(
-            mesh.shape.get(ax, 1) == 1 for ax in ("dp", "sp", "pp", "tp")
-        )
-    )
-
-    def attend(layer_idx, q, k, v, kc, vc):
-        # One batch row per token: the decode write shape (T==1 path).
-        tok_tables = _layer_tables(paged, layer_idx, token_tables)
-        kc, vc = paged_write(
-            kc, vc,
-            k.reshape(T, 1, *k.shape[2:]), v.reshape(T, 1, *v.shape[2:]),
-            tok_tables, pos_row, mesh=mesh,
-        )
-        window = _layer_window(cfg, layer_idx)
-        if kernel_ok:
-            ctx = ragged_paged_attention(
-                q[0], kc, vc,
-                _layer_tables(paged, layer_idx, page_tables),
-                seq_starts, seq_lens, kv_lens,
-                scale=cfg.q_scale,
-                logit_softcap=cfg.attn_logit_softcap,
-                window=window, force_kernel=True,
-            )
-        else:
-            ctx = ragged_gather_attention(
-                q[0], kc, vc, tok_tables, positions,
-                scale=cfg.q_scale,
-                logit_softcap=cfg.attn_logit_softcap,
-                window=window,
-            )
-        return ctx[None], kc, vc
-
-    x, paged = _run_paged_stack(
-        params, cfg, tokens[None], positions[None], paged, attend
-    )
-    return x[0], paged
-
-
 def make_sp_override(
     cfg: ModelConfig, mesh, positions: jax.Array, impl: str = "ring"
 ):

@@ -82,8 +82,8 @@ PHASES: dict[str, tuple[str, str]] = {
               "bucketed prefill groups (only while a request waits)"),
     "restore": ("loop", "one faulting slot's host->device page scatter"),
     "chunk": ("loop", "one chunk of one long prompt's prefill"),
-    "dispatch": ("loop", "_dispatch_step: one decode block, spec round "
-                 "or ragged dispatch, slot-state upkeep included"),
+    "dispatch": ("loop", "_dispatch_step: one decode block or spec "
+                 "round, slot-state upkeep included"),
     "resolve": ("loop", "first tokens whose copies landed: read, stamp, "
                 "hand to the client (only while one is pending)"),
     "process": ("loop", "one in-flight block: readback, emit, finish"),
@@ -91,8 +91,6 @@ PHASES: dict[str, tuple[str, str]] = {
     "prefill": ("nested", "the prefill program's dispatch call (bucketed "
                 "group or chunk), inside admit / chunk"),
     "decode": ("nested", "the decode block's dispatch call"),
-    "ragged": ("nested", "the ragged mixed dispatch call"),
-    "ragged_spec": ("nested", "the ragged spec dispatch call"),
     "spec_decode": ("nested", "the spec round's dispatch call"),
     "readback_wait": ("nested", "np.asarray on a block's packed tokens: "
                       "blocks until the device finished the block"),

@@ -371,7 +371,7 @@ def _decode_call(
     return acc, m[..., :1], l[..., :1]
 
 
-# What Mosaic says to every int8-KV stage (decode read, ragged read, write)
+# What Mosaic says to every int8-KV stage (decode read, write)
 # on TPU v5e with jax 0.9.0 / libtpu 0.0.34 (scripts/tpu_kernel_check.py,
 # 2026-09-26): the scale pages are [ps, Hk] slabs with Hk (8 or 16) in the
 # lane dimension, and a DMA slice must be a multiple of the 128-lane tile.

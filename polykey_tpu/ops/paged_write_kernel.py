@@ -36,8 +36,10 @@ layer · num_pages, so the aliased output IS the donated stacked pool.
 Garbage-page collisions are intended: inactive lanes all target page 0
 (engine convention, engine.py "Inactive slots"); several lanes then RMW
 page 0 concurrently and *some* full page wins — page 0 is never read
-unmasked. Active lanes never share a page (allocator invariant), so
-their full-page write-backs cannot clobber each other.
+unmasked. Active lanes never share a page (allocator invariant: the
+decode write is one row per lane, each lane a different sequence), so
+their full-page write-backs cannot clobber each other; rows that share
+a page must not be written as lanes of one wave.
 
 Hk*D must be 128-aligned for the folded data-pool DMA — the same
 `use_paged_kernel` gate as the read kernel. Off-TPU (and under
@@ -54,26 +56,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-# Why the engine refuses ragged dispatch on TPU (engine.py, next to the
-# int8-KV refusal). forward_ragged writes its flat token stream through
-# this kernel as B = stream-width single-row lanes. Two things stop that:
-# the kernel holds one page buffer and one DMA semaphore per lane per pool
-# and wave, so the default 520-token stream asks for more semaphore memory
-# than the core has (Mosaic's message below, 8B-int8 smoke on TPU v5e,
-# jax 0.9.0 / libtpu 0.0.34, 2026-09-26); and a narrower stream that did
-# compile would lose writes, because a prefill chunk's tokens share pages
-# and every lane of a wave writes back its own copy of the whole page (the
-# "active lanes never share a page" invariant above holds for decode
-# lanes only). The ragged step needs a write that groups rows by page.
-RAGGED_WRITE_MOSAIC_ERROR = (
-    "RESOURCE_EXHAUSTED: Allocation (size=4160) would exceed memory "
-    "(size=2048) :: #allocation4 [shape = 's32[1040]{0}', space=sflag, "
-    "size = 0x1040, tag = 'scratch operand'] :: paged_kv_write (the KV "
-    "write kernel takes one DMA semaphore and one page buffer per stream "
-    "token, and rows of one prefill chunk share pages)"
-)
 
 
 def _make_kernel(n_pools: int, B: int, ps: int):

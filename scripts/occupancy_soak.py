@@ -64,18 +64,14 @@ def sched_witness_verdict():
         schedwitness.load_witness(os.path.dirname(path)))
 
 
-def build_engine(args, ragged: bool = False, overrides: dict = None,
-                 params=None, draft_params=None):
+def build_engine(args, overrides: dict = None, params=None,
+                 draft_params=None):
     import dataclasses
 
     from polykey_tpu.engine.config import EngineConfig
     from polykey_tpu.engine.engine import InferenceEngine
 
     cfg = EngineConfig(
-        # Ragged dispatch (ISSUE 12): admissions/chunks ride one flat
-        # mixed prefill+decode dispatch instead of the bucket table —
-        # the padding-waste A/B this harness measures (--ab-ragged).
-        ragged_dispatch=ragged,
         model=args.model,
         dtype="float32",
         kv_dtype=args.kv_dtype,
@@ -135,14 +131,6 @@ def main() -> int:
                     help="exit 1 when measured avg_lanes/slots is below")
     ap.add_argument("--seed", type=int, default=29)
     ap.add_argument("--out", default="")
-    ap.add_argument("--ragged", action="store_true",
-                    help="enable the ragged mixed prefill+decode "
-                         "dispatch (ISSUE 12)")
-    ap.add_argument("--ab-ragged", action="store_true",
-                    help="run the soak TWICE — bucketed baseline then "
-                         "ragged — same seed and knobs, and write ONE "
-                         "combined artifact with the measured "
-                         "padding-waste reduction (ISSUE 12 acceptance)")
     ap.add_argument("--ab-spec", action="store_true",
                     help="speculative-round A/B (ISSUE 19): train the "
                          "sweep's Markov target+draft pair, then run the "
@@ -192,34 +180,8 @@ def run_main(args) -> int:
         return run_hostkv_main(args)
     if getattr(args, "ab_spec", False):
         return run_spec_ab(args)
-    if args.ab_ragged:
-        if args.timeline:
-            # One flag, two engines — ambiguous target. Refuse loudly
-            # instead of silently writing neither.
-            log("--timeline is not supported with --ab-ragged (two "
-                "engines, one path); run the modes separately for a "
-                "Perfetto trace")
-            return 2
-        log("=== A/B: bucketed baseline ===")
-        bucketed = run_soak(args, ragged=False)
-        log("=== A/B: ragged ===")
-        ragged = run_soak(args, ragged=True)
-        result = {
-            "mode": "ab_ragged",
-            "bucketed": bucketed,
-            "ragged": ragged,
-            # The acceptance number: padding waste (1 − useful/dispatched)
-            # bucketed vs ragged at equal offered load and seed.
-            "padding_waste_bucketed": bucketed["padding_waste"],
-            "padding_waste_ragged": ragged["padding_waste"],
-            "waste_reduction": round(
-                bucketed["padding_waste"] - ragged["padding_waste"], 4
-            ),
-        }
-        failures = (bucketed["failed_in_window"] + ragged["failed_in_window"])
-    else:
-        result = run_soak(args, ragged=args.ragged)
-        failures = result["failed_in_window"]
+    result = run_soak(args)
+    failures = result["failed_in_window"]
 
     verdict = sched_witness_verdict()
     if verdict is not None:
@@ -243,26 +205,17 @@ def run_main(args) -> int:
     if failures:
         log(f"FAIL: {failures} requests errored inside the window")
         return 1
-    gates = (
-        [result] if not args.ab_ragged
-        else [result["bucketed"], result["ragged"]]
-    )
-    for res in gates:
-        if args.min_occupancy and res["occupancy"] < args.min_occupancy:
-            log(f"FAIL: occupancy {res['occupancy']:.3f} < "
-                f"{args.min_occupancy}")
-            return 1
-        log(f"OK: {res['avg_lanes']:.2f}/{args.slots} lanes "
-            f"(occupancy {res['occupancy']:.3f}, padding waste "
-            f"{res['padding_waste']:.3f}) over {res['window_s']:.0f}s")
-    if args.ab_ragged:
-        log(f"padding waste: bucketed {result['padding_waste_bucketed']:.3f}"
-            f" -> ragged {result['padding_waste_ragged']:.3f} "
-            f"(reduction {result['waste_reduction']:.3f})")
+    if args.min_occupancy and result["occupancy"] < args.min_occupancy:
+        log(f"FAIL: occupancy {result['occupancy']:.3f} < "
+            f"{args.min_occupancy}")
+        return 1
+    log(f"OK: {result['avg_lanes']:.2f}/{args.slots} lanes "
+        f"(occupancy {result['occupancy']:.3f}, padding waste "
+        f"{result['padding_waste']:.3f}) over {result['window_s']:.0f}s")
     return 0
 
 
-def run_soak(args, ragged: bool, overrides: dict = None,
+def run_soak(args, overrides: dict = None,
              params=None, draft_params=None, corpus_fn=None) -> dict:
     rng = np.random.default_rng(args.seed)
 
@@ -285,7 +238,7 @@ def run_soak(args, ragged: bool, overrides: dict = None,
 
     from polykey_tpu.engine.engine import GenRequest
 
-    engine = build_engine(args, ragged=ragged, overrides=overrides,
+    engine = build_engine(args, overrides=overrides,
                           params=params, draft_params=draft_params)
     try:
         def completed() -> int:
@@ -407,7 +360,6 @@ def run_soak(args, ragged: bool, overrides: dict = None,
         result = {
             "config": {
                 "slots": args.slots, "model": args.model,
-                "ragged": ragged,
                 "kv_dtype": args.kv_dtype or "fp",
                 "max_new": args.max_new, "block_steps": args.block,
                 "prefill_budget": stats1["prefill_budget"],
@@ -483,10 +435,8 @@ def run_soak(args, ragged: bool, overrides: dict = None,
             "tok_s": round(tokens / window_s, 1) if window_s else None,
             # Padding-waste accounting (ISSUE 12), first-class: token
             # rows the device computed vs rows that were useful work
-            # over the window (decode dead lanes + prefill padding —
-            # bucket/pad-group padding on the bucketed path, stream-tail
-            # padding on the ragged path). waste = 1 − useful/dispatched
-            # is the number the ragged dispatch exists to cut.
+            # over the window (decode dead lanes + bucket/pad-group
+            # prefill padding). waste = 1 − useful/dispatched.
             "tokens_dispatched": tokens_dispatched,
             "tokens_useful": tokens_useful,
             "tokens_useful_fraction": round(
@@ -511,7 +461,7 @@ def run_soak(args, ragged: bool, overrides: dict = None,
                 "drafts_accepted": stats1.get("drafts_accepted"),
             }
 
-        if args.timeline and not args.ab_ragged and engine.timeline is not None:
+        if args.timeline and engine.timeline is not None:
             from polykey_tpu.obs.timeline import engine_timelines, to_perfetto
 
             trace = to_perfetto(
@@ -620,8 +570,7 @@ def run_spec_ab(args) -> int:
         "spec_gamma": args.spec_gamma,
     }
     log("=== leg 1/3: plain (trained target, no draft) ===")
-    plain = run_soak(args, ragged=args.ragged, params=target_params,
-                     corpus_fn=corpus)
+    plain = run_soak(args, params=target_params, corpus_fn=corpus)
     # The two SPEC legs share one initial rate — the plain leg's
     # MEASURED completed throughput with 30% headroom — and then track
     # the same 2-4x-slots backlog band the plain leg used. Stall/gap
@@ -643,11 +592,11 @@ def run_spec_ab(args) -> int:
         f"2-4x-slots backlog band")
     log("=== leg 2/3: spec, host-loop crossing schedule (emulated) ===")
     host = run_soak(
-        spec_args, ragged=args.ragged,
+        spec_args,
         overrides={**spec_over, "spec_host_sync": True},
         params=target_params, draft_params=draft_params, corpus_fn=corpus)
     log("=== leg 3/3: spec, device-resident rounds ===")
-    dev = run_soak(spec_args, ragged=args.ragged, overrides=spec_over,
+    dev = run_soak(spec_args, overrides=spec_over,
                    params=target_params, draft_params=draft_params,
                    corpus_fn=corpus)
 

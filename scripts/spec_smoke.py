@@ -9,12 +9,11 @@ Three fast gates, CPU-only:
    cap, and mixed per-lane dials — both with and without the top-p
    truncation path (candidates 0 / 8). A numpy reference independently
    checks the greedy rows' acceptance/emit columns.
-2. ENGINE: greedy streams are BIT-IDENTICAL across plain decode,
-   spec-on-bucketed, and spec-on-ragged engines at the same seed (the
-   unified dispatch serves prefill chunks + spec verify lanes in one
-   ragged call), with a chunked long prompt in the mix.
-3. ACCOUNTING: the spec engines actually speculated (drafts_proposed
-   > 0) and export the per-lane dial stats the autopilot reads.
+2. ENGINE: greedy streams are BIT-IDENTICAL between the plain and the
+   speculative engine at the same seed, with a chunked long prompt in
+   the mix.
+3. ACCOUNTING: the spec engine actually speculated (drafts_proposed
+   > 0) and exports the per-lane dial stats the autopilot reads.
 
 Exit nonzero on any mismatch — `make spec-smoke`, wired into ci-check
 and CI.
@@ -176,7 +175,6 @@ def engine_smoke() -> None:
     # throughput), and a bad draft exercises the rejection/correction
     # path far harder than a good one.
     spec = dataclasses.replace(base, draft_model="tiny-llama", spec_gamma=3)
-    spec_ragged = dataclasses.replace(spec, ragged_dispatch=True)
     specs = [
         dict(prompt="hi", max_new_tokens=8, seed=11),
         dict(prompt="abcdefgh" * 2, max_new_tokens=8, seed=11),
@@ -185,23 +183,15 @@ def engine_smoke() -> None:
     ]
     plain, _ = _serve(base, specs)
     for depth in (1, 2):
-        bucketed, bstats = _serve(spec, specs, depth=depth)
-        ragged, rstats = _serve(spec_ragged, specs, depth=depth)
-        assert bucketed == plain, (
-            f"depth {depth}: spec-on-bucketed diverged from plain:\n"
-            f"plain={plain}\nbucketed={bucketed}"
+        streams, stats = _serve(spec, specs, depth=depth)
+        assert streams == plain, (
+            f"depth {depth}: spec diverged from plain:\n"
+            f"plain={plain}\nspec={streams}"
         )
-        assert ragged == plain, (
-            f"depth {depth}: spec-on-ragged diverged from plain:\n"
-            f"plain={plain}\nragged={ragged}"
-        )
-        assert rstats["ragged"] is True
-        for name, stats in (("bucketed", bstats), ("ragged", rstats)):
-            assert stats["drafts_proposed"] > 0, (depth, name, stats)
-            assert stats["spec_gamma"] >= 1, (depth, name, stats)
-        log(f"depth {depth}: greedy bit-identity plain == spec-bucketed "
-            f"== spec-ragged OK "
-            f"(ragged proposed {rstats['drafts_proposed']} drafts)")
+        assert stats["drafts_proposed"] > 0, (depth, stats)
+        assert stats["spec_gamma"] >= 1, (depth, stats)
+        log(f"depth {depth}: greedy bit-identity plain == spec OK "
+            f"(proposed {stats['drafts_proposed']} drafts)")
 
 
 def main() -> int:

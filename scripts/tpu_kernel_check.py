@@ -1,13 +1,13 @@
 """Compile-and-compare check of every Pallas kernel on the attached TPU.
 
-Interpret-mode tests (tests/test_kernels.py, test_ragged.py) prove the
+Interpret-mode tests (tests/test_kernels.py) prove the
 math on the CPU; this proves Mosaic LOWERING at serving geometry
 (page_size 16, 32 lanes, 4k-position tables; 8B Hq=32/Hk=8/D=128, 1B
 D=64, Gemma-2 D=256 with softcap and window): each kernel is compiled on
 the chip and compared with its jnp reference path.
 
     fp decode · flash prefill · fp write · int8-KV read and write stages
-    · ragged (decode-only, prefill-only, mixed) · packed-int4 qdot
+    · packed-int4 qdot
 
 One line per (kernel, geometry): PASS with the max abs error, or FAIL
 with the head of the compiler's message (the whole message goes to
@@ -37,7 +37,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 PS, LANES, TABLE = 16, 32, 256          # page size, decode lanes, 4k/16
-PREFILL = 512                           # prefill bucket / ragged width
+PREFILL = 512                           # prefill bucket
 KERNEL = {"force_kernel": True}         # --interpret swaps in interpret=True
 
 # (label, Hq, Hk, D, softcap, window) — the served families' head shapes.
@@ -209,73 +209,6 @@ def check_write(quantized: bool) -> None:
              f"{label} B={LANES}", int8 if quantized else fp)
 
 
-def check_ragged() -> None:
-    """Flat streams at the 8B and 1B head shapes: 32 decode singles, one
-    512-token prefill range, and both at once (the engine's B + W
-    layout, decode rows first)."""
-    from polykey_tpu.ops.ragged_paged_attention_kernel import (
-        ragged_gather_attention,
-        ragged_paged_attention,
-    )
-
-    W = PREFILL
-    streams = {
-        # name: (decode lanes, prefill tokens)
-        "decode-only": (LANES, 0),
-        "prefill-only": (0, W),
-        "mixed": (LANES, W),
-    }
-    for label, Hq, Hk, D, softcap, window in GEOMETRIES[:2]:
-        for name, (n_dec, n_pre) in streams.items():
-            def run(Hq=Hq, Hk=Hk, D=D, n_dec=n_dec, n_pre=n_pre,
-                    quantized=False):
-                S = n_dec + (1 if n_pre else 0)
-                T = n_dec + n_pre
-                N = S * TABLE + 1
-                kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
-                q = jax.random.normal(kq, (T, Hq, D), jnp.bfloat16)
-                kp = jax.random.normal(kk, (N, PS, Hk * D), jnp.bfloat16)
-                vp = jax.random.normal(kv, (N, PS, Hk * D), jnp.bfloat16)
-                tables = (1 + np.arange(S * TABLE, dtype=np.int32)
-                          ).reshape(S, TABLE)
-                starts = np.arange(S, dtype=np.int32)
-                lens = np.ones((S,), np.int32)
-                kv_lens = np.linspace(
-                    7, TABLE * PS, S).astype(np.int32)
-                if n_pre:
-                    starts[-1], lens[-1] = n_dec, n_pre
-                    kv_lens[-1] = TABLE * PS // 4 + n_pre   # a later chunk
-                # Per-token rows for the gather reference.
-                seq_of = np.repeat(np.arange(S), lens)
-                pos = np.concatenate([
-                    kv_lens[s] - lens[s] + np.arange(lens[s])
-                    for s in range(S)
-                ]).astype(np.int32)
-                pools = (kp, vp)
-                if quantized:
-                    pools = (quantized_pool(kp, D), quantized_pool(vp, D))
-                kw = dict(scale=D ** -0.5)
-                # The reference gathers a whole 4k window per token:
-                # 64 tokens at a time keeps it inside HBM.
-                want = jnp.concatenate([
-                    ragged_gather_attention(
-                        q[i:i + 64], *pools,
-                        jnp.asarray(tables[seq_of[i:i + 64]]),
-                        jnp.asarray(pos[i:i + 64]), **kw)
-                    for i in range(0, T, 64)
-                ])
-                got = ragged_paged_attention(
-                    q, *pools, jnp.asarray(tables), jnp.asarray(starts),
-                    jnp.asarray(lens), jnp.asarray(kv_lens),
-                    **KERNEL, **kw)
-                return assert_close(got, want, 8e-2)
-
-            case("ragged-fp", f"{label} {name}", run)
-            if name == "mixed":
-                case("ragged-int8kv", f"{label} {name}",
-                     lambda run=run: run(quantized=True))
-
-
 def check_int4() -> None:
     """Packed-uint8 int4 weights through qdot at the 8B MLP shape, under
     jit (the unpack must fuse or at least lower), plus what this machine
@@ -361,7 +294,6 @@ def main() -> int:
     check_int4()
     check_decode(quantized=True)
     check_write(quantized=True)
-    check_ragged()
     failed = [r for r in RESULTS if not r[2]]
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)

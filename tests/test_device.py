@@ -110,7 +110,6 @@ def test_replica_pool_passes_the_pool_size_down():
 
 @pytest.mark.parametrize("option, message", [
     (dict(kv_dtype="int8"), "POLYKEY_KV_DTYPE.*aligned to tiling"),
-    (dict(ragged_dispatch=True), "POLYKEY_RAGGED.*RESOURCE_EXHAUSTED"),
 ])
 def test_options_whose_kernels_fail_on_tpu_are_refused_at_start(
     monkeypatch, option, message,

@@ -401,7 +401,7 @@ def test_disagg_excludes_replicas_and_draft():
 def test_unset_disagg_builds_no_pool(monkeypatch):
     # POLYKEY_DISAGG unset → from_env carries "" and the service
     # builder's disagg branch is unreachable (single-process paths
-    # byte-identical — the chaos/ragged/pool suites pin behavior).
+    # byte-identical — the chaos/pool suites pin behavior).
     monkeypatch.delenv("POLYKEY_DISAGG", raising=False)
     assert EngineConfig.from_env().disagg == ""
 

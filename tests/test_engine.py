@@ -516,12 +516,16 @@ def test_grpc_concurrent_streams(grpc_stack):
 
 def test_traced_request_span_tree(engine):
     """End-to-end tracing acceptance: a streaming generation through an
-    obs-wired stack leaves a span tree in the flight recorder whose
-    queue/prefill/decode/detokenize phases account for the request's
-    wall time, retrievable via the engine_stats tool."""
+    obs-wired stack leaves a span tree in the flight recorder with the
+    queue/prefill/decode/detokenize phases, every span nested inside its
+    parent, retrievable via the engine_stats tool."""
     from polykey_tpu.obs import Observability
 
     obs = Observability()
+    # The recorder files durations only; keep the spans themselves to
+    # check where each starts and ends.
+    recorded, record = [], obs.recorder.record
+    obs.recorder.record = lambda span: (recorded.append(span), record(span))
     service = TpuService(engine, obs=obs)
     logger = Logger(stream=io.StringIO(), level="debug")
     server, _, port = gateway_server.build_server(
@@ -552,16 +556,25 @@ def test_traced_request_span_tree(engine):
         assert blocks and sum(
             int(b["attrs"]["tokens"]) for b in blocks
         ) >= chunks[-1].usage.completion_tokens - 1
-        # The engine phases partition the request's wall time: their sum
-        # must land within the RPC's root duration, close to it (slack
-        # for RPC framing + scheduler jitter on busy CI hosts).
-        phase_ms = sum(
-            children[p]["duration_ms"]
-            for p in ("queue_wait", "prefill_wait", "prefill", "decode",
-                      "detokenize")
+        # Every span lies inside its parent (one monotonic clock), and
+        # the engine phases follow one another in order.
+        root = next(
+            s for s in recorded if s.attrs.get("tool") == "llm_generate"
         )
-        assert phase_ms <= trace["duration_ms"] * 1.05
-        assert phase_ms >= trace["duration_ms"] * 0.5
+
+        def assert_nested(parent):
+            for child in parent.children:
+                assert child.end is not None, child.name
+                assert parent.start <= child.start <= child.end <= parent.end, (
+                    parent.name, child.name)
+                assert_nested(child)
+
+        assert root.end is not None
+        assert_nested(root)
+        spans = {c.name: c for c in root.children}
+        order = ("queue_wait", "prefill_wait", "prefill", "decode")
+        for before, after in zip(order, order[1:]):
+            assert spans[before].end <= spans[after].start, (before, after)
 
         # TTFT/ITL percentiles (histogram-backed) surface in the stats.
         assert stats["ttft_ms_p50"] > 0
@@ -706,17 +719,40 @@ def test_adaptive_block_solo_vs_loaded():
             eng.submit(r)
             tokens, done, error = _collect(r)
             assert error is None and done is not None
-            # The always-on accumulators: the deepest in-flight target
-            # any dispatch ran with.
-            return (tokens, eng._last_dispatch_steps, eng._depth_target,
+            # The always-on accumulator (the deepest in-flight target
+            # any dispatch ran with) and the timeline ring's event order.
+            return (tokens, eng.timeline.events(),
                     eng.metrics.depth_target_max)
         finally:
             eng.shutdown()
 
-    solo_tokens, solo_k, solo_tail_depth, solo_max = run_solo(cfg)
-    static_tokens, static_k, static_tail_depth, static_max = run_solo(
-        static_cfg)
-    assert solo_k == 1 and static_k == 8
+    def dispatch_steps(events, loaded):
+        return {e["steps"] for e in events
+                if e["kind"] == "dispatch" and (e["lanes"] > 1) == loaded}
+
+    def assert_tail_capped(events, steps, need=11):
+        """In-flight work never exceeds what the stream still needs, in
+        ring order: dispatch i runs with a depth target of at most the
+        blocks the host still counted as needed (`need` decode tokens
+        less `steps` per block it had processed), and the loop drains to
+        target - 1 queued blocks before it dispatches again."""
+        dispatched = processed = 0
+        allowed = None
+        for e in events:
+            if e["kind"] == "process":
+                processed += 1
+            elif e["kind"] == "dispatch":
+                if allowed is not None:
+                    assert dispatched - processed <= allowed, (
+                        dispatched, processed, allowed)
+                dispatched += 1
+                needed = -(-max(0, need - steps * processed) // steps)
+                allowed = max(0, needed - 1)
+
+    solo_tokens, solo_events, solo_max = run_solo(cfg)
+    static_tokens, static_events, static_max = run_solo(static_cfg)
+    assert dispatch_steps(solo_events, loaded=False) == {1}
+    assert dispatch_steps(static_events, loaded=False) == {8}
     assert solo_tokens == static_tokens
     # Constant LOOKAHEAD steps MID-STREAM: shrinking K deepens the
     # pipeline so the queued-ahead work keeps covering the roundtrip —
@@ -727,11 +763,10 @@ def test_adaptive_block_solo_vs_loaded():
     assert solo_max >= 1 + (cfg.lookahead_blocks - 1) * 8, solo_max
     assert solo_max <= 1 + (cfg.lookahead_blocks - 1) * 8
     # Tail cap: in-flight work never exceeds what active streams still
-    # need — the final dispatches shrink to one block, so stream tails
-    # don't leave ~lookahead x K steps of dead full-batch work queued in
-    # front of the next arrival's prefill.
-    assert solo_tail_depth == 1, solo_tail_depth
-    assert static_tail_depth == 1, static_tail_depth
+    # need, so stream tails don't leave ~lookahead x K steps of dead
+    # full-batch work queued in front of the next arrival's prefill.
+    assert_tail_capped(solo_events, steps=1)
+    assert_tail_capped(static_events, steps=8)
     assert static_max <= cfg.lookahead_blocks
 
     # Under load (>1 active stream) the adaptive engine uses the full K.
@@ -743,7 +778,11 @@ def test_adaptive_block_solo_vs_loaded():
             eng.submit(r)
         outs = [_collect(r) for r in reqs]
         assert all(e is None for _, _, e in outs)
-        assert eng._last_dispatch_steps == 8
+        # Every dispatch that carried more than one live stream ran the
+        # full block; whatever ran with one stream left ran the solo one.
+        events = eng.timeline.events()
+        assert dispatch_steps(events, loaded=True) == {8}
+        assert dispatch_steps(events, loaded=False) <= {1}
     finally:
         eng.shutdown()
 

@@ -12,7 +12,6 @@ import queue
 import time
 
 import grpc
-import pytest
 
 from polykey_tpu.engine.config import EngineConfig
 from polykey_tpu.engine.engine import GenRequest, InferenceEngine
@@ -475,130 +474,3 @@ def test_spec_top_k_one_is_greedy_end_to_end():
         assert outs == plain
     finally:
         eng.shutdown()
-
-
-# -- spec × ragged unification (ISSUE 19) -------------------------------------
-#
-# The acceptance bar: gamma-token verify windows ride the flat token
-# stream as ordinary per-sequence ranges, so ONE mixed dispatch serves
-# prefill chunks, decode lanes, and spec verify lanes — and the greedy
-# stream stays bit-identical to the bucketed spec path AND the plain
-# engine at both lookahead depths, with chunked prompts in the mix.
-
-SPEC_RAGGED_CONFIG = dataclasses.replace(SPEC_CONFIG, ragged_dispatch=True)
-# Chunked prompt: 48 bytes > max bucket 32, so admission spans several
-# ragged/bucketed prefill dispatches while other lanes decode.
-MIXED_PROMPTS = ["hi", "abcdefgh" * 6, "draft and verify", "q"]
-
-
-def _serve_specs(config, depth=None, monkeypatch=None, max_new=8):
-    if depth is not None:
-        monkeypatch.setenv("POLYKEY_DISPATCH_LOOKAHEAD", str(depth))
-    eng = InferenceEngine(config)
-    try:
-        reqs = [
-            GenRequest(prompt=p, max_new_tokens=max_new, seed=11)
-            for p in MIXED_PROMPTS
-        ]
-        for r in reqs:
-            eng.submit(r)
-        outs = []
-        for r in reqs:
-            tokens, done, error = _collect(r)
-            assert error is None, error
-            assert done is not None
-            outs.append(tokens)
-        stats = eng.stats()
-    finally:
-        eng.shutdown()
-    return outs, stats
-
-
-@pytest.mark.parametrize("depth", [1, 2])
-def test_spec_ragged_greedy_bit_identical(depth, monkeypatch):
-    """THE unification acceptance criterion: greedy streams are
-    bit-identical across plain / spec-on-bucketed / spec-on-ragged at
-    lookahead depths 1 and 2, with a chunked prompt in the batch."""
-    plain, _ = _serve_specs(BASE_CONFIG, depth, monkeypatch)
-    bucketed, bsnap = _serve_specs(SPEC_CONFIG, depth, monkeypatch)
-    ragged, rsnap = _serve_specs(SPEC_RAGGED_CONFIG, depth, monkeypatch)
-    assert bucketed == plain
-    assert ragged == plain
-    assert rsnap["ragged"] is True
-    # Both spec paths really speculated.
-    assert bsnap["drafts_proposed"] > 0
-    assert rsnap["drafts_proposed"] > 0
-
-
-def test_spec_ragged_kill_switch(monkeypatch):
-    """POLYKEY_DISABLE_RAGGED on a spec+ragged config: the engine falls
-    back to the bucketed SPEC path (speculation survives, the flat
-    stream doesn't)."""
-    monkeypatch.setenv("POLYKEY_DISABLE_RAGGED", "1")
-    eng = InferenceEngine(SPEC_RAGGED_CONFIG)
-    try:
-        assert eng._ragged is False
-        assert eng._spec is True
-        r = GenRequest(prompt="still speculates", max_new_tokens=6)
-        eng.submit(r)
-        tokens, done, error = _collect(r)
-        assert error is None and done is not None and len(tokens) == 6
-        assert eng.metrics.snapshot().get("drafts_proposed", 0) > 0
-    finally:
-        eng.shutdown()
-
-
-def test_spec_ragged_mid_stream_supervisor_restart():
-    """Mid-stream supervisor restart on the unified path: an injected
-    step stall wedges the spec×ragged engine, the watchdog trips, the
-    supervisor swaps in a fresh engine — and the restarted engine's
-    greedy stream is STILL bit-identical to the plain engine's (restart
-    must not perturb determinism: seeds key on fold_in(seed, position),
-    not on engine lifetime)."""
-    from polykey_tpu import faults
-    from polykey_tpu.engine.supervisor import EngineSupervisor
-    from polykey_tpu.engine.watchdog import Watchdog
-    from polykey_tpu.gateway.health import SERVING, HealthService
-
-    plain, _ = _serve_specs(BASE_CONFIG)
-    cfg = dataclasses.replace(
-        SPEC_RAGGED_CONFIG, watchdog_timeout_s=0.25, supervise=False
-    )
-    faults.clear()
-    faults.install("step-stall=1.0@1")
-    engine = InferenceEngine(cfg)
-    health = HealthService()
-    health.set_serving_status("", SERVING)
-    watchdog = Watchdog(engine, health=health, check_interval_s=0.05)
-    watchdog.start()
-    supervisor = EngineSupervisor(
-        engine, lambda: InferenceEngine(cfg),
-        watchdog=watchdog, health=health,
-        max_restarts=2, restart_window_s=60.0,
-        check_interval_s=0.05, join_timeout_s=5.0,
-    ).start()
-    try:
-        victim = GenRequest(prompt=MIXED_PROMPTS[1], max_new_tokens=8,
-                            seed=11)
-        engine.submit(victim)
-        _, done_v, error_v = _collect(victim, timeout=15.0)
-        assert done_v is None and error_v is not None   # failed cleanly
-        deadline = time.monotonic() + 10.0
-        while supervisor.restarts < 1 and time.monotonic() < deadline:
-            time.sleep(0.05)
-        assert supervisor.restarts == 1
-        outs = []
-        for p in MIXED_PROMPTS:
-            r = GenRequest(prompt=p, max_new_tokens=8, seed=11)
-            supervisor.engine.submit(r)
-            tokens, done, error = _collect(r, timeout=60.0)
-            assert error is None and done is not None
-            outs.append(tokens)
-        assert outs == plain
-        assert supervisor.engine.stats()["ragged"] is True
-        assert supervisor.engine.metrics.snapshot()["drafts_proposed"] > 0
-    finally:
-        faults.clear()
-        supervisor.stop()
-        watchdog.stop()
-        supervisor.engine.shutdown()

@@ -345,19 +345,6 @@ def test_tiny_host_pool_pressure_never_kills_engine():
     assert stats["kv_host_capacity"] == 3
 
 
-def test_ragged_dispatch_with_host_tier_bit_identical():
-    """Ragged mode (ISSUE 12) composes: faulting slots are skipped by
-    the ragged batch builder until their restore issues, then their
-    suffix ranges ride the mixed dispatch — streams stay identical."""
-    cfg_r = dataclasses.replace(CFG, ragged_dispatch=True)
-    ref, _ = _serve(
-        dataclasses.replace(REF_CFG, ragged_dispatch=True), STICKY_MIX
-    )
-    out, stats = _serve(cfg_r, STICKY_MIX)
-    assert out == ref
-    assert stats["kv_pages_restored"] > 0
-
-
 def test_spec_engine_with_host_tier_greedy_exact():
     """Speculative engines + host tier: restores refill only the TARGET
     pool (the draft's prefix KV is lost with the device pages), which

@@ -13,7 +13,7 @@ Three things are held here.
   guard the next architecture's PR runs into first.
 - PARITY: paged prefill + decode equal the non-paged forward through every
   path that addresses the layout (XLA gather/scatter, the Pallas kernels in
-  interpret mode, int8 KV, tp = 2, ragged).
+  interpret mode, int8 KV, tp = 2).
 - BOUNDARY: the host tier and the handoff wire format keep [..., Hk, D];
   pages cross that boundary byte for byte, and a blob written by the tree
   BEFORE the fold (tests/data/kv_handoff_parent_pr30.pkkv) restores here.
@@ -46,7 +46,6 @@ from polykey_tpu.models.config import TINY_LLAMA, get_config
 from polykey_tpu.models.transformer import (
     forward,
     forward_paged,
-    forward_ragged,
     init_params,
 )
 from polykey_tpu.parallel.mesh import MeshConfig, create_mesh
@@ -296,15 +295,13 @@ def _interpret_kernels(monkeypatch):
 @pytest.mark.parametrize("path,kv_dtype,tp", [
     ("xla", None, 1), ("xla", jnp.int8, 1), ("xla", None, 2),
     ("pallas-interpret", None, 1), ("pallas-interpret", jnp.int8, 1),
-    ("pallas-interpret", None, 2), ("ragged", None, 1),
-    ("ragged", jnp.int8, 1),
+    ("pallas-interpret", None, 2),
 ])
 def test_paged_prefill_and_decode_match_forward(path, kv_dtype, tp,
                                                 monkeypatch):
-    """Prefill 5 tokens, decode 4 more one at a time (or feed the same
-    through the ragged stream): every hidden state equals the one-shot
-    non-paged forward. Two layers and non-contiguous pages, so a page of
-    one layer landing in another's range would show."""
+    """Prefill 5 tokens, decode 4 more one at a time: every hidden state
+    equals the one-shot non-paged forward. Two layers and non-contiguous
+    pages, so a page of one layer landing in another's range would show."""
     if path == "pallas-interpret":
         _interpret_kernels(monkeypatch)
     cfg = TINY_LLAMA
@@ -323,46 +320,18 @@ def test_paged_prefill_and_decode_match_forward(path, kv_dtype, tp,
         paged = jax.device_put(paged, paged_kv_sharding(mesh))
 
     got = []
-    if path == "ragged":
-        # Row 0's five-token chunk, then both rows token by token, as one
-        # flat stream per call (padding rows: position 0, garbage tables).
-        def stream(rows):               # rows: [(b, t)]
-            pad = -len(rows) % 8
-            b = np.array([r[0] for r in rows] + [0] * pad)
-            t = np.array([r[1] for r in rows] + [0] * pad)
-            live = np.arange(len(b)) < len(rows)
-            tok_tables = np.where(live[:, None], np.asarray(tables)[b], 0)
-            hidden, new = forward_ragged(
-                params, cfg, jnp.asarray(np.asarray(tokens)[b, t]),
-                jnp.asarray(np.where(live, t, 0), jnp.int32), paged_box[0],
-                jnp.asarray(tok_tables, jnp.int32),
-                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                jnp.zeros((1,), jnp.int32), tables[:1], mesh=mesh,
-            )
-            paged_box[0] = new
-            return {r: hidden[i] for i, r in enumerate(rows)}
-
-        paged_box = [paged]
-        out = stream([(0, t) for t in range(5)])
-        out.update(stream([(1, t) for t in range(5)]))
-        for t in range(5, T):
-            out.update(stream([(0, t), (1, t)]))
-        have = jnp.stack([
-            jnp.stack([out[(b, t)] for t in range(T)]) for b in range(B)
-        ])
-    else:
+    hidden, paged = forward_paged(
+        params, cfg, tokens[:, :5], positions[:, :5], paged, tables,
+        mesh=mesh,
+    )
+    got.append(hidden)
+    for t in range(5, T):
         hidden, paged = forward_paged(
-            params, cfg, tokens[:, :5], positions[:, :5], paged, tables,
-            mesh=mesh,
+            params, cfg, tokens[:, t:t + 1], positions[:, t:t + 1],
+            paged, tables, mesh=mesh,
         )
         got.append(hidden)
-        for t in range(5, T):
-            hidden, paged = forward_paged(
-                params, cfg, tokens[:, t:t + 1], positions[:, t:t + 1],
-                paged, tables, mesh=mesh,
-            )
-            got.append(hidden)
-        have = jnp.concatenate(got, axis=1)
+    have = jnp.concatenate(got, axis=1)
     err = float(jnp.max(jnp.abs(have - want)))
     if kv_dtype is None:
         assert err < 5e-4, err

@@ -134,7 +134,7 @@ def test_ttft_phases_sum_to_ttft(prompts):
     for r in requests:
         eng._submit.put(r)
     eng._admit()
-    while eng._has_pending_prefill():
+    while any(s is not None and s.pending is not None for s in eng._slots):
         eng._advance_chunked_prefills(None)
     eng._resolve_prefills(block=True)
     if len(prompts) > 1:

@@ -52,9 +52,9 @@ def blocking(findings, rule=None):
 def test_rule_table_lists_the_rules(capsys):
     assert sched.main(["--list-rules"]) == 0
     out = capsys.readouterr().out
-    for rule_id in ("SL000", "SL001", "SL002", "SL003", "SL004",
-                    "SL005", "SL006"):
+    for rule_id in ("SL000", "SL001", "SL002", "SL003", "SL004", "SL006"):
         assert rule_id in out
+    assert "SL005" not in out
 
 
 def test_only_typo_is_a_usage_error(capsys):
@@ -262,7 +262,7 @@ def test_sl003_ordered_frontiers_are_clean(tmp_path):
 def test_sl003_missing_faulting_slot_guard_fires(tmp_path):
     findings = schedlint(tmp_path, "polykey_tpu/engine/k.py", """\
         class Eng:
-            def _build_ragged_batch(self, width):
+            def _advance_chunked_prefills(self, width):
                 for s in self.slots:
                     if s.pending is None:
                         continue
@@ -279,7 +279,7 @@ def test_sl003_missing_faulting_slot_guard_fires(tmp_path):
 def test_sl003_guarded_builder_is_clean(tmp_path):
     findings = schedlint(tmp_path, "polykey_tpu/engine/l.py", """\
         class Eng:
-            def _build_ragged_batch(self, width):
+            def _advance_chunked_prefills(self, width):
                 for s in self.slots:
                     if s.pending is None:
                         continue
@@ -351,83 +351,6 @@ def test_sl004_bounded_ctor_shed_and_size_check_are_clean(tmp_path):
     assert not blocking(findings, "SL004")
 
 
-# -- SL005 quota conservation -------------------------------------------------
-
-
-CONSERVING_BUILDER = """\
-    class Eng:
-        def _build_ragged_batch(self, W):
-            ranges = []
-            spent = 0
-            for s in self.slots:
-                take = min(s.need, W - spent)
-                ranges.append((s.idx, take))
-                spent += take
-                if spent >= W:
-                    break
-            return ranges
-"""
-
-
-def test_sl005_conserving_builder_is_clean(tmp_path):
-    findings = schedlint(tmp_path, "polykey_tpu/engine/o.py",
-                         CONSERVING_BUILDER, only={"SL005"})
-    assert not blocking(findings, "SL005")
-
-
-def test_sl005_uncharged_builder_fires(tmp_path):
-    findings = schedlint(tmp_path, "polykey_tpu/engine/p.py", """\
-        class Eng:
-            def _build_ragged_batch(self, W):
-                ranges = []
-                for s in self.slots:
-                    take = min(s.need, W)
-                    ranges.append((s.idx, take))
-                return ranges
-    """, only={"SL005"})
-    hits = blocking(findings, "SL005")
-    assert len(hits) == 1
-    assert "does not charge" in hits[0].message
-
-
-def test_sl005_strict_budget_exit_fires(tmp_path):
-    findings = schedlint(
-        tmp_path, "polykey_tpu/engine/q.py",
-        CONSERVING_BUILDER.replace("if spent >= W:", "if spent > W:"),
-        only={"SL005"})
-    hits = blocking(findings, "SL005")
-    assert len(hits) == 1
-    assert "`spent >`" in hits[0].message
-
-
-def test_sl005_operands_identity_is_clean_and_teeth(tmp_path):
-    operands = """\
-        class Eng:
-            def _ragged_prefill_operands(self, reqs):
-                off = 0
-                useful = 0
-                lens = [0] * len(reqs)
-                for j, r in enumerate(reqs):
-                    width = r.width
-                    lens[j] = width
-                    off += width
-                    useful += width
-                return off, useful, lens
-    """
-    findings = schedlint(tmp_path, "polykey_tpu/engine/r.py", operands,
-                         only={"SL005"})
-    assert not blocking(findings, "SL005")
-    # Dropping one of the three same-width advances breaks the
-    # sum(lens) == offset identity and must fire.
-    findings = schedlint(
-        tmp_path.joinpath("broken"), "polykey_tpu/engine/r.py",
-        operands.replace("useful += width", "useful += 1"),
-        only={"SL005"})
-    hits = blocking(findings, "SL005")
-    assert len(hits) == 1
-    assert "SAME width" in hits[0].message
-
-
 # -- teeth against the real engine -------------------------------------------
 
 
@@ -485,7 +408,7 @@ def test_stale_contract_anchors_are_sl000(tmp_path):
     assert any("engine loop" in f.message for f in hits)
     names = {f.message.split("(")[0] for f in hits
              if "contract anchor" in f.message}
-    assert len(names) == len(sched._CONTRACT_ANCHORS)
+    assert len(names) == len(sched.ORDERED_FRONTIERS)
 
 
 # -- SL006 witness merge ------------------------------------------------------
