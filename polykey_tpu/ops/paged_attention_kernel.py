@@ -15,6 +15,18 @@ pages, whose DMAs are all in flight together, so per-page DMA latency
 amortizes G× and the per-group attention block is [G·page_size] wide —
 MXU-shaped work instead of page_size-sliver matmuls. G consecutive page
 table entries cover contiguous positions, so the group's mask is one iota.
+G follows the BYTES a block moves (`_decode_call`): the same bytes in
+flight a slot at every folded width.
+
+The two buffer slots alternate ACROSS sequences, not only inside one (the
+grid runs in order): while a sequence's last block computes, the first
+block of the next sequence that has any visible page is already on its way
+into the other slot, so one fetch a call is waited on cold, not one a
+sequence; and a sequence walks only its live blocks [blo, bhi), not the
+table's whole width. What a call costs beyond its bytes, measured on a v5e
+(PERF.md §5): ~0.5 µs a sequence (the grid step, its q and output blocks)
+and ~30 ns a page descriptor (start and wait), which is what bounds a
+narrow tp shard whose 8 KB pages carry 10 ns of bytes.
 
 Invalid page-table tails (the reserved garbage page 0) are never DMA'd:
 the loop bound is ceil((position+1)/page_size), data-dependent per
@@ -66,12 +78,13 @@ def _kernel(
     #         by exp(m - m_global)): acc [1, Hq, D] f32, m/l
     #         [1, Hq, MINOR] f32
     # scratch: k/v bufs [2, G, ps, Hk·D] VMEM (+ [2, G, ps, Hk] scale
-    #         bufs when quantized) and matching DMA semaphores (2, G)
+    #         bufs when quantized), one DMA semaphore a slot for each
+    #         (every page of a block signals its slot's), and the
+    #         schedule's state [2] int32 SMEM, which outlives a program
     *refs,
     scale: float,
     logit_softcap: Optional[float],
     page_size: int,
-    num_tables: int,   # P — static max pages per sequence
     groups: int,       # Hq // Hk
     pages_per_block: int,   # G — pages per buffer slot (DMAs in flight)
     quantized: bool = False,
@@ -80,182 +93,214 @@ def _kernel(
         (q_ref, k_pages_ref, v_pages_ref, ks_pages_ref, vs_pages_ref,
          acc_ref, m_ref, l_ref,
          k_buf, v_buf, ks_buf, vs_buf,
-         k_sems, v_sems, ks_sems, vs_sems) = refs
+         k_sems, v_sems, ks_sems, vs_sems, state_ref) = refs
+        streams = ((k_pages_ref, k_buf, k_sems), (v_pages_ref, v_buf, v_sems),
+                   (ks_pages_ref, ks_buf, ks_sems),
+                   (vs_pages_ref, vs_buf, vs_sems))
     else:
         (q_ref, k_pages_ref, v_pages_ref,
          acc_ref, m_ref, l_ref,
-         k_buf, v_buf, k_sems, v_sems) = refs
-        ks_pages_ref = vs_pages_ref = None
-        ks_buf = vs_buf = ks_sems = vs_sems = None
+         k_buf, v_buf, k_sems, v_sems, state_ref) = refs
+        streams = ((k_pages_ref, k_buf, k_sems), (v_pages_ref, v_buf, v_sems))
+        ks_buf = vs_buf = None
     b = pl.program_id(0)
+    B = pl.num_programs(0)
     q_pos = pos_ref[b]
     window = win_ref[0]
     G = pages_per_block
-    n_blocks = (num_tables + G - 1) // G           # static
 
-    # Pages [lo, hi) hold positions visible to this query, intersected
-    # with this shard's page sub-range (context-parallel decode: each sp
-    # shard covers a contiguous page range; [0, P) when unsharded).
-    # Blocks [blo, bhi) are the G-page groups overlapping that range.
-    hi = jnp.minimum(jax.lax.div(q_pos, page_size) + 1, rng_ref[1])
-    lo = jnp.where(
-        window > 0,
-        jnp.maximum(jax.lax.div(q_pos - window + 1, page_size), 0),
-        0,
-    )
-    lo = jnp.maximum(lo, rng_ref[0])
-    blo = jax.lax.div(lo, G)
-    bhi = jax.lax.div(hi + G - 1, G)
-
-    def page_dma(p, slot, j, pages_ref, buf, sems):
-        return pltpu.make_async_copy(
-            pages_ref.at[pt_ref[b, p]], buf.at[slot, j], sems.at[slot, j]
+    def page_span(seq):
+        # Pages [lo, hi) hold positions visible to seq's query, intersected
+        # with this shard's page sub-range (context-parallel decode: each
+        # sp shard covers a contiguous page range; [0, P) when unsharded).
+        pos = pos_ref[seq]
+        hi = jnp.minimum(jax.lax.div(pos, page_size) + 1, rng_ref[1])
+        lo = jnp.where(
+            window > 0,
+            jnp.maximum(jax.lax.div(pos - window + 1, page_size), 0),
+            0,
         )
+        return jnp.maximum(lo, rng_ref[0]), hi
 
-    def start_block(blk, slot):
-        # All G page DMAs of the group go out together (latency overlaps);
-        # pages outside [lo, hi) are skipped — their rows are masked below.
-        for j in range(G):
-            p = blk * G + j
+    def block_pages(blk, lo, hi):
+        # The pages of G-page block `blk` inside [lo, hi): the only ones
+        # fetched (none when the span is empty) — the rest of the slot
+        # holds stale rows, masked below.
+        return jnp.maximum(lo, blk * G), jnp.minimum(hi, (blk + 1) * G)
 
-            @pl.when((p >= lo) & (p < hi))
-            def _go(p=p, j=j):
-                page_dma(p, slot, j, k_pages_ref, k_buf, k_sems).start()
-                page_dma(p, slot, j, v_pages_ref, v_buf, v_sems).start()
-                if quantized:
-                    page_dma(p, slot, j, ks_pages_ref, ks_buf,
-                             ks_sems).start()
-                    page_dma(p, slot, j, vs_pages_ref, vs_buf,
-                             vs_sems).start()
+    def start_block(seq, blk, slot, lo, hi):
+        # All page DMAs of the block go out together (latency overlaps).
+        def go(p, _):
+            for pages_ref, buf, sems in streams:
+                pltpu.make_async_copy(
+                    pages_ref.at[pt_ref[seq, p]], buf.at[slot, p - blk * G],
+                    sems.at[slot],
+                ).start()
+            return _
 
-    def wait_block(blk, slot):
-        for j in range(G):
-            p = blk * G + j
+        jax.lax.fori_loop(*block_pages(blk, lo, hi), go, None)
 
-            @pl.when((p >= lo) & (p < hi))
-            def _wait(p=p, j=j):
-                page_dma(p, slot, j, k_pages_ref, k_buf, k_sems).wait()
-                page_dma(p, slot, j, v_pages_ref, v_buf, v_sems).wait()
-                if quantized:
-                    page_dma(p, slot, j, ks_pages_ref, ks_buf,
-                             ks_sems).wait()
-                    page_dma(p, slot, j, vs_pages_ref, vs_buf,
-                             vs_sems).wait()
+    def wait_block(blk, slot, lo, hi):
+        # One wait a page started: a wait looks only at its slot's
+        # semaphore and the page's size, so any page stands for the source.
+        def done(p, _):
+            for pages_ref, buf, sems in streams:
+                pltpu.make_async_copy(
+                    pages_ref.at[0], buf.at[slot, p - blk * G], sems.at[slot],
+                ).wait()
+            return _
 
-    @pl.when((lo < hi) & (blo < bhi))
-    def _first():
-        start_block(blo, blo % 2)
+        jax.lax.fori_loop(*block_pages(blk, lo, hi), done, None)
+
+    # The schedule (module docstring). Blocks [blo, blo + n_blocks) are the
+    # G-page groups overlapping this sequence's pages; they alternate
+    # between the two buffer slots, and the alternation runs on across
+    # sequences: state_ref = [slot of the block in flight, sequence it is
+    # for]. Only the first live sequence of a call starts (and waits on) a
+    # cold fetch; one with no visible page starts and waits on nothing.
+    lo, hi = page_span(b)
+    live = lo < hi
+    blo = jax.lax.div(lo, G)
+    n_blocks = jnp.where(live, jax.lax.div(hi + G - 1, G) - blo, 0)
+
+    @pl.when(b == 0)
+    def _reset():
+        state_ref[0] = 0
+        state_ref[1] = -1
+
+    slot0 = state_ref[0]
+
+    @pl.when(live & (state_ref[1] != b))
+    def _cold():
+        start_block(b, blo, slot0, lo, hi)
+
+    def has_no_page(seq):
+        slo, shi = page_span(jnp.minimum(seq, B - 1))
+        return (seq < B) & (slo >= shi)
+
+    # Only a live sequence hands over, so only it looks for its successor.
+    nxt = jax.lax.while_loop(
+        has_no_page, lambda seq: seq + 1, jnp.where(live, b + 1, B)
+    )
+    nxt_seq = jnp.minimum(nxt, B - 1)
+    nxt_lo, nxt_hi = page_span(nxt_seq)
+    nxt_hi = jnp.where(nxt < B, nxt_hi, nxt_lo)    # no next: an empty span
+
+    @pl.when(live)
+    def _hand_over():
+        state_ref[0] = (slot0 + n_blocks) % 2
+        state_ref[1] = nxt
 
     Hq, D = q_ref.shape[1], q_ref.shape[2]
     W = G * page_size                               # group window width
     q = q_ref[0].astype(jnp.float32) * scale                  # [Hq, D]
 
-    def body(blk, carry):
+    def body(i, carry):
         m, l, acc = carry
-
-        def run(carry):
-            m, l, acc = carry
-            slot = blk % 2
-
-            @pl.when(blk + 1 < bhi)
-            def _next():
-                start_block(blk + 1, (blk + 1) % 2)
-
-            wait_block(blk, slot)
-            # Buffers hold [G, ps, Hk*D] (heads folded into lanes so the
-            # DMA slice stays 128-aligned for any head_dim); the G pages
-            # cover contiguous positions, so they flatten to one [W, Hk*D]
-            # block with a single iota mask.
-            k = k_buf[slot].reshape(W, -1)
-            v = v_buf[slot].reshape(W, -1)
-            D = q.shape[1]
-            num_kv = k.shape[1] // D
-            if quantized:
-                # Per-(position, head) dequant scales for this group —
-                # applied on the per-head slices below, so the int8
-                # pages stream at half the bf16 bytes and dequant rides
-                # the matmul operand load.
-                ks2 = ks_buf[slot].reshape(W, num_kv).astype(jnp.float32)
-                vs2 = vs_buf[slot].reshape(W, num_kv).astype(jnp.float32)
-
-            kv_pos1 = blk * W + jax.lax.broadcasted_iota(
-                jnp.int32, (W, 1), dimension=0
-            )                                                 # [W, 1]
-            valid1 = (kv_pos1 >= lo * page_size) & (kv_pos1 < hi * page_size)
-            # Rows of pages that were never DMA'd hold stale VMEM; zero V
-            # there so masked-out weights cannot multiply NaN garbage.
-            v = jnp.where(valid1, v.astype(jnp.float32), 0.0)
-            if quantized:
-                # The V-side matmul SUMS over rows, so stale scale rows
-                # must be zeroed like v itself — 0·NaN from a stale bf16
-                # pattern would poison every output. K-side NaNs stay
-                # confined to their own masked logit column.
-                vs2 = jnp.where(valid1, vs2, 0.0)
-
-            # Mosaic lowers only plain 2D matmuls — unroll over kv heads
-            # (q head h ↔ kv head h//groups, heads grouped contiguously).
-            def k_head(h):
-                kk = k[:, h * D:(h + 1) * D].astype(jnp.float32)
-                if quantized:
-                    kk = kk * ks2[:, h:h + 1]
-                return kk
-
-            s = jnp.concatenate(
-                [
-                    jax.lax.dot_general(
-                        q[h * groups:(h + 1) * groups],       # [g, D]
-                        k_head(h),
-                        dimension_numbers=(((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    for h in range(num_kv)
-                ],
-                axis=0,
-            )                                                 # [Hq, W]
-            if logit_softcap is not None:
-                s = logit_softcap * jnp.tanh(s / logit_softcap)
-
-            kv_pos = blk * W + jax.lax.broadcasted_iota(
-                jnp.int32, (Hq, W), dimension=1
-            )
-            mask = kv_pos <= q_pos
-            mask &= (window <= 0) | (kv_pos > q_pos - window)
-            mask &= valid1.reshape(1, W)
-            s = jnp.where(mask, s, _NEG_INF)
-
-            m_cur = jnp.max(s, axis=1, keepdims=True)         # [Hq, 1]
-            m_new = jnp.maximum(m, m_cur)
-            pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # [Hq, W]
-            corr = jnp.exp(m - m_new)
-            l_new = corr * l + jnp.sum(pexp, axis=1, keepdims=True)
-            def v_head(h):
-                vv = v[:, h * D:(h + 1) * D]
-                if quantized:
-                    vv = vv * vs2[:, h:h + 1]
-                return vv
-
-            pv = jnp.concatenate(
-                [
-                    jax.lax.dot_general(
-                        pexp[h * groups:(h + 1) * groups],    # [g, W]
-                        v_head(h),
-                        dimension_numbers=(((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )
-                    for h in range(num_kv)
-                ],
-                axis=0,
-            )                                                 # [Hq, D]
-            acc_new = acc * corr + pv
-            return m_new, l_new, acc_new
-
-        return jax.lax.cond(
-            (lo < hi) & (blk >= blo) & (blk < bhi), run, lambda c: c, carry
+        blk = blo + i
+        slot = (slot0 + i) % 2
+        # What streams in behind this block: this sequence's next block,
+        # or after the last one the next live sequence's first.
+        last = i + 1 == n_blocks
+        start_block(
+            jnp.where(last, nxt_seq, b),
+            jnp.where(last, jax.lax.div(nxt_lo, G), blk + 1),
+            1 - slot,
+            jnp.where(last, nxt_lo, lo),
+            jnp.where(last, nxt_hi, hi),
         )
+        wait_block(blk, slot, lo, hi)
+        # Buffers hold [G, ps, Hk*D] (heads folded into lanes so the
+        # DMA slice stays 128-aligned for any head_dim); the G pages
+        # cover contiguous positions, so they flatten to one [W, Hk*D]
+        # block with a single iota mask.
+        k = k_buf[slot].reshape(W, -1)
+        v = v_buf[slot].reshape(W, -1)
+        num_kv = k.shape[1] // D
+        if quantized:
+            # Per-(position, head) dequant scales for this group —
+            # applied on the per-head slices below, so the int8
+            # pages stream at half the bf16 bytes and dequant rides
+            # the matmul operand load.
+            ks2 = ks_buf[slot].reshape(W, num_kv).astype(jnp.float32)
+            vs2 = vs_buf[slot].reshape(W, num_kv).astype(jnp.float32)
+
+        kv_pos1 = blk * W + jax.lax.broadcasted_iota(
+            jnp.int32, (W, 1), dimension=0
+        )                                                 # [W, 1]
+        valid1 = (kv_pos1 >= lo * page_size) & (kv_pos1 < hi * page_size)
+        # Rows of pages that were never DMA'd hold stale VMEM; zero V
+        # there so masked-out weights cannot multiply NaN garbage.
+        v = jnp.where(valid1, v.astype(jnp.float32), 0.0)
+        if quantized:
+            # The V-side matmul SUMS over rows, so stale scale rows
+            # must be zeroed like v itself — 0·NaN from a stale bf16
+            # pattern would poison every output. K-side NaNs stay
+            # confined to their own masked logit column.
+            vs2 = jnp.where(valid1, vs2, 0.0)
+
+        # Mosaic lowers only plain 2D matmuls — unroll over kv heads
+        # (q head h ↔ kv head h//groups, heads grouped contiguously).
+        def k_head(h):
+            kk = k[:, h * D:(h + 1) * D].astype(jnp.float32)
+            if quantized:
+                kk = kk * ks2[:, h:h + 1]
+            return kk
+
+        s = jnp.concatenate(
+            [
+                jax.lax.dot_general(
+                    q[h * groups:(h + 1) * groups],       # [g, D]
+                    k_head(h),
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                for h in range(num_kv)
+            ],
+            axis=0,
+        )                                                 # [Hq, W]
+        if logit_softcap is not None:
+            s = logit_softcap * jnp.tanh(s / logit_softcap)
+
+        kv_pos = blk * W + jax.lax.broadcasted_iota(
+            jnp.int32, (Hq, W), dimension=1
+        )
+        mask = kv_pos <= q_pos
+        mask &= (window <= 0) | (kv_pos > q_pos - window)
+        mask &= valid1.reshape(1, W)
+        s = jnp.where(mask, s, _NEG_INF)
+
+        m_cur = jnp.max(s, axis=1, keepdims=True)         # [Hq, 1]
+        m_new = jnp.maximum(m, m_cur)
+        pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # [Hq, W]
+        corr = jnp.exp(m - m_new)
+        l_new = corr * l + jnp.sum(pexp, axis=1, keepdims=True)
+
+        def v_head(h):
+            vv = v[:, h * D:(h + 1) * D]
+            if quantized:
+                vv = vv * vs2[:, h:h + 1]
+            return vv
+
+        pv = jnp.concatenate(
+            [
+                jax.lax.dot_general(
+                    pexp[h * groups:(h + 1) * groups],    # [g, W]
+                    v_head(h),
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                for h in range(num_kv)
+            ],
+            axis=0,
+        )                                                 # [Hq, D]
+        return m_new, l_new, acc * corr + pv
 
     m0 = jnp.full((Hq, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((Hq, 1), jnp.float32)
     acc0 = jnp.zeros((Hq, D), jnp.float32)
+    # Only the live blocks are walked: ~4 turns at ~450-token contexts, not
+    # one turn (and a branch) for each of the table's P // G groups.
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
 
     acc_ref[0] = acc
@@ -265,6 +310,7 @@ def _kernel(
 
 
 _STAT_MINOR = 128   # lane width for the m/l stat outputs (tile-aligned)
+_BLOCK_BYTES = 512 * 1024   # one pool's bytes in flight a buffer slot
 
 
 @functools.partial(
@@ -301,17 +347,21 @@ def _decode_call(
     Hk = folded // D
     P = page_tables.shape[1]
     if pages_per_block <= 0:
-        # Target ~128 positions per block (one MXU tile of rows) with all
-        # of a block's page DMAs in flight together; bounded by the table.
-        pages_per_block = max(1, min(P, 128 // ps if ps <= 128 else 1))
-    G = min(pages_per_block, P)
+        # A block keeps _BLOCK_BYTES a pool in flight, whatever the folded
+        # width it is handed (a tp shard's 256 lanes take more positions
+        # than a chip's 1024), between 128 positions (one MXU tile of rows)
+        # and 512 (a block is computed whole: past the contexts served,
+        # wider is masked work). Two slots of two pools of it, and the f32
+        # copies the matmuls take, stay inside the scoped VMEM.
+        rows = _BLOCK_BYTES // (folded * k_pages.dtype.itemsize)
+        pages_per_block = min(max(rows, 128), 512) // ps
+    G = max(1, min(pages_per_block, P))               # bounded by the table
 
     kernel = functools.partial(
         _kernel,
         scale=scale,
         logit_softcap=logit_softcap,
         page_size=ps,
-        num_tables=P,
         groups=Hq // Hk,
         pages_per_block=G,
         quantized=quantized,
@@ -336,7 +386,8 @@ def _decode_call(
         ]
         operands = [q, k_pages, v_pages, ks_pages, vs_pages]
     n_sems = 4 if quantized else 2
-    scratch += [pltpu.SemaphoreType.DMA((2, G))] * n_sems
+    scratch += [pltpu.SemaphoreType.DMA((2,))] * n_sems
+    scratch += [pltpu.SMEM((2,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
@@ -425,7 +476,7 @@ def paged_attention_decode(
     window: Optional[jax.Array] = None,
     interpret: bool = False,
     force_kernel: bool = False,
-    pages_per_block: int = 0,   # 0 → auto (~128 positions per block)
+    pages_per_block: int = 0,   # 0 → auto (from the bytes a block moves)
     mesh=None,                  # serving mesh → shard_map the kernel
 ) -> jax.Array:
     """Decode-step paged attention; returns [B, 1, Hq, D].

@@ -12,10 +12,17 @@ the chip and compared with its jnp reference path.
 One line per (kernel, geometry): PASS with the max abs error, or FAIL
 with the head of the compiler's message (the whole message goes to
 chiprun_out/kernel_check.txt). Exits non-zero on any failure and when
-there is no TPU. It times nothing — timing belongs to the benchmark —
-except the one-off probe that jax.block_until_ready really blocks.
+there is no TPU. It times two things: the one-off probe that
+jax.block_until_ready really blocks, and the paged decode kernel alone
+(`decode-time` rows: µs a call beside its K/V bytes ÷ the chip's HBM
+bandwidth at the two shapes the benchmark's cells run, so that a call's
+cost can be split into bytes ÷ bandwidth + the rest without a server; in
+no cell — what the users pay is the benchmark's to say).
 
 Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
+     python scripts/tpu_kernel_check.py --timing   (the decode-time rows
+       alone; --sweep adds lanes, table width and block width varied one
+       at a time)
      JAX_PLATFORMS=cpu python scripts/tpu_kernel_check.py --interpret
        rehearses the script itself at small tables in Pallas interpret
        mode — it proves nothing about lowering and exits 2 like any run
@@ -28,6 +35,7 @@ import os
 import sys
 import time
 import traceback
+from functools import partial
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -241,6 +249,111 @@ def check_int4() -> None:
     case("int4-native", "jnp.int4 32x4096x1024", native)
 
 
+# The decode kernel as the benchmark's cells call it: 16 lanes on 4k-position
+# tables; one chip of mistral-7b holds all 8 KV heads (1024 folded lanes), a
+# tp = 4 shard of mixtral-8x7b 2 of them (256).
+TIMED_SHAPES = [("1024-lanes", 32, 8, 128), ("256-lanes", 8, 2, 128)]
+TIMED_CONTEXTS = (128, 512, 2048)
+TIMED_CALLS = 128                       # kernel calls inside one jitted scan
+
+
+def hbm_bytes_per_s() -> float:
+    """The attached chip's published HBM bandwidth, from the benchmark's one
+    table of peaks (a device that is not in it is an error)."""
+    sys.path.insert(0, os.path.join(ROOT, "perfbench"))
+    import peaks
+
+    return peaks.row(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+
+
+def time_decode(B, Hq, Hk, D, P, contexts, pages_per_block=0) -> str:
+    """µs a call of the decode kernel on bf16 pools, every lane's pages
+    scattered over a pool no cache could hold, beside the least time its
+    K/V bytes allow. `contexts`: one length for every lane, or one a lane.
+    The calls run back to back inside one jitted scan (a new q each, so
+    nothing is hoisted) and the scan's own turn, measured empty, is taken
+    off."""
+    from polykey_tpu.ops.paged_attention_kernel import paged_attention_decode
+
+    contexts = np.broadcast_to(np.asarray(contexts, np.int32), (B,))
+    interpret = "interpret" in KERNEL       # a rehearsal: small and few
+    N = B * P + 1 if interpret else max(B * P + 1, 8192)
+    rng = np.random.default_rng(11)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
+    calls = 2 if interpret else TIMED_CALLS
+    qs = jax.random.normal(kq, (calls, B, 1, Hq, D), jnp.bfloat16)
+    kp = jax.random.normal(kk, (N, PS, Hk * D), jnp.bfloat16)
+    vp = jax.random.normal(kv, (N, PS, Hk * D), jnp.bfloat16)
+    tables = np.zeros((B, P), np.int32)
+    pages = rng.permutation(N - 1)[:B * P].reshape(B, P) + 1
+    for b in range(B):
+        used = (int(contexts[b]) + PS - 1) // PS
+        tables[b, :used] = pages[b, :used]
+    tables = jnp.asarray(tables)
+    positions = jnp.asarray(contexts - 1).reshape(B, 1)
+
+    def scan_of(step):
+        # The pools go in as arguments: closed over, they would be compiled
+        # into the program as half a gigabyte of constants.
+        return jax.jit(lambda qs, kp, vp: jax.lax.scan(
+            lambda c, q: (c + step(q, kp, vp).astype(jnp.float32), None),
+            jnp.zeros((B, 1, Hq, D), jnp.float32), qs)[0])
+
+    def seconds(fn):
+        jax.block_until_ready(fn(qs, kp, vp))    # compile
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(qs, kp, vp))
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    kernel = scan_of(lambda q, kp, vp: paged_attention_decode(
+        q, kp, vp, tables, positions, scale=D ** -0.5,
+        pages_per_block=pages_per_block, **KERNEL))
+    empty = scan_of(lambda q, kp, vp: q)
+    us = (seconds(kernel) - seconds(empty)) / calls * 1e6
+    kv_bytes = 2 * int(contexts.sum()) * Hk * D * 2
+    if interpret:
+        return (f"ran (interpret mode on the host: no device time); "
+                f"K/V {kv_bytes / 1e6:.2f} MB")
+    peak = hbm_bytes_per_s()
+    least = kv_bytes / peak * 1e6
+    return (f"{us:.1f} us/call; K/V {kv_bytes / 1e6:.2f} MB = {least:.1f} us "
+            f"at {peak / 1e9:.0f} GB/s ({100 * least / us:.1f} %), "
+            f"rest {us - least:.1f} us")
+
+
+def check_decode_timing(sweep: bool) -> None:
+    for label, Hq, Hk, D in TIMED_SHAPES:
+        timed = partial(time_decode, 16, Hq, Hk, D)
+        for ctx in TIMED_CONTEXTS:
+            ctx = min(ctx, TABLE * PS)
+            case("decode-time", f"{label} B=16 ctx={ctx}",
+                 partial(timed, TABLE, ctx))
+        # The cells' own mix: every lane another length, 68 to 860 tokens.
+        mixed = np.minimum(np.linspace(68, 860, 16), TABLE * PS).astype(int)
+        case("decode-time", f"{label} B=16 ctx=68..860",
+             partial(timed, TABLE, mixed))
+        if not sweep:
+            continue
+        ctx = min(448, TABLE * PS)
+        for B in (1, 4, 8, 32):
+            case("decode-time", f"{label} B={B} ctx={ctx}",
+                 partial(time_decode, B, Hq, Hk, D, TABLE, ctx))
+        for P in (32, 64, 1024):
+            case("decode-time", f"{label} B=16 ctx={ctx} table={P}",
+                 partial(timed, P, min(ctx, P * PS)))
+        for ppb in (4, 8, 16, 32, 64):
+            case("decode-time", f"{label} B=16 ctx={ctx} pages/block={ppb}",
+                 partial(timed, TABLE, ctx, ppb))
+            case("decode-time", f"{label} B=16 ctx=68..860 pages/block={ppb}",
+                 partial(timed, TABLE, mixed, ppb))
+        for ctx in (1, 16, 17):
+            case("decode-time", f"{label} B=16 ctx={ctx}",
+                 partial(timed, TABLE, ctx))
+
+
 def check_block_until_ready() -> None:
     """Does jax.block_until_ready block here? A long dependent matmul
     chain is dispatched; the call returning in a sliver of the time the
@@ -287,13 +400,17 @@ def main() -> int:
         return 2
     # The smoke's default path first; the kernels that have never run on
     # hardware last, so a hang there costs no other case its evidence.
+    timing_only = "--timing" in sys.argv[1:]
     check_block_until_ready()
-    check_flash()
-    check_decode(quantized=False)
-    check_write(quantized=False)
-    check_int4()
-    check_decode(quantized=True)
-    check_write(quantized=True)
+    if not timing_only:
+        check_flash()
+        check_decode(quantized=False)
+        check_write(quantized=False)
+        check_int4()
+    check_decode_timing(sweep="--sweep" in sys.argv[1:])
+    if not timing_only:
+        check_decode(quantized=True)
+        check_write(quantized=True)
     failed = [r for r in RESULTS if not r[2]]
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)

@@ -133,6 +133,116 @@ def test_paged_decode_kernel_no_gqa_single_page():
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
 
+# What the page-streaming schedule can get wrong (fetches kept in flight
+# ACROSS sequences, only the live blocks walked, the block width taken from
+# the folded width): (B, Hk, positions, window, split, pages_per_block).
+# Pages are 16 positions, tables 64 pages, D = 128, so Hk = 2 / 8 are the
+# 256 / 1024 folded lanes of a tp = 4 shard and of a whole chip: f32 pools
+# there take 32- and 8-page blocks (512 and 128 positions). `split` = r runs
+# the kernel on the page sub-ranges [0, r) and [r, 64) — a context-parallel
+# shard's view, in which a short sequence has NO visible page — and merges
+# the two unnormalised states the way the sp axis does.
+_SCHEDULE_CASES = {
+    "neighbours-of-very-different-lengths":
+        (4, 2, [3, 1000, 17, 700], None, None, 0),
+    "no-visible-page-between-two-live-ones":
+        (4, 2, [300, 50, 20, 400], None, 8, 0),
+    "first-and-last-have-no-visible-page":
+        (4, 2, [20, 300, 500, 30], None, 8, 0),
+    "nobody-has-a-visible-page": (3, 2, [20, 100, 60], None, 8, 0),
+    "only-sequence": (1, 2, [333], None, None, 0),
+    "only-sequence-cut-by-a-split": (1, 2, [333], None, 16, 0),
+    "context-ends-on-a-block-edge":
+        (4, 2, [511, 512, 1023, 15], None, None, 0),
+    "window-lifts-lo-past-whole-blocks":
+        (4, 2, [700, 90, 300, 1000], 100, None, 0),
+    "window-and-split": (4, 2, [700, 90, 300, 1000], 200, 40, 0),
+    "folded-width-256": (3, 2, [600, 130, 1023], None, None, 0),
+    "folded-width-1024": (3, 8, [600, 130, 1023], None, None, 0),
+    "pages-per-block-given-3": (3, 2, [600, 130, 1023], None, None, 3),
+    "pages-per-block-given-5-split": (3, 2, [600, 130, 1023], 300, 23, 5),
+}
+
+
+@pytest.mark.parametrize("case", _SCHEDULE_CASES)
+def test_paged_decode_kernel_schedule(case):
+    from polykey_tpu.ops import paged_attention_kernel as pak
+
+    B, Hk, positions, win, split, ppb = _SCHEDULE_CASES[case]
+    ps, P, D, groups = 16, 64, 128, 2
+    q, kp, vp, pt, pos = _paged_case(
+        B, Hk * groups, Hk, D, ps, P, [[p] for p in positions])
+    w = None if win is None else jnp.int32(win)
+    ref = paged_attention(q, kp, vp, pt, pos, scale=0.09, window=w)
+
+    def state(rlo, rhi):
+        return pak._decode_call(
+            q[:, 0], kp, vp, pt, pos[:, 0],
+            jnp.asarray([0 if win is None else win], jnp.int32),
+            jnp.asarray([rlo, rhi], jnp.int32),
+            scale=0.09, logit_softcap=None, interpret=True,
+            pages_per_block=ppb,
+        )
+
+    if split is None:
+        acc, m, den = state(0, P)
+    else:
+        (a1, m1, l1), (a2, m2, l2) = state(0, split), state(split, P)
+        # A shard with no visible page hands back the empty state, which
+        # the merge weighs with exp(-1e30 - m) = 0.
+        empty = np.asarray(positions) // ps < split
+        assert np.all(np.asarray(l2)[empty] == 0.0)
+        assert np.all(np.asarray(a2)[empty] == 0.0)
+        m = jnp.maximum(m1, m2)
+        den = l1 * jnp.exp(m1 - m) + l2 * jnp.exp(m2 - m)
+        acc = a1 * jnp.exp(m1 - m) + a2 * jnp.exp(m2 - m)
+    out = (acc / jnp.maximum(den, 1e-9))[:, None]
+    assert bool(jnp.isfinite(out).all())
+    assert float(jnp.max(jnp.abs(ref - out))) < TOL
+
+
+@pytest.mark.parametrize("folded,itemsize,ppb,want", [
+    (1024, 2, 0, 16),     # one chip of mistral-7b: 512 KB, 256 positions
+    (512, 2, 0, 32),      # a tp = 2 shard: the same bytes, 512 positions
+    (256, 2, 0, 32),      # a tp = 4 shard: never over 512 positions
+    (1024, 1, 0, 32),     # int8 pools: half the bytes a position
+    (4096, 2, 0, 8),      # never under 128 positions
+    (256, 2, 3, 3),       # given explicitly: honoured
+    (256, 2, 500, 256),   # bounded by the table
+])
+def test_paged_decode_block_width_follows_the_bytes(folded, itemsize, ppb,
+                                                    want):
+    """The block the kernel streams is sized in bytes in flight, from the
+    shape it is handed — read off the scratch buffers it asks for."""
+    from polykey_tpu.ops import paged_attention_kernel as pak
+
+    dtype = {2: jnp.bfloat16, 1: jnp.int8}[itemsize]
+    B, ps, P, D = 2, 16, 256, 128
+    Hk = folded // D
+    pool = jax.ShapeDtypeStruct((64, ps, folded), dtype)
+    if itemsize == 1:
+        scales = jax.ShapeDtypeStruct((64, ps, Hk), jnp.bfloat16)
+        pool = (pool, scales)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: pak._decode_call.__wrapped__(     # the jit's function
+            *a, scale=1.0, logit_softcap=None, interpret=False,
+            pages_per_block=ppb)
+    )(
+        jax.ShapeDtypeStruct((B, Hk, D), jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((B, P), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+    )
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == "paged_attention_decode"
+    scratch = [
+        x.aval.shape for x in call.params["jaxpr"].invars
+        if len(x.aval.shape) == 4
+    ]
+    assert scratch[:2] == [(2, want, ps, folded)] * 2, scratch
+
+
 def test_paged_decode_kernel_shard_mapped_on_mesh():
     """The decode kernel under shard_map on a dp=2 x tp=2 mesh (GSPMD
     cannot partition a pallas_call — parallel/sharding.py layout: batch

@@ -195,12 +195,14 @@ def v5e():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("tp", [1, 2, 4])
 def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp, monkeypatch):
     """The same count in the step the chip runs — both Pallas kernels on the
     stacked pool, `paged_kv_write` aliasing it — compiled for a v5e by the
     compiler installed here. Toy depth, real page geometry; the folded
-    dimension a tp shard sees is 128 lanes."""
+    dimension a tp shard sees is 128 lanes, and at tp = 4 the 256 lanes
+    (2 KV heads of 128) of a mixtral-8x7b shard, where the decode kernel
+    takes a wider block than on 1024 lanes."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -215,7 +217,7 @@ def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp, monkeypatch):
         paged_attention_kernel, "use_paged_kernel", lambda Hk, D: True
     )
     cfg = replace(TINY_LLAMA, name="layout-probe", num_heads=2 * tp,
-                  num_kv_heads=2 * tp, head_dim=64)
+                  num_kv_heads=2 * tp, head_dim=128 if tp == 4 else 64)
     mesh = create_mesh(MeshConfig(tp=tp), devices=list(v5e.devices)[:tp])
     repl = NamedSharding(mesh, P())
     shapes = jax.eval_shape(
