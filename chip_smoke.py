@@ -74,8 +74,10 @@ def build_allocator() -> None:
     build is not fatal: the engine falls back to the Python allocator
     and engine_stats says which one served."""
     lib = os.path.join(ROOT, "build", "libblock_allocator.so")
-    if os.path.exists(lib):
+    try:
         os.remove(lib)
+    except FileNotFoundError:
+        pass    # none yet, or a smoke started beside this one removed it
     subprocess.run(
         ["make", "-s", "build/libblock_allocator.so"], cwd=ROOT, check=False,
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, timeout=120,

@@ -101,6 +101,15 @@ class EngineConfig:
     page_size: int = 16
     num_pages: int = 2048                # includes reserved garbage page 0
     max_seq_len: int = 4096              # per-request position cap
+    # The prefill window widths that are compiled, each for 1/2/4/8 rows.
+    # A prompt (or a prefix-cache suffix, or a long prompt's tail) is
+    # covered by the FEWEST rows they allow (engine.prefill_cover): one
+    # window of the bucket that holds it, or several windows of a
+    # narrower bucket as consecutive rows of one dispatch — 129..256
+    # tokens here are two 128-row windows, not one of 512; 257..512 stay
+    # one 512 window (three or four 128-windows pad to as many rows).
+    # Buckets should be whole pages: a bucket that is not is never
+    # split over.
     prefill_buckets: tuple[int, ...] = (128, 512)
     prefill_chunk: int = 0               # 0 → max(prefill_buckets)
     max_new_tokens_cap: int = 1024

@@ -49,6 +49,7 @@ Design:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import queue
@@ -278,8 +279,12 @@ def _prefill_fn(
     start[i]..start[i]+T-1 and sample from each hidden state at relative
     index last_rel[i]. One compiled shape serves every path: single
     admissions (N=1), burst admissions batched by bucket (N up to the
-    group cap), and long prompts chunk through it N=1 at a time (the
-    engine discards the sampled token for all but the final chunk).
+    group cap), a prompt covered by several windows (prefill_cover:
+    consecutive rows on ONE page table at starts s, s+T, …), and long
+    prompts chunk through it N=1 at a time; the engine discards the
+    sampled token of every window but a prompt's last. Rows may share
+    a table because forward_paged writes every row's K/V of a layer
+    before any row's attention gathers it, masked by absolute position.
     Padded tail positions write KV that is either masked (position > any
     query), overwritten by later decode steps, or lands on the reserved
     garbage page — never read; padded GROUP rows point their whole table
@@ -469,7 +474,61 @@ def _kv_gather_quant_fn(paged: PagedKV, idx):
     )
 
 
-_MAX_PREFILL_GROUP = 8   # burst admissions batched per prefill dispatch
+_MAX_PREFILL_GROUP = 8   # rows batched per prefill dispatch
+
+
+def prefill_group_sizes(slots: int) -> tuple[int, ...]:
+    """The row counts N a prefill dispatch is padded to — the [N, bucket]
+    shapes the warm-up compiles — given the slot count: a group fills
+    from free slots, so 3 or 4 slots stop at 4 rows, 5 and more at
+    _MAX_PREFILL_GROUP."""
+    return tuple(
+        n for n, least in ((1, 1), (2, 2), (4, 3), (_MAX_PREFILL_GROUP, 5))
+        if slots >= least
+    )
+
+
+def prefill_cover(
+    n: int, start: int, widths: tuple[int, ...], groups: tuple[int, ...],
+    page_size: int = 1,
+) -> list[tuple[int, int]]:
+    """The (width, start) windows that prefill `n` tokens from position
+    `start` with the FEWEST rows the compiled shapes allow. `widths` are
+    the window widths that exist (the prefill buckets; the chunk width
+    beside them for a long prompt's tail), `groups` the row counts a
+    dispatch is padded to (prefill_group_sizes).
+
+    A span no wider than the widest window takes ceil(n / w) windows of
+    ONE width w, consecutive rows of one [N, w] dispatch on the same
+    page table: within a layer every row's K/V is written before any
+    row's attention gathers (models/transformer.py), and the mask is by
+    absolute position, so a later row reads the earlier rows' keys of
+    the same layer. w is the width whose padded row count
+    pad(ceil(n / w)) * w is least, a tie going to the fewer windows; a
+    width is split over only where it is whole pages (every start stays
+    page-aligned, ops/paged_attention.paged_write's page-granular
+    scatter) and its windows fit one group. Buckets (128, 512): n <= 128
+    -> [128]; 129..256 -> [128, 128]; 257..512 -> [512] (three or four
+    128-windows pad to 512 rows — no gain, so one window).
+
+    A longer span leads with windows of the widest width, one dispatch
+    each (the long prompt's chunks), and covers its tail the same way."""
+    widest = max(widths)
+    windows = []
+    while n > widest:
+        windows.append((widest, start))
+        start += widest
+        n -= widest
+    best = None
+    for w in sorted(widths):
+        k = -(-n // w)
+        if k > 1 and (w % page_size or k > groups[-1]):
+            continue
+        rows = next(g for g in groups if g >= k) * w
+        if best is None or (rows, k) < best[:2]:
+            best = (rows, k, w)
+    _, k, w = best
+    return windows + [(w, start + i * w) for i in range(k)]
 
 # Router weight of a HOST-resident cached prefix token relative to a
 # device-resident one (prefix_warmth): warm — no recompute — but a
@@ -877,6 +936,13 @@ class InferenceEngine:
             )
 
         self._chunk = config.prefill_chunk or max(config.prefill_buckets)
+        # What prefill_cover chooses from: the [N, bucket] shapes of an
+        # admission, and for a long prompt's tail the buckets narrower
+        # than the chunk beside the chunk itself.
+        self._group_sizes = prefill_group_sizes(config.max_decode_slots)
+        self._chunk_widths = tuple(
+            b for b in config.prefill_buckets if b < self._chunk
+        ) + (self._chunk,)
         # Interleaved-prefill budget (config.prefill_budget; 0 → auto):
         # prefill tokens allowed per loop iteration while decode lanes
         # are live. Floored at one chunk so a budget below the dispatch
@@ -1610,22 +1676,22 @@ class InferenceEngine:
             if self.health is not None:
                 self.health.shutdown()
 
-    def _bucket_for(self, length: int) -> Optional[int]:
-        for b in self.config.prefill_buckets:
-            if length <= b:
-                return b
-        return None
-
     def _admit(self, budget: Optional[int] = None) -> tuple[bool, int]:
-        """Admit waiting requests into free slots. Short prompts are
-        gathered into per-bucket groups and prefilled in ONE batched
-        dispatch per group (burst admissions — e.g. cold start — pay one
-        device call instead of one per request; spec engines batch the
-        same way, prefilling both pools per dispatch); long prompts
+        """Admit waiting requests into free slots. A short prompt (or a
+        prefix-cache suffix) is covered by the fewest rows the compiled
+        windows allow (prefill_cover): one window of the bucket that
+        holds it, or several windows of a narrower bucket — 129..256
+        tokens on buckets (128, 512) are two 128-row windows, not one of
+        512. Windows gather into per-bucket groups and prefill in ONE
+        batched dispatch per group (burst admissions — e.g. cold start —
+        pay one device call instead of one per request; spec engines
+        batch the same way, prefilling both pools per dispatch); a
+        prompt's windows are consecutive rows of the SAME dispatch, so
+        a group that cannot take them all goes out first. Long prompts
         register for chunked prefill.
 
         `budget` (tokens, None → unbounded) is the interleaved-prefill
-        discipline: each short admission charges its padded bucket width
+        discipline: each short admission charges the rows of its cover
         (the prefill tokens its group dispatch will compute); once spent
         reaches the budget, the rest of the queue WAITS for the next
         loop iteration — i.e. for the next decode block to dispatch
@@ -1634,7 +1700,9 @@ class InferenceEngine:
         (_advance_chunked_prefills). Returns (admitted_any, spent)."""
         admitted = False
         spent = 0
-        groups: dict[int, list] = {}    # bucket → [(slot_idx, slot, ids)]
+        # bucket → [(slot_idx, slot, window ids, window start, last window)]
+        groups: dict[int, list] = {}
+        cap = self._group_sizes[-1]
         try:
             while budget is None or spent < budget:
                 free_slots = [
@@ -1664,14 +1732,22 @@ class InferenceEngine:
                             request.timings.prompt_tokens,
                         )
                     if prep is not None:
-                        bucket = prep[0]
-                        # Budget charge = the bucket width (known only
+                        bucket, rows = self._cover_rows(
+                            *prep, self.config.prefill_buckets
+                        )
+                        # Budget charge = the cover's rows (known only
                         # after tokenize), so the LAST admission may
                         # overshoot by one bucket — the budget is a soft
                         # bound at dispatch granularity (config).
-                        spent += bucket
-                        groups.setdefault(bucket, []).append(prep[1:])
-                        if len(groups[bucket]) >= _MAX_PREFILL_GROUP:
+                        spent += bucket * len(rows)
+                        group = groups.setdefault(bucket, [])
+                        if len(group) + len(rows) > cap:
+                            self._dispatch_prefill_group(
+                                bucket, groups.pop(bucket)
+                            )
+                            group = groups.setdefault(bucket, [])
+                        group += rows
+                        if len(group) >= cap:
                             self._dispatch_prefill_group(
                                 bucket, groups.pop(bucket)
                             )
@@ -1694,6 +1770,29 @@ class InferenceEngine:
             for bucket, group in groups.items():
                 self._dispatch_prefill_group(bucket, group)
 
+    def _cover_rows(self, slot_idx: int, slot: "_Slot", ids, start: int,
+                    widths: tuple[int, ...]) -> tuple[int, list]:
+        """The next prefill dispatch of `ids` from position `start`, as
+        (width, rows): every window of the cover (prefill_cover) when
+        the span fits the widest of `widths`, else its leading
+        chunk-wide window alone. A row is (slot_idx, slot, window ids,
+        window start, last); `last` marks the window that reaches the
+        span's end — the one whose sampled token is the request's first
+        token."""
+        windows = prefill_cover(
+            len(ids), start, widths, self._group_sizes,
+            self.config.page_size,
+        )
+        if len(ids) > max(widths):
+            windows = windows[:1]
+        width = windows[0][0]
+        end = start + len(ids)
+        return width, [
+            (slot_idx, slot, ids[at - start:at - start + width], at,
+             at + width >= end)
+            for _, at in windows
+        ]
+
     def _requeue_front(self, request: GenRequest) -> None:
         # queue.Queue has no push-front; rebuild (small queues, rare path).
         items = [request]
@@ -1707,8 +1806,9 @@ class InferenceEngine:
 
     def _prepare_request(self, slot_idx: int, request: GenRequest):
         """Tokenize, budget, allocate pages, and register the slot.
-        Returns (bucket, slot_idx, slot, prompt_ids, start) for short
-        prompts (the caller batches their prefill dispatches — plain and
+        Returns (slot_idx, slot, ids, start) — the tokens to prefill
+        and the position they start at — for short prompts (the caller
+        covers them with windows and batches the dispatches, plain and
         spec engines alike) or None for long prompts (registered for
         chunked prefill)."""
         cfg = self.config
@@ -1856,7 +1956,7 @@ class InferenceEngine:
         ).view(np.int32)
         slot = _Slot(request=request, pages=pages, position_cap=total_len)
         slot.seed_row = seed_row
-        bucket = self._bucket_for(prompt_len)
+        widest = max(cfg.prefill_buckets)
 
         slot.table = page_table
         slot.prompt_len = prompt_len
@@ -1870,9 +1970,7 @@ class InferenceEngine:
             # lanes admitted this same iteration dispatch ahead of it
             # (the page-aware no-stall property).
             slot.restore_pages = restore_items
-            kind = (
-                "ctx" if prompt_len > max(cfg.prefill_buckets) else "prefix"
-            )
+            kind = "ctx" if prompt_len > widest else "prefix"
             self.metrics.on_kv_fault(kind, len(restore_items))
             slot.pending = ids
             slot.filled = len(chain) * cfg.page_size
@@ -1881,21 +1979,21 @@ class InferenceEngine:
 
         if matched:
             # Prefill only the suffix. A bucket-sized suffix rides the
-            # batched bucket path at its own width (a hit must not cost
-            # more than a miss); longer suffixes chunk from the offset.
+            # batched bucket path, covered at its own length from the
+            # offset (a hit must not cost more than a miss); longer
+            # suffixes chunk from the offset.
             # On spec engines the group dispatch prefills BOTH pools, and
             # cached pages already hold both models' prefix KV.
             filled = len(matched) * cfg.page_size
             suffix = ids[filled:]
-            suffix_bucket = self._bucket_for(len(suffix))
             self._slots[slot_idx] = slot
-            if suffix_bucket is None:
+            if len(suffix) > widest:
                 slot.pending = ids
                 slot.filled = filled
                 return None
-            return suffix_bucket, slot_idx, slot, suffix, filled
+            return slot_idx, slot, suffix, filled
 
-        if bucket is None:
+        if prompt_len > widest:
             # Long prompt: register the slot in prefilling state; the
             # engine loop runs one chunk per iteration (interleaved with
             # decode steps) until the prompt is in cache. Its page table
@@ -1909,15 +2007,21 @@ class InferenceEngine:
         # Registered but inactive until _resolve_prefills reads the token —
         # after the next decode block is dispatched, so prefill overlaps it.
         self._slots[slot_idx] = slot
-        return bucket, slot_idx, slot, ids, 0
+        return slot_idx, slot, ids, 0
 
-    def _dispatch_prefill_group(self, bucket: int, group: list) -> None:
+    def _dispatch_prefill_group(self, bucket: int, group: list) -> bool:
         """One batched prefill dispatch for up to _MAX_PREFILL_GROUP
-        same-bucket admissions, padded to a power of two so the compiled
-        shape set stays small ({1,2,4,8} × buckets). Padded rows point their
-        page tables at the reserved garbage page and are never resolved."""
+        same-bucket windows, padded to a power of two so the compiled
+        shape set stays small ({1,2,4,8} × buckets). A row is (slot_idx,
+        slot, window ids, window start, last): the windows of one prompt
+        are consecutive rows on the same page table at starts s, s +
+        bucket, …, and only a `last` row's sampled token activates its
+        slot — the others' are discarded without a sync, as a chunked
+        prompt's are. Padded rows point their page tables at the
+        reserved garbage page and are never resolved. False when the
+        dispatch failed (every member slot is then finished)."""
         n = len(group)
-        n_pad = 1 if n == 1 else 2 if n == 2 else 4 if n <= 4 else 8
+        n_pad = next(g for g in self._group_sizes if g >= n)
         cfg = self.config
         tokens = np.zeros((n_pad, bucket), dtype=np.int32)
         starts = np.zeros((n_pad,), dtype=np.int32)
@@ -1927,9 +2031,9 @@ class InferenceEngine:
         top_p = np.ones((n_pad,), dtype=np.float32)
         top_k = np.zeros((n_pad,), dtype=np.int32)
         seeds = np.zeros((n_pad, 2), dtype=np.int32)
-        for r, (slot_idx, slot, ids, start) in enumerate(group):
+        for r, (slot_idx, slot, ids, start, _) in enumerate(group):
             tokens[r, : len(ids)] = ids
-            starts[r] = start                   # >0: prefix-cache suffix
+            starts[r] = start       # >0: a later window, a cached prefix
             last_rel[r] = len(ids) - 1
             tables[r] = slot.table[0]
             temp[r] = slot.request.temperature
@@ -1944,14 +2048,16 @@ class InferenceEngine:
             put(starts), put(last_rel), put(tables), put(seeds),
             put(temp), put(top_p), put(top_k),
         )
-        real = sum(len(ids) for _, _, ids, _ in group)
+        real = sum(len(row[2]) for row in group)
         try:
             if self._faults is not None:
                 self._faults.maybe_raise("prefill-error", replica=self.replica_id, tier=self._tier)
             with self._phase("prefill", bucket=bucket, rows=n_pad * bucket,
                              tokens=real):
+                # The last dispatch's stamp is the one that stays: the
+                # one that completes the prompt.
                 issued = time.monotonic()
-                for _, slot, _, _ in group:
+                for _, slot, _, _, _ in group:
                     slot.request.timings.prefill_dispatched = issued
                 if self._spec:
                     # Spec burst admissions batch exactly like plain ones
@@ -1978,17 +2084,23 @@ class InferenceEngine:
             # Contain the failure to this group: every member slot is
             # already registered, so each must be finished (pages released,
             # client errored) or they leak and their clients hang forever.
-            for slot_idx, slot, _, _ in group:
+            for slot_idx, slot, _, _, _ in group:
                 if self._slots[slot_idx] is slot:
                     self._finish(slot_idx, error=f"prefill failed: {e}")
-            return
+            return False
         # Padding-waste accounting: the group computed n_pad × bucket
-        # token rows for Σ len(ids) real prompt tokens.
-        self.metrics.on_prefill_rows(n_pad * bucket, real)
-        for r, (slot_idx, slot, _, _) in enumerate(group):
+        # token rows for Σ len(ids) real prompt tokens, in n windows; a
+        # slot with several rows here is a prompt split over windows.
+        rows_of = collections.Counter(row[0] for row in group)
+        self.metrics.on_prefill_rows(
+            n_pad * bucket, real, n, sum(c > 1 for c in rows_of.values()),
+        )
+        for r, (slot_idx, slot, ids, _, last) in enumerate(group):
             if self.timeline is not None:
-                self.timeline.prefill(slot_idx, bucket, True)
-            self._merge_slot(slot_idx, slot, toks_dev, r)
+                self.timeline.prefill(slot_idx, len(ids), last)
+            if last:
+                self._merge_slot(slot_idx, slot, toks_dev, r)
+        return True
 
     def _compile_warmup(self) -> None:
         """Pre-compile the greedy prefill group shapes and the greedy
@@ -2002,13 +2114,13 @@ class InferenceEngine:
         warm_sampled = cfg.warm_sampled_variants
         greedy_variants = (True, False) if warm_sampled else (True,)
         put = partial(jax.device_put, device=self._repl)
-        # Possible padded group sizes given the slot count (groups are
-        # bounded by free slots; n=3 pads to 4, n=5 pads to 8). A
-        # full-rate admission burst of 32 then costs 4 weight-read
-        # passes instead of 8 — prefill is weight-bandwidth-bound
-        # exactly like decode, so group width amortizes it.
-        pads = ([1] + ([2] if B >= 2 else []) + ([4] if B >= 3 else [])
-                + ([8] if B >= 5 else []))
+        # Padded group sizes given the slot count (prefill_group_sizes:
+        # n=3 pads to 4, n=5 pads to 8). A full-rate admission burst of
+        # 32 then costs 4 weight-read passes instead of 8 — prefill is
+        # weight-bandwidth-bound exactly like decode, so group width
+        # amortizes it. The same shapes serve a prompt covered by
+        # several windows (prefill_cover): no shape is added for it.
+        pads = self._group_sizes
         self._upload_slot_state()
         dev = self._dev
         zrow = np.zeros((cfg.pages_per_seq,), np.int32)
@@ -2206,57 +2318,6 @@ class InferenceEngine:
                     lowered.compile()
                 )
         return fn(*args, **kwargs)
-
-    def _run_prefill(
-        self, tokens: np.ndarray, start: int, last_rel: int,
-        page_table: np.ndarray, request: GenRequest,
-        seed_row: np.ndarray,
-    ) -> jax.Array:
-        """One prefill window at absolute offset `start`, sampling from
-        relative index `last_rel`. Returns the sampled token as a DEVICE
-        scalar — callers either discard it (non-final chunks, no sync at
-        all) or resolve it later (_resolve_prefills), so dispatching a
-        prefill never blocks the engine loop on the device."""
-        put = partial(jax.device_put, device=self._repl)
-        common = (
-            jax.device_put(tokens, self._prefill_tok),
-            put(np.asarray([start], dtype=np.int32)),
-            put(np.asarray([last_rel], dtype=np.int32)),
-            put(np.ascontiguousarray(page_table)),
-            put(seed_row.reshape(1, 2)),
-        )
-        sampling = (
-            put(np.asarray([request.temperature], dtype=np.float32)),
-            put(np.asarray([request.top_p], dtype=np.float32)),
-            put(np.asarray([self._eff_top_k(request)], dtype=np.int32)),
-        )
-        if self._faults is not None:
-            self._faults.maybe_raise("prefill-error", replica=self.replica_id, tier=self._tier)
-        width = int(tokens.shape[1])
-        with self._phase("prefill", bucket=width, rows=width,
-                         tokens=last_rel + 1):
-            # The last chunk's stamp is the one that stays: the dispatch
-            # that completes the prompt.
-            request.timings.prefill_dispatched = time.monotonic()
-            if self._spec:
-                first_token, self.paged, self.d_paged = self._jit_spec_prefill(
-                    self.params, self.draft_params,
-                    self.model_cfg, self.draft_cfg,
-                    self.paged, self.d_paged,
-                    *common, *sampling,
-                    greedy=request.temperature == 0.0,
-                    candidates=self.config.top_p_candidates,
-                    mesh=self.mesh,
-                )
-            else:
-                first_token, self.paged = self._jit_prefill(
-                    self.params, self.model_cfg, self.paged,
-                    *common, *sampling,
-                    greedy=request.temperature == 0.0,
-                    candidates=self.config.top_p_candidates,
-                    mesh=self.mesh,
-                )
-            return first_token
 
     def _merge_slot(
         self, slot_idx: int, slot: _Slot, toks_dev: jax.Array, row: int
@@ -2870,14 +2931,15 @@ class InferenceEngine:
         return spent
 
     def _prefill_one_chunk(self, slot_idx: int) -> int:
-        """Advance a long-prompt slot by one fixed-size chunk; the final
-        chunk samples the first token and activates the slot. Returns
-        the charged prefill width — one full chunk window when a
-        dispatch issued (the budget charges at chunk granularity even
-        for a partial final chunk), 0 when the slot exited without
-        dispatching (cancelled / deadline-expired / prefill failure),
-        so quota accounting (schedlint SL005) never bills tokens that
-        never rode a dispatch."""
+        """Advance a long-prompt slot by one dispatch: a chunk-wide
+        window, or — once no more than a chunk is left — the cover of
+        the tail (prefill_cover: a 600-token prompt's last 88 take a
+        128-row window, not a second 512), whose last window samples
+        the first token and activates the slot. Returns the rows
+        dispatched (what the budget is charged), 0 when the slot exited
+        without dispatching (cancelled / deadline-expired / prefill
+        failure), so quota accounting (schedlint SL005) never bills
+        tokens that never rode a dispatch."""
         slot = self._slots[slot_idx]
         assert slot is not None and slot.pending is not None
         request = slot.request
@@ -2889,33 +2951,16 @@ class InferenceEngine:
             self.metrics.on_deadline_expired("prefill")
             self._finish(slot_idx, error=f"{DEADLINE_MSG} during prefill")
             return 0
-        C = self._chunk
-        prompt_len = len(slot.pending)
-        take = min(C, prompt_len - slot.filled)
-        tokens = np.zeros((1, C), dtype=np.int32)
-        tokens[0, :take] = slot.pending[slot.filled:slot.filled + take]
-        final = slot.filled + take >= prompt_len
-        try:
-            token_dev = self._run_prefill(
-                tokens, slot.filled, take - 1, slot.table, request,
-                slot.seed_row,
-            )
-        except Exception as e:
-            self._finish(slot_idx, error=f"prefill failed: {e}")
+        width, rows = self._cover_rows(
+            slot_idx, slot, slot.pending[slot.filled:], slot.filled,
+            self._chunk_widths,
+        )
+        # No row of a dispatch that leaves tokens behind is `last`:
+        # nothing is merged and its device token is never read.
+        if not self._dispatch_prefill_group(width, rows):
             return 0
-        if self.timeline is not None:
-            self.timeline.prefill(slot_idx, take, final)
-        # The chunk window is C tokens wide; `take` carried real ones.
-        self.metrics.on_prefill_rows(C, take)
-        if final:
-            # The final chunk's sampled token activates the lane (on-device
-            # merge; the host delivers it to the client once its async copy
-            # lands). Non-final chunks never sync at all — the device token
-            # is discarded.
-            self._merge_slot(slot_idx, slot, token_dev, 0)
-        else:
-            slot.filled += take
-        return C
+        slot.filled += width * len(rows)
+        return width * len(rows)
 
     def _upload_slot_state(self) -> None:
         # A COPY of each mirror goes up, never the mirror: on the CPU

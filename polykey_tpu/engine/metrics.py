@@ -180,6 +180,11 @@ class EngineMetrics:
         # and chunks (on_prefill_rows).
         self.prefill_rows_dispatched = 0
         self.prefill_rows_useful = 0
+        # Windows (real rows) among those dispatches, and prompts whose
+        # cover put more than one window into a dispatch
+        # (engine.prefill_cover).
+        self.prefill_windows_dispatched = 0
+        self.prefill_prompts_split = 0
         # Deepest in-flight target any dispatch ran with (on_dispatch).
         self.depth_target_max = 0
         # Lookahead pipeline accounting (ISSUE 6): per processed block,
@@ -297,14 +302,19 @@ class EngineMetrics:
             self.tokens_dispatched_total += dispatched
             self.tokens_useful_total += useful
 
-    def on_prefill_rows(self, dispatched: int, useful: int) -> None:
+    def on_prefill_rows(self, dispatched: int, useful: int,
+                        windows: int, split: int) -> None:
         """One bucketed-group or chunk prefill dispatch: `dispatched`
         rows computed (n_pad x bucket, or the chunk width) for `useful`
-        real prompt tokens. Feeds the prefill-only pair and, as before,
-        the mixed padding-waste pair."""
+        real prompt tokens in `windows` real rows, `split` of its
+        prompts covered by more than one of them. Feeds the
+        prefill-only counters and, as before, the mixed padding-waste
+        pair."""
         with self._lock:
             self.prefill_rows_dispatched += dispatched
             self.prefill_rows_useful += useful
+            self.prefill_windows_dispatched += windows
+            self.prefill_prompts_split += split
             self.tokens_dispatched_total += dispatched
             self.tokens_useful_total += useful
 
@@ -603,6 +613,9 @@ class EngineMetrics:
                 "decode_lane_steps_dead": self.decode_lane_steps_dead,
                 "prefill_rows_dispatched": self.prefill_rows_dispatched,
                 "prefill_rows_useful": self.prefill_rows_useful,
+                "prefill_windows_dispatched":
+                    self.prefill_windows_dispatched,
+                "prefill_prompts_split": self.prefill_prompts_split,
                 "blocks_processed": self.blocks_processed,
                 "lookahead_observed_max": self.lookahead_max,
                 "lookahead_observed_mean": (

@@ -186,6 +186,12 @@ _ENGINE_FAMILIES: tuple = (
      "included).", "prefill_rows_dispatched"),
     ("counter", "polykey_prefill_rows_useful_total",
      "Real prompt tokens among those rows.", "prefill_rows_useful"),
+    ("counter", "polykey_prefill_windows_dispatched_total",
+     "Prefill windows (real rows) among those dispatches.",
+     "prefill_windows_dispatched"),
+    ("counter", "polykey_prefill_prompts_split_total",
+     "Prompts covered by more than one window of a dispatch.",
+     "prefill_prompts_split"),
 )
 
 _SPEC_FAMILIES: tuple = (

@@ -89,3 +89,20 @@ def test_fails_alone_in_a_directory(tmp_path):
     )
     assert run.returncode != 0
     assert _last_json(run.stdout) is None
+
+
+def test_prefill_cover_check_rehearses_on_the_cpu():
+    """scripts/tpu_prefill_cover_check.py at the configuration's tiny size:
+    the two-row prefill agrees with the one-row window (float32: to
+    rounding), so the script the chip runs is known to run."""
+    run = subprocess.run(
+        [sys.executable,
+         os.path.join(ROOT, "scripts", "tpu_prefill_cover_check.py"),
+         "--tiny", "--reps", "1"],
+        env=_env(), cwd=ROOT, capture_output=True, text=True, timeout=600,
+    )
+    assert run.returncode == 0, run.stderr[-2000:]
+    lines = run.stdout.strip().splitlines()
+    assert lines[0].startswith("device cpu")
+    assert lines[-1] == "PASS"
+    assert "first token" in run.stdout

@@ -1021,3 +1021,264 @@ def test_int8_kv_engine_serves():
             assert len(tokens) == 12
     finally:
         engine.shutdown()
+
+
+# -- the prefill cover (ISSUE 41): fewest rows the compiled windows allow ------
+
+
+@pytest.mark.parametrize("buckets", [
+    (128, 512), (16, 32), (16, 64), (16, 128), (32,), (64, 256, 1024),
+], ids=lambda b: "-".join(map(str, b)))
+def test_prefill_cover_is_the_fewest_rows(buckets):
+    """Every span from 1 to twice the widest bucket, at a page-aligned
+    start, for every group-size set a slot count gives: the cover holds
+    exactly the span, never dispatches more rows than one bucket (or
+    whole chunks) did, keeps every start page-aligned and its split
+    windows inside one group."""
+    from polykey_tpu.engine.engine import prefill_cover, prefill_group_sizes
+
+    page, widest = 8, max(buckets)
+
+    def before(n):
+        if n > widest:
+            return -(-n // widest) * widest
+        return next(b for b in buckets if n <= b)
+
+    def pad(k, groups):
+        return next(g for g in groups if g >= k)
+
+    for slots in (1, 2, 4, 16):
+        groups = prefill_group_sizes(slots)
+        for start in (0, 5 * page):
+            for n in range(1, 2 * widest + 1):
+                windows = prefill_cover(n, start, buckets, groups, page)
+                lead = (n - 1) // widest    # chunk-wide windows, then the tail
+                tail = windows[lead:]
+                assert all(width == widest for width, _ in windows[:lead])
+                # Contiguous from `start`, every start page-aligned.
+                at = start
+                for width, begin in windows:
+                    assert begin == at and begin % page == 0
+                    assert width in buckets
+                    at += width
+                # Holds the span, and no window is empty.
+                assert at - start >= n > at - start - windows[-1][0]
+                # The tail is one dispatch: one width, inside a group.
+                assert len({w for w, _ in tail}) == 1
+                assert len(tail) <= groups[-1]
+                rows = lead * widest + pad(len(tail), groups) * tail[0][0]
+                assert rows <= before(n), (n, windows)
+                # No narrower or equally wide cover with fewer windows.
+                left = n - lead * widest
+                for b in buckets:
+                    k = -(-left // b)
+                    if k == 1 or (b % page == 0 and k <= groups[-1]):
+                        assert (pad(len(tail), groups) * tail[0][0], len(tail)) \
+                            <= (pad(k, groups) * b, k)
+    groups = prefill_group_sizes(16)
+    if buckets == (128, 512):
+        assert prefill_cover(128, 0, buckets, groups, 16) == [(128, 0)]
+        assert prefill_cover(129, 0, buckets, groups, 16) == \
+            [(128, 0), (128, 128)]
+        assert prefill_cover(256, 32, buckets, groups, 16) == \
+            [(128, 32), (128, 160)]
+        assert prefill_cover(257, 0, buckets, groups, 16) == [(512, 0)]
+        assert prefill_cover(600, 0, buckets, groups, 16) == \
+            [(512, 0), (128, 512)]
+        # One compiled row: nothing to split over.
+        assert prefill_cover(200, 0, buckets, (1,), 16) == [(512, 0)]
+        # A bucket that is not whole pages is never split over.
+        assert prefill_cover(200, 0, buckets, groups, 48) == [(512, 0)]
+
+
+def _hand_driven(config):
+    """An engine whose loop has ended: driven by hand."""
+    eng = InferenceEngine(config)
+    eng.shutdown()
+    return eng
+
+
+@pytest.mark.parametrize("small,n,start", [
+    (16, 25, 0), (16, 32, 0), (16, 27, 8), (8, 13, 16),
+])
+def test_two_rows_on_one_table_equal_one_wide_row(small, n, start):
+    """`_prefill_fn` with rows at (start, start + small) on ONE page table
+    is the prefill of the same tokens in one row of 2 x small: within a
+    layer every row's K/V is written before any row's attention gathers,
+    so the second row reads the first row's keys. Float32 on the CPU:
+    the logits at the last real position, the sampled token and the
+    pages written agree to rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from polykey_tpu.engine.engine import _prefill_fn
+    from polykey_tpu.models.transformer import forward_paged, unembed
+
+    eng = _hand_driven(TEST_CONFIG)     # only its params and pools are used
+    cfg, page = eng.model_cfg, TEST_CONFIG.page_size
+    pages_each = (start + 2 * small) // page
+    rng = np.random.default_rng(n)
+    ids = rng.integers(32, 127, size=start + n).astype(np.int32)
+
+    def table(first):
+        row = np.zeros((TEST_CONFIG.pages_per_seq,), np.int32)
+        row[:pages_each] = first + np.arange(pages_each)
+        return row
+
+    def rows(width, first):
+        k = -(-n // width)
+        tokens = np.zeros((k, width), np.int32)
+        tokens.reshape(-1)[:n] = ids[start:]
+        starts = (start + np.arange(k) * width).astype(np.int32)
+        last_rel = np.full((k,), width - 1, np.int32)
+        last_rel[-1] = start + n - 1 - starts[-1]
+        return (jnp.asarray(tokens), jnp.asarray(starts),
+                jnp.asarray(last_rel), jnp.asarray(np.tile(table(first), (k, 1))))
+
+    def logits_of(paged, tokens, starts, last_rel, tables):
+        positions = starts[:, None] + jnp.arange(tokens.shape[1])[None, :]
+        hidden, paged = forward_paged(
+            eng.params, cfg, tokens, positions, paged, tables
+        )
+        last = hidden[jnp.arange(len(starts)), last_rel]
+        return unembed(eng.params, cfg, last), paged
+
+    def sampled(paged, tokens, starts, last_rel, tables):
+        k = len(starts)
+        return _prefill_fn(
+            eng.params, cfg, paged, tokens, starts, last_rel, tables,
+            jnp.zeros((k, 2), jnp.int32), jnp.zeros((k,), jnp.float32),
+            jnp.ones((k,), jnp.float32), jnp.zeros((k,), jnp.int32),
+            greedy=True,
+        )
+
+    paged = eng.paged
+    one_first, two_first = 1, 1 + pages_each
+    if start:
+        # The cached prefix both variants start from, on both tables.
+        for first in (one_first, two_first):
+            prefix = np.zeros((1, start), np.int32)
+            prefix[0] = ids[:start]
+            _, paged = logits_of(
+                paged, jnp.asarray(prefix), jnp.zeros((1,), jnp.int32),
+                jnp.asarray([start - 1], jnp.int32),
+                jnp.asarray(table(first))[None],
+            )
+    one, paged = logits_of(paged, *rows(2 * small, one_first))
+    two, paged = logits_of(paged, *rows(small, two_first))
+    assert len(two) == -(-n // small)
+    np.testing.assert_allclose(one[-1], two[-1], rtol=0, atol=2e-5)
+    live = -(-(start + n) // page)
+    for pool in (paged.k, paged.v):
+        a = np.array(pool[:, one_first:one_first + live])
+        b = np.array(pool[:, two_first:two_first + live])
+        tail = start + n - (live - 1) * page    # real rows of the last page
+        a[:, -1, tail:] = b[:, -1, tail:] = 0   # padding rows differ
+        assert np.abs(a).max() > 0
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-5)
+    token_one, paged = sampled(paged, *rows(2 * small, one_first))
+    token_two, paged = sampled(paged, *rows(small, two_first))
+    assert int(token_one[-1]) == int(token_two[-1]) == int(jax.numpy.argmax(one[-1]))
+
+
+@pytest.mark.parametrize("budget,admitted", [(None, 3), (32, 1), (33, 2)])
+def test_admit_puts_a_prompts_windows_in_one_group(budget, admitted):
+    """Two prompts that split and one that does not, waiting together:
+    each prompt's windows are consecutive rows of one dispatch, a group
+    that cannot take a prompt whole goes out first, the budget is
+    charged the cover's rows, and the counters say what was dispatched."""
+    import dataclasses
+
+    config = dataclasses.replace(
+        TEST_CONFIG, prefill_buckets=(16, 64), max_seq_len=128,
+        num_pages=96,
+    )
+    eng = _hand_driven(config)
+    dispatched = []
+    dispatch = eng._dispatch_prefill_group
+
+    def recording(bucket, group):
+        dispatched.append((bucket, [
+            (slot_idx, len(ids), start, last)
+            for slot_idx, _, ids, start, last in group
+        ]))
+        return dispatch(bucket, group)
+
+    eng._dispatch_prefill_group = recording
+    prompts = ["a" * 19, "b" * 30, "c" * 9]         # 20, 31, 10 tokens
+    for prompt in prompts:
+        eng._submit.put(GenRequest(prompt=prompt, max_new_tokens=4))
+    worked, spent = eng._admit(budget=budget)
+    assert worked
+    want = [
+        (16, [(0, 16, 0, False), (0, 4, 16, True),
+              (1, 16, 0, False), (1, 15, 16, True)]),
+        (16, [(2, 10, 0, True)]),
+    ]
+    if admitted == 1:
+        want = [(16, want[0][1][:2])]
+    elif admitted == 2:
+        want = want[:1]
+    assert dispatched == want
+    assert spent == 32 * min(admitted, 2) + 16 * (admitted == 3)
+    assert eng._submit.qsize() == 3 - admitted
+    snap = eng.metrics.snapshot()
+    windows = sum(len(rows) for _, rows in want)
+    assert snap["prefill_windows_dispatched"] == windows
+    assert snap["prefill_prompts_split"] == min(admitted, 2)
+    padded = sum(
+        16 * next(g for g in (1, 2, 4) if g >= len(rows)) for _, rows in want
+    )
+    assert snap["prefill_rows_dispatched"] == padded
+    assert snap["prefill_rows_useful"] == sum(
+        n for _, rows in want for _, n, _, _ in rows
+    )
+    # Only a prompt's last window activated its lane.
+    assert int(eng._active.sum()) == admitted
+    assert [int(n) for n in eng._seq_lens[:3]] == \
+        [21, 32, 11][:admitted] + [0] * (3 - admitted)
+
+
+def test_warmed_engine_compiles_nothing_for_a_covered_mix():
+    """A prompt over several windows rides shapes the warm-up compiled:
+    a mix of split, unsplit and long prompts after warm-up builds no
+    executable (same-engine deltas only: jit caches are shared between
+    engines with equal jit parameters)."""
+    import dataclasses
+
+    eng = InferenceEngine(dataclasses.replace(
+        TEST_CONFIG, compile_warmup=True, warm_sampled_variants=False,
+        # A shape key no other test uses, as the warm-up tests above.
+        max_decode_slots=7, prefill_buckets=(24, 96), max_seq_len=192,
+        num_pages=200,
+    ))
+    try:
+        before = eng.stats()
+        n_prefill = eng._jit_prefill._cache_size()
+        n_decode = eng._jit_decode._cache_size()
+        # 10: one 24-window; 30 and 48: two; 60: three would pad to four,
+        # so the 96-window; 100: a 96-chunk and a 24 tail; 130: a chunk
+        # and a split tail.
+        lengths = (10, 30, 48, 60, 96, 100, 130)
+        requests = [
+            GenRequest(prompt="x" * (n - 1), max_new_tokens=4)
+            for n in lengths
+        ]
+        for r in requests[:4]:
+            eng.submit(r)
+        for r in requests[:4]:
+            _, done, error = _collect(r)
+            assert error is None and done is not None
+        for r in requests[4:]:
+            eng.submit(r)
+            _, done, error = _collect(r)
+            assert error is None and done is not None
+        after = eng.stats()
+        assert after["compiles"] == before["compiles"]
+        assert eng._jit_prefill._cache_size() == n_prefill
+        assert eng._jit_decode._cache_size() == n_decode
+        assert after["prefill_prompts_split"] == 3          # 30, 48, 130
+        assert after["prefill_windows_dispatched"] == 1 + 2 + 2 + 1 + 1 + 2 + 3
+        assert after["prefill_rows_useful"] == sum(lengths)
+    finally:
+        eng.shutdown()

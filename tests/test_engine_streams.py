@@ -19,6 +19,12 @@ under a sticky mix, a speculative engine at depth 1 and 2 — each over
 prompt lengths on both sides of every bucket and chunk edge, in two
 geometries: buckets 16/32 and the registry default 128/512.
 
+The cover (ISSUE 41) — a prompt prefilled as several windows of a
+narrower bucket, consecutive rows of one group dispatch — has cases of
+its own at its edges, in a third geometry (buckets 16/64) where a small
+prompt splits, and in 16/32 where none does (two 16-windows are the 32
+bucket's rows).
+
 Every case runs its traffic TWICE on fresh engines in one process and
 holds both passes to the reference: the second pass finds every
 executable already compiled, which is the condition under which the
@@ -29,6 +35,7 @@ cannot count on another file having warmed its shapes).
 
 import dataclasses
 import queue
+import threading
 import time
 
 import jax
@@ -97,6 +104,21 @@ GEOMETRIES = {
     ),
 }
 GEOMETRY_IDS = tuple(GEOMETRIES)
+
+# The cover's cases run in these: 16/64 splits a 17..32-token prompt over
+# two 16-windows (as 128/512 does 129..256), 16/32 never splits.
+COVER_GEOMETRIES = {
+    **GEOMETRIES,
+    "b16-64": Geometry(
+        EngineConfig(
+            **_COMMON, page_size=8, num_pages=96, max_seq_len=128,
+            prefill_buckets=(16, 64), max_new_tokens_cap=16,
+        ),
+        edges=(1, 15, 16, 17, 63, 64, 65, 90), max_new=8,
+        clip=16, clipped_prompt=90,
+    ),
+}
+COVER_GEOMETRY_IDS = tuple(COVER_GEOMETRIES)
 
 _REF_NEW = 16       # one compiled generate() per geometry; streams are prefixes
 
@@ -362,6 +384,170 @@ def test_mix_matches_generate(mix, geometry_id):
     serve_twice(config, geometry, specs, check)
 
 
+# -- the cover: a prompt over several windows of one dispatch ------------------
+
+
+def _splits(geometry, n: int) -> bool:
+    """Whether `n` tokens to prefill take two windows of the small bucket:
+    more than one, no more than two, and two of them fewer rows than the
+    wide bucket (stated here from the geometry, not from the engine)."""
+    small, wide = geometry.config.prefill_buckets
+    return small < n <= 2 * small < wide
+
+
+def _cover_edge(geometry, n):
+    # One prompt length beside a decoding neighbour, greedy and sampled.
+    specs = [
+        dict(prompt="neighbour", max_new_tokens=16),
+        dict(prompt=_prompt(n), max_new_tokens=geometry.max_new,
+             after=(0, 2)),
+        dict(prompt=_prompt(n, salt=3), max_new_tokens=geometry.max_new,
+             temperature=0.9, top_p=0.8, top_k=5, seed=42 + n),
+    ]
+    return geometry.config, specs, dict(split=2 * _splits(geometry, n))
+
+
+def _cover_edges():
+    def at(pick):
+        def build(geometry):
+            small = geometry.config.prefill_buckets[0]
+            return _cover_edge(geometry, pick(small))
+        return build
+
+    return {
+        "b": at(lambda b: b), "b+1": at(lambda b: b + 1),
+        "mid": at(lambda b: b + b // 2), "2b": at(lambda b: 2 * b),
+        "2b+1": at(lambda b: 2 * b + 1),
+    }
+
+
+def _cover_two_split(geometry):
+    # Two prompts that both split, sent together from idle: their four
+    # windows are one group's rows when one admission takes both.
+    small = geometry.config.prefill_buckets[0]
+    lengths = (small + 5, 2 * small - 3)
+    specs = [
+        dict(prompt=_prompt(n, salt=i), max_new_tokens=geometry.max_new)
+        for i, n in enumerate(lengths)
+    ]
+    split = sum(_splits(geometry, n) for n in lengths)
+    return geometry.config, specs, dict(
+        split=split, windows=2 + split if split else None,
+    )
+
+
+def _cover_split_beside_unsplit(geometry):
+    # One group holds a split prompt's two rows and an unsplit one's row;
+    # the wide bucket's group goes out beside it.
+    small = geometry.config.prefill_buckets[0]
+    lengths = (small + 3, small - 2, 2 * small + 1)
+    specs = [
+        dict(prompt=_prompt(n, salt=i), max_new_tokens=geometry.max_new,
+             **(dict(temperature=0.9, top_k=5, seed=5) if i == 1 else {}))
+        for i, n in enumerate(lengths)
+    ]
+    return geometry.config, specs, dict(
+        split=sum(_splits(geometry, n) for n in lengths),
+    )
+
+
+def _letters(n: int, first: int) -> str:
+    return "".join(chr(first + (i * 7) % 26) for i in range(n))
+
+
+def _cover_prefix_suffix(geometry):
+    # The second prompt shares a cached page-aligned prefix with the
+    # first: only its suffix prefills, from the offset, and that suffix
+    # is itself covered by two small windows where the geometry splits.
+    base = geometry.config
+    small = base.prefill_buckets[0]
+    prefix = small + 2 * base.page_size             # tokens, whole pages
+    first = _letters(prefix + 5, 97)                # prefix + 6 tokens
+    second = first[:prefix] + _letters(small + 3, 65)
+    suffix = small + 4                              # = tokens - prefix
+    config = dataclasses.replace(base, prefix_cache=True)
+    specs = [
+        dict(prompt=first, max_new_tokens=geometry.max_new),
+        dict(prompt=second, max_new_tokens=geometry.max_new, after=(0, 1)),
+    ]
+    return config, specs, dict(
+        split=_splits(geometry, prefix + 6) + _splits(geometry, suffix),
+        prefix_hit_tokens=prefix,
+    )
+
+
+def _cover_spec(geometry):
+    # A speculative engine's group dispatch prefills both pools per row.
+    small = geometry.config.prefill_buckets[0]
+    lengths = (small + 1, small + small // 2, 2 * small)
+    config = dataclasses.replace(
+        geometry.config, draft_model="tiny-llama", spec_gamma=3,
+    )
+    specs = [
+        dict(prompt=_prompt(n, salt=i), max_new_tokens=geometry.max_new,
+             seed=11)
+        for i, n in enumerate(lengths)
+    ]
+    return config, specs, dict(
+        split=sum(_splits(geometry, n) for n in lengths), drafts=True,
+    )
+
+
+def _cover_long_tail(geometry):
+    # Long prompts, one at a time: the chunk-wide window, then the tail
+    # in the small window — and a tail that is itself split.
+    small, wide = geometry.config.prefill_buckets
+    tails = (small - 3, small + 5)
+    specs = [
+        dict(prompt=_prompt(wide + tail, salt=i),
+             max_new_tokens=geometry.max_new)
+        for i, tail in enumerate(tails)
+    ]
+    specs[1]["after"] = (0, geometry.max_new)
+    rows = sum(
+        wide + (2 * small if _splits(geometry, tail)
+                else small if tail <= small else wide)
+        for tail in tails
+    )
+    return geometry.config, specs, dict(
+        split=sum(_splits(geometry, tail) for tail in tails), rows=rows,
+    )
+
+
+COVER_CASES = {
+    **_cover_edges(),
+    "two-split": _cover_two_split,
+    "split-beside-unsplit": _cover_split_beside_unsplit,
+    "prefix-suffix": _cover_prefix_suffix,
+    "spec": _cover_spec,
+    "long-tail": _cover_long_tail,
+}
+
+
+@pytest.mark.parametrize("geometry_id", COVER_GEOMETRY_IDS)
+@pytest.mark.parametrize("case", tuple(COVER_CASES))
+def test_cover_matches_generate(case, geometry_id):
+    """A prompt covered by several windows of one group dispatch streams
+    what generate() streams, and the counters say how it was covered."""
+    geometry = COVER_GEOMETRIES[geometry_id]
+    config, specs, want = COVER_CASES[case](geometry)
+
+    def check(stats):
+        assert stats["prefill_prompts_split"] == want["split"]
+        assert stats["prefill_windows_dispatched"] >= \
+            len(specs) + want["split"]
+        if want.get("windows") is not None:
+            assert stats["prefill_windows_dispatched"] == want["windows"]
+        if want.get("rows") is not None:
+            assert stats["prefill_rows_dispatched"] == want["rows"]
+        if "prefix_hit_tokens" in want:
+            assert stats["prefix_hit_tokens"] == want["prefix_hit_tokens"]
+        if want.get("drafts"):
+            assert stats["drafts_proposed"] > 0
+
+    serve_twice(config, geometry, specs, check)
+
+
 # -- speculative engine -------------------------------------------------------
 
 
@@ -431,15 +617,6 @@ def _chaos_config(geometry, **kw):
     )
 
 
-def _await(predicate, timeout=20.0, interval=0.02):
-    deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        if predicate():
-            return True
-        time.sleep(interval)
-    return predicate()
-
-
 @pytest.mark.parametrize("geometry_id", GEOMETRY_IDS)
 def test_pool_resume_matches_generate(geometry_id):
     """A replica wedged mid-stream: the stream resumes on the survivor and
@@ -505,7 +682,14 @@ def test_supervisor_restart_mid_stream_matches_generate(geometry_id,
         watchdog=watchdog, health=health,
         max_restarts=2, restart_window_s=60.0,
         check_interval_s=0.05, join_timeout_s=5.0,
-    ).start()
+    )
+    # The rebuild compiles a warmed engine while the suite's other
+    # workers compile theirs: the test waits for the supervisor to say
+    # it swapped (or gave up), not for a number of seconds.
+    settled = threading.Event()
+    supervisor.add_restart_listener(lambda fresh: settled.set())
+    supervisor.add_giveup_listener(lambda reason: settled.set())
+    supervisor.start()
     try:
         want = reference_stream(
             jax.device_get(engine.params), engine.model_cfg,
@@ -523,7 +707,8 @@ def test_supervisor_restart_mid_stream_matches_generate(geometry_id,
         head = reader.tokens[id(victim)]
         assert kind == "error"
         assert 2 <= len(head) < 12 and head == want[:len(head)]
-        assert _await(lambda: supervisor.restarts == 1, timeout=15.0)
+        assert settled.wait(timeout=120.0)      # a hang's guard, as _Reader's
+        assert supervisor.restarts == 1 and not supervisor.gave_up
         faults.clear()
         fresh = supervisor.engine
         assert fresh is not engine
