@@ -699,3 +699,48 @@ class EngineConfig:
                         f"sp={self.sp} must divide every prefill bucket "
                         f"and the prefill chunk (got {b})"
                     )
+        self._refuse_for_recurrent_state()
+
+    def _refuse_for_recurrent_state(self) -> None:
+        """A model with per-slot recurrent state (ModelConfig.stateful:
+        kv_cache.SlotState beside the pages) is served by the plain
+        prefill / decode pair on one device and by nothing else yet. Every
+        feature that moves, shares or rebuilds a slot's K/V pages would
+        have to move, share or rebuild that state with them, and none
+        does: each is refused here, in one place, rather than half-ported.
+        The model is looked up when the engine validates its config
+        (InferenceEngine.__init__), so a ModelConfig registered after this
+        EngineConfig was built is seen; a name the registry does not know
+        cannot be cleared and is refused too."""
+        from ..models.config import get_config
+
+        try:
+            model = get_config(self.model)
+        except KeyError as e:
+            raise ValueError(e.args[0]) from None
+        if not model.stateful:
+            return
+        refused = {
+            "prefix_cache (cached pages carry no recurrent state to resume "
+            "from)": self.prefix_cache,
+            "host_kv_bytes (the host tier spills pages, not state)":
+                self.host_kv_bytes > 0,
+            "disagg / disagg_tier (the KV handoff ships pages, not state)":
+                bool(self.disagg or self.disagg_tier),
+            "draft_model (a rejected draft token cannot be taken back out "
+            "of a recurrence)": self.draft_model is not None,
+            "kv_dtype=int8 (no quantized pool path for a layer pattern)":
+                self.kv_dtype == "int8",
+            "quantize (no quantized weights for a layer pattern yet)":
+                self.quantize,
+            "tp/dp/ep/sp/pp/num_slices > 1 (the state is not sharded)":
+                max(self.tp, self.dp, self.ep, self.sp, self.pp,
+                    self.num_slices) > 1,
+        }
+        for what, on in refused.items():
+            if on:
+                raise ValueError(
+                    f"{self.model} keeps per-slot recurrent state (Mamba-2 "
+                    f"h and conv columns, engine/kv_cache.py SlotState) "
+                    f"beside its KV pages; not supported with it: {what}"
+                )

@@ -78,6 +78,23 @@ def compile_counts() -> dict:
     }
 
 
+def release_compile_heap() -> None:
+    """Hand the host heap that compiling left behind back to the OS NOW,
+    before serving. A process that compiled its executables (a cold
+    start) otherwise pays that release some seconds into serving: every
+    thread stops for 1-2 s, the device idle under the engine's `process`
+    phase (PERF.md section 7; a warm start, which loads them, has no such
+    hole). glibc only; a no-op where malloc_trim is not to be had."""
+    import ctypes
+    import gc
+
+    gc.collect()
+    try:
+        ctypes.CDLL("libc.so.6").malloc_trim(0)
+    except (OSError, AttributeError):
+        pass
+
+
 def device_identity() -> dict:
     """Platform as JAX reports it, plus the roofline row it maps to
     (None off-TPU; an unknown TPU kind raises — roofline.detect_chip)."""

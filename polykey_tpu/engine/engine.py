@@ -71,7 +71,13 @@ from ..analysis import schedwitness as _schedwitness
 from ..faults import get_injector
 from ..models.config import ModelConfig, get_config
 from ..obs.timeline import TimelineRecorder, phase
-from ..models.transformer import forward_paged, unembed
+from ..models.hybrid import (
+    FROM_PREVIOUS_ROW,
+    FROM_SLOT,
+    FROM_ZERO,
+    PrefillRows,
+)
+from ..models.transformer import forward_slots, unembed
 from ..parallel.mesh import MeshConfig, create_mesh
 from ..parallel.sharding import (
     init_sharded_params,
@@ -86,6 +92,7 @@ from .device import (
     device_memory,
     install_compile_census,
     mosaic_calls,
+    release_compile_heap,
 )
 from .kv_cache import (
     AllocationError,
@@ -93,8 +100,10 @@ from .kv_cache import (
     KVHandoffState,
     KVWireError,
     PagedKV,
+    SlotState,
     fold_heads,
     init_paged_kv,
+    init_slot_state,
     unfold_heads,
 )
 from .metrics import EngineMetrics, RequestTimings
@@ -273,6 +282,7 @@ class _RRCursor:
 def _prefill_fn(
     params, cfg: ModelConfig, paged: PagedKV,
     tokens, start, last_rel, page_table, seeds, temperature, top_p, top_k,
+    state: SlotState = SlotState(), state_rows=None,
     *, greedy: bool, candidates: int = 0, mesh=None,
 ):
     """Prefill N windows (tokens [N, T]) at absolute positions
@@ -290,6 +300,12 @@ def _prefill_fn(
     garbage page — never read; padded GROUP rows point their whole table
     at the garbage page.
 
+    `state` is the per-slot recurrent state of a stateful model, donated
+    and returned like `paged` (an empty pytree otherwise), and
+    `state_rows` [N, 3] int32 says per row whose state it reads, where it
+    starts from and which slot keeps its end (hybrid.PrefillRows: slot,
+    source, store); a row's real length is last_rel + 1.
+
     `greedy` is a static variant selector: an all-greedy group takes a
     pure-argmax tail (no full-vocab sort, no RNG use) — at 128k-256k vocab
     the top-p sort is a real per-step cost, and greedy is the north-star
@@ -298,8 +314,12 @@ def _prefill_fn(
     """
     N, T = tokens.shape
     positions = start[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    hidden, paged = forward_paged(
-        params, cfg, tokens, positions, paged, page_table, mesh=mesh
+    rows = None if state_rows is None else PrefillRows(
+        state_rows[:, 0], state_rows[:, 1], state_rows[:, 2], last_rel + 1
+    )
+    hidden, paged, state = forward_slots(
+        params, cfg, tokens, positions, paged, page_table, state, rows=rows,
+        mesh=mesh,
     )
     last = hidden[jnp.arange(N), last_rel]                 # [N, H]
     logits = unembed(params, cfg, last)                    # [N, V]
@@ -307,13 +327,13 @@ def _prefill_fn(
         logits, seeds, start + last_rel + 1, temperature, top_p, top_k,
         greedy, candidates,
     )
-    return token, paged
+    return token, paged, state
 
 
 def _decode_fn(
     params, cfg: ModelConfig, paged: PagedKV,
     last_tokens, seq_lens, page_tables, active, caps, seeds, temperature,
-    top_p, top_k,
+    top_p, top_k, state: SlotState = SlotState(),
     *, greedy: bool, steps: int, eos_id: int, candidates: int = 0, mesh=None,
 ):
     """`steps` decode steps for the whole slot batch in ONE dispatch.
@@ -335,14 +355,18 @@ def _decode_fn(
 
     `greedy` (static) selects the argmax-only tail when every active slot
     is greedy, skipping sample_dynamic's [B, vocab] sort entirely.
+
+    `state` (the per-slot recurrent state of a stateful model; an empty
+    pytree otherwise) rides the scan's carry beside the pool: a sub-step
+    advances it for the lanes live at that sub-step and for no other.
     """
 
     def one(carry, _):
-        last, seq, act, paged = carry
+        last, seq, act, paged, state = carry
         positions = jnp.maximum(seq - 1, 0)[:, None]       # [B, 1]
-        hidden, paged = forward_paged(
+        hidden, paged, state = forward_slots(
             params, cfg, last[:, None], positions, paged, page_tables,
-            mesh=mesh,
+            state, active=act, mesh=mesh,
         )
         logits = unembed(params, cfg, hidden[:, 0])        # [B, V]
         # The new token lands at index seq → that position keys its draw.
@@ -353,13 +377,13 @@ def _decode_fn(
         new_seq = seq + act.astype(jnp.int32)
         cont = act & (tokens != eos_id) & (new_seq < caps)
         packed = jnp.where(act, tokens, -1)
-        return (tokens, new_seq, cont, paged), packed
+        return (tokens, new_seq, cont, paged, state), packed
 
-    carry = (last_tokens, seq_lens, active, paged)
-    (last, seq, act, paged), packed = jax.lax.scan(
+    carry = (last_tokens, seq_lens, active, paged, state)
+    (last, seq, act, paged, state), packed = jax.lax.scan(
         one, carry, None, length=steps
     )
-    return packed, last, seq, act, paged
+    return packed, last, seq, act, paged, state
 
 
 def _merge_lane_fn(
@@ -763,8 +787,8 @@ class InferenceEngine:
         self._jit_prefill = jax.jit(
             _prefill_fn,
             static_argnames=("cfg", "greedy", "candidates", "mesh"),
-            donate_argnames=("paged",),
-            out_shardings=(self._repl, self._pool_sharding),
+            donate_argnames=("paged", "state"),
+            out_shardings=(self._repl, self._pool_sharding, self._repl),
         )
         self._dp_steps = NamedSharding(self.mesh, PartitionSpec(None, "dp"))
         # Double-buffered slot state: the three per-step-advancing vectors
@@ -781,10 +805,12 @@ class InferenceEngine:
             static_argnames=(
                 "cfg", "greedy", "steps", "eos_id", "candidates", "mesh",
             ),
-            donate_argnames=("paged", "last_tokens", "seq_lens", "active"),
+            donate_argnames=(
+                "paged", "last_tokens", "seq_lens", "active", "state",
+            ),
             out_shardings=(
                 self._dp_steps, self._dp_vec, self._dp_vec,
-                self._dp_vec, self._pool_sharding,
+                self._dp_vec, self._pool_sharding, self._repl,
             ),
         )
         # Lane merges: tiny functional updates of the device-resident decode
@@ -856,6 +882,17 @@ class InferenceEngine:
             )
 
         self.paged = new_pool(self.model_cfg)
+        # What a slot holds beside its pages (kv_cache.SlotState): born
+        # on the device like the pools; an empty pytree for a model with
+        # no recurrent state.
+        self.state = SlotState()
+        if self.model_cfg.stateful:
+            self.state = jax.tree.map(
+                lambda x: jnp.zeros(x.shape, x.dtype, device=self._repl),
+                jax.eval_shape(
+                    lambda: init_slot_state(self.model_cfg, B, self._dtype)
+                ),
+            )
         self.allocator = BlockAllocator(config.num_pages)
         # --- Host-memory KV tier (ISSUE 15): a second page pool in host
         # RAM for COLD pages (prefix-cache entries of finished sticky
@@ -1159,6 +1196,10 @@ class InferenceEngine:
         self._depth_target = self._depth
         if config.compile_warmup:
             self._compile_warmup()
+            # What building or loading the executables left on the host
+            # heap goes back to the OS before the first request, not 2 s
+            # into serving (device.release_compile_heap).
+            release_compile_heap()
         self._wake = threading.Event()
         self._stop = threading.Event()
         self.dead: Optional[str] = None
@@ -1416,6 +1457,9 @@ class InferenceEngine:
                 "slots_total": self.config.max_decode_slots,
                 "pages_free": self.allocator.num_free,
                 "pages_total": self.config.num_pages,
+                # Bytes of per-slot recurrent state beside the pool
+                # (kv_cache.SlotState; 0 for a model that has none).
+                "state_pool_bytes": self.state.nbytes,
                 "queued": self._submit.qsize(),
                 "inflight_blocks": len(self._inflight_q),
                 "prefill_budget": self._prefill_budget,
@@ -2048,12 +2092,15 @@ class InferenceEngine:
             put(starts), put(last_rel), put(tables), put(seeds),
             put(temp), put(top_p), put(top_k),
         )
+        # A stateful model: what each row does with its slot's state.
+        stateful = self.model_cfg.stateful
+        state_rows = self._state_rows(group, n_pad) if stateful else None
         real = sum(len(row[2]) for row in group)
         try:
             if self._faults is not None:
                 self._faults.maybe_raise("prefill-error", replica=self.replica_id, tier=self._tier)
             with self._phase("prefill", bucket=bucket, rows=n_pad * bucket,
-                             tokens=real):
+                             tokens=real, stateful=stateful):
                 # The last dispatch's stamp is the one that stays: the
                 # one that completes the prompt.
                 issued = time.monotonic()
@@ -2073,9 +2120,10 @@ class InferenceEngine:
                         mesh=self.mesh,
                     )
                 else:
-                    toks_dev, self.paged = self._jit_prefill(
+                    toks_dev, self.paged, self.state = self._jit_prefill(
                         self.params, self.model_cfg, self.paged,
-                        *common,
+                        *common, self.state,
+                        None if state_rows is None else put(state_rows),
                         greedy=greedy,
                         candidates=self.config.top_p_candidates,
                         mesh=self.mesh,
@@ -2095,12 +2143,39 @@ class InferenceEngine:
         self.metrics.on_prefill_rows(
             n_pad * bucket, real, n, sum(c > 1 for c in rows_of.values()),
         )
+        if stateful:
+            sources = collections.Counter(state_rows[:n, 1].tolist())
+            self.metrics.on_state_rows(
+                sources[FROM_ZERO], sources[FROM_PREVIOUS_ROW],
+                sources[FROM_SLOT],
+            )
         for r, (slot_idx, slot, ids, _, last) in enumerate(group):
             if self.timeline is not None:
                 self.timeline.prefill(slot_idx, len(ids), last)
             if last:
                 self._merge_slot(slot_idx, slot, toks_dev, r)
         return True
+
+    def _state_rows(self, group: list, n_pad: int) -> np.ndarray:
+        """[n_pad, 3] int32 (slot, source, store) of a prefill group for a
+        stateful model (hybrid.PrefillRows). A window at position 0
+        starts from zero state — admission resets the slot by never
+        reading what its last occupant left; a later window starts where
+        the row above ended when that row is the same prompt's (the
+        cover's consecutive rows), else from what the slot stores (a
+        long prompt's next chunk). Only a slot's LAST row of the dispatch
+        stores: the others, and the padded rows, name a slot past the
+        last, which the scatter drops."""
+        rows = np.zeros((n_pad, 3), np.int32)
+        rows[:, 2] = len(self._slots)
+        for r, (slot_idx, _, _, start, _) in enumerate(group):
+            chained = r > 0 and group[r - 1][0] == slot_idx
+            source = (FROM_ZERO if start == 0 else
+                      FROM_PREVIOUS_ROW if chained else FROM_SLOT)
+            ends = r + 1 == len(group) or group[r + 1][0] != slot_idx
+            rows[r] = (slot_idx, source,
+                       slot_idx if ends else len(self._slots))
+        return rows
 
     def _compile_warmup(self) -> None:
         """Pre-compile the greedy prefill group shapes and the greedy
@@ -2138,6 +2213,12 @@ class InferenceEngine:
                     put(np.ones((n,), np.float32)),
                     put(np.zeros((n,), np.int32)),
                 )
+                # A stateful model's rows read slot 0 from zero and store
+                # nowhere.
+                state_rows = (
+                    put(self._state_rows([], n))
+                    if self.model_cfg.stateful else None
+                )
                 # greedy is a static argname keyed on the BATCH (all-greedy
                 # vs any-sampled), so both variants occur at serving time —
                 # warm both or the first sampled admission pays a compile.
@@ -2156,10 +2237,10 @@ class InferenceEngine:
                             mesh=self.mesh,
                         )
                     else:
-                        toks_dev, self.paged = self._warm_call(
+                        toks_dev, self.paged, self.state = self._warm_call(
                             "prefill", self._jit_prefill,
                             self.params, self.model_cfg, self.paged,
-                            *window,
+                            *window, self.state, state_rows,
                             greedy=greedy,
                             candidates=self.config.top_p_candidates,
                             mesh=self.mesh,
@@ -2239,12 +2320,13 @@ class InferenceEngine:
                         dev["last_tokens"], dev["seq_lens"], dev["page_tables"],
                         dev["active"], dev["caps"], dev["seeds"],
                         dev["temperature"], dev["top_p"], dev["top_k"],
+                        self.state,
                         greedy=False, steps=steps,
                         eos_id=self.tokenizer.eos_id,
                         candidates=0, mesh=self.mesh,
                     )
                     (_, dev["last_tokens"], dev["seq_lens"], dev["active"],
-                     self.paged) = outs
+                     self.paged, self.state) = outs
         else:
             # greedy is batch-keyed at dispatch (all-greedy vs any-sampled)
             # and the adaptive dispatcher alternates between the solo and
@@ -2257,6 +2339,7 @@ class InferenceEngine:
                         dev["last_tokens"], dev["seq_lens"], dev["page_tables"],
                         dev["active"], dev["caps"], dev["seeds"],
                         dev["temperature"], dev["top_p"], dev["top_k"],
+                        self.state,
                         greedy=greedy, steps=steps,
                         eos_id=self.tokenizer.eos_id,
                         candidates=self.config.top_p_candidates, mesh=self.mesh,
@@ -2264,7 +2347,7 @@ class InferenceEngine:
                     # Donated slot state: rebind or the next warmup call
                     # would feed deleted buffers.
                     (_, dev["last_tokens"], dev["seq_lens"], dev["active"],
-                     self.paged) = outs
+                     self.paged, self.state) = outs
         self._jit_retire(
             dev["last_tokens"], dev["seq_lens"], dev["page_tables"],
             dev["active"], dev["caps"], np.int32(0),
@@ -3095,9 +3178,9 @@ class InferenceEngine:
         )
         live = tuple(int(i) for i in np.flatnonzero(act))
         with self._phase("decode", seq=self._dispatch_seq + 1, lanes=lanes,
-                         steps=steps):
+                         steps=steps, stateful=self.model_cfg.stateful):
             (packed_dev, last_dev, seq_dev, act_dev,
-             self.paged) = self._jit_decode(
+             self.paged, self.state) = self._jit_decode(
                 self.params,
                 self.model_cfg,
                 self.paged,
@@ -3110,6 +3193,7 @@ class InferenceEngine:
                 dev["temperature"],
                 dev["top_p"],
                 dev["top_k"],
+                self.state,
                 greedy=greedy,
                 steps=steps,
                 eos_id=self.tokenizer.eos_id,

@@ -179,13 +179,53 @@ class PagedKV:
         return self.ks is not None
 
 
+@struct.dataclass
+class SlotState:
+    """What a slot holds BESIDE its pages: the fixed-size recurrent state
+    of a stateful model (ModelConfig.stateful), indexed by slot. One entry
+    per Mamba-2 layer, in pattern order: `ssm` [slots, H, P, N] float32
+    (the recurrence is summed over thousands of steps) and `conv`
+    [slots, K−1, conv_dim] in the activation dtype (the conv's last K−1
+    input columns, stored as they were computed). A model without such
+    state holds two empty tuples — an empty pytree: nothing is allocated,
+    carried or donated for it.
+
+    It rides every dispatch that `PagedKV` rides, donated the same way,
+    so the donation chain orders its writers as it orders the pool's.
+    The rules its writers keep (models/hybrid.py; tests/test_hybrid.py):
+    a prompt's first window starts from zero state, whatever the slot's
+    last occupant left; a padded position never advances it, and what is
+    stored is the state after the last REAL token; an inactive decode
+    lane's state is not advanced; a prompt's next window in the same
+    dispatch starts where the row above ended; a long prompt's next chunk
+    starts from what the slot stores."""
+
+    ssm: tuple = ()
+    conv: tuple = ()
+
+    @property
+    def nbytes(self) -> int:
+        return sum(x.nbytes for x in jax.tree.leaves(self))
+
+
+def init_slot_state(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16) -> SlotState:
+    layers = cfg.layer_pattern.count("M")
+    ssm = (slots, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
+    conv = (slots, max(cfg.conv_kernel - 1, 0), cfg.conv_dim)
+    return SlotState(
+        ssm=tuple(jnp.zeros(ssm, jnp.float32) for _ in range(layers)),
+        conv=tuple(jnp.zeros(conv, dtype) for _ in range(layers)),
+    )
+
+
 def init_paged_kv(
     cfg: ModelConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16,
     kv_dtype=None,
 ) -> PagedKV:
     """`kv_dtype=jnp.int8` builds quantized pools (+ bf16 scale pools);
-    None keeps the full-precision layout in `dtype`."""
-    shape = (cfg.num_layers, num_pages, page_size,
+    None keeps the full-precision layout in `dtype`. One pool layer for
+    each layer that attends (a hybrid stack's "*" layers)."""
+    shape = (cfg.kv_layers, num_pages, page_size,
              cfg.num_kv_heads * cfg.head_dim)
     if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
         sshape = shape[:-1] + (cfg.num_kv_heads,)
@@ -217,7 +257,7 @@ def kv_pool_bytes(
         per_slot = cfg.num_kv_heads * (cfg.head_dim * 1 + 2)  # values + scale
     else:
         per_slot = cfg.num_kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize
-    return 2 * cfg.num_layers * num_pages * page_size * per_slot
+    return 2 * cfg.kv_layers * num_pages * page_size * per_slot
 
 
 def host_kv_page_bytes(

@@ -185,6 +185,11 @@ class EngineMetrics:
         # (engine.prefill_cover).
         self.prefill_windows_dispatched = 0
         self.prefill_prompts_split = 0
+        # Per-slot recurrent state of a stateful model (prefill rows by
+        # where their state started: kv_cache.SlotState's rules).
+        self.state_slots_reset = 0
+        self.state_windows_chained = 0
+        self.state_chunks_resumed = 0
         # Deepest in-flight target any dispatch ran with (on_dispatch).
         self.depth_target_max = 0
         # Lookahead pipeline accounting (ISSUE 6): per processed block,
@@ -317,6 +322,16 @@ class EngineMetrics:
             self.prefill_prompts_split += split
             self.tokens_dispatched_total += dispatched
             self.tokens_useful_total += useful
+
+    def on_state_rows(self, reset: int, chained: int, resumed: int) -> None:
+        """One prefill dispatch of a stateful model: real rows that
+        started from zero state (an admission: the slot is reset), from
+        the row above (a prompt's next window in the dispatch), from the
+        slot's stored state (a long prompt's next chunk)."""
+        with self._lock:
+            self.state_slots_reset += reset
+            self.state_windows_chained += chained
+            self.state_chunks_resumed += resumed
 
     def on_phase(self, name: str, seconds: float) -> None:
         """One engine phase ended (obs.timeline.phase). A name outside
@@ -616,6 +631,9 @@ class EngineMetrics:
                 "prefill_windows_dispatched":
                     self.prefill_windows_dispatched,
                 "prefill_prompts_split": self.prefill_prompts_split,
+                "state_slots_reset": self.state_slots_reset,
+                "state_windows_chained": self.state_windows_chained,
+                "state_chunks_resumed": self.state_chunks_resumed,
                 "blocks_processed": self.blocks_processed,
                 "lookahead_observed_max": self.lookahead_max,
                 "lookahead_observed_mean": (

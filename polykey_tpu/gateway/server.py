@@ -157,15 +157,29 @@ def normalize_address(addr: str) -> str:
     return addr
 
 
+def rpc_workers(service: Service) -> int:
+    """Threads of the RPC pool. A streaming RPC holds one for its whole
+    life, so the pool bounds the streams in flight: with 32 workers an
+    engine of 64 decode slots never saw more than 31 of them busy, its
+    queue empty, while the other clients waited for a thread (PERF.md §6,
+    PR 43). Twice the backend's slots — a queued request holds a thread
+    too — and never under the 32 every smaller engine has had."""
+    engine = getattr(service, "engine", None)
+    slots = getattr(getattr(engine, "config", None), "max_decode_slots", 0)
+    return max(32, 2 * int(slots or 0))
+
+
 def build_server(
     service: Service,
     logger: Optional[Logger] = None,
     address: str = ":50051",
-    max_workers: int = 32,
+    max_workers: Optional[int] = None,
     health: Optional[HealthService] = None,
     obs: Optional[Observability] = None,
 ):
     """Assemble the fully-wired gRPC server; returns (server, health, port).
+
+    `max_workers` None sizes the RPC pool from the backend (`rpc_workers`).
 
     An existing HealthService may be passed in so backends created before the
     server (the engine + its watchdog) can flip serving status. Passing an
@@ -177,7 +191,8 @@ def build_server(
     logger = logger or Logger()
     server = grpc.server(
         futures.ThreadPoolExecutor(
-            max_workers=max_workers, thread_name_prefix="polykey-rpc"
+            max_workers=max_workers or rpc_workers(service),
+            thread_name_prefix="polykey-rpc",
         ),
         interceptors=[LoggingInterceptor(logger, obs=obs)],
         options=_KEEPALIVE_OPTIONS,

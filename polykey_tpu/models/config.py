@@ -42,10 +42,75 @@ class ModelConfig:
     # num_experts/top_k x the dispatch FLOPs; off for tiny test configs,
     # where dispatch's token-drop-on-overflow would perturb exactness checks.
     moe_dispatch: bool = False
+    # Hybrid stacks (models/hybrid.py): one character a layer, and a
+    # layer is a mixer OR a feed-forward part alone, under one norm —
+    # "M" a Mamba-2 mixer, "*" attention, "E" a latent expert layer.
+    # Empty: the homogeneous attention+MLP block above, scanned.
+    layer_pattern: str = ""
+    use_rope: bool = True                     # False: no position embedding
+    # "M": H heads x P dims, state [H, P, N] per sequence, G groups share
+    # B and C, a causal depthwise conv of `conv_kernel` taps over x|B|C.
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_groups: int = 0
+    conv_kernel: int = 0
+    ssm_chunk: int = 128                      # prefill's chunked form
+    # "E": sigmoid router over `n_routed_experts`, top
+    # `num_experts_per_tok` of them, experts of `intermediate_size` in a
+    # latent of `moe_latent_size`, one shared expert on the full hidden.
+    # The chip holds experts [first_expert, first_expert + experts_held)
+    # and computes their part of the sum (ops/moe.py moe_latent_held).
+    n_routed_experts: int = 0
+    experts_held: int = 0
+    first_expert: int = 0
+    moe_latent_size: int = 0
+    moe_shared_intermediate: int = 0
+    routed_scaling_factor: float = 1.0
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def stateful(self) -> bool:
+        """Holds per-slot recurrent state beside the K/V pages."""
+        return "M" in self.layer_pattern
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that own a K/V pool."""
+        if self.layer_pattern:
+            return self.layer_pattern.count("*")
+        return self.num_layers
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the conv runs over: x | B | C."""
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state_size
+
+    def __post_init__(self):
+        if not self.layer_pattern:
+            return
+        if len(self.layer_pattern) != self.num_layers or \
+                set(self.layer_pattern) - set("ME*"):
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r} must be num_layers="
+                f"{self.num_layers} characters of 'M', 'E', '*'"
+            )
+        if "E" in self.layer_pattern and not (
+            0 < self.experts_held
+            and self.first_expert + self.experts_held <= self.n_routed_experts
+        ):
+            raise ValueError(
+                f"experts held [{self.first_expert}, "
+                f"{self.first_expert + self.experts_held}) must lie inside "
+                f"the router's {self.n_routed_experts}"
+            )
 
     @property
     def q_scale(self) -> float:
@@ -56,6 +121,21 @@ class ModelConfig:
     def num_params(self) -> int:
         """Approximate parameter count (embeddings + blocks + head)."""
         embed = self.vocab_size * self.hidden_size
+        if self.layer_pattern:
+            h = self.hidden_size
+            kinds = {
+                "M": h * (2 * self.mamba_inner
+                          + 2 * self.ssm_groups * self.ssm_state_size
+                          + self.mamba_num_heads)
+                + self.mamba_inner * h + self.conv_dim * self.conv_kernel,
+                "*": h * self.head_dim * 2 * (self.num_heads
+                                              + self.num_kv_heads),
+                "E": h * self.n_routed_experts + 2 * h * self.moe_latent_size
+                + 2 * h * self.moe_shared_intermediate
+                + self.experts_held * 2 * self.moe_latent_size
+                * self.intermediate_size,
+            }
+            return 2 * embed + sum(kinds[k] for k in self.layer_pattern)
         attn = self.hidden_size * self.head_dim * (
             self.num_heads * 2 + self.num_kv_heads * 2
         )
@@ -238,6 +318,35 @@ TINY_GEMMA = replace(
     scale_embeddings=True,
 )
 
+# A hybrid stack at toy size: every kind of layer, two windows of state
+# chunks per 16-token bucket, half the routed experts held.
+TINY_HYBRID = ModelConfig(
+    name="tiny-hybrid",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=32,
+    num_layers=5,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=16,
+    max_seq_len=512,
+    activation="relu2",
+    layer_pattern="MEM*E",
+    use_rope=False,
+    mamba_num_heads=8,
+    mamba_head_dim=16,
+    ssm_state_size=16,
+    ssm_groups=2,
+    conv_kernel=4,
+    ssm_chunk=8,
+    n_routed_experts=16,
+    experts_held=8,
+    num_experts_per_tok=4,
+    moe_latent_size=32,
+    moe_shared_intermediate=48,
+    routed_scaling_factor=2.5,
+)
+
 # A mid-size llama for single-chip benchmarking without 8B's 16 GiB of bf16
 # weights (v5e has 16 GiB HBM; 8B serves in int8 — see engine docs).
 LLAMA_1B_BENCH = replace(LLAMA32_1B, name="llama-1b-bench")
@@ -270,6 +379,7 @@ MODEL_REGISTRY = {
         TINY_LLAMA,
         TINY_MIXTRAL,
         TINY_GEMMA,
+        TINY_HYBRID,
         LLAMA_1B_BENCH,
         MIXTRAL_BENCH,
     )

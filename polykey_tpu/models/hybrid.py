@@ -1,0 +1,374 @@
+"""Hybrid stacks: a layer pattern that is data (ModelConfig.layer_pattern).
+
+Each layer is `x ← x + f(RMSNorm(x))` with ONE of three bodies, chosen by
+its character of the pattern:
+
+- "M", a Mamba-2 mixer: `[z | xBC | dt] = W_in u`; xBC through a causal
+  depthwise conv and silu, split into x [H, P], B and C [G, N];
+  Δ = softplus(dt + dt_bias), A = −exp(A_log); the recurrence
+  `h_t = exp(Δ_t A) h_{t−1} + Δ_t x_t ⊗ B_t`, `y_t = h_t C_t + D x_t`;
+  `W_out RMSNorm_grouped(y · silu(z))`. What a sequence carries between
+  dispatches is h [H, P, N] (float32) and the conv's last K−1 columns:
+  the per-slot state of engine/kv_cache.py `SlotState`.
+- "*", attention over the paged K/V pool: the projections, the paged
+  write and the kernels of models/transformer.py `forward_paged`, with no
+  position embedding where `cfg.use_rope` is off.
+- "E", a latent expert layer (ops/moe.py `moe_latent_held`).
+
+Parameters are grouped by kind, `params["layers"][kind]` a tuple with one
+tree per layer of that kind in pattern order, and the stack walks the
+pattern unrolled. (Not stacked on a leading axis: a static slice of a
+stacked leaf may be materialised, and an expert leaf here is 0.7 GB.)
+
+A decode step (T = 1) advances the recurrence one token for the active
+lanes (`ssm_state_update`, ops/hybrid_kernels.py). A prefill dispatch
+runs the chunked (SSD) form over chunks of `cfg.ssm_chunk`, whose
+inter-chunk pass also carries state from one ROW of the dispatch to the
+next when the rows are consecutive windows of one prompt (`PrefillRows`).
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops import hybrid_kernels
+from ..ops.moe import moe_latent_held
+from .config import ModelConfig
+from .layers import rms_norm, rope
+from .quant import embed_lookup, qdot
+
+KINDS = {"M": "mamba", "*": "attention", "E": "moe"}
+
+# Where a prefill row's state starts (PrefillRows.source).
+FROM_ZERO, FROM_SLOT, FROM_PREVIOUS_ROW = 0, 1, 2
+# The step Δ a seeded dt_bias stands for: the family's published
+# time_step_min / time_step_max. Read by init_layer and by nothing served.
+DT_INIT = (0.001, 0.1)
+
+
+class PrefillRows(NamedTuple):
+    """What each row [N] of a prefill dispatch does with the per-slot
+    state. `slot`: whose stored state a FROM_SLOT row starts from.
+    `source`: FROM_ZERO (an admission's first window), FROM_SLOT (a long
+    prompt's next chunk), FROM_PREVIOUS_ROW (the next window of the same
+    prompt in this dispatch: the row above ended where this one starts).
+    `store`: the slot that keeps this row's end state, or an index past
+    the last slot for a row whose end state nobody keeps (a padded row, a
+    window with a successor in the dispatch). `length`: the row's real
+    tokens; the positions after them never advance state."""
+
+    slot: jax.Array
+    source: jax.Array
+    store: jax.Array
+    length: jax.Array
+
+
+def layer_kinds(cfg: ModelConfig) -> list[tuple[str, int]]:
+    """(kind, index among the layers of its kind) for each layer."""
+    seen: dict = {}
+    out = []
+    for ch in cfg.layer_pattern:
+        kind = KINDS[ch]
+        out.append((kind, seen.get(kind, 0)))
+        seen[kind] = seen.get(kind, 0) + 1
+    return out
+
+
+# -- parameters ------------------------------------------------------------
+
+
+def _normal(key, shape, dtype, fan_in):
+    return jax.random.normal(key, shape, dtype) * fan_in**-0.5
+
+
+def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
+    h = cfg.hidden_size
+    gain = jnp.ones((h,), dtype)
+    k = jax.random.split(key, 8)
+    if kind == "mamba":
+        inner, heads = cfg.mamba_inner, cfg.mamba_num_heads
+        # Δ log-uniform in DT_INIT; dt_bias is its inverse softplus (the
+        # published init).
+        lo, hi = (jnp.log(v) for v in DT_INIT)
+        dt = jnp.exp(
+            jax.random.uniform(k[2], (heads,), jnp.float32) * (hi - lo) + lo)
+        return {
+            "norm": gain,
+            "w_in": _normal(k[0], (h, inner + cfg.conv_dim + heads), dtype, h),
+            "conv_w": _normal(k[1], (cfg.conv_kernel, cfg.conv_dim), dtype,
+                              cfg.conv_kernel),
+            "conv_b": jax.random.normal(k[4], (cfg.conv_dim,), dtype) * 0.1,
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(
+                jax.random.uniform(k[5], (heads,), jnp.float32, 1.0, 16.0)),
+            "D": jnp.ones((heads,), jnp.float32),
+            "gate_norm": jnp.ones((inner,), dtype),
+            "w_out": _normal(k[3], (inner, h), dtype, inner),
+        }
+    if kind == "attention":
+        q, kv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
+        return {
+            "norm": gain,
+            "wq": _normal(k[0], (h, q), dtype, h),
+            "wk": _normal(k[1], (h, kv), dtype, h),
+            "wv": _normal(k[2], (h, kv), dtype, h),
+            "wo": _normal(k[3], (q, h), dtype, q),
+        }
+    latent, inner = cfg.moe_latent_size, cfg.intermediate_size
+    shared, held = cfg.moe_shared_intermediate, cfg.experts_held
+    return {
+        "norm": gain,
+        "router": _normal(k[0], (h, cfg.n_routed_experts), dtype, h),
+        "router_bias": jax.random.normal(
+            k[7], (cfg.n_routed_experts,), jnp.float32) * 0.02,
+        "fc1": _normal(k[1], (h, latent), dtype, h),
+        "fc2": _normal(k[2], (latent, h), dtype, latent),
+        "up": _normal(k[3], (held, latent, inner), dtype, latent),
+        "down": _normal(k[4], (held, inner, latent), dtype, inner),
+        "shared_up": _normal(k[5], (h, shared), dtype, h),
+        "shared_down": _normal(k[6], (shared, h), dtype, shared),
+    }
+
+
+def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
+    """Seeded random parameters, grouped by kind (module text). One layer
+    a jitted call: a leaf is born in `dtype` where it will live, and the
+    temporaries are one layer's (at the published widths the bf16 tree is
+    9.3 GB of a 16 GB chip, and an expert leaf alone 0.7 GB)."""
+    from .transformer import init_top_params
+
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
+    keys = jax.random.split(k_layers, cfg.num_layers)
+    make = jax.jit(init_layer, static_argnums=(1, 2, 3))
+    groups: dict = {kind: [] for kind in KINDS.values()}
+    for layer_key, (kind, _) in zip(keys, layer_kinds(cfg)):
+        groups[kind].append(make(layer_key, kind, cfg, dtype))
+    top = jax.jit(init_top_params, static_argnums=(2, 3))(
+        k_embed, k_head, cfg, dtype)
+    return {**top,
+            "layers": {kind: tuple(trees) for kind, trees in groups.items()}}
+
+
+# -- the Mamba-2 mixer -----------------------------------------------------
+
+
+def _split_in(p: dict, u: jax.Array, cfg: ModelConfig):
+    inner = cfg.mamba_inner
+    zxbcdt = qdot(u, p["w_in"])
+    return (zxbcdt[..., :inner], zxbcdt[..., inner:inner + cfg.conv_dim],
+            zxbcdt[..., inner + cfg.conv_dim:])
+
+
+def _split_xbc(xbc: jax.Array, cfg: ModelConfig):
+    inner, gn = cfg.mamba_inner, cfg.ssm_groups * cfg.ssm_state_size
+    lead = xbc.shape[:-1]
+    x = xbc[..., :inner].reshape(*lead, cfg.mamba_num_heads, cfg.mamba_head_dim)
+    Bm = xbc[..., inner:inner + gn].reshape(
+        *lead, cfg.ssm_groups, cfg.ssm_state_size)
+    Cm = xbc[..., inner + gn:].reshape(
+        *lead, cfg.ssm_groups, cfg.ssm_state_size)
+    return x, Bm, Cm
+
+
+def _conv(ext: jax.Array, p: dict, T: int) -> jax.Array:
+    """silu(causal depthwise conv + bias) over `ext` [.., K−1+T, C], whose
+    first K−1 columns are what came before."""
+    w = p["conv_w"].astype(jnp.float32)
+    taps = w.shape[0]
+    out = sum(
+        ext[..., k:k + T, :].astype(jnp.float32) * w[k] for k in range(taps)
+    ) + p["conv_b"].astype(jnp.float32)
+    return jax.nn.silu(out).astype(ext.dtype)
+
+
+def _gated_out(p: dict, y, z, cfg: ModelConfig):
+    """W_out · RMSNorm over each of the G groups of (y · silu(z))."""
+    lead = y.shape[:-2]
+    g = y.reshape(*lead, cfg.mamba_inner).astype(jnp.float32) \
+        * jax.nn.silu(z.astype(jnp.float32))
+    grouped = g.reshape(*lead, cfg.ssm_groups, -1)
+    var = jnp.mean(jnp.square(grouped), axis=-1, keepdims=True)
+    normed = (grouped * jax.lax.rsqrt(var + cfg.rms_norm_eps)).reshape(g.shape)
+    normed = normed * p["gate_norm"].astype(jnp.float32)
+    return qdot(normed.astype(z.dtype), p["w_out"])
+
+
+def mamba_decode(p: dict, u, cfg: ModelConfig, ssm, conv, active):
+    """One token for every lane: u [B, hidden], ssm [B, H, P, N] f32,
+    conv [B, K−1, C]. A lane that is not `active` keeps both unchanged
+    (Δ = 0 leaves h as it is, bit for bit; the conv window does not
+    shift). Returns (out [B, hidden], ssm, conv)."""
+    z, xbc, dt = _split_in(p, u, cfg)
+    ext = jnp.concatenate([conv, xbc[:, None, :]], axis=1)      # [B, K, C]
+    x, Bm, Cm = _split_xbc(_conv(ext, p, 1)[:, 0], cfg)
+    conv = jnp.where(active[:, None, None], ext[:, 1:], conv)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    dt = jnp.where(active[:, None], dt, 0.0)                    # [B, H]
+    dA = jnp.exp(dt * -jnp.exp(p["A_log"]))
+    xf = x.astype(jnp.float32)
+    update = (hybrid_kernels.ssm_state_update if hybrid_kernels.use_kernels()
+              else hybrid_kernels.ssm_state_update_jnp)
+    ssm, y = update(ssm, dA, xf * dt[..., None], Bm.astype(jnp.float32),
+                    Cm.astype(jnp.float32))
+    y = y + p["D"][:, None] * xf
+    return _gated_out(p, y, z, cfg), ssm, conv
+
+
+def ssd_chunks(x, dt, A, Bm, Cm, h_first, kind, chunk: int):
+    """The chunked (SSD) form of the recurrence over N rows of T tokens,
+    equal to it token by token. x [N, T, H, P], dt [N, T, H] (0 where a
+    position must not advance state), A [H], Bm / Cm [N, T, G, S], all
+    float32; h_first [N, H, P, S] the state each row starts from when its
+    `kind` [N] is FROM_SLOT (FROM_ZERO: zeros; FROM_PREVIOUS_ROW: where
+    the row above ended). Returns (y [N, T, H, P] without the D term, the
+    state at each row's end [N, H, P, S])."""
+    N, T, H, P = x.shape
+    G, S = Bm.shape[2:]
+    Q = min(chunk, T)
+    if T % Q:
+        raise ValueError(f"a prefill window of {T} is not whole chunks of {Q}")
+    nc = T // Q
+    C = N * nc
+    Hg = H // G
+    # Per-head decays with the chunk's positions LAST ([C, H, Q], and
+    # [C, H, Q, Q] for the pairs): the large temporaries then lie with
+    # 128 positions on the lanes, not 16 heads.
+    a = (dt * A).reshape(C, Q, H).transpose(0, 2, 1)
+    xdt = (x * dt[..., None]).reshape(C, Q, G, Hg, P)
+    Bc, Cc = Bm.reshape(C, Q, G, S), Cm.reshape(C, Q, G, S)
+    cs = jnp.cumsum(a, axis=-1)                                 # [C, H, Q]
+    total = cs[..., -1]                                         # [C, H]
+
+    def per_token(f):           # [C, H, Q] → [C, Q, G, Hg, 1]
+        return f.transpose(0, 2, 1).reshape(C, Q, G, Hg, 1)
+
+    # Within a chunk: y_t = Σ_{s≤t} (C_t·B_s) exp(cs_t − cs_s) Δ_s x_s.
+    diff = cs[..., :, None] - cs[..., None, :]                  # [C, H, t, s]
+    decay = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)), diff,
+                              -jnp.inf))
+    cb = jnp.einsum("ctgn,csgn->cgts", Cc, Bc)
+    scores = cb[:, :, None] * decay.reshape(C, G, Hg, Q, Q)
+    y = jnp.einsum("cghts,csghp->ctghp", scores, xdt)
+
+    # What a chunk adds to the state, and what it leaves of the old one.
+    to_end = per_token(jnp.exp(total[..., None] - cs))
+    added = jnp.einsum("cqghp,cqgn->cghpn", xdt * to_end, Bc)
+    keep = jnp.exp(total).reshape(C, G, Hg)
+
+    # Between chunks, in dispatch order: a row's first chunk starts where
+    # `kind` says, every other chunk where the one before it ended.
+    first = jnp.arange(C) % nc == 0
+    chunk_kind = jnp.where(first, jnp.repeat(kind, nc), FROM_PREVIOUS_ROW)
+    h_first = h_first.reshape(N, G, Hg, P, S)
+
+    def step(prev_end, inputs):
+        ck, row, add, kp = inputs
+        start = jnp.where(
+            ck == FROM_PREVIOUS_ROW, prev_end,
+            jnp.where(ck == FROM_SLOT, h_first[row], 0.0),
+        )
+        end = kp[..., None, None] * start + add
+        return end, (start, end)
+
+    _, (starts, ends) = jax.lax.scan(
+        step, jnp.zeros_like(added[0]),
+        (chunk_kind, jnp.arange(C) // nc, added, keep),
+    )
+    y = y + jnp.einsum("cqgn,cghpn->cqghp", Cc, starts) \
+        * per_token(jnp.exp(cs))
+    return (y.reshape(N, T, H, P),
+            ends[nc - 1::nc].reshape(N, H, P, S))
+
+
+def mamba_prefill(p: dict, u, cfg: ModelConfig, ssm, conv,
+                  rows: PrefillRows):
+    """N windows of T tokens: u [N, T, hidden]; ssm / conv the stored
+    state of the WHOLE slot batch. Returns (out, ssm, conv) with the end
+    state of every row that `rows.store` keeps written to its slot."""
+    N, T, _ = u.shape
+    taps = cfg.conv_kernel
+    z, xbc, dt = _split_in(p, u, cfg)
+    source = rows.source
+    # The K−1 columns before each row: nothing, the slot's, or the tail of
+    # the row above (a full window: every one of its columns is real).
+    above = jnp.roll(xbc[:, T - (taps - 1):], 1, axis=0)
+    before = jnp.where(
+        (source == FROM_PREVIOUS_ROW)[:, None, None], above,
+        jnp.where((source == FROM_SLOT)[:, None, None], conv[rows.slot], 0),
+    ).astype(xbc.dtype)
+    ext = jnp.concatenate([before, xbc], axis=1)                # [N, K−1+T, C]
+    x, Bm, Cm = _split_xbc(_conv(ext, p, T), cfg)
+    # The conv state after the last REAL token: columns length .. length+K−2
+    # of ext (a shorter row reaches back into `before`).
+    conv_end = jax.vmap(
+        lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, taps - 1, axis=0)
+    )(ext, rows.length)
+    real = jnp.arange(T)[None, :] < rows.length[:, None]
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    dt = jnp.where(real[..., None], dt, 0.0)
+    xf = x.astype(jnp.float32)
+    y, ssm_end = ssd_chunks(
+        xf, dt, -jnp.exp(p["A_log"]), Bm.astype(jnp.float32),
+        Cm.astype(jnp.float32), ssm[rows.slot], source, cfg.ssm_chunk,
+    )
+    y = y + p["D"][:, None] * xf
+    ssm = ssm.at[rows.store].set(ssm_end, mode="drop")
+    conv = conv.at[rows.store].set(conv_end.astype(conv.dtype), mode="drop")
+    return _gated_out(p, y, z, cfg), ssm, conv
+
+
+# -- the stack -------------------------------------------------------------
+
+
+def attention_layer(p: dict, h, positions, cfg: ModelConfig, attend, idx,
+                    kc, vc):
+    B, T, _ = h.shape
+    q = qdot(h, p["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
+    k = qdot(h, p["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    v = qdot(h, p["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+    ctx, kc, vc = attend(jnp.int32(idx), q, k, v, kc, vc)
+    out = qdot(ctx.reshape(B, T, cfg.num_heads * cfg.head_dim), p["wo"])
+    return out, kc, vc
+
+
+def run_stack(params, cfg: ModelConfig, tokens, positions, pools, attend,
+              state=None, rows=None, active=None):
+    """embed → the pattern's layers, unrolled → final norm. `pools` is the
+    (kc, vc) pair `attend` threads (models/transformer.py); `state` the
+    per-slot SlotState of a stateful pattern; a prefill dispatch says
+    what each row does with it (`rows`, a PrefillRows), a decode step
+    (T = 1, one row a lane; `rows` None) which lanes are live (`active`).
+    Returns (hidden, pools, state)."""
+    decode = rows is None
+    eps = cfg.rms_norm_eps
+    x = embed_lookup(params["embed"], tokens)
+    kc, vc = pools
+    ssm = list(state.ssm) if state is not None else []
+    conv = list(state.conv) if state is not None else []
+    for kind, idx in layer_kinds(cfg):
+        p = params["layers"][kind][idx]
+        h = rms_norm(x, p["norm"], eps)
+        if kind == "mamba":
+            if decode:
+                out, ssm[idx], conv[idx] = mamba_decode(
+                    p, h[:, 0], cfg, ssm[idx], conv[idx], active)
+                out = out[:, None]
+            else:
+                out, ssm[idx], conv[idx] = mamba_prefill(
+                    p, h, cfg, ssm[idx], conv[idx], rows)
+        elif kind == "attention":
+            out, kc, vc = attention_layer(
+                p, h, positions, cfg, attend, idx, kc, vc)
+        else:
+            out = moe_latent_held(p, h, cfg)
+        x = x + out
+    x = rms_norm(x, params["final_norm"], eps)
+    if state is not None:
+        state = state.replace(ssm=tuple(ssm), conv=tuple(conv))
+    return x, (kc, vc), state

@@ -54,6 +54,8 @@ def _activate(x: jax.Array, activation: str) -> jax.Array:
         return jax.nn.silu(x)
     if activation == "gelu_tanh":
         return jax.nn.gelu(x, approximate=True)
+    if activation == "relu2":
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(f"unknown activation {activation!r}")
 
 

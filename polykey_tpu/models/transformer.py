@@ -127,6 +127,10 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> dict:
     parallel/sharding.init_sharded_params instead, which draws the same
     values layer by layer straight into their final dtype and sharding.
     """
+    if cfg.layer_pattern:
+        from .hybrid import init_params as init_hybrid_params
+
+        return init_hybrid_params(key, cfg, dtype)
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     layers = jax.vmap(lambda k: init_layer_params(k, cfg, dtype))(
         jax.random.split(k_layers, cfg.num_layers)
@@ -374,6 +378,36 @@ def forward_paged(
     run under shard_map when tp/dp/sp extents exceed 1 — GSPMD cannot
     partition an opaque pallas_call; the jnp paths need no help.
     """
+    if cfg.stateful:
+        raise ValueError(
+            f"{cfg.name} carries per-slot recurrent state: forward_slots"
+        )
+    hidden, paged, _ = forward_slots(
+        params, cfg, tokens, positions, paged, page_tables, None, mesh=mesh,
+    )
+    return hidden, paged
+
+
+def forward_slots(
+    params: dict,
+    cfg: ModelConfig,
+    tokens: jax.Array,               # [B, T] int32, right-padded
+    positions: jax.Array,            # [B, T] absolute positions
+    paged,                           # engine.kv_cache.PagedKV
+    page_tables: jax.Array,          # [B, P] int32
+    state,                           # engine.kv_cache.SlotState (or None)
+    rows=None,                       # prefill: hybrid.PrefillRows
+    active=None,                     # decode: [B] bool, the live lanes
+    mesh=None,
+):
+    """`forward_paged` over everything a slot holds: the paged pools and,
+    for a stateful model, the per-slot recurrent state beside them, which
+    a prefill's `rows` or a decode step's `active` lanes say how to use
+    (models/hybrid.py `run_stack`). Returns
+    (hidden, paged, state); a model without state hands `state` back as it
+    came. The homogeneous families keep `_run_paged_stack`'s scan; a
+    layer pattern walks its layers unrolled, its "*" layers on the same
+    write and attention kernels over their own pool layers."""
     from ..ops.paged_attention import paged_attention, paged_write
     from ..ops.paged_attention_kernel import paged_attention_decode
 
@@ -395,7 +429,24 @@ def forward_paged(
         )
         return ctx, kc, vc
 
-    return _run_paged_stack(params, cfg, tokens, positions, paged, attend)
+    if not cfg.layer_pattern:
+        hidden, paged = _run_paged_stack(
+            params, cfg, tokens, positions, paged, attend
+        )
+        return hidden, paged, state
+    if paged.quantized:
+        raise ValueError("a layer pattern has no int8-KV path")
+    from .hybrid import run_stack
+
+    pools = (paged.k.reshape(-1, *paged.k.shape[2:]),
+             paged.v.reshape(-1, *paged.v.shape[2:]))
+    hidden, (kc, vc), state = run_stack(
+        params, cfg, tokens, positions, pools, attend, state, rows, active
+    )
+    paged = paged.replace(
+        k=kc.reshape(paged.k.shape), v=vc.reshape(paged.v.shape)
+    )
+    return hidden, paged, state
 
 
 def make_sp_override(

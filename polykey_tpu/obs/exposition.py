@@ -78,6 +78,9 @@ _ENGINE_FAMILIES: tuple = (
      "Free KV pages in the block allocator.", "pages_free"),
     ("gauge", "polykey_pages_total",
      "Total KV pages in the pool.", "pages_total"),
+    ("gauge", "polykey_state_pool_bytes",
+     "Bytes of per-slot recurrent state beside the KV pool (0: the model "
+     "has none).", "state_pool_bytes"),
     ("gauge", "polykey_tokens_per_sec",
      "Decode throughput over the last ~1s window.", "tokens_per_sec"),
     # Occupancy tracker (ISSUE 4): measured live-lane accounting — the
@@ -192,6 +195,15 @@ _ENGINE_FAMILIES: tuple = (
     ("counter", "polykey_prefill_prompts_split_total",
      "Prompts covered by more than one window of a dispatch.",
      "prefill_prompts_split"),
+    ("counter", "polykey_state_slots_reset_total",
+     "Prefill windows of a stateful model that started from zero state "
+     "(admissions).", "state_slots_reset"),
+    ("counter", "polykey_state_windows_chained_total",
+     "Prefill windows that started from the end state of the row above "
+     "in the same dispatch.", "state_windows_chained"),
+    ("counter", "polykey_state_chunks_resumed_total",
+     "Prefill windows that started from the slot's stored state (a long "
+     "prompt's next chunk).", "state_chunks_resumed"),
 )
 
 _SPEC_FAMILIES: tuple = (

@@ -10,6 +10,10 @@ Two formulations:
   compute" layout. Cost: num_experts/top_k × the FLOPs of sparse dispatch
   (4× for Mixtral 8×7B's 8-choose-2) — acceptable for correctness paths and
   small batches.
+- `moe_latent_held` — one chip's share of a latent expert layer: a sigmoid
+  router over every published expert, the held experts' part of the sum
+  (ops/hybrid_kernels.py `moe_held_experts`) plus the shared expert. No
+  capacity, no drop.
 - `moe_mlp_dispatch` — capacity-bucketed sparse dispatch: tokens gather into
   per-expert buckets (static capacity, dropped on overflow like GShard/
   Switch), experts run batched matmuls on their buckets only, results
@@ -27,7 +31,8 @@ import jax.numpy as jnp
 
 from ..models.config import ModelConfig
 from ..models.layers import _activate
-from ..models.quant import qeinsum_expert
+from ..models.quant import qdot, qeinsum_expert
+from . import hybrid_kernels
 
 
 def _router_weights(
@@ -122,3 +127,46 @@ def moe_mlp_dispatch(
         gathered.astype(jnp.float32) * weight[..., None], axis=1
     )                                                               # [N,H]
     return mixed.reshape(B, T, H).astype(h.dtype)
+
+
+def latent_router_weights(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Combine weights [.., n_routed_experts] (float32, 0 off the chosen):
+    sigmoid scores; the top `num_experts_per_tok` of score + correction
+    bias are chosen; each takes its own score over the sum of ALL the
+    chosen scores — held here or not — times `routed_scaling_factor`."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "...h,he->...e", h, p["router"], preferred_element_type=jnp.float32,
+    ))
+    _, idx = jax.lax.top_k(scores + p["router_bias"], cfg.num_experts_per_tok)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    chosen = chosen * (
+        cfg.routed_scaling_factor / jnp.sum(chosen, axis=-1, keepdims=True)
+    )
+    onehot = jax.nn.one_hot(idx, cfg.n_routed_experts, dtype=jnp.float32)
+    return jnp.sum(onehot * chosen[..., None], axis=-2)
+
+
+def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A latent expert layer as ONE chip of its expert-parallel group
+    computes it: [B, T, H] → [B, T, H]. The router keeps its published
+    width and top-k; the routed sum runs over the chosen experts that are
+    HELD, `[first_expert, first_expert + experts_held)`, inside the latent
+    (`fc1` down to it, `fc2` back); the shared expert runs on the full
+    hidden. What the absent experts would add is left out, here and in
+    the reference alike, and no token is dropped at any width: every held
+    expert's weights are read once and the combine weights mask
+    (hybrid_kernels.moe_held_experts), so the work is rows x experts held
+    whatever the routing chose. The experts are not gated: relu(up)² only."""
+    if cfg.activation != "relu2":
+        raise ValueError("the held-experts product computes relu(up)² only")
+    B, T, H = h.shape
+    tokens = h.reshape(B * T, H)
+    weights = latent_router_weights(p, tokens, cfg)[
+        :, cfg.first_expert:cfg.first_expert + cfg.experts_held
+    ]
+    held = (hybrid_kernels.moe_held_experts if hybrid_kernels.use_kernels()
+            else hybrid_kernels.moe_held_experts_jnp)
+    routed = held(qdot(tokens, p["fc1"]), p["up"], p["down"], weights)
+    out = qdot(routed.astype(h.dtype), p["fc2"])
+    shared = _activate(qdot(tokens, p["shared_up"]), cfg.activation)
+    return (out + qdot(shared, p["shared_down"])).reshape(B, T, H)

@@ -111,6 +111,10 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh, params_tree=None):
         params_tree = jax.eval_shape(
             lambda: init_params(jax.random.PRNGKey(0), cfg)
         )
+    if cfg.layer_pattern:
+        # A layer pattern runs on one device (engine/config.py refuses a
+        # mesh for it): its kind-grouped tree is placed whole.
+        return jax.tree.map(lambda _: NamedSharding(mesh, P()), params_tree)
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: NamedSharding(
             mesh, _spec_for_path(_path_keys(path), leaf, mesh)
@@ -147,6 +151,14 @@ def init_sharded_params(
     """
     from ..models.quant import quantize_embed, quantize_linears
     from ..models.transformer import init_layer_params, init_top_params
+
+    if cfg.layer_pattern:
+        from ..models.hybrid import init_params as init_hybrid_params
+
+        if quantize_bits:
+            raise ValueError("a layer pattern has no quantized weights yet")
+        with jax.default_device(mesh.devices.flat[0]):
+            return shard_params(init_hybrid_params(key, cfg, dtype), cfg, mesh)
 
     def one_layer(k):
         layer = init_layer_params(k, cfg, dtype)

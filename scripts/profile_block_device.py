@@ -99,7 +99,7 @@ def main():
             packed = None
             t0 = time.monotonic()
             for _ in range(M):
-                packed, last, seq, act, pool = fn(
+                packed, last, seq, act, pool, _ = fn(
                     params, cfg, pool, last, seq, page_tables, act,
                     st["caps"], st["seeds"], st["temperature"],
                     st["top_p"], st["top_k"],

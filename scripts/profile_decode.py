@@ -167,12 +167,12 @@ def main():
     outs = run_block(paged)
     jax.block_until_ready(outs)
     log(f"block compile+1st: {time.monotonic() - t0:.1f}s")
-    paged = outs[-1]
+    paged = outs[-2]
     t0 = time.monotonic()
     n = 5
     for _ in range(n):
         outs = run_block(paged)
-        paged = outs[-1]
+        paged = outs[-2]
         jax.block_until_ready(outs[0])
     ms = (time.monotonic() - t0) / n * 1000
     log(f"decode block (K={K}): {ms:.2f} ms -> {ms / K:.2f} ms/step, "

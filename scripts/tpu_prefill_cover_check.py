@@ -176,7 +176,7 @@ def main(argv=None) -> int:
             [tables, np.zeros((pad, config.pages_per_seq), np.int32)]
         )
         n = len(start)
-        out, engine.paged = engine._jit_prefill(
+        out, engine.paged, _ = engine._jit_prefill(
             engine.params, engine.model_cfg, engine.paged,
             *placed(tokens, start, last_rel, tables),
             put(np.zeros((n, 2), np.int32)),

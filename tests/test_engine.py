@@ -1176,8 +1176,8 @@ def test_two_rows_on_one_table_equal_one_wide_row(small, n, start):
         a[:, -1, tail:] = b[:, -1, tail:] = 0   # padding rows differ
         assert np.abs(a).max() > 0
         np.testing.assert_allclose(a, b, rtol=0, atol=2e-5)
-    token_one, paged = sampled(paged, *rows(2 * small, one_first))
-    token_two, paged = sampled(paged, *rows(small, two_first))
+    token_one, paged, _ = sampled(paged, *rows(2 * small, one_first))
+    token_two, paged, _ = sampled(paged, *rows(small, two_first))
     assert int(token_one[-1]) == int(token_two[-1]) == int(jax.numpy.argmax(one[-1]))
 
 

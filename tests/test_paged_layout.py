@@ -245,7 +245,7 @@ def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp, monkeypatch):
                              "mesh"),
             donate_argnames=("paged",),
             out_shardings=(repl, repl, repl, repl,
-                           jax.tree.map(lambda s: pool_sh, pool)),
+                           jax.tree.map(lambda s: pool_sh, pool), repl),
         ).lower(
             params, cfg, paged, arg((B,), jnp.int32), arg((B,), jnp.int32),
             arg((B, tables), jnp.int32), arg((B,), jnp.bool_),
@@ -272,6 +272,89 @@ def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp, monkeypatch):
              if c.lstrip().startswith("%paged_attention_decode")]
     assert len(writes) == len(reads) == 1, calls
     assert writes[0].count(stack) == 2
+
+
+def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch):
+    """The decode step of a hybrid stack (models/hybrid.py) compiled for a
+    v5e at the published widths of its two new kernels — a state of
+    [slots, 128, 64, 128] float32 a mixer layer, experts of 1024 x 2688 —
+    at toy depth and with 8 experts held: the per-slot state and the pool
+    are aliased input to output (donated, updated in place), no
+    instruction copies a state-sized buffer, and both kernels are there
+    under the names the benchmark's readers hold fixed."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    from polykey_tpu.engine.kv_cache import init_slot_state
+    from polykey_tpu.models.config import get_config
+    from polykey_tpu.ops import hybrid_kernels, paged_attention_kernel
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    monkeypatch.setattr(
+        paged_attention_kernel, "use_paged_kernel", lambda Hk, D: True
+    )
+    monkeypatch.setattr(hybrid_kernels, "use_kernels", lambda: True)
+    cfg = replace(
+        get_config("tiny-hybrid"), name="hybrid-probe", hidden_size=512,
+        layer_pattern="M*E", num_layers=3, num_heads=4, num_kv_heads=2,
+        head_dim=128, mamba_num_heads=128, mamba_head_dim=64,
+        ssm_state_size=128, ssm_groups=8, ssm_chunk=128,
+        intermediate_size=2688, moe_latent_size=1024,
+        moe_shared_intermediate=256, n_routed_experts=32, experts_held=8,
+        num_experts_per_tok=6,
+    )
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def shaped(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+            tree,
+        )
+
+    B, pages, ps, tables = 64, 1024, 16, 8
+    params = shaped(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
+    paged = shaped(jax.eval_shape(
+        lambda: init_paged_kv(cfg, pages, ps, jnp.bfloat16)))
+    state = shaped(jax.eval_shape(
+        lambda: init_slot_state(cfg, B, jnp.bfloat16)))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    try:
+        compiled = jax.jit(
+            engine_mod._decode_fn,
+            static_argnames=("cfg", "greedy", "steps", "eos_id", "candidates",
+                             "mesh"),
+            donate_argnames=("paged", "last_tokens", "seq_lens", "active",
+                             "state"),
+        ).lower(
+            params, cfg, paged, arg((B,), jnp.int32), arg((B,), jnp.int32),
+            arg((B, tables), jnp.int32), arg((B,), jnp.bool_),
+            arg((B,), jnp.int32), arg((B, 2), jnp.int32),
+            arg((B,), jnp.float32), arg((B,), jnp.float32),
+            arg((B,), jnp.int32), state,
+            greedy=True, steps=2, eos_id=-1, candidates=0, mesh=None,
+        ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    hlo = compiled.as_text()
+    ssm = "f32[64,128,64,128]"
+    assert aliased_pool_parameters(hlo, ssm) == 1
+    assert [line for line in hlo.splitlines()
+            if ssm in line and " copy(" in line] == []
+    stats = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((paged, state)))
+    assert stats.alias_size_in_bytes >= held
+    calls = [line.split(" custom-call(")[0].lstrip() for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("%ssm_state_update", "%moe_held_experts", "%paged_kv_write",
+                 "%paged_attention_decode"):
+        assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
 
 
 # -- parity ---------------------------------------------------------------------
