@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from ..ops import hybrid_kernels
 from ..ops.moe import moe_latent_held
 from .config import ModelConfig
-from .layers import rms_norm, rope
+from .layers import qkv_project, rms_norm, rope
 from .quant import embed_lookup, qdot
 
 KINDS = {"M": "mamba", "*": "attention", "E": "moe"}
@@ -326,9 +326,7 @@ def mamba_prefill(p: dict, u, cfg: ModelConfig, ssm, conv,
 def attention_layer(p: dict, h, positions, cfg: ModelConfig, attend, idx,
                     kc, vc):
     B, T, _ = h.shape
-    q = qdot(h, p["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = qdot(h, p["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = qdot(h, p["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
+    q, k, v = qkv_project(p, h, cfg)
     if cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)

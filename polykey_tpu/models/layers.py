@@ -68,11 +68,38 @@ def mlp(p: dict, x: jax.Array, activation: str) -> jax.Array:
 def qkv_project(
     p: dict, x: jax.Array, cfg: ModelConfig
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """q, k, v of one attention layer, split into heads: [B, T, heads, D].
+
+    Each product is held FLAT ([B, T, heads · D]) behind an optimization
+    barrier of its own until it is done, and only then split. Without it,
+    wherever a dimension of 1 stands beside the rows — [B, 1, H] in the
+    decode step, [1, T, H] in a one-row prefill — the TPU compiler folds
+    the head split of q and k (whose consumer is `rope`) INTO the product:
+    a convolution with a window over heads (`dim_labels=bf0_0oi->b0f`) on
+    the weight viewed [heads, D, H]. That form wants the weight stack
+    relaid `{1,2,0}` on every dispatch (640 MB of `wq` + `wk` a decode
+    block at mistral-7b's widths), stages each layer's whole `wq` / `wk`
+    in VMEM through a fusion of its own and runs the product from there
+    with nothing to overlap. Flat, q and k are what `wv`, `wo` and the FFN
+    are: one fusion whose matmul streams the layer's weight from HBM in
+    the layout it is stored in. One barrier a product, not one around the
+    three: a joint barrier keeps all three results live together, and the
+    multi-row prefill modules — which never folded — then come out of the
+    compiler's memory assignment with a third more operations a layer.
+    The arithmetic is `qdot`'s, bit for bit; tests/test_paged_layout.py
+    holds the compiled decode and prefill steps to this
+    (scripts/decode_step_census.py prints them)."""
     B, T, _ = x.shape
-    q = qdot(x, p["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim)
-    k = qdot(x, p["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    v = qdot(x, p["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
-    return q, k, v
+
+    def heads(name: str, n: int) -> jax.Array:
+        flat = jax.lax.optimization_barrier(qdot(x, p[name]))
+        return flat.reshape(B, T, n, cfg.head_dim)
+
+    return (
+        heads("wq", cfg.num_heads),
+        heads("wk", cfg.num_kv_heads),
+        heads("wv", cfg.num_kv_heads),
+    )
 
 
 def init_attention_params(

@@ -14,12 +14,16 @@ import pytest
 from polykey_tpu.engine.sampling import SamplingParams, sample
 from polykey_tpu.models.config import TINY_GEMMA, TINY_LLAMA
 from polykey_tpu.models.generate import decode_step, generate, prefill
+from polykey_tpu.models.layers import qkv_project
+from polykey_tpu.models.quant import qdot, quantize_params
 from polykey_tpu.models.transformer import (
     forward,
     init_cache,
     init_params,
     unembed,
 )
+from polykey_tpu.parallel.mesh import MeshConfig, create_mesh
+from polykey_tpu.parallel.sharding import shard_params
 
 
 @pytest.fixture(scope="module")
@@ -210,3 +214,48 @@ def test_mixtral_bench_fits_one_chip():
     # int8 weights well under half the chip: leaves room for 16 slots of
     # KV pages, activations, and the compiler's scratch.
     assert total < 6 * 2**30, f"mixtral-bench int8 tree is {total/2**30:.1f} GiB"
+
+
+def folded_qkv(p, x, cfg):
+    """`qkv_project` as it stood before ISSUE 44: each product reshaped to
+    heads at once (on a TPU the compiler folds that reshape into the q and
+    k products wherever a dimension of 1 stands beside the rows)."""
+    B, T, _ = x.shape
+    return (
+        qdot(x, p["wq"]).reshape(B, T, cfg.num_heads, cfg.head_dim),
+        qdot(x, p["wk"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim),
+        qdot(x, p["wv"]).reshape(B, T, cfg.num_kv_heads, cfg.head_dim),
+    )
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("rows,width", [(3, 1), (1, 128)])
+@pytest.mark.parametrize("leaves", ["bf16", "int8", "int4"])
+def test_qkv_project_is_the_same_product_bit_for_bit(leaves, rows, width, tp):
+    """Holding q, k, v flat behind a barrier until the products are done
+    changes where the head split happens, not one bit of what it splits:
+    decode ([B, 1, H]) and one-row prefill ([1, T, H]) shapes, every kind
+    of weight leaf, one device and a tp = 2 mesh."""
+    cfg = TINY_LLAMA
+    params = init_params(jax.random.PRNGKey(3), cfg, jnp.bfloat16)
+    if leaves != "bf16":
+        params = quantize_params(params, cfg, bits=int(leaves[3:]))
+    if tp > 1:
+        mesh = create_mesh(MeshConfig(tp=tp), devices=jax.devices()[:tp])
+        params = shard_params(params, cfg, mesh)
+    x = jax.random.normal(
+        jax.random.PRNGKey(4), (rows, width, cfg.hidden_size), jnp.bfloat16)
+
+    def run(project):
+        return jax.jit(lambda params, x: project(
+            jax.tree.map(lambda leaf: leaf[1], params["layers"]["attn"]),
+            x, cfg,
+        ))(params, x)
+
+    got, want = run(qkv_project), run(folded_qkv)
+    heads = (cfg.num_heads, cfg.num_kv_heads, cfg.num_kv_heads)
+    for g, w, n in zip(got, want, heads):
+        assert g.shape == (rows, width, n, cfg.head_dim) and g.dtype == w.dtype
+        np.testing.assert_array_equal(
+            np.asarray(g.astype(jnp.float32)), np.asarray(w.astype(jnp.float32))
+        )

@@ -19,6 +19,7 @@ Three things are held here.
   BEFORE the fold (tests/data/kv_handoff_parent_pr30.pkkv) restores here.
 """
 
+import importlib.util
 import json
 import os
 import re
@@ -50,8 +51,23 @@ from polykey_tpu.models.transformer import (
 )
 from polykey_tpu.parallel.mesh import MeshConfig, create_mesh
 from polykey_tpu.parallel.sharding import paged_kv_sharding, param_shardings
+from test_models import folded_qkv
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+
+
+def _load_script(name: str):
+    path = os.path.join(os.path.dirname(DATA), os.pardir, "scripts",
+                        f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# Reads a compiled module's text and compiles the engine's step functions
+# for a described chip; it touches no topology while it is imported.
+_CENSUS = _load_script("decode_step_census")
 
 # -- structure ----------------------------------------------------------------
 
@@ -59,7 +75,6 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
              "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
              "u64": 8}
 _ARRAY = re.compile(r"\b(" + "|".join(_ITEMSIZE) + r")\[([\d,]*)\]")
-_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
 # What may hold a pool: where it comes in, how a loop or a branch carries it,
 # a view of it, and the write that updates it in place.
 _HOLDERS = {"parameter", "get-tuple-element", "tuple", "while", "conditional",
@@ -74,30 +89,28 @@ def _largest(result_type: str) -> int:
     return max(sizes, default=0)
 
 
+def _module(hlo: str):
+    """(computations, fused): every computation's instructions as (name,
+    result type, opcode, line), and the names of the fused computations
+    (their instructions materialise nothing themselves)."""
+    return _CENSUS.computations(hlo), _CENSUS.fused_computations(hlo)
+
+
+def _root(computations, name: str) -> tuple[str, str]:
+    """(opcode, result type) of a computation's ROOT."""
+    for _, result_type, op, line in computations.get(name, []):
+        if line.lstrip().startswith("ROOT"):
+            return op, result_type
+    return "", ""
+
+
 def pool_sized_instructions(hlo: str, layer_bytes: int) -> list[str]:
     """Instructions of a compiled module that MATERIALISE an array of one
     layer's pool bytes or more and are not one of `_HOLDERS`. A fusion is
     judged by what its computation returns (an in-place scatter of the pool
     is the XLA paths' write); instructions inside a fused computation
     materialise nothing themselves."""
-    computations: dict[str, list[tuple[str, str, str, str]]] = {}
-    current = None
-    for line in hlo.splitlines():
-        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
-        if head:
-            current = computations.setdefault(head.group(1), [])
-            continue
-        m = _INSTR.match(line)
-        if m and current is not None:
-            current.append((m.group(1), m.group(2), m.group(3), line))
-    fused = set(re.findall(r"kind=k\w+, calls=%?([\w.\-]+)", hlo))
-
-    def root_op(computation: str) -> str:
-        for name, _, op, line in computations.get(computation, []):
-            if line.lstrip().startswith("ROOT"):
-                return op
-        return ""
-
+    computations, fused = _module(hlo)
     found = []
     for comp, instructions in computations.items():
         if comp in fused:
@@ -107,7 +120,7 @@ def pool_sized_instructions(hlo: str, layer_bytes: int) -> list[str]:
                 continue
             if op == "fusion":
                 called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
-                if root_op(called) == "scatter":
+                if _root(computations, called)[0] == "scatter":
                     continue
             found.append(f"{op} {result_type} %{name}")
     return found
@@ -196,67 +209,20 @@ def v5e():
 
 
 @pytest.mark.parametrize("tp", [1, 2, 4])
-def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp, monkeypatch):
+def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp):
     """The same count in the step the chip runs — both Pallas kernels on the
     stacked pool, `paged_kv_write` aliasing it — compiled for a v5e by the
     compiler installed here. Toy depth, real page geometry; the folded
     dimension a tp shard sees is 128 lanes, and at tp = 4 the 256 lanes
     (2 KV heads of 128) of a mixtral-8x7b shard, where the decode kernel
     takes a wider block than on 1024 lanes."""
-    from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from polykey_tpu.ops import paged_attention_kernel
-
-    # A described chip cannot read a cached executable back (it warns).
-    cache_was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    # The gates ask jax.default_backend(), which is the CPU here.
-    monkeypatch.setattr(
-        paged_attention_kernel, "use_paged_kernel", lambda Hk, D: True
-    )
     cfg = replace(TINY_LLAMA, name="layout-probe", num_heads=2 * tp,
                   num_kv_heads=2 * tp, head_dim=128 if tp == 4 else 64)
-    mesh = create_mesh(MeshConfig(tp=tp), devices=list(v5e.devices)[:tp])
-    repl = NamedSharding(mesh, P())
-    shapes = jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    pages, ps = 1024, 16
+    hlo = _CENSUS.compile_step(
+        cfg, list(v5e.devices), tp=tp, lanes=8, pages=pages, page_size=ps,
+        max_seq_len=8 * ps, steps=2,
     )
-    params = jax.tree.map(
-        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        shapes, param_shardings(cfg, mesh, shapes),
-    )
-    B, pages, ps, tables = 8, 1024, 16, 8
-    pool_sh = paged_kv_sharding(mesh)
-    pool = jax.eval_shape(lambda: init_paged_kv(cfg, pages, ps, jnp.bfloat16))
-    paged = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=pool_sh),
-        pool,
-    )
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=repl)
-
-    try:
-        compiled = jax.jit(
-            engine_mod._decode_fn,
-            static_argnames=("cfg", "greedy", "steps", "eos_id", "candidates",
-                             "mesh"),
-            donate_argnames=("paged",),
-            out_shardings=(repl, repl, repl, repl,
-                           jax.tree.map(lambda s: pool_sh, pool), repl),
-        ).lower(
-            params, cfg, paged, arg((B,), jnp.int32), arg((B,), jnp.int32),
-            arg((B, tables), jnp.int32), arg((B,), jnp.bool_),
-            arg((B,), jnp.int32), arg((B, 2), jnp.int32),
-            arg((B,), jnp.float32), arg((B,), jnp.float32),
-            arg((B,), jnp.int32),
-            greedy=True, steps=2, eos_id=2, candidates=0, mesh=mesh,
-        ).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was_on)
-    hlo = compiled.as_text()
     folded = cfg.num_kv_heads * cfg.head_dim // tp
     assert pool_sized_instructions(hlo, pages * ps * folded * 2) == []
     assert aliased_pool_parameters(
@@ -355,6 +321,161 @@ def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch)
     for name in ("%ssm_state_update", "%moe_held_experts", "%paged_kv_write",
                  "%paged_attention_decode"):
         assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
+
+
+# -- the q / k / v projections in the compiled steps (ISSUE 44) ---------------
+#
+# Where a dimension of 1 stands beside the rows ([B, 1, H] in the decode
+# step, [1, T, H] in a one-row prefill) the TPU compiler folds the head split
+# that follows the q and k products INTO them: a convolution with a window
+# over heads on the weight viewed [heads, D, H]. That form relays the whole
+# `wq` / `wk` stacks {1,2,0} on every dispatch, stages each layer's slice in
+# VMEM through a fusion of its own and runs the product from there.
+# `layers.qkv_project` holds the three results flat until the products are
+# done; these cases hold the compiled steps to it, at the widths the
+# benchmark's cells run.
+
+_HEAD_WINDOW = re.compile(r"window=\{size=[^}]*\},? dim_labels=bf0_0oi->b0f")
+
+_MISTRAL = dict(
+    name="mistral-7b-widths", vocab_size=32768, hidden_size=4096,
+    intermediate_size=14336, num_heads=32, num_kv_heads=8, head_dim=128,
+    max_seq_len=8192, rope_theta=1e6, rms_norm_eps=1e-5,
+    tie_embeddings=False,
+)
+# leaves, tp, layers, experts. bf16 at 32 layers does not fit one chip (its
+# deployments are int8), and the verdict does not follow the depth: the
+# layers are one scanned body.
+_DECODE_CASES = {
+    "mistral-7b-int8": ("int8", 1, 32, 0),
+    "mistral-7b-bf16": ("none", 1, 4, 0),
+    "mixtral-tp4-shard-int8": ("int8", 4, 2, 8),
+    "mixtral-tp4-shard-bf16": ("none", 4, 2, 8),
+}
+
+
+@pytest.fixture
+def expression(request, monkeypatch):
+    """`flat`: the tree's `qkv_project`. `folded`: the parent's, put in its
+    place; jit caches a trace by the step function, so the caches go before
+    AND after (a later test must not be handed the folded trace)."""
+    if request.param == "folded":
+        from polykey_tpu.models import transformer
+
+        monkeypatch.setattr(transformer, "qkv_project", folded_qkv)
+        jax.clear_caches()
+        yield request.param
+        jax.clear_caches()
+    else:
+        yield request.param
+
+
+def _step_at_cell_widths(v5e, leaves, tp, layers, experts, prefill=None):
+    """(compiled text, one layer's `wk` bytes on a shard) of the engine's
+    step at the cells' geometry: 16 lanes, 2,048 x 16-token pages, a
+    4,096-position table, K = 8."""
+    from polykey_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(
+        **_MISTRAL, num_layers=layers, num_experts=experts,
+        num_experts_per_tok=2 if experts else 0, moe_dispatch=bool(experts),
+    )
+    hlo = _CENSUS.compile_step(
+        cfg, list(v5e.devices), quantize=leaves, tp=tp, prefill=prefill)
+    itemsize = 1 if leaves == "int8" else 2
+    wk_bytes = (cfg.hidden_size * cfg.num_kv_heads * cfg.head_dim // tp
+                * itemsize)
+    return hlo, wk_bytes
+
+
+def weight_relayouts(hlo: str, nbytes: int) -> list[str]:
+    """(a) `copy` / `transpose` instructions outside any fused computation
+    with a result of `nbytes` or more."""
+    computations, fused = _module(hlo)
+    return [
+        f"{op} {result_type} %{name}"
+        for comp, instructions in computations.items() if comp not in fused
+        for name, result_type, op, _ in instructions
+        if op in ("copy", "transpose") and _largest(result_type) >= nbytes
+    ]
+
+
+def head_window_products(hlo: str) -> list[str]:
+    """(b) convolutions with a window over heads (Mixtral's expert products,
+    `0bf_0io->0bf`, are not this form and stay)."""
+    return _HEAD_WINDOW.findall(hlo)
+
+
+def staged_weight_slices(hlo: str, nbytes: int) -> list[str]:
+    """(c) fusions whose ROOT is a `dynamic-slice` with a result of `nbytes`
+    or more: a layer's weight cut out of its stack and materialised."""
+    computations, fused = _module(hlo)
+    found = []
+    for comp, instructions in computations.items():
+        if comp in fused:
+            continue
+        for name, _, op, line in instructions:
+            if op != "fusion":
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+            root, result_type = _root(computations, called)
+            if root == "dynamic-slice" and _largest(result_type) >= nbytes:
+                found.append(f"{result_type} %{name}")
+    return found
+
+
+def layer_weight_copies(hlo: str, dtype: str, hidden: int) -> list[str]:
+    """`copy` instructions outside any fused computation whose result has
+    the shape of ONE layer's projection weight, [1, hidden, out] (what a
+    one-row prefill of the parent makes of `wq` and `wk` every layer);
+    activations of a prefill are larger than a weight, so bytes alone
+    would not tell them apart there."""
+    shape = re.compile(rf"^{dtype}\[(?:1,)?{hidden},\d+\]")
+    computations, fused = _module(hlo)
+    return [
+        f"{result_type} %{name}"
+        for comp, instructions in computations.items() if comp not in fused
+        for name, result_type, op, _ in instructions
+        if op == "copy" and shape.match(result_type)
+    ]
+
+
+@pytest.mark.parametrize("case", list(_DECODE_CASES))
+def test_decode_step_compiled_for_v5e_projects_qkv_as_plain_matmuls(v5e, case):
+    hlo, wk_bytes = _step_at_cell_widths(v5e, *_DECODE_CASES[case])
+    assert weight_relayouts(hlo, wk_bytes) == []
+    assert head_window_products(hlo) == []
+    assert staged_weight_slices(hlo, wk_bytes) == []
+    # Still the step the benchmark's readers know: both kernels, by name.
+    assert "%paged_kv_write" in hlo and "%paged_attention_decode" in hlo
+
+
+@pytest.mark.parametrize("expression", ["folded"], indirect=True)
+def test_qkv_census_sees_the_folded_projection(v5e, expression):
+    """The census has teeth: the parent's expression, compiled the same way,
+    relays both stacks, stages both layers' slices and takes two products
+    with a window over heads (32 for q, 8 for k)."""
+    hlo, wk_bytes = _step_at_cell_widths(
+        v5e, *_DECODE_CASES["mistral-7b-int8"])
+    assert len(weight_relayouts(hlo, wk_bytes)) == 2
+    assert len(head_window_products(hlo)) == 2
+    assert len(staged_weight_slices(hlo, wk_bytes)) == 2
+
+
+@pytest.mark.parametrize("expression", ["flat", "folded"], indirect=True)
+@pytest.mark.parametrize("rows,width", [(1, 128), (1, 512), (2, 128), (2, 512)])
+def test_prefill_step_compiled_for_v5e_copies_no_layer_weight(
+        v5e, rows, width, expression):
+    """The prefill modules of `mistral-7b` (int8, 32 layers): a ONE-row
+    module folds like the decode step, and the parent's expression then
+    copies the layer's `wq` and `wk` in VMEM every layer; a multi-row module
+    never took that form and must compile as it did."""
+    hlo, _ = _step_at_cell_widths(
+        v5e, "int8", 1, 32, 0, prefill=(rows, width))
+    folds = expression == "folded" and rows == 1
+    assert len(head_window_products(hlo)) == (2 if folds else 0)
+    assert len(layer_weight_copies(hlo, "s8", 4096)) == (2 if folds else 0)
+    assert "%flash_attention" in hlo
 
 
 # -- parity ---------------------------------------------------------------------
