@@ -583,6 +583,23 @@ class _InflightBlock(NamedTuple):
     steps: int = 0
 
 
+@dataclass(eq=False)     # compared by identity: `toks_dev` is an array
+class _FirstTokens:
+    """One prefill dispatch whose sampled tokens hold first tokens the
+    host has not read yet (the rows of a dispatch share one `toks_dev`
+    and land together). Kept in dispatch order, which is the order the
+    device finishes them in; the first read of a dispatch's tokens runs
+    inside its `first_token` phase (_read_first_tokens)."""
+
+    toks_dev: jax.Array
+    members: list           # [(slot_idx, slot)] of the dispatch's `last` rows
+    # When the engine last looked and found it unfinished; until then,
+    # when its dispatch call returned. The tokens cannot have been ready
+    # for longer than the time since (metrics.on_first_tokens_read).
+    polled: float
+    read: bool = False      # its `first_token` phase has been entered
+
+
 class EngineDeadError(RuntimeError):
     """The engine (or pool) cannot take work. `retry_after_ms`, when the
     raiser can estimate it (a replica pool with a supervised restart in
@@ -1130,6 +1147,18 @@ class InferenceEngine:
         from collections import deque
 
         self._inflight_q: deque = deque()
+        # Prefill dispatches whose first tokens wait to be read, oldest
+        # first (_FirstTokens; engine thread only).
+        self._first_tokens: list = []
+        # (end time, reason) of the `_admit` visits that left requests
+        # waiting, oldest first, and when the visit now running began:
+        # what a request's queue time is cut by when it is admitted
+        # (_stamp_admitted). Engine thread only. Requests leave the
+        # queue oldest first, so an admission drops the visits that
+        # ended before its request arrived; the bound only matters to a
+        # queue that nothing leaves.
+        self._admit_visits: deque = deque(maxlen=1024)
+        self._admit_began = 0.0
         try:
             # polylint: disable=ML004(documented operator override: env beats any programmatic config, see comment above)
             self._depth = max(1, int(os.environ.get(
@@ -1744,6 +1773,7 @@ class InferenceEngine:
         (_advance_chunked_prefills). Returns (admitted_any, spent)."""
         admitted = False
         spent = 0
+        self._admit_began = time.monotonic()
         # bucket → [(slot_idx, slot, window ids, window start, last window)]
         groups: dict[int, list] = {}
         cap = self._group_sizes[-1]
@@ -1754,7 +1784,7 @@ class InferenceEngine:
                 ]
                 if not free_slots:
                     if not self._submit.empty():
-                        self.metrics.on_admit_deferred("no_slot")
+                        self._defer_admission("no_slot")
                     return admitted, spent
                 try:
                     request = self._submit.get_nowait()
@@ -1798,7 +1828,7 @@ class InferenceEngine:
                 except AllocationError:
                     # Pool exhausted: put it back and let running requests
                     # finish. FIFO fairness over throughput.
-                    self.metrics.on_admit_deferred("no_pages")
+                    self._defer_admission("no_pages")
                     self._requeue_front(request)
                     return admitted, spent
                 except Exception as e:
@@ -1808,11 +1838,35 @@ class InferenceEngine:
             if not self._submit.empty():
                 # This iteration's prefill budget is spent: whoever still
                 # waits does so behind the next decode block.
-                self.metrics.on_admit_deferred("budget")
+                self._defer_admission("budget")
             return admitted, spent
         finally:
             for bucket, group in groups.items():
                 self._dispatch_prefill_group(bucket, group)
+
+    def _defer_admission(self, reason: str) -> None:
+        """This `_admit` visit leaves requests waiting, for `reason`."""
+        self.metrics.on_admit_deferred(reason)
+        self._admit_visits.append((time.monotonic(), reason))
+
+    def _stamp_admitted(self, timings: RequestTimings) -> None:
+        """Admission has taken a request out of the queue: stamp
+        `prefill_start`, and cut the time since `enqueued` at the
+        `_admit` visits that ended inside it. From a visit that left the
+        request waiting to the next visit (for the last one: to the
+        start of this visit) is that visit's reason; the rest — before
+        the first visit that saw the request, and inside this one — is
+        nobody's decision and is left to cause `loop`
+        (metrics.QUEUE_CAUSES, EngineMetrics.on_first_token)."""
+        visits = self._admit_visits
+        while visits and visits[0][0] <= timings.enqueued:
+            visits.popleft()
+        deferred: dict = {}
+        ends = [end for end, _ in visits]
+        for (end, reason), nxt in zip(visits, ends[1:] + [self._admit_began]):
+            deferred[reason] = deferred.get(reason, 0.0) + (nxt - end)
+        timings.queue_deferred = deferred
+        timings.prefill_start = time.monotonic()
 
     def _cover_rows(self, slot_idx: int, slot: "_Slot", ids, start: int,
                     widths: tuple[int, ...]) -> tuple[int, list]:
@@ -1860,7 +1914,7 @@ class InferenceEngine:
             # Decode-tier resume (ISSUE 13): the prompt's KV arrives
             # with the request; nothing tokenizes or prefills here.
             return self._admit_resume(slot_idx, request)
-        request.timings.prefill_start = time.monotonic()
+        self._stamp_admitted(request.timings)
 
         if self._faults is not None:
             self._faults.maybe_raise("tokenizer-error", replica=self.replica_id, tier=self._tier)
@@ -2100,7 +2154,7 @@ class InferenceEngine:
             if self._faults is not None:
                 self._faults.maybe_raise("prefill-error", replica=self.replica_id, tier=self._tier)
             with self._phase("prefill", bucket=bucket, rows=n_pad * bucket,
-                             tokens=real, stateful=stateful):
+                             tokens=real):
                 # The last dispatch's stamp is the one that stays: the
                 # one that completes the prompt.
                 issued = time.monotonic()
@@ -2149,11 +2203,17 @@ class InferenceEngine:
                 sources[FROM_ZERO], sources[FROM_PREVIOUS_ROW],
                 sources[FROM_SLOT],
             )
+        completes = []
         for r, (slot_idx, slot, ids, _, last) in enumerate(group):
             if self.timeline is not None:
                 self.timeline.prefill(slot_idx, len(ids), last)
             if last:
                 self._merge_slot(slot_idx, slot, toks_dev, r)
+                completes.append((slot_idx, slot))
+        if completes:
+            self._first_tokens.append(
+                _FirstTokens(toks_dev, completes, time.monotonic())
+            )
         return True
 
     def _state_rows(self, group: list, n_pad: int) -> np.ndarray:
@@ -2490,20 +2550,60 @@ class InferenceEngine:
         self._lane_gamma[slot_idx] = max(self._gamma_max, 1)
 
     def _has_unresolved(self) -> bool:
-        """A prefilled slot still waits for its first token's delivery."""
-        return any(
-            s is not None and s.token_dev is not None for s in self._slots
-        )
+        """A prefill dispatch's first tokens still wait to be read."""
+        return bool(self._first_tokens)
+
+    def _unread(self, record: _FirstTokens) -> list:
+        """The members of a dispatch whose first token is still to read
+        (a member that finished meanwhile — cancelled, failed — is not)."""
+        return [
+            (i, slot) for i, slot in record.members
+            if self._slots[i] is slot and slot.token_dev is not None
+        ]
 
     def _resolve_prefills(self, block: bool = False) -> None:
-        """Deliver first tokens whose async D2H copies have landed (all of
-        them when `block=True`). Activation already happened at merge time;
-        this is purely client-facing delivery + host bookkeeping."""
-        for i, slot in enumerate(self._slots):
-            if slot is None or slot.token_dev is None:
-                continue
-            if block or slot.token_dev.is_ready():
+        """Deliver the first tokens of the prefill dispatches whose async
+        D2H copies have landed (all of them when `block=True`), oldest
+        dispatch first. Activation already happened at merge time; this
+        is purely client-facing delivery + host bookkeeping."""
+        for record in list(self._first_tokens):
+            unread = self._unread(record)
+            if not unread:
+                self._first_tokens.remove(record)
+            elif block or record.toks_dev.is_ready():
+                self._read_first_tokens(record, unread)
+            else:
+                record.polled = time.monotonic()
+
+    def _read_first_tokens(self, record: _FirstTokens, members: list) -> None:
+        """Hand `members` of one prefill dispatch their first tokens. The
+        dispatch's first read — all its members from _resolve_prefills,
+        the first of them to come up in a block's emit loop from
+        _process_step — runs inside the dispatch's `first_token` phase:
+        that span's start is when the host took up tokens the device had
+        finished earlier."""
+        span = contextlib.nullcontext()
+        if not record.read:
+            record.read = True
+            self.metrics.on_first_tokens_read(
+                time.monotonic() - record.polled
+            )
+            span = self._phase("first_token")
+        with span:
+            for i, slot in members:
                 self._resolve_slot(i, slot)
+        if not self._unread(record):
+            self._first_tokens.remove(record)
+
+    def _resolve_in_block(self, slot_idx: int, slot: _Slot) -> None:
+        """A lane of the block in hand still owes its client a first
+        token, which precedes the block's tokens in the stream: its
+        prefill ran before the block, so the read is local."""
+        record = next(
+            r for r in self._first_tokens
+            if any(s is slot for _, s in r.members)
+        )
+        self._read_first_tokens(record, [(slot_idx, slot)])
 
     def _resolve_slot(self, slot_idx: int, slot: _Slot) -> None:
         try:
@@ -2544,9 +2644,13 @@ class InferenceEngine:
         """A request's first token is in hand (bucketed, batched and
         chunked prefill all funnel through _resolve_slot; a handoff
         resume comes from _admit_resume): stamp it, file the three TTFT
-        phases, and give a traced request its `prefill_wait` (admitted,
-        tokenized, held on the host), `prefill` (dispatched: device queue,
-        the prefill, readback, this resolve) and open `decode` spans."""
+        phases and the queue's causes, and give a traced request its
+        `prefill_wait` (admitted, tokenized, held on the host), `prefill`
+        (from the dispatch call to here) and open `decode` spans. What
+        the time after the dispatch call is made of — the device's
+        queue, the prefill, the finished token waiting to be read — is
+        not a request's to know: a capture times the first two, the
+        `first_token` phase and the poll gap the third."""
         request = slot.request
         timings = request.timings
         timings.first_token = time.monotonic()
@@ -2628,7 +2732,7 @@ class InferenceEngine:
         request, so a retry re-admits cleanly)."""
         cfg = self.config
         state: KVHandoffState = request.resume_state
-        request.timings.prefill_start = time.monotonic()
+        self._stamp_admitted(request.timings)
         try:
             state.validate_for(
                 self.model_cfg, cfg.page_size, self._kv_quantized
@@ -3178,7 +3282,7 @@ class InferenceEngine:
         )
         live = tuple(int(i) for i in np.flatnonzero(act))
         with self._phase("decode", seq=self._dispatch_seq + 1, lanes=lanes,
-                         steps=steps, stateful=self.model_cfg.stateful):
+                         steps=steps):
             (packed_dev, last_dev, seq_dev, act_dev,
              self.paged, self.state) = self._jit_decode(
                 self.params,
@@ -3380,9 +3484,7 @@ class InferenceEngine:
                 self._finish(i, error=f"{DEADLINE_MSG} mid-decode")
                 continue
             if slot.token_dev is not None:
-                # First token precedes block tokens in the client stream
-                # (its copy landed with the prefill, before this block).
-                self._resolve_slot(i, slot)
+                self._resolve_in_block(i, slot)
                 if self._slots[i] is not slot:
                     continue
             # The block's own [K, B] shape, not the configured K — the
@@ -3552,7 +3654,7 @@ class InferenceEngine:
                 self._finish(i, error=f"{DEADLINE_MSG} mid-decode")
                 continue
             if slot.token_dev is not None:
-                self._resolve_slot(i, slot)
+                self._resolve_in_block(i, slot)
                 if self._slots[i] is not slot:
                     continue
             before = slot.generated

@@ -33,15 +33,30 @@ from ..obs.timeline import PHASES
 LANE_BUCKETS = (1, 2, 4, 8, 12, 16, 24, 32, 48, 64, 96, 128, 192, 256,
                 384, 512)
 
+# What a request's time in the submit queue is charged to (ISSUE 45):
+# `loop` — the engine thread was in another phase and had not looked at
+# it (or was inside the visit that admitted it); the other three are the
+# reasons an `_admit` visit left it waiting (EngineMetrics.admit_deferred
+# counts the same visits), each charged from that visit to the next.
+QUEUE_CAUSES = ("loop", "budget", "no_slot", "no_pages")
+
 
 @dataclass
 class RequestTimings:
     enqueued: float = field(default_factory=time.monotonic)
     prefill_start: float = 0.0
+    # Seconds of enqueued -> prefill_start spent behind `_admit` visits
+    # that left the request waiting, by the visit's reason (budget,
+    # no_slot, no_pages); stamped with prefill_start. What they do not
+    # cover is cause `loop` (QUEUE_CAUSES).
+    queue_deferred: dict = field(default_factory=dict)
     # Host time at which the prefill dispatch that completes the prompt
     # was issued (the group dispatch of a bucketed prompt, the last chunk
     # of a chunked one): splits the time after admission into "held on
-    # the host" and "device queue + prefill + readback" (ISSUE 26).
+    # the host" and what follows the dispatch call (ISSUE 26) — the
+    # device's queue and the prefill (a capture times both), then the
+    # finished token waiting for the host to read it (the `first_token`
+    # engine phase, EngineMetrics.first_token_poll_gap_seconds).
     prefill_dispatched: float = 0.0
     first_token: float = 0.0
     finished: float = 0.0
@@ -163,6 +178,9 @@ class EngineMetrics:
             "queue": 0.0, "prefill_wait": 0.0, "first_token": 0.0,
         }
         self.ttft_phase_count = 0
+        # The same requests' `queue` seconds by cause (QUEUE_CAUSES): the
+        # four add up to ttft_phase_seconds["queue"], request by request.
+        self.ttft_queue_seconds = dict.fromkeys(QUEUE_CAUSES, 0.0)
         # Each time _admit leaves a waiting request where it is, by
         # reason: no free slot, no pages (AllocationError, requeued), or
         # the interleaved-prefill budget of this iteration spent.
@@ -180,6 +198,13 @@ class EngineMetrics:
         # and chunks (on_prefill_rows).
         self.prefill_rows_dispatched = 0
         self.prefill_rows_useful = 0
+        # Prefill dispatches whose first tokens were read, and for each
+        # the seconds since the engine last looked at it and found it
+        # unfinished (or since its dispatch call returned): an upper
+        # bound, with no capture, on how long finished first tokens lay
+        # unread (on_first_tokens_read).
+        self.first_token_poll_gap_seconds = 0.0
+        self.first_token_poll_gap_count = 0
         # Windows (real rows) among those dispatches, and prompts whose
         # cover put more than one window into a dispatch
         # (engine.prefill_cover).
@@ -340,14 +365,28 @@ class EngineMetrics:
         self.phase_count[name] += 1
 
     def on_first_token(self, timings: RequestTimings) -> None:
-        """A request's first token resolved: file its three TTFT phases."""
+        """A request's first token resolved: file its three TTFT phases,
+        and its queue time by cause — `loop` is what the deferring
+        visits do not cover, so the causes add up to `queue` exactly."""
         queue, wait, first = timings.ttft_phases_s()
+        deferred = timings.queue_deferred
         acc = self.ttft_phase_seconds
+        by_cause = self.ttft_queue_seconds
         with self._lock:
             acc["queue"] += queue
             acc["prefill_wait"] += wait
             acc["first_token"] += first
             self.ttft_phase_count += 1
+            for reason, seconds in deferred.items():
+                by_cause[reason] += seconds
+            by_cause["loop"] += queue - sum(deferred.values())
+
+    def on_first_tokens_read(self, poll_gap_s: float) -> None:
+        """One prefill dispatch's first tokens are about to be read,
+        `poll_gap_s` after the engine last found them unfinished."""
+        with self._lock:
+            self.first_token_poll_gap_seconds += poll_gap_s
+            self.first_token_poll_gap_count += 1
 
     def on_admit_deferred(self, reason: str) -> None:
         self.admit_deferred[reason] += 1
@@ -620,6 +659,10 @@ class EngineMetrics:
                     for k, v in self.ttft_phase_seconds.items()
                 },
                 "ttft_phase_count": self.ttft_phase_count,
+                "ttft_queue_seconds": {
+                    k: round(v, 6)
+                    for k, v in self.ttft_queue_seconds.items()
+                },
                 "admit_deferred": dict(self.admit_deferred),
                 "decode_lane_steps_delivered":
                     self.decode_lane_steps_delivered,
@@ -628,6 +671,10 @@ class EngineMetrics:
                 "decode_lane_steps_dead": self.decode_lane_steps_dead,
                 "prefill_rows_dispatched": self.prefill_rows_dispatched,
                 "prefill_rows_useful": self.prefill_rows_useful,
+                "first_token_poll_gap_seconds":
+                    round(self.first_token_poll_gap_seconds, 6),
+                "first_token_poll_gap_count":
+                    self.first_token_poll_gap_count,
                 "prefill_windows_dispatched":
                     self.prefill_windows_dispatched,
                 "prefill_prompts_split": self.prefill_prompts_split,

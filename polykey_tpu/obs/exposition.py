@@ -174,6 +174,19 @@ _ENGINE_FAMILIES: tuple = (
     ("labeled:reason", "polykey_admit_deferred_total",
      "Times admission left a waiting request in the queue, by reason "
      "(no_slot, no_pages, budget).", "admit_deferred"),
+    ("labeled:cause", "polykey_ttft_queue_seconds_total",
+     "The queue phase of polykey_ttft_phase_seconds_total by cause: "
+     "behind an admission visit that left the request waiting (budget, "
+     "no_slot, no_pages), or not looked at by the engine thread (loop).",
+     "ttft_queue_seconds"),
+    ("counter", "polykey_first_token_poll_gap_seconds_total",
+     "Per prefill dispatch whose first tokens were read: seconds since "
+     "the engine last found it unfinished (or dispatched it). A "
+     "finished first token lay unread for no longer.",
+     "first_token_poll_gap_seconds"),
+    ("counter", "polykey_first_token_reads_total",
+     "Prefill dispatches whose first tokens were read.",
+     "first_token_poll_gap_count"),
     ("counter", "polykey_decode_lane_steps_delivered_total",
      "Decode lane-steps whose token reached a request (counted when "
      "the block is processed).", "decode_lane_steps_delivered"),

@@ -94,6 +94,10 @@ PHASES: dict[str, tuple[str, str]] = {
     "spec_decode": ("nested", "the spec round's dispatch call"),
     "readback_wait": ("nested", "np.asarray on a block's packed tokens: "
                       "blocks until the device finished the block"),
+    "first_token": ("nested", "the first read of one prefill dispatch's "
+                    "first tokens, handed to their clients: all of them "
+                    "inside resolve, or the first of them to come up in "
+                    "a block's emit loop inside process"),
 }
 LOOP_PHASES = tuple(n for n, (level, _) in PHASES.items() if level == "loop")
 

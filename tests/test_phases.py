@@ -10,7 +10,11 @@ import pytest
 
 from polykey_tpu.engine.config import EngineConfig
 from polykey_tpu.engine.engine import GenRequest, InferenceEngine
-from polykey_tpu.engine.metrics import EngineMetrics, RequestTimings
+from polykey_tpu.engine.metrics import (
+    QUEUE_CAUSES,
+    EngineMetrics,
+    RequestTimings,
+)
 from polykey_tpu.obs.exposition import engine_collector
 from polykey_tpu.obs.timeline import (
     EVENT_FIELDS,
@@ -208,6 +212,219 @@ def test_an_empty_queue_defers_nothing():
     eng = stopped_engine()
     assert eng._admit() == (False, 0)
     assert sum(eng.metrics.admit_deferred.values()) == 0
+    assert not eng._admit_visits
+
+
+# -- queue time by cause (ISSUE 45) ---------------------------------------------
+
+
+def serve_queue(eng, budget=None, free=None) -> None:
+    """Visit `_admit` until the queue is empty, reading every first token
+    after each visit; `free(eng)` makes room between visits."""
+    while not eng._submit.empty():
+        eng._admit(budget=budget)
+        eng._resolve_prefills(block=True)
+        if free is not None:
+            free(eng)
+
+
+def finish_all(eng) -> None:
+    for i, slot in enumerate(eng._slots):
+        if slot is not None:
+            eng._finish(i)
+
+
+@pytest.mark.parametrize("cause", ["loop", "budget", "no_slot", "no_pages"])
+def test_queue_causes_partition_the_queue_phase(cause):
+    """Four forced situations: nothing deferred, the iteration's budget
+    spent, no free slot, AllocationError. Every request's causes add up
+    to its queue phase; only `loop` and the forced cause hold time."""
+    config, budget, free = CONFIG, None, None
+    if cause == "budget":
+        budget = 16                          # one 16-row window a visit
+    elif cause == "no_slot":
+        free = finish_all
+    elif cause == "no_pages":
+        # 7 usable pages: one 12 + 40 token request takes all of them.
+        config, free = dataclasses.replace(CONFIG, num_pages=8), finish_all
+    eng = stopped_engine(config)
+    n = {"loop": 3, "budget": 3, "no_slot": 6, "no_pages": 3}[cause]
+    requests = [GenRequest(prompt=f"{SHORT} {i}", max_new_tokens=40)
+                for i in range(n)]
+    for r in requests:
+        eng._submit.put(r)
+    serve_queue(eng, budget=budget, free=free)
+
+    waited = [r for r in requests if r.timings.queue_deferred]
+    for r in requests:
+        t = r.timings
+        assert t.first_token > 0.0
+        assert set(t.queue_deferred) <= {cause}
+        queue = t.ttft_phases_s()[0]
+        assert 0.0 <= sum(t.queue_deferred.values()) <= queue
+    # The first visit admits what it can; who it left waits behind it.
+    assert len(waited) == {"loop": 0, "budget": 2, "no_slot": 2,
+                           "no_pages": 2}[cause]
+    if cause != "loop":
+        last = requests[-1].timings
+        visits = 2 if cause == "no_slot" else 3
+        assert eng.metrics.admit_deferred[cause] == visits - 1
+        # Behind every deferring visit up to the start of its own.
+        assert 0.0 < last.queue_deferred[cause] < last.ttft_phases_s()[0]
+
+    by_cause = eng.metrics.ttft_queue_seconds
+    assert set(by_cause) == set(QUEUE_CAUSES)
+    assert sum(by_cause.values()) == pytest.approx(
+        eng.metrics.ttft_phase_seconds["queue"], abs=1e-6)
+    assert by_cause["loop"] > 0.0
+    for name in set(QUEUE_CAUSES) - {"loop", cause}:
+        assert by_cause[name] == 0.0
+    assert by_cause[cause] > 0.0
+    snap = eng.metrics.snapshot()           # rounded to the microsecond
+    assert sum(snap["ttft_queue_seconds"].values()) == pytest.approx(
+        snap["ttft_phase_seconds"]["queue"], abs=4e-6)
+
+
+def test_queue_cause_stretches_are_cut_at_the_visits():
+    """By hand: visits that ended at 2 (budget), 3 (budget) and 5
+    (no_slot) left a request enqueued at 1 waiting; the admitting visit
+    began at 6. A visit that ended before it arrived is not its."""
+    eng = stopped_engine()
+    eng._admit_visits.extend(
+        [(0.5, "no_pages"), (2.0, "budget"), (3.0, "budget"),
+         (5.0, "no_slot")])
+    eng._admit_began = 6.0
+    timings = RequestTimings(enqueued=1.0)
+    eng._stamp_admitted(timings)
+    assert timings.queue_deferred == {"budget": 3.0, "no_slot": 1.0}
+    assert [end for end, _ in eng._admit_visits] == [2.0, 3.0, 5.0]
+    timings.prefill_start = 6.5
+    timings.first_token = 7.0
+    eng.metrics.on_first_token(timings)
+    assert eng.metrics.ttft_queue_seconds == {
+        "loop": 1.5, "budget": 3.0, "no_slot": 1.0, "no_pages": 0.0}
+    # A request that arrived after the last of them waited on the loop.
+    late = RequestTimings(enqueued=5.5)
+    eng._stamp_admitted(late)
+    assert late.queue_deferred == {} and not eng._admit_visits
+
+
+# -- a finished first token waiting for the host (ISSUE 45) --------------------
+
+
+def test_first_token_phase_once_per_resolved_dispatch():
+    """Two group dispatches (cap 2 a visit by budget) and a chunked
+    prompt: `first_token` is entered once per dispatch whose tokens are
+    read, inside `resolve`, oldest dispatch first; the poll-gap pair
+    moves only then."""
+    eng = stopped_engine()
+    prompts = [SHORT + " a", SHORT + " b", SHORT + " c", LONG]
+    requests = [GenRequest(prompt=p, max_new_tokens=4) for p in prompts]
+    for r in requests[:3]:
+        eng._submit.put(r)
+    eng._admit(budget=32)                   # a and b: one dispatch of two
+    eng._admit(budget=32)                   # c: a dispatch of its own
+    eng._submit.put(requests[3])
+    eng._admit()                            # registered for chunks
+    while any(s is not None and s.pending is not None for s in eng._slots):
+        eng._advance_chunked_prefills(None)
+    m = eng.metrics
+    chunks = -(-len(eng.tokenizer.encode(LONG)) // 16)
+    assert m.phase_count["prefill"] == 2 + chunks
+    # Only a dispatch that completes a prompt leaves tokens to read.
+    assert [len(rec.members) for rec in eng._first_tokens] == [2, 1, 1]
+    assert m.phase_count["first_token"] == 0
+    assert m.first_token_poll_gap_count == 0
+
+    order = []
+    resolve_slot = eng._resolve_slot
+
+    def recording(slot_idx, slot):
+        # Inside `first_token`, which is inside the caller's `resolve`:
+        # neither has been counted yet.
+        order.append((slot.request, m.phase_count["first_token"],
+                      m.phase_count["resolve"]))
+        resolve_slot(slot_idx, slot)
+
+    eng._resolve_slot = recording
+    with eng._phase("resolve"):
+        eng._resolve_prefills(block=True)
+    assert [r for r, _, _ in order] == requests
+    assert [n for _, n, _ in order] == [0, 0, 1, 2]
+    assert {n for _, _, n in order} == {0}
+    assert m.phase_count["first_token"] == 3 == m.first_token_poll_gap_count
+    assert m.phase_count["resolve"] == 1
+    assert m.phase_seconds["first_token"] <= m.phase_seconds["resolve"]
+    assert m.first_token_poll_gap_seconds > 0.0
+    assert not eng._has_unresolved()
+    # Nothing to read: neither the phase nor the pair moves.
+    gap = m.first_token_poll_gap_seconds
+    eng._resolve_prefills(block=True)
+    assert m.phase_count["first_token"] == 3
+    assert m.first_token_poll_gap_seconds == gap
+
+
+def test_poll_gap_runs_from_the_last_unfinished_look():
+    """A dispatch found unfinished is stamped and kept; a block's emit
+    loop reads a dispatch at the turn of the first of its lanes, inside
+    one `first_token` phase, and the rest at their own turns; a dispatch
+    whose every member finished unread is dropped without a phase."""
+
+    class Unfinished:
+        def is_ready(self):
+            return False
+
+    eng = stopped_engine()
+    pair = [GenRequest(prompt=f"{SHORT} {i}", max_new_tokens=4)
+            for i in range(2)]
+    late = GenRequest(prompt=SHORT + " z", max_new_tokens=4)
+    for r in pair:
+        eng._submit.put(r)
+    eng._admit()                            # one dispatch of two rows
+    block = eng._dispatch_step()            # both lanes ride this block
+    eng._submit.put(late)
+    eng._admit()                            # dispatched after the block
+    records = list(eng._first_tokens)
+    assert [len(rec.members) for rec in records] == [2, 1]
+    # Records are told apart by identity (list.remove): `==` on their
+    # token arrays, [2] against [1], is not a truth value.
+    assert records[0] != records[1] and records[1] == records[1]
+    landed = records[1].toks_dev
+    for rec in records:
+        rec.toks_dev = Unfinished()
+    stamps = [rec.polled for rec in records]
+    eng._resolve_prefills()
+    assert eng._first_tokens == records
+    assert all(rec.polled > was for rec, was in zip(records, stamps))
+    assert eng.metrics.first_token_poll_gap_count == 0
+
+    inside = []
+    resolve_slot = eng._resolve_slot
+
+    def recording(slot_idx, slot):
+        # Entered but not yet counted: the read is inside the phase.
+        inside.append(eng.metrics.first_token_poll_gap_count
+                      - eng.metrics.phase_count["first_token"])
+        resolve_slot(slot_idx, slot)
+
+    eng._resolve_slot = recording
+    eng._process_step(block)
+    # The pair's first lane opened the dispatch's phase; its second was
+    # read at its own turn in the loop, outside it.
+    assert inside == [1, 0]
+    assert eng._first_tokens == records[1:]
+    assert all(r.timings.first_token > 0.0 for r in pair)
+    assert not late.timings.first_token
+    assert eng.metrics.phase_count["first_token"] == 1
+    assert eng.metrics.first_token_poll_gap_count == 1
+    assert eng.metrics.ttft_phase_count == 2
+    # `late` is cancelled and finished before its token was read.
+    records[1].toks_dev = landed
+    eng._finish(records[1].members[0][0], error="cancelled")
+    eng._resolve_prefills(block=True)
+    assert not eng._first_tokens
+    assert eng.metrics.phase_count["first_token"] == 1
+    assert eng.metrics.first_token_poll_gap_count == 1
 
 
 # -- lane-step outcomes -----------------------------------------------------
@@ -352,15 +569,60 @@ def test_exporter_renders_one_sample_per_phase_and_counter(live):
         assert f'polykey_ttft_phase_seconds_total{{phase="{name}"}}' in page
     for reason in ("no_slot", "no_pages", "budget"):
         assert f'polykey_admit_deferred_total{{reason="{reason}"}}' in page
+    for cause in QUEUE_CAUSES:
+        assert f'polykey_ttft_queue_seconds_total{{cause="{cause}"}}' in page
     for family in ("polykey_decode_lane_steps_delivered_total",
                    "polykey_decode_lane_steps_overshoot_total",
                    "polykey_decode_lane_steps_dead_total",
                    "polykey_prefill_rows_dispatched_total",
                    "polykey_prefill_rows_useful_total",
-                   "polykey_ttft_phase_requests_total"):
+                   "polykey_ttft_phase_requests_total",
+                   "polykey_first_token_poll_gap_seconds_total",
+                   "polykey_first_token_reads_total"):
         assert f"\n{family} " in page
     stats = live.stats()
     for key in ("phase_seconds", "phase_count", "ttft_phase_seconds",
-                "ttft_phase_count", "admit_deferred",
-                "decode_lane_steps_delivered", "prefill_rows_useful"):
+                "ttft_phase_count", "ttft_queue_seconds", "admit_deferred",
+                "decode_lane_steps_delivered", "prefill_rows_useful",
+                "first_token_poll_gap_seconds",
+                "first_token_poll_gap_count"):
         assert key in stats
+
+
+def test_a_supervised_restart_hands_the_wait_accumulators_over():
+    """The queue causes, the `first_token` phase and the poll-gap pair
+    live in EngineMetrics, so the engine a supervisor swaps in goes on
+    from the dead one's readings (as phase_seconds does)."""
+    from polykey_tpu.engine.supervisor import EngineSupervisor
+
+    old = stopped_engine()
+    for i in range(2):
+        old._submit.put(GenRequest(prompt=f"{SHORT} {i}", max_new_tokens=4))
+    serve_queue(old, budget=16)
+    before = old.metrics.snapshot()
+    assert before["ttft_queue_seconds"]["budget"] > 0.0
+    assert before["first_token_poll_gap_count"] == 2
+    old.dead = "killed by the test"
+    supervisor = EngineSupervisor(old, lambda: InferenceEngine(CONFIG))
+    supervisor._restart(old)
+    fresh = supervisor.engine
+    try:
+        assert fresh is not old and fresh.metrics is old.metrics
+        assert not fresh._first_tokens and not fresh._admit_visits
+        request = GenRequest(prompt=SHORT, max_new_tokens=4)
+        fresh.submit(request)
+        _tokens, done, error = _collect(request)
+        assert error is None and done is not None
+        wait_drained(fresh)
+        after = fresh.metrics.snapshot()
+        assert after["ttft_phase_count"] == 3
+        assert after["phase_count"]["first_token"] == 3
+        assert after["first_token_poll_gap_count"] == 3
+        assert after["first_token_poll_gap_seconds"] > \
+            before["first_token_poll_gap_seconds"]
+        assert after["ttft_queue_seconds"]["budget"] == \
+            before["ttft_queue_seconds"]["budget"]
+        assert after["ttft_queue_seconds"]["loop"] > \
+            before["ttft_queue_seconds"]["loop"]
+    finally:
+        fresh.shutdown()
