@@ -819,16 +819,16 @@ class GraphEnv:
             # is audited like one. (The gather half of the pair donates
             # nothing — it is a read, the pool stays.)
             P = cfg.pages_per_seq
-            zk = np.zeros(
-                (engine.model_cfg.num_layers, P, *engine.paged.k.shape[2:]),
-                engine.paged.k.dtype,
+            pages = jax.tree.map(
+                lambda pool: np.zeros(
+                    (pool.shape[0], P, *pool.shape[2:]), pool.dtype),
+                engine.paged,
             )
             yield (
                 f"{engine_label}._jit_kv_restore",
                 partial(
                     engine._jit_kv_restore.lower,
-                    engine.paged, np.zeros((P,), np.int32), zk,
-                    np.zeros_like(zk),
+                    engine.paged, np.zeros((P,), np.int32), pages,
                 ),
                 count_big_leaves(engine.paged),
             )
@@ -1260,7 +1260,7 @@ class ShapeLayoutContracts(GraphCheck):
         # Paged decode DMA kernel: folded lane dim Hk*D = 128.
         N, ps, P = 8, 16, 4
         q = jnp.zeros((2, Hq, D), jnp.float32)
-        kp = jnp.zeros((N, ps, Hk * D), jnp.float32)
+        kvp = jnp.zeros((2 * N, ps, Hk * D), jnp.float32)     # page halves
         tables = jnp.zeros((2, P), jnp.int32)
         positions = jnp.zeros((2,), jnp.int32)
         window = jnp.zeros((1,), jnp.int32)
@@ -1270,17 +1270,18 @@ class ShapeLayoutContracts(GraphCheck):
             lambda *args: paged_mod._decode_call(
                 *args, scale=D ** -0.5, logit_softcap=None, interpret=False,
             ),
-            (q, kp, kp, tables, positions, window, page_range),
+            (q, kvp, tables, positions, window, page_range),
             [((2, Hq, D), "float32"),
              ((2, Hq, 1), "float32"), ((2, Hq, 1), "float32")],
         ))
-        # int8-KV variant: (values, scales) pairs, scales [N, ps, Hk].
-        kq = jnp.zeros((N, ps, Hk * D), jnp.int8)
+        # int8-KV variant: the (values, k scales, v scales) triple,
+        # scales [N, ps, Hk].
+        kq = jnp.zeros((2 * N, ps, Hk * D), jnp.int8)
         scales = jnp.zeros((N, ps, Hk), jnp.bfloat16)
         findings.extend(abstract_contract(
             "ops.paged_attention_kernel._decode_call[int8]",
             lambda q2, kv, sc, t, p, w, r: paged_mod._decode_call(
-                q2, (kv, sc), (kv, sc), t, p, w, r,
+                q2, (kv, sc, sc), t, p, w, r,
                 scale=D ** -0.5, logit_softcap=None, interpret=False,
             ),
             (q.astype(jnp.bfloat16), kq, scales, tables, positions, window,
@@ -1305,7 +1306,11 @@ class ShapeLayoutContracts(GraphCheck):
         from ..models.config import get_config
         from ..models.transformer import init_params
         from ..parallel.mesh import MeshConfig, create_mesh
-        from ..parallel.sharding import paged_kv_sharding, param_shardings
+        from ..parallel.sharding import (
+            kv_scale_sharding,
+            paged_kv_sharding,
+            param_shardings,
+        )
 
         findings: list[Finding] = []
         n_devices = len(jax.devices())
@@ -1359,10 +1364,12 @@ class ShapeLayoutContracts(GraphCheck):
                         f"kv_pool[{model}/{mesh_name}]",
                         tuple(leaf.shape), kv_sh,
                     ))
+                scale_sh = kv_scale_sharding(mesh)
                 for leaf in jax.tree_util.tree_leaves(scale_pool):
                     findings.extend(sharding_divisibility(
                         f"kv_scale_pool[{model}/{mesh_name}]",
-                        tuple(leaf.shape), kv_sh,
+                        tuple(leaf.shape),
+                        kv_sh if leaf.ndim == 5 else scale_sh,
                     ))
         return findings
 

@@ -81,6 +81,7 @@ from ..models.transformer import forward_slots, unembed
 from ..parallel.mesh import MeshConfig, create_mesh
 from ..parallel.sharding import (
     init_sharded_params,
+    kv_scale_sharding,
     paged_kv_sharding,
     shard_params,
 )
@@ -101,10 +102,10 @@ from .kv_cache import (
     KVWireError,
     PagedKV,
     SlotState,
-    fold_heads,
+    fold_pages,
     init_paged_kv,
     init_slot_state,
-    unfold_heads,
+    unfold_pages,
 )
 from .metrics import EngineMetrics, RequestTimings
 from .prefix_cache import TIER_DEVICE, TIER_HOST
@@ -449,53 +450,35 @@ def _retire_lane_fn(last_tokens, seq_lens, page_tables, active, caps, slot):
     )
 
 
-def _kv_restore_fn(paged: PagedKV, idx, k, v):
+def _kv_restore_fn(paged: PagedKV, idx, pages: PagedKV):
     """Scatter handed-off page contents into the pool at the target's
-    own page ids (ISSUE 13 decode-side restore). `k`/`v` arrive in the
-    stored layout, [L, P, page_size, Hk·D]: the host folds its
-    [..., Hk, D] pages (kv_cache.fold_heads, a view) before the upload,
-    so nothing is relaid out on the device. `idx`/`k`/`v` are
+    own page ids (ISSUE 13 decode-side restore). `pages` arrives in the
+    stored layout, kv [L, P, 2, page_size, Hk·D] (and, for int8 KV, the
+    two scale pools' pages, restored with it byte for byte): the host
+    folds its K and V pages (kv_cache.fold_pages) before the upload,
+    so nothing is relaid out on the device. `idx` and `pages` are
     padded to a FIXED width (pages_per_seq) so one compiled executable
     serves every handoff size — pad rows target the reserved garbage
     page 0, whose contents are never read (inactive lanes write it
     constantly anyway). The pool is donated: the restore is an in-place
     page write ordered after every in-flight dispatch through the
     donation chain, exactly like a prefill's KV writes."""
-    return paged.replace(
-        k=paged.k.at[:, idx].set(k), v=paged.v.at[:, idx].set(v)
-    )
+    return jax.tree.map(lambda pool, new: pool.at[:, idx].set(new), paged, pages)
 
 
-def _kv_restore_quant_fn(paged: PagedKV, idx, k, v, ks, vs):
-    """Int8 pair-form variant of `_kv_restore_fn`: the value pools and
-    their bf16 scale pools restore together, byte-for-byte."""
-    return paged.replace(
-        k=paged.k.at[:, idx].set(k), v=paged.v.at[:, idx].set(v),
-        ks=paged.ks.at[:, idx].set(ks), vs=paged.vs.at[:, idx].set(vs),
-    )
-
-
-def _kv_gather_fn(paged: PagedKV, idx):
+def _kv_gather_fn(paged: PagedKV, idx) -> PagedKV:
     """Gather page contents out of the pool for host-tier eviction
     (ISSUE 15) — the read half of the fixed-width gather/scatter pair
     whose write half is `_kv_restore_fn`; pages leave in the stored
-    layout and the host unfolds them (kv_cache.unfold_heads). `idx` is
+    layout (a PagedKV of page-wide arrays, the int8 scale pools' pages
+    with it) and the host unfolds them (kv_cache.unfold_pages). `idx` is
     padded to pages_per_seq (pad rows read the reserved garbage page 0
     and are discarded host-side), so ONE compiled executable serves every spill
     batch — the GL001 discipline. Read-only: the pool is NOT donated
     (the gathered copy leaves, the pool stays), so in-flight decode
     blocks are unaffected and the copy observes the donation-chain
     ordering of every dispatch issued before it."""
-    return jnp.take(paged.k, idx, axis=1), jnp.take(paged.v, idx, axis=1)
-
-
-def _kv_gather_quant_fn(paged: PagedKV, idx):
-    """Int8 pair-form variant of `_kv_gather_fn`: values and their bf16
-    scale pools gather together, byte-for-byte."""
-    return (
-        jnp.take(paged.k, idx, axis=1), jnp.take(paged.v, idx, axis=1),
-        jnp.take(paged.ks, idx, axis=1), jnp.take(paged.vs, idx, axis=1),
-    )
+    return jax.tree.map(lambda pool: jnp.take(pool, idx, axis=1), paged)
 
 
 _MAX_PREFILL_GROUP = 8   # rows batched per prefill dispatch
@@ -784,13 +767,10 @@ class InferenceEngine:
                 "kv_dtype=int8 (POLYKEY_KV_DTYPE) does not lower on TPU "
                 f"yet: {INT8_KV_MOSAIC_ERROR}"
             )
-        pool_sh = paged_kv_sharding(self.mesh)
-        if self._kv_quantized:
-            self._pool_sharding = PagedKV(
-                k=pool_sh, v=pool_sh, ks=pool_sh, vs=pool_sh
-            )
-        else:
-            self._pool_sharding = PagedKV(k=pool_sh, v=pool_sh)
+        scale_sh = kv_scale_sharding(self.mesh) if self._kv_quantized else None
+        self._pool_sharding = PagedKV(
+            kv=paged_kv_sharding(self.mesh), ks=scale_sh, vs=scale_sh
+        )
         self._repl = NamedSharding(self.mesh, PartitionSpec())
         # Sequence-parallel prefill: the window's token axis shards over
         # sp, spreading prefill compute across chips; the page pools are
@@ -858,7 +838,7 @@ class InferenceEngine:
         # every other pool-touching dispatch; the fixed padded width
         # (pages_per_seq) keeps it ONE executable per engine.
         self._jit_kv_restore = jax.jit(
-            _kv_restore_quant_fn if self._kv_quantized else _kv_restore_fn,
+            _kv_restore_fn,
             donate_argnames=("paged",),
             out_shardings=self._pool_sharding,
         )
@@ -866,10 +846,8 @@ class InferenceEngine:
         # gather/scatter pair (restore above is the write half). Same
         # fixed width (pages_per_seq), one executable; outputs land
         # replicated so the host copy is a straight np.asarray.
-        n_gather_out = 4 if self._kv_quantized else 2
         self._jit_kv_gather = jax.jit(
-            _kv_gather_quant_fn if self._kv_quantized else _kv_gather_fn,
-            out_shardings=(self._repl,) * n_gather_out,
+            _kv_gather_fn, out_shardings=self._repl,
         )
         # Per-request RNG roots for seedless requests (GenRequest.seed
         # None): drawn once per admission from the engine seed.
@@ -2421,18 +2399,12 @@ class InferenceEngine:
             P = cfg.pages_per_seq
             idx0 = np.zeros((P,), np.int32)
             jax.block_until_ready(self._jit_kv_gather(self.paged, put(idx0)))
-            zk = np.zeros(
-                (self.model_cfg.num_layers, P, *self.paged.k.shape[2:]),
-                self.paged.k.dtype,
+            zeros = jax.tree.map(
+                lambda pool: put(np.zeros(
+                    (pool.shape[0], P, *pool.shape[2:]), pool.dtype)),
+                self.paged,
             )
-            operands = [put(idx0), put(zk), put(np.zeros_like(zk))]
-            if self._kv_quantized:
-                zs = np.zeros(
-                    (self.model_cfg.num_layers, P, *self.paged.ks.shape[2:]),
-                    self.paged.ks.dtype,
-                )
-                operands += [put(zs), put(np.zeros_like(zs))]
-            self.paged = self._jit_kv_restore(self.paged, *operands)
+            self.paged = self._jit_kv_restore(self.paged, put(idx0), zeros)
         jax.block_until_ready(self.paged)
         self._warm_compiles = {
             k: v - compiles_before[k] for k, v in compile_counts().items()
@@ -2689,12 +2661,10 @@ class InferenceEngine:
             idx = jnp.asarray(np.asarray(slot.pages[:n_kv], np.int32))
             with _host_crossing("handoff-export"):
                 # polylint: disable=PL008(handoff export: deliberate one-shot gather; prefill_only cold path never taken by in-process serving)
-                k = np.asarray(jnp.take(self.paged.k, idx, axis=1))
-                # polylint: disable=PL008(handoff export gather; prefill_only cold path)
-                v = np.asarray(jnp.take(self.paged.v, idx, axis=1))
-                # The wire format keeps the heads apart (kv_cache.py).
-                k = unfold_heads(k, self.model_cfg.head_dim)
-                v = unfold_heads(v, self.model_cfg.head_dim)
+                kv = np.asarray(jnp.take(self.paged.kv, idx, axis=1))
+                # The wire format keeps K and V, and the heads, apart
+                # (kv_cache.py).
+                k, v = unfold_pages(kv, self.model_cfg.head_dim)
                 ks = vs = None
                 if self.paged.quantized:
                     # polylint: disable=PL008(handoff export gather; prefill_only cold path)
@@ -2737,10 +2707,10 @@ class InferenceEngine:
             state.validate_for(
                 self.model_cfg, cfg.page_size, self._kv_quantized
             )
-            if jnp.dtype(state.k.dtype) != self.paged.k.dtype:
+            if jnp.dtype(state.k.dtype) != self.paged.kv.dtype:
                 raise KVWireError(
                     f"kv-handoff pool dtype mismatch: blob "
-                    f"{state.k.dtype}, target {self.paged.k.dtype}"
+                    f"{state.k.dtype}, target {self.paged.kv.dtype}"
                 )
         except KVWireError as e:
             # _admit wraps as "admission failed: kv-handoff ..." — the
@@ -2778,16 +2748,18 @@ class InferenceEngine:
             return out
 
         try:
-            put = partial(jax.device_put, device=self._repl)
-            operands = [put(idx), put(fold_heads(_pad(state.k))),
-                        put(fold_heads(_pad(state.v)))]
-            if self._kv_quantized:
-                operands += [put(_pad(state.ks)), put(_pad(state.vs))]
             # _host_crossing: the padded page payload rides up as one
             # deliberate upload (the handoff's whole point).
             with _host_crossing("handoff-restore"):
                 request.timings.prefill_dispatched = time.monotonic()
-                self.paged = self._jit_kv_restore(self.paged, *operands)
+                self.paged = self._jit_kv_restore(
+                    self.paged, jax.device_put(idx, self._repl),
+                    self._page_upload(
+                        _pad(state.k), _pad(state.v),
+                        _pad(state.ks) if self._kv_quantized else None,
+                        _pad(state.vs) if self._kv_quantized else None,
+                    ),
+                )
         except Exception as e:
             self.allocator.release_all(pages)
             raise RuntimeError(f"kv-handoff restore failed: {e}") from e
@@ -2938,6 +2910,17 @@ class InferenceEngine:
         self._note_sched_frontier("restore", served)
         return issued
 
+    def _page_upload(self, k, v, ks, vs) -> PagedKV:
+        """`_jit_kv_restore`'s page operand from host arrays padded to
+        pages_per_seq: K and V (heads apart, as the host tier and the
+        wire keep them) folded into the stored layout, the int8 scale
+        pages beside them as they are."""
+        put = partial(jax.device_put, device=self._repl)
+        pages = PagedKV(kv=put(fold_pages(k, v)))
+        if self._kv_quantized:
+            pages = pages.replace(ks=put(ks), vs=put(vs))
+        return pages
+
     def _restore_slot_pages(self, slot_idx: int, slot: _Slot) -> None:
         """One faulting slot's restore: copy its host pages into the
         fixed-width upload buffers, scatter them into the slot's own
@@ -2953,7 +2936,7 @@ class InferenceEngine:
         idx = np.zeros((P,), np.int32)        # pad rows → garbage page 0
         k = np.zeros((self.model_cfg.num_layers, P, cfg.page_size,
                       self.model_cfg.num_kv_heads,
-                      self.model_cfg.head_dim), self.paged.k.dtype)
+                      self.model_cfg.head_dim), self.paged.kv.dtype)
         v = np.zeros_like(k)
         ks = vs = None
         if self._kv_quantized:
@@ -2968,14 +2951,13 @@ class InferenceEngine:
                 ks[:, r] = hks
                 vs[:, r] = hvs
         try:
-            put = partial(jax.device_put, device=self._repl)
-            operands = [put(idx), put(fold_heads(k)), put(fold_heads(v))]
-            if self._kv_quantized:
-                operands += [put(ks), put(vs)]
             # _host_crossing: the page payload rides up as one
             # deliberate upload — the page fault's whole point.
             with _host_crossing("kv-fault-restore"):
-                self.paged = self._jit_kv_restore(self.paged, *operands)
+                self.paged = self._jit_kv_restore(
+                    self.paged, jax.device_put(idx, self._repl),
+                    self._page_upload(k, v, ks, vs),
+                )
         except Exception as e:
             # Host copies are untouched on failure; _finish re-adopts
             # them into the cache so the warmth survives this slot.
@@ -3027,19 +3009,16 @@ class InferenceEngine:
         idx[:len(cands)] = [page for _, page in cands]
         outs = self._jit_kv_gather(self.paged, jax.device_put(idx, self._repl))
         with _host_crossing("kv-evict-gather"):
+            # The host tier keeps K and V, and the heads, apart
+            # (kv_cache.HostKVPool).
             # polylint: disable=PL008(eviction gather resolve: one packed D2H read per spill batch; cold path, reached from dispatch only via _finish under the resident-floor check)
-            k = np.asarray(outs[0])
-            # polylint: disable=PL008(spill gather read, same cold path)
-            v = np.asarray(outs[1])
-            # The host tier keeps the heads apart (kv_cache.HostKVPool).
-            k = unfold_heads(k, self.model_cfg.head_dim)
-            v = unfold_heads(v, self.model_cfg.head_dim)
+            k, v = unfold_pages(np.asarray(outs.kv), self.model_cfg.head_dim)
             ks = vs = None
             if self._kv_quantized:
                 # polylint: disable=PL008(spill gather read, same cold path)
-                ks = np.asarray(outs[2])
+                ks = np.asarray(outs.ks)
                 # polylint: disable=PL008(spill gather read, same cold path)
-                vs = np.asarray(outs[3])
+                vs = np.asarray(outs.vs)
         moved: list[tuple[bytes, int]] = []   # (key, gather row)
         for r, (key, _page) in enumerate(cands):
             try:

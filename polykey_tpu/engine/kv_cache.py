@@ -6,19 +6,26 @@ fixed-size pages inside one preallocated pool per layer, so sequences grow
 without reallocation or fragmentation, and the decode batch is composed by
 page-table indirection rather than copying.
 
-Stored layout (per K and V), stated here once and pointed to elsewhere:
-[num_layers, num_pages, page_size, num_kv_heads · head_dim] — heads FOLDED
-into the last (lane) dimension, head-major, so a `tp` shard of that
-dimension is whole heads and one page is a contiguous, 128-lane-aligned
-[page_size, Hk·D] slab: what both Pallas kernels DMA, with no reshape of a
-pool anywhere (under the TPU's tiled layout splitting the last dimension is
-a relayout of the whole pool, not a bitcast). The model step views the stack
-as [L·N, page_size, Hk·D] (a merge of leading dimensions only) and addresses
-page (layer, page) as `layer · N + page` — models/transformer.py
-`_run_paged_stack`. int8-KV scale pools are [L, N, page_size, Hk]. The host
-tier (HostKVPool) and the handoff wire format (KVHandoffState) keep the
-heads apart, [..., Hk, D]; `fold_heads` / `unfold_heads` convert page-sized
-host arrays at that boundary.
+Stored layout, stated here once and pointed to elsewhere: ONE array
+[num_layers, num_pages, 2, page_size, num_kv_heads · head_dim] — K at index
+0 and V at index 1 of a page, heads FOLDED into the last (lane) dimension,
+head-major, so a `tp` shard of that dimension is whole heads and one page is
+a contiguous, 128-lane-aligned [2, page_size, Hk·D] slab: what both Pallas
+kernels DMA under ONE descriptor a page (a descriptor costs ~10 ns on a v5e
+whatever it carries, as much as the bytes of a tp shard's 8 KB page half: K
+and V pools apart paid it twice a page — PERF.md §6, PR 46), with no
+reshape of a pool anywhere (under the TPU's tiled layout splitting the last
+dimension is a relayout of the whole pool, not a bitcast). The model step
+views the stack as its page halves, [L·N·2, page_size, Hk·D] (a merge of
+leading dimensions only: page p's K at 2p, its V at 2p + 1 — the kernels
+take both under one descriptor, the XLA gathers and scatters each by one
+index), and addresses page (layer, page) as `layer · N + page` —
+models/transformer.py `_run_paged_stack`. int8-KV scale pools stay two,
+[L, N, page_size, Hk] each. The host tier (HostKVPool) and the handoff wire
+format (KVHandoffState) keep K and V in arrays of their own with the heads
+apart, [..., Hk, D]; `fold_pages` / `unfold_pages` convert page-sized host
+arrays at that boundary, so no byte of a host page or a wire blob follows
+the device's layout.
 
 The allocator is host-side bookkeeping: the C++ implementation
 (native/block_allocator.cc, loaded via ctypes) with a pure-Python fallback of
@@ -149,10 +156,11 @@ class BlockAllocator:
 
 @struct.dataclass
 class PagedKV:
-    """Device-side page pools: k/v [L, num_pages, page_size, Hk·D] (heads
-    folded into lanes — the stored layout, module docstring).
+    """Device-side page pool: kv [L, num_pages, 2, page_size, Hk·D] (K and V
+    of a page side by side, heads folded into lanes — the stored layout,
+    module docstring).
 
-    With int8 KV (EngineConfig.kv_dtype="int8") k/v hold int8 values and
+    With int8 KV (EngineConfig.kv_dtype="int8") kv holds int8 values and
     ks/vs hold per-(token, head) bf16 scales [L, num_pages, page_size, Hk]
     — symmetric absmax over the head_dim axis, quantized at write time
     (ops/paged_attention.paged_write) and dequantized at read time. The
@@ -161,18 +169,17 @@ class PagedKV:
     ks/vs are None for fp pools (an empty pytree subtree — the fp paths
     never see extra buffers)."""
 
-    k: jax.Array
-    v: jax.Array
+    kv: jax.Array
     ks: Optional[jax.Array] = None
     vs: Optional[jax.Array] = None
 
     @property
     def page_size(self) -> int:
-        return self.k.shape[2]
+        return self.kv.shape[3]
 
     @property
     def num_pages(self) -> int:
-        return self.k.shape[1]
+        return self.kv.shape[1]
 
     @property
     def quantized(self) -> bool:
@@ -225,28 +232,40 @@ def init_paged_kv(
     """`kv_dtype=jnp.int8` builds quantized pools (+ bf16 scale pools);
     None keeps the full-precision layout in `dtype`. One pool layer for
     each layer that attends (a hybrid stack's "*" layers)."""
-    shape = (cfg.kv_layers, num_pages, page_size,
+    shape = (cfg.kv_layers, num_pages, 2, page_size,
              cfg.num_kv_heads * cfg.head_dim)
     if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
-        sshape = shape[:-1] + (cfg.num_kv_heads,)
+        sshape = (cfg.kv_layers, num_pages, page_size, cfg.num_kv_heads)
         return PagedKV(
-            k=jnp.zeros(shape, jnp.int8), v=jnp.zeros(shape, jnp.int8),
+            kv=jnp.zeros(shape, jnp.int8),
             ks=jnp.zeros(sshape, jnp.bfloat16),
             vs=jnp.zeros(sshape, jnp.bfloat16),
         )
-    return PagedKV(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    return PagedKV(kv=jnp.zeros(shape, dtype))
 
 
 def fold_heads(pages):
-    """[..., Hk, D] → [..., Hk·D]: host-tier / wire pages into the stored
-    layout (a view on a contiguous numpy array)."""
+    """[..., Hk, D] → [..., Hk·D]: rows or pages with the heads apart into
+    the stored lane fold (a view on a contiguous numpy array)."""
     return pages.reshape(*pages.shape[:-2], -1)
 
 
 def unfold_heads(pages, head_dim: int):
-    """[..., Hk·D] → [..., Hk, D]: gathered pool pages into the host-tier /
-    wire layout."""
+    """[..., Hk·D] → [..., Hk, D]: the inverse of `fold_heads`."""
     return pages.reshape(*pages.shape[:-1], -1, head_dim)
+
+
+def fold_pages(k: np.ndarray, v: np.ndarray) -> np.ndarray:
+    """Host-tier / wire pages, K and V apart [..., page_size, Hk, D], into
+    the stored layout [..., 2, page_size, Hk·D] for an upload."""
+    return np.stack([fold_heads(k), fold_heads(v)], axis=-3)
+
+
+def unfold_pages(kv: np.ndarray, head_dim: int) -> tuple:
+    """Gathered pool pages [..., 2, page_size, Hk·D] into the host-tier /
+    wire layout: (k, v), each [..., page_size, Hk, D]."""
+    pages = unfold_heads(kv, head_dim)
+    return pages[..., 0, :, :, :], pages[..., 1, :, :, :]
 
 
 def kv_pool_bytes(

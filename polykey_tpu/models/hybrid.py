@@ -324,29 +324,28 @@ def mamba_prefill(p: dict, u, cfg: ModelConfig, ssm, conv,
 
 
 def attention_layer(p: dict, h, positions, cfg: ModelConfig, attend, idx,
-                    kc, vc):
+                    pool):
     B, T, _ = h.shape
     q, k, v = qkv_project(p, h, cfg)
     if cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
-    ctx, kc, vc = attend(jnp.int32(idx), q, k, v, kc, vc)
+    ctx, pool = attend(jnp.int32(idx), q, k, v, pool)
     out = qdot(ctx.reshape(B, T, cfg.num_heads * cfg.head_dim), p["wo"])
-    return out, kc, vc
+    return out, pool
 
 
-def run_stack(params, cfg: ModelConfig, tokens, positions, pools, attend,
+def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
               state=None, rows=None, active=None):
-    """embed → the pattern's layers, unrolled → final norm. `pools` is the
-    (kc, vc) pair `attend` threads (models/transformer.py); `state` the
+    """embed → the pattern's layers, unrolled → final norm. `pool` is the
+    stacked page pool `attend` threads (models/transformer.py); `state` the
     per-slot SlotState of a stateful pattern; a prefill dispatch says
     what each row does with it (`rows`, a PrefillRows), a decode step
     (T = 1, one row a lane; `rows` None) which lanes are live (`active`).
-    Returns (hidden, pools, state)."""
+    Returns (hidden, pool, state)."""
     decode = rows is None
     eps = cfg.rms_norm_eps
     x = embed_lookup(params["embed"], tokens)
-    kc, vc = pools
     ssm = list(state.ssm) if state is not None else []
     conv = list(state.conv) if state is not None else []
     for kind, idx in layer_kinds(cfg):
@@ -361,12 +360,12 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pools, attend,
                 out, ssm[idx], conv[idx] = mamba_prefill(
                     p, h, cfg, ssm[idx], conv[idx], rows)
         elif kind == "attention":
-            out, kc, vc = attention_layer(
-                p, h, positions, cfg, attend, idx, kc, vc)
+            out, pool = attention_layer(
+                p, h, positions, cfg, attend, idx, pool)
         else:
             out = moe_latent_held(p, h, cfg)
         x = x + out
     x = rms_norm(x, params["final_norm"], eps)
     if state is not None:
         state = state.replace(ssm=tuple(ssm), conv=tuple(conv))
-    return x, (kc, vc), state
+    return x, pool, state

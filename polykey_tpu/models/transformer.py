@@ -161,12 +161,14 @@ def embed_tokens(params: dict, cfg: ModelConfig, tokens: jax.Array) -> jax.Array
     return x
 
 
-def apply_layer(layer_params, layer_idx, x, positions, cfg: ModelConfig, attend, kc, vc):
+def apply_layer(layer_params, layer_idx, x, positions, cfg: ModelConfig, attend, cache):
     """One transformer block at absolute layer index `layer_idx`.
 
     Norms, projections, RoPE, residuals, MLP/MoE, and Gemma post-norms live
     here; the KV mechanics are injected via
-    `attend(layer_idx, q, k, v, kc, vc) → (ctx, kc, vc)`. Shared by the
+    `attend(layer_idx, q, k, v, cache) → (ctx, cache)`, `cache` whatever
+    pytree the caller threads (a layer's contiguous (k, v), the paged
+    stack, nothing). Shared by the
     scanned stack (_run_stack) and the pipeline-parallel stage bodies
     (parallel/pipeline.py), so a stage runs the exact computation the
     unsharded stack runs.
@@ -180,7 +182,7 @@ def apply_layer(layer_params, layer_idx, x, positions, cfg: ModelConfig, attend,
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
 
-    ctx, kc, vc = attend(layer_idx, q, k, v, kc, vc)
+    ctx, cache = attend(layer_idx, q, k, v, cache)
 
     attn_out = ctx.reshape(B, T, cfg.num_heads * cfg.head_dim)
     attn_out = qdot(attn_out, layer_params["attn"]["wo"])
@@ -197,7 +199,7 @@ def apply_layer(layer_params, layer_idx, x, positions, cfg: ModelConfig, attend,
         mlp_out = rms_norm(mlp_out, layer_params["post_ln2"], eps, norm_offset)
     x = x + mlp_out
 
-    return x, kc, vc
+    return x, cache
 
 
 def _run_stack(params, cfg: ModelConfig, tokens, positions, kv_scanned, attend):
@@ -208,30 +210,30 @@ def _run_stack(params, cfg: ModelConfig, tokens, positions, kv_scanned, attend):
     x = embed_tokens(params, cfg, tokens)
 
     def body(x, scanned):
-        layer_params, layer_idx, kc, vc = scanned
-        x, kc, vc = apply_layer(
-            layer_params, layer_idx, x, positions, cfg, attend, kc, vc
+        layer_params, layer_idx, cache = scanned
+        return apply_layer(
+            layer_params, layer_idx, x, positions, cfg, attend, cache
         )
-        return x, (kc, vc)
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], layer_ids) + kv_scanned
+        body, x, (params["layers"], layer_ids, kv_scanned)
     )
     x = rms_norm(x, params["final_norm"], eps, norm_offset)
     return x, new_k, new_v
 
 
 def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged, attend):
-    """The stack over the paged KV pools: embed → scan(layer body) → final
-    norm, with the WHOLE stacked pools in the scan carry; returns (hidden,
+    """The stack over the paged KV pool: embed → scan(layer body) → final
+    norm, with the WHOLE stacked pool in the scan carry; returns (hidden,
     updated paged).
 
-    The pools are stored [L, N, page_size, Hk·D] (engine/kv_cache.py) and
-    carried as [L·N, page_size, Hk·D] — a merge of leading dimensions, a
-    bitcast under any tiling. `attend(layer_idx, q, k, v, kc, vc)` gets that
-    whole stack as kc / vc ((values, scales) pairs for int8 KV) and
-    addresses page (layer, page) as `layer_idx · N + page`
+    The pool is stored [L, N, 2, page_size, Hk·D] (engine/kv_cache.py) and
+    carried as its page halves, [L·N·2, page_size, Hk·D] — a merge of
+    leading dimensions, a bitcast under any tiling, made once outside the
+    scan. `attend(layer_idx, q, k, v, cache)` gets that
+    whole stack as `cache` (the (values, k scales, v scales) triple for
+    int8 KV) and addresses page (layer, page) as `layer_idx · N + page`
     (`_layer_tables`): the write kernel aliases the stack, the XLA scatters
     update it in place in the carry, the decode kernel DMAs single pages out
     of it. No layer's pool is ever sliced out, copied or written back, so a
@@ -241,33 +243,46 @@ def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged, attend)
     Not scanned as xs/ys (the way _run_stack scans a contiguous cache):
     that makes XLA build the updated stack in a second full-size buffer."""
     norm_offset = 1.0 if cfg.scale_embeddings else 0.0
-    if paged.quantized:
-        # int8 KV: the cache operand is a (values, scales) pair; the
-        # write/read ops dispatch on the pair form.
-        stored = ((paged.k, paged.ks), (paged.v, paged.vs))
-    else:
-        stored = (paged.k, paged.v)
-    pools = jax.tree.map(lambda p: p.reshape(-1, *p.shape[2:]), stored)
-
     x = embed_tokens(params, cfg, tokens)
 
     def body(carry, scanned):
-        x, (kc, vc) = carry
+        x, pool = carry
         layer_params, layer_idx = scanned
-        x, kc, vc = apply_layer(
-            layer_params, layer_idx, x, positions, cfg, attend, kc, vc
-        )
-        return (x, (kc, vc)), None
+        return apply_layer(
+            layer_params, layer_idx, x, positions, cfg, attend, pool
+        ), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
-    (x, pools), _ = jax.lax.scan(
-        body, (x, pools), (params["layers"], layer_ids)
+    (x, pool), _ = jax.lax.scan(
+        body, (x, _stacked(paged)), (params["layers"], layer_ids)
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, norm_offset)
-    kc, vc = jax.tree.map(lambda p, like: p.reshape(like.shape), pools, stored)
+    return x, _unstacked(paged, pool)
+
+
+def _stacked(paged):
+    """The pool as the model step carries it and the ops take it: every
+    leaf with its leading dimensions merged down to [·, page_size, ·] (a
+    bitcast) — the kv array as page halves [L·N·2, page_size, Hk·D], page
+    p's K at 2p and its V at 2p + 1 — alone, or in the int8 (values,
+    k scales, v scales) triple the ops dispatch on."""
+    def merge(p):
+        return p.reshape(-1, *p.shape[-2:])
+
     if paged.quantized:
-        return x, paged.replace(k=kc[0], v=vc[0], ks=kc[1], vs=vc[1])
-    return x, paged.replace(k=kc, v=vc)
+        return merge(paged.kv), merge(paged.ks), merge(paged.vs)
+    return merge(paged.kv)
+
+
+def _unstacked(paged, pool):
+    """`_stacked`'s inverse: the carried pool back under `paged`'s shapes."""
+    if paged.quantized:
+        kv, ks, vs = pool
+        return paged.replace(
+            kv=kv.reshape(paged.kv.shape), ks=ks.reshape(paged.ks.shape),
+            vs=vs.reshape(paged.vs.shape),
+        )
+    return paged.replace(kv=pool.reshape(paged.kv.shape))
 
 
 def _layer_tables(paged, layer_idx, tables: jax.Array) -> jax.Array:
@@ -285,7 +300,7 @@ def make_causal_attend(cfg: ModelConfig, positions: jax.Array):
     q_pos = positions[:, :, None]                       # [B, T, 1]
     kv_pos = positions[:, None, :]                      # [B, 1, S]
 
-    def attend(layer_idx, q, k, v, kc, vc):
+    def attend(layer_idx, q, k, v, cache):
         mask = kv_pos <= q_pos
         window = _layer_window(cfg, layer_idx)
         if window is not None:
@@ -294,7 +309,7 @@ def make_causal_attend(cfg: ModelConfig, positions: jax.Array):
             q, k, v, mask,
             scale=cfg.q_scale, logit_softcap=cfg.attn_logit_softcap,
         )
-        return ctx, kc, vc
+        return ctx, cache
 
     return attend
 
@@ -330,7 +345,8 @@ def forward(
         # falls back to the reference attention off-TPU and for tiny shapes.
         from ..ops.flash_attention import flash_attention
 
-        def attend(layer_idx, q, k, v, kc, vc):
+        def attend(layer_idx, q, k, v, cache):
+            kc, vc = cache
             kc = kc.at[batch_idx, positions].set(k)
             vc = vc.at[batch_idx, positions].set(v)
             ctx = flash_attention(
@@ -339,16 +355,16 @@ def forward(
                 logit_softcap=cfg.attn_logit_softcap,
                 window=_layer_window(cfg, layer_idx),
             )
-            return ctx, kc, vc
+            return ctx, (kc, vc)
 
         kv_scanned = (cache.k, cache.v)
     else:
         causal = make_causal_attend(cfg, positions)
 
-        def attend(layer_idx, q, k, v, kc, vc):
+        def attend(layer_idx, q, k, v, cache):
             if attn_override is not None:
-                return attn_override(layer_idx, q, k, v), kc, vc
-            return causal(layer_idx, q, k, v, kc, vc)
+                return attn_override(layer_idx, q, k, v), cache
+            return causal(layer_idx, q, k, v, cache)
 
         empty = jnp.zeros((cfg.num_layers, 0), dtype=jnp.float32)
         kv_scanned = (empty, empty)
@@ -413,21 +429,21 @@ def forward_slots(
 
     decode = tokens.shape[1] == 1
 
-    def attend(layer_idx, q, k, v, kc, vc):
+    def attend(layer_idx, q, k, v, pool):
         tables = _layer_tables(paged, layer_idx, page_tables)
-        kc, vc = paged_write(kc, vc, k, v, tables, positions, mesh=mesh)
+        pool = paged_write(pool, k, v, tables, positions, mesh=mesh)
         # Single-token steps take the DMA decode kernel (reads only valid
         # pages); prefill buckets take the gather path (wide T amortizes
         # the window materialization, and flash covers contiguous prefill).
         op = paged_attention_decode if decode else paged_attention
         ctx = op(
-            q, kc, vc, tables, positions,
+            q, pool, tables, positions,
             scale=cfg.q_scale,
             logit_softcap=cfg.attn_logit_softcap,
             window=_layer_window(cfg, layer_idx),
             mesh=mesh,
         )
-        return ctx, kc, vc
+        return ctx, pool
 
     if not cfg.layer_pattern:
         hidden, paged = _run_paged_stack(
@@ -438,15 +454,11 @@ def forward_slots(
         raise ValueError("a layer pattern has no int8-KV path")
     from .hybrid import run_stack
 
-    pools = (paged.k.reshape(-1, *paged.k.shape[2:]),
-             paged.v.reshape(-1, *paged.v.shape[2:]))
-    hidden, (kc, vc), state = run_stack(
-        params, cfg, tokens, positions, pools, attend, state, rows, active
+    hidden, pool, state = run_stack(
+        params, cfg, tokens, positions, _stacked(paged), attend, state,
+        rows, active,
     )
-    paged = paged.replace(
-        k=kc.reshape(paged.k.shape), v=vc.reshape(paged.v.shape)
-    )
-    return hidden, paged, state
+    return hidden, _unstacked(paged, pool), state
 
 
 def make_sp_override(

@@ -11,10 +11,17 @@ Page-table convention (engine/kv_cache.py): page_tables[b, j] is the page id
 holding positions [j*page_size, (j+1)*page_size); unused tail entries point
 at the reserved garbage page 0 and are excluded by the position mask.
 
-Pools arrive in the stored layout (engine/kv_cache.py): [N, page_size, Hk·D],
-heads folded into the last dimension. Every path here folds or unfolds the
-ROWS it writes or the pages it gathered — never a pool. N may be the whole
-stack's L·num_pages with the caller's page ids offset by layer · num_pages
+The pool arrives in the stored layout (engine/kv_cache.py) viewed as page
+HALVES: ONE array [2N, page_size, Hk·D], page p's K at 2p and its V at
+2p + 1, heads folded into the last dimension — the stored
+[N, 2, page_size, Hk·D] with its leading dimensions merged; int8 KV is the
+triple (values, k scales, v scales) with scale pools [N, page_size, Hk].
+The XLA paths address a half by ONE index, as when K and V were pools
+apart (a second, K / V index component made a prefill dispatch 0.7–3.5 ms
+longer on a v5e: PERF.md §6, PR 46); the Pallas kernels take a page's two
+halves under one descriptor. Every path here folds or unfolds the ROWS it
+writes or the pages it gathered — never a pool. N may be the whole stack's
+L·num_pages with the caller's page ids offset by layer · num_pages
 (models/transformer.py `_run_paged_stack`).
 """
 
@@ -27,16 +34,18 @@ import jax.numpy as jnp
 
 
 def paged_gather_kv(
-    k_pages: jax.Array,       # [num_pages, page_size, Hk·D]
-    v_pages: jax.Array,
+    kv_pages: jax.Array,      # [2 · num_pages, page_size, Hk·D]
     page_tables: jax.Array,   # [B, P] int32
     head_dim: int,
 ) -> tuple[jax.Array, jax.Array]:
-    """Materialize [B, P*page_size, Hk, D] K/V windows from the pools."""
+    """Materialize [B, P*page_size, Hk, D] K/V windows from the pool.
+
+    Two gathers of page halves: one gather of whole pages followed by a
+    slice would write every window a second time."""
     B, P = page_tables.shape
-    page_size = k_pages.shape[1]
-    k = k_pages[page_tables]  # [B, P, page_size, Hk·D]
-    v = v_pages[page_tables]
+    page_size = kv_pages.shape[1]
+    k = kv_pages[2 * page_tables]  # [B, P, page_size, Hk·D]
+    v = kv_pages[2 * page_tables + 1]
     return (
         k.reshape(B, P * page_size, -1, head_dim),
         v.reshape(B, P * page_size, -1, head_dim),
@@ -45,8 +54,8 @@ def paged_gather_kv(
 
 def paged_attention(
     q: jax.Array,             # [B, T, Hq, D]
-    k_pages: jax.Array,       # [num_pages, page_size, Hk·D]
-    v_pages: jax.Array,
+    kv_pages,                 # [2 · num_pages, page_size, Hk·D], or the
+                              # int8 (values, k scales, v scales) triple
     page_tables: jax.Array,   # [B, P]
     q_positions: jax.Array,   # [B, T] absolute positions of the queries
     *,
@@ -66,13 +75,12 @@ def paged_attention(
     """
     from .flash_attention import flash_attention
 
-    if isinstance(k_pages, tuple):
-        # int8 KV pools (values, scales): gather both, dequantize into
-        # the compute dtype — the dequant is an elementwise producer XLA
-        # fuses into the window consumers, and the pool-side HBM read
-        # stays int8.
-        (kq, ks_pool), (vq, vs_pool) = k_pages, v_pages
-        k, v = paged_gather_kv(kq, vq, page_tables, q.shape[-1])
+    if isinstance(kv_pages, tuple):
+        # int8 KV: gather values and scales, dequantize into the compute
+        # dtype — the dequant is an elementwise producer XLA fuses into
+        # the window consumers, and the pool-side HBM read stays int8.
+        values, ks_pool, vs_pool = kv_pages
+        k, v = paged_gather_kv(values, page_tables, q.shape[-1])
         B, P = page_tables.shape
         ps, Hk = ks_pool.shape[1], ks_pool.shape[2]
         ks = ks_pool[page_tables].reshape(B, P * ps, Hk)
@@ -80,7 +88,7 @@ def paged_attention(
         k = dequantize_kv(k, ks, q.dtype)
         v = dequantize_kv(v, vs, q.dtype)
     else:
-        k, v = paged_gather_kv(k_pages, v_pages, page_tables, q.shape[-1])
+        k, v = paged_gather_kv(kv_pages, page_tables, q.shape[-1])
     return flash_attention(
         q, k, v, q_positions,
         scale=scale, logit_softcap=logit_softcap, window=window, mesh=mesh,
@@ -110,25 +118,25 @@ def dequantize_kv(values: jax.Array, scales: jax.Array, dtype) -> jax.Array:
 
 
 def paged_write(
-    k_pages,                  # [num_pages, page_size, Hk·D], or a
-                              # (values, scales) pair for int8 KV pools
-    v_pages,
+    kv_pages,                 # [2 · num_pages, page_size, Hk·D], or the
+                              # int8 (values, k scales, v scales) triple
     k_new: jax.Array,         # [B, T, Hk, D]
     v_new: jax.Array,
     page_tables: jax.Array,   # [B, P]
     positions: jax.Array,     # [B, T] absolute position of each new token
     mesh=None,
 ):
-    """Write new KV into their pages at (page_table[pos // ps], pos % ps).
+    """Write new KV into their pages at (page_table[pos // ps], pos % ps);
+    returns the pool in the form it came.
 
-    With int8 KV pools (`k_pages`/`v_pages` as (values, scales) pairs —
-    engine/kv_cache.py PagedKV.quantized) the rows quantize at write time
-    and the scale pools [N, ps, Hk] take the same write path as the data.
+    With int8 KV (the triple — engine/kv_cache.py PagedKV.quantized) the
+    rows quantize at write time and the scale pools [N, ps, Hk] take the
+    same write path as the data.
 
     Three paths, fastest applicable wins:
     - T == 1 on TPU: the Pallas DMA write kernel
       (ops/paged_write_kernel.py) — per-lane page RMW into the aliased
-      pools. The XLA scatter here lowers to a sequential per-row update
+      pool. The XLA scatter here lowers to a sequential per-row update
       loop that measured ~10 ms/step of a ~21 ms 1B decode step
       (scripts/profile_block_device.py); the kernel makes it ~free.
     - T > 1 with page-aligned consecutive rows (every engine prefill
@@ -138,36 +146,37 @@ def paged_write(
       (tests, non-bucket positions) still get exact semantics.
     - otherwise: the per-token XLA scatter.
     """
-    quantized = isinstance(k_pages, tuple)
+    quantized = isinstance(kv_pages, tuple)
     Hk, D = k_new.shape[2], k_new.shape[3]
 
     def fold(rows):           # [B, T, Hk, D] → [B, T, Hk·D], the pool's rows
         return rows.reshape(*rows.shape[:2], Hk * D)
 
-    # (pool, rows) pairs sharing one (page, offset) index layout.
+    # The data pool takes a K row and a V row a position, in the two
+    # halves of its page; each int8 scale pool one row, sharing the
+    # (page, offset) index layout.
     if quantized:
-        (kq, ks_pool), (vq, vs_pool) = k_pages, v_pages
+        data, *scale_pools = kv_pages
         k8, k_s = quantize_kv_rows(k_new)
         v8, v_s = quantize_kv_rows(v_new)
-        writes = [(kq, fold(k8)), (vq, fold(v8)),
-                  (ks_pool, k_s.astype(ks_pool.dtype)),
-                  (vs_pool, v_s.astype(vs_pool.dtype))]
-        data_pool = kq
+        kv_rows = (fold(k8), fold(v8))
+        scale_rows = tuple(
+            r.astype(p.dtype) for p, r in zip(scale_pools, (k_s, v_s)))
     else:
-        writes = [(k_pages, fold(k_new)), (v_pages, fold(v_new))]
-        data_pool = k_pages
+        data, scale_pools, scale_rows = kv_pages, (), ()
+        kv_rows = (fold(k_new), fold(v_new))
 
-    page_size = data_pool.shape[1]
+    page_size = data.shape[1]
     B, T = positions.shape
     P = page_tables.shape[1]
     batch_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
     page_ids = page_tables[batch_idx, positions // page_size]   # [B, T]
     offsets = positions % page_size                             # [B, T]
 
+    pools_in = (data, *scale_pools)
+
     def repack(pools):
-        if quantized:
-            return (pools[0], pools[2]), (pools[1], pools[3])
-        return pools[0], pools[1]
+        return tuple(pools) if quantized else pools[0]
 
     if T == 1:
         from .paged_attention_kernel import (
@@ -178,17 +187,25 @@ def paged_write(
         pp = mesh.shape.get("pp", 1) if mesh is not None else 1
         gate = use_quantized_paged_kernel if quantized else use_paged_kernel
         if gate(Hk, D) and pp == 1:
+            # A lane's rows as the kernel blends them into the entries of
+            # its page: [B, 2, 1, Hk·D] for the two halves, [B, 1, 1, Hk].
             return repack(_write_decode_kernel(
-                writes, page_ids[:, 0], offsets[:, 0], mesh, Hk,
+                list(pools_in),
+                [jnp.stack(kv_rows, axis=1), *(r[:, None] for r in scale_rows)],
+                page_ids[:, 0], offsets[:, 0], mesh, Hk,
             ))
 
+    # The XLA paths: K and V are two scatters into the one pool, each of
+    # the parent's form (rows stacked to match a whole page would be
+    # copied once more on the way).
     def token_scatter(pools):
-        return tuple(
-            p.at[page_ids, offsets].set(r)
-            for p, (_, r) in zip(pools, writes)
-        )
+        data, *scales = pools
+        for half, rows in enumerate(kv_rows):
+            data = data.at[2 * page_ids + half, offsets].set(rows)
+        return (data, *(
+            p.at[page_ids, offsets].set(r) for p, r in zip(scales, scale_rows)
+        ))
 
-    pools_in = tuple(p for p, _ in writes)
     if T > 1 and T % page_size == 0:
         n_pg = T // page_size
         consecutive = jnp.all(
@@ -196,18 +213,21 @@ def paged_write(
         )
         aligned = jnp.all(positions[:, 0] % page_size == 0) & consecutive
 
+        def pages(rows):      # [B, T, ·] → [B, n_pg, page_size, ·]
+            return rows.reshape(B, n_pg, page_size, *rows.shape[2:])
+
         def page_scatter(pools):
             first = positions[:, 0] // page_size                 # [B]
             pg_idx = first[:, None] + jnp.arange(n_pg, dtype=jnp.int32)
             pg_ids = jnp.take_along_axis(
                 page_tables, jnp.clip(pg_idx, 0, P - 1), axis=1
             )                                                    # [B, n_pg]
-            return tuple(
-                p.at[pg_ids].set(
-                    r.reshape(B, n_pg, page_size, *r.shape[2:])
-                )
-                for p, (_, r) in zip(pools, writes)
-            )
+            data, *scales = pools
+            for half, rows in enumerate(kv_rows):
+                data = data.at[2 * pg_ids + half].set(pages(rows))
+            return (data, *(
+                p.at[pg_ids].set(pages(r)) for p, r in zip(scales, scale_rows)
+            ))
 
         return repack(jax.lax.cond(
             aligned, page_scatter, token_scatter, pools_in
@@ -216,20 +236,18 @@ def paged_write(
     return repack(token_scatter(pools_in))
 
 
-def _write_decode_kernel(writes, page_ids, offsets, mesh, Hk):
-    """Dispatch the Pallas write kernel over (pool, rows) pairs, under
-    shard_map when the mesh shards batch (dp) or heads (tp). Pools are
+def _write_decode_kernel(pools, rows, page_ids, offsets, mesh, Hk):
+    """Dispatch the Pallas write kernel over pools and their lanes' rows,
+    under shard_map when the mesh shards batch (dp) or heads (tp). Pools are
     replicated over dp/sp, so every replica must apply every lane's
     write: the dp-local updates all-gather (tiny — B rows) before the
     kernel writes the full batch into the local head shard. Mirrors
-    paged_attention_decode's specs. Data pools are [N, ps, Hk·D], int8
-    KV adds scale pools [N, ps, Hk], rows [B, 1, ·] alike: tp shards the
-    last dimension of all of them (heads are major in the fold, so a
-    shard is Hk/tp whole heads)."""
+    paged_attention_decode's specs. The data pool is [2N, ps, Hk·D] with
+    rows [B, 2, 1, Hk·D], int8 KV adds scale pools [N, ps, Hk] with rows
+    [B, 1, 1, Hk]: tp shards the last dimension of all of them (heads are
+    major in the fold, so a shard is Hk/tp whole heads)."""
     from .paged_write_kernel import paged_write_rows_kernel
 
-    pools = [p for p, _ in writes]
-    rows = [r for _, r in writes]
     dp = mesh.shape.get("dp", 1) if mesh is not None else 1
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     if dp <= 1 and tp <= 1:
@@ -247,7 +265,7 @@ def _write_decode_kernel(writes, page_ids, offsets, mesh, Hk):
     from jax.sharding import PartitionSpec as Pspec
 
     pool_spec = Pspec(None, None, "tp")
-    row_spec = Pspec("dp", None, "tp")
+    row_spec = Pspec("dp", None, None, "tp")
 
     def inner(pools_l, rows_l, pid, off):
         if dp > 1:

@@ -1,8 +1,8 @@
 """Pallas paged-attention decode kernel (TPU).
 
 The gather path (ops/paged_attention.py) materializes each sequence's KV
-window in HBM every decode step: `k_pages[page_tables]` reads the pages AND
-writes a [B, P·page_size, Hk, D] copy, so the cache crosses HBM twice. This
+window in HBM every decode step: `kv_pages[2 * page_tables]` reads the pages
+AND writes a [B, P·page_size, Hk, D] copy, so the cache crosses HBM twice. This
 kernel reads each valid page exactly once: one grid program per sequence,
 a double-buffered DMA loop streams that sequence's pages HBM → VMEM while
 the previous block's attention accumulates into online-softmax state
@@ -25,8 +25,15 @@ into the other slot, so one fetch a call is waited on cold, not one a
 sequence; and a sequence walks only its live blocks [blo, bhi), not the
 table's whole width. What a call costs beyond its bytes, measured on a v5e
 (PERF.md §5): ~0.5 µs a sequence (the grid step, its q and output blocks)
-and ~30 ns a page descriptor (start and wait), which is what bounds a
-narrow tp shard whose 8 KB pages carry 10 ns of bytes.
+and ~5–8 ns a DMA descriptor started, ~4.5 awaited, whatever it carries —
+which weighs on a narrow tp shard, whose page halves are 8 KB, 10 ns of
+bytes. So a call issues as few as the pages allow: K and V of a page lie
+side by side in the pool (engine/kv_cache.py; here as its page halves, 2p
+and 2p + 1) and come in under ONE start,
+and a block's pages are awaited by RUNS — every page of a block signals its
+slot's semaphore and a wait looks only at the semaphore and a byte count,
+so the n pages in flight are awaited as one wait of 1, 2, 4 … pages for
+each set bit of n (`_wait_runs`): a full block of G = 2^k pages is one wait.
 
 Invalid page-table tails (the reserved garbage page 0) are never DMA'd:
 the loop bound is ceil((position+1)/page_size), data-dependent per
@@ -68,18 +75,18 @@ def _kernel(
     win_ref,       # [1] int32 sliding window (<=0 → global)
     rng_ref,       # [2] int32 page sub-range [rlo, rhi) — CP shard's slice
     # then, positionally (arity varies with `quantized`):
-    # inputs: q [1, Hq, D] VMEM block; k/v pages [N, ps, Hk·D] HBM
-    #         (the stored layout, heads in lanes; manual DMA; N may be
-    #         the whole stack's L·num_pages, the table's ids offset by
-    #         the layer); quantized adds
-    #         ks/vs scale pages [N, ps, Hk] HBM (bf16)
+    # inputs: q [1, Hq, D] VMEM block; kv page halves [2N, ps, Hk·D] HBM
+    #         (the stored layout: page p's K at 2p, its V at 2p + 1,
+    #         heads in lanes; manual DMA; N may be the whole stack's
+    #         L·num_pages, the table's ids offset by the layer);
+    #         quantized adds ks/vs scale pages [N, ps, Hk] HBM (bf16)
     # outputs: unnormalized online-softmax state — the wrapper
     #         normalizes, or merges across CP shards first (acc/l scale
     #         by exp(m - m_global)): acc [1, Hq, D] f32, m/l
     #         [1, Hq, MINOR] f32
-    # scratch: k/v bufs [2, G, ps, Hk·D] VMEM (+ [2, G, ps, Hk] scale
-    #         bufs when quantized), one DMA semaphore a slot for each
-    #         (every page of a block signals its slot's), and the
+    # scratch: kv buf [2, G, 2, ps, Hk·D] VMEM (+ two [2, G, ps, Hk]
+    #         scale bufs when quantized), one DMA semaphore a slot for
+    #         each (every page of a block signals its slot's), and the
     #         schedule's state [2] int32 SMEM, which outlives a program
     *refs,
     scale: float,
@@ -89,19 +96,22 @@ def _kernel(
     pages_per_block: int,   # G — pages per buffer slot (DMAs in flight)
     quantized: bool = False,
 ):
+    def kv_halves(page):      # a page's K and V: two adjacent entries
+        return kv_pages_ref.at[pl.ds(2 * page, 2)]
+
+    # A stream: (a page id → that page in HBM, its buffer, its semaphores).
     if quantized:
-        (q_ref, k_pages_ref, v_pages_ref, ks_pages_ref, vs_pages_ref,
+        (q_ref, kv_pages_ref, ks_pages_ref, vs_pages_ref,
          acc_ref, m_ref, l_ref,
-         k_buf, v_buf, ks_buf, vs_buf,
-         k_sems, v_sems, ks_sems, vs_sems, state_ref) = refs
-        streams = ((k_pages_ref, k_buf, k_sems), (v_pages_ref, v_buf, v_sems),
-                   (ks_pages_ref, ks_buf, ks_sems),
-                   (vs_pages_ref, vs_buf, vs_sems))
+         kv_buf, ks_buf, vs_buf,
+         kv_sems, ks_sems, vs_sems, state_ref) = refs
+        streams = ((kv_halves, kv_buf, kv_sems),
+                   (lambda page: ks_pages_ref.at[page], ks_buf, ks_sems),
+                   (lambda page: vs_pages_ref.at[page], vs_buf, vs_sems))
     else:
-        (q_ref, k_pages_ref, v_pages_ref,
-         acc_ref, m_ref, l_ref,
-         k_buf, v_buf, k_sems, v_sems, state_ref) = refs
-        streams = ((k_pages_ref, k_buf, k_sems), (v_pages_ref, v_buf, v_sems))
+        (q_ref, kv_pages_ref, acc_ref, m_ref, l_ref,
+         kv_buf, kv_sems, state_ref) = refs
+        streams = ((kv_halves, kv_buf, kv_sems),)
         ks_buf = vs_buf = None
     b = pl.program_id(0)
     B = pl.num_programs(0)
@@ -129,11 +139,12 @@ def _kernel(
         return jnp.maximum(lo, blk * G), jnp.minimum(hi, (blk + 1) * G)
 
     def start_block(seq, blk, slot, lo, hi):
-        # All page DMAs of the block go out together (latency overlaps).
+        # All page DMAs of the block go out together (latency overlaps):
+        # one a page and stream, K and V of the page in it.
         def go(p, _):
-            for pages_ref, buf, sems in streams:
+            for page_at, buf, sems in streams:
                 pltpu.make_async_copy(
-                    pages_ref.at[pt_ref[seq, p]], buf.at[slot, p - blk * G],
+                    page_at(pt_ref[seq, p]), buf.at[slot, p - blk * G],
                     sems.at[slot],
                 ).start()
             return _
@@ -141,16 +152,19 @@ def _kernel(
         jax.lax.fori_loop(*block_pages(blk, lo, hi), go, None)
 
     def wait_block(blk, slot, lo, hi):
-        # One wait a page started: a wait looks only at its slot's
-        # semaphore and the page's size, so any page stands for the source.
-        def done(p, _):
-            for pages_ref, buf, sems in streams:
-                pltpu.make_async_copy(
-                    pages_ref.at[0], buf.at[slot, p - blk * G], sems.at[slot],
-                ).wait()
-            return _
-
-        jax.lax.fori_loop(*block_pages(blk, lo, hi), done, None)
+        # The n pages started are awaited by runs: a wait looks only at
+        # its slot's semaphore and a byte count, so the slot's first `run`
+        # pages stand for source and destination alike; one wait for each
+        # set bit of n adds up to the n pages' bytes.
+        first, end = block_pages(blk, lo, hi)
+        n = jnp.maximum(end - first, 0)
+        for run in _wait_runs(G):
+            @pl.when((n & run) != 0)
+            def _():
+                for _, buf, sems in streams:
+                    landed = buf.at[slot, pl.ds(0, run)]
+                    pltpu.make_async_copy(
+                        landed, landed, sems.at[slot]).wait()
 
     # The schedule (module docstring). Blocks [blo, blo + n_blocks) are the
     # G-page groups overlapping this sequence's pages; they alternate
@@ -210,12 +224,12 @@ def _kernel(
             jnp.where(last, nxt_hi, hi),
         )
         wait_block(blk, slot, lo, hi)
-        # Buffers hold [G, ps, Hk*D] (heads folded into lanes so the
-        # DMA slice stays 128-aligned for any head_dim); the G pages
-        # cover contiguous positions, so they flatten to one [W, Hk*D]
-        # block with a single iota mask.
-        k = k_buf[slot].reshape(W, -1)
-        v = v_buf[slot].reshape(W, -1)
+        # The buffer holds [G, 2, ps, Hk*D] (heads folded into lanes so
+        # the DMA slice stays 128-aligned for any head_dim); the G pages
+        # cover contiguous positions, so each half flattens to one
+        # [W, Hk*D] block with a single iota mask.
+        k = kv_buf[slot, :, 0].reshape(W, -1)
+        v = kv_buf[slot, :, 1].reshape(W, -1)
         num_kv = k.shape[1] // D
         if quantized:
             # Per-(position, head) dequant scales for this group —
@@ -310,7 +324,32 @@ def _kernel(
 
 
 _STAT_MINOR = 128   # lane width for the m/l stat outputs (tile-aligned)
-_BLOCK_BYTES = 512 * 1024   # one pool's bytes in flight a buffer slot
+_BLOCK_BYTES = 512 * 1024   # K's bytes (and V's as many) in flight a slot
+
+
+def _block_pages(pages_per_block: int, row_bytes: int, page_size: int,
+                 table_pages: int) -> int:
+    """G, the pages a buffer slot holds: `pages_per_block` if given (> 0),
+    else from the bytes a block moves; never more than the table has."""
+    if pages_per_block <= 0:
+        # A block keeps _BLOCK_BYTES of K and as many of V in flight,
+        # whatever the folded width it is handed (a tp shard's 256 lanes
+        # take more positions than a chip's 1024), between 128 positions
+        # (one MXU tile of rows) and 512 (a block is computed whole: past
+        # the contexts served, wider is masked work). Two slots of it, and
+        # the f32 copies the matmuls take, stay inside the scoped VMEM.
+        rows = _BLOCK_BYTES // row_bytes
+        pages_per_block = min(max(rows, 128), 512) // page_size
+    return max(1, min(pages_per_block, table_pages))
+
+
+def _wait_runs(pages_per_block: int) -> tuple:
+    """The run lengths, in pages, that `wait_block` awaits a block's pages
+    by: the powers of two up to G. A block with n pages in flight takes
+    the runs that are the set bits of n, so the waits consume exactly the
+    bytes the n starts signalled — one wait for a full block of 2^k
+    pages, at most ⌊log2 G⌋ + 1 for any other n."""
+    return tuple(1 << j for j in range(pages_per_block.bit_length()))
 
 
 @functools.partial(
@@ -319,8 +358,9 @@ _BLOCK_BYTES = 512 * 1024   # one pool's bytes in flight a buffer slot
 )
 def _decode_call(
     q: jax.Array,             # [B, Hq, D]
-    k_pages,                  # [N, ps, Hk·D], or (values, scales) pairs
-    v_pages,                  #   for int8 KV (scales [N, ps, Hk] bf16)
+    kv_pages,                 # [2N, ps, Hk·D], or the int8 (values,
+                              #   k scales, v scales) triple (scales
+                              #   [N, ps, Hk] bf16)
     page_tables: jax.Array,   # [B, P] int32
     positions: jax.Array,     # [B] int32
     window: jax.Array,        # [1] int32
@@ -336,26 +376,19 @@ def _decode_call(
     normalizes, or first merges partial states across context-parallel
     shards (acc/l scale by exp(m - m_global)).
 
-    The pools are taken as they are stored (engine/kv_cache.py: heads
-    folded into lanes, every page DMA 128-aligned for any head_dim) and
-    stay in HBM (`pl.ANY`): nothing here reshapes or copies a pool."""
-    quantized = isinstance(k_pages, tuple)
+    The pool is taken as it is stored (engine/kv_cache.py: K and V of a
+    page side by side — entries 2p and 2p + 1 here — heads folded into
+    lanes, every page DMA 128-aligned for any head_dim) and stays in HBM
+    (`pl.ANY`): nothing here reshapes or copies a pool."""
+    quantized = isinstance(kv_pages, tuple)
     if quantized:
-        (k_pages, ks_pages), (v_pages, vs_pages) = k_pages, v_pages
+        kv_pages, ks_pages, vs_pages = kv_pages
     B, Hq, D = q.shape
-    _, ps, folded = k_pages.shape
+    _, ps, folded = kv_pages.shape
     Hk = folded // D
-    P = page_tables.shape[1]
-    if pages_per_block <= 0:
-        # A block keeps _BLOCK_BYTES a pool in flight, whatever the folded
-        # width it is handed (a tp shard's 256 lanes take more positions
-        # than a chip's 1024), between 128 positions (one MXU tile of rows)
-        # and 512 (a block is computed whole: past the contexts served,
-        # wider is masked work). Two slots of two pools of it, and the f32
-        # copies the matmuls take, stay inside the scoped VMEM.
-        rows = _BLOCK_BYTES // (folded * k_pages.dtype.itemsize)
-        pages_per_block = min(max(rows, 128), 512) // ps
-    G = max(1, min(pages_per_block, P))               # bounded by the table
+    G = _block_pages(
+        pages_per_block, folded * kv_pages.dtype.itemsize, ps,
+        page_tables.shape[1])
 
     kernel = functools.partial(
         _kernel,
@@ -368,25 +401,17 @@ def _decode_call(
     )
     stat_spec = pl.BlockSpec((1, Hq, _STAT_MINOR), lambda b, *_: (b, 0, 0))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [
-        pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
-        any_spec,
-        any_spec,
-    ]
-    scratch = [
-        pltpu.VMEM((2, G, ps, folded), k_pages.dtype),
-        pltpu.VMEM((2, G, ps, folded), k_pages.dtype),
-    ]
-    operands = [q, k_pages, v_pages]
+    in_specs = [pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)), any_spec]
+    scratch = [pltpu.VMEM((2, G, 2, ps, folded), kv_pages.dtype)]
+    operands = [q, kv_pages]
     if quantized:
         in_specs += [any_spec, any_spec]
         scratch += [
             pltpu.VMEM((2, G, ps, Hk), ks_pages.dtype),
             pltpu.VMEM((2, G, ps, Hk), vs_pages.dtype),
         ]
-        operands = [q, k_pages, v_pages, ks_pages, vs_pages]
-    n_sems = 4 if quantized else 2
-    scratch += [pltpu.SemaphoreType.DMA((2,))] * n_sems
+        operands += [ks_pages, vs_pages]
+    scratch += [pltpu.SemaphoreType.DMA((2,))] * (len(operands) - 1)
     scratch += [pltpu.SMEM((2,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -466,8 +491,8 @@ def use_paged_kernel(num_kv_heads: int, head_dim: int) -> bool:
 
 def paged_attention_decode(
     q: jax.Array,             # [B, 1, Hq, D] (single decode step)
-    k_pages: jax.Array,       # [N, ps, Hk·D] (or int8 (values, scales))
-    v_pages: jax.Array,
+    kv_pages,                 # [2N, ps, Hk·D] (or the int8 (values,
+                              #   k scales, v scales) triple)
     page_tables: jax.Array,   # [B, P]
     q_positions: jax.Array,   # [B, 1] absolute positions
     *,
@@ -497,9 +522,9 @@ def paged_attention_decode(
     online-softmax states merge via pmax/psum over sp. ep stays an
     unmentioned axis with replicated operands.
     """
-    quantized = isinstance(k_pages, tuple)
+    quantized = isinstance(kv_pages, tuple)
     B = q.shape[0]
-    data_pool = k_pages[0] if quantized else k_pages
+    data_pool = kv_pages[0] if quantized else kv_pages
     D = q.shape[3]
     Hk = data_pool.shape[2] // D
 
@@ -508,7 +533,7 @@ def paged_attention_decode(
         from .paged_attention import paged_attention
 
         return paged_attention(
-            q, k_pages, v_pages, page_tables, q_positions,
+            q, kv_pages, page_tables, q_positions,
             scale=scale, logit_softcap=logit_softcap, window=window,
         )
 
@@ -540,7 +565,7 @@ def paged_attention_decode(
         from .paged_attention import paged_attention
 
         return paged_attention(
-            q, k_pages, v_pages, page_tables, q_positions,
+            q, kv_pages, page_tables, q_positions,
             scale=scale, logit_softcap=logit_softcap, window=window,
         )
     if dp > 1 or tp > 1 or sp > 1:
@@ -556,7 +581,7 @@ def paged_attention_decode(
             )
         from jax.sharding import PartitionSpec as P
 
-        def inner_sm(q2, kp2, vp2, pt2, pos2, win2):
+        def inner_sm(q2, kv2, pt2, pos2, win2):
             # Context-parallel decode: each sp shard covers a contiguous
             # page sub-range of every sequence (pools are sp-replicated,
             # so this shards the attention READS — the long-context
@@ -571,7 +596,7 @@ def paged_attention_decode(
                 rng = jnp.stack([rlo, rhi])
             else:
                 rng = jnp.array([0, P_tables], jnp.int32)
-            acc, m, l = inner(q2, kp2, vp2, pt2, pos2, win2, rng)
+            acc, m, l = inner(q2, kv2, pt2, pos2, win2, rng)
             if sp > 1:
                 m_g = jax.lax.pmax(m, "sp")
                 corr = jnp.exp(m - m_g)
@@ -579,20 +604,18 @@ def paged_attention_decode(
                 acc = jax.lax.psum(acc * corr, "sp")
             return _normalize(acc, l, q2.dtype)
 
-        # Quantized pools are (values, scales) pairs: per-arg specs are
-        # pytrees matching that structure. Data [N, ps, Hk·D] and scale
-        # pools [N, ps, Hk] both head-shard on their last dimension.
-        pool_spec = (
-            (P(None, None, "tp"), P(None, None, "tp"))
-            if quantized else P(None, None, "tp")
-        )
+        # The int8 form is a (values, k scales, v scales) triple: its spec
+        # is a pytree matching that structure. Data [2N, ps, Hk·D] and
+        # scale pools [N, ps, Hk] all head-shard on their last dimension.
+        pool_spec = P(None, None, "tp")
+        if quantized:
+            pool_spec = (pool_spec,) * 3
         sm = jax.shard_map(
             inner_sm,
             mesh=mesh,
             in_specs=(
                 P("dp", "tp", None),          # q [B, Hq, D]
-                pool_spec,                    # k_pages
-                pool_spec,                    # v_pages
+                pool_spec,                    # kv_pages
                 P("dp", None),                # page_tables
                 P("dp"),                      # positions
                 P(None),                      # window
@@ -601,12 +624,12 @@ def paged_attention_decode(
             check_vma=False,
         )
         out = sm(
-            q[:, 0], k_pages, v_pages, page_tables,
+            q[:, 0], kv_pages, page_tables,
             q_positions[:, 0].astype(jnp.int32), win,
         )
     else:
         acc, _, l = inner(
-            q[:, 0], k_pages, v_pages, page_tables,
+            q[:, 0], kv_pages, page_tables,
             q_positions[:, 0].astype(jnp.int32), win,
             jnp.array([0, P_tables], jnp.int32),
         )

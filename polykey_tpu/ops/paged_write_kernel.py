@@ -25,12 +25,14 @@ The pools are input_output_aliased — in place, no pool copy (the engine
 donates the pool through every dispatch).
 
 The kernel is generic over a LIST of (pool, rows) writes sharing one
-(page, offset) index layout: the fp path writes [k, v] data pools
-([N, ps, Hk*D] — the stored layout of engine/kv_cache.py, heads folded
-into lanes, taken as it lies: no pool is reshaped here or on return);
-the int8-KV path adds the bf16 scale pools [N, ps, Hk] in the same
-waves. N is whatever the caller's page ids address — the model step
-passes the whole stack, [L·num_pages, ps, Hk*D], with ids offset by
+(page, offset) index layout; a page of a pool is `span` consecutive
+[ps, ·] entries of it, read and written back under ONE descriptor each
+way. The fp path writes the ONE data pool ([2N, ps, Hk*D] — the stored
+layout of engine/kv_cache.py as page halves: page p's K at 2p, its V at
+2p + 1, span 2; heads folded into lanes, taken as it lies: no pool is
+reshaped here or on return); the int8-KV path adds the two bf16 scale
+pools [N, ps, Hk] (span 1) in the same waves. N is whatever the caller's
+page ids address — the model step passes the whole stack, ids offset by
 layer · num_pages, so the aliased output IS the donated stacked pool.
 
 Garbage-page collisions are intended: inactive lanes all target page 0
@@ -73,14 +75,18 @@ def _make_kernel(n_pools: int, B: int, ps: int):
         r_sems = scratch[n_pools:2 * n_pools]
         w_sems = scratch[2 * n_pools:3 * n_pools]
 
+        def page(i, b):           # lane b's page of pool i: `span` entries
+            span = bufs[i].shape[1]
+            return outs[i].at[pl.ds(span * pids_ref[b], span)]
+
         def read_dma(i, b):
             return pltpu.make_async_copy(
-                outs[i].at[pids_ref[b]], bufs[i].at[b], r_sems[i].at[b]
+                page(i, b), bufs[i].at[b], r_sems[i].at[b]
             )
 
         def write_dma(i, b):
             return pltpu.make_async_copy(
-                bufs[i].at[b], outs[i].at[pids_ref[b]], w_sems[i].at[b]
+                bufs[i].at[b], page(i, b), w_sems[i].at[b]
             )
 
         # Wave 1: every lane's page reads, all pools, all at once.
@@ -106,8 +112,10 @@ def _make_kernel(n_pools: int, B: int, ps: int):
 
 
 def paged_write_rows_kernel(
-    pools: list,              # data [N, ps, Hk*D] and/or scale [N, ps, Hk]
-    rows: list,               # matching [B, 1, Hk*D] / [B, 1, Hk]
+    pools: list,              # data [2N, ps, Hk*D] and/or scale [N, ps, Hk]
+    rows: list,               # matching [B, span, 1, ·]: [B, 2, 1, Hk*D] /
+                              # [B, 1, 1, Hk]; a row broadcasts against
+                              # the positions of its entry of the page
     page_ids: jax.Array,      # [B] int32
     offsets: jax.Array,       # [B] int32
     *,
@@ -122,7 +130,8 @@ def paged_write_rows_kernel(
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     row_specs = [
-        pl.BlockSpec(r.shape, lambda *_: (0, 0, 0), memory_space=pltpu.VMEM)
+        pl.BlockSpec(r.shape, lambda *_: (0, 0, 0, 0),
+                     memory_space=pltpu.VMEM)
         for r in rows
     ]
     outs = pl.pallas_call(
@@ -136,7 +145,8 @@ def paged_write_rows_kernel(
             in_specs=row_specs + [any_spec] * n,
             out_specs=[any_spec] * n,
             scratch_shapes=(
-                [pltpu.VMEM((B, ps, p.shape[2]), p.dtype) for p in pools]
+                [pltpu.VMEM((B, r.shape[1], ps, p.shape[2]), p.dtype)
+                 for p, r in zip(pools, rows)]
                 + [pltpu.SemaphoreType.DMA((B,))] * (2 * n)
             ),
         ),

@@ -105,17 +105,14 @@ def _staged(cfg: ModelConfig, mesh: Mesh, M: int, B: int, T: int):
             attend = make_causal_attend(cfg, pos_in)
 
             def body(h, scanned):
-                lp, idx, kc, vc = scanned
-                h, _, _ = apply_layer(
-                    lp, idx, h, pos_in, cfg, attend, kc, vc
-                )
+                lp, idx = scanned
+                h, _ = apply_layer(lp, idx, h, pos_in, cfg, attend, None)
                 return h, None
 
             idxs = p * layers_per_stage + jnp.arange(
                 layers_per_stage, dtype=jnp.int32
             )
-            empty = jnp.zeros((layers_per_stage, 0), jnp.float32)
-            h, _ = lax.scan(body, x_in, (local_layers, idxs, empty, empty))
+            h, _ = lax.scan(body, x_in, (local_layers, idxs))
             return h
 
         perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]

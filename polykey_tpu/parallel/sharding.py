@@ -219,15 +219,21 @@ def _put_layer_jit(treedef, shardings: tuple):
 
 
 def paged_kv_sharding(mesh: Mesh) -> NamedSharding:
-    """Page pools [L, N, page_size, Hk·D] (stored layout: engine/kv_cache.py)
-    and the int8-KV scale pools [L, N, page_size, Hk]: the last dimension
-    shards over tp. Heads are major in the fold, so a shard is Hk/tp whole
-    heads in both.
+    """The page pool [L, N, 2, page_size, Hk·D] (stored layout:
+    engine/kv_cache.py): the last dimension shards over tp — heads are
+    major in the fold, so a shard is Hk/tp whole heads, K and V of a page
+    alike.
 
     Pages are *not* dp-sharded: any decode slot may hold any page, so the
     pool replicates over dp (each dp replica serves its own slot subset with
     its own pool in the dp>1 serving layout).
     """
+    return NamedSharding(mesh, P("pp", None, None, None, "tp"))
+
+
+def kv_scale_sharding(mesh: Mesh) -> NamedSharding:
+    """The int8-KV scale pools [L, N, page_size, Hk]: heads over tp, as
+    the pool they scale."""
     return NamedSharding(mesh, P("pp", None, None, "tp"))
 
 

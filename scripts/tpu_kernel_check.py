@@ -15,9 +15,10 @@ chiprun_out/kernel_check.txt). Exits non-zero on any failure and when
 there is no TPU. It times two things: the one-off probe that
 jax.block_until_ready really blocks, and the paged decode kernel alone
 (`decode-time` rows: µs a call beside its K/V bytes ÷ the chip's HBM
-bandwidth at the two shapes the benchmark's cells run, so that a call's
-cost can be split into bytes ÷ bandwidth + the rest without a server; in
-no cell — what the users pay is the benchmark's to say).
+bandwidth at the two shapes the benchmark's cells run, and the DMA
+descriptors the call starts and awaits, so that a call's cost can be
+split into bytes ÷ bandwidth + the rest without a server; in no cell —
+what the users pay is the benchmark's to say).
 
 Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
      python scripts/tpu_kernel_check.py --timing   (the decode-time rows
@@ -95,9 +96,11 @@ def paged_inputs(B, Hq, Hk, D, P, dtype, seed=0):
     N = B * P + 1
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(kq, (B, 1, Hq, D), dtype)
-    # Pools in the stored layout (engine/kv_cache.py): heads folded.
-    kp = jax.random.normal(kk, (N, PS, Hk * D), dtype)
-    vp = jax.random.normal(kv, (N, PS, Hk * D), dtype)
+    # The pool in the stored layout (engine/kv_cache.py) as the ops take
+    # it: page halves [2N, PS, Hk·D], page p's K at 2p, its V at 2p + 1.
+    kvp = jnp.stack([jax.random.normal(kk, (N, PS, Hk * D), dtype),
+                     jax.random.normal(kv, (N, PS, Hk * D), dtype)],
+                    axis=1).reshape(2 * N, PS, Hk * D)
     positions = np.linspace(5, P * PS - 1, B).astype(np.int32).reshape(B, 1)
     tables = np.zeros((B, P), np.int32)
     page = 1
@@ -105,17 +108,17 @@ def paged_inputs(B, Hq, Hk, D, P, dtype, seed=0):
         for j in range(int(positions[b, 0]) // PS + 1):
             tables[b, j] = page
             page += 1
-    return q, kp, vp, jnp.asarray(tables), jnp.asarray(positions)
+    return q, kvp, jnp.asarray(tables), jnp.asarray(positions)
 
 
 def quantized_pool(pool, D):
-    """A folded fp pool [N, PS, Hk·D] as the int8 (values, scales) pair:
-    values folded alike, scales [N, PS, Hk]."""
+    """An fp pool of page halves [2N, PS, Hk·D] as the int8 (values,
+    k scales, v scales) triple: values laid out alike, scales [N, PS, Hk]."""
     from polykey_tpu.engine.kv_cache import fold_heads, unfold_heads
     from polykey_tpu.ops.paged_attention import quantize_kv_rows
 
     values, scales = quantize_kv_rows(unfold_heads(pool, D))
-    return fold_heads(values), scales
+    return fold_heads(values), scales[0::2], scales[1::2]
 
 
 def check_decode(quantized: bool) -> None:
@@ -123,22 +126,22 @@ def check_decode(quantized: bool) -> None:
     from polykey_tpu.ops.paged_attention_kernel import paged_attention_decode
 
     for label, Hq, Hk, D, softcap, window in GEOMETRIES:
-        q, kp, vp, tables, positions = paged_inputs(
+        q, kvp, tables, positions = paged_inputs(
             LANES, Hq, Hk, D, TABLE, jnp.bfloat16)
         kw = dict(scale=D ** -0.5, logit_softcap=softcap,
                   window=None if window is None else jnp.int32(window))
 
-        def fp(kp=kp, vp=vp, kw=kw):
-            want = paged_attention(q, kp, vp, tables, positions, **kw)
+        def fp(kvp=kvp, kw=kw):
+            want = paged_attention(q, kvp, tables, positions, **kw)
             got = paged_attention_decode(
-                q, kp, vp, tables, positions, **KERNEL, **kw)
+                q, kvp, tables, positions, **KERNEL, **kw)
             return assert_close(got, want, 8e-2)
 
-        def int8(kp=kp, vp=vp, kw=kw, D=D):
-            kq, vq = quantized_pool(kp, D), quantized_pool(vp, D)
-            want = paged_attention(q, kq, vq, tables, positions, **kw)
+        def int8(kvp=kvp, kw=kw, D=D):
+            kvq = quantized_pool(kvp, D)
+            want = paged_attention(q, kvq, tables, positions, **kw)
             got = paged_attention_decode(
-                q, kq, vq, tables, positions, **KERNEL, **kw)
+                q, kvq, tables, positions, **KERNEL, **kw)
             return assert_close(got, want, 8e-2)
 
         case("decode-int8kv" if quantized else "decode-fp",
@@ -186,32 +189,38 @@ def check_write(quantized: bool) -> None:
     offsets = jnp.asarray(rng.integers(0, PS, LANES).astype(np.int32))
 
     def compare(pools, rows):
+        """`pools`: the data pool as page halves [2N, PS, Hk·D] with rows
+        [B, 2, 1, Hk·D], then any scale pools [N, PS, Hk] with rows
+        [B, 1, 1, Hk]."""
         got = paged_write_rows_kernel(
             pools, rows, page_ids, offsets,
             interpret=KERNEL.get("interpret", False))
         for pool, row, out in zip(pools, rows, got):
-            want = pool.at[page_ids, offsets].set(row[:, 0])
+            span = row.shape[1]
+            want = pool
+            for half in range(span):
+                want = want.at[span * page_ids + half, offsets].set(
+                    row[:, half, 0])
             if not bool(jnp.array_equal(out, want)):
                 raise AssertionError("written pool differs from the scatter")
         return "equal"
 
     for label, _, Hk, D, _, _ in GEOMETRIES:
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
-        kp = jax.random.normal(k1, (N, PS, Hk * D), jnp.bfloat16)
-        kn = jax.random.normal(k2, (LANES, 1, Hk * D), jnp.bfloat16)
+        kvp = jax.random.normal(k1, (2 * N, PS, Hk * D), jnp.bfloat16)
+        kvn = jax.random.normal(k2, (LANES, 2, 1, Hk * D), jnp.bfloat16)
 
-        def fp(kp=kp, kn=kn):
-            return compare([kp, kp * 0.5], [kn, kn + 1])
+        def fp(kvp=kvp, kvn=kvn):
+            return compare([kvp], [kvn])
 
         def int8(Hk=Hk, D=D, k3=k3, k2=k2):
             k8p = jnp.asarray(np.random.default_rng(1).integers(
-                -127, 128, (N, PS, Hk * D)), jnp.int8)
+                -127, 128, (2 * N, PS, Hk * D)), jnp.int8)
             ksp = jax.random.normal(k3, (N, PS, Hk), jnp.bfloat16)
             k8r = jnp.asarray(np.random.default_rng(2).integers(
-                -127, 128, (LANES, 1, Hk * D)), jnp.int8)
-            ksr = jax.random.normal(k2, (LANES, 1, Hk), jnp.bfloat16)
-            return compare([k8p, -k8p, ksp, ksp * 0.5],
-                           [k8r, -k8r, ksr, ksr + 1])
+                -127, 128, (LANES, 2, 1, Hk * D)), jnp.int8)
+            ksr = jax.random.normal(k2, (LANES, 1, 1, Hk), jnp.bfloat16)
+            return compare([k8p, ksp, ksp * 0.5], [k8r, ksr, ksr + 1])
 
         case("write-int8kv" if quantized else "write-fp",
              f"{label} B={LANES}", int8 if quantized else fp)
@@ -269,11 +278,13 @@ def hbm_bytes_per_s() -> float:
 def time_decode(B, Hq, Hk, D, P, contexts, pages_per_block=0) -> str:
     """µs a call of the decode kernel on bf16 pools, every lane's pages
     scattered over a pool no cache could hold, beside the least time its
-    K/V bytes allow. `contexts`: one length for every lane, or one a lane.
+    K/V bytes allow and the DMA descriptors it starts and awaits (counted
+    from the kernel's own block width and wait runs, not measured).
+    `contexts`: one length for every lane, or one a lane.
     The calls run back to back inside one jitted scan (a new q each, so
     nothing is hoisted) and the scan's own turn, measured empty, is taken
     off."""
-    from polykey_tpu.ops.paged_attention_kernel import paged_attention_decode
+    from polykey_tpu.ops import paged_attention_kernel as pak
 
     contexts = np.broadcast_to(np.asarray(contexts, np.int32), (B,))
     interpret = "interpret" in KERNEL       # a rehearsal: small and few
@@ -282,8 +293,9 @@ def time_decode(B, Hq, Hk, D, P, contexts, pages_per_block=0) -> str:
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
     calls = 2 if interpret else TIMED_CALLS
     qs = jax.random.normal(kq, (calls, B, 1, Hq, D), jnp.bfloat16)
-    kp = jax.random.normal(kk, (N, PS, Hk * D), jnp.bfloat16)
-    vp = jax.random.normal(kv, (N, PS, Hk * D), jnp.bfloat16)
+    kvp = jnp.stack([jax.random.normal(kk, (N, PS, Hk * D), jnp.bfloat16),
+                     jax.random.normal(kv, (N, PS, Hk * D), jnp.bfloat16)],
+                    axis=1).reshape(2 * N, PS, Hk * D)
     tables = np.zeros((B, P), np.int32)
     pages = rng.permutation(N - 1)[:B * P].reshape(B, P) + 1
     for b in range(B):
@@ -293,35 +305,46 @@ def time_decode(B, Hq, Hk, D, P, contexts, pages_per_block=0) -> str:
     positions = jnp.asarray(contexts - 1).reshape(B, 1)
 
     def scan_of(step):
-        # The pools go in as arguments: closed over, they would be compiled
+        # The pool goes in as an argument: closed over, it would be compiled
         # into the program as half a gigabyte of constants.
-        return jax.jit(lambda qs, kp, vp: jax.lax.scan(
-            lambda c, q: (c + step(q, kp, vp).astype(jnp.float32), None),
+        return jax.jit(lambda qs, kvp: jax.lax.scan(
+            lambda c, q: (c + step(q, kvp).astype(jnp.float32), None),
             jnp.zeros((B, 1, Hq, D), jnp.float32), qs)[0])
 
     def seconds(fn):
-        jax.block_until_ready(fn(qs, kp, vp))    # compile
+        jax.block_until_ready(fn(qs, kvp))    # compile
         best = float("inf")
         for _ in range(5):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(qs, kp, vp))
+            jax.block_until_ready(fn(qs, kvp))
             best = min(best, time.perf_counter() - t0)
         return best
 
-    kernel = scan_of(lambda q, kp, vp: paged_attention_decode(
-        q, kp, vp, tables, positions, scale=D ** -0.5,
+    kernel = scan_of(lambda q, kvp: pak.paged_attention_decode(
+        q, kvp, tables, positions, scale=D ** -0.5,
         pages_per_block=pages_per_block, **KERNEL))
-    empty = scan_of(lambda q, kp, vp: q)
+    empty = scan_of(lambda q, kvp: q)
     us = (seconds(kernel) - seconds(empty)) / calls * 1e6
     kv_bytes = 2 * int(contexts.sum()) * Hk * D * 2
+    # One start a page (K and V under it); a block's n pages awaited as one
+    # wait for each set bit of n (the kernel's own G and `_wait_runs`).
+    G = pak._block_pages(pages_per_block, Hk * D * 2, PS, P)
+    starts = waits = 0
+    for ctx in contexts:
+        pages = (int(ctx) + PS - 1) // PS
+        starts += pages
+        waits += sum(
+            sum(1 for run in pak._wait_runs(G) if n & run)
+            for n in [G] * (pages // G) + [pages % G])
+    counted = f"{starts} starts + {waits} waits"
     if interpret:
         return (f"ran (interpret mode on the host: no device time); "
-                f"K/V {kv_bytes / 1e6:.2f} MB")
+                f"K/V {kv_bytes / 1e6:.2f} MB; {counted}")
     peak = hbm_bytes_per_s()
     least = kv_bytes / peak * 1e6
     return (f"{us:.1f} us/call; K/V {kv_bytes / 1e6:.2f} MB = {least:.1f} us "
             f"at {peak / 1e9:.0f} GB/s ({100 * least / us:.1f} %), "
-            f"rest {us - least:.1f} us")
+            f"rest {us - least:.1f} us; {counted}")
 
 
 def check_decode_timing(sweep: bool) -> None:

@@ -149,14 +149,12 @@ def main(argv=None) -> int:
 
     live = -(-n_tokens // config.page_size)
     real = n_tokens - (live - 1) * config.page_size   # rows of the last page
-    kv_diff = 0.0
-    kv_max = 0.0
-    for pool in (engine.paged.k, engine.paged.v):
-        a = np.array(pool[:, 1:1 + live], np.float32)
-        b = np.array(pool[:, 1 + pages:1 + pages + live], np.float32)
-        a[:, -1, real:] = b[:, -1, real:] = 0.0       # padding rows differ
-        kv_diff = max(kv_diff, float(np.max(np.abs(a - b))))
-        kv_max = max(kv_max, float(np.max(np.abs(a))))
+    # K and V of the pages alike: [L, live, 2, page_size, Hk·D].
+    a = np.array(engine.paged.kv[:, 1:1 + live], np.float32)
+    b = np.array(engine.paged.kv[:, 1 + pages:1 + pages + live], np.float32)
+    a[:, -1, :, real:] = b[:, -1, :, real:] = 0.0     # padding rows differ
+    kv_diff = float(np.max(np.abs(a - b)))
+    kv_max = float(np.max(np.abs(a)))
     print(f"K/V of positions 0..{n_tokens - 1}, {live} pages x "
           f"{engine.model_cfg.num_layers} layers: max |one - two| "
           f"{kv_diff:.6f} (largest entry {kv_max:.3f})")

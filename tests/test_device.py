@@ -60,7 +60,7 @@ def _devices_of(config) -> list:
         ids = [d.id for d in engine.mesh.devices.flat]
         # The params really live there, not just the mesh object.
         assert {d.id for d in engine.params["embed"].devices()} == set(ids)
-        assert {d.id for d in engine.paged.k.devices()} == set(ids)
+        assert {d.id for d in engine.paged.kv.devices()} == set(ids)
         assert engine.stats()["devices"] == ids
         return ids
     finally:

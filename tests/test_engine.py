@@ -1002,7 +1002,7 @@ def test_int8_kv_engine_serves():
     engine = InferenceEngine(cfg)
     try:
         assert engine.paged.quantized
-        assert engine.paged.k.dtype == jnp.int8
+        assert engine.paged.kv.dtype == jnp.int8
         assert engine.paged.ks.dtype == jnp.bfloat16
         reqs = [GenRequest(prompt=f"int8 kv {i}", max_new_tokens=12)
                 for i in range(6)]
@@ -1169,13 +1169,13 @@ def test_two_rows_on_one_table_equal_one_wide_row(small, n, start):
     assert len(two) == -(-n // small)
     np.testing.assert_allclose(one[-1], two[-1], rtol=0, atol=2e-5)
     live = -(-(start + n) // page)
-    for pool in (paged.k, paged.v):
-        a = np.array(pool[:, one_first:one_first + live])
-        b = np.array(pool[:, two_first:two_first + live])
-        tail = start + n - (live - 1) * page    # real rows of the last page
-        a[:, -1, tail:] = b[:, -1, tail:] = 0   # padding rows differ
-        assert np.abs(a).max() > 0
-        np.testing.assert_allclose(a, b, rtol=0, atol=2e-5)
+    # K and V of the pages alike: [L, live, 2, page, Hk·D].
+    a = np.array(paged.kv[:, one_first:one_first + live])
+    b = np.array(paged.kv[:, two_first:two_first + live])
+    tail = start + n - (live - 1) * page    # real rows of the last page
+    a[:, -1, :, tail:] = b[:, -1, :, tail:] = 0   # padding rows differ
+    assert np.abs(a[:, :, 0]).max() > 0 and np.abs(a[:, :, 1]).max() > 0
+    np.testing.assert_allclose(a, b, rtol=0, atol=2e-5)
     token_one, paged, _ = sampled(paged, *rows(2 * small, one_first))
     token_two, paged, _ = sampled(paged, *rows(small, two_first))
     assert int(token_one[-1]) == int(token_two[-1]) == int(jax.numpy.argmax(one[-1]))

@@ -75,9 +75,12 @@ def test_flash_fallback_off_tpu_matches():
 def _paged_case(B, Hq, Hk, D, ps, P, positions):
     N = B * P + 1
     q = jax.random.normal(jax.random.PRNGKey(0), (B, 1, Hq, D), jnp.float32)
-    # Pools in the stored layout (engine/kv_cache.py): heads folded.
+    # The pool in the stored layout (engine/kv_cache.py), as the ops take
+    # it: page halves [2N, ps, Hk·D], page p's K at 2p and its V at 2p + 1,
+    # heads folded.
     kp = jax.random.normal(jax.random.PRNGKey(1), (N, ps, Hk * D), jnp.float32)
     vp = jax.random.normal(jax.random.PRNGKey(2), (N, ps, Hk * D), jnp.float32)
+    kvp = jnp.stack([kp, vp], axis=1).reshape(2 * N, ps, Hk * D)
     pts = np.zeros((B, P), np.int32)
     page = 1
     for b in range(B):
@@ -85,22 +88,22 @@ def _paged_case(B, Hq, Hk, D, ps, P, positions):
         for j in range(needed):
             pts[b, j] = page
             page += 1
-    return q, kp, vp, jnp.asarray(pts), jnp.asarray(positions, jnp.int32)
+    return q, kvp, jnp.asarray(pts), jnp.asarray(positions, jnp.int32)
 
 
 @pytest.mark.parametrize("softcap,win", [
     (None, None), (50.0, None), (None, 24), (30.0, 24),
 ])
 def test_paged_decode_kernel_matches_gather(softcap, win):
-    q, kp, vp, pt, pos = _paged_case(
+    q, kvp, pt, pos = _paged_case(
         4, 8, 2, 64, 16, 8, [[5], [37], [63], [100]]
     )
     w = None if win is None else jnp.int32(win)
     ref = paged_attention(
-        q, kp, vp, pt, pos, scale=0.125, logit_softcap=softcap, window=w
+        q, kvp, pt, pos, scale=0.125, logit_softcap=softcap, window=w
     )
     out = paged_attention_decode(
-        q, kp, vp, pt, pos, scale=0.125, logit_softcap=softcap, window=w,
+        q, kvp, pt, pos, scale=0.125, logit_softcap=softcap, window=w,
         interpret=True,
     )
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
@@ -112,23 +115,23 @@ def test_paged_decode_kernel_multi_group(g, win):
     """Force small page groups so the group loop runs multiple blocks,
     including a partial last group (P=8 with G=3) and a window whose lo
     lands mid-group (non-DMA'd rows inside a live group must be masked)."""
-    q, kp, vp, pt, pos = _paged_case(
+    q, kvp, pt, pos = _paged_case(
         4, 8, 2, 64, 16, 8, [[5], [37], [63], [100]]
     )
     w = None if win is None else jnp.int32(win)
-    ref = paged_attention(q, kp, vp, pt, pos, scale=0.125, window=w)
+    ref = paged_attention(q, kvp, pt, pos, scale=0.125, window=w)
     out = paged_attention_decode(
-        q, kp, vp, pt, pos, scale=0.125, window=w,
+        q, kvp, pt, pos, scale=0.125, window=w,
         interpret=True, pages_per_block=g,
     )
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
 
 def test_paged_decode_kernel_no_gqa_single_page():
-    q, kp, vp, pt, pos = _paged_case(1, 2, 2, 32, 16, 4, [[5]])
-    ref = paged_attention(q, kp, vp, pt, pos, scale=0.125)
+    q, kvp, pt, pos = _paged_case(1, 2, 2, 32, 16, 4, [[5]])
+    ref = paged_attention(q, kvp, pt, pos, scale=0.125)
     out = paged_attention_decode(
-        q, kp, vp, pt, pos, scale=0.125, interpret=True
+        q, kvp, pt, pos, scale=0.125, interpret=True
     )
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
@@ -170,14 +173,14 @@ def test_paged_decode_kernel_schedule(case):
 
     B, Hk, positions, win, split, ppb = _SCHEDULE_CASES[case]
     ps, P, D, groups = 16, 64, 128, 2
-    q, kp, vp, pt, pos = _paged_case(
+    q, kvp, pt, pos = _paged_case(
         B, Hk * groups, Hk, D, ps, P, [[p] for p in positions])
     w = None if win is None else jnp.int32(win)
-    ref = paged_attention(q, kp, vp, pt, pos, scale=0.09, window=w)
+    ref = paged_attention(q, kvp, pt, pos, scale=0.09, window=w)
 
     def state(rlo, rhi):
         return pak._decode_call(
-            q[:, 0], kp, vp, pt, pos[:, 0],
+            q[:, 0], kvp, pt, pos[:, 0],
             jnp.asarray([0 if win is None else win], jnp.int32),
             jnp.asarray([rlo, rhi], jnp.int32),
             scale=0.09, logit_softcap=None, interpret=True,
@@ -201,6 +204,51 @@ def test_paged_decode_kernel_schedule(case):
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
 
+@pytest.mark.parametrize("G", [1, 2, 3, 8, 12, 32])
+def test_paged_decode_wait_runs_add_up_to_the_pages_started(G):
+    """A block's n pages in flight are awaited as one wait for each set bit
+    of n: for every n in 1 … G the runs taken add up to exactly n pages —
+    the bytes the n starts signalled — and a full power-of-two block is
+    ONE wait."""
+    from polykey_tpu.ops import paged_attention_kernel as pak
+
+    runs = pak._wait_runs(G)
+    assert len(runs) == G.bit_length() and max(runs) <= G
+    for n in range(1, G + 1):
+        taken = [run for run in runs if n & run]
+        assert sum(taken) == n
+    if G & (G - 1) == 0:
+        assert [run for run in runs if G & run] == [G]
+
+
+@pytest.mark.parametrize("n", range(1, 9))
+def test_paged_decode_waits_consume_what_the_starts_signalled(n):
+    """The kernel under the TPU interpreter with DMAs that land only when
+    they are AWAITED and semaphores counted in bytes: every block size
+    n = 1 … G pages, as a sequence's only block, behind a full block, and
+    handed over from the sequence before it. A wait short of the bytes
+    started leaves pages unlanded (the interpreter's uninitialised VMEM is
+    NaN) and a residue on the slot's semaphore for the next block to trip
+    over; a wait beyond them has nothing to wake it."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from polykey_tpu.ops import paged_attention_kernel as pak
+
+    G, ps, P = 8, 16, 24
+    positions = [n * ps - 1, (G + n) * ps - 3, 5, (2 * G + n) * ps - 1]
+    q, kvp, pt, pos = _paged_case(4, 4, 2, 64, ps, P, [[p] for p in positions])
+    ref = paged_attention(q, kvp, pt, pos, scale=0.125)
+    acc, _, den = pak._decode_call(
+        q[:, 0], kvp, pt, pos[:, 0], jnp.zeros((1,), jnp.int32),
+        jnp.asarray([0, P], jnp.int32), scale=0.125, logit_softcap=None,
+        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait"),
+        pages_per_block=G,
+    )
+    out = (acc / jnp.maximum(den, 1e-9))[:, None]
+    assert bool(jnp.isfinite(out).all())
+    assert float(jnp.max(jnp.abs(ref - out))) < TOL
+
+
 @pytest.mark.parametrize("folded,itemsize,ppb,want", [
     (1024, 2, 0, 16),     # one chip of mistral-7b: 512 KB, 256 positions
     (512, 2, 0, 32),      # a tp = 2 shard: the same bytes, 512 positions
@@ -219,16 +267,16 @@ def test_paged_decode_block_width_follows_the_bytes(folded, itemsize, ppb,
     dtype = {2: jnp.bfloat16, 1: jnp.int8}[itemsize]
     B, ps, P, D = 2, 16, 256, 128
     Hk = folded // D
-    pool = jax.ShapeDtypeStruct((64, ps, folded), dtype)
+    pool = jax.ShapeDtypeStruct((128, ps, folded), dtype)
     if itemsize == 1:
         scales = jax.ShapeDtypeStruct((64, ps, Hk), jnp.bfloat16)
-        pool = (pool, scales)
+        pool = (pool, scales, scales)
     jaxpr = jax.make_jaxpr(
         lambda *a: pak._decode_call.__wrapped__(     # the jit's function
             *a, scale=1.0, logit_softcap=None, interpret=False,
             pages_per_block=ppb)
     )(
-        jax.ShapeDtypeStruct((B, Hk, D), jnp.bfloat16), pool, pool,
+        jax.ShapeDtypeStruct((B, Hk, D), jnp.bfloat16), pool,
         jax.ShapeDtypeStruct((B, P), jnp.int32),
         jax.ShapeDtypeStruct((B,), jnp.int32),
         jax.ShapeDtypeStruct((1,), jnp.int32),
@@ -236,11 +284,11 @@ def test_paged_decode_block_width_follows_the_bytes(folded, itemsize, ppb,
     )
     (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert call.params["name"] == "paged_attention_decode"
-    scratch = [
+    (scratch,) = [
         x.aval.shape for x in call.params["jaxpr"].invars
-        if len(x.aval.shape) == 4
+        if len(x.aval.shape) == 5
     ]
-    assert scratch[:2] == [(2, want, ps, folded)] * 2, scratch
+    assert scratch == (2, want, 2, ps, folded), scratch
 
 
 def test_paged_decode_kernel_shard_mapped_on_mesh():
@@ -256,19 +304,18 @@ def test_paged_decode_kernel_shard_mapped_on_mesh():
         pytest.skip("needs 4 devices")
     mesh = create_mesh(MeshConfig(dp=2, tp=2), devices=jax.devices()[:4])
 
-    q, kp, vp, pt, pos = _paged_case(
+    q, kvp, pt, pos = _paged_case(
         4, 8, 2, 64, 16, 8, [[5], [37], [63], [100]]
     )
-    ref = paged_attention(q, kp, vp, pt, pos, scale=0.125)
+    ref = paged_attention(q, kvp, pt, pos, scale=0.125)
 
     q_s = jax.device_put(q, NamedSharding(mesh, P("dp", None, "tp", None)))
-    kp_s = jax.device_put(kp, NamedSharding(mesh, P(None, None, "tp")))
-    vp_s = jax.device_put(vp, NamedSharding(mesh, P(None, None, "tp")))
+    kvp_s = jax.device_put(kvp, NamedSharding(mesh, P(None, None, "tp")))
     pt_s = jax.device_put(pt, NamedSharding(mesh, P("dp", None)))
     pos_s = jax.device_put(pos, NamedSharding(mesh, P("dp", None)))
 
     out = paged_attention_decode(
-        q_s, kp_s, vp_s, pt_s, pos_s, scale=0.125,
+        q_s, kvp_s, pt_s, pos_s, scale=0.125,
         interpret=True, mesh=mesh,
     )
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
@@ -289,19 +336,18 @@ def test_paged_decode_kernel_context_parallel(softcap, win):
         pytest.skip("needs 4 devices")
     mesh = create_mesh(MeshConfig(sp=2, tp=2), devices=jax.devices()[:4])
 
-    q, kp, vp, pt, pos = _paged_case(
+    q, kvp, pt, pos = _paged_case(
         4, 8, 2, 64, 16, 8, [[5], [37], [99], [127]]
     )
     w = None if win is None else jnp.int32(win)
     ref = paged_attention(
-        q, kp, vp, pt, pos, scale=0.125, logit_softcap=softcap, window=w
+        q, kvp, pt, pos, scale=0.125, logit_softcap=softcap, window=w
     )
 
     rep = NamedSharding(mesh, P())
     out = paged_attention_decode(
         jax.device_put(q, NamedSharding(mesh, P(None, None, "tp"))),
-        jax.device_put(kp, NamedSharding(mesh, P(None, None, "tp"))),
-        jax.device_put(vp, NamedSharding(mesh, P(None, None, "tp"))),
+        jax.device_put(kvp, NamedSharding(mesh, P(None, None, "tp"))),
         jax.device_put(pt, rep), jax.device_put(pos, rep),
         scale=0.125, logit_softcap=softcap, window=w,
         interpret=True, mesh=mesh,
@@ -366,9 +412,9 @@ def test_kernel_kill_switches(monkeypatch):
 
 
 def test_paged_decode_fallback_off_tpu():
-    q, kp, vp, pt, pos = _paged_case(2, 4, 2, 24, 8, 4, [[3], [19]])
-    ref = paged_attention(q, kp, vp, pt, pos, scale=0.3)
-    out = paged_attention_decode(q, kp, vp, pt, pos, scale=0.3)
+    q, kvp, pt, pos = _paged_case(2, 4, 2, 24, 8, 4, [[3], [19]])
+    ref = paged_attention(q, kvp, pt, pos, scale=0.3)
+    out = paged_attention_decode(q, kvp, pt, pos, scale=0.3)
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
 
@@ -385,10 +431,10 @@ def test_pp_mesh_routes_to_gather_path(monkeypatch):
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices")
 
-    q, kp, vp, pt, pos = _paged_case(
+    q, kvp, pt, pos = _paged_case(
         4, 8, 2, 64, 16, 8, [[5], [37], [63], [100]]
     )
-    ref = paged_attention(q, kp, vp, pt, pos, scale=0.125)
+    ref = paged_attention(q, kvp, pt, pos, scale=0.125)
 
     mesh = create_mesh(MeshConfig(pp=2, tp=2), devices=jax.devices()[:4])
     from polykey_tpu.ops import paged_attention as pa_mod
@@ -405,8 +451,7 @@ def test_pp_mesh_routes_to_gather_path(monkeypatch):
     )
     out = pak.paged_attention_decode(
         jax.device_put(q, NamedSharding(mesh, P_(None, None, "tp"))),
-        jax.device_put(kp, NamedSharding(mesh, P_(None, None, "tp"))),
-        jax.device_put(vp, NamedSharding(mesh, P_(None, None, "tp"))),
+        jax.device_put(kvp, NamedSharding(mesh, P_(None, None, "tp"))),
         jax.device_put(pt, NamedSharding(mesh, P_())),
         jax.device_put(pos, NamedSharding(mesh, P_())),
         scale=0.125, interpret=True, mesh=mesh,
@@ -425,16 +470,17 @@ def test_paged_decode_kernel_quantized_matches_gather(win):
     from polykey_tpu.engine.kv_cache import fold_heads, unfold_heads
     from polykey_tpu.ops.paged_attention import quantize_kv_rows
 
-    q, kp, vp, pt, pos = _paged_case(
+    q, kvp, pt, pos = _paged_case(
         4, 8, 2, 64, 16, 8, [[5], [37], [63], [100]]
     )
-    k8, ks = quantize_kv_rows(unfold_heads(kp, 64))
-    v8, vs = quantize_kv_rows(unfold_heads(vp, 64))
-    kq, vq = (fold_heads(k8), ks), (fold_heads(v8), vs)
-    ref = paged_attention(q, kq, vq, pt, pos, scale=0.125,
+    k8, ks = quantize_kv_rows(unfold_heads(kvp[0::2], 64))
+    v8, vs = quantize_kv_rows(unfold_heads(kvp[1::2], 64))
+    kvq = (jnp.stack([fold_heads(k8), fold_heads(v8)], axis=1).reshape(
+        kvp.shape), ks, vs)
+    ref = paged_attention(q, kvq, pt, pos, scale=0.125,
                           window=None if win is None else jnp.int32(win))
     out = paged_attention_decode(
-        q, kq, vq, pt, pos, scale=0.125,
+        q, kvq, pt, pos, scale=0.125,
         window=None if win is None else jnp.int32(win),
         interpret=True,
     )

@@ -76,16 +76,31 @@ def test_unique_pages(allocator_factory):
 
 def test_paged_write_and_gather_roundtrip():
     Hk, D, page_size = 2, 4, 4
-    pools = jnp.zeros((8, page_size, Hk * D), dtype=jnp.float32)
+    pool = jnp.zeros((2 * 8, page_size, Hk * D), dtype=jnp.float32)
     # One sequence using pages [3, 5]: positions 0..7.
     page_tables = jnp.array([[3, 5]], dtype=jnp.int32)
     positions = jnp.arange(8, dtype=jnp.int32)[None, :]
     k_new = jax.random.normal(jax.random.PRNGKey(0), (1, 8, Hk, D))
     v_new = jax.random.normal(jax.random.PRNGKey(1), (1, 8, Hk, D))
-    k_pages, v_pages = paged_write(pools, pools, k_new, v_new, page_tables, positions)
-    k_out, v_out = paged_gather_kv(k_pages, v_pages, page_tables, D)
+    pool = paged_write(pool, k_new, v_new, page_tables, positions)
+    k_out, v_out = paged_gather_kv(pool, page_tables, D)
     np.testing.assert_allclose(np.asarray(k_out[0]), np.asarray(k_new[0]))
     np.testing.assert_allclose(np.asarray(v_out[0]), np.asarray(v_new[0]))
+
+
+def test_prefill_gather_reads_k_and_v_as_two_gathers():
+    """K and V are two gathers of page halves: one gather of whole
+    [2, ps, Hk·D] pages followed by a slice would write every window a
+    second time (16 MB a row and layer at the cells' table width)."""
+    B, P, ps, folded, D = 2, 6, 4, 32, 8
+    jaxpr = jax.make_jaxpr(lambda kv, pt: paged_gather_kv(kv, pt, D))(
+        jnp.zeros((2 * 16, ps, folded)), jnp.zeros((B, P), jnp.int32))
+    gathers = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "gather"]
+    assert [tuple(e.outvars[0].aval.shape) for e in gathers] == [
+        (B, P, ps, folded)] * 2
+    assert not any(                       # no window of whole K+V pages
+        v.aval.shape == (B, P, 2, ps, folded)
+        for e in jaxpr.jaxpr.eqns for v in e.outvars)
 
 
 def test_forward_paged_matches_contiguous():
@@ -140,17 +155,24 @@ def test_forward_paged_incremental_decode():
     )
 
 
-def _scatter_reference(k_pages, v_pages, k_new, v_new, page_tables, positions):
-    """The original per-token XLA scatter, kept as the oracle for the
-    faster write paths (page-granular cond path + Pallas DMA kernel)."""
-    ps = k_pages.shape[1]
+def _scatter_reference(kv_pages, k_new, v_new, page_tables, positions):
+    """The original per-token XLA scatter over a K pool and a V pool of
+    their own, kept as the oracle for the write paths (token scatter,
+    page-granular cond path, Pallas DMA kernel): the stored pool's two
+    halves must read as those two pools."""
+    ps = kv_pages.shape[1]
     bi = jnp.arange(page_tables.shape[0], dtype=jnp.int32)[:, None]
     page_ids = page_tables[bi, positions // ps]
     offsets = positions % ps
     return (
-        k_pages.at[page_ids, offsets].set(fold_heads(k_new)),
-        v_pages.at[page_ids, offsets].set(fold_heads(v_new)),
+        kv_pages[0::2].at[page_ids, offsets].set(fold_heads(k_new)),
+        kv_pages[1::2].at[page_ids, offsets].set(fold_heads(v_new)),
     )
+
+
+def _assert_pool_is(kv_pages, want_k, want_v):
+    np.testing.assert_array_equal(np.asarray(kv_pages[0::2]), np.asarray(want_k))
+    np.testing.assert_array_equal(np.asarray(kv_pages[1::2]), np.asarray(want_v))
 
 
 def _write_fixture(B, T, P, start, seed=0):
@@ -159,9 +181,10 @@ def _write_fixture(B, T, P, start, seed=0):
     rng = np.random.default_rng(seed)
     pools = init_paged_kv(cfg, num_pages=1 + B * P, page_size=ps)
     kp = jnp.asarray(
-        rng.normal(size=pools.k[0].shape).astype(np.float32), jnp.bfloat16
+        rng.normal(size=pools.kv[0, :, 0].shape).astype(np.float32), jnp.bfloat16
     )
-    vp = kp * 2
+    # One layer's pool as the ops take it: page halves [2N, ps, Hk·D].
+    kvp = jnp.stack([kp, kp * 2], axis=1).reshape(-1, *kp.shape[1:])
     k_new = jnp.asarray(
         rng.normal(size=(B, T, cfg.num_kv_heads, cfg.head_dim)), jnp.bfloat16
     )
@@ -170,7 +193,13 @@ def _write_fixture(B, T, P, start, seed=0):
     for b in range(B):
         pt[b] = np.arange(P) + 1 + b * P
     positions = start[:, None] + np.arange(T)[None, :]
-    return kp, vp, k_new, v_new, jnp.asarray(pt), jnp.asarray(positions, jnp.int32)
+    return kvp, k_new, v_new, jnp.asarray(pt), jnp.asarray(positions, jnp.int32)
+
+
+def _kernel_rows(k_new, v_new):
+    """A decode step's [B, 1, Hk, D] rows as the write kernel blends them
+    into the two halves of a page: [B, 2, 1, Hk·D]."""
+    return jnp.stack([fold_heads(k_new), fold_heads(v_new)], axis=1)
 
 
 def test_paged_write_aligned_prefill_matches_scatter():
@@ -178,22 +207,20 @@ def test_paged_write_aligned_prefill_matches_scatter():
     engine prefill chunk) must be byte-identical to the token scatter."""
     B, T, P = 3, 32, 4
     start = np.array([0, 16, 32])          # all page-aligned
-    kp, vp, kn, vn, pt, pos = _write_fixture(B, T, P, start)
-    got_k, got_v = paged_write(kp, vp, kn, vn, pt, pos)
-    want_k, want_v = _scatter_reference(kp, vp, kn, vn, pt, pos)
-    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
-    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    kvp, kn, vn, pt, pos = _write_fixture(B, T, P, start)
+    _assert_pool_is(
+        paged_write(kvp, kn, vn, pt, pos),
+        *_scatter_reference(kvp, kn, vn, pt, pos))
 
 
 def test_paged_write_unaligned_prefill_matches_scatter():
     """Unaligned starts must fall back (runtime cond) to exact scatter."""
     B, T, P = 3, 32, 4
     start = np.array([0, 8, 17])           # rows 1, 2 unaligned
-    kp, vp, kn, vn, pt, pos = _write_fixture(B, T, P, start)
-    got_k, got_v = paged_write(kp, vp, kn, vn, pt, pos)
-    want_k, want_v = _scatter_reference(kp, vp, kn, vn, pt, pos)
-    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
-    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    kvp, kn, vn, pt, pos = _write_fixture(B, T, P, start)
+    _assert_pool_is(
+        paged_write(kvp, kn, vn, pt, pos),
+        *_scatter_reference(kvp, kn, vn, pt, pos))
 
 
 def test_paged_write_decode_kernel_interpret_matches_scatter():
@@ -204,18 +231,15 @@ def test_paged_write_decode_kernel_interpret_matches_scatter():
 
     B, P = 4, 3
     start = np.array([5, 16, 31, 47])
-    kp, vp, kn, vn, pt, pos = _write_fixture(B, 1, P, start)
-    ps = kp.shape[1]
+    kvp, kn, vn, pt, pos = _write_fixture(B, 1, P, start)
+    ps = kvp.shape[1]
     bi = jnp.arange(B, dtype=jnp.int32)[:, None]
     page_ids = pt[bi, pos // ps][:, 0]
     offsets = (pos % ps)[:, 0]
-    got_k, got_v = paged_write_rows_kernel(
-        [kp, vp], [fold_heads(kn), fold_heads(vn)], page_ids, offsets,
-        interpret=True,
+    (got,) = paged_write_rows_kernel(
+        [kvp], [_kernel_rows(kn, vn)], page_ids, offsets, interpret=True,
     )
-    want_k, want_v = _scatter_reference(kp, vp, kn, vn, pt, pos)
-    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
-    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    _assert_pool_is(got, *_scatter_reference(kvp, kn, vn, pt, pos))
 
 
 def test_paged_write_mesh_kernel_path_matches_scatter(monkeypatch):
@@ -233,8 +257,8 @@ def test_paged_write_mesh_kernel_path_matches_scatter(monkeypatch):
     mesh = create_mesh(MeshConfig(tp=2, dp=2, sp=2))
     B, P = 4, 3
     start = np.array([5, 16, 31, 40])
-    kp, vp, kn, vn, pt, pos = _write_fixture(B, 1, P, start)
-    ps = kp.shape[1]
+    kvp, kn, vn, pt, pos = _write_fixture(B, 1, P, start)
+    ps = kvp.shape[1]
     bi = jnp.arange(B, dtype=jnp.int32)[:, None]
     page_ids = pt[bi, pos // ps][:, 0]
     offsets = (pos % ps)[:, 0]
@@ -243,13 +267,11 @@ def test_paged_write_mesh_kernel_path_matches_scatter(monkeypatch):
         pwk, "paged_write_rows_kernel",
         partial(pwk.paged_write_rows_kernel, interpret=True),
     )
-    got_k, got_v = pa._write_decode_kernel(
-        [(kp, fold_heads(kn)), (vp, fold_heads(vn))], page_ids, offsets,
-        mesh, TINY_LLAMA.num_kv_heads,
+    (got,) = pa._write_decode_kernel(
+        [kvp], [_kernel_rows(kn, vn)], page_ids, offsets, mesh,
+        TINY_LLAMA.num_kv_heads,
     )
-    want_k, want_v = _scatter_reference(kp, vp, kn, vn, pt, pos)
-    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want_k))
-    np.testing.assert_array_equal(np.asarray(got_v), np.asarray(want_v))
+    _assert_pool_is(got, *_scatter_reference(kvp, kn, vn, pt, pos))
 
 
 # ---- int8 KV cache ----
@@ -293,7 +315,7 @@ def test_forward_paged_int8_kv_tracks_fp():
     pool_fp = init_paged_kv(cfg, 1 + B * P, ps, jnp.float32)
     pool_q = init_paged_kv(cfg, 1 + B * P, ps, jnp.float32,
                            kv_dtype=jnp.int8)
-    assert pool_q.quantized and pool_q.k.dtype == jnp.int8
+    assert pool_q.quantized and pool_q.kv.dtype == jnp.int8
     h_fp, pool_fp = forward_paged(params, cfg, tokens, positions, pool_fp, pt)
     h_q, pool_q = forward_paged(params, cfg, tokens, positions, pool_q, pt)
     scale = float(jnp.max(jnp.abs(h_fp))) + 1e-6
@@ -308,8 +330,9 @@ def test_forward_paged_int8_kv_tracks_fp():
 
 
 def test_paged_write_rows_kernel_with_scale_pools():
-    """The generalized RMW kernel over four pools (int8 data + bf16
-    scales) matches per-pool scatter in interpret mode."""
+    """The generalized RMW kernel over three pools (the int8 data pool,
+    K and V of a page side by side, + two bf16 scale pools) matches
+    per-pool scatter in interpret mode."""
     from polykey_tpu.ops.paged_write_kernel import paged_write_rows_kernel
 
     B, P, ps, Hk, D = 4, 3, 16, 4, 32
@@ -326,10 +349,13 @@ def test_paged_write_rows_kernel_with_scale_pools():
     page_ids = jnp.asarray(rng.permutation(N - 1)[:B].astype(np.int32) + 1)
     offsets = jnp.asarray(rng.integers(0, ps, B).astype(np.int32))
 
-    outs = paged_write_rows_kernel(
-        [kq, vq, ks, vs], [k8, v8, ksr, vsr], page_ids, offsets,
-        interpret=True,
+    kvq, ks_out, vs_out = paged_write_rows_kernel(
+        [jnp.stack([kq, vq], axis=1).reshape(2 * N, ps, Hk * D), ks, vs],
+        [jnp.stack([k8, v8], axis=1), ksr[:, None], vsr[:, None]],
+        page_ids, offsets, interpret=True,
     )
-    for pool, rows, got in zip([kq, vq, ks, vs], [k8, v8, ksr, vsr], outs):
+    for pool, rows, got in zip(
+            [kq, vq, ks, vs], [k8, v8, ksr, vsr],
+            [kvq[0::2], kvq[1::2], ks_out, vs_out]):
         want = pool.at[page_ids, offsets].set(rows[:, 0])
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -710,6 +710,7 @@ def test_ledger_rederives_8b_int8_weight_fraction():
 def test_kv_pool_mirror_matches_allocator_byte_for_byte():
     """The ledger's stdlib pool arithmetic is a pure mirror of the jax
     allocator — pinned against the real arrays so they can't drift."""
+    import jax
     import jax.numpy as jnp
 
     from polykey_tpu.engine import kv_cache
@@ -718,8 +719,7 @@ def test_kv_pool_mirror_matches_allocator_byte_for_byte():
     mcfg = get_config("tiny-llama")
     for kv_dtype_str, kv_dtype in (("bfloat16", None), ("int8", jnp.int8)):
         pool = kv_cache.init_paged_kv(mcfg, 8, 16, jnp.bfloat16, kv_dtype)
-        nbytes = sum(x.nbytes for x in (pool.k, pool.v, pool.ks, pool.vs)
-                     if x is not None)
+        nbytes = sum(x.nbytes for x in jax.tree.leaves(pool))
         assert kv_pool_bytes_spec(mcfg, 8, 16, kv_dtype_str) == nbytes
         assert nbytes == kv_cache.kv_pool_bytes(
             mcfg, 8, 16, jnp.bfloat16, kv_dtype)

@@ -1,22 +1,25 @@
-"""The stored KV layout (engine/kv_cache.py): pools [L, N, page_size, Hk·D],
-kept whole in the layer scan's carry and addressed by (layer, page) in place.
+"""The stored KV layout (engine/kv_cache.py): ONE pool [L, N, 2, page_size,
+Hk·D] (K and V of a page side by side: one DMA descriptor a page), kept whole
+in the layer scan's carry and addressed by (layer, page) in place.
 
 Three things are held here.
 
 - STRUCTURE, a count from the compiled decode step: no instruction other than
   the pool parameters, the loops' carries, bitcasts and the in-place writes
   (the kernels' aliased outputs on TPU, the scatters elsewhere) has a result
-  of one layer's pool bytes or more, and the pools' outputs alias their
-  donated inputs. Before ISSUE 34 the step sliced a layer's pool out, folded
+  of one layer's K (or V) bytes or more, and the pool's output aliases its
+  donated input. Before ISSUE 34 the step sliced a layer's pool out, folded
   it, and wrote it back: 8 such instructions in the step compiled for a v5e
   (74 % of the Mistral-7B step on the chip), 4 in the CPU's. This is the
   guard the next architecture's PR runs into first.
 - PARITY: paged prefill + decode equal the non-paged forward through every
   path that addresses the layout (XLA gather/scatter, the Pallas kernels in
   interpret mode, int8 KV, tp = 2).
-- BOUNDARY: the host tier and the handoff wire format keep [..., Hk, D];
-  pages cross that boundary byte for byte, and a blob written by the tree
-  BEFORE the fold (tests/data/kv_handoff_parent_pr30.pkkv) restores here.
+- BOUNDARY: the host tier and the handoff wire format keep K and V in
+  arrays of their own, [..., Hk, D]; pages cross that boundary byte for
+  byte, and a blob written by the tree BEFORE the fold — and so before K
+  and V shared a page (tests/data/kv_handoff_parent_pr30.pkkv) — restores
+  here.
 """
 
 import importlib.util
@@ -39,9 +42,11 @@ from polykey_tpu.engine.kv_cache import (
     KVHandoffState,
     deserialize_kv_state,
     fold_heads,
+    fold_pages,
     init_paged_kv,
     serialize_kv_state,
     unfold_heads,
+    unfold_pages,
 )
 from polykey_tpu.models.config import TINY_LLAMA, get_config
 from polykey_tpu.models.transformer import (
@@ -163,8 +168,8 @@ def _engine_decode_hlo(model: str, tp: int) -> tuple[str, int, str]:
             eos_id=engine.tokenizer.eos_id,
             candidates=cfg.top_p_candidates, mesh=engine.mesh,
         ).compile()
-        L, N, ps, folded = engine.paged.k.shape
-        shape = f"f32[{L},{N},{ps},{folded // tp}]"
+        L, N, _, ps, folded = engine.paged.kv.shape
+        shape = f"f32[{L},{N},2,{ps},{folded // tp}]"
         return compiled.as_text(), N * ps * (folded // tp) * 4, shape
     finally:
         engine.shutdown()
@@ -176,7 +181,7 @@ def _engine_decode_hlo(model: str, tp: int) -> tuple[str, int, str]:
 def test_decode_step_moves_no_pool(model, tp):
     hlo, layer_bytes, pool_shape = _engine_decode_hlo(model, tp)
     assert pool_sized_instructions(hlo, layer_bytes) == []
-    assert aliased_pool_parameters(hlo, pool_shape) == 2      # K and V
+    assert aliased_pool_parameters(hlo, pool_shape) == 1      # K and V in one
 
 
 def test_pool_sized_instructions_sees_a_layer_slice():
@@ -226,18 +231,42 @@ def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp):
     folded = cfg.num_kv_heads * cfg.head_dim // tp
     assert pool_sized_instructions(hlo, pages * ps * folded * 2) == []
     assert aliased_pool_parameters(
-        hlo, f"bf16[{cfg.num_layers},{pages},{ps},{folded}]"
-    ) == 2
+        hlo, f"bf16[{cfg.num_layers},{pages},2,{ps},{folded}]"
+    ) == 1
     # One write and one read kernel per layer and step, under the names the
     # benchmark's readers hold fixed, on the whole stack.
-    stack = f"bf16[{cfg.num_layers * pages},{ps},{folded}]"
+    stack = f"bf16[{cfg.num_layers * pages * 2},{ps},{folded}]"   # halves
     calls = [line.split(" custom-call(")[0] for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     writes = [c for c in calls if c.lstrip().startswith("%paged_kv_write")]
     reads = [c for c in calls
              if c.lstrip().startswith("%paged_attention_decode")]
     assert len(writes) == len(reads) == 1, calls
-    assert writes[0].count(stack) == 2
+    assert writes[0].count(stack) == 1
+
+
+@pytest.mark.parametrize("tp,rows", [(1, 1), (1, 2), (4, 2)])
+def test_prefill_step_compiled_for_v5e_moves_no_pool(v5e, tp, rows):
+    """A prefill dispatch of one and of two 128-token rows, compiled for a
+    v5e: the page-aligned scatters write whole [ps, Hk·D] page halves into
+    the donated stack in place and the window gathers read it where it lies
+    — no instruction materialises a layer's K (or V) bytes, and the pool's
+    output aliases its input. (A ONE-row module compiles differently from
+    the others, ISSUE 44: gathering through a [2N, ps, Hk·D] view of the
+    pool passed at two rows and cost 1.25 s a dispatch at one, on the chip,
+    PR 46.)"""
+    cfg = replace(TINY_LLAMA, name="layout-probe", num_heads=2 * tp,
+                  num_kv_heads=2 * tp, head_dim=128 if tp == 4 else 64)
+    pages, ps = 1024, 16
+    hlo = _CENSUS.compile_step(
+        cfg, list(v5e.devices), tp=tp, pages=pages, page_size=ps,
+        max_seq_len=8 * ps, prefill=(rows, 128),
+    )
+    folded = cfg.num_kv_heads * cfg.head_dim // tp
+    assert pool_sized_instructions(hlo, pages * ps * folded * 2) == []
+    assert aliased_pool_parameters(
+        hlo, f"bf16[{cfg.num_layers},{pages},2,{ps},{folded}]"
+    ) == 1
 
 
 def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch):
@@ -572,27 +601,28 @@ def _drain(engine, **kw):
 
 
 def _gather(engine, pages):
+    """[k, v] (+ [ks, vs] of an int8 pool) of `pages`, as the host tier
+    and the wire hold them: K and V apart, the heads apart."""
     idx = np.zeros((engine.config.pages_per_seq,), np.int32)
     idx[:len(pages)] = pages
     outs = engine._jit_kv_gather(engine.paged, jnp.asarray(idx))
-    head_dim = engine.model_cfg.head_dim
-    return [
-        unfold_heads(np.asarray(o), head_dim)[:, :len(pages)]
-        if i < 2 else np.asarray(o)[:, :len(pages)]
-        for i, o in enumerate(outs)
-    ]
+    arrays = list(unfold_pages(np.asarray(outs.kv), engine.model_cfg.head_dim))
+    if outs.quantized:
+        arrays += [np.asarray(outs.ks), np.asarray(outs.vs)]
+    return [a[:, :len(pages)] for a in arrays]
 
 
 def _restore(engine, pages, arrays):
     P = engine.config.pages_per_seq
     idx = np.zeros((P,), np.int32)
     idx[:len(pages)] = pages
-    operands = [jnp.asarray(idx)]
-    for i, a in enumerate(arrays):
-        padded = np.zeros((a.shape[0], P) + a.shape[2:], a.dtype)
-        padded[:, :len(pages)] = a
-        operands.append(jnp.asarray(fold_heads(padded) if i < 2 else padded))
-    engine.paged = engine._jit_kv_restore(engine.paged, *operands)
+    padded = []
+    for a in arrays:
+        padded.append(np.zeros((a.shape[0], P) + a.shape[2:], a.dtype))
+        padded[-1][:, :len(pages)] = a
+    padded += [None] * (4 - len(padded))
+    engine.paged = engine._jit_kv_restore(
+        engine.paged, jnp.asarray(idx), engine._page_upload(*padded))
 
 
 @pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
@@ -658,7 +688,8 @@ def test_blob_of_the_unfolded_layout_restores_here():
     engine = InferenceEngine(EngineConfig(**config), seed=record["seed"])
     try:
         state.validate_for(engine.model_cfg, config["page_size"], False)
-        assert engine.paged.k.shape[-1] == (
+        assert engine.paged.kv.shape[2:] == (
+            2, config["page_size"],
             engine.model_cfg.num_kv_heads * engine.model_cfg.head_dim)
         target = list(range(5, 5 + state.num_pages))
         _restore(engine, target, [state.k, state.v])
@@ -684,11 +715,21 @@ def test_stored_layout_is_head_folded_for_every_pool():
         q = jax.eval_shape(
             lambda c=cfg: init_paged_kv(c, 8, 16, kv_dtype=jnp.int8))
         folded = cfg.num_kv_heads * cfg.head_dim
-        assert fp.k.shape == fp.v.shape == (cfg.num_layers, 8, 16, folded)
-        assert q.k.shape == (cfg.num_layers, 8, 16, folded)
-        assert q.ks.shape == (cfg.num_layers, 8, 16, cfg.num_kv_heads)
+        # K and V of a page side by side in ONE array.
+        assert fp.kv.shape == q.kv.shape == (cfg.num_layers, 8, 2, 16, folded)
+        assert (fp.ks, fp.vs) == (None, None) and q.kv.dtype == jnp.int8
+        assert q.ks.shape == q.vs.shape == (
+            cfg.num_layers, 8, 16, cfg.num_kv_heads)
         assert (fp.page_size, fp.num_pages) == (16, 8)
+        assert (q.page_size, q.num_pages) == (16, 8)
         page = np.arange(2 * 16 * folded).reshape(2, 16, cfg.num_kv_heads,
                                                   cfg.head_dim)
         assert np.array_equal(
             unfold_heads(fold_heads(page), cfg.head_dim), page)
+        # The host boundary: K and V pages apart <-> one stored page.
+        stored = fold_pages(page, page + 1)
+        assert stored.shape == (2, 2, 16, folded)
+        assert np.array_equal(stored[:, 0], fold_heads(page))
+        back_k, back_v = unfold_pages(stored, cfg.head_dim)
+        assert np.array_equal(back_k, page)
+        assert np.array_equal(back_v, page + 1)
