@@ -703,8 +703,9 @@ class EngineConfig:
 
     def _refuse_for_recurrent_state(self) -> None:
         """A model with per-slot recurrent state (ModelConfig.stateful:
-        kv_cache.SlotState beside the pages) is served by the plain
-        prefill / decode pair on one device and by nothing else yet. Every
+        kv_cache.SlotState beside the pages — a mixer's h, a conv's last
+        columns) is served by the plain prefill / decode pair on one
+        device and by nothing else yet. Every
         feature that moves, shares or rebuilds a slot's K/V pages would
         have to move, share or rebuild that state with them, and none
         does: each is refused here, in one place, rather than half-ported.
@@ -728,7 +729,7 @@ class EngineConfig:
             "disagg / disagg_tier (the KV handoff ships pages, not state)":
                 bool(self.disagg or self.disagg_tier),
             "draft_model (a rejected draft token cannot be taken back out "
-            "of a recurrence)": self.draft_model is not None,
+            "of the state)": self.draft_model is not None,
             "kv_dtype=int8 (no quantized pool path for a layer pattern)":
                 self.kv_dtype == "int8",
             "quantize (no quantized weights for a layer pattern yet)":
@@ -740,7 +741,7 @@ class EngineConfig:
         for what, on in refused.items():
             if on:
                 raise ValueError(
-                    f"{self.model} keeps per-slot recurrent state (Mamba-2 "
-                    f"h and conv columns, engine/kv_cache.py SlotState) "
+                    f"{self.model} keeps per-slot recurrent state "
+                    f"({model.state_held}, engine/kv_cache.py SlotState) "
                     f"beside its KV pages; not supported with it: {what}"
                 )

@@ -188,14 +188,16 @@ class PagedKV:
 
 @struct.dataclass
 class SlotState:
-    """What a slot holds BESIDE its pages: the fixed-size recurrent state
-    of a stateful model (ModelConfig.stateful), indexed by slot. One entry
-    per Mamba-2 layer, in pattern order: `ssm` [slots, H, P, N] float32
-    (the recurrence is summed over thousands of steps) and `conv`
-    [slots, K−1, conv_dim] in the activation dtype (the conv's last K−1
-    input columns, stored as they were computed). A model without such
-    state holds two empty tuples — an empty pytree: nothing is allocated,
-    carried or donated for it.
+    """What a slot holds BESIDE its pages: the fixed-size state of a
+    stateful model (ModelConfig.stateful), indexed by slot, one entry per
+    layer that needs it, in pattern order. `ssm`, one per Mamba-2 mixer:
+    [slots, H, P, N] float32 (the recurrence is summed over thousands of
+    steps). `conv`, one per layer with a causal conv — a mixer or a gated
+    short convolution: [slots, K−1, channels] in the activation dtype (the
+    conv's last K−1 input columns, stored as they were computed). A tuple
+    is empty where the pattern has no such layer (a conv-only model holds
+    no `ssm` leaf), and a model without such state holds two empty tuples
+    — an empty pytree: nothing is allocated, carried or donated for it.
 
     It rides every dispatch that `PagedKV` rides, donated the same way,
     so the donation chain orders its writers as it orders the pool's.
@@ -216,12 +218,14 @@ class SlotState:
 
 
 def init_slot_state(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16) -> SlotState:
-    layers = cfg.layer_pattern.count("M")
     ssm = (slots, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
-    conv = (slots, max(cfg.conv_kernel - 1, 0), cfg.conv_dim)
+    channels = {"M": cfg.conv_dim, "C": cfg.hidden_size}
     return SlotState(
-        ssm=tuple(jnp.zeros(ssm, jnp.float32) for _ in range(layers)),
-        conv=tuple(jnp.zeros(conv, dtype) for _ in range(layers)),
+        ssm=tuple(jnp.zeros(ssm, jnp.float32)
+                  for _ in range(cfg.layer_pattern.count("M"))),
+        conv=tuple(
+            jnp.zeros((slots, max(cfg.conv_kernel - 1, 0), channels[ch]), dtype)
+            for ch in cfg.layer_pattern if ch in channels),
     )
 
 

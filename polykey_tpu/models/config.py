@@ -42,12 +42,18 @@ class ModelConfig:
     # num_experts/top_k x the dispatch FLOPs; off for tiny test configs,
     # where dispatch's token-drop-on-overflow would perturb exactness checks.
     moe_dispatch: bool = False
-    # Hybrid stacks (models/hybrid.py): one character a layer, and a
-    # layer is a mixer OR a feed-forward part alone, under one norm —
-    # "M" a Mamba-2 mixer, "*" attention, "E" a latent expert layer.
+    # Hybrid stacks (models/hybrid.py): one character an entry, and an
+    # entry is an operator OR a feed-forward part alone, under one norm —
+    # "M" a Mamba-2 mixer, "C" a gated short convolution, "*" attention,
+    # "E" an expert layer, "D" a dense gated MLP. A published layer that
+    # holds an operator AND a feed-forward part under two norms is two
+    # entries ("CD", "*E"), and `num_layers` counts entries.
     # Empty: the homogeneous attention+MLP block above, scanned.
     layer_pattern: str = ""
     use_rope: bool = True                     # False: no position embedding
+    # "*": RMSNorm over each q head and each k head (one learned gain of
+    # head_dim each) before the position embedding.
+    qk_norm: bool = False
     # "M": H heads x P dims, state [H, P, N] per sequence, G groups share
     # B and C, a causal depthwise conv of `conv_kernel` taps over x|B|C.
     mamba_num_heads: int = 0
@@ -56,17 +62,27 @@ class ModelConfig:
     ssm_groups: int = 0
     conv_kernel: int = 0
     ssm_chunk: int = 128                      # prefill's chunked form
+    # "C": [B | C | u] = W_in h, a causal depthwise conv of `conv_kernel`
+    # taps over B ⊙ u (no bias, no activation), W_out (C ⊙ conv): what a
+    # sequence carries is the conv's last K−1 columns, nothing else.
+    # "D": act(h W_gate) ⊙ h W_up through W_down at this width.
+    dense_intermediate_size: int = 0
     # "E": sigmoid router over `n_routed_experts`, top
-    # `num_experts_per_tok` of them, experts of `intermediate_size` in a
-    # latent of `moe_latent_size`, one shared expert on the full hidden.
-    # The chip holds experts [first_expert, first_expert + experts_held)
-    # and computes their part of the sum (ops/moe.py moe_latent_held).
+    # `num_experts_per_tok` of them by score + bias, each weighed by its
+    # score over the sum of the chosen (+ `router_norm_eps`), experts of
+    # `intermediate_size`. Two forms (ops/moe.py `moe_held`):
+    # `moe_latent_size` > 0, un-gated relu² experts inside a latent with
+    # one shared expert on the full hidden; 0, gated experts
+    # (act(h W_gate,e) ⊙ h W_up,e) W_down,e on the full hidden, no shared
+    # expert. The chip holds experts [first_expert, first_expert +
+    # experts_held) and computes their part of the sum.
     n_routed_experts: int = 0
     experts_held: int = 0
     first_expert: int = 0
     moe_latent_size: int = 0
     moe_shared_intermediate: int = 0
     routed_scaling_factor: float = 1.0
+    router_norm_eps: float = 0.0
 
     @property
     def is_moe(self) -> bool:
@@ -74,8 +90,17 @@ class ModelConfig:
 
     @property
     def stateful(self) -> bool:
-        """Holds per-slot recurrent state beside the K/V pages."""
-        return "M" in self.layer_pattern
+        """Holds per-slot state beside the K/V pages (kv_cache.SlotState)."""
+        return bool(self.state_held)
+
+    @property
+    def state_held(self) -> str:
+        """What a slot holds beside its pages, in words; "" for nothing."""
+        if "M" in self.layer_pattern:
+            return "Mamba-2 h and conv columns"
+        if "C" in self.layer_pattern:
+            return "short-conv columns"
+        return ""
 
     @property
     def kv_layers(self) -> int:
@@ -97,10 +122,10 @@ class ModelConfig:
         if not self.layer_pattern:
             return
         if len(self.layer_pattern) != self.num_layers or \
-                set(self.layer_pattern) - set("ME*"):
+                set(self.layer_pattern) - set("MC*ED"):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r} must be num_layers="
-                f"{self.num_layers} characters of 'M', 'E', '*'"
+                f"{self.num_layers} characters of 'M', 'C', '*', 'E', 'D'"
             )
         if "E" in self.layer_pattern and not (
             0 < self.experts_held
@@ -123,19 +148,26 @@ class ModelConfig:
         embed = self.vocab_size * self.hidden_size
         if self.layer_pattern:
             h = self.hidden_size
+            if self.moe_latent_size:
+                experts = (2 * h * self.moe_latent_size
+                           + 2 * h * self.moe_shared_intermediate
+                           + self.experts_held * 2 * self.moe_latent_size
+                           * self.intermediate_size)
+            else:
+                experts = self.experts_held * 3 * h * self.intermediate_size
             kinds = {
                 "M": h * (2 * self.mamba_inner
                           + 2 * self.ssm_groups * self.ssm_state_size
                           + self.mamba_num_heads)
                 + self.mamba_inner * h + self.conv_dim * self.conv_kernel,
+                "C": 4 * h * h + h * self.conv_kernel,
                 "*": h * self.head_dim * 2 * (self.num_heads
                                               + self.num_kv_heads),
-                "E": h * self.n_routed_experts + 2 * h * self.moe_latent_size
-                + 2 * h * self.moe_shared_intermediate
-                + self.experts_held * 2 * self.moe_latent_size
-                * self.intermediate_size,
+                "E": h * self.n_routed_experts + experts,
+                "D": 3 * h * self.dense_intermediate_size,
             }
-            return 2 * embed + sum(kinds[k] for k in self.layer_pattern)
+            tables = 1 if self.tie_embeddings else 2
+            return tables * embed + sum(kinds[k] for k in self.layer_pattern)
         attn = self.hidden_size * self.head_dim * (
             self.num_heads * 2 + self.num_kv_heads * 2
         )
@@ -347,6 +379,32 @@ TINY_HYBRID = ModelConfig(
     routed_scaling_factor=2.5,
 )
 
+# An operator + feed-forward pattern at toy size: both leading dense
+# entries, then two periods of conv, conv, attention, conv over gated
+# experts on the full hidden, all held; conv-only state, q/k norms, RoPE,
+# one tied matrix.
+TINY_LFM2 = ModelConfig(
+    name="tiny-lfm2",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=32,
+    num_layers=20,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=16,
+    max_seq_len=512,
+    rope_theta=1_000_000.0,
+    tie_embeddings=True,
+    layer_pattern="CDCD*ECECECE*ECECECE",
+    qk_norm=True,
+    conv_kernel=3,
+    dense_intermediate_size=96,
+    n_routed_experts=16,
+    experts_held=16,
+    num_experts_per_tok=4,
+    router_norm_eps=1e-6,
+)
+
 # A mid-size llama for single-chip benchmarking without 8B's 16 GiB of bf16
 # weights (v5e has 16 GiB HBM; 8B serves in int8 — see engine docs).
 LLAMA_1B_BENCH = replace(LLAMA32_1B, name="llama-1b-bench")
@@ -380,6 +438,7 @@ MODEL_REGISTRY = {
         TINY_MIXTRAL,
         TINY_GEMMA,
         TINY_HYBRID,
+        TINY_LFM2,
         LLAMA_1B_BENCH,
         MIXTRAL_BENCH,
     )

@@ -1,7 +1,8 @@
 """Hybrid stacks: a layer pattern that is data (ModelConfig.layer_pattern).
 
-Each layer is `x ← x + f(RMSNorm(x))` with ONE of three bodies, chosen by
-its character of the pattern:
+Each entry is `x ← x + f(RMSNorm(x))` with ONE of five bodies, chosen by
+its character of the pattern; a published layer that is an operator and a
+feed-forward part under two norms is two entries:
 
 - "M", a Mamba-2 mixer: `[z | xBC | dt] = W_in u`; xBC through a causal
   depthwise conv and silu, split into x [H, P], B and C [G, N];
@@ -10,21 +11,30 @@ its character of the pattern:
   `W_out RMSNorm_grouped(y · silu(z))`. What a sequence carries between
   dispatches is h [H, P, N] (float32) and the conv's last K−1 columns:
   the per-slot state of engine/kv_cache.py `SlotState`.
+- "C", a gated short convolution: `[B | C | u] = W_in h`; a causal
+  depthwise conv of K taps over B ⊙ u, no bias, no activation;
+  `W_out (C ⊙ conv)`. What a sequence carries is the conv's last K−1
+  columns alone, through the helpers the mixer's conv uses
+  (`_window_decode`, `_window_prefill`).
 - "*", attention over the paged K/V pool: the projections, the paged
-  write and the kernels of models/transformer.py `forward_paged`, with no
-  position embedding where `cfg.use_rope` is off.
-- "E", a latent expert layer (ops/moe.py `moe_latent_held`).
+  write and the kernels of models/transformer.py `forward_paged`, with
+  RMSNorm over each q and k head first where `cfg.qk_norm` says so, and
+  no position embedding where `cfg.use_rope` is off.
+- "E", an expert layer (ops/moe.py `moe_held`): latent un-gated experts
+  with a shared expert, or gated experts on the full hidden.
+- "D", a dense gated MLP (models/layers.py `mlp`).
 
 Parameters are grouped by kind, `params["layers"][kind]` a tuple with one
 tree per layer of that kind in pattern order, and the stack walks the
 pattern unrolled. (Not stacked on a leading axis: a static slice of a
 stacked leaf may be materialised, and an expert leaf here is 0.7 GB.)
 
-A decode step (T = 1) advances the recurrence one token for the active
-lanes (`ssm_state_update`, ops/hybrid_kernels.py). A prefill dispatch
-runs the chunked (SSD) form over chunks of `cfg.ssm_chunk`, whose
-inter-chunk pass also carries state from one ROW of the dispatch to the
-next when the rows are consecutive windows of one prompt (`PrefillRows`).
+A decode step (T = 1) advances the state one token for the active lanes
+(the mixer's recurrence: `ssm_state_update`, ops/hybrid_kernels.py). A
+prefill dispatch runs the mixer's chunked (SSD) form over chunks of
+`cfg.ssm_chunk`, whose inter-chunk pass also carries state from one ROW of
+the dispatch to the next when the rows are consecutive windows of one
+prompt (`PrefillRows`); a conv's columns pass from row to row the same way.
 """
 
 from __future__ import annotations
@@ -35,12 +45,13 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import hybrid_kernels
-from ..ops.moe import moe_latent_held
+from ..ops.moe import moe_held
 from .config import ModelConfig
-from .layers import qkv_project, rms_norm, rope
+from .layers import init_mlp_params, mlp, qkv_project, rms_norm, rope
 from .quant import embed_lookup, qdot
 
-KINDS = {"M": "mamba", "*": "attention", "E": "moe"}
+KINDS = {"M": "mamba", "C": "conv", "*": "attention", "E": "moe",
+         "D": "dense"}
 
 # Where a prefill row's state starts (PrefillRows.source).
 FROM_ZERO, FROM_SLOT, FROM_PREVIOUS_ROW = 0, 1, 2
@@ -108,22 +119,47 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
             "gate_norm": jnp.ones((inner,), dtype),
             "w_out": _normal(k[3], (inner, h), dtype, inner),
         }
+    if kind == "conv":
+        return {
+            "norm": gain,
+            "w_in": _normal(k[0], (h, 3 * h), dtype, h),
+            "conv_w": _normal(k[1], (cfg.conv_kernel, h), dtype,
+                              cfg.conv_kernel),
+            "w_out": _normal(k[2], (h, h), dtype, h),
+        }
     if kind == "attention":
         q, kv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
-        return {
+        layer = {
             "norm": gain,
             "wq": _normal(k[0], (h, q), dtype, h),
             "wk": _normal(k[1], (h, kv), dtype, h),
             "wv": _normal(k[2], (h, kv), dtype, h),
             "wo": _normal(k[3], (q, h), dtype, q),
         }
+        if cfg.qk_norm:
+            layer["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
+            layer["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
+        return layer
+    if kind == "dense":
+        return {"norm": gain,
+                **init_mlp_params(k[0], h, cfg.dense_intermediate_size, dtype)}
     latent, inner = cfg.moe_latent_size, cfg.intermediate_size
     shared, held = cfg.moe_shared_intermediate, cfg.experts_held
-    return {
+    router = {
         "norm": gain,
         "router": _normal(k[0], (h, cfg.n_routed_experts), dtype, h),
         "router_bias": jax.random.normal(
             k[7], (cfg.n_routed_experts,), jnp.float32) * 0.02,
+    }
+    if not latent:
+        return {
+            **router,
+            "gate": _normal(k[1], (held, h, inner), dtype, h),
+            "up": _normal(k[2], (held, h, inner), dtype, h),
+            "down": _normal(k[3], (held, inner, h), dtype, inner),
+        }
+    return {
+        **router,
         "fc1": _normal(k[1], (h, latent), dtype, h),
         "fc2": _normal(k[2], (latent, h), dtype, latent),
         "up": _normal(k[3], (held, latent, inner), dtype, latent),
@@ -173,15 +209,53 @@ def _split_xbc(xbc: jax.Array, cfg: ModelConfig):
     return x, Bm, Cm
 
 
-def _conv(ext: jax.Array, p: dict, T: int) -> jax.Array:
-    """silu(causal depthwise conv + bias) over `ext` [.., K−1+T, C], whose
-    first K−1 columns are what came before."""
+def _taps(ext: jax.Array, p: dict, T: int) -> jax.Array:
+    """The causal depthwise conv (float32) over `ext` [.., K−1+T, C],
+    whose first K−1 columns are what came before."""
     w = p["conv_w"].astype(jnp.float32)
-    taps = w.shape[0]
-    out = sum(
-        ext[..., k:k + T, :].astype(jnp.float32) * w[k] for k in range(taps)
-    ) + p["conv_b"].astype(jnp.float32)
+    return sum(
+        ext[..., k:k + T, :].astype(jnp.float32) * w[k]
+        for k in range(w.shape[0])
+    )
+
+
+def _conv(ext: jax.Array, p: dict, T: int) -> jax.Array:
+    """The mixer's: silu(conv + bias)."""
+    out = _taps(ext, p, T) + p["conv_b"].astype(jnp.float32)
     return jax.nn.silu(out).astype(ext.dtype)
+
+
+def _window_decode(conv, col, active):
+    """One new column for every lane: conv [B, K−1, C] the stored
+    columns, col [B, C]. Returns (ext [B, K, C], the stored columns after
+    the step: shifted for the `active` lanes, as they were for the rest)."""
+    ext = jnp.concatenate([conv, col[:, None, :]], axis=1)
+    return ext, jnp.where(active[:, None, None], ext[:, 1:], conv)
+
+
+def _window_prefill(conv, cols, rows: PrefillRows, taps: int):
+    """N rows of T new columns: cols [N, T, C], conv the stored columns of
+    the WHOLE slot batch. The K−1 columns before each row are nothing, the
+    slot's, or the tail of the row above (a full window: every one of its
+    columns is real). Returns (ext [N, K−1+T, C], the columns after each
+    row's last REAL token [N, K−1, C]: columns length .. length+K−2 of
+    ext — a shorter row reaches back into what came before)."""
+    T, source = cols.shape[1], rows.source
+    above = jnp.roll(cols[:, T - (taps - 1):], 1, axis=0)
+    before = jnp.where(
+        (source == FROM_PREVIOUS_ROW)[:, None, None], above,
+        jnp.where((source == FROM_SLOT)[:, None, None], conv[rows.slot], 0),
+    ).astype(cols.dtype)
+    ext = jnp.concatenate([before, cols], axis=1)
+    end = jax.vmap(
+        lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, taps - 1, axis=0)
+    )(ext, rows.length)
+    return ext, end
+
+
+def _store_rows(conv, end, rows: PrefillRows):
+    """The end columns of every row that `rows.store` keeps, to its slot."""
+    return conv.at[rows.store].set(end.astype(conv.dtype), mode="drop")
 
 
 def _gated_out(p: dict, y, z, cfg: ModelConfig):
@@ -202,9 +276,8 @@ def mamba_decode(p: dict, u, cfg: ModelConfig, ssm, conv, active):
     (Δ = 0 leaves h as it is, bit for bit; the conv window does not
     shift). Returns (out [B, hidden], ssm, conv)."""
     z, xbc, dt = _split_in(p, u, cfg)
-    ext = jnp.concatenate([conv, xbc[:, None, :]], axis=1)      # [B, K, C]
+    ext, conv = _window_decode(conv, xbc, active)               # [B, K, C]
     x, Bm, Cm = _split_xbc(_conv(ext, p, 1)[:, 0], cfg)
-    conv = jnp.where(active[:, None, None], ext[:, 1:], conv)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     dt = jnp.where(active[:, None], dt, 0.0)                    # [B, H]
     dA = jnp.exp(dt * -jnp.exp(p["A_log"]))
@@ -289,35 +362,49 @@ def mamba_prefill(p: dict, u, cfg: ModelConfig, ssm, conv,
     state of the WHOLE slot batch. Returns (out, ssm, conv) with the end
     state of every row that `rows.store` keeps written to its slot."""
     N, T, _ = u.shape
-    taps = cfg.conv_kernel
     z, xbc, dt = _split_in(p, u, cfg)
-    source = rows.source
-    # The K−1 columns before each row: nothing, the slot's, or the tail of
-    # the row above (a full window: every one of its columns is real).
-    above = jnp.roll(xbc[:, T - (taps - 1):], 1, axis=0)
-    before = jnp.where(
-        (source == FROM_PREVIOUS_ROW)[:, None, None], above,
-        jnp.where((source == FROM_SLOT)[:, None, None], conv[rows.slot], 0),
-    ).astype(xbc.dtype)
-    ext = jnp.concatenate([before, xbc], axis=1)                # [N, K−1+T, C]
+    ext, conv_end = _window_prefill(conv, xbc, rows, cfg.conv_kernel)
     x, Bm, Cm = _split_xbc(_conv(ext, p, T), cfg)
-    # The conv state after the last REAL token: columns length .. length+K−2
-    # of ext (a shorter row reaches back into `before`).
-    conv_end = jax.vmap(
-        lambda e, n: jax.lax.dynamic_slice_in_dim(e, n, taps - 1, axis=0)
-    )(ext, rows.length)
     real = jnp.arange(T)[None, :] < rows.length[:, None]
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     dt = jnp.where(real[..., None], dt, 0.0)
     xf = x.astype(jnp.float32)
     y, ssm_end = ssd_chunks(
         xf, dt, -jnp.exp(p["A_log"]), Bm.astype(jnp.float32),
-        Cm.astype(jnp.float32), ssm[rows.slot], source, cfg.ssm_chunk,
+        Cm.astype(jnp.float32), ssm[rows.slot], rows.source, cfg.ssm_chunk,
     )
     y = y + p["D"][:, None] * xf
     ssm = ssm.at[rows.store].set(ssm_end, mode="drop")
-    conv = conv.at[rows.store].set(conv_end.astype(conv.dtype), mode="drop")
-    return _gated_out(p, y, z, cfg), ssm, conv
+    return _gated_out(p, y, z, cfg), ssm, _store_rows(conv, conv_end, rows)
+
+
+# -- the gated short convolution ---------------------------------------------
+
+
+def _conv_in(p: dict, u):
+    """(B ⊙ u, the columns the conv runs over; C, the gate of its output)."""
+    h = u.shape[-1]
+    bcu = qdot(u, p["w_in"])
+    return bcu[..., :h] * bcu[..., 2 * h:], bcu[..., h:2 * h]
+
+
+def conv_decode(p: dict, u, conv, active):
+    """One token for every lane: u [B, hidden], conv [B, K−1, hidden]. A
+    lane that is not `active` keeps its columns. Returns (out, conv)."""
+    z, gate = _conv_in(p, u)
+    ext, conv = _window_decode(conv, z, active)
+    y = _taps(ext, p, 1)[:, 0].astype(u.dtype)
+    return qdot(gate * y, p["w_out"]), conv
+
+
+def conv_prefill(p: dict, u, cfg: ModelConfig, conv, rows: PrefillRows):
+    """N windows of T tokens: u [N, T, hidden]; conv the stored columns of
+    the WHOLE slot batch. Returns (out, conv) with the columns after the
+    last real token of every row that `rows.store` keeps written."""
+    z, gate = _conv_in(p, u)
+    ext, end = _window_prefill(conv, z, rows, cfg.conv_kernel)
+    y = _taps(ext, p, u.shape[1]).astype(u.dtype)
+    return qdot(gate * y, p["w_out"]), _store_rows(conv, end, rows)
 
 
 # -- the stack -------------------------------------------------------------
@@ -327,6 +414,9 @@ def attention_layer(p: dict, h, positions, cfg: ModelConfig, attend, idx,
                     pool):
     B, T, _ = h.shape
     q, k, v = qkv_project(p, h, cfg)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
     if cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
@@ -348,22 +438,33 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
     x = embed_lookup(params["embed"], tokens)
     ssm = list(state.ssm) if state is not None else []
     conv = list(state.conv) if state is not None else []
+    held = 0                    # stateful layers so far: the index in `conv`
     for kind, idx in layer_kinds(cfg):
         p = params["layers"][kind][idx]
         h = rms_norm(x, p["norm"], eps)
         if kind == "mamba":
             if decode:
-                out, ssm[idx], conv[idx] = mamba_decode(
-                    p, h[:, 0], cfg, ssm[idx], conv[idx], active)
+                out, ssm[idx], conv[held] = mamba_decode(
+                    p, h[:, 0], cfg, ssm[idx], conv[held], active)
                 out = out[:, None]
             else:
-                out, ssm[idx], conv[idx] = mamba_prefill(
-                    p, h, cfg, ssm[idx], conv[idx], rows)
+                out, ssm[idx], conv[held] = mamba_prefill(
+                    p, h, cfg, ssm[idx], conv[held], rows)
+            held += 1
+        elif kind == "conv":
+            if decode:
+                out, conv[held] = conv_decode(p, h[:, 0], conv[held], active)
+                out = out[:, None]
+            else:
+                out, conv[held] = conv_prefill(p, h, cfg, conv[held], rows)
+            held += 1
         elif kind == "attention":
             out, pool = attention_layer(
                 p, h, positions, cfg, attend, idx, pool)
+        elif kind == "dense":
+            out = mlp(p, h, cfg.activation)
         else:
-            out = moe_latent_held(p, h, cfg)
+            out = moe_held(p, h, cfg)
         x = x + out
     x = rms_norm(x, params["final_norm"], eps)
     if state is not None:

@@ -1,4 +1,4 @@
-"""Pallas kernels of the hybrid stack's two decode-bound layers (TPU).
+"""Pallas kernels of the hybrid stacks' two decode-bound layers (TPU).
 
 `ssm_state_update` — one decode step of the Mamba-2 recurrence for every
 lane: h ← dA · h + (Δ·x) ⊗ B, y = h · C, with the float32 state
@@ -13,14 +13,17 @@ shape (my chip runs, PR 43): 0.906 ms a call against 0.656 of bytes; the
 form with the decay broadcast from a column and the reduction over lanes
 on the vector units read 1.167, XLA's own fusion 0.871.
 
-`moe_held_experts` — the held experts of a latent expert layer as ONE
-pass over their weights: for every held expert e, relu(v · W_up[e])² ·
-w[:, e] · W_down[e], summed over e into a float32 [rows, latent]. Every
-expert's weights are read whatever the routing chose (the combine weight
-of an expert a row did not choose is 0): a step's work is fixed by rows ×
-experts held, not by the seed. Rows tile outermost, so the output tile
-stays resident while the experts stream past; a dispatch wider than one
-row tile re-reads the weights once per tile.
+`moe_held_experts` — the held experts of an expert layer as ONE pass over
+their weights: for every held expert e, a(v, e) · w[:, e] · W_down[e],
+summed over e into a float32 [rows, width of v]. Two instances, chosen by
+static arguments: un-gated, a = relu(v · W_up[e])² (the experts of a
+latent layer), and gated, a = silu(v · W_gate[e]) ⊙ (v · W_up[e]) (experts
+on the full hidden: three matrices an expert). Every expert's weights are
+read whatever the routing chose (the combine weight of an expert a row did
+not choose is 0): a step's work is fixed by rows × experts held, not by
+the seed. Rows tile outermost, so the output tile stays resident while
+the experts stream past; a dispatch wider than one row tile re-reads the
+weights once per tile.
 
 Off-TPU both run the same mathematics in jax.numpy (`*_jnp`); the tests
 run the kernels in interpret mode against them.
@@ -28,10 +31,14 @@ run the kernels in interpret mode against them.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..models.layers import _activate
 
 _VMEM_LIMIT = 64 * 1024 * 1024
 MOE_ROW_TILE = 512
@@ -122,19 +129,30 @@ def ssm_state_update(h, dA, xdt, Bm, Cm, *, interpret: bool = False):
     return new, y.transpose(0, 1, 3, 2).reshape(B, H, P)
 
 
-# -- held experts of a latent expert layer ---------------------------------
+# -- held experts of an expert layer ---------------------------------------
 
-
-def moe_held_experts_jnp(v, up, down, weights):
+def moe_held_experts_jnp(v, up, down, weights, *, gate=None,
+                         activation: str = "relu2"):
     """v [R, L], up [E, L, I], down [E, I, L], weights [R, E] float32
-    (0 where a row did not choose the expert) → float32 [R, L]."""
+    (0 where a row did not choose the expert) → float32 [R, L]. `gate`
+    [E, L, I]: the gated form, activation(v · gate) ⊙ (v · up)."""
     h = jnp.einsum("rl,eli->eri", v, up, preferred_element_type=jnp.float32)
-    a = jnp.square(jax.nn.relu(h)) * weights.T[:, :, None]
+    if gate is None:
+        a = _activate(h, activation)
+    else:
+        a = _activate(jnp.einsum("rl,eli->eri", v, gate,
+                                 preferred_element_type=jnp.float32),
+                      activation) * h
+    a = a * weights.T[:, :, None]
     return jnp.einsum("eri,eil->rl", a.astype(v.dtype), down,
                       preferred_element_type=jnp.float32)
 
 
-def _moe_kernel(v_ref, w_ref, up_ref, down_ref, out_ref):
+def _moe_kernel(*refs, activation: str, gated: bool):
+    if gated:
+        v_ref, w_ref, gate_ref, up_ref, down_ref, out_ref = refs
+    else:
+        v_ref, w_ref, up_ref, down_ref, out_ref = refs
     first = (pl.program_id(1) == 0) & (pl.program_id(2) == 0)
 
     @pl.when(first)
@@ -143,14 +161,20 @@ def _moe_kernel(v_ref, w_ref, up_ref, down_ref, out_ref):
 
     v = v_ref[...]
     h = jnp.dot(v, up_ref[...], preferred_element_type=jnp.float32)
-    a = jnp.square(jnp.maximum(h, 0.0)) * w_ref[...]
+    if gated:
+        a = _activate(jnp.dot(v, gate_ref[...],
+                              preferred_element_type=jnp.float32),
+                      activation) * h
+    else:
+        a = _activate(h, activation)
+    a = a * w_ref[...]
     out_ref[...] += jnp.dot(a.astype(v.dtype), down_ref[...],
                             preferred_element_type=jnp.float32)
 
 
 def _inner_tile(inner: int) -> int:
     """The widest 128-aligned divisor of the experts' width up to 1024
-    (2688 → 896): a block of each weight is then under 2 MiB."""
+    (2688 → 896, 1536 → 768): a block of each weight is then a few MiB."""
     best = inner
     for t in range(128, min(inner, 1024) + 1, 128):
         if inner % t == 0:
@@ -158,7 +182,8 @@ def _inner_tile(inner: int) -> int:
     return best
 
 
-def moe_held_experts(v, up, down, weights, *, interpret: bool = False):
+def moe_held_experts(v, up, down, weights, *, gate=None,
+                     activation: str = "relu2", interpret: bool = False):
     """The kernel form of `moe_held_experts_jnp`."""
     R, L = v.shape
     E, _, inner = up.shape
@@ -172,13 +197,16 @@ def moe_held_experts(v, up, down, weights, *, interpret: bool = False):
     # [E, rows, 1]: an expert's weights as a column that broadcasts along
     # the activation's lanes.
     wcol = weights.astype(jnp.float32).T[:, :, None]
+    into = pl.BlockSpec((None, L, it), lambda r, e, i: (e, 0, i))
+    ins = [up] if gate is None else [gate, up]
     out = pl.pallas_call(
-        _moe_kernel,
+        functools.partial(_moe_kernel, activation=activation,
+                          gated=gate is not None),
         grid=(rows // tile, E, inner // it),
         in_specs=[
             pl.BlockSpec((tile, L), lambda r, e, i: (r, 0)),
             pl.BlockSpec((None, tile, 1), lambda r, e, i: (e, r, 0)),
-            pl.BlockSpec((None, L, it), lambda r, e, i: (e, 0, i)),
+            *[into] * len(ins),
             pl.BlockSpec((None, it, L), lambda r, e, i: (e, i, 0)),
         ],
         out_specs=pl.BlockSpec((tile, L), lambda r, e, i: (r, 0)),
@@ -189,5 +217,5 @@ def moe_held_experts(v, up, down, weights, *, interpret: bool = False):
         ),
         interpret=interpret,
         name="moe_held_experts",
-    )(v, wcol, up, down)
+    )(v, wcol, *ins, down)
     return out[:R]

@@ -1,6 +1,7 @@
-"""Mixture-of-Experts layer (Mixtral-style top-k routing).
+"""Mixture-of-Experts layers.
 
-Two formulations:
+Mixtral-style softmax top-k routing, in two formulations, and the held
+experts of a sigmoid-routed layer (`moe_held`), in two forms:
 
 - `moe_mlp` — einsum-dense: every token runs through every expert, weighted
   by the (sparse) combine matrix. Simple, fully differentiable, and shards
@@ -10,10 +11,12 @@ Two formulations:
   compute" layout. Cost: num_experts/top_k × the FLOPs of sparse dispatch
   (4× for Mixtral 8×7B's 8-choose-2) — acceptable for correctness paths and
   small batches.
-- `moe_latent_held` — one chip's share of a latent expert layer: a sigmoid
-  router over every published expert, the held experts' part of the sum
-  (ops/hybrid_kernels.py `moe_held_experts`) plus the shared expert. No
-  capacity, no drop.
+- `moe_held` — one chip's share of a sigmoid-routed expert layer: the
+  router over every published expert, and the held experts' part of the
+  sum as one pass over their weights (ops/hybrid_kernels.py
+  `moe_held_experts`). No capacity, no drop. `moe_latent_held`: un-gated
+  relu² experts inside a latent, plus a shared expert; `moe_gated_held`:
+  gated experts on the full hidden.
 - `moe_mlp_dispatch` — capacity-bucketed sparse dispatch: tokens gather into
   per-expert buckets (static capacity, dropped on overflow like GShard/
   Switch), experts run batched matmuls on their buckets only, results
@@ -133,17 +136,57 @@ def latent_router_weights(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Combine weights [.., n_routed_experts] (float32, 0 off the chosen):
     sigmoid scores; the top `num_experts_per_tok` of score + correction
     bias are chosen; each takes its own score over the sum of ALL the
-    chosen scores — held here or not — times `routed_scaling_factor`."""
+    chosen scores — held here or not — (+ `router_norm_eps` where the
+    model adds one) times `routed_scaling_factor`."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "...h,he->...e", h, p["router"], preferred_element_type=jnp.float32,
     ))
     _, idx = jax.lax.top_k(scores + p["router_bias"], cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
-    chosen = chosen * (
-        cfg.routed_scaling_factor / jnp.sum(chosen, axis=-1, keepdims=True)
-    )
+    total = jnp.sum(chosen, axis=-1, keepdims=True)
+    if cfg.router_norm_eps:
+        total = total + cfg.router_norm_eps
+    chosen = chosen * (cfg.routed_scaling_factor / total)
     onehot = jax.nn.one_hot(idx, cfg.n_routed_experts, dtype=jnp.float32)
     return jnp.sum(onehot * chosen[..., None], axis=-2)
+
+
+def _held_weights(p: dict, tokens: jax.Array, cfg: ModelConfig):
+    """(combine weights of the held experts [rows, held], the product)."""
+    weights = latent_router_weights(p, tokens, cfg)[
+        :, cfg.first_expert:cfg.first_expert + cfg.experts_held
+    ]
+    held = (hybrid_kernels.moe_held_experts if hybrid_kernels.use_kernels()
+            else hybrid_kernels.moe_held_experts_jnp)
+    return weights, held
+
+
+def moe_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The expert layer of a layer pattern ("E"), by what the config
+    states: a latent → `moe_latent_held`; none → `moe_gated_held`. A
+    combination neither computes is refused here, not half-served."""
+    if cfg.moe_latent_size:
+        return moe_latent_held(p, h, cfg)
+    if cfg.moe_shared_intermediate:
+        raise ValueError(
+            "a shared expert beside gated experts on the full hidden is "
+            "not computed: moe_shared_intermediate needs moe_latent_size")
+    return moe_gated_held(p, h, cfg)
+
+
+def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Gated experts on the full hidden as the chip that holds experts
+    `[first_expert, first_expert + experts_held)` computes them:
+    [B, T, H] → [B, T, H], Σ_e w_e · (act(h W_gate,e) ⊙ h W_up,e) W_down,e
+    over the chosen experts that are held. The router, the masking and the
+    work are `moe_latent_held`'s: every held expert's three matrices are
+    read once whatever the routing chose, and no token is dropped."""
+    B, T, H = h.shape
+    tokens = h.reshape(B * T, H)
+    weights, held = _held_weights(p, tokens, cfg)
+    out = held(tokens, p["up"], p["down"], weights, gate=p["gate"],
+               activation=cfg.activation)
+    return out.astype(h.dtype).reshape(B, T, H)
 
 
 def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -161,11 +204,7 @@ def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
         raise ValueError("the held-experts product computes relu(up)² only")
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
-    weights = latent_router_weights(p, tokens, cfg)[
-        :, cfg.first_expert:cfg.first_expert + cfg.experts_held
-    ]
-    held = (hybrid_kernels.moe_held_experts if hybrid_kernels.use_kernels()
-            else hybrid_kernels.moe_held_experts_jnp)
+    weights, held = _held_weights(p, tokens, cfg)
     routed = held(qdot(tokens, p["fc1"]), p["up"], p["down"], weights)
     out = qdot(routed.astype(h.dtype), p["fc2"])
     shared = _activate(qdot(tokens, p["shared_up"]), cfg.activation)

@@ -17,8 +17,6 @@ size, on LOGITS. Tolerances, and why:
 
 import dataclasses
 import os
-import queue
-import time
 
 import jax
 import jax.numpy as jnp
@@ -27,25 +25,25 @@ import pytest
 
 import reference_nemotron_h as ref
 from polykey_tpu.engine.config import EngineConfig
-from polykey_tpu.engine.engine import GenRequest, InferenceEngine
-from polykey_tpu.engine.kv_cache import init_paged_kv, init_slot_state
+from polykey_tpu.engine.engine import InferenceEngine
 from polykey_tpu.models.config import MODEL_REGISTRY, get_config
 from polykey_tpu.models.hybrid import (
     FROM_PREVIOUS_ROW,
     FROM_SLOT,
     FROM_ZERO,
-    PrefillRows,
     ssd_chunks,
 )
-from polykey_tpu.models.transformer import forward_slots, init_params, unembed
+from polykey_tpu.models.transformer import init_params
 from polykey_tpu.ops import hybrid_kernels
 from polykey_tpu.ops.moe import moe_latent_held
+from pattern_stack import SlotBatch, served, text, worst_margin
 
 F32_TOL = 2e-4
 STATE_TOL = 1e-5
 CFG = get_config("tiny-hybrid")
-SLOTS, PAGE, PAGES_PER_SEQ = 4, 8, 24
-NOWHERE = SLOTS          # a store index past the last slot: dropped
+BATCH = SlotBatch(CFG, ref, F32_TOL)
+fresh, prefill, decode, decode_tail = (
+    BATCH.fresh, BATCH.prefill, BATCH.decode, BATCH.decode_tail)
 
 
 @pytest.fixture(scope="module")
@@ -57,60 +55,6 @@ def params():
 def tokens():
     return np.asarray(
         jax.random.randint(jax.random.PRNGKey(1), (200,), 3, 130), np.int32)
-
-
-def fresh():
-    return (init_paged_kv(CFG, 1 + SLOTS * PAGES_PER_SEQ, PAGE, jnp.float32),
-            init_slot_state(CFG, SLOTS, jnp.float32))
-
-
-def table(slot):
-    first = 1 + slot * PAGES_PER_SEQ
-    return np.arange(first, first + PAGES_PER_SEQ, dtype=np.int32)
-
-
-def prefill(params, paged, state, slot, ids, start, width, sources,
-            store_last=True):
-    """`ids` from position `start` as len(sources) rows of `width` in ONE
-    dispatch; returns (logits of the real positions, paged, state)."""
-    n = len(sources)
-    toks = np.zeros((n, width), np.int32)
-    lengths = []
-    for r in range(n):
-        part = ids[r * width:(r + 1) * width]
-        toks[r, :len(part)] = part
-        lengths.append(len(part))
-    positions = start + np.arange(n)[:, None] * width + np.arange(width)[None]
-    store = [NOWHERE] * (n - 1) + [slot if store_last else NOWHERE]
-    rows = PrefillRows(
-        jnp.full((n,), slot, jnp.int32), jnp.asarray(sources, jnp.int32),
-        jnp.asarray(store, jnp.int32), jnp.asarray(lengths, jnp.int32))
-    hidden, paged, state = forward_slots(
-        params, CFG, jnp.asarray(toks), jnp.asarray(positions, jnp.int32),
-        paged, jnp.tile(table(slot)[None], (n, 1)), state, rows=rows)
-    logits = unembed(params, CFG, hidden.reshape(n * width, -1)[:len(ids)])
-    return np.asarray(logits), paged, state
-
-
-def decode(params, paged, state, slot, token, position, active=True):
-    last = np.zeros((SLOTS,), np.int32)
-    pos = np.zeros((SLOTS, 1), np.int32)
-    tables = np.zeros((SLOTS, PAGES_PER_SEQ), np.int32)
-    act = np.zeros((SLOTS,), bool)
-    if active:
-        last[slot], pos[slot, 0], tables[slot] = token, position, table(slot)
-        act[slot] = True
-    hidden, paged, state = forward_slots(
-        params, CFG, jnp.asarray(last)[:, None], jnp.asarray(pos), paged,
-        jnp.asarray(tables), state, active=jnp.asarray(act))
-    return np.asarray(unembed(params, CFG, hidden[slot, 0])), paged, state
-
-
-def decode_tail(params, paged, state, slot, ids, start, want):
-    """Teacher-forced decode of ids[start:], compared with the reference."""
-    for i in range(start, len(ids)):
-        got, paged, state = decode(params, paged, state, slot, ids[i], i)
-        np.testing.assert_allclose(got, want[i], atol=F32_TOL, rtol=0)
 
 
 def test_reference_copy_is_the_benchmarks_file():
@@ -295,8 +239,7 @@ def test_bfloat16_fails_the_float32_tolerance(params, tokens):
     want = ref.forward(params, CFG, ids)
     low = jax.tree.map(
         lambda w: w.astype(jnp.bfloat16) if w.ndim > 1 else w, params)
-    paged = init_paged_kv(CFG, 1 + SLOTS * PAGES_PER_SEQ, PAGE, jnp.bfloat16)
-    state = init_slot_state(CFG, SLOTS, jnp.bfloat16)
+    paged, state = fresh(jnp.bfloat16)
     got, _, _ = prefill(low, paged, state, 0, ids, 0, 16, [FROM_ZERO])
     assert np.max(np.abs(got - want)) > 10 * F32_TOL
 
@@ -317,40 +260,6 @@ def engine():
     eng.shutdown()
 
 
-def served(engine, prompts, new=10):
-    requests = [GenRequest(prompt=p, max_new_tokens=new) for p in prompts]
-    for request in requests:
-        engine.submit(request)
-    out = []
-    for request in requests:
-        ids, deadline = [], time.monotonic() + 120
-        while True:
-            kind, value = request.out.get(timeout=deadline - time.monotonic())
-            if kind == "token":
-                ids.append(value)
-            elif kind == "done":
-                break
-            else:
-                raise AssertionError(value)
-        out.append(ids)
-    return out
-
-
-def worst_margin(engine, prompt, ids):
-    """Teacher-force the reference with the served tokens: how far below
-    the reference's best logit each served token lies, at worst."""
-    prompt_ids = engine.tokenizer.encode(prompt)
-    logits = ref.forward(engine.params, engine.model_cfg,
-                         np.asarray(prompt_ids + ids[:-1], np.int32))
-    rows = logits[len(prompt_ids) - 1:]
-    return max(float(np.max(row) - row[t]) for row, t in zip(rows, ids))
-
-
-def text(n, salt):
-    rng = np.random.default_rng(salt)
-    return "".join(chr(c) for c in rng.integers(0x20, 0x7F, n - 1))
-
-
 @pytest.mark.parametrize("tokens_in,reset,chained,resumed", [
     (10, 1, 0, 0),       # one window
     (28, 1, 1, 0),       # two 16-rows of one dispatch
@@ -364,7 +273,7 @@ def test_engine_serves_what_the_reference_computes(
     (ids,) = served(engine, [prompt])
     assert len(ids) == 10
     # A served token is the reference's argmax up to summation order.
-    assert worst_margin(engine, prompt, ids) <= F32_TOL
+    assert worst_margin(ref, engine, prompt, ids) <= F32_TOL
     after = engine.stats()
     moved = {k: after[k] - before[k] for k in (
         "state_slots_reset", "state_windows_chained", "state_chunks_resumed")}
@@ -381,7 +290,7 @@ def test_engine_reuses_slots_and_batches_lanes(engine):
     outs = served(engine, prompts, new=9)
     for prompt, ids in zip(prompts, outs):
         assert len(ids) == 9
-        assert worst_margin(engine, prompt, ids) <= F32_TOL
+        assert worst_margin(ref, engine, prompt, ids) <= F32_TOL
 
 
 def test_engine_stats_name_the_state(engine):

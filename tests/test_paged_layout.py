@@ -269,19 +269,15 @@ def test_prefill_step_compiled_for_v5e_moves_no_pool(v5e, tp, rows):
     ) == 1
 
 
-def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch):
-    """The decode step of a hybrid stack (models/hybrid.py) compiled for a
-    v5e at the published widths of its two new kernels — a state of
-    [slots, 128, 64, 128] float32 a mixer layer, experts of 1024 x 2688 —
-    at toy depth and with 8 experts held: the per-slot state and the pool
-    are aliased input to output (donated, updated in place), no
-    instruction copies a state-sized buffer, and both kernels are there
-    under the names the benchmark's readers hold fixed."""
+def _compile_pattern_step(v5e, monkeypatch, cfg, lanes=64, prefill=None):
+    """(compiled, paged, state): the engine's decode step — its prefill
+    step at `prefill` = (rows, width) — of a layer pattern with per-slot
+    state, lowered from shapes for one described v5e chip with every
+    kernel gate answering as the chip would."""
     from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
 
     from polykey_tpu.engine.kv_cache import init_slot_state
-    from polykey_tpu.models.config import get_config
     from polykey_tpu.ops import hybrid_kernels, paged_attention_kernel
 
     cache_was_on = jax.config.jax_enable_compilation_cache
@@ -291,15 +287,6 @@ def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch)
         paged_attention_kernel, "use_paged_kernel", lambda Hk, D: True
     )
     monkeypatch.setattr(hybrid_kernels, "use_kernels", lambda: True)
-    cfg = replace(
-        get_config("tiny-hybrid"), name="hybrid-probe", hidden_size=512,
-        layer_pattern="M*E", num_layers=3, num_heads=4, num_kv_heads=2,
-        head_dim=128, mamba_num_heads=128, mamba_head_dim=64,
-        ssm_state_size=128, ssm_groups=8, ssm_chunk=128,
-        intermediate_size=2688, moe_latent_size=1024,
-        moe_shared_intermediate=256, n_routed_experts=32, experts_held=8,
-        num_experts_per_tok=6,
-    )
     one = SingleDeviceSharding(v5e.devices[0])
 
     def shaped(tree):
@@ -308,7 +295,7 @@ def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch)
             tree,
         )
 
-    B, pages, ps, tables = 64, 1024, 16, 8
+    B, pages, ps, tables = lanes, 1024, 16, 8
     params = shaped(jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)))
     paged = shaped(jax.eval_shape(
@@ -320,22 +307,65 @@ def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch)
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     try:
-        compiled = jax.jit(
-            engine_mod._decode_fn,
-            static_argnames=("cfg", "greedy", "steps", "eos_id", "candidates",
-                             "mesh"),
-            donate_argnames=("paged", "last_tokens", "seq_lens", "active",
-                             "state"),
-        ).lower(
-            params, cfg, paged, arg((B,), jnp.int32), arg((B,), jnp.int32),
-            arg((B, tables), jnp.int32), arg((B,), jnp.bool_),
-            arg((B,), jnp.int32), arg((B, 2), jnp.int32),
-            arg((B,), jnp.float32), arg((B,), jnp.float32),
-            arg((B,), jnp.int32), state,
-            greedy=True, steps=2, eos_id=-1, candidates=0, mesh=None,
-        ).compile()
+        if prefill is None:
+            compiled = jax.jit(
+                engine_mod._decode_fn,
+                static_argnames=("cfg", "greedy", "steps", "eos_id",
+                                 "candidates", "mesh"),
+                donate_argnames=("paged", "last_tokens", "seq_lens", "active",
+                                 "state"),
+            ).lower(
+                params, cfg, paged, arg((B,), jnp.int32), arg((B,), jnp.int32),
+                arg((B, tables), jnp.int32), arg((B,), jnp.bool_),
+                arg((B,), jnp.int32), arg((B, 2), jnp.int32),
+                arg((B,), jnp.float32), arg((B,), jnp.float32),
+                arg((B,), jnp.int32), state,
+                greedy=True, steps=2, eos_id=-1, candidates=0, mesh=None,
+            ).compile()
+        else:
+            N, T = prefill
+            compiled = jax.jit(
+                engine_mod._prefill_fn,
+                static_argnames=("cfg", "greedy", "candidates", "mesh"),
+                donate_argnames=("paged", "state"),
+            ).lower(
+                params, cfg, paged, arg((N, T), jnp.int32),
+                arg((N,), jnp.int32), arg((N,), jnp.int32),
+                arg((N, T // ps), jnp.int32), arg((N, 2), jnp.int32),
+                arg((N,), jnp.float32), arg((N,), jnp.float32),
+                arg((N,), jnp.int32), state, arg((N, 3), jnp.int32),
+                greedy=True, candidates=0, mesh=None,
+            ).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    return compiled, paged, state
+
+
+def _kernel_calls(hlo: str) -> list[str]:
+    return [line.split(" custom-call(")[0].lstrip() for line in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch):
+    """The decode step of a hybrid stack (models/hybrid.py) compiled for a
+    v5e at the published widths of its two new kernels — a state of
+    [slots, 128, 64, 128] float32 a mixer layer, experts of 1024 x 2688 —
+    at toy depth and with 8 experts held: the per-slot state and the pool
+    are aliased input to output (donated, updated in place), no
+    instruction copies a state-sized buffer, and both kernels are there
+    under the names the benchmark's readers hold fixed."""
+    from polykey_tpu.models.config import get_config
+
+    cfg = replace(
+        get_config("tiny-hybrid"), name="hybrid-probe", hidden_size=512,
+        layer_pattern="M*E", num_layers=3, num_heads=4, num_kv_heads=2,
+        head_dim=128, mamba_num_heads=128, mamba_head_dim=64,
+        ssm_state_size=128, ssm_groups=8, ssm_chunk=128,
+        intermediate_size=2688, moe_latent_size=1024,
+        moe_shared_intermediate=256, n_routed_experts=32, experts_held=8,
+        num_experts_per_tok=6,
+    )
+    compiled, paged, state = _compile_pattern_step(v5e, monkeypatch, cfg)
     hlo = compiled.as_text()
     ssm = "f32[64,128,64,128]"
     assert aliased_pool_parameters(hlo, ssm) == 1
@@ -345,10 +375,57 @@ def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch)
     held = sum(x.size * x.dtype.itemsize
                for x in jax.tree.leaves((paged, state)))
     assert stats.alias_size_in_bytes >= held
-    calls = [line.split(" custom-call(")[0].lstrip() for line in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls = _kernel_calls(hlo)
     for name in ("%ssm_state_update", "%moe_held_experts", "%paged_kv_write",
                  "%paged_attention_decode"):
+        assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
+
+
+@pytest.mark.parametrize("prefill", [None, (1, 128), (2, 512)],
+                         ids=["decode", "prefill-1x128", "prefill-2x512"])
+def test_operator_ffn_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
+    """The steps of an operator + feed-forward pattern (a gated short conv,
+    QK-normed RoPE attention, a dense part and gated experts on the full
+    hidden; one tied matrix) compiled for a v5e at the published widths of
+    what it adds — hidden 2048, 32 / 8 heads of 64 (a page half of 512
+    lanes), experts of 2048 x 1536 — at toy depth, 8 experts held and a
+    short vocabulary. Decode: the conv columns and the pool are aliased
+    input to output, every kernel is there once under the name the
+    benchmark's readers hold fixed, and the gated product is the SAME
+    kernel name the un-gated one has. Every module: q and k at head width
+    64 stay plain matmuls (ISSUE 44's three forms are absent), although a
+    norm over each head stands between the product and `rope`."""
+    from polykey_tpu.models.config import get_config
+
+    cfg = replace(
+        get_config("tiny-lfm2"), name="operator-ffn-probe", vocab_size=4096,
+        hidden_size=2048, layer_pattern="CD*E", num_layers=4, num_heads=32,
+        num_kv_heads=8, head_dim=64, intermediate_size=1536,
+        dense_intermediate_size=11776, n_routed_experts=64, experts_held=8,
+    )
+    compiled, paged, state = _compile_pattern_step(
+        v5e, monkeypatch, cfg, prefill=prefill)
+    hlo = compiled.as_text()
+    wk_bytes = cfg.hidden_size * cfg.num_kv_heads * cfg.head_dim * 2
+    assert head_window_products(hlo) == []
+    assert staged_weight_slices(hlo, wk_bytes) == []
+    assert layer_weight_copies(hlo, "bf16", cfg.hidden_size) == []
+    calls = _kernel_calls(hlo)
+    assert sum(c.startswith("%moe_held_experts") for c in calls) == 1, calls
+    assert jax.tree.leaves(state.ssm) == []
+    if prefill is not None:
+        # (At this probe's geometry a pattern's prefill module copies the
+        # pool after its last attending layer's write, the sibling stack's
+        # too; the cell's traced prefill on the chip shows no such copy,
+        # PERF.md section 5. Copies are counted by a weight's shape here.)
+        return
+    assert weight_relayouts(hlo, wk_bytes) == []
+    conv = "bf16[64,2,2048]"
+    assert aliased_pool_parameters(hlo, conv) == 1
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((paged, state)))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    for name in ("%paged_kv_write", "%paged_attention_decode"):
         assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
 
 
