@@ -78,6 +78,7 @@ from ..models.hybrid import (
     PrefillRows,
 )
 from ..models.transformer import forward_slots, unembed
+from ..ops.moe import held_experts_grouped
 from ..parallel.mesh import MeshConfig, create_mesh
 from ..parallel.sharding import (
     init_sharded_params,
@@ -2172,8 +2173,15 @@ class InferenceEngine:
         # token rows for Σ len(ids) real prompt tokens, in n windows; a
         # slot with several rows here is a prompt split over windows.
         rows_of = collections.Counter(row[0] for row in group)
+        rows = n_pad * bucket
+        # An expert layer of a layer pattern ran these rows sorted by
+        # expert, not every row against every held expert: ops/moe.py
+        # decides by the same function.
+        grouped = ("E" in self.model_cfg.layer_pattern
+                   and held_experts_grouped(rows))
         self.metrics.on_prefill_rows(
-            n_pad * bucket, real, n, sum(c > 1 for c in rows_of.values()),
+            rows, real, n, sum(c > 1 for c in rows_of.values()),
+            grouped_experts=rows if grouped else 0,
         )
         if stateful:
             sources = collections.Counter(state_rows[:n, 1].tolist())

@@ -198,6 +198,9 @@ class EngineMetrics:
         # and chunks (on_prefill_rows).
         self.prefill_rows_dispatched = 0
         self.prefill_rows_useful = 0
+        # Of the rows dispatched, those whose expert layers ran the
+        # grouped product (ops/moe.py held_experts_grouped).
+        self.prefill_rows_grouped_experts = 0
         # Prefill dispatches whose first tokens were read, and for each
         # the seconds since the engine last looked at it and found it
         # unfinished (or since its dispatch call returned): an upper
@@ -333,16 +336,19 @@ class EngineMetrics:
             self.tokens_useful_total += useful
 
     def on_prefill_rows(self, dispatched: int, useful: int,
-                        windows: int, split: int) -> None:
+                        windows: int, split: int,
+                        grouped_experts: int = 0) -> None:
         """One bucketed-group or chunk prefill dispatch: `dispatched`
         rows computed (n_pad x bucket, or the chunk width) for `useful`
         real prompt tokens in `windows` real rows, `split` of its
-        prompts covered by more than one of them. Feeds the
-        prefill-only counters and, as before, the mixed padding-waste
-        pair."""
+        prompts covered by more than one of them; `grouped_experts`:
+        `dispatched` again where the model's expert layers ran them as
+        the grouped product, else 0. Feeds the prefill-only counters
+        and, as before, the mixed padding-waste pair."""
         with self._lock:
             self.prefill_rows_dispatched += dispatched
             self.prefill_rows_useful += useful
+            self.prefill_rows_grouped_experts += grouped_experts
             self.prefill_windows_dispatched += windows
             self.prefill_prompts_split += split
             self.tokens_dispatched_total += dispatched
@@ -671,6 +677,8 @@ class EngineMetrics:
                 "decode_lane_steps_dead": self.decode_lane_steps_dead,
                 "prefill_rows_dispatched": self.prefill_rows_dispatched,
                 "prefill_rows_useful": self.prefill_rows_useful,
+                "prefill_rows_grouped_experts":
+                    self.prefill_rows_grouped_experts,
                 "first_token_poll_gap_seconds":
                     round(self.first_token_poll_gap_seconds, 6),
                 "first_token_poll_gap_count":

@@ -202,6 +202,10 @@ _ENGINE_FAMILIES: tuple = (
      "included).", "prefill_rows_dispatched"),
     ("counter", "polykey_prefill_rows_useful_total",
      "Real prompt tokens among those rows.", "prefill_rows_useful"),
+    ("counter", "polykey_prefill_rows_grouped_experts_total",
+     "Of those rows, the rows a layer pattern's expert layers computed "
+     "sorted by expert (the grouped product, from 512 rows up on the "
+     "chip).", "prefill_rows_grouped_experts"),
     ("counter", "polykey_prefill_windows_dispatched_total",
      "Prefill windows (real rows) among those dispatches.",
      "prefill_windows_dispatched"),

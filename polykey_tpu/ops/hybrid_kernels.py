@@ -25,7 +25,22 @@ the seed. Rows tile outermost, so the output tile stays resident while
 the experts stream past; a dispatch wider than one row tile re-reads the
 weights once per tile.
 
-Off-TPU both run the same mathematics in jax.numpy (`*_jnp`); the tests
+`moe_held_experts_grouped` — the same sum over rows SORTED by expert, for
+row counts above the chip's ridge (197 TFLOP/s ÷ 819 GB/s = 240 rows),
+where the masked form is bound by arithmetic nobody asked for (rows ×
+experts held, 16 × the routed work at top-4 of 64): the (row, chosen held
+expert) pairs are put in order of expert (a counting sort over the same
+dense weights), each expert's run padded to a row tile, and ONE call
+walks the tiles with the tile → expert map scalar-prefetched, gathering
+a tile's rows and adding its results back inside the kernel — every held
+expert's weights read once, every pair computed once, no token dropped,
+a choice of an expert not held left out as the masked form's zero weight
+leaves it out.
+The rule is the static row count against MOE_GROUPED_ABOVE_ROWS
+(ops/moe.py `held_experts_grouped`): decode (64 rows) and a one-window
+prefill (128) stay masked.
+
+Off-TPU all run the same mathematics in jax.numpy (`*_jnp`); the tests
 run the kernels in interpret mode against them.
 """
 
@@ -42,6 +57,19 @@ from ..models.layers import _activate
 
 _VMEM_LIMIT = 64 * 1024 * 1024
 MOE_ROW_TILE = 512
+# The grouped form's row tile: a tile's arithmetic stays under an expert's
+# weight stream, so padding each expert's run to a tile costs nothing the
+# stream does not hide.
+MOE_GROUP_TILE = 128
+# Masked up to this many rows, grouped above: the chip's ridge is 240 rows,
+# and at 512 the masked call takes twice the grouped one's time, at 1,024
+# three and a half times; up to 256 the two are within a few percent of
+# each other and of the experts' bytes (scripts/tpu_kernel_check.py
+# --timing has the table; PERF.md §5).
+MOE_GROUPED_ABOVE_ROWS = 256
+# Rows of one grouped call: they and their float32 result stay in VMEM,
+# and the one-hot products that gather and add them grow with the count.
+MOE_GROUP_ROWS = 1024
 
 
 def use_kernels() -> bool:
@@ -218,4 +246,209 @@ def moe_held_experts(v, up, down, weights, *, gate=None,
         interpret=interpret,
         name="moe_held_experts",
     )(v, wcol, *ins, down)
+    return out[:R]
+
+
+def group_rows_by_expert(weights, chosen: int, tile: int):
+    """The expert-sorted, tile-padded order of a routing, as a counting
+    sort leaves it — no element gathered, scattered or compared with
+    another one by one. `weights` [R, E] float32: a row's combine weight
+    for each held expert, 0 where it did not choose it (such a pair adds
+    nothing in any form, so it is not a pair); `chosen`: the most experts
+    a row has a weight for. Returns (rank [E, 1, R] int32: a row's place
+    in its expert's run, the rows in order, -1 where the row is not in it;
+    tile_expert [tiles] and tile_rank [tiles]: the expert a tile belongs
+    to and the run's place its first row has; live [1]: the tiles in use)
+    — tiles: the static bound; one past the live count repeats the last
+    live one, so it fetches nothing new."""
+    R, E = weights.shape
+    chose = weights != 0
+    tiles = min(-(-R * min(chosen, E) // tile) + E, -(-R // tile) * E)
+    # Rows above a row that chose the same expert: a product with the
+    # strict lower triangle, exact in float32 (counts up to R).
+    above = jnp.dot(jnp.tri(R, k=-1, dtype=jnp.bfloat16),
+                    chose.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32).astype(jnp.int32)
+    rank = jnp.where(chose, above, -1).T[:, None, :]
+    counts = jnp.sum(chose, axis=0, dtype=jnp.int32)
+    tiles_of = (counts + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles_of)
+    tile_start = tile_end - tiles_of
+    live = tile_end[-1]
+    at = jnp.minimum(jnp.arange(tiles, dtype=jnp.int32),
+                     jnp.maximum(live - 1, 0))
+    # The expert whose tiles include `at`: the experts that end at or
+    # before it, counted (no table look-up).
+    tile_expert = jnp.minimum(
+        jnp.sum(tile_end[None, :] <= at[:, None], axis=1, dtype=jnp.int32),
+        E - 1)
+    started = jnp.max(jnp.where(tile_start[None, :] <= at[:, None],
+                                tile_start[None, :], 0), axis=1)
+    return (rank, tile_expert, ((at - started) * tile).astype(jnp.int32),
+            live.astype(jnp.int32).reshape(1))
+
+
+def _moe_grouped_kernel(expert_ref, rank_ref, live_ref, *refs,
+                        activation: str, gated: bool):
+    del expert_ref                       # the index maps read it
+    if gated:
+        (v_ref, place_ref, w_ref, gate_ref, up_ref, down_ref,
+         out_ref, x_ref, y_ref, col_ref) = refs
+    else:
+        (v_ref, place_ref, w_ref, up_ref, down_ref,
+         out_ref, x_ref, y_ref, col_ref) = refs
+    R = v_ref.shape[0]
+    tile = x_ref.shape[0]
+    # (program ids are read out here: interpret mode has none inside a
+    # branch)
+    t, i = pl.program_id(0), pl.program_id(1)
+    first, last = i == 0, i == pl.num_programs(1) - 1
+
+    @pl.when((t == 0) & first)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(t < live_ref[0])
+    def _():
+        # pick[j, r]: row r is the tile's j-th row (its place in the
+        # expert's run is the tile's first + j); a place past the run's
+        # end picks none.
+        pick = place_ref[...] == rank_ref[t] + jax.lax.broadcasted_iota(
+            jnp.int32, (tile, R), 0)
+
+        @pl.when(first)
+        def _():
+            # The tile's rows, gathered on the MXU: a one-hot row picks
+            # one bf16 row exactly. Their combine weights likewise.
+            x_ref[...] = jnp.dot(
+                pick.astype(v_ref.dtype), v_ref[...],
+                preferred_element_type=jnp.float32).astype(x_ref.dtype)
+            col_ref[...] = jnp.sum(jnp.where(pick, w_ref[...], 0.0),
+                                   axis=1, keepdims=True)
+
+        x = x_ref[...]
+        h = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
+        if gated:
+            a = _activate(jnp.dot(x, gate_ref[...],
+                                  preferred_element_type=jnp.float32),
+                          activation) * h
+        else:
+            a = _activate(h, activation)
+        a = a * col_ref[...]
+        y = jnp.dot(a.astype(x.dtype), down_ref[...],
+                    preferred_element_type=jnp.float32)
+
+        @pl.when(first)
+        def _():
+            y_ref[...] = y
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            y_ref[...] += y
+
+        @pl.when(last)
+        def _():
+            # Each place's float32 result added to its row, on the MXU
+            # too: the one-hot transposed times the result split into
+            # three bf16 parts (24 bits of mantissa), summed in float32.
+            put = pick.astype(jnp.bfloat16)
+            rest = y_ref[...]
+            for _ in range(3):
+                part = rest.astype(jnp.bfloat16)
+                out_ref[...] += jax.lax.dot_general(
+                    put, part, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                rest = rest - part.astype(jnp.float32)
+
+
+def moe_held_experts_grouped(v, up, down, weights, *, chosen: int,
+                             gate=None, activation: str = "relu2",
+                             interpret: bool = False):
+    """`moe_held_experts_jnp`'s sum, each (row, chosen held expert) pair
+    computed once (`chosen`: the most held experts a row may have chosen,
+    which bounds the pairs): the grid walks the expert-sorted row tiles
+    (group_rows_by_expert), the rows and the float32 result stay in VMEM
+    for the whole call, and a tile's rows are gathered — its results added
+    to their rows — by one-hot products on the MXU, under the weight
+    stream (XLA's own gather of the sorted rows cost more than the
+    experts' bytes). More than MOE_GROUP_ROWS rows run as calls of that
+    many."""
+    call = functools.partial(
+        _grouped_call, chosen=chosen, activation=activation,
+        tile=MOE_GROUP_TILE, inner_tile=_inner_tile(up.shape[2]),
+        interpret=interpret)
+    return jnp.concatenate([
+        call(v[r:r + MOE_GROUP_ROWS], up, down, weights[r:r + MOE_GROUP_ROWS],
+             gate)
+        for r in range(0, v.shape[0], MOE_GROUP_ROWS)])
+
+
+# Jitted by itself: a prefill module calls it once an expert layer (and once
+# a thousand rows), and traces and lowers the kernel once for all of them.
+@functools.partial(jax.jit, static_argnames=(
+    "chosen", "activation", "tile", "inner_tile", "interpret"))
+def _grouped_call(v, up, down, weights, gate, *, chosen: int, activation: str,
+                  tile: int, inner_tile: int, interpret: bool):
+    R, L = v.shape
+    E, _, inner = up.shape
+    pad = -R % 128                  # the one-hot products' contraction
+    if pad:
+        v = jnp.pad(v, ((0, pad), (0, 0)))
+        weights = jnp.pad(weights, ((0, pad), (0, 0)))
+    rows = R + pad
+    weights = weights.astype(jnp.float32)
+    rank, tile_expert, tile_rank, live = group_rows_by_expert(
+        weights, chosen, tile)
+    it = inner_tile
+    last = inner // it - 1
+
+    def whole(t, i, *_):
+        return 0, 0
+
+    def of_expert(t, i, expert_ref, rank_ref, live_ref):
+        return expert_ref[t], 0, 0
+
+    def block(t, i, live_ref):
+        # Odd tiles walk the experts' width backwards, so a run longer
+        # than a tile keeps the block at the turn; a tile past the live
+        # count stays on the last live step's blocks.
+        on = jnp.minimum(t, jnp.maximum(live_ref[0] - 1, 0))
+        i = jnp.where(t < live_ref[0], i, last)
+        return jnp.where(on % 2 == 1, last - i, i)
+
+    into = pl.BlockSpec(
+        (None, L, it),
+        lambda t, i, expert_ref, rank_ref, live_ref: (
+            expert_ref[t], 0, block(t, i, live_ref)))
+    ins = [up] if gate is None else [gate, up]
+    out = pl.pallas_call(
+        functools.partial(_moe_grouped_kernel, activation=activation,
+                          gated=gate is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(tile_expert.shape[0], last + 1),
+            in_specs=[
+                pl.BlockSpec((rows, L), whole),
+                pl.BlockSpec((None, 1, rows), of_expert),
+                pl.BlockSpec((None, 1, rows), of_expert),
+                *[into] * len(ins),
+                pl.BlockSpec(
+                    (None, it, L),
+                    lambda t, i, expert_ref, rank_ref, live_ref: (
+                        expert_ref[t], block(t, i, live_ref), 0)),
+            ],
+            out_specs=pl.BlockSpec((rows, L), whole),
+            scratch_shapes=[pltpu.VMEM((tile, L), v.dtype),
+                            pltpu.VMEM((tile, L), jnp.float32),
+                            pltpu.VMEM((tile, 1), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, L), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="moe_held_experts_grouped",
+    )(tile_expert, tile_rank, live, v, rank, weights.T[:, None, :],
+      *ins, down)
     return out[:R]

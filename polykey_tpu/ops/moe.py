@@ -14,7 +14,10 @@ experts of a sigmoid-routed layer (`moe_held`), in two forms:
 - `moe_held` — one chip's share of a sigmoid-routed expert layer: the
   router over every published expert, and the held experts' part of the
   sum as one pass over their weights (ops/hybrid_kernels.py
-  `moe_held_experts`). No capacity, no drop. `moe_latent_held`: un-gated
+  `moe_held_experts`: every row against every held expert, the combine
+  weights masking) up to the chip's ridge, and over rows sorted by expert
+  above it (`moe_held_experts_grouped`: each chosen pair once). No
+  capacity, no drop, in either. `moe_latent_held`: un-gated
   relu² experts inside a latent, plus a shared expert; `moe_gated_held`:
   gated experts on the full hidden.
 - `moe_mlp_dispatch` — capacity-bucketed sparse dispatch: tokens gather into
@@ -151,14 +154,37 @@ def latent_router_weights(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     return jnp.sum(onehot * chosen[..., None], axis=-2)
 
 
-def _held_weights(p: dict, tokens: jax.Array, cfg: ModelConfig):
-    """(combine weights of the held experts [rows, held], the product)."""
+def held_experts_grouped(rows: int) -> bool:
+    """Whether `moe_held` runs a call of `rows` rows (batch × window) as
+    the grouped product: on the chip, above the ridge — the whole rule,
+    on the call's static row count. The engine counts by the same
+    function (prefill_rows_grouped_experts)."""
+    return (hybrid_kernels.use_kernels()
+            and rows > hybrid_kernels.MOE_GROUPED_ABOVE_ROWS)
+
+
+def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Σ over the chosen experts that are held of weight · a(v, e) ·
+    W_down,e, float32 [rows, width of v]: the router reads `tokens`
+    [rows, H]; the experts read v = `tokens` through `fc1` where the
+    config states a latent, else `tokens`, gated where the layer has a
+    `gate`. Three forms of one sum over the same combine weights, chosen
+    by the backend and the static row count alone: off the chip
+    `moe_held_experts_jnp`; on it the masked one-pass kernel up to the
+    ridge (a decode step, a one-window prefill) and the grouped kernel
+    above it."""
     weights = latent_router_weights(p, tokens, cfg)[
         :, cfg.first_expert:cfg.first_expert + cfg.experts_held
     ]
+    v = qdot(tokens, p["fc1"]) if cfg.moe_latent_size else tokens
+    how = {"gate": p.get("gate"), "activation": cfg.activation}
+    if held_experts_grouped(tokens.shape[0]):
+        return hybrid_kernels.moe_held_experts_grouped(
+            v, p["up"], p["down"], weights,
+            chosen=min(cfg.num_experts_per_tok, cfg.experts_held), **how)
     held = (hybrid_kernels.moe_held_experts if hybrid_kernels.use_kernels()
             else hybrid_kernels.moe_held_experts_jnp)
-    return weights, held
+    return held(v, p["up"], p["down"], weights, **how)
 
 
 def moe_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -178,14 +204,13 @@ def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Gated experts on the full hidden as the chip that holds experts
     `[first_expert, first_expert + experts_held)` computes them:
     [B, T, H] → [B, T, H], Σ_e w_e · (act(h W_gate,e) ⊙ h W_up,e) W_down,e
-    over the chosen experts that are held. The router, the masking and the
-    work are `moe_latent_held`'s: every held expert's three matrices are
-    read once whatever the routing chose, and no token is dropped."""
+    over the chosen experts that are held. The router and the product
+    are `moe_latent_held`'s (`_held_product`): every held expert's three
+    matrices are read once whatever the routing chose, and no token is
+    dropped."""
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
-    weights, held = _held_weights(p, tokens, cfg)
-    out = held(tokens, p["up"], p["down"], weights, gate=p["gate"],
-               activation=cfg.activation)
+    out = _held_product(p, tokens, cfg)
     return out.astype(h.dtype).reshape(B, T, H)
 
 
@@ -197,15 +222,14 @@ def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     (`fc1` down to it, `fc2` back); the shared expert runs on the full
     hidden. What the absent experts would add is left out, here and in
     the reference alike, and no token is dropped at any width: every held
-    expert's weights are read once and the combine weights mask
-    (hybrid_kernels.moe_held_experts), so the work is rows x experts held
-    whatever the routing chose. The experts are not gated: relu(up)² only."""
+    expert's weights are read once (`_held_product`: the combine weights
+    mask, or the rows are sorted by expert). The experts are not gated:
+    relu(up)² only."""
     if cfg.activation != "relu2":
         raise ValueError("the held-experts product computes relu(up)² only")
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
-    weights, held = _held_weights(p, tokens, cfg)
-    routed = held(qdot(tokens, p["fc1"]), p["up"], p["down"], weights)
+    routed = _held_product(p, tokens, cfg)
     out = qdot(routed.astype(h.dtype), p["fc2"])
     shared = _activate(qdot(tokens, p["shared_up"]), cfg.activation)
     return (out + qdot(shared, p["shared_down"])).reshape(B, T, H)

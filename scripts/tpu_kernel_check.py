@@ -18,12 +18,19 @@ jax.block_until_ready really blocks, and the paged decode kernel alone
 bandwidth at the two shapes the benchmark's cells run, and the DMA
 descriptors the call starts and awaits, so that a call's cost can be
 split into bytes ÷ bandwidth + the rest without a server; in no cell —
-what the users pay is the benchmark's to say).
+what the users pay is the benchmark's to say). `--timing` also times the
+held experts' product (`held-time` rows: the masked one-pass kernel and
+the grouped one, both instances at their published shapes, 64 to 1,024
+rows, µs a call beside the held experts' bytes ÷ the bandwidth — the
+table the crossover constant `MOE_GROUPED_ABOVE_ROWS` is set from) and
+compares grouped against masked and the jnp twin there (`held-compare`).
 
 Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
-     python scripts/tpu_kernel_check.py --timing   (the decode-time rows
-       alone; --sweep adds lanes, table width and block width varied one
-       at a time)
+     python scripts/tpu_kernel_check.py --timing   (the decode-time and
+       held-time rows alone, ~3 min; --sweep adds lanes, table width and
+       block width of the decode kernel varied one at a time)
+     python scripts/tpu_kernel_check.py --held-experts   (the held-time and
+       held-compare rows alone)
      JAX_PLATFORMS=cpu python scripts/tpu_kernel_check.py --interpret
        rehearses the script itself at small tables in Pallas interpret
        mode — it proves nothing about lowering and exits 2 like any run
@@ -377,6 +384,168 @@ def check_decode_timing(sweep: bool) -> None:
                  partial(timed, TABLE, ctx))
 
 
+# (label, hidden the router reads, width the experts read, experts' width,
+# experts published, held, top-k, activation, gated): the two hybrid
+# configurations of the benchmark as one chip holds them.
+HELD_SHAPES = [
+    ("lfm2 64x(2048x1536) top-4/64", 2048, 2048, 1536, 64, 64, 4, "silu", True),
+    ("nemotron 128x(1024x2688) top-22/512", 4096, 1024, 2688, 512, 128, 22,
+     "relu2", False),
+]
+HELD_ROWS = (64, 128, 256, 512, 1024)
+HELD_CALLS = 16
+
+
+def held_shape(shape):
+    """The shape as checked: the published one, or a toy under --interpret
+    (a rehearsal of the script)."""
+    if "interpret" not in KERNEL:
+        return shape
+    return (shape[0], 64, 64, 256, 8, 4, 2, *shape[7:])
+
+
+def held_inputs(shape, rows: int, calls: int):
+    """Seeded experts, and for each of `calls` calls seeded rows routed by
+    a sigmoid router of the published width: (weights, v [calls, rows, L],
+    the combine weights of the held experts [calls, rows, held], 0 off
+    the chosen)."""
+    _, H, L, inner, published, held, k, _, gated = shape
+    key = jax.random.split(jax.random.PRNGKey(rows), 6)
+    weights = {
+        "up": jax.random.normal(key[0], (held, L, inner), jnp.bfloat16)
+        * L ** -0.5,
+        "down": jax.random.normal(key[1], (held, inner, L), jnp.bfloat16)
+        * inner ** -0.5,
+    }
+    if gated:
+        weights["gate"] = jax.random.normal(
+            key[2], (held, L, inner), jnp.bfloat16) * L ** -0.5
+    tokens = jax.random.normal(key[3], (calls, rows, H), jnp.bfloat16)
+    router = jax.random.normal(key[4], (H, published), jnp.bfloat16) * H ** -0.5
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "crh,he->cre", tokens, router, preferred_element_type=jnp.float32))
+    chosen, idx = jax.lax.top_k(scores, k)
+    chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    dense = jnp.sum(jax.nn.one_hot(idx, published) * chosen[..., None],
+                    axis=-2)[..., :held]
+    v = tokens if L == H else jax.random.normal(
+        key[5], (calls, rows, L), jnp.bfloat16)
+    return weights, v, dense
+
+
+def time_held(shape, rows: int, grouped: bool) -> str:
+    """µs a call of the held experts' product, masked or grouped (the
+    grouped call with its sort, gather and combine), beside the least time
+    the held experts' bytes allow. Calls run back to back inside one
+    jitted scan, each on rows and a routing of its own (the grouped call
+    with its counting sort; the router's own work is in neither)."""
+    from polykey_tpu.ops import hybrid_kernels as hk
+
+    shape = held_shape(shape)
+    _, _, L, inner, _, held, k, activation, _ = shape
+    interpret = "interpret" in KERNEL
+    calls = 2 if interpret else HELD_CALLS
+    weights, v, dense = held_inputs(shape, rows, calls)
+
+    def product(v, dense, up, down, gate):
+        how = {"gate": gate, "activation": activation, "interpret": interpret}
+        if grouped:
+            return hk.moe_held_experts_grouped(
+                v, up, down, dense, chosen=min(k, held), **how)
+        return hk.moe_held_experts(v, up, down, dense, **how)
+
+    def scan_of(step):
+        return jax.jit(lambda xs, up, down, gate: jax.lax.scan(
+            lambda c, x: (c + step(*x, up, down, gate), None),
+            jnp.zeros((rows, L), jnp.float32), xs)[0])
+
+    def seconds(fn):
+        args = ((v, dense), weights["up"], weights["down"],
+                weights.get("gate"))
+        jax.block_until_ready(fn(*args))      # compile
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    empty = scan_of(lambda v, dense, up, down, gate:
+                    v.astype(jnp.float32) * jnp.sum(dense))
+    us = (seconds(scan_of(product)) - seconds(empty)) / calls * 1e6
+    nbytes = sum(w.size * w.dtype.itemsize for w in weights.values())
+    pairs = float(jnp.mean(jnp.sum(dense > 0, axis=(1, 2))))
+    flops = 2 * L * inner * len(weights) * (
+        pairs if grouped else rows * held)
+    if interpret:
+        return (f"ran (interpret mode on the host: no device time); "
+                f"experts {nbytes / 1e6:.2f} MB")
+    peak = hbm_bytes_per_s()
+    least = nbytes / peak * 1e6
+    return (f"{us:.1f} us/call; experts {nbytes / 1e6:.1f} MB = {least:.1f} us "
+            f"at {peak / 1e9:.0f} GB/s ({100 * least / us:.1f} %); "
+            f"{pairs:.0f} held pairs of {rows * k}, "
+            f"{flops / 1e9:.1f} GFLOP computed")
+
+
+def compare_held(shape, rows: int) -> str:
+    """Grouped against masked and both against the jnp twin on one seeded
+    routing at the published shape: the largest |difference|, beside the
+    masked kernel's own distance from its twin (what summation order in
+    float32 and a bf16 cast of the activation give), and row by row
+    relative to the row's largest value — a row left out or computed
+    twice reads 1."""
+    from polykey_tpu.ops import hybrid_kernels as hk
+
+    shape = held_shape(shape)
+    interpret = "interpret" in KERNEL
+    weights, v, dense = held_inputs(shape, rows, 1)
+    how = {"activation": shape[7], "gate": weights.get("gate")}
+    args = (v[0], weights["up"], weights["down"])
+    twin = jax.jit(partial(hk.moe_held_experts_jnp, **how))(*args, dense[0])
+    masked = jax.jit(partial(hk.moe_held_experts, interpret=interpret, **how))(
+        *args, dense[0])
+    grouped = jax.jit(partial(
+        hk.moe_held_experts_grouped, chosen=min(shape[5], shape[6]),
+        interpret=interpret, **how))(*args, dense[0])
+    scale = float(jnp.max(jnp.abs(twin)))
+    own = max_err(masked, twin)
+    err = max(max_err(grouped, masked), max_err(grouped, twin))
+    by_row = float(jnp.max(
+        jnp.max(jnp.abs(grouped - masked), axis=-1)
+        / jnp.maximum(jnp.max(jnp.abs(masked), axis=-1), 1e-6 * scale)))
+    quiet = jnp.all(dense[0] == 0, axis=-1)
+    if bool(jnp.any(jnp.where(quiet[:, None], grouped, 0.0) != 0)):
+        raise AssertionError("a row with no held choice is not zero")
+    # Twice the masked kernel's own distance from its twin, or 2^-8 of the
+    # scale where that distance is 0 (one bf16 rounding of an activation).
+    tol = max(2 * own, scale * 2.0 ** -8)
+    if not np.isfinite(err) or err > tol or by_row > 0.05:
+        raise AssertionError(
+            f"grouped differs by {err:.3e} (masked vs twin {own:.3e}, scale "
+            f"{scale:.3e}), worst row {by_row:.3e}")
+    return (f"grouped vs masked/twin {err:.2e}; masked vs twin {own:.2e}; "
+            f"scale {scale:.2e}; worst row {by_row:.2e}; "
+            f"{int(jnp.sum(quiet))} rows with no held choice")
+
+
+def check_held_experts() -> None:
+    from polykey_tpu.ops.hybrid_kernels import MOE_GROUPED_ABOVE_ROWS
+
+    for shape in HELD_SHAPES:
+        for rows in (512, 1024):
+            case("held-compare", f"{shape[0]} rows={rows}",
+                 partial(compare_held, shape, rows))
+        for rows in HELD_ROWS:
+            for grouped in (False, True):
+                served = grouped == (rows > MOE_GROUPED_ABOVE_ROWS)
+                case("held-time",
+                     f"{shape[0]} rows={rows} "
+                     f"{'grouped' if grouped else 'masked'}"
+                     f"{' (served)' if served else ''}",
+                     partial(time_held, shape, rows, grouped))
+
+
 def check_block_until_ready() -> None:
     """Does jax.block_until_ready block here? A long dependent matmul
     chain is dispatched; the call returning in a sliver of the time the
@@ -423,14 +592,17 @@ def main() -> int:
         return 2
     # The smoke's default path first; the kernels that have never run on
     # hardware last, so a hang there costs no other case its evidence.
-    timing_only = "--timing" in sys.argv[1:]
+    held_only = "--held-experts" in sys.argv[1:]
+    timing_only = held_only or "--timing" in sys.argv[1:]
     check_block_until_ready()
     if not timing_only:
         check_flash()
         check_decode(quantized=False)
         check_write(quantized=False)
         check_int4()
-    check_decode_timing(sweep="--sweep" in sys.argv[1:])
+    if not held_only:
+        check_decode_timing(sweep="--sweep" in sys.argv[1:])
+    check_held_experts()
     if not timing_only:
         check_decode(quantized=True)
         check_write(quantized=True)

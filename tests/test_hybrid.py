@@ -36,6 +36,7 @@ from polykey_tpu.models.hybrid import (
 from polykey_tpu.models.transformer import init_params
 from polykey_tpu.ops import hybrid_kernels
 from polykey_tpu.ops.moe import moe_latent_held
+import grouped_experts
 from pattern_stack import SlotBatch, served, text, worst_margin
 
 F32_TOL = 2e-4
@@ -121,6 +122,22 @@ def test_held_experts_kernel_matches_jnp(rows, monkeypatch):
     want = hybrid_kernels.moe_held_experts_jnp(v, up, down, w)
     got = hybrid_kernels.moe_held_experts(v, up, down, w, interpret=True)
     np.testing.assert_allclose(got, want, atol=1e-4)   # summation order
+
+
+@pytest.mark.parametrize("routing", list(grouped_experts.ROUTINGS))
+@pytest.mark.parametrize("rows", grouped_experts.ROWS)
+def test_grouped_held_experts_match_jnp(rows, routing):
+    """The un-gated instance over rows sorted by expert (ISSUE 48)."""
+    grouped_experts.check(rows, routing, gated=False)
+
+
+@pytest.mark.parametrize("rows, form", grouped_experts.RULE)
+def test_the_row_count_alone_chooses_the_held_product(
+        rows, form, params, monkeypatch):
+    """`moe_held` of a latent layer: the jnp form off the chip; on
+    it the masked kernel up to 128 rows, the grouped one from 512."""
+    grouped_experts.check_rule(
+        rows, form, params["layers"]["moe"][0], CFG, monkeypatch)
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer(params):
@@ -280,6 +297,30 @@ def test_engine_serves_what_the_reference_computes(
     assert moved == {"state_slots_reset": reset,
                      "state_windows_chained": chained,
                      "state_chunks_resumed": resumed}
+
+
+@pytest.mark.parametrize("rule, want", [
+    # The rule answering from 64 rows up: a 150-token prompt rides two
+    # 64-wide chunks (counted) and two 16-rows in one dispatch (not).
+    (lambda rows: rows >= 64, 128),
+    # As it answers off the chip: the jnp form ran every row.
+    (None, 0),
+], ids=["wide-dispatches", "off-chip"])
+def test_engine_counts_the_rows_its_expert_layers_ran_grouped(
+        engine, rule, want, monkeypatch):
+    """`prefill_rows_grouped_experts` (ISSUE 48): host-known, counted by
+    the function `moe_held` decides by, per dispatch."""
+    from polykey_tpu.engine import engine as engine_mod
+
+    if rule is not None:
+        monkeypatch.setattr(engine_mod, "held_experts_grouped", rule)
+    before = engine.stats()
+    served(engine, [text(150, 150)])
+    after = engine.stats()
+    grew = {k: after[k] - before[k] for k in (
+        "prefill_rows_grouped_experts", "prefill_rows_dispatched")}
+    assert grew == {"prefill_rows_grouped_experts": want,
+                    "prefill_rows_dispatched": 160}
 
 
 def test_engine_reuses_slots_and_batches_lanes(engine):

@@ -40,6 +40,7 @@ from polykey_tpu.models.hybrid import (
 from polykey_tpu.models.transformer import init_params, unembed
 from polykey_tpu.ops import hybrid_kernels
 from polykey_tpu.ops.moe import latent_router_weights, moe_gated_held, moe_held
+import grouped_experts
 from pattern_stack import SLOTS, SlotBatch, served, text, worst_margin
 
 F32_TOL = 2e-4
@@ -134,6 +135,22 @@ def test_held_experts_kernel_matches_jnp(rows, gated, monkeypatch):
                                           **how)
     np.testing.assert_allclose(want, written_out, atol=1e-4)
     np.testing.assert_allclose(got, want, atol=1e-4)   # summation order
+
+
+@pytest.mark.parametrize("routing", list(grouped_experts.ROUTINGS))
+@pytest.mark.parametrize("rows", grouped_experts.ROWS)
+def test_grouped_held_experts_match_jnp(rows, routing):
+    """The gated instance over rows sorted by expert (ISSUE 48)."""
+    grouped_experts.check(rows, routing, gated=True)
+
+
+@pytest.mark.parametrize("rows, form", grouped_experts.RULE)
+def test_the_row_count_alone_chooses_the_held_product(
+        rows, form, params, monkeypatch):
+    """`moe_held` of gated experts on the full hidden: the jnp form off the chip; on
+    it the masked kernel up to 128 rows, the grouped one from 512."""
+    grouped_experts.check_rule(
+        rows, form, params["layers"]["moe"][0], CFG, monkeypatch)
 
 
 def test_router_bias_chooses_but_does_not_weigh():

@@ -346,6 +346,14 @@ def _kernel_calls(hlo: str) -> list[str]:
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
+def sorts(hlo: str) -> list[str]:
+    """The module's sorts by an integer key (the router's top-k is a sort
+    by a float32 score, in every module): the grouped product orders its
+    rows by a counting sort, so no module has one."""
+    return [line.strip()[:160] for line in hlo.splitlines()
+            if " sort(" in line and "= (s32[" in line]
+
+
 def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch):
     """The decode step of a hybrid stack (models/hybrid.py) compiled for a
     v5e at the published widths of its two new kernels — a state of
@@ -379,6 +387,37 @@ def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch)
     for name in ("%ssm_state_update", "%moe_held_experts", "%paged_kv_write",
                  "%paged_attention_decode"):
         assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
+    # The decode program holds the masked call and no sort (ISSUE 48).
+    assert not any("grouped" in c for c in calls), calls
+    assert sorts(hlo) == []
+
+
+def test_latent_prefill_of_512_rows_compiled_for_v5e_runs_grouped(
+        v5e, monkeypatch):
+    """The same stack's prefill step at four 128-token windows: the held
+    experts' product is the grouped call (one kernel), at the published
+    widths of a latent expert layer; at one window it is the masked call
+    the decode step has."""
+    from polykey_tpu.models.config import get_config
+
+    cfg = replace(
+        get_config("tiny-hybrid"), name="hybrid-probe", hidden_size=512,
+        layer_pattern="M*E", num_layers=3, num_heads=4, num_kv_heads=2,
+        head_dim=128, mamba_num_heads=128, mamba_head_dim=64,
+        ssm_state_size=128, ssm_groups=8, ssm_chunk=128,
+        intermediate_size=2688, moe_latent_size=1024,
+        moe_shared_intermediate=256, n_routed_experts=32, experts_held=8,
+        num_experts_per_tok=6,
+    )
+    for prefill, name in (((4, 128), "%moe_held_experts_grouped"),
+                          ((1, 128), "%moe_held_experts.")):
+        compiled, _, _ = _compile_pattern_step(
+            v5e, monkeypatch, cfg, prefill=prefill)
+        hlo = compiled.as_text()
+        held = [c for c in _kernel_calls(hlo)
+                if c.startswith("%moe_held_experts")]
+        assert len(held) == 1 and (held[0] + ".").startswith(name), held
+        assert sorts(hlo) == []
 
 
 @pytest.mark.parametrize("prefill", [None, (1, 128), (2, 512)],
@@ -412,6 +451,12 @@ def test_operator_ffn_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
     assert layer_weight_copies(hlo, "bf16", cfg.hidden_size) == []
     calls = _kernel_calls(hlo)
     assert sum(c.startswith("%moe_held_experts") for c in calls) == 1, calls
+    # 1,024 rows run sorted by expert; a one-window prefill and a decode
+    # step keep the masked call (ISSUE 48). No module sorts by comparison.
+    grouped = prefill == (2, 512)
+    assert any(c.startswith("%moe_held_experts_grouped")
+               for c in calls) == grouped, calls
+    assert sorts(hlo) == []
     assert jax.tree.leaves(state.ssm) == []
     if prefill is not None:
         # (At this probe's geometry a pattern's prefill module copies the

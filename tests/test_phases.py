@@ -560,6 +560,25 @@ def test_phase_counts_match_the_dispatch_counters(live):
         for p in (SHORT, LONG, SHORT + " again"))
 
 
+def test_a_model_without_expert_layers_counts_no_grouped_rows(
+        live, monkeypatch):
+    """`prefill_rows_grouped_experts` follows the rule `moe_held` decides
+    by only where the model has an "E" layer: with the rule answering yes
+    for every dispatch, a plain decoder still counts none."""
+    from polykey_tpu.engine import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "held_experts_grouped", lambda rows: True)
+    before = live.metrics.snapshot()
+    r = GenRequest(prompt=SHORT + " once more", max_new_tokens=4)
+    live.submit(r)
+    _tokens, done, error = _collect(r)
+    assert error is None and done is not None
+    wait_drained(live)
+    after = live.metrics.snapshot()
+    assert after["prefill_rows_dispatched"] > before["prefill_rows_dispatched"]
+    assert after["prefill_rows_grouped_experts"] == 0
+
+
 def test_exporter_renders_one_sample_per_phase_and_counter(live):
     page = "\n".join(engine_collector(live)())
     for name in PHASES:
@@ -576,6 +595,7 @@ def test_exporter_renders_one_sample_per_phase_and_counter(live):
                    "polykey_decode_lane_steps_dead_total",
                    "polykey_prefill_rows_dispatched_total",
                    "polykey_prefill_rows_useful_total",
+                   "polykey_prefill_rows_grouped_experts_total",
                    "polykey_ttft_phase_requests_total",
                    "polykey_first_token_poll_gap_seconds_total",
                    "polykey_first_token_reads_total"):
@@ -584,6 +604,7 @@ def test_exporter_renders_one_sample_per_phase_and_counter(live):
     for key in ("phase_seconds", "phase_count", "ttft_phase_seconds",
                 "ttft_phase_count", "ttft_queue_seconds", "admit_deferred",
                 "decode_lane_steps_delivered", "prefill_rows_useful",
+                "prefill_rows_grouped_experts",
                 "first_token_poll_gap_seconds",
                 "first_token_poll_gap_count"):
         assert key in stats
