@@ -703,9 +703,9 @@ class EngineConfig:
 
     def _refuse_for_recurrent_state(self) -> None:
         """A model with per-slot recurrent state (ModelConfig.stateful:
-        kv_cache.SlotState beside the pages — a mixer's h, a conv's last
-        columns) is served by the plain prefill / decode pair on one
-        device and by nothing else yet. Every
+        kv_cache.SlotState beside the pages — a mixer's h, a delta rule's
+        S, a conv's last columns) is served by the plain prefill / decode
+        pair on one device and by nothing else yet. Every
         feature that moves, shares or rebuilds a slot's K/V pages would
         have to move, share or rebuild that state with them, and none
         does: each is refused here, in one place, rather than half-ported.

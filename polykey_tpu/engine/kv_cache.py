@@ -190,12 +190,15 @@ class PagedKV:
 class SlotState:
     """What a slot holds BESIDE its pages: the fixed-size state of a
     stateful model (ModelConfig.stateful), indexed by slot, one entry per
-    layer that needs it, in pattern order. `ssm`, one per Mamba-2 mixer:
-    [slots, H, P, N] float32 (the recurrence is summed over thousands of
-    steps). `conv`, one per layer with a causal conv — a mixer or a gated
-    short convolution: [slots, K−1, channels] in the activation dtype (the
-    conv's last K−1 input columns, stored as they were computed). A tuple
-    is empty where the pattern has no such layer (a conv-only model holds
+    layer that needs it, in pattern order. `ssm`, the recurrent matrix of
+    a layer, one per layer that has a recurrence — a Mamba-2 mixer's h
+    [slots, H, P, N], a gated delta rule's S [slots, Hv, Dk, Dv] — in
+    float32 (the recurrence is summed over thousands of steps). `conv`,
+    one per layer with a causal conv — a mixer, a delta-rule layer or a
+    gated short convolution: [slots, K−1, channels] in the activation
+    dtype (the conv's last K−1 input columns, stored as they were
+    computed). A tuple is empty where the pattern has no such layer (a
+    conv-only model holds
     no `ssm` leaf), and a model without such state holds two empty tuples
     — an empty pytree: nothing is allocated, carried or donated for it.
 
@@ -218,11 +221,15 @@ class SlotState:
 
 
 def init_slot_state(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16) -> SlotState:
-    ssm = (slots, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size)
-    channels = {"M": cfg.conv_dim, "C": cfg.hidden_size}
+    matrix = {
+        "M": (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size),
+        "L": (cfg.delta_value_heads, cfg.delta_key_dim, cfg.delta_value_dim),
+    }
+    channels = {"M": cfg.conv_dim, "C": cfg.hidden_size,
+                "L": cfg.delta_conv_dim}
     return SlotState(
-        ssm=tuple(jnp.zeros(ssm, jnp.float32)
-                  for _ in range(cfg.layer_pattern.count("M"))),
+        ssm=tuple(jnp.zeros((slots, *matrix[ch]), jnp.float32)
+                  for ch in cfg.layer_pattern if ch in matrix),
         conv=tuple(
             jnp.zeros((slots, max(cfg.conv_kernel - 1, 0), channels[ch]), dtype)
             for ch in cfg.layer_pattern if ch in channels),

@@ -44,8 +44,9 @@ class ModelConfig:
     moe_dispatch: bool = False
     # Hybrid stacks (models/hybrid.py): one character an entry, and an
     # entry is an operator OR a feed-forward part alone, under one norm —
-    # "M" a Mamba-2 mixer, "C" a gated short convolution, "*" attention,
-    # "E" an expert layer, "D" a dense gated MLP. A published layer that
+    # "M" a Mamba-2 mixer, "C" a gated short convolution, "L" a gated
+    # delta-rule linear attention, "*" attention, "E" an expert layer,
+    # "D" a dense gated MLP. A published layer that
     # holds an operator AND a feed-forward part under two norms is two
     # entries ("CD", "*E"), and `num_layers` counts entries.
     # Empty: the homogeneous attention+MLP block above, scanned.
@@ -54,6 +55,16 @@ class ModelConfig:
     # "*": RMSNorm over each q head and each k head (one learned gain of
     # head_dim each) before the position embedding.
     qk_norm: bool = False
+    # "*": the leading share of each head's dims that the rotary embedding
+    # turns (rotate-half inside it); the rest pass.
+    partial_rotary_factor: float = 1.0
+    # "*": W_q yields a gate beside each head's query ([.., heads, 2 D],
+    # query then gate); the context is multiplied by sigmoid(gate) before
+    # W_o.
+    attn_output_gate: bool = False
+    # Every RMSNorm of a pattern but the delta body's gated one: the gain
+    # is `norm_offset + w` (1.0: the zero-centred norm, w starts at 0).
+    norm_offset: float = 0.0
     # "M": H heads x P dims, state [H, P, N] per sequence, G groups share
     # B and C, a causal depthwise conv of `conv_kernel` taps over x|B|C.
     mamba_num_heads: int = 0
@@ -62,19 +73,32 @@ class ModelConfig:
     ssm_groups: int = 0
     conv_kernel: int = 0
     ssm_chunk: int = 128                      # prefill's chunked form
+    # "L": `delta_key_heads` q and k heads of `delta_key_dim`, each read by
+    # `delta_value_heads` ÷ `delta_key_heads` value heads of
+    # `delta_value_dim`; state [value heads, key dim, value dim] per
+    # sequence; a causal depthwise conv of `conv_kernel` taps over q|k|v;
+    # prefill in chunks of `delta_chunk`.
+    delta_key_heads: int = 0
+    delta_value_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_chunk: int = 64
     # "C": [B | C | u] = W_in h, a causal depthwise conv of `conv_kernel`
     # taps over B ⊙ u (no bias, no activation), W_out (C ⊙ conv): what a
     # sequence carries is the conv's last K−1 columns, nothing else.
     # "D": act(h W_gate) ⊙ h W_up through W_down at this width.
     dense_intermediate_size: int = 0
-    # "E": sigmoid router over `n_routed_experts`, top
-    # `num_experts_per_tok` of them by score + bias, each weighed by its
-    # score over the sum of the chosen (+ `router_norm_eps`), experts of
-    # `intermediate_size`. Two forms (ops/moe.py `moe_held`):
+    # "E": a router over `n_routed_experts` — `router_scoring` "sigmoid":
+    # the top `num_experts_per_tok` by score + bias; "softmax": over all
+    # of them, the top by score, no bias — each chosen expert weighed by
+    # its score over the sum of the chosen (+ `router_norm_eps`), experts
+    # of `intermediate_size`. Two forms (ops/moe.py `moe_held`):
     # `moe_latent_size` > 0, un-gated relu² experts inside a latent with
     # one shared expert on the full hidden; 0, gated experts
-    # (act(h W_gate,e) ⊙ h W_up,e) W_down,e on the full hidden, no shared
-    # expert. The chip holds experts [first_expert, first_expert +
+    # (act(h W_gate,e) ⊙ h W_up,e) W_down,e on the full hidden, beside
+    # them a gated shared expert of `moe_shared_intermediate` where that
+    # is set, weighed by sigmoid(w_s · h) where `shared_expert_gate` says
+    # so. The chip holds experts [first_expert, first_expert +
     # experts_held) and computes their part of the sum.
     n_routed_experts: int = 0
     experts_held: int = 0
@@ -83,6 +107,8 @@ class ModelConfig:
     moe_shared_intermediate: int = 0
     routed_scaling_factor: float = 1.0
     router_norm_eps: float = 0.0
+    router_scoring: str = "sigmoid"
+    shared_expert_gate: bool = False
 
     @property
     def is_moe(self) -> bool:
@@ -98,6 +124,8 @@ class ModelConfig:
         """What a slot holds beside its pages, in words; "" for nothing."""
         if "M" in self.layer_pattern:
             return "Mamba-2 h and conv columns"
+        if "L" in self.layer_pattern:
+            return "delta-rule S and conv columns"
         if "C" in self.layer_pattern:
             return "short-conv columns"
         return ""
@@ -118,14 +146,26 @@ class ModelConfig:
         """Channels the conv runs over: x | B | C."""
         return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state_size
 
+    @property
+    def delta_conv_dim(self) -> int:
+        """Channels the delta body's conv runs over: q | k | v."""
+        return (2 * self.delta_key_heads * self.delta_key_dim
+                + self.delta_value_heads * self.delta_value_dim)
+
+    @property
+    def rotary_dim(self) -> int:
+        """The leading dims of a head that the rotary embedding turns."""
+        return int(self.head_dim * self.partial_rotary_factor)
+
     def __post_init__(self):
         if not self.layer_pattern:
             return
         if len(self.layer_pattern) != self.num_layers or \
-                set(self.layer_pattern) - set("MC*ED"):
+                set(self.layer_pattern) - set("MCL*ED"):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r} must be num_layers="
-                f"{self.num_layers} characters of 'M', 'C', '*', 'E', 'D'"
+                f"{self.num_layers} characters of 'M', 'C', 'L', '*', 'E', "
+                f"'D'"
             )
         if "E" in self.layer_pattern and not (
             0 < self.experts_held
@@ -154,15 +194,22 @@ class ModelConfig:
                            + self.experts_held * 2 * self.moe_latent_size
                            * self.intermediate_size)
             else:
-                experts = self.experts_held * 3 * h * self.intermediate_size
+                experts = (self.experts_held * 3 * h * self.intermediate_size
+                           + 3 * h * self.moe_shared_intermediate
+                           + h * self.shared_expert_gate)
+            values = self.delta_value_heads * self.delta_value_dim
             kinds = {
                 "M": h * (2 * self.mamba_inner
                           + 2 * self.ssm_groups * self.ssm_state_size
                           + self.mamba_num_heads)
                 + self.mamba_inner * h + self.conv_dim * self.conv_kernel,
                 "C": 4 * h * h + h * self.conv_kernel,
-                "*": h * self.head_dim * 2 * (self.num_heads
-                                              + self.num_kv_heads),
+                "L": h * (self.delta_conv_dim + values
+                          + 2 * self.delta_value_heads)
+                + values * h + self.delta_conv_dim * self.conv_kernel,
+                "*": h * self.head_dim * (
+                    (3 if self.attn_output_gate else 2) * self.num_heads
+                    + 2 * self.num_kv_heads),
                 "E": h * self.n_routed_experts + experts,
                 "D": 3 * h * self.dense_intermediate_size,
             }
@@ -184,7 +231,15 @@ class ModelConfig:
     def num_active_params(self) -> int:
         """Parameters touched per token: for MoE, only the router plus the
         top-k routed experts count (roofline math — per-token FLOPs scale
-        with active params, not total)."""
+        with active params, not total); of a pattern's held experts, the
+        most a token can choose."""
+        if self.layer_pattern:
+            width = self.moe_latent_size or self.hidden_size
+            matrices = 2 if self.moe_latent_size else 3
+            idle = max(self.experts_held - self.num_experts_per_tok, 0)
+            return self.num_params() - (
+                self.layer_pattern.count("E") * idle * matrices * width
+                * self.intermediate_size)
         if not self.is_moe:
             return self.num_params()
         return self.num_params() - (
@@ -405,6 +460,43 @@ TINY_LFM2 = ModelConfig(
     router_norm_eps=1e-6,
 )
 
+# A gated delta-rule / gated-attention pattern at toy size: two periods of
+# three linear-attention layers and one attending layer, each over softmax-
+# routed gated experts with a gated shared expert; 2 key heads under 4
+# value heads (so the repeat is exercised), key and value widths that
+# differ, rotary on a quarter of the head, zero-centred norms, all 16
+# experts held.
+TINY_QWEN3_NEXT = ModelConfig(
+    name="tiny-qwen3-next",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=32,
+    num_layers=16,
+    num_heads=4,
+    num_kv_heads=2,
+    head_dim=16,
+    max_seq_len=512,
+    rope_theta=10_000_000.0,
+    rms_norm_eps=1e-6,
+    layer_pattern="LELELE*E" * 2,
+    qk_norm=True,
+    partial_rotary_factor=0.25,
+    attn_output_gate=True,
+    norm_offset=1.0,
+    delta_key_heads=2,
+    delta_value_heads=4,
+    delta_key_dim=8,
+    delta_value_dim=16,
+    delta_chunk=8,
+    conv_kernel=4,
+    n_routed_experts=16,
+    experts_held=16,
+    num_experts_per_tok=4,
+    moe_shared_intermediate=32,
+    router_scoring="softmax",
+    shared_expert_gate=True,
+)
+
 # A mid-size llama for single-chip benchmarking without 8B's 16 GiB of bf16
 # weights (v5e has 16 GiB HBM; 8B serves in int8 — see engine docs).
 LLAMA_1B_BENCH = replace(LLAMA32_1B, name="llama-1b-bench")
@@ -439,6 +531,7 @@ MODEL_REGISTRY = {
         TINY_GEMMA,
         TINY_HYBRID,
         TINY_LFM2,
+        TINY_QWEN3_NEXT,
         LLAMA_1B_BENCH,
         MIXTRAL_BENCH,
     )

@@ -1,8 +1,9 @@
 """Hybrid stacks: a layer pattern that is data (ModelConfig.layer_pattern).
 
-Each entry is `x ← x + f(RMSNorm(x))` with ONE of five bodies, chosen by
+Each entry is `x ← x + f(RMSNorm(x))` with ONE of six bodies, chosen by
 its character of the pattern; a published layer that is an operator and a
-feed-forward part under two norms is two entries:
+feed-forward part under two norms is two entries (the norm's gain is
+`cfg.norm_offset + w`: 1 + w is the zero-centred norm):
 
 - "M", a Mamba-2 mixer: `[z | xBC | dt] = W_in u`; xBC through a causal
   depthwise conv and silu, split into x [H, P], B and C [G, N];
@@ -16,12 +17,23 @@ feed-forward part under two norms is two entries:
   `W_out (C ⊙ conv)`. What a sequence carries is the conv's last K−1
   columns alone, through the helpers the mixer's conv uses
   (`_window_decode`, `_window_prefill`).
+- "L", a gated delta-rule linear attention: `[q | k | v | z] = W_qkvz u`,
+  `[b | a] = W_ba u`; q|k|v through a causal depthwise conv (no bias) and
+  silu; per value head (its key head is shared by Hv / Hk of them)
+  q̃ = q / ‖q‖ / √Dk, k̃ = k / ‖k‖, β = sigmoid(b),
+  g = −exp(A_log) softplus(a + dt_bias); the recurrence over a matrix
+  S [Dk, Dv]: `S ← e^g S`, `S ← S + k̃ ⊗ β (v − Sᵀk̃)`, `o = Sᵀq̃`;
+  `W_out (RMSNorm_Dv(o) · gain ⊙ silu(z))`. What a sequence carries is S
+  [Hv, Dk, Dv] (float32) and the conv's last K−1 columns.
 - "*", attention over the paged K/V pool: the projections, the paged
   write and the kernels of models/transformer.py `forward_paged`, with
-  RMSNorm over each q and k head first where `cfg.qk_norm` says so, and
-  no position embedding where `cfg.use_rope` is off.
+  RMSNorm over each q and k head first where `cfg.qk_norm` says so, the
+  position embedding over the leading `cfg.rotary_dim` of a head (none
+  where `cfg.use_rope` is off), and the context multiplied by the sigmoid
+  of a gate that W_q yields beside the query where `cfg.attn_output_gate`.
 - "E", an expert layer (ops/moe.py `moe_held`): latent un-gated experts
-  with a shared expert, or gated experts on the full hidden.
+  with a shared expert, or gated experts on the full hidden, with or
+  without a gated shared expert.
 - "D", a dense gated MLP (models/layers.py `mlp`).
 
 Parameters are grouped by kind, `params["layers"][kind]` a tuple with one
@@ -30,11 +42,13 @@ pattern unrolled. (Not stacked on a leading axis: a static slice of a
 stacked leaf may be materialised, and an expert leaf here is 0.7 GB.)
 
 A decode step (T = 1) advances the state one token for the active lanes
-(the mixer's recurrence: `ssm_state_update`, ops/hybrid_kernels.py). A
-prefill dispatch runs the mixer's chunked (SSD) form over chunks of
-`cfg.ssm_chunk`, whose inter-chunk pass also carries state from one ROW of
-the dispatch to the next when the rows are consecutive windows of one
-prompt (`PrefillRows`); a conv's columns pass from row to row the same way.
+(the mixer's recurrence: `ssm_state_update`, the delta rule's:
+`gated_delta_state_update`, ops/hybrid_kernels.py). A prefill dispatch
+runs the mixer's chunked (SSD) form over chunks of `cfg.ssm_chunk` and the
+delta rule's chunked form over chunks of `cfg.delta_chunk`; the
+inter-chunk pass of either also carries state from one ROW of the dispatch
+to the next when the rows are consecutive windows of one prompt
+(`PrefillRows`); a conv's columns pass from row to row the same way.
 """
 
 from __future__ import annotations
@@ -50,8 +64,9 @@ from .config import ModelConfig
 from .layers import init_mlp_params, mlp, qkv_project, rms_norm, rope
 from .quant import embed_lookup, qdot
 
-KINDS = {"M": "mamba", "C": "conv", "*": "attention", "E": "moe",
-         "D": "dense"}
+KINDS = {"M": "mamba", "C": "conv", "L": "delta", "*": "attention",
+         "E": "moe", "D": "dense"}
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 # Where a prefill row's state starts (PrefillRows.source).
 FROM_ZERO, FROM_SLOT, FROM_PREVIOUS_ROW = 0, 1, 2
@@ -97,24 +112,31 @@ def _normal(key, shape, dtype, fan_in):
 
 def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
     h = cfg.hidden_size
-    gain = jnp.ones((h,), dtype)
+    # The effective gain is 1 (w = 0 under the zero-centred norm).
+    gain = jnp.full((h,), 1.0 - cfg.norm_offset, dtype)
     k = jax.random.split(key, 8)
-    if kind == "mamba":
-        inner, heads = cfg.mamba_inner, cfg.mamba_num_heads
+
+    def step_and_decay(heads):
         # Δ log-uniform in DT_INIT; dt_bias is its inverse softplus (the
-        # published init).
+        # published init); A = −exp(A_log) uniform in [−16, −1].
         lo, hi = (jnp.log(v) for v in DT_INIT)
         dt = jnp.exp(
             jax.random.uniform(k[2], (heads,), jnp.float32) * (hi - lo) + lo)
+        return {
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(
+                jax.random.uniform(k[5], (heads,), jnp.float32, 1.0, 16.0)),
+        }
+
+    if kind == "mamba":
+        inner, heads = cfg.mamba_inner, cfg.mamba_num_heads
         return {
             "norm": gain,
             "w_in": _normal(k[0], (h, inner + cfg.conv_dim + heads), dtype, h),
             "conv_w": _normal(k[1], (cfg.conv_kernel, cfg.conv_dim), dtype,
                               cfg.conv_kernel),
             "conv_b": jax.random.normal(k[4], (cfg.conv_dim,), dtype) * 0.1,
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-            "A_log": jnp.log(
-                jax.random.uniform(k[5], (heads,), jnp.float32, 1.0, 16.0)),
+            **step_and_decay(heads),
             "D": jnp.ones((heads,), jnp.float32),
             "gate_norm": jnp.ones((inner,), dtype),
             "w_out": _normal(k[3], (inner, h), dtype, inner),
@@ -127,18 +149,32 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
                               cfg.conv_kernel),
             "w_out": _normal(k[2], (h, h), dtype, h),
         }
+    if kind == "delta":
+        channels = cfg.delta_conv_dim
+        values = cfg.delta_value_heads * cfg.delta_value_dim
+        return {
+            "norm": gain,
+            "w_qkvz": _normal(k[0], (h, channels + values), dtype, h),
+            "w_ba": _normal(k[4], (h, 2 * cfg.delta_value_heads), dtype, h),
+            "conv_w": _normal(k[1], (cfg.conv_kernel, channels), dtype,
+                              cfg.conv_kernel),
+            **step_and_decay(cfg.delta_value_heads),
+            "gate_norm": jnp.ones((cfg.delta_value_dim,), dtype),
+            "w_out": _normal(k[3], (values, h), dtype, values),
+        }
     if kind == "attention":
         q, kv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
         layer = {
             "norm": gain,
-            "wq": _normal(k[0], (h, q), dtype, h),
+            "wq": _normal(k[0], (h, q * (2 if cfg.attn_output_gate else 1)),
+                          dtype, h),
             "wk": _normal(k[1], (h, kv), dtype, h),
             "wv": _normal(k[2], (h, kv), dtype, h),
             "wo": _normal(k[3], (q, h), dtype, q),
         }
         if cfg.qk_norm:
-            layer["q_norm"] = jnp.ones((cfg.head_dim,), dtype)
-            layer["k_norm"] = jnp.ones((cfg.head_dim,), dtype)
+            layer["q_norm"] = layer["k_norm"] = jnp.full(
+                (cfg.head_dim,), 1.0 - cfg.norm_offset, dtype)
         return layer
     if kind == "dense":
         return {"norm": gain,
@@ -148,16 +184,22 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
     router = {
         "norm": gain,
         "router": _normal(k[0], (h, cfg.n_routed_experts), dtype, h),
-        "router_bias": jax.random.normal(
-            k[7], (cfg.n_routed_experts,), jnp.float32) * 0.02,
     }
+    if cfg.router_scoring == "sigmoid":
+        router["router_bias"] = jax.random.normal(
+            k[7], (cfg.n_routed_experts,), jnp.float32) * 0.02
     if not latent:
-        return {
+        layer = {
             **router,
             "gate": _normal(k[1], (held, h, inner), dtype, h),
             "up": _normal(k[2], (held, h, inner), dtype, h),
             "down": _normal(k[3], (held, inner, h), dtype, inner),
         }
+        if shared:
+            layer["shared"] = init_mlp_params(k[4], h, shared, dtype)
+        if cfg.shared_expert_gate:
+            layer["shared_score"] = _normal(k[5], (h,), dtype, h)
+        return layer
     return {
         **router,
         "fc1": _normal(k[1], (h, latent), dtype, h),
@@ -220,8 +262,11 @@ def _taps(ext: jax.Array, p: dict, T: int) -> jax.Array:
 
 
 def _conv(ext: jax.Array, p: dict, T: int) -> jax.Array:
-    """The mixer's: silu(conv + bias)."""
-    out = _taps(ext, p, T) + p["conv_b"].astype(jnp.float32)
+    """The mixer's and the delta body's: silu(conv + bias where the layer
+    has one)."""
+    out = _taps(ext, p, T)
+    if "conv_b" in p:
+        out = out + p["conv_b"].astype(jnp.float32)
     return jax.nn.silu(out).astype(ext.dtype)
 
 
@@ -290,6 +335,23 @@ def mamba_decode(p: dict, u, cfg: ModelConfig, ssm, conv, active):
     return _gated_out(p, y, z, cfg), ssm, conv
 
 
+def _chunk_sources(kind, nc: int):
+    """For each of the N · nc chunks of a dispatch, in dispatch order:
+    where its state starts — a row's first chunk where the row's `kind`
+    [N] says, every other chunk where the one before it ended — and the
+    row it belongs to."""
+    at = jnp.arange(kind.shape[0] * nc)
+    return (jnp.where(at % nc == 0, jnp.repeat(kind, nc), FROM_PREVIOUS_ROW),
+            at // nc)
+
+
+def _chunk_start(source, prev_end, stored):
+    """The state a chunk starts from: where the chunk before it ended,
+    what the slot `stored`, or zero."""
+    return jnp.where(source == FROM_PREVIOUS_ROW, prev_end,
+                     jnp.where(source == FROM_SLOT, stored, 0.0))
+
+
 def ssd_chunks(x, dt, A, Bm, Cm, h_first, kind, chunk: int):
     """The chunked (SSD) form of the recurrence over N rows of T tokens,
     equal to it token by token. x [N, T, H, P], dt [N, T, H] (0 where a
@@ -331,24 +393,18 @@ def ssd_chunks(x, dt, A, Bm, Cm, h_first, kind, chunk: int):
     added = jnp.einsum("cqghp,cqgn->cghpn", xdt * to_end, Bc)
     keep = jnp.exp(total).reshape(C, G, Hg)
 
-    # Between chunks, in dispatch order: a row's first chunk starts where
-    # `kind` says, every other chunk where the one before it ended.
-    first = jnp.arange(C) % nc == 0
-    chunk_kind = jnp.where(first, jnp.repeat(kind, nc), FROM_PREVIOUS_ROW)
+    # Between chunks, in dispatch order.
     h_first = h_first.reshape(N, G, Hg, P, S)
 
     def step(prev_end, inputs):
-        ck, row, add, kp = inputs
-        start = jnp.where(
-            ck == FROM_PREVIOUS_ROW, prev_end,
-            jnp.where(ck == FROM_SLOT, h_first[row], 0.0),
-        )
+        source, row, add, kp = inputs
+        start = _chunk_start(source, prev_end, h_first[row])
         end = kp[..., None, None] * start + add
         return end, (start, end)
 
     _, (starts, ends) = jax.lax.scan(
         step, jnp.zeros_like(added[0]),
-        (chunk_kind, jnp.arange(C) // nc, added, keep),
+        (*_chunk_sources(kind, nc), added, keep),
     )
     y = y + jnp.einsum("cqgn,cghpn->cqghp", Cc, starts) \
         * per_token(jnp.exp(cs))
@@ -376,6 +432,156 @@ def mamba_prefill(p: dict, u, cfg: ModelConfig, ssm, conv,
     y = y + p["D"][:, None] * xf
     ssm = ssm.at[rows.store].set(ssm_end, mode="drop")
     return _gated_out(p, y, z, cfg), ssm, _store_rows(conv, conv_end, rows)
+
+
+# -- the gated delta-rule linear attention -----------------------------------
+
+
+def _delta_in(p: dict, u, cfg: ModelConfig):
+    """(q|k|v, the columns the conv runs over; z, the output's gate; b; a)."""
+    channels, heads = cfg.delta_conv_dim, cfg.delta_value_heads
+    qkvz, ba = qdot(u, p["w_qkvz"]), qdot(u, p["w_ba"])
+    return (qkvz[..., :channels], qkvz[..., channels:],
+            ba[..., :heads], ba[..., heads:])
+
+
+def _delta_factors(p: dict, x, b, a, cfg: ModelConfig, live):
+    """The conv's output x [.., q|k|v] and b, a [.., Hv] → float32
+    q̃ [.., Hk, Dk] (L2-normed, scaled by Dk^−½), k̃ (L2-normed),
+    v [.., Hv, Dv], β and g [.., Hv] — both 0 where not `live`
+    [.., 1]: such a position leaves the state as it is."""
+    lead, width = x.shape[:-1], cfg.delta_key_heads * cfg.delta_key_dim
+    x = x.astype(jnp.float32)
+
+    def unit(y):
+        y = y.reshape(*lead, cfg.delta_key_heads, cfg.delta_key_dim)
+        return y * jax.lax.rsqrt(
+            jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+
+    q = unit(x[..., :width]) * cfg.delta_key_dim ** -0.5
+    k = unit(x[..., width:2 * width])
+    v = x[..., 2 * width:].reshape(
+        *lead, cfg.delta_value_heads, cfg.delta_value_dim)
+    beta = jnp.where(live, jax.nn.sigmoid(b.astype(jnp.float32)), 0.0)
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
+        a.astype(jnp.float32) + p["dt_bias"])
+    return q, k, v, beta, jnp.where(live, g, 0.0)
+
+
+def _delta_out(p: dict, o, z, cfg: ModelConfig):
+    """W_out (RMSNorm over each value head's dims of o, times its gain,
+    ⊙ silu(z)): the gate comes after the norm."""
+    var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+    normed = o * jax.lax.rsqrt(var + cfg.rms_norm_eps) \
+        * p["gate_norm"].astype(jnp.float32)
+    y = normed.reshape(z.shape) * jax.nn.silu(z.astype(jnp.float32))
+    return qdot(y.astype(z.dtype), p["w_out"])
+
+
+def delta_decode(p: dict, u, cfg: ModelConfig, S, conv, active):
+    """One token for every lane: u [B, hidden], S [B, Hv, Dk, Dv] f32,
+    conv [B, K−1, C]. A lane that is not `active` keeps both unchanged
+    (g = 0 and β = 0 leave S as it is, bit for bit; the conv window does
+    not shift). Returns (out [B, hidden], S, conv)."""
+    qkv, z, b, a = _delta_in(p, u, cfg)
+    ext, conv = _window_decode(conv, qkv, active)               # [B, K, C]
+    q, k, v, beta, g = _delta_factors(
+        p, _conv(ext, p, 1)[:, 0], b, a, cfg, active[:, None])
+    update = (hybrid_kernels.gated_delta_state_update
+              if hybrid_kernels.use_kernels()
+              else hybrid_kernels.gated_delta_state_update_jnp)
+    S, o = update(S, jnp.exp(g), beta, k, q, v)
+    return _delta_out(p, o, z, cfg), S, conv
+
+
+def delta_chunks(q, k, v, beta, g, S_first, kind, chunk: int):
+    """The chunked form of the gated delta rule over N rows of T tokens,
+    equal to it token by token. q / k [N, T, Hk, Dk] (as `_delta_factors`
+    leaves them), v [N, T, Hv, Dv], beta / g [N, T, Hv] (0 where a
+    position must not advance state), all float32; S_first [N, Hv, Dk, Dv]
+    the state each row starts from when its `kind` [N] is FROM_SLOT
+    (FROM_ZERO: zeros; FROM_PREVIOUS_ROW: where the row above ended).
+    Returns (o [N, T, Hv, Dv], the state at each row's end).
+
+    Inside a chunk, with c the running sum of g and Γ_ts = e^{c_t − c_s}
+    (s ≤ t): the tokens' corrections solve (I + A) D = β (V − e^c K̃ S₀),
+    A = strictLower(diag(β) (K̃K̃ᵀ ⊙ Γ)); A is nilpotent, so (I + A)^−1 is
+    the product of I + (−A)^{2^i}. Then o = e^c Q̃ S₀ + (Q̃K̃ᵀ ⊙ Γ) D and
+    the chunk leaves S = e^{c_Q} S₀ + (e^{c_Q − c} K̃)ᵀ D. Every product in
+    float32 at the highest precision."""
+    N, T, Hk, Dk = q.shape
+    Hv, Dv = v.shape[2:]
+    Q, r = min(chunk, T), Hv // Hk
+    if T % Q:
+        raise ValueError(f"a prefill window of {T} is not whole chunks of {Q}")
+    nc = T // Q
+    C = N * nc
+    # A key head's r value heads lie beside it: [C, Hk, r, Q, ..].
+    qc = q.reshape(C, Q, Hk, Dk).transpose(0, 2, 1, 3)
+    kc = k.reshape(C, Q, Hk, Dk).transpose(0, 2, 1, 3)
+    vc = v.reshape(C, Q, Hk, r, Dv).transpose(0, 2, 3, 1, 4)
+    bc = beta.reshape(C, Q, Hk, r).transpose(0, 2, 3, 1)
+    cs = jnp.cumsum(g.reshape(C, Q, Hk, r).transpose(0, 2, 3, 1), axis=-1)
+    total = cs[..., -1:]                                        # [C, Hk, r, 1]
+    gamma = jnp.exp(jnp.where(jnp.tril(jnp.ones((Q, Q), bool)),
+                              cs[..., :, None] - cs[..., None, :], -jnp.inf))
+    kk = jnp.einsum("cgtd,cgsd->cgts", kc, kc, precision=_HIGHEST)
+    qk = jnp.einsum("cgtd,cgsd->cgts", qc, kc, precision=_HIGHEST)
+
+    # (I + A)^−1 = Π (I + X^{2^i}), X = −A, factors until 2^i reaches Q.
+    X = -(bc[..., None] * kk[:, :, None] * gamma) \
+        * jnp.tri(Q, k=-1, dtype=jnp.float32)
+    inverse = jnp.eye(Q, dtype=jnp.float32) + X
+    for _ in range((Q - 1).bit_length() - 1):
+        X = jnp.einsum("cgrts,cgrsu->cgrtu", X, X, precision=_HIGHEST)
+        inverse = inverse + jnp.einsum(
+            "cgrts,cgrsu->cgrtu", inverse, X, precision=_HIGHEST)
+    # D = U − W S₀: what the corrections are from zero state, and what a
+    # start state takes from them.
+    U = jnp.einsum("cgrts,cgrsv->cgrtv", inverse, bc[..., None] * vc,
+                   precision=_HIGHEST)
+    W = jnp.einsum("cgrts,cgrs,cgsd->cgrtd", inverse, bc * jnp.exp(cs), kc,
+                   precision=_HIGHEST)
+    to_end = jnp.exp(total - cs)[..., None] * kc[:, :, None]    # [C,Hk,r,Q,Dk]
+    keep = jnp.exp(total)[..., None]                            # [C,Hk,r,1,1]
+
+    # Between chunks, in dispatch order.
+    S_first = S_first.reshape(N, Hk, r, Dk, Dv)
+
+    def step(prev_end, inputs):
+        source, row, w, u, te, kp = inputs
+        start = _chunk_start(source, prev_end, S_first[row])
+        d = u - jnp.einsum("grtd,grdv->grtv", w, start, precision=_HIGHEST)
+        end = kp * start + jnp.einsum("grtd,grtv->grdv", te, d,
+                                      precision=_HIGHEST)
+        return end, (start, d, end)
+
+    _, (starts, D, ends) = jax.lax.scan(
+        step, jnp.zeros_like(S_first[0]),
+        (*_chunk_sources(kind, nc), W, U, to_end, keep),
+    )
+    o = (jnp.einsum("cgtd,cgrdv->cgrtv", qc, starts, precision=_HIGHEST)
+         * jnp.exp(cs)[..., None]
+         + jnp.einsum("cgrts,cgrsv->cgrtv", qk[:, :, None] * gamma, D,
+                      precision=_HIGHEST))
+    return (o.transpose(0, 3, 1, 2, 4).reshape(N, T, Hv, Dv),
+            ends[nc - 1::nc].reshape(N, Hv, Dk, Dv))
+
+
+def delta_prefill(p: dict, u, cfg: ModelConfig, S, conv, rows: PrefillRows):
+    """N windows of T tokens: u [N, T, hidden]; S / conv the stored state
+    of the WHOLE slot batch. Returns (out, S, conv) with the end state of
+    every row that `rows.store` keeps written to its slot."""
+    T = u.shape[1]
+    qkv, z, b, a = _delta_in(p, u, cfg)
+    ext, conv_end = _window_prefill(conv, qkv, rows, cfg.conv_kernel)
+    real = jnp.arange(T)[None, :] < rows.length[:, None]
+    q, k, v, beta, g = _delta_factors(
+        p, _conv(ext, p, T), b, a, cfg, real[..., None])
+    o, S_end = delta_chunks(q, k, v, beta, g, S[rows.slot], rows.source,
+                            cfg.delta_chunk)
+    S = S.at[rows.store].set(S_end, mode="drop")
+    return _delta_out(p, o, z, cfg), S, _store_rows(conv, conv_end, rows)
 
 
 # -- the gated short convolution ---------------------------------------------
@@ -414,13 +620,17 @@ def attention_layer(p: dict, h, positions, cfg: ModelConfig, attend, idx,
                     pool):
     B, T, _ = h.shape
     q, k, v = qkv_project(p, h, cfg)
+    if cfg.attn_output_gate:
+        q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
-        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     if cfg.use_rope:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+        k = rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
     ctx, pool = attend(jnp.int32(idx), q, k, v, pool)
+    if cfg.attn_output_gate:
+        ctx = ctx * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(ctx.dtype)
     out = qdot(ctx.reshape(B, T, cfg.num_heads * cfg.head_dim), p["wo"])
     return out, pool
 
@@ -434,23 +644,27 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
     (T = 1, one row a lane; `rows` None) which lanes are live (`active`).
     Returns (hidden, pool, state)."""
     decode = rows is None
-    eps = cfg.rms_norm_eps
+    eps, offset = cfg.rms_norm_eps, cfg.norm_offset
     x = embed_lookup(params["embed"], tokens)
     ssm = list(state.ssm) if state is not None else []
     conv = list(state.conv) if state is not None else []
     held = 0                    # stateful layers so far: the index in `conv`
+    carried = 0                 # those with a recurrence: the index in `ssm`
     for kind, idx in layer_kinds(cfg):
         p = params["layers"][kind][idx]
-        h = rms_norm(x, p["norm"], eps)
-        if kind == "mamba":
+        h = rms_norm(x, p["norm"], eps, offset)
+        if kind in ("mamba", "delta"):
+            one, many = ((mamba_decode, mamba_prefill) if kind == "mamba"
+                         else (delta_decode, delta_prefill))
             if decode:
-                out, ssm[idx], conv[held] = mamba_decode(
-                    p, h[:, 0], cfg, ssm[idx], conv[held], active)
+                out, ssm[carried], conv[held] = one(
+                    p, h[:, 0], cfg, ssm[carried], conv[held], active)
                 out = out[:, None]
             else:
-                out, ssm[idx], conv[held] = mamba_prefill(
-                    p, h, cfg, ssm[idx], conv[held], rows)
+                out, ssm[carried], conv[held] = many(
+                    p, h, cfg, ssm[carried], conv[held], rows)
             held += 1
+            carried += 1
         elif kind == "conv":
             if decode:
                 out, conv[held] = conv_decode(p, h[:, 0], conv[held], active)
@@ -466,7 +680,7 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
         else:
             out = moe_held(p, h, cfg)
         x = x + out
-    x = rms_norm(x, params["final_norm"], eps)
+    x = rms_norm(x, params["final_norm"], eps, offset)
     if state is not None:
         state = state.replace(ssm=tuple(ssm), conv=tuple(conv))
     return x, pool, state

@@ -34,8 +34,14 @@ def rope(
     x: jax.Array,                # [B, T, H, D]
     positions: jax.Array,        # [B, T]
     theta: float,
+    rotary_dim: Optional[int] = None,
 ) -> jax.Array:
-    """Rotary position embedding, half-split (rotate-half) convention."""
+    """Rotary position embedding, half-split (rotate-half) convention;
+    over the leading `rotary_dim` of the head where that is given (a
+    partial rotary: the dims after it pass as they are)."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        turned = rope(x[..., :rotary_dim], positions, theta)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     half = x.shape[-1] // 2
     freqs = theta ** (
         -jnp.arange(0, half, dtype=jnp.float32) / half
@@ -68,7 +74,9 @@ def mlp(p: dict, x: jax.Array, activation: str) -> jax.Array:
 def qkv_project(
     p: dict, x: jax.Array, cfg: ModelConfig
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """q, k, v of one attention layer, split into heads: [B, T, heads, D].
+    """q, k, v of one attention layer, split into heads: [B, T, heads, D]
+    (q [B, T, heads, 2 D] where `cfg.attn_output_gate`: each head's query,
+    then its gate — one product, as wide again).
 
     Each product is held FLAT ([B, T, heads · D]) behind an optimization
     barrier of its own until it is done, and only then split. Without it,
@@ -91,12 +99,13 @@ def qkv_project(
     (scripts/decode_step_census.py prints them)."""
     B, T, _ = x.shape
 
-    def heads(name: str, n: int) -> jax.Array:
+    def heads(name: str, n: int, width: int = cfg.head_dim) -> jax.Array:
         flat = jax.lax.optimization_barrier(qdot(x, p[name]))
-        return flat.reshape(B, T, n, cfg.head_dim)
+        return flat.reshape(B, T, n, width)
 
     return (
-        heads("wq", cfg.num_heads),
+        heads("wq", cfg.num_heads,
+              cfg.head_dim * (2 if cfg.attn_output_gate else 1)),
         heads("wk", cfg.num_kv_heads),
         heads("wv", cfg.num_kv_heads),
     )

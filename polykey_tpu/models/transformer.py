@@ -63,10 +63,11 @@ def init_cache(
 
 def _norm_init(cfg: ModelConfig, dtype) -> jax.Array:
     # rms_norm computes gain = offset + w (offset 1.0 for the Gemma storage
-    # convention, models with scale_embeddings). Init w so the effective
-    # gain is 1 — zero gains would make every hidden state identically
-    # zero at init, turning random-init tests vacuous.
-    norm_offset = 1.0 if cfg.scale_embeddings else 0.0
+    # convention, models with scale_embeddings; a layer pattern states its
+    # own, ModelConfig.norm_offset). Init w so the effective gain is 1 —
+    # zero gains would make every hidden state identically zero at init,
+    # turning random-init tests vacuous.
+    norm_offset = 1.0 if cfg.scale_embeddings else cfg.norm_offset
     return jnp.full((cfg.hidden_size,), 1.0 - norm_offset, dtype)
 
 

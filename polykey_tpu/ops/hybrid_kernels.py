@@ -1,4 +1,4 @@
-"""Pallas kernels of the hybrid stacks' two decode-bound layers (TPU).
+"""Pallas kernels of the hybrid stacks' decode-bound layers (TPU).
 
 `ssm_state_update` — one decode step of the Mamba-2 recurrence for every
 lane: h ← dA · h + (Δ·x) ⊗ B, y = h · C, with the float32 state
@@ -12,6 +12,16 @@ h · C runs on the otherwise idle MXU. Alone on the chip at the published
 shape (my chip runs, PR 43): 0.906 ms a call against 0.656 of bytes; the
 form with the decay broadcast from a column and the reduction over lanes
 on the vector units read 1.167, XLA's own fusion 0.871.
+
+`gated_delta_state_update` — one decode step of the gated delta rule for
+every lane: S ← e^g S + k̃ ⊗ β (v − e^g Sᵀk̃), o = Sᵀq̃ of the NEW state,
+with the float32 state [B, Hv, Dk, Dv] aliased in place. Both contractions
+are taken from the state as it was read (m = e^g Sᵀk̃; o = e^g Sᵀq̃ +
+(q̃·k̃) d), so the state is read once and written once (64 lanes × 2 MiB a
+layer) and everything else is kilobytes. k̃ and q̃ reach the kernel
+transposed ([B, Dk, Hk]: a key head's vector is a [Dk, 1] column that
+broadcasts along the state's lanes, the value dims); the contractions over
+Dk are sums down the sublanes on the vector units.
 
 `moe_held_experts` — the held experts of an expert layer as ONE pass over
 their weights: for every held expert e, a(v, e) · w[:, e] · W_down[e],
@@ -155,6 +165,74 @@ def ssm_state_update(h, dA, xdt, Bm, Cm, *, interpret: bool = False):
         name="ssm_state_update",
     )(dA, cols, Bm, Cm, h)
     return new, y.transpose(0, 1, 3, 2).reshape(B, H, P)
+
+
+# -- gated delta rule decode state update ----------------------------------
+
+
+def gated_delta_state_update_jnp(S, decay, beta, k, q, v):
+    """S [B, Hv, Dk, Dv], decay = e^g and beta [B, Hv], k / q [B, Hk, Dk]
+    (L2-normed, q scaled; value head j reads key head j div Hv/Hk),
+    v [B, Hv, Dv], all float32 → (S_new, o [B, Hv, Dv]). A lane with
+    decay 1 and beta 0 keeps its state bit for bit."""
+    rep = S.shape[1] // k.shape[1]
+    kh, qh = jnp.repeat(k, rep, axis=1), jnp.repeat(q, rep, axis=1)
+    hi = jax.lax.Precision.HIGHEST
+    m = decay[..., None] * jnp.einsum("bhkv,bhk->bhv", S, kh, precision=hi)
+    d = beta[..., None] * (v - m)
+    o = (decay[..., None] * jnp.einsum("bhkv,bhk->bhv", S, qh, precision=hi)
+         + jnp.sum(qh * kh, axis=-1, keepdims=True) * d)
+    new = decay[..., None, None] * S + kh[..., :, None] * d[..., None, :]
+    return new, o
+
+
+def _delta_kernel(decay_ref, beta_ref, qk_ref, kt_ref, qt_ref, v_ref, s_ref,
+                  out_ref, o_ref):
+    # decay / beta [B, Hv] and qk = q̃·k̃ [B, Hk] whole, in SMEM (scalars of
+    # a head); blocks of one lane: kt / qt [Dk, Hk], v / o [Hv, Dv],
+    # s / out [Hv, Dk, Dv].
+    Hv, Hk = v_ref.shape[0], kt_ref.shape[1]
+    lane = pl.program_id(0)
+    for head in range(Hv):
+        g = head // (Hv // Hk)
+        kc, qc = kt_ref[:, g:g + 1], qt_ref[:, g:g + 1]        # [Dk, 1]
+        decay = decay_ref[lane, head]
+        S = s_ref[head]
+        m = decay * jnp.sum(S * kc, axis=0, keepdims=True)     # [1, Dv]
+        d = beta_ref[lane, head] * (v_ref[head:head + 1, :] - m)
+        o_ref[head:head + 1, :] = (
+            decay * jnp.sum(S * qc, axis=0, keepdims=True)
+            + qk_ref[lane, g] * d)
+        out_ref[head] = decay * S + kc * d
+
+
+def gated_delta_state_update(S, decay, beta, k, q, v, *,
+                             interpret: bool = False):
+    """The kernel form of `gated_delta_state_update_jnp`; `S` is updated
+    in place (aliased), so the caller's donated state buffer is the
+    output."""
+    B, Hv, Dk, Dv = S.shape
+    Hk = k.shape[1]
+    scalars = pl.BlockSpec(memory_space=pltpu.SMEM)
+    column = pl.BlockSpec((None, Dk, Hk), lambda b: (b, 0, 0))
+    row = pl.BlockSpec((None, Hv, Dv), lambda b: (b, 0, 0))
+    state = pl.BlockSpec((None, Hv, Dk, Dv), lambda b: (b, 0, 0, 0))
+    return pl.pallas_call(
+        _delta_kernel,
+        grid=(B,),
+        in_specs=[scalars, scalars, scalars, column, column, row, state],
+        out_specs=[state, row],
+        out_shape=[jax.ShapeDtypeStruct(S.shape, S.dtype),
+                   jax.ShapeDtypeStruct(v.shape, jnp.float32)],
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="gated_delta_state_update",
+    )(decay, beta, jnp.sum(q * k, axis=-1), k.transpose(0, 2, 1),
+      q.transpose(0, 2, 1), v, S)
 
 
 # -- held experts of an expert layer ---------------------------------------

@@ -1,7 +1,7 @@
 """Mixture-of-Experts layers.
 
 Mixtral-style softmax top-k routing, in two formulations, and the held
-experts of a sigmoid-routed layer (`moe_held`), in two forms:
+experts of a layer pattern's expert layer (`moe_held`), in two forms:
 
 - `moe_mlp` — einsum-dense: every token runs through every expert, weighted
   by the (sparse) combine matrix. Simple, fully differentiable, and shards
@@ -11,15 +11,18 @@ experts of a sigmoid-routed layer (`moe_held`), in two forms:
   compute" layout. Cost: num_experts/top_k × the FLOPs of sparse dispatch
   (4× for Mixtral 8×7B's 8-choose-2) — acceptable for correctness paths and
   small batches.
-- `moe_held` — one chip's share of a sigmoid-routed expert layer: the
-  router over every published expert, and the held experts' part of the
-  sum as one pass over their weights (ops/hybrid_kernels.py
+- `moe_held` — one chip's share of an expert layer: the router over
+  every published expert (`held_router_weights`: sigmoid scores chosen
+  by score + bias, or a softmax over all of them, as the config states),
+  and the held experts' part of the sum as one pass over their weights
+  (ops/hybrid_kernels.py
   `moe_held_experts`: every row against every held expert, the combine
   weights masking) up to the chip's ridge, and over rows sorted by expert
   above it (`moe_held_experts_grouped`: each chosen pair once). No
   capacity, no drop, in either. `moe_latent_held`: un-gated
   relu² experts inside a latent, plus a shared expert; `moe_gated_held`:
-  gated experts on the full hidden.
+  gated experts on the full hidden, plus a gated shared expert where the
+  config states one.
 - `moe_mlp_dispatch` — capacity-bucketed sparse dispatch: tokens gather into
   per-expert buckets (static capacity, dropped on overflow like GShard/
   Switch), experts run batched matmuls on their buckets only, results
@@ -36,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import ModelConfig
-from ..models.layers import _activate
+from ..models.layers import _activate, mlp
 from ..models.quant import qdot, qeinsum_expert
 from . import hybrid_kernels
 
@@ -135,16 +138,22 @@ def moe_mlp_dispatch(
     return mixed.reshape(B, T, H).astype(h.dtype)
 
 
-def latent_router_weights(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Combine weights [.., n_routed_experts] (float32, 0 off the chosen):
-    sigmoid scores; the top `num_experts_per_tok` of score + correction
-    bias are chosen; each takes its own score over the sum of ALL the
-    chosen scores — held here or not — (+ `router_norm_eps` where the
-    model adds one) times `routed_scaling_factor`."""
-    scores = jax.nn.sigmoid(jnp.einsum(
-        "...h,he->...e", h, p["router"], preferred_element_type=jnp.float32,
-    ))
-    _, idx = jax.lax.top_k(scores + p["router_bias"], cfg.num_experts_per_tok)
+def held_router_weights(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Combine weights [.., n_routed_experts] (float32, 0 off the chosen),
+    by `cfg.router_scoring`: "sigmoid" scores, the top
+    `num_experts_per_tok` of score + correction bias chosen; "softmax"
+    over ALL the published experts, the top by score chosen, no bias.
+    Each chosen expert takes its own score over the sum of ALL the chosen
+    scores — held here or not — (+ `router_norm_eps` where the model adds
+    one) times `routed_scaling_factor`."""
+    logits = jnp.einsum(
+        "...h,he->...e", h, p["router"], preferred_element_type=jnp.float32)
+    if cfg.router_scoring == "softmax":
+        scores = by = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        by = scores + p["router_bias"]
+    _, idx = jax.lax.top_k(by, cfg.num_experts_per_tok)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     total = jnp.sum(chosen, axis=-1, keepdims=True)
     if cfg.router_norm_eps:
@@ -165,15 +174,16 @@ def held_experts_grouped(rows: int) -> bool:
 
 def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Σ over the chosen experts that are held of weight · a(v, e) ·
-    W_down,e, float32 [rows, width of v]: the router reads `tokens`
-    [rows, H]; the experts read v = `tokens` through `fc1` where the
-    config states a latent, else `tokens`, gated where the layer has a
-    `gate`. Three forms of one sum over the same combine weights, chosen
-    by the backend and the static row count alone: off the chip
+    W_down,e, float32 [rows, width of v]: the router
+    (`held_router_weights`) reads `tokens` [rows, H]; the experts read
+    v = `tokens` through `fc1` where the config states a latent, else
+    `tokens`, gated where the layer has a `gate`. Three forms of one sum
+    over the same combine weights, chosen by the backend and the static
+    row count alone: off the chip
     `moe_held_experts_jnp`; on it the masked one-pass kernel up to the
     ridge (a decode step, a one-window prefill) and the grouped kernel
     above it."""
-    weights = latent_router_weights(p, tokens, cfg)[
+    weights = held_router_weights(p, tokens, cfg)[
         :, cfg.first_expert:cfg.first_expert + cfg.experts_held
     ]
     v = qdot(tokens, p["fc1"]) if cfg.moe_latent_size else tokens
@@ -191,12 +201,17 @@ def moe_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     """The expert layer of a layer pattern ("E"), by what the config
     states: a latent → `moe_latent_held`; none → `moe_gated_held`. A
     combination neither computes is refused here, not half-served."""
+    if cfg.router_scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"router_scoring {cfg.router_scoring!r} is not "
+                         "computed: sigmoid or softmax")
+    if cfg.shared_expert_gate and (cfg.moe_latent_size
+                                   or not cfg.moe_shared_intermediate):
+        raise ValueError(
+            "a gate on the shared expert is computed beside gated experts "
+            "on the full hidden only: shared_expert_gate needs "
+            "moe_shared_intermediate and no moe_latent_size")
     if cfg.moe_latent_size:
         return moe_latent_held(p, h, cfg)
-    if cfg.moe_shared_intermediate:
-        raise ValueError(
-            "a shared expert beside gated experts on the full hidden is "
-            "not computed: moe_shared_intermediate needs moe_latent_size")
     return moe_gated_held(p, h, cfg)
 
 
@@ -207,10 +222,20 @@ def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     over the chosen experts that are held. The router and the product
     are `moe_latent_held`'s (`_held_product`): every held expert's three
     matrices are read once whatever the routing chose, and no token is
-    dropped."""
+    dropped. Where the config states `moe_shared_intermediate`, a shared
+    expert of the same gated form is added on every chip (it is
+    replicated, not held in shares), weighed by the scalar
+    sigmoid(w_s · h) where `shared_expert_gate` says so."""
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
     out = _held_product(p, tokens, cfg)
+    if cfg.moe_shared_intermediate:
+        shared = mlp(p["shared"], tokens, cfg.activation).astype(jnp.float32)
+        if cfg.shared_expert_gate:
+            shared = shared * jax.nn.sigmoid(jnp.einsum(
+                "rh,h->r", tokens, p["shared_score"],
+                preferred_element_type=jnp.float32))[:, None]
+        out = out + shared
     return out.astype(h.dtype).reshape(B, T, H)
 
 

@@ -31,6 +31,9 @@ Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
        block width of the decode kernel varied one at a time)
      python scripts/tpu_kernel_check.py --held-experts   (the held-time and
        held-compare rows alone)
+     python scripts/tpu_kernel_check.py --delta-state   (the gated delta
+       rule's decode update alone: `delta-compare` against its jnp form,
+       `delta-time` µs a call beside the state's bytes ÷ the bandwidth)
      JAX_PLATFORMS=cpu python scripts/tpu_kernel_check.py --interpret
        rehearses the script itself at small tables in Pallas interpret
        mode — it proves nothing about lowering and exits 2 like any run
@@ -546,6 +549,73 @@ def check_held_experts() -> None:
                      partial(time_held, shape, rows, grouped))
 
 
+# -- the gated delta rule's decode state update -------------------------------
+
+DELTA_SHAPE = (64, 16, 32, 128, 128)     # lanes, key heads, value heads, Dk, Dv
+DELTA_CALLS = 18                         # two steps of nine layers
+
+
+def delta_inputs(shape, seed: int = 0):
+    B, Hk, Hv, Dk, Dv = shape
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    return (jax.random.normal(k[0], (B, Hv, Dk, Dv), jnp.float32),
+            jnp.exp(-jax.random.uniform(k[1], (B, Hv), jnp.float32)),
+            jax.nn.sigmoid(jax.random.normal(k[2], (B, Hv), jnp.float32)),
+            unit(jax.random.normal(k[3], (B, Hk, Dk), jnp.float32)),
+            unit(jax.random.normal(k[4], (B, Hk, Dk), jnp.float32)) * Dk ** -0.5,
+            jax.random.normal(k[5], (B, Hv, Dv), jnp.float32))
+
+
+def check_delta_state() -> None:
+    """`gated_delta_state_update` at the published shape: against its
+    jax.numpy form (an inactive lane bit for bit), and microseconds a call
+    — calls back to back inside one jitted scan that carries S, as the
+    decode step's nine layers do — beside the least time its bytes allow."""
+    from polykey_tpu.ops import hybrid_kernels as hk
+
+    interpret = "interpret" in KERNEL
+    shape = (4, 2, 4, 8, 16) if interpret else DELTA_SHAPE
+    kernel = partial(hk.gated_delta_state_update, interpret=interpret)
+
+    def compare():
+        S, decay, beta, k, q, v = delta_inputs(shape)
+        decay, beta = decay.at[1].set(1.0), beta.at[1].set(0.0)
+        want = jax.jit(hk.gated_delta_state_update_jnp)(S, decay, beta, k, q, v)
+        got = jax.jit(kernel)(S, decay, beta, k, q, v)
+        if not bool(jnp.all(got[0][1] == S[1])):
+            raise AssertionError("an inactive lane's state moved")
+        return (f"S {assert_close(got[0], want[0], 1e-4)}, "
+                f"o {assert_close(got[1], want[1], 1e-4)}, lane 1 untouched")
+
+    def timed(update):
+        S, decay, beta, k, q, v = delta_inputs(shape)
+        calls = 2 if interpret else DELTA_CALLS
+        run = jax.jit(lambda S, *rest: jax.lax.scan(
+            lambda S, _: update(S, *rest), S, None, length=calls),
+            donate_argnums=0)
+        S, _ = jax.block_until_ready(run(S, decay, beta, k, q, v))  # compile
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            S, _ = jax.block_until_ready(run(S, decay, beta, k, q, v))
+            best = min(best, time.perf_counter() - t0)
+        if interpret:
+            return "rehearsed"
+        least = 2 * S.nbytes / hbm_bytes_per_s()
+        return (f"{best / calls * 1e6:.1f} us a call; state read + written "
+                f"{least * 1e6:.1f} us ({100 * least * calls / best:.1f} %)")
+
+    geometry = "x".join(map(str, shape))
+    case("delta-compare", geometry, compare)
+    case("delta-time", geometry + " kernel", partial(timed, kernel))
+    case("delta-time", geometry + " jnp", partial(
+        timed, hk.gated_delta_state_update_jnp))
+
+
 def check_block_until_ready() -> None:
     """Does jax.block_until_ready block here? A long dependent matmul
     chain is dispatched; the call returning in a sliver of the time the
@@ -593,8 +663,12 @@ def main() -> int:
     # The smoke's default path first; the kernels that have never run on
     # hardware last, so a hang there costs no other case its evidence.
     held_only = "--held-experts" in sys.argv[1:]
+    delta_only = "--delta-state" in sys.argv[1:]
     timing_only = held_only or "--timing" in sys.argv[1:]
     check_block_until_ready()
+    if delta_only:
+        check_delta_state()
+        return report(identity, interpret)
     if not timing_only:
         check_flash()
         check_decode(quantized=False)
@@ -603,9 +677,14 @@ def main() -> int:
     if not held_only:
         check_decode_timing(sweep="--sweep" in sys.argv[1:])
     check_held_experts()
+    check_delta_state()
     if not timing_only:
         check_decode(quantized=True)
         check_write(quantized=True)
+    return report(identity, interpret)
+
+
+def report(identity, interpret: bool) -> int:
     failed = [r for r in RESULTS if not r[2]]
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)

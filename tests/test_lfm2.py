@@ -39,7 +39,7 @@ from polykey_tpu.models.hybrid import (
 )
 from polykey_tpu.models.transformer import init_params, unembed
 from polykey_tpu.ops import hybrid_kernels
-from polykey_tpu.ops.moe import latent_router_weights, moe_gated_held, moe_held
+from polykey_tpu.ops.moe import held_router_weights, moe_gated_held, moe_held
 import grouped_experts
 from pattern_stack import SLOTS, SlotBatch, served, text, worst_margin
 
@@ -164,12 +164,12 @@ def test_router_bias_chooses_but_does_not_weigh():
         jnp.asarray([2.0, 1.0, 0.0, -1.0]))
     h = jnp.zeros((1, CFG.hidden_size)).at[0, 0].set(1.0)
     s = 1.0 / (1.0 + np.exp(-np.asarray([2.0, 1.0, 0.0, -1.0])))
-    plain = latent_router_weights(
+    plain = held_router_weights(
         {"router": router, "router_bias": jnp.zeros((4,))}, h, cfg)
     total = s[0] + s[1] + 1e-6
     np.testing.assert_allclose(
         plain[0], [s[0] / total, s[1] / total, 0.0, 0.0], atol=1e-6)
-    biased = latent_router_weights(
+    biased = held_router_weights(
         {"router": router,
          "router_bias": jnp.asarray([0.0, 0.0, 0.5, 0.0])}, h, cfg)
     total = s[0] + s[2] + 1e-6
@@ -209,8 +209,10 @@ def test_the_four_shares_add_up_to_the_uncut_layer(params):
 def test_an_expert_form_nobody_computes_is_refused(params):
     p = params["layers"]["moe"][0]
     x = jnp.zeros((1, 2, CFG.hidden_size))
-    with pytest.raises(ValueError, match="shared expert beside gated"):
-        moe_held(p, x, dataclasses.replace(CFG, moe_shared_intermediate=8))
+    with pytest.raises(ValueError, match="gate on the shared expert"):
+        moe_held(p, x, dataclasses.replace(CFG, shared_expert_gate=True))
+    with pytest.raises(ValueError, match="router_scoring"):
+        moe_held(p, x, dataclasses.replace(CFG, router_scoring="tanh"))
 
 
 def test_conv_decode_form_equals_its_prefill_form_column_by_column(params):

@@ -474,6 +474,68 @@ def test_operator_ffn_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
         assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
 
 
+@pytest.mark.parametrize("prefill", [None, (1, 128), (2, 512)],
+                         ids=["decode", "prefill-1x128", "prefill-2x512"])
+def test_delta_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
+    """The steps of a gated delta-rule / output-gated attention pattern
+    (zero-centred norms, partial rotary, softmax-routed gated experts with
+    a gated shared expert) compiled for a v5e at the published widths of
+    what it adds — hidden 2048, 16 / 32 linear heads of 128 (a state of
+    [slots, 32, 128, 128] float32 a layer), 16 / 2 heads of 256 with a
+    gate as wide as the query, experts of 2048 x 512 — at toy depth, 8
+    experts held and a short vocabulary. Decode: S, the conv columns and
+    the pool are aliased input to output, no instruction copies a
+    state-sized buffer, and every kernel is there once under the name the
+    benchmark's readers hold fixed; the delta update is that ONE call a
+    linear layer. Prefill: the chunked form, which never calls the decode
+    kernel. Every module: q (with its gate) and k at head width 256 stay
+    plain matmuls (ISSUE 44's three forms are absent)."""
+    from polykey_tpu.models.config import get_config
+
+    cfg = replace(
+        get_config("tiny-qwen3-next"), name="delta-attention-probe",
+        vocab_size=4096, hidden_size=2048, layer_pattern="LE*E", num_layers=4,
+        num_heads=16, num_kv_heads=2, head_dim=256, delta_key_heads=16,
+        delta_value_heads=32, delta_key_dim=128, delta_value_dim=128,
+        delta_chunk=64, intermediate_size=512, moe_shared_intermediate=512,
+        n_routed_experts=512, experts_held=8, num_experts_per_tok=10,
+    )
+    compiled, paged, state = _compile_pattern_step(
+        v5e, monkeypatch, cfg, prefill=prefill)
+    hlo = compiled.as_text()
+    wk_bytes = cfg.hidden_size * cfg.num_kv_heads * cfg.head_dim * 2
+    assert head_window_products(hlo) == []
+    # (A one-row prefill reads its slot's float32 S by a slice: state, not
+    # a weight. The weights are bf16.)
+    assert [r for r in staged_weight_slices(hlo, wk_bytes)
+            if not r.startswith("f32")] == []
+    assert layer_weight_copies(hlo, "bf16", cfg.hidden_size) == []
+    calls = _kernel_calls(hlo)
+    assert sum(c.startswith("%moe_held_experts") for c in calls) == 2, calls
+    grouped = prefill == (2, 512)
+    assert all(c.startswith("%moe_held_experts_grouped") == grouped
+               for c in calls if c.startswith("%moe_held_experts")), calls
+    assert sorts(hlo) == []
+    updates = sum(c.startswith("%gated_delta_state_update") for c in calls)
+    assert updates == (1 if prefill is None else 0), calls
+    if prefill is not None:
+        return
+    # (The conv's columns — 3 MB a layer, larger than `wk` here — are
+    # copied once a layer into the layout the window's concatenation wants:
+    # 7 us of a step at the chip's bandwidth. No weight is.)
+    S, conv = "f32[64,32,128,128]", "bf16[64,3,8192]"
+    assert [r for r in weight_relayouts(hlo, wk_bytes) if conv not in r] == []
+    assert aliased_pool_parameters(hlo, S) == 1
+    assert aliased_pool_parameters(hlo, conv) == 1
+    assert [line for line in hlo.splitlines()
+            if S in line and " copy(" in line] == []
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((paged, state)))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    for name in ("%paged_kv_write", "%paged_attention_decode"):
+        assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
+
+
 # -- the q / k / v projections in the compiled steps (ISSUE 44) ---------------
 #
 # Where a dimension of 1 stands beside the rows ([B, 1, H] in the decode
