@@ -1269,13 +1269,14 @@ class ShapeLayoutContracts(GraphCheck):
             "ops.paged_attention_kernel._decode_call",
             lambda *args: paged_mod._decode_call(
                 *args, scale=D ** -0.5, logit_softcap=None, interpret=False,
+                state=False,
             ),
             (q, kvp, tables, positions, window, page_range),
-            [((2, Hq, D), "float32"),
-             ((2, Hq, 1), "float32"), ((2, Hq, 1), "float32")],
+            [((2, Hq, D), "float32")],
         ))
         # int8-KV variant: the (values, k scales, v scales) triple,
-        # scales [N, ps, Hk].
+        # scales [N, ps, Hk]; here as a context-parallel shard calls it,
+        # for the unnormalised state.
         kq = jnp.zeros((2 * N, ps, Hk * D), jnp.int8)
         scales = jnp.zeros((N, ps, Hk), jnp.bfloat16)
         findings.extend(abstract_contract(
@@ -1283,6 +1284,7 @@ class ShapeLayoutContracts(GraphCheck):
             lambda q2, kv, sc, t, p, w, r: paged_mod._decode_call(
                 q2, (kv, sc, sc), t, p, w, r,
                 scale=D ** -0.5, logit_softcap=None, interpret=False,
+                state=True,
             ),
             (q.astype(jnp.bfloat16), kq, scales, tables, positions, window,
              page_range),

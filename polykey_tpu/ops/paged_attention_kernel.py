@@ -3,51 +3,59 @@
 The gather path (ops/paged_attention.py) materializes each sequence's KV
 window in HBM every decode step: `kv_pages[2 * page_tables]` reads the pages
 AND writes a [B, P·page_size, Hk, D] copy, so the cache crosses HBM twice. This
-kernel reads each valid page exactly once: one grid program per sequence,
-a double-buffered DMA loop streams that sequence's pages HBM → VMEM while
-the previous block's attention accumulates into online-softmax state
+kernel reads each valid page exactly once: ONE program walks the call's
+sequences (all of them where their q and output blocks fit the scoped VMEM,
+`_program_lanes`), a double-buffered DMA loop streams each sequence's pages
+HBM → VMEM while the pages before them accumulate into online-softmax state
 (running max m, denominator l, fp32 accumulator) — the same recurrence as
-ops/flash_attention.py.
+ops/flash_attention.py — and the program writes one normalised [S, Hq, D]
+block in the activations' dtype.
 
-Pages stream in GROUPS of `pages_per_block` (G): each buffer slot holds G
-pages, whose DMAs are all in flight together, so per-page DMA latency
-(~µs for a 32 KB page — the dominant cost of a one-page-at-a-time loop)
-amortizes G× and the per-group attention block is [G·page_size] wide —
-MXU-shaped work instead of page_size-sliver matmuls. G consecutive page
-table entries cover contiguous positions, so the group's mask is one iota.
-G follows the BYTES a block moves (`_decode_call`): the same bytes in
-flight a slot at every folded width.
+Pages stream in BLOCKS of `pages_per_block` (G): each buffer slot holds G
+pages, whose DMAs all go out together, so per-page DMA latency amortizes
+G×. A block is awaited and computed by ROW TILES of Gt pages (`_tile_pages`),
+each with a semaphore of its own: a tile's attention is [Gt·page_size] wide
+— MXU-shaped work — and runs as soon as ITS pages have landed, while the
+rest of the block and the next one are still on their way; only the tiles
+that hold a fetched page are computed, so a sequence with one page does one
+tile. Gt consecutive page table entries cover contiguous positions, so the
+tile's mask is one iota. G and Gt follow BYTES (`_BLOCK_BYTES`,
+`_TILE_BYTES`): the same bytes in flight a slot, and the same bytes a tile's
+dependent chain of products, maximum, exponential and products is spread
+over, at every folded width.
 
-The two buffer slots alternate ACROSS sequences, not only inside one (the
-grid runs in order): while a sequence's last block computes, the first
-block of the next sequence that has any visible page is already on its way
-into the other slot, so one fetch a call is waited on cold, not one a
-sequence; and a sequence walks only its live blocks [blo, bhi), not the
-table's whole width. What a call costs beyond its bytes, measured on a v5e
-(PERF.md §5): ~0.5 µs a sequence (the grid step, its q and output blocks)
-and ~5–8 ns a DMA descriptor started, ~4.5 awaited, whatever it carries —
-which weighs on a narrow tp shard, whose page halves are 8 KB, 10 ns of
-bytes. So a call issues as few as the pages allow: K and V of a page lie
+The two buffer slots alternate ACROSS sequences, not only inside one:
+while a sequence's last block computes, the first block of the next
+sequence that has any visible page is already on its way into the other
+slot, so one fetch a call is waited on cold, not one a sequence; and a
+sequence walks only its live blocks [blo, bhi), not the table's whole
+width. That hand-over is the carry of the loop over a program's sequences
+and lives in SMEM only from one program to the next. What a call costs
+beyond its bytes, measured on a v5e (PERF.md §5): ~0.45 µs a sequence of
+one tile's dependent chain and the scalar core's schedule (nothing of it
+is the grid step), and ~30 ns of scalar work a page started — which is
+the call on a narrow tp shard, whose page is 16 KB, 20 ns of bytes. So a
+call issues as few descriptors as the pages allow: K and V of a page lie
 side by side in the pool (engine/kv_cache.py; here as its page halves, 2p
-and 2p + 1) and come in under ONE start,
-and a block's pages are awaited by RUNS — every page of a block signals its
-slot's semaphore and a wait looks only at the semaphore and a byte count,
-so the n pages in flight are awaited as one wait of 1, 2, 4 … pages for
-each set bit of n (`_wait_runs`): a full block of G = 2^k pages is one wait.
+and 2p + 1) and come in under ONE start, and a tile's pages are awaited by
+RUNS — every page of a tile signals the tile's semaphore and a wait looks
+only at the semaphore and a byte count, so the n pages in flight are
+awaited as one wait of 1, 2, 4 … pages for each set bit of n (`_wait_runs`):
+a full tile of Gt = 2^k pages is one wait.
 
 Invalid page-table tails (the reserved garbage page 0) are never DMA'd:
 the loop bound is ceil((position+1)/page_size), data-dependent per
 sequence, and Gemma-2 sliding-window layers also skip pages wholly below
-position - window. Buffer regions for pages outside [lo, hi) hold stale
-VMEM; their logits are masked, and V is zeroed on those rows so masked
-weights never multiply uninitialized data (0·NaN would poison the
-accumulator).
+position - window. In the tile that straddles an end of [lo, hi) the rows
+of pages outside it hold stale VMEM; their logits are masked, and V is
+zeroed on those rows so masked weights never multiply uninitialized data
+(0·NaN would poison the accumulator). Tiles wholly outside are never read.
 
-The kernel emits UNNORMALIZED online-softmax state (acc, m, l) over a
-page sub-range: the wrapper normalizes locally, or — context-parallel
-decode, mesh sp>1 — each sp shard covers a contiguous slice of every
-sequence's pages and partial states merge via pmax/psum before
-normalizing (see paged_attention_decode).
+Where no merge follows, the kernel divides by max(l, 1e-9) itself. For
+context-parallel decode (mesh sp>1) it emits the UNNORMALIZED state
+(acc, m, l) over a page sub-range instead: each sp shard covers a
+contiguous slice of every sequence's pages and the partial states merge
+via pmax/psum before normalizing (see paged_attention_decode).
 
 Covers GQA, logit soft-capping, and dynamic sliding windows; falls back to
 the gather implementation off-TPU (`use_kernel` dispatch in
@@ -58,6 +66,7 @@ kill-switch).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -68,66 +77,85 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
+def _div(x, n: int):
+    """x // n on the scalar core for a static n: a shift where n is a power
+    of two (pages, blocks and tiles are) — the core divides in software,
+    and the schedule's divisions were 6 % of a tp shard's call (PERF.md
+    §5). A shift rounds down; every use either has x >= 0 or clamps the
+    result at 0, where that agrees with a division rounding toward zero."""
+    if n & (n - 1) == 0:
+        return jax.lax.shift_right_arithmetic(x, n.bit_length() - 1)
+    return jax.lax.div(x, n)
+
+
 def _kernel(
     # scalar prefetch
     pt_ref,        # [B, P] int32 page tables
     pos_ref,       # [B] int32 decode position per sequence
     win_ref,       # [1] int32 sliding window (<=0 → global)
     rng_ref,       # [2] int32 page sub-range [rlo, rhi) — CP shard's slice
-    # then, positionally (arity varies with `quantized`):
-    # inputs: q [1, Hq, D] VMEM block; kv page halves [2N, ps, Hk·D] HBM
-    #         (the stored layout: page p's K at 2p, its V at 2p + 1,
-    #         heads in lanes; manual DMA; N may be the whole stack's
-    #         L·num_pages, the table's ids offset by the layer);
-    #         quantized adds ks/vs scale pages [N, ps, Hk] HBM (bf16)
-    # outputs: unnormalized online-softmax state — the wrapper
-    #         normalizes, or merges across CP shards first (acc/l scale
-    #         by exp(m - m_global)): acc [1, Hq, D] f32, m/l
-    #         [1, Hq, MINOR] f32
+    # then, positionally (arity varies with `quantized` and `state`):
+    # inputs: q [S, Hq, D] VMEM block — the program's S sequences; kv page
+    #         halves [2N, ps, Hk·D] HBM (the stored layout: page p's K at
+    #         2p, its V at 2p + 1, heads in lanes; manual DMA; N may be the
+    #         whole stack's L·num_pages, the table's ids offset by the
+    #         layer); quantized adds ks/vs scale pages [N, ps, Hk] HBM (bf16)
+    # outputs: ONE normalised block [S, Hq, D] in the activations' dtype,
+    #         or with `state` the unnormalised online-softmax state for a
+    #         merge across CP shards (acc/l scale by exp(m - m_global)):
+    #         acc [S, Hq, D] f32, m/l [S, Hq, 1] f32
     # scratch: kv buf [2, G, 2, ps, Hk·D] VMEM (+ two [2, G, ps, Hk]
-    #         scale bufs when quantized), one DMA semaphore a slot for
-    #         each (every page of a block signals its slot's), and the
-    #         schedule's state [2] int32 SMEM, which outlives a program
+    #         scale bufs when quantized), DMA semaphores [2, G // Gt] for
+    #         each (every page of a tile signals its slot's and tile's),
+    #         and the schedule's state [2] int32 SMEM, which outlives a
+    #         program
     *refs,
     scale: float,
     logit_softcap: Optional[float],
     page_size: int,
     groups: int,       # Hq // Hk
     pages_per_block: int,   # G — pages per buffer slot (DMAs in flight)
+    pages_per_tile: int,    # Gt — pages a row tile awaits and computes;
+                            #   divides G
     quantized: bool = False,
+    state: bool = False,
 ):
     def kv_halves(page):      # a page's K and V: two adjacent entries
         return kv_pages_ref.at[pl.ds(2 * page, 2)]
 
     # A stream: (a page id → that page in HBM, its buffer, its semaphores).
+    n_out = 3 if state else 1
     if quantized:
-        (q_ref, kv_pages_ref, ks_pages_ref, vs_pages_ref,
-         acc_ref, m_ref, l_ref,
-         kv_buf, ks_buf, vs_buf,
-         kv_sems, ks_sems, vs_sems, state_ref) = refs
+        q_ref, kv_pages_ref, ks_pages_ref, vs_pages_ref = refs[:4]
+        out_refs = refs[4:4 + n_out]
+        (kv_buf, ks_buf, vs_buf,
+         kv_sems, ks_sems, vs_sems, state_ref) = refs[4 + n_out:]
         streams = ((kv_halves, kv_buf, kv_sems),
                    (lambda page: ks_pages_ref.at[page], ks_buf, ks_sems),
                    (lambda page: vs_pages_ref.at[page], vs_buf, vs_sems))
     else:
-        (q_ref, kv_pages_ref, acc_ref, m_ref, l_ref,
-         kv_buf, kv_sems, state_ref) = refs
+        q_ref, kv_pages_ref = refs[:2]
+        out_refs = refs[2:2 + n_out]
+        kv_buf, kv_sems, state_ref = refs[2 + n_out:]
         streams = ((kv_halves, kv_buf, kv_sems),)
         ks_buf = vs_buf = None
-    b = pl.program_id(0)
-    B = pl.num_programs(0)
-    q_pos = pos_ref[b]
+    S, Hq, D = q_ref.shape
+    first = pl.program_id(0) * S          # the program's first sequence
+    B = pl.num_programs(0) * S
     window = win_ref[0]
     G = pages_per_block
+    Gt = pages_per_tile
+    T = Gt * page_size                    # a row tile's positions
 
     def page_span(seq):
         # Pages [lo, hi) hold positions visible to seq's query, intersected
         # with this shard's page sub-range (context-parallel decode: each
         # sp shard covers a contiguous page range; [0, P) when unsharded).
         pos = pos_ref[seq]
-        hi = jnp.minimum(jax.lax.div(pos, page_size) + 1, rng_ref[1])
+        hi = jnp.minimum(_div(pos, page_size) + 1, rng_ref[1])
         lo = jnp.where(
             window > 0,
-            jnp.maximum(jax.lax.div(pos - window + 1, page_size), 0),
+            jnp.maximum(_div(pos - window + 1, page_size), 0),
             0,
         )
         return jnp.maximum(lo, rng_ref[0]), hi
@@ -135,196 +163,238 @@ def _kernel(
     def block_pages(blk, lo, hi):
         # The pages of G-page block `blk` inside [lo, hi): the only ones
         # fetched (none when the span is empty) — the rest of the slot
-        # holds stale rows, masked below.
+        # holds stale rows, never computed or masked below.
         return jnp.maximum(lo, blk * G), jnp.minimum(hi, (blk + 1) * G)
 
     def start_block(seq, blk, slot, lo, hi):
         # All page DMAs of the block go out together (latency overlaps):
         # one a page and stream, K and V of the page in it.
         def go(p, _):
+            at = p - blk * G
             for page_at, buf, sems in streams:
                 pltpu.make_async_copy(
-                    page_at(pt_ref[seq, p]), buf.at[slot, p - blk * G],
-                    sems.at[slot],
+                    page_at(pt_ref[seq, p]), buf.at[slot, at],
+                    sems.at[slot, _div(at, Gt)],
                 ).start()
             return _
 
         jax.lax.fori_loop(*block_pages(blk, lo, hi), go, None)
 
-    def wait_block(blk, slot, lo, hi):
-        # The n pages started are awaited by runs: a wait looks only at
-        # its slot's semaphore and a byte count, so the slot's first `run`
-        # pages stand for source and destination alike; one wait for each
-        # set bit of n adds up to the n pages' bytes.
-        first, end = block_pages(blk, lo, hi)
-        n = jnp.maximum(end - first, 0)
-        for run in _wait_runs(G):
+    def wait_tile(slot, t, first_at, end_at):
+        # The n pages started of tile `t` (of the slot's pages [first_at,
+        # end_at) that were fetched) are awaited by runs: a wait looks
+        # only at the tile's semaphore and a byte count, so the slot's
+        # first `run` pages stand for source and destination alike; one
+        # wait for each set bit of n adds up to the n pages' bytes.
+        n = (jnp.minimum(end_at, (t + 1) * Gt)
+             - jnp.maximum(first_at, t * Gt))
+        for run in _wait_runs(Gt):
             @pl.when((n & run) != 0)
             def _():
                 for _, buf, sems in streams:
                     landed = buf.at[slot, pl.ds(0, run)]
                     pltpu.make_async_copy(
-                        landed, landed, sems.at[slot]).wait()
-
-    # The schedule (module docstring). Blocks [blo, blo + n_blocks) are the
-    # G-page groups overlapping this sequence's pages; they alternate
-    # between the two buffer slots, and the alternation runs on across
-    # sequences: state_ref = [slot of the block in flight, sequence it is
-    # for]. Only the first live sequence of a call starts (and waits on) a
-    # cold fetch; one with no visible page starts and waits on nothing.
-    lo, hi = page_span(b)
-    live = lo < hi
-    blo = jax.lax.div(lo, G)
-    n_blocks = jnp.where(live, jax.lax.div(hi + G - 1, G) - blo, 0)
-
-    @pl.when(b == 0)
-    def _reset():
-        state_ref[0] = 0
-        state_ref[1] = -1
-
-    slot0 = state_ref[0]
-
-    @pl.when(live & (state_ref[1] != b))
-    def _cold():
-        start_block(b, blo, slot0, lo, hi)
+                        landed, landed, sems.at[slot, t]).wait()
 
     def has_no_page(seq):
         slo, shi = page_span(jnp.minimum(seq, B - 1))
         return (seq < B) & (slo >= shi)
 
-    # Only a live sequence hands over, so only it looks for its successor.
-    nxt = jax.lax.while_loop(
-        has_no_page, lambda seq: seq + 1, jnp.where(live, b + 1, B)
-    )
-    nxt_seq = jnp.minimum(nxt, B - 1)
-    nxt_lo, nxt_hi = page_span(nxt_seq)
-    nxt_hi = jnp.where(nxt < B, nxt_hi, nxt_lo)    # no next: an empty span
+    def tile_rows(buf, slot, page0, half=None):
+        # A row tile of a slot: Gt pages from `page0`, one [T, ·] block
+        # (the pages cover contiguous positions). A block that is one tile
+        # is read whole, with no dynamic slice.
+        pages = slice(None) if Gt == G else pl.ds(page0, Gt)
+        rows = buf[slot, pages] if half is None else buf[slot, pages, half]
+        return rows.reshape(T, -1)
 
-    @pl.when(live)
-    def _hand_over():
-        state_ref[0] = (slot0 + n_blocks) % 2
-        state_ref[1] = nxt
+    def sequence(j, carry):
+        # The schedule (module docstring). Blocks [blo, blo + n_blocks) are
+        # the G-page groups overlapping this sequence's pages; they
+        # alternate between the two buffer slots, and the alternation runs
+        # on across sequences: the carry = (slot of the block in flight,
+        # sequence it is for). Only the first live sequence of a call
+        # starts (and waits on) a cold fetch; one with no visible page
+        # starts and waits on nothing.
+        slot0, in_flight = carry
+        b = first + j
+        q_pos = pos_ref[b]
+        lo, hi = page_span(b)
+        live = lo < hi
+        blo = _div(lo, G)
+        n_blocks = jnp.where(live, _div(hi + G - 1, G) - blo, 0)
 
-    Hq, D = q_ref.shape[1], q_ref.shape[2]
-    W = G * page_size                               # group window width
-    q = q_ref[0].astype(jnp.float32) * scale                  # [Hq, D]
+        @pl.when(live & (in_flight != b))
+        def _cold():
+            start_block(b, blo, slot0, lo, hi)
 
-    def body(i, carry):
-        m, l, acc = carry
-        blk = blo + i
-        slot = (slot0 + i) % 2
-        # What streams in behind this block: this sequence's next block,
-        # or after the last one the next live sequence's first.
-        last = i + 1 == n_blocks
-        start_block(
-            jnp.where(last, nxt_seq, b),
-            jnp.where(last, jax.lax.div(nxt_lo, G), blk + 1),
-            1 - slot,
-            jnp.where(last, nxt_lo, lo),
-            jnp.where(last, nxt_hi, hi),
+        # Only a live sequence hands over, so only it looks for its
+        # successor.
+        nxt = jax.lax.while_loop(
+            has_no_page, lambda seq: seq + 1, jnp.where(live, b + 1, B)
         )
-        wait_block(blk, slot, lo, hi)
-        # The buffer holds [G, 2, ps, Hk*D] (heads folded into lanes so
-        # the DMA slice stays 128-aligned for any head_dim); the G pages
-        # cover contiguous positions, so each half flattens to one
-        # [W, Hk*D] block with a single iota mask.
-        k = kv_buf[slot, :, 0].reshape(W, -1)
-        v = kv_buf[slot, :, 1].reshape(W, -1)
-        num_kv = k.shape[1] // D
-        if quantized:
-            # Per-(position, head) dequant scales for this group —
-            # applied on the per-head slices below, so the int8
-            # pages stream at half the bf16 bytes and dequant rides
-            # the matmul operand load.
-            ks2 = ks_buf[slot].reshape(W, num_kv).astype(jnp.float32)
-            vs2 = vs_buf[slot].reshape(W, num_kv).astype(jnp.float32)
+        nxt_seq = jnp.minimum(nxt, B - 1)
+        nxt_lo, nxt_hi = page_span(nxt_seq)
+        nxt_hi = jnp.where(nxt < B, nxt_hi, nxt_lo)  # no next: an empty span
 
-        kv_pos1 = blk * W + jax.lax.broadcasted_iota(
-            jnp.int32, (W, 1), dimension=0
-        )                                                 # [W, 1]
-        valid1 = (kv_pos1 >= lo * page_size) & (kv_pos1 < hi * page_size)
-        # Rows of pages that were never DMA'd hold stale VMEM; zero V
-        # there so masked-out weights cannot multiply NaN garbage.
-        v = jnp.where(valid1, v.astype(jnp.float32), 0.0)
-        if quantized:
-            # The V-side matmul SUMS over rows, so stale scale rows
-            # must be zeroed like v itself — 0·NaN from a stale bf16
-            # pattern would poison every output. K-side NaNs stay
-            # confined to their own masked logit column.
-            vs2 = jnp.where(valid1, vs2, 0.0)
+        q = q_ref[j].astype(jnp.float32) * scale              # [Hq, D]
 
-        # Mosaic lowers only plain 2D matmuls — unroll over kv heads
-        # (q head h ↔ kv head h//groups, heads grouped contiguously).
-        def k_head(h):
-            kk = k[:, h * D:(h + 1) * D].astype(jnp.float32)
-            if quantized:
-                kk = kk * ks2[:, h:h + 1]
-            return kk
+        def block(i, carry):
+            blk = blo + i
+            slot = (slot0 + i) % 2
+            # What streams in behind this block: this sequence's next
+            # block, or after the last one the next live sequence's first.
+            last = i + 1 == n_blocks
+            start_block(
+                jnp.where(last, nxt_seq, b),
+                jnp.where(last, _div(nxt_lo, G), blk + 1),
+                1 - slot,
+                jnp.where(last, nxt_lo, lo),
+                jnp.where(last, nxt_hi, hi),
+            )
 
-        s = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    q[h * groups:(h + 1) * groups],       # [g, D]
-                    k_head(h),
-                    dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
+            first_at, end_at = (
+                page - blk * G for page in block_pages(blk, lo, hi))
+
+            def tile(t, carry):
+                # The buffer holds [G, 2, ps, Hk*D] (heads folded into
+                # lanes so the DMA slice stays 128-aligned for any
+                # head_dim); a tile's Gt pages flatten to one [T, Hk*D]
+                # block with a single iota mask.
+                m, l, acc = carry
+                wait_tile(slot, t, first_at, end_at)
+                page0 = t * Gt
+                k = tile_rows(kv_buf, slot, page0, 0)
+                v = tile_rows(kv_buf, slot, page0, 1)
+                num_kv = k.shape[1] // D
+                if quantized:
+                    # Per-(position, head) dequant scales for this tile —
+                    # applied on the per-head slices below, so the int8
+                    # pages stream at half the bf16 bytes and dequant
+                    # rides the matmul operand load.
+                    ks2 = tile_rows(ks_buf, slot, page0).astype(jnp.float32)
+                    vs2 = tile_rows(vs_buf, slot, page0).astype(jnp.float32)
+
+                pos0 = (blk * G + page0) * page_size
+                kv_pos1 = pos0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (T, 1), dimension=0
+                )                                             # [T, 1]
+                valid1 = ((kv_pos1 >= lo * page_size)
+                          & (kv_pos1 < hi * page_size))
+                # Rows of pages that were never DMA'd hold stale VMEM (the
+                # tile that straddles an end of the span); zero V there so
+                # masked-out weights cannot multiply NaN garbage.
+                v = jnp.where(valid1, v.astype(jnp.float32), 0.0)
+                if quantized:
+                    # The V-side matmul SUMS over rows, so stale scale
+                    # rows must be zeroed like v itself — 0·NaN from a
+                    # stale bf16 pattern would poison every output.
+                    # K-side NaNs stay confined to their own masked logit
+                    # column.
+                    vs2 = jnp.where(valid1, vs2, 0.0)
+
+                # Mosaic lowers only plain 2D matmuls — unroll over kv
+                # heads (q head h ↔ kv head h//groups, heads grouped
+                # contiguously).
+                def k_head(h):
+                    kk = k[:, h * D:(h + 1) * D].astype(jnp.float32)
+                    if quantized:
+                        kk = kk * ks2[:, h:h + 1]
+                    return kk
+
+                s = jnp.concatenate(
+                    [
+                        jax.lax.dot_general(
+                            q[h * groups:(h + 1) * groups],   # [g, D]
+                            k_head(h),
+                            dimension_numbers=(((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )
+                        for h in range(num_kv)
+                    ],
+                    axis=0,
+                )                                             # [Hq, T]
+                if logit_softcap is not None:
+                    s = logit_softcap * jnp.tanh(s / logit_softcap)
+
+                kv_pos = pos0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (Hq, T), dimension=1
                 )
-                for h in range(num_kv)
-            ],
-            axis=0,
-        )                                                 # [Hq, W]
-        if logit_softcap is not None:
-            s = logit_softcap * jnp.tanh(s / logit_softcap)
+                mask = kv_pos <= q_pos
+                mask &= (window <= 0) | (kv_pos > q_pos - window)
+                mask &= valid1.reshape(1, T)
+                s = jnp.where(mask, s, _NEG_INF)
 
-        kv_pos = blk * W + jax.lax.broadcasted_iota(
-            jnp.int32, (Hq, W), dimension=1
-        )
-        mask = kv_pos <= q_pos
-        mask &= (window <= 0) | (kv_pos > q_pos - window)
-        mask &= valid1.reshape(1, W)
-        s = jnp.where(mask, s, _NEG_INF)
+                m_cur = jnp.max(s, axis=1, keepdims=True)     # [Hq, 1]
+                m_new = jnp.maximum(m, m_cur)
+                pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # [Hq, T]
+                corr = jnp.exp(m - m_new)
+                l_new = corr * l + jnp.sum(pexp, axis=1, keepdims=True)
 
-        m_cur = jnp.max(s, axis=1, keepdims=True)         # [Hq, 1]
-        m_new = jnp.maximum(m, m_cur)
-        pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)   # [Hq, W]
-        corr = jnp.exp(m - m_new)
-        l_new = corr * l + jnp.sum(pexp, axis=1, keepdims=True)
+                def v_head(h):
+                    vv = v[:, h * D:(h + 1) * D]
+                    if quantized:
+                        vv = vv * vs2[:, h:h + 1]
+                    return vv
 
-        def v_head(h):
-            vv = v[:, h * D:(h + 1) * D]
-            if quantized:
-                vv = vv * vs2[:, h:h + 1]
-            return vv
+                pv = jnp.concatenate(
+                    [
+                        jax.lax.dot_general(
+                            pexp[h * groups:(h + 1) * groups],    # [g, T]
+                            v_head(h),
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )
+                        for h in range(num_kv)
+                    ],
+                    axis=0,
+                )                                             # [Hq, D]
+                return m_new, l_new, acc * corr + pv
 
-        pv = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    pexp[h * groups:(h + 1) * groups],    # [g, W]
-                    v_head(h),
-                    dimension_numbers=(((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for h in range(num_kv)
-            ],
-            axis=0,
-        )                                                 # [Hq, D]
-        return m_new, l_new, acc * corr + pv
+            # Only the tiles that hold a fetched page are computed: a lane
+            # with one page does one tile, a full block all G // Gt, and
+            # stale VMEM outside them is never read.
+            return jax.lax.fori_loop(
+                _div(first_at, Gt), _div(end_at + Gt - 1, Gt), tile, carry)
 
-    m0 = jnp.full((Hq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Hq, 1), jnp.float32)
-    acc0 = jnp.zeros((Hq, D), jnp.float32)
-    # Only the live blocks are walked: ~4 turns at ~450-token contexts, not
-    # one turn (and a branch) for each of the table's P // G groups.
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
+        m0 = jnp.full((Hq, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((Hq, 1), jnp.float32)
+        acc0 = jnp.zeros((Hq, D), jnp.float32)
+        # Only the live blocks are walked: ~4 turns at ~450-token contexts,
+        # not one turn (and a branch) for each of the table's P // G groups.
+        m, l, acc = jax.lax.fori_loop(0, n_blocks, block, (m0, l0, acc0))
 
-    acc_ref[0] = acc
-    minor = m_ref.shape[2]
-    m_ref[0] = jnp.broadcast_to(m, (Hq, minor))
-    l_ref[0] = jnp.broadcast_to(l, (Hq, minor))
+        if state:
+            acc_ref, m_ref, l_ref = out_refs
+            acc_ref[j] = acc
+            m_ref[j] = m
+            l_ref[j] = l
+        else:
+            (o_ref,) = out_refs
+            o_ref[j] = (acc / jnp.maximum(l, 1e-9)).astype(o_ref.dtype)
+        return (jnp.where(live, (slot0 + n_blocks) % 2, slot0),
+                jnp.where(live, nxt, in_flight))
+
+    @pl.when(pl.program_id(0) == 0)
+    def _reset():
+        state_ref[0] = 0
+        state_ref[1] = -1
+
+    # The hand-over is the loop's carry inside a program and lives in SMEM
+    # only from one program to the next.
+    slot, in_flight = jax.lax.fori_loop(
+        0, S, sequence, (state_ref[0], state_ref[1]))
+    state_ref[0] = slot
+    state_ref[1] = in_flight
 
 
-_STAT_MINOR = 128   # lane width for the m/l stat outputs (tile-aligned)
-_BLOCK_BYTES = 512 * 1024   # K's bytes (and V's as many) in flight a slot
+_BLOCK_BYTES = 1024 * 1024  # K's bytes (and V's as many) in flight a slot
+_TILE_BYTES = 256 * 1024    # K's bytes a row tile computes at once
+# What a program's q and output blocks may take of the scoped VMEM (16 MiB
+# on a v5e), the pipeline's second buffer of each counted: beside them stand
+# the two K/V slots (4 × _BLOCK_BYTES = 4 MiB) and a tile's float32 copies
+# of K and V (2 × 2 × _TILE_BYTES = 1 MiB from bf16 pools).
+_LANE_BLOCK_BYTES = 4 * 1024 * 1024
 
 
 def _block_pages(pages_per_block: int, row_bytes: int, page_size: int,
@@ -333,14 +403,38 @@ def _block_pages(pages_per_block: int, row_bytes: int, page_size: int,
     else from the bytes a block moves; never more than the table has."""
     if pages_per_block <= 0:
         # A block keeps _BLOCK_BYTES of K and as many of V in flight,
-        # whatever the folded width it is handed (a tp shard's 256 lanes
-        # take more positions than a chip's 1024), between 128 positions
-        # (one MXU tile of rows) and 512 (a block is computed whole: past
-        # the contexts served, wider is masked work). Two slots of it, and
-        # the f32 copies the matmuls take, stay inside the scoped VMEM.
+        # whatever the folded width it is handed, between 128 positions
+        # (one MXU tile of rows) and 512 (measured on a v5e, PERF.md §5:
+        # 512 on a chip's 1024 lanes and on a tp shard's 256; past it a
+        # cold fetch grows and nothing else moves). Two slots of it, and
+        # the f32 copies a row tile's matmuls take, stay inside the scoped
+        # VMEM.
         rows = _BLOCK_BYTES // row_bytes
         pages_per_block = min(max(rows, 128), 512) // page_size
     return max(1, min(pages_per_block, table_pages))
+
+
+def _tile_pages(pages_per_block: int, row_bytes: int, page_size: int) -> int:
+    """Gt, the pages a row tile waits for and computes at once:
+    _TILE_BYTES of K, whatever the folded width — a tile's arithmetic is
+    one dependent chain (products, maximum, exponential, products), so a
+    narrow row takes more positions to be worth one: 128 positions on 1024
+    lanes of bf16, 256 on 512, the whole block of 512 on a tp shard's 256
+    (measured, PERF.md §5) — where that divides the block evenly, else the
+    whole block."""
+    pages = max(_TILE_BYTES // row_bytes, 128) // page_size
+    if pages < 1 or pages_per_block % pages:
+        return pages_per_block
+    return pages
+
+
+def _program_lanes(lanes: int, lane_bytes: int) -> int:
+    """S, the sequences a program walks: all of them when their q and
+    output blocks (`lane_bytes` a sequence, twice for the pipeline's second
+    buffer) fit _LANE_BLOCK_BYTES, else the largest divisor of `lanes`
+    that does."""
+    fit = max(1, _LANE_BLOCK_BYTES // (2 * lane_bytes))
+    return max(s for s in range(1, lanes + 1) if lanes % s == 0 and s <= fit)
 
 
 def _wait_runs(pages_per_block: int) -> tuple:
@@ -354,7 +448,8 @@ def _wait_runs(pages_per_block: int) -> tuple:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scale", "logit_softcap", "interpret", "pages_per_block"),
+    static_argnames=(
+        "scale", "logit_softcap", "interpret", "pages_per_block", "state"),
 )
 def _decode_call(
     q: jax.Array,             # [B, Hq, D]
@@ -369,12 +464,14 @@ def _decode_call(
     scale: float,
     logit_softcap: Optional[float],
     interpret: bool,
+    state: bool,
     pages_per_block: int = 0,   # 0 → auto
 ):
-    """Returns UNNORMALIZED online-softmax state (acc [B,Hq,D] f32,
-    m [B,Hq,1], l [B,Hq,1]) over the pages in `page_range` — the caller
-    normalizes, or first merges partial states across context-parallel
-    shards (acc/l scale by exp(m - m_global)).
+    """Attention over the pages in `page_range`: [B, Hq, D] in q's dtype,
+    normalised inside the kernel — or, with `state`, the UNNORMALIZED
+    online-softmax state (acc [B,Hq,D] f32, m [B,Hq,1], l [B,Hq,1]) for a
+    caller that first merges partial states across context-parallel shards
+    (acc/l scale by exp(m - m_global)).
 
     The pool is taken as it is stored (engine/kv_cache.py: K and V of a
     page side by side — entries 2p and 2p + 1 here — heads folded into
@@ -386,9 +483,17 @@ def _decode_call(
     B, Hq, D = q.shape
     _, ps, folded = kv_pages.shape
     Hk = folded // D
-    G = _block_pages(
-        pages_per_block, folded * kv_pages.dtype.itemsize, ps,
-        page_tables.shape[1])
+    row_bytes = folded * kv_pages.dtype.itemsize
+    G = _block_pages(pages_per_block, row_bytes, ps, page_tables.shape[1])
+    Gt = _tile_pages(G, row_bytes, ps)
+    if state:
+        out_shapes = [((Hq, D), jnp.float32), ((Hq, 1), jnp.float32),
+                      ((Hq, 1), jnp.float32)]
+    else:
+        out_shapes = [((Hq, D), q.dtype)]
+    S = _program_lanes(B, Hq * D * q.dtype.itemsize + sum(
+        math.prod(shape) * jnp.dtype(dtype).itemsize
+        for shape, dtype in out_shapes))
 
     kernel = functools.partial(
         _kernel,
@@ -397,11 +502,16 @@ def _decode_call(
         page_size=ps,
         groups=Hq // Hk,
         pages_per_block=G,
+        pages_per_tile=Gt,
         quantized=quantized,
+        state=state,
     )
-    stat_spec = pl.BlockSpec((1, Hq, _STAT_MINOR), lambda b, *_: (b, 0, 0))
+
+    def lanes_spec(shape):
+        return pl.BlockSpec((S, *shape), lambda b, *_: (b, 0, 0))
+
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)), any_spec]
+    in_specs = [lanes_spec((Hq, D)), any_spec]
     scratch = [pltpu.VMEM((2, G, 2, ps, folded), kv_pages.dtype)]
     operands = [q, kv_pages]
     if quantized:
@@ -411,26 +521,21 @@ def _decode_call(
             pltpu.VMEM((2, G, ps, Hk), vs_pages.dtype),
         ]
         operands += [ks_pages, vs_pages]
-    scratch += [pltpu.SemaphoreType.DMA((2,))] * (len(operands) - 1)
+    scratch += [pltpu.SemaphoreType.DMA((2, G // Gt))] * (len(operands) - 1)
     scratch += [pltpu.SMEM((2,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B,),
+        grid=(B // S,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, *_: (b, 0, 0)),
-            stat_spec,
-            stat_spec,
-        ],
+        out_specs=[lanes_spec(shape) for shape, _ in out_shapes],
         scratch_shapes=scratch,
     )
-    acc, m, l = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, _STAT_MINOR), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hq, _STAT_MINOR), jnp.float32),
+            jax.ShapeDtypeStruct((B, *shape), dtype)
+            for shape, dtype in out_shapes
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -444,7 +549,7 @@ def _decode_call(
         page_range.astype(jnp.int32),
         *operands,
     )
-    return acc, m[..., :1], l[..., :1]
+    return tuple(out) if state else out[0]
 
 
 # What Mosaic says to every int8-KV stage (decode read, write)
@@ -549,9 +654,6 @@ def paged_attention_decode(
     )
     P_tables = page_tables.shape[1]
 
-    def _normalize(acc, l, dtype):
-        return (acc / jnp.maximum(l, 1e-9)).astype(dtype)
-
     dp = mesh.shape.get("dp", 1) if mesh is not None else 1
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
@@ -588,21 +690,20 @@ def paged_attention_decode(
             # bandwidth bound — sp-fold), then partial online-softmax
             # states merge with a max/psum pair. sp=1 degenerates to the
             # full range and no collectives.
-            if sp > 1:
-                s = jax.lax.axis_index("sp")
-                chunk = -(-P_tables // sp)
-                rlo = (s * chunk).astype(jnp.int32)
-                rhi = jnp.minimum(P_tables, rlo + chunk).astype(jnp.int32)
-                rng = jnp.stack([rlo, rhi])
-            else:
-                rng = jnp.array([0, P_tables], jnp.int32)
-            acc, m, l = inner(q2, kv2, pt2, pos2, win2, rng)
-            if sp > 1:
-                m_g = jax.lax.pmax(m, "sp")
-                corr = jnp.exp(m - m_g)
-                l = jax.lax.psum(l * corr, "sp")
-                acc = jax.lax.psum(acc * corr, "sp")
-            return _normalize(acc, l, q2.dtype)
+            if sp == 1:
+                return inner(q2, kv2, pt2, pos2, win2,
+                             jnp.array([0, P_tables], jnp.int32), state=False)
+            s = jax.lax.axis_index("sp")
+            chunk = -(-P_tables // sp)
+            rlo = (s * chunk).astype(jnp.int32)
+            rhi = jnp.minimum(P_tables, rlo + chunk).astype(jnp.int32)
+            acc, m, l = inner(
+                q2, kv2, pt2, pos2, win2, jnp.stack([rlo, rhi]), state=True)
+            m_g = jax.lax.pmax(m, "sp")
+            corr = jnp.exp(m - m_g)
+            l = jax.lax.psum(l * corr, "sp")
+            acc = jax.lax.psum(acc * corr, "sp")
+            return (acc / jnp.maximum(l, 1e-9)).astype(q2.dtype)
 
         # The int8 form is a (values, k scales, v scales) triple: its spec
         # is a pytree matching that structure. Data [2N, ps, Hk·D] and
@@ -628,10 +729,9 @@ def paged_attention_decode(
             q_positions[:, 0].astype(jnp.int32), win,
         )
     else:
-        acc, _, l = inner(
+        out = inner(
             q[:, 0], kv_pages, page_tables,
             q_positions[:, 0].astype(jnp.int32), win,
-            jnp.array([0, P_tables], jnp.int32),
+            jnp.array([0, P_tables], jnp.int32), state=False,
         )
-        out = _normalize(acc, l, q.dtype)
     return out[:, None]

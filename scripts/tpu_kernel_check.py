@@ -15,7 +15,8 @@ chiprun_out/kernel_check.txt). Exits non-zero on any failure and when
 there is no TPU. It times two things: the one-off probe that
 jax.block_until_ready really blocks, and the paged decode kernel alone
 (`decode-time` rows: µs a call beside its K/V bytes ÷ the chip's HBM
-bandwidth at the two shapes the benchmark's cells run, and the DMA
+bandwidth at the two shapes the benchmark's cells run, 16 and 64
+sequences a call, the rest of it by sequence, and the DMA
 descriptors the call starts and awaits, so that a call's cost can be
 split into bytes ÷ bandwidth + the rest without a server; in no cell —
 what the users pay is the benchmark's to say). `--timing` also times the
@@ -272,7 +273,11 @@ def check_int4() -> None:
 # tables; one chip of mistral-7b holds all 8 KV heads (1024 folded lanes), a
 # tp = 4 shard of mixtral-8x7b 2 of them (256).
 TIMED_SHAPES = [("1024-lanes", 32, 8, 128), ("256-lanes", 8, 2, 128)]
-TIMED_CONTEXTS = (128, 512, 2048)
+TIMED_CONTEXTS = (PS, 128, 512, 2048)   # PS: one page a lane
+TIMED_SEQUENCES = (16, 64)
+# --sweep: the hybrid cells' page halves of 512 lanes, in 64-wide heads
+# (lfm2) and in 256-wide ones (qwen3-next), 64 sequences a call.
+SWEPT_SHAPES = [("512-lanes-d64", 32, 8, 64), ("512-lanes-d256", 16, 2, 256)]
 TIMED_CALLS = 128                       # kernel calls inside one jitted scan
 
 
@@ -289,7 +294,7 @@ def time_decode(B, Hq, Hk, D, P, contexts, pages_per_block=0) -> str:
     """µs a call of the decode kernel on bf16 pools, every lane's pages
     scattered over a pool no cache could hold, beside the least time its
     K/V bytes allow and the DMA descriptors it starts and awaits (counted
-    from the kernel's own block width and wait runs, not measured).
+    from the kernel's own block and tile widths and wait runs, not measured).
     `contexts`: one length for every lane, or one a lane.
     The calls run back to back inside one jitted scan (a new q each, so
     nothing is hoisted) and the scan's own turn, measured empty, is taken
@@ -336,16 +341,17 @@ def time_decode(B, Hq, Hk, D, P, contexts, pages_per_block=0) -> str:
     empty = scan_of(lambda q, kvp: q)
     us = (seconds(kernel) - seconds(empty)) / calls * 1e6
     kv_bytes = 2 * int(contexts.sum()) * Hk * D * 2
-    # One start a page (K and V under it); a block's n pages awaited as one
-    # wait for each set bit of n (the kernel's own G and `_wait_runs`).
+    # One start a page (K and V under it); a row tile's n pages awaited as
+    # one wait for each set bit of n (the kernel's own G, Gt, `_wait_runs`).
     G = pak._block_pages(pages_per_block, Hk * D * 2, PS, P)
+    Gt = pak._tile_pages(G, Hk * D * 2, PS)
     starts = waits = 0
     for ctx in contexts:
         pages = (int(ctx) + PS - 1) // PS
         starts += pages
         waits += sum(
-            sum(1 for run in pak._wait_runs(G) if n & run)
-            for n in [G] * (pages // G) + [pages % G])
+            sum(1 for run in pak._wait_runs(Gt) if n & run)
+            for n in [Gt] * (pages // Gt) + [pages % Gt])
     counted = f"{starts} starts + {waits} waits"
     if interpret:
         return (f"ran (interpret mode on the host: no device time); "
@@ -354,23 +360,30 @@ def time_decode(B, Hq, Hk, D, P, contexts, pages_per_block=0) -> str:
     least = kv_bytes / peak * 1e6
     return (f"{us:.1f} us/call; K/V {kv_bytes / 1e6:.2f} MB = {least:.1f} us "
             f"at {peak / 1e9:.0f} GB/s ({100 * least / us:.1f} %), "
-            f"rest {us - least:.1f} us; {counted}")
+            f"rest {us - least:.1f} us = {(us - least) / B:.2f} us a sequence; "
+            f"{counted}")
 
 
 def check_decode_timing(sweep: bool) -> None:
     for label, Hq, Hk, D in TIMED_SHAPES:
         timed = partial(time_decode, 16, Hq, Hk, D)
-        for ctx in TIMED_CONTEXTS:
-            ctx = min(ctx, TABLE * PS)
-            case("decode-time", f"{label} B=16 ctx={ctx}",
-                 partial(timed, TABLE, ctx))
-        # The cells' own mix: every lane another length, 68 to 860 tokens.
-        mixed = np.minimum(np.linspace(68, 860, 16), TABLE * PS).astype(int)
-        case("decode-time", f"{label} B=16 ctx=68..860",
-             partial(timed, TABLE, mixed))
+        # 16 sequences as the dense cells send them, 64 as the hybrid ones;
+        # one page a lane is the call that moves almost nothing: what it
+        # costs is what a sequence costs whatever it moves.
+        for B in TIMED_SEQUENCES:
+            for ctx in TIMED_CONTEXTS:
+                ctx = min(ctx, TABLE * PS)
+                case("decode-time", f"{label} B={B} ctx={ctx}",
+                     partial(time_decode, B, Hq, Hk, D, TABLE, ctx))
+            # The cells' own mix: every lane another length, 68 to 860.
+            mixed = np.minimum(
+                np.linspace(68, 860, B), TABLE * PS).astype(int)
+            case("decode-time", f"{label} B={B} ctx=68..860",
+                 partial(time_decode, B, Hq, Hk, D, TABLE, mixed))
         if not sweep:
             continue
         ctx = min(448, TABLE * PS)
+        mixed = np.minimum(np.linspace(68, 860, 16), TABLE * PS).astype(int)
         for B in (1, 4, 8, 32):
             case("decode-time", f"{label} B={B} ctx={ctx}",
                  partial(time_decode, B, Hq, Hk, D, TABLE, ctx))
@@ -382,9 +395,14 @@ def check_decode_timing(sweep: bool) -> None:
                  partial(timed, TABLE, ctx, ppb))
             case("decode-time", f"{label} B=16 ctx=68..860 pages/block={ppb}",
                  partial(timed, TABLE, mixed, ppb))
-        for ctx in (1, 16, 17):
+        for ctx in (1, 17):
             case("decode-time", f"{label} B=16 ctx={ctx}",
                  partial(timed, TABLE, ctx))
+    for label, Hq, Hk, D in SWEPT_SHAPES if sweep else ():
+        mixed = np.minimum(np.linspace(68, 860, 64), TABLE * PS).astype(int)
+        for name, ctx in ((PS, PS), ("68..860", mixed)):
+            case("decode-time", f"{label} B=64 ctx={name}",
+                 partial(time_decode, 64, Hq, Hk, D, TABLE, ctx))
 
 
 # (label, hidden the router reads, width the experts read, experts' width,

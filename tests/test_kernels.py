@@ -183,7 +183,7 @@ def test_paged_decode_kernel_schedule(case):
             q[:, 0], kvp, pt, pos[:, 0],
             jnp.asarray([0 if win is None else win], jnp.int32),
             jnp.asarray([rlo, rhi], jnp.int32),
-            scale=0.09, logit_softcap=None, interpret=True,
+            scale=0.09, logit_softcap=None, interpret=True, state=True,
             pages_per_block=ppb,
         )
 
@@ -202,6 +202,168 @@ def test_paged_decode_kernel_schedule(case):
     out = (acc / jnp.maximum(den, 1e-9))[:, None]
     assert bool(jnp.isfinite(out).all())
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
+
+
+# Sequences walked inside a program, a block computed by row tiles, one
+# normalised output. Pages are 16 positions, tables 64 pages, two KV heads
+# of 128 (256 folded lanes): f32 pools there take 32-page blocks (512
+# positions) of two 256-position row tiles. Positions (context − 1) at a
+# page's, a row tile's and a block's edges (127 / 128 too: the tile of a
+# chip's 1024 lanes), then the cells' mix.
+_EDGES = [0, 15, 16, 127, 128, 254, 255, 256, 510, 511, 512, 640]
+_P, _PS, _D = 64, 16, 128
+
+
+def _lane_positions(B):
+    mix = np.linspace(67, 859, max(B - len(_EDGES), 2)).astype(int)
+    return [int(p) for p in (_EDGES + list(mix))[:B]]
+
+
+def _programs_of(monkeypatch, lanes):
+    """Hold the q and output blocks of a program to `lanes` sequences of the
+    cases' shape (a fresh jit of the call: the cached trace of an unpatched
+    test must not serve)."""
+    from polykey_tpu.ops import paged_attention_kernel as pak
+
+    state_bytes = 8 * _D * 4 * 2 + 2 * 8 * 4        # q + acc, m, l: f32
+    monkeypatch.setattr(pak, "_LANE_BLOCK_BYTES", 2 * lanes * state_bytes)
+    monkeypatch.setattr(pak, "_decode_call", jax.jit(
+        pak._decode_call.__wrapped__,
+        static_argnames=("scale", "logit_softcap", "interpret",
+                         "pages_per_block", "state")))
+
+
+def _int8_pool(kvp, D):
+    from polykey_tpu.engine.kv_cache import fold_heads, unfold_heads
+    from polykey_tpu.ops.paged_attention import quantize_kv_rows
+
+    k8, ks = quantize_kv_rows(unfold_heads(kvp[0::2], D))
+    v8, vs = quantize_kv_rows(unfold_heads(kvp[1::2], D))
+    return (jnp.stack([fold_heads(k8), fold_heads(v8)], axis=1).reshape(
+        kvp.shape), ks, vs)
+
+
+# (sequences, sequences a program may hold — None: what the bytes allow).
+_LANES = {"1": (1, None), "3": (3, None), "16": (16, None), "64": (64, None),
+          "6-by-3": (6, 4), "16-by-4": (16, 4)}
+# (window, soft-cap, split, int8): `split` = r runs the page sub-ranges
+# [0, r) and [r, P) for the unnormalised state and merges them as the sp
+# axis does — past r a short lane has no visible page between live ones.
+_FORMS = {"plain": (None, None, None, False),
+          "window": (100, None, None, False),
+          "softcap-window": (200, 30.0, None, False),
+          "split": (None, None, 8, False),
+          "int8": (None, None, None, True)}
+
+
+@pytest.mark.parametrize("form", _FORMS)
+@pytest.mark.parametrize("lanes", _LANES)
+def test_paged_decode_kernel_lanes_tiles_and_output(lanes, form, monkeypatch):
+    from polykey_tpu.ops import paged_attention_kernel as pak
+
+    B, held = _LANES[lanes]
+    win, softcap, split, int8 = _FORMS[form]
+    if held is not None:
+        _programs_of(monkeypatch, held)
+    q, kvp, pt, pos = _paged_case(
+        B, 8, 2, _D, _PS, _P, [[p] for p in _lane_positions(B)])
+    if int8:
+        kvp = _int8_pool(kvp, _D)
+    w = None if win is None else jnp.int32(win)
+    ref = paged_attention(
+        q, kvp, pt, pos, scale=0.125, logit_softcap=softcap, window=w)
+    if split is None:
+        out = paged_attention_decode(
+            q, kvp, pt, pos, scale=0.125, logit_softcap=softcap, window=w,
+            interpret=True)
+        assert out.dtype == q.dtype
+    else:
+        (a1, m1, l1), (a2, m2, l2) = (
+            pak._decode_call(
+                q[:, 0], kvp, pt, pos[:, 0], jnp.zeros((1,), jnp.int32),
+                jnp.asarray(rng, jnp.int32), scale=0.125,
+                logit_softcap=softcap, interpret=True, state=True)
+            for rng in ([0, split], [split, _P]))
+        assert m1.shape == l1.shape == (B, 8, 1) and a1.dtype == jnp.float32
+        m = jnp.maximum(m1, m2)
+        den = l1 * jnp.exp(m1 - m) + l2 * jnp.exp(m2 - m)
+        acc = a1 * jnp.exp(m1 - m) + a2 * jnp.exp(m2 - m)
+        out = (acc / jnp.maximum(den, 1e-9))[:, None]
+    assert bool(jnp.isfinite(out).all())
+    assert float(jnp.max(jnp.abs(ref - out))) < TOL
+
+
+@pytest.mark.parametrize("position", _EDGES)
+def test_paged_decode_kernel_one_sequence_at_every_edge(position):
+    """B = 1 at each edge by itself: the only sequence's last tile is the
+    call's last, with nothing in flight behind it."""
+    q, kvp, pt, pos = _paged_case(1, 8, 2, _D, _PS, _P, [[position]])
+    ref = paged_attention(q, kvp, pt, pos, scale=0.125)
+    out = paged_attention_decode(q, kvp, pt, pos, scale=0.125, interpret=True)
+    assert float(jnp.max(jnp.abs(ref - out))) < TOL
+
+
+def test_paged_decode_kernel_stale_rows_past_the_last_tile_are_never_read():
+    """Under the TPU interpreter uninitialised VMEM is NaN and a page lands
+    only when it is awaited: one page a lane beside full blocks, so every
+    slot holds rows no DMA wrote — a tile computed past the last fetched
+    page, or a straddling tile's stale V rows left unzeroed, reads NaN."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    q, kvp, pt, pos = _paged_case(
+        6, 8, 2, _D, _PS, _P, [[3], [1000], [0], [257], [15], [640]])
+    ref = paged_attention(q, kvp, pt, pos, scale=0.125)
+    out = paged_attention_decode(
+        q, kvp, pt, pos, scale=0.125,
+        interpret=pltpu.InterpretParams(dma_execution_mode="on_wait"))
+    assert bool(jnp.isfinite(out).all())
+    assert float(jnp.max(jnp.abs(ref - out))) < TOL
+
+
+@pytest.mark.parametrize("B,Hq,D,state,budget,want", [
+    (16, 32, 128, False, None, 16),     # mistral-7b: one program
+    (16, 8, 128, False, None, 16),      # a Mixtral tp = 4 shard
+    (64, 32, 64, False, None, 64),      # lfm2: 64 sequences, 64-wide heads
+    (64, 16, 256, False, None, 64),     # qwen3-next: head width 256
+    (64, 32, 128, True, None, 64),      # the state for a merge: f32 acc, m, l
+    (512, 32, 128, True, None, 64),     # past the budget: a divisor that fits
+    (6, 8, 64, False, 4, 3),            # a B the fitting block does not divide
+    (7, 8, 64, False, 4, 1),            # … and a prime one
+    (1, 8, 64, False, None, 1),
+])
+def test_paged_decode_lanes_a_program_follow_the_bytes(B, Hq, D, state,
+                                                       budget, want,
+                                                       monkeypatch):
+    """The sequences a program walks come from the VMEM arithmetic on the
+    shapes it is handed — read off the q block and the grid it asks for."""
+    from polykey_tpu.ops import paged_attention_kernel as pak
+
+    if budget is not None:
+        monkeypatch.setattr(
+            pak, "_LANE_BLOCK_BYTES", 2 * budget * Hq * D * 2 * 2)
+    ps, P = 16, 256
+    jaxpr = jax.make_jaxpr(
+        lambda *a: pak._decode_call.__wrapped__(
+            *a, scale=1.0, logit_softcap=None, interpret=False, state=state)
+    )(
+        jax.ShapeDtypeStruct((B, Hq, D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((128, ps, Hq // 4 * D), jnp.bfloat16),
+        jax.ShapeDtypeStruct((B, P), jnp.int32),
+        jax.ShapeDtypeStruct((B,), jnp.int32),
+        jax.ShapeDtypeStruct((1,), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+    )
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (B // want,)
+    blocks = [tuple(getattr(d, "block_size", d) for d in b.block_shape)
+              for b in call.params["grid_mapping"].block_mappings]
+    assert (want, Hq, D) in blocks
+    outs = [(tuple(v.aval.shape), str(v.aval.dtype)) for v in call.outvars]
+    if state:
+        assert outs == [((B, Hq, D), "float32"), ((B, Hq, 1), "float32"),
+                        ((B, Hq, 1), "float32")]
+    else:
+        assert outs == [((B, Hq, D), "bfloat16")]
 
 
 @pytest.mark.parametrize("G", [1, 2, 3, 8, 12, 32])
@@ -242,26 +404,31 @@ def test_paged_decode_waits_consume_what_the_starts_signalled(n):
         q[:, 0], kvp, pt, pos[:, 0], jnp.zeros((1,), jnp.int32),
         jnp.asarray([0, P], jnp.int32), scale=0.125, logit_softcap=None,
         interpret=pltpu.InterpretParams(dma_execution_mode="on_wait"),
-        pages_per_block=G,
+        state=True, pages_per_block=G,
     )
     out = (acc / jnp.maximum(den, 1e-9))[:, None]
     assert bool(jnp.isfinite(out).all())
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
 
-@pytest.mark.parametrize("folded,itemsize,ppb,want", [
-    (1024, 2, 0, 16),     # one chip of mistral-7b: 512 KB, 256 positions
-    (512, 2, 0, 32),      # a tp = 2 shard: the same bytes, 512 positions
-    (256, 2, 0, 32),      # a tp = 4 shard: never over 512 positions
-    (1024, 1, 0, 32),     # int8 pools: half the bytes a position
-    (4096, 2, 0, 8),      # never under 128 positions
-    (256, 2, 3, 3),       # given explicitly: honoured
-    (256, 2, 500, 256),   # bounded by the table
+@pytest.mark.parametrize("folded,itemsize,ppb,want,tiles", [
+    (1024, 2, 0, 32, 4),    # one chip of mistral-7b: 1 MB, 512 positions,
+                            #   in row tiles of 256 KB: 128 positions
+    (512, 2, 0, 32, 2),     # 512 lanes: never over 512 positions; tiles of 256
+    (256, 2, 0, 32, 1),     # a tp = 4 shard: the whole block is one tile
+    (1024, 1, 0, 32, 2),    # int8 pools: half the bytes a position
+    (2048, 2, 0, 16, 2),    # 2048 lanes: 256 positions, tiles of 128
+    (8192, 2, 0, 8, 1),     # never under 128 positions
+    (256, 2, 3, 3, 1),      # given explicitly: honoured, one tile
+    (1024, 2, 12, 12, 1),   # … a tile that does not divide it: one tile
+    (256, 2, 500, 256, 8),  # bounded by the table
 ])
 def test_paged_decode_block_width_follows_the_bytes(folded, itemsize, ppb,
-                                                    want):
-    """The block the kernel streams is sized in bytes in flight, from the
-    shape it is handed — read off the scratch buffers it asks for."""
+                                                    want, tiles):
+    """The block the kernel streams is sized in bytes in flight and the row
+    tile it waits for and computes in bytes too, from the shape it is handed
+    — read off the scratch buffers and the semaphores (one a slot and tile)
+    it asks for."""
     from polykey_tpu.ops import paged_attention_kernel as pak
 
     dtype = {2: jnp.bfloat16, 1: jnp.int8}[itemsize]
@@ -274,7 +441,7 @@ def test_paged_decode_block_width_follows_the_bytes(folded, itemsize, ppb,
     jaxpr = jax.make_jaxpr(
         lambda *a: pak._decode_call.__wrapped__(     # the jit's function
             *a, scale=1.0, logit_softcap=None, interpret=False,
-            pages_per_block=ppb)
+            state=False, pages_per_block=ppb)
     )(
         jax.ShapeDtypeStruct((B, Hk, D), jnp.bfloat16), pool,
         jax.ShapeDtypeStruct((B, P), jnp.int32),
@@ -289,6 +456,9 @@ def test_paged_decode_block_width_follows_the_bytes(folded, itemsize, ppb,
         if len(x.aval.shape) == 5
     ]
     assert scratch == (2, want, 2, ps, folded), scratch
+    sems = [x.aval.shape for x in call.params["jaxpr"].invars
+            if "semaphore" in str(x.aval).lower()]
+    assert sems == [(2, tiles)] * (3 if itemsize == 1 else 1), sems
 
 
 def test_paged_decode_kernel_shard_mapped_on_mesh():
@@ -318,6 +488,32 @@ def test_paged_decode_kernel_shard_mapped_on_mesh():
         q_s, kvp_s, pt_s, pos_s, scale=0.125,
         interpret=True, mesh=mesh,
     )
+    assert float(jnp.max(jnp.abs(ref - out))) < TOL
+
+
+def test_paged_decode_kernel_shard_mapped_256_lanes_a_shard():
+    """A Mixtral tp = 4 shard's call: 16 sequences, 8 of 32 query heads and
+    2 of 8 KV heads of 128 a shard (256 folded lanes), every edge and the
+    cells' mix among the lanes — one program a shard, one normalised
+    output, against the unsharded gather."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from polykey_tpu.parallel.mesh import MeshConfig, create_mesh
+
+    if jax.device_count() < 4:
+        pytest.skip("needs 4 devices")
+    mesh = create_mesh(MeshConfig(tp=4), devices=jax.devices()[:4])
+    q, kvp, pt, pos = _paged_case(
+        16, 32, 8, 128, _PS, _P, [[p] for p in _lane_positions(16)])
+    ref = paged_attention(q, kvp, pt, pos, scale=0.09)
+    rep = NamedSharding(mesh, P())
+    out = paged_attention_decode(
+        jax.device_put(q, NamedSharding(mesh, P(None, None, "tp", None))),
+        jax.device_put(kvp, NamedSharding(mesh, P(None, None, "tp"))),
+        jax.device_put(pt, rep), jax.device_put(pos, rep),
+        scale=0.09, interpret=True, mesh=mesh,
+    )
+    assert out.shape == q.shape
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
 

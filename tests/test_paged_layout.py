@@ -663,6 +663,80 @@ def test_decode_step_compiled_for_v5e_projects_qkv_as_plain_matmuls(v5e, case):
     assert "%paged_kv_write" in hlo and "%paged_attention_decode" in hlo
 
 
+# The decode program around the paged decode kernel (ISSUE 50): the kernel
+# walks the step's sequences in one program and hands back ONE normalised
+# block in the activations' dtype, so nothing stands between rope and the
+# call, or between the call and `wo`.
+# heads, KV heads, head width, hidden, leaves, tp, experts, what reads the
+# result. (At head width 256 in bf16 the `wo` product takes its operand in
+# another layout and the compiler relays the result once: that copy stood
+# behind the parent's normalising fusion too and is not the kernel's.)
+_ATTEND_CASES = {
+    "mistral-7b": (32, 8, 128, 4096, "int8", 1, 0, ["fusion"]),
+    "mixtral-tp4-shard": (32, 8, 128, 4096, "int8", 4, 8, ["fusion"]),
+    "head-width-256": (16, 2, 256, 2048, "none", 1, 0, ["copy"]),
+}
+
+
+def _around(hlo: str, call: str):
+    """(opcodes that produce the call's operands, opcodes that consume its
+    result — seen through a bitcast) in the call's own computation."""
+    for instructions in _module(hlo)[0].values():
+        by_name = {name: (op, line) for name, _, op, line in instructions}
+        if call not in by_name:
+            continue
+        operands = re.findall(
+            r"%([\w.\-]+)", by_name[call][1].split("custom-call(")[1]
+            .split(")")[0])
+
+        def users(name):
+            found = []
+            for user, (op, line) in by_name.items():
+                if re.search(rf"\(.*%{re.escape(name)}\b", line):
+                    found += users(user) if op == "bitcast" else [op]
+            return found
+
+        return [by_name[o][0] for o in operands if o in by_name], users(call)
+    raise AssertionError(f"{call} is in no computation")
+
+
+@pytest.mark.parametrize("case", list(_ATTEND_CASES))
+def test_decode_step_compiled_for_v5e_calls_the_decode_kernel_bare(v5e, case):
+    from polykey_tpu.models.config import ModelConfig
+
+    Hq, Hk, D, hidden, leaves, tp, experts, readers = _ATTEND_CASES[case]
+    cfg = ModelConfig(
+        **{**_MISTRAL, "num_heads": Hq, "num_kv_heads": Hk, "head_dim": D,
+           "hidden_size": hidden},
+        num_layers=2, num_experts=experts,
+        num_experts_per_tok=2 if experts else 0, moe_dispatch=bool(experts),
+    )
+    hlo = _CENSUS.compile_step(
+        cfg, list(v5e.devices), quantize=leaves, tp=tp)
+    # One call an attending layer (the layers are one scanned body), with
+    # one result: the 16 lanes' heads in bf16, not three float32 states.
+    calls = [c for c in _kernel_calls(hlo)
+             if c.startswith("%paged_attention_decode")]
+    assert len(calls) == 1, calls
+    name, result = calls[0].lstrip("%").split(" = ")
+    assert result.startswith(f"bf16[16,{Hq // tp},{D}]"), result
+    # Nothing relays q on its way in, the result goes to `wo` as it is, and
+    # no float32 form of it exists for XLA to normalise.
+    producers, consumers = _around(hlo, name)
+    assert not {"copy", "transpose"} & set(producers), producers
+    assert consumers == readers, consumers
+    computations, fused = _module(hlo)
+    assert [name for comp, instructions in computations.items()
+            if comp not in fused for name, result_type, _, _ in instructions
+            if f"f32[16,{Hq // tp},{D}]" in result_type] == []
+    # No pool-shaped operation: 2,048 pages of 16 positions a layer (at two
+    # layers the weight stacks XLA prefetches are as large, and are not
+    # pools: told apart by the page's shape).
+    folded = Hk * D // tp
+    assert [found for found in pool_sized_instructions(
+        hlo, 2048 * 16 * folded * 2) if f",16,{folded}]" in found] == []
+
+
 @pytest.mark.parametrize("expression", ["folded"], indirect=True)
 def test_qkv_census_sees_the_folded_projection(v5e, expression):
     """The census has teeth: the parent's expression, compiled the same way,
