@@ -77,7 +77,11 @@ from ..models.hybrid import (
     FROM_ZERO,
     PrefillRows,
 )
-from ..models.transformer import forward_slots, unembed
+from ..models.transformer import (
+    forward_slots,
+    forward_slots_counted,
+    unembed,
+)
 from ..ops.moe import held_experts_grouped
 from ..parallel.mesh import MeshConfig, create_mesh
 from ..parallel.sharding import (
@@ -361,12 +365,18 @@ def _decode_fn(
     `state` (the per-slot recurrent state of a stateful model; an empty
     pytree otherwise) rides the scan's carry beside the pool: a sub-step
     advances it for the lanes live at that sub-step and for no other.
+
+    A layer pattern with expert layers also counts the held experts its
+    live lanes chose (forward_slots_counted): the block's sum rides home
+    as ONE MORE ROW of `packed` ([steps + 1, B], the sum in every
+    column). A model without an expert layer compiles to what it compiled
+    to without the count, and downloads [steps, B].
     """
 
     def one(carry, _):
         last, seq, act, paged, state = carry
         positions = jnp.maximum(seq - 1, 0)[:, None]       # [B, 1]
-        hidden, paged, state = forward_slots(
+        hidden, paged, state, hit = forward_slots_counted(
             params, cfg, last[:, None], positions, paged, page_tables,
             state, active=act, mesh=mesh,
         )
@@ -379,12 +389,16 @@ def _decode_fn(
         new_seq = seq + act.astype(jnp.int32)
         cont = act & (tokens != eos_id) & (new_seq < caps)
         packed = jnp.where(act, tokens, -1)
-        return (tokens, new_seq, cont, paged, state), packed
+        return (tokens, new_seq, cont, paged, state), (packed, hit)
 
     carry = (last_tokens, seq_lens, active, paged, state)
-    (last, seq, act, paged, state), packed = jax.lax.scan(
+    (last, seq, act, paged, state), (packed, hits) = jax.lax.scan(
         one, carry, None, length=steps
     )
+    if hits is not None:
+        packed = jnp.concatenate(
+            [packed, jnp.broadcast_to(jnp.sum(hits), (1, packed.shape[1]))]
+        )
     return packed, last, seq, act, paged, state
 
 
@@ -878,6 +892,9 @@ class InferenceEngine:
             )
 
         self.paged = new_pool(self.model_cfg)
+        # Expert layers of a layer pattern: a decode block of such a model
+        # brings home the held experts its live lanes chose (_decode_fn).
+        self._expert_layers = self.model_cfg.layer_pattern.count("E")
         # What a slot holds beside its pages (kv_cache.SlotState): born
         # on the device like the pools; an empty pytree for a model with
         # no recurrent state.
@@ -2177,8 +2194,7 @@ class InferenceEngine:
         # An expert layer of a layer pattern ran these rows sorted by
         # expert, not every row against every held expert: ops/moe.py
         # decides by the same function.
-        grouped = ("E" in self.model_cfg.layer_pattern
-                   and held_experts_grouped(rows))
+        grouped = self._expert_layers and held_experts_grouped(rows)
         self.metrics.on_prefill_rows(
             rows, real, n, sum(c > 1 for c in rows_of.values()),
             grouped_experts=rows if grouped else 0,
@@ -3452,6 +3468,14 @@ class InferenceEngine:
         # ~roundtrip_ms when the host is on the critical path (the r03
         # signature this pipeline exists to erase).
         stall_ms = (time.monotonic() - t_sync) * 1e3
+        if self._expert_layers:
+            # One more row (_decode_fn): the held experts the block's
+            # expert layers hit, over its steps that had a live lane.
+            packed, hit = packed[:-1], int(packed[-1, 0])
+            self.metrics.on_held_experts(
+                self._expert_layers * int((packed >= 0).any(axis=1).sum()),
+                hit,
+            )
         self.metrics.on_process_block(
             lookahead, stall_ms, trace_id=self._block_trace_id(reqs, live)
         )

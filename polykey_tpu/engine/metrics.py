@@ -201,6 +201,13 @@ class EngineMetrics:
         # Of the rows dispatched, those whose expert layers ran the
         # grouped product (ops/moe.py held_experts_grouped).
         self.prefill_rows_grouped_experts = 0
+        # The decode program's expert layers, over the blocks whose
+        # readback has landed: expert layers × steps with a live lane,
+        # and the held experts a live lane chose there — the part of the
+        # held experts' read that was asked for (on_held_experts; in the
+        # snapshot once a block of a model with expert layers is in).
+        self.held_expert_calls = 0
+        self.held_experts_hit = 0
         # Prefill dispatches whose first tokens were read, and for each
         # the seconds since the engine last looked at it and found it
         # unfinished (or since its dispatch call returned): an upper
@@ -353,6 +360,15 @@ class EngineMetrics:
             self.prefill_prompts_split += split
             self.tokens_dispatched_total += dispatched
             self.tokens_useful_total += useful
+
+    def on_held_experts(self, calls: int, hit: int) -> None:
+        """One decode block of a layer pattern with expert layers has
+        landed: `calls` = its expert layers × its steps with a live lane,
+        `hit` = the held experts a live lane chose, summed over those
+        calls (counted on the device, engine._decode_fn)."""
+        with self._lock:
+            self.held_expert_calls += calls
+            self.held_experts_hit += hit
 
     def on_state_rows(self, reset: int, chained: int, resumed: int) -> None:
         """One prefill dispatch of a stateful model: real rows that
@@ -714,6 +730,9 @@ class EngineMetrics:
                 )
             drafts_proposed = self.drafts_proposed
             drafts_accepted = self.drafts_accepted
+            if self.held_expert_calls:
+                snap["held_expert_calls"] = self.held_expert_calls
+                snap["held_experts_hit"] = self.held_experts_hit
         if self.ttft_hist.count:
             # TTFT tail percentiles — TTFT is half the north-star metric
             # and its tail, not its mean, is what operators chase. These

@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import hybrid_kernels
-from ..ops.moe import moe_held
+from ..ops.moe import held_experts_hit, held_weights, moe_held
 from .config import ModelConfig
 from .layers import init_mlp_params, mlp, qkv_project, rms_norm, rope
 from .quant import embed_lookup, qdot
@@ -642,7 +642,10 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
     per-slot SlotState of a stateful pattern; a prefill dispatch says
     what each row does with it (`rows`, a PrefillRows), a decode step
     (T = 1, one row a lane; `rows` None) which lanes are live (`active`).
-    Returns (hidden, pool, state)."""
+    Returns (hidden, pool, state, hits): `hits` the held experts a live
+    lane chose, summed over the expert layers of a decode step (int32
+    scalar; None for a prefill dispatch and for a pattern without an
+    expert layer — nothing is counted there)."""
     decode = rows is None
     eps, offset = cfg.rms_norm_eps, cfg.norm_offset
     x = embed_lookup(params["embed"], tokens)
@@ -650,6 +653,7 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
     conv = list(state.conv) if state is not None else []
     held = 0                    # stateful layers so far: the index in `conv`
     carried = 0                 # those with a recurrence: the index in `ssm`
+    hits = None
     for kind, idx in layer_kinds(cfg):
         p = params["layers"][kind][idx]
         h = rms_norm(x, p["norm"], eps, offset)
@@ -677,10 +681,15 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
                 p, h, positions, cfg, attend, idx, pool)
         elif kind == "dense":
             out = mlp(p, h, cfg.activation)
+        elif active is not None:
+            weights = held_weights(p, h[:, 0], cfg)
+            hit = held_experts_hit(weights, active)
+            hits = hit if hits is None else hits + hit
+            out = moe_held(p, h, cfg, weights)
         else:
             out = moe_held(p, h, cfg)
         x = x + out
     x = rms_norm(x, params["final_norm"], eps, offset)
     if state is not None:
         state = state.replace(ssm=tuple(ssm), conv=tuple(conv))
-    return x, pool, state
+    return x, pool, state, hits

@@ -405,7 +405,16 @@ def forward_paged(
     return hidden, paged
 
 
-def forward_slots(
+def forward_slots(params, cfg, tokens, positions, paged, page_tables, state,
+                  rows=None, active=None, mesh=None):
+    """`forward_slots_counted` without its count: (hidden, paged, state)."""
+    return forward_slots_counted(
+        params, cfg, tokens, positions, paged, page_tables, state, rows,
+        active, mesh,
+    )[:3]
+
+
+def forward_slots_counted(
     params: dict,
     cfg: ModelConfig,
     tokens: jax.Array,               # [B, T] int32, right-padded
@@ -421,8 +430,12 @@ def forward_slots(
     for a stateful model, the per-slot recurrent state beside them, which
     a prefill's `rows` or a decode step's `active` lanes say how to use
     (models/hybrid.py `run_stack`). Returns
-    (hidden, paged, state); a model without state hands `state` back as it
-    came. The homogeneous families keep `_run_paged_stack`'s scan; a
+    (hidden, paged, state, hits); a model without state hands `state` back
+    as it came, and `hits` is the held experts the live lanes of a decode
+    step chose over a layer pattern's expert layers (None where nothing
+    is counted: no expert layer, a prefill; the decode block sends it
+    home, engine._decode_fn). The homogeneous families keep
+    `_run_paged_stack`'s scan; a
     layer pattern walks its layers unrolled, its "*" layers on the same
     write and attention kernels over their own pool layers."""
     from ..ops.paged_attention import paged_attention, paged_write
@@ -450,16 +463,16 @@ def forward_slots(
         hidden, paged = _run_paged_stack(
             params, cfg, tokens, positions, paged, attend
         )
-        return hidden, paged, state
+        return hidden, paged, state, None
     if paged.quantized:
         raise ValueError("a layer pattern has no int8-KV path")
     from .hybrid import run_stack
 
-    hidden, pool, state = run_stack(
+    hidden, pool, state, hits = run_stack(
         params, cfg, tokens, positions, _stacked(paged), attend, state,
         rows, active,
     )
-    return hidden, _unstacked(paged, pool), state
+    return hidden, _unstacked(paged, pool), state, hits
 
 
 def make_sp_override(

@@ -230,6 +230,18 @@ _SPEC_FAMILIES: tuple = (
      "Speculative draft tokens accepted.", "drafts_accepted"),
 )
 
+# A layer pattern's expert layers in the decode program (in a snapshot
+# once such a model has decoded a block): hit / (calls x experts held) is
+# the share of the held experts' read that a live lane asked for.
+_HELD_EXPERT_FAMILIES: tuple = (
+    ("polykey_held_expert_calls_total",
+     "Expert-layer calls of the decode program: expert layers x steps "
+     "with a live lane.", "held_expert_calls"),
+    ("polykey_held_experts_hit_total",
+     "Held experts a live lane chose, summed over those calls.",
+     "held_experts_hit"),
+)
+
 
 def _labeled_lines(kind: str, name: str, help_text: str, key: str,
                    members: list) -> list[str]:
@@ -607,11 +619,16 @@ def engine_collector(engine_or_provider):
                 lines += render_header(name, help_text, kind)
                 for labels, _engine, snap in members:
                     lines.append(render_sample(name, labels, snap[key]))
-        if any(snap.get("drafts_proposed") for _, _, snap in members):
-            for name, help_text, key in _SPEC_FAMILIES:
+        for present, families in (
+            ("drafts_proposed", _SPEC_FAMILIES),
+            ("held_expert_calls", _HELD_EXPERT_FAMILIES),
+        ):
+            if not any(snap.get(present) for _, _, snap in members):
+                continue
+            for name, help_text, key in families:
                 lines += render_header(name, help_text, "counter")
                 for labels, _engine, snap in members:
-                    if snap.get("drafts_proposed"):
+                    if snap.get(present):
                         lines.append(render_sample(name, labels, snap[key]))
         if any(snap.get("spec_gamma") is not None for _, _, snap in members):
             # Per-lane dial aggregates (ISSUE 19): gamma went per-lane,

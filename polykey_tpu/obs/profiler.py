@@ -85,7 +85,14 @@ class ProfilerCapture:
                 )
             target = log_dir or f"{fallback}-{self._captures}"
             os.makedirs(target, exist_ok=True)
-            jax.profiler.start_trace(target)
+            # No Python tracer: its hook on every call of every thread
+            # freezes the gateway's stream handlers for the length of a
+            # capture of a full slot batch, so what was captured was not
+            # what is served. The host tracer stays at its default, so
+            # the polykey/ spans and their attributes are recorded.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(target, profiler_options=options)
             self._dir = target
             self._captures += 1
         if self.recorder is not None:

@@ -172,10 +172,32 @@ def held_experts_grouped(rows: int) -> bool:
             and rows > hybrid_kernels.MOE_GROUPED_ABOVE_ROWS)
 
 
-def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+def held_weights(p: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The combine weights of the experts this chip holds, float32
+    [rows, experts_held]: `held_router_weights` of `tokens` [rows, H],
+    cut to `[first_expert, first_expert + experts_held)`."""
+    return held_router_weights(p, tokens, cfg)[
+        :, cfg.first_expert:cfg.first_expert + cfg.experts_held
+    ]
+
+
+def held_experts_hit(weights: jax.Array, live: jax.Array) -> jax.Array:
+    """How many held experts have a non-zero combine weight on at least
+    one `live` row (int32 scalar): `weights` [rows, experts_held] as
+    `held_weights` gives them, `live` [rows] bool. The part of the held
+    experts' read that some row asked for; a row that is not live (an
+    idle decode lane routes its garbage token all the same) counts for
+    nothing."""
+    return jnp.sum(
+        jnp.any((weights != 0) & live[:, None], axis=0), dtype=jnp.int32)
+
+
+def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig,
+                  weights=None) -> jax.Array:
     """Σ over the chosen experts that are held of weight · a(v, e) ·
     W_down,e, float32 [rows, width of v]: the router
-    (`held_router_weights`) reads `tokens` [rows, H]; the experts read
+    (`held_weights`, or `weights` where the caller has taken them
+    already) reads `tokens` [rows, H]; the experts read
     v = `tokens` through `fc1` where the config states a latent, else
     `tokens`, gated where the layer has a `gate`. Three forms of one sum
     over the same combine weights, chosen by the backend and the static
@@ -183,9 +205,8 @@ def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     `moe_held_experts_jnp`; on it the masked one-pass kernel up to the
     ridge (a decode step, a one-window prefill) and the grouped kernel
     above it."""
-    weights = held_router_weights(p, tokens, cfg)[
-        :, cfg.first_expert:cfg.first_expert + cfg.experts_held
-    ]
+    if weights is None:
+        weights = held_weights(p, tokens, cfg)
     v = qdot(tokens, p["fc1"]) if cfg.moe_latent_size else tokens
     how = {"gate": p.get("gate"), "activation": cfg.activation}
     if held_experts_grouped(tokens.shape[0]):
@@ -197,10 +218,13 @@ def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
     return held(v, p["up"], p["down"], weights, **how)
 
 
-def moe_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+def moe_held(p: dict, h: jax.Array, cfg: ModelConfig,
+             weights=None) -> jax.Array:
     """The expert layer of a layer pattern ("E"), by what the config
     states: a latent → `moe_latent_held`; none → `moe_gated_held`. A
-    combination neither computes is refused here, not half-served."""
+    combination neither computes is refused here, not half-served.
+    `weights`: the `held_weights` of h's rows, where the caller has taken
+    them already (the decode step counts the experts they hit)."""
     if cfg.router_scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"router_scoring {cfg.router_scoring!r} is not "
                          "computed: sigmoid or softmax")
@@ -211,11 +235,12 @@ def moe_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
             "on the full hidden only: shared_expert_gate needs "
             "moe_shared_intermediate and no moe_latent_size")
     if cfg.moe_latent_size:
-        return moe_latent_held(p, h, cfg)
-    return moe_gated_held(p, h, cfg)
+        return moe_latent_held(p, h, cfg, weights)
+    return moe_gated_held(p, h, cfg, weights)
 
 
-def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig,
+                   weights=None) -> jax.Array:
     """Gated experts on the full hidden as the chip that holds experts
     `[first_expert, first_expert + experts_held)` computes them:
     [B, T, H] → [B, T, H], Σ_e w_e · (act(h W_gate,e) ⊙ h W_up,e) W_down,e
@@ -228,7 +253,7 @@ def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     sigmoid(w_s · h) where `shared_expert_gate` says so."""
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
-    out = _held_product(p, tokens, cfg)
+    out = _held_product(p, tokens, cfg, weights)
     if cfg.moe_shared_intermediate:
         shared = mlp(p["shared"], tokens, cfg.activation).astype(jnp.float32)
         if cfg.shared_expert_gate:
@@ -239,7 +264,8 @@ def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
     return out.astype(h.dtype).reshape(B, T, H)
 
 
-def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
+def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig,
+                    weights=None) -> jax.Array:
     """A latent expert layer as ONE chip of its expert-parallel group
     computes it: [B, T, H] → [B, T, H]. The router keeps its published
     width and top-k; the routed sum runs over the chosen experts that are
@@ -254,7 +280,7 @@ def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
         raise ValueError("the held-experts product computes relu(up)² only")
     B, T, H = h.shape
     tokens = h.reshape(B * T, H)
-    routed = _held_product(p, tokens, cfg)
+    routed = _held_product(p, tokens, cfg, weights)
     out = qdot(routed.astype(h.dtype), p["fc2"])
     shared = _activate(qdot(tokens, p["shared_up"]), cfg.activation)
     return (out + qdot(shared, p["shared_down"])).reshape(B, T, H)

@@ -78,3 +78,41 @@ def test_profile_capture_roundtrip(tmp_path):
         assert resp.struct_output["profiling"] is False
     finally:
         engine.shutdown()
+
+
+def test_capture_holds_the_program_spans_and_no_python_frames(tmp_path):
+    """A capture is started without JAX's Python tracer (ISSUE 53: its
+    hook froze the streams a capture measures): the `polykey/` spans the
+    benchmark reads are in it, and no event of the tracer's (`$file:line
+    function` frames) is."""
+    from jax.profiler import ProfileData
+
+    engine = InferenceEngine(CONFIG)
+    service = TpuService(engine)
+    try:
+        log_dir = str(tmp_path / "trace")
+        service.execute_tool(
+            "engine_profile", _params(action="start", log_dir=log_dir),
+            None, None,
+        )
+        resp = service.execute_tool(
+            "llm_generate", _params(prompt="profile me", max_tokens=4),
+            None, None,
+        )
+        assert resp.status.code == 200
+        service.execute_tool(
+            "engine_profile", _params(action="stop"), None, None
+        )
+    finally:
+        engine.shutdown()
+    (trace,) = glob.glob(
+        os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True
+    )
+    names = {
+        event.name
+        for plane in ProfileData.from_file(trace).planes
+        for line in plane.lines
+        for event in line.events
+    }
+    assert {"polykey/decode", "polykey/prefill"} <= names
+    assert not [name for name in names if name.startswith("$")]
