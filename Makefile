@@ -7,11 +7,11 @@ CXX ?= g++
 CXXFLAGS ?= -O2 -std=c++17 -Wall -Wextra
 BUILD_DIR := build
 
-.PHONY: help run run-client test test-models native protos clean bench dryrun \
-	kernel-check chip-smoke bench-tokenizer metrics-smoke \
+.PHONY: help run run-client test test-models native protos clean perfbench-tiny dryrun \
+	kernel-check chip-smoke metrics-smoke \
 	obs-smoke chaos-smoke print-chaos occupancy-smoke occupancy-soak \
-	failover-smoke failover-soak timeline-capture perf-gate \
-	perf-gate-reference flightwatch spec-smoke \
+	failover-smoke failover-soak timeline-capture \
+	flightwatch spec-smoke \
 	disagg-smoke disagg-soak hostkv-smoke hostkv-soak \
 	autopilot-smoke autopilot-soak \
 	postmortem postmortem-smoke
@@ -35,21 +35,19 @@ test-report: ## Tests with the Jest-style report renderer
 	    from polykey_tpu.gateway.beautify import print_jest_report; \
 	    print_jest_report(open('/tmp/pytest-report.jsonl'))"
 
-native: $(BUILD_DIR)/log-beautifier $(BUILD_DIR)/libblock_allocator.so ## Build native C++ components
+native: $(BUILD_DIR)/log-beautifier ## Build the native log beautifier
 
 $(BUILD_DIR)/log-beautifier: native/log_beautifier.cc
 	@mkdir -p $(BUILD_DIR)
 	$(CXX) $(CXXFLAGS) -o $@ $<
 
-$(BUILD_DIR)/libblock_allocator.so: native/block_allocator.cc
-	@mkdir -p $(BUILD_DIR)
-	$(CXX) $(CXXFLAGS) -shared -fPIC -o $@ $<
-
 protos: ## Regenerate protobuf stubs from protos/
 	./scripts/gen_protos.sh
 
-bench: ## Run the benchmark harness (prints one JSON line)
-	$(PYTHON) bench.py
+# As tests/perfbench/test_perfbench_rehearsal.py runs it; on the chip,
+# BENCHMARK.json's `command` with perfbench/run.py's arguments, no --tiny.
+perfbench-tiny: ## One benchmark cell (BENCHMARK.json + perfbench/) on the CPU at toy size
+	$(PYTHON) perfbench/run.py --workload mistral-7b.decode-saturated --seed 2147483659 --seconds 3 --trace 0 --tiny
 
 # Observability acceptance probe (ISSUE 10; grown from PR 1's
 # metrics-smoke): families, OpenMetrics exemplars, the gated /debug
@@ -59,17 +57,6 @@ obs-smoke: ## Boot the stack on CPU; assert families, exemplars, debug endpoints
 	JAX_PLATFORMS=cpu $(PYTHON) scripts/obs_smoke.py
 
 metrics-smoke: obs-smoke ## Legacy alias for obs-smoke
-
-# Perf-regression sentinel (ISSUE 11): deterministic CPU soak compared
-# against the committed perf/slo_reference.json with explicit noise
-# tolerances — the first automated perf-trajectory gate. Regenerate the
-# reference (and commit it) after an INTENTIONAL perf change with
-# `make perf-gate-reference`.
-perf-gate: ## Deterministic CPU soak gated against perf/slo_reference.json
-	JAX_PLATFORMS=cpu $(PYTHON) scripts/perf_gate.py
-
-perf-gate-reference: ## Regenerate perf/slo_reference.json from this machine
-	JAX_PLATFORMS=cpu $(PYTHON) scripts/perf_gate.py --write-reference
 
 # Operator triage console (ISSUE 11): top-style live view over /metrics
 # + /debug/slo (set POLYKEY_DEBUG_ENDPOINTS=1 on the server for the
@@ -260,9 +247,6 @@ kernel-check: ## Compile + compare every Pallas kernel on the attached TPU
 chip-smoke: ## Gateway -> engine on the attached TPU, once (CPU rehearsal: chip_smoke.py --tiny)
 	$(PYTHON) chip_smoke.py
 
-bench-tokenizer: ## (Re)train the bench's local BPE tokenizer asset
-	$(PYTHON) scripts/build_bench_tokenizer.py
-
 dryrun: ## Compile-check the multi-chip sharded step on a virtual mesh
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	  $(PYTHON) scripts/dryrun_multichip.py
@@ -275,10 +259,10 @@ multiproc-demo: ## 2-process jax.distributed train+serve on localhost CPU
 
 lint: ## Lint: ruff (pinned ruff.toml, same config as CI) + polylint
 	@if command -v ruff >/dev/null 2>&1; then \
-	  ruff check polykey_tpu/ tests/ bench.py scripts/; \
+	  ruff check polykey_tpu/ tests/ scripts/; \
 	else \
 	  echo "ruff not installed (CI pins ruff==0.12.5); falling back to a syntax gate"; \
-	  $(PYTHON) -m compileall -q polykey_tpu/ tests/ bench.py scripts/; \
+	  $(PYTHON) -m compileall -q polykey_tpu/ tests/ scripts/; \
 	fi
 	@$(MAKE) polylint
 
@@ -324,14 +308,10 @@ schedlint: ## Scheduler liveness & fairness contract analysis (stdlib-only)
 
 ASAN_FLAGS := -g -O1 -fsanitize=address,undefined -fno-omit-frame-pointer
 
-native-asan: ## Build native components under ASan/UBSan and smoke-run them
+native-asan: ## Build the log beautifier under ASan/UBSan and smoke-run it
 	@mkdir -p $(BUILD_DIR)/asan
 	$(CXX) -std=c++17 -Wall -Wextra $(ASAN_FLAGS) \
 	  -o $(BUILD_DIR)/asan/log-beautifier native/log_beautifier.cc
-	$(CXX) -std=c++17 -Wall -Wextra $(ASAN_FLAGS) \
-	  -o $(BUILD_DIR)/asan/block-allocator-smoke \
-	  native/block_allocator_smoke.cc native/block_allocator.cc
-	$(BUILD_DIR)/asan/block-allocator-smoke
 	@printf '%s\n' \
 	  '{"time":"2026-08-03T00:00:00Z","level":"INFO","msg":"gRPC call received","method":"/polykey.v2.PolykeyService/ExecuteTool","trace_id":"smoke1"}' \
 	  '{"time":"2026-08-03T00:00:01Z","level":"INFO","msg":"gRPC call finished","method":"/polykey.v2.PolykeyService/ExecuteTool","duration":"12.3ms","code":"OK","trace_id":"smoke1"}' \
@@ -355,7 +335,7 @@ scan: ## Security scan (Trivy fs over the tree + lockfile, CRITICAL/HIGH gate)
 	  --scanners vuln,secret \
 	  --severity CRITICAL,HIGH
 
-ci-check: ## Run the CI pipeline locally: lint+polylint+racelint+graphlint+memlint+schedlint, chaos, failover, disagg(+lock/heap/sched-witness gates), postmortem, occupancy(+sched-witness gate), spec, hostkv(+heap-witness gate), autopilot(+analysis-all gate), obs, perf-gate, tests, native(+asan), scan
+ci-check: ## Run the CI pipeline locally: lint+polylint+racelint+graphlint+memlint+schedlint, chaos, failover, disagg(+lock/heap/sched-witness gates), postmortem, occupancy(+sched-witness gate), spec, hostkv(+heap-witness gate), autopilot(+analysis-all gate), obs, tests, native(+asan), scan
 	@$(MAKE) lint
 	@$(MAKE) racelint
 	@$(MAKE) graphlint
@@ -370,7 +350,6 @@ ci-check: ## Run the CI pipeline locally: lint+polylint+racelint+graphlint+memli
 	@$(MAKE) hostkv-smoke
 	@$(MAKE) autopilot-smoke
 	@$(MAKE) obs-smoke
-	@$(MAKE) perf-gate
 	@$(MAKE) test
 	@$(MAKE) native
 	@$(MAKE) native-asan

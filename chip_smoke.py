@@ -68,22 +68,6 @@ def check(condition: bool, message: str) -> None:
         raise SmokeFailure(message)
 
 
-def build_allocator() -> None:
-    """Build the native block allocator from the checkout's own source,
-    so no stale library rides along (build/ is git-ignored). A failed
-    build is not fatal: the engine falls back to the Python allocator
-    and engine_stats says which one served."""
-    lib = os.path.join(ROOT, "build", "libblock_allocator.so")
-    try:
-        os.remove(lib)
-    except FileNotFoundError:
-        pass    # none yet, or a smoke started beside this one removed it
-    subprocess.run(
-        ["make", "-s", "build/libblock_allocator.so"], cwd=ROOT, check=False,
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, timeout=120,
-    )
-
-
 def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -283,7 +267,6 @@ def report(args, device: dict, first: dict, last: dict, asked: int) -> None:
           f"{'n/a' if args.tiny else args.quantize} tp={args.tp} "
           f"replicas={args.replicas} slots={int(head['slots_total'])} "
           f"pages={int(head['pages_total'])}")
-    print(f"allocator={head['allocator']}")
     for i, eng in enumerate(warm):
         comp = _ints(eng["warmup_compiles"])
         print(f"engine[{i}] devices={[int(d) for d in eng['devices']]} "
@@ -337,14 +320,11 @@ def run(args) -> dict:
     from polykey_tpu.gateway.config import Config
     from polykey_tpu.gateway.jsonlog import Logger
 
-    disabled = sorted(k for k in os.environ if k.startswith("POLYKEY_DISABLE_"))
-    check(not disabled, f"kill switch set: {', '.join(disabled)}")
     explicit_cpu = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
     check(args.tiny or not explicit_cpu,
           "JAX_PLATFORMS=cpu: the smoke needs a TPU (use --tiny for the "
           "CPU rehearsal)")
 
-    build_allocator()
     os.makedirs(OUT_DIR, exist_ok=True)
     log_path = os.path.join(OUT_DIR, "chip_smoke_server.log")
     port = free_port()

@@ -335,7 +335,7 @@ def load_witness_arg(path: Optional[str], loader):
 
 # -- runner -------------------------------------------------------------------
 
-DEFAULT_TARGETS = ("polykey_tpu", "bench.py", "scripts")
+DEFAULT_TARGETS = ("polykey_tpu", "scripts")
 _EXCLUDE_DIRS = {"__pycache__"}
 # Generated protobuf stubs and this package's test fixtures are not ours
 # to lint.

@@ -146,26 +146,12 @@ DONATED_EXECUTABLES = {
 # DEPLOY.md row. A knob must appear in exactly one place.
 # ---------------------------------------------------------------------------
 
-INTERNAL_KNOB_PREFIXES: dict[str, str] = {
-    # bench.py's phase harness: workload shaping for one-off measurement
-    # runs (request counts, prompt lengths, sweep axes). Not serving
-    # configuration; documented inline in bench.py's phase docstrings.
-    "POLYKEY_BENCH_": "bench.py harness workload knobs (PERF.md runbook)",
-}
-
 INTERNAL_KNOBS: dict[str, str] = {
     # dev/test escape hatches and harness-local switches; each is
     # documented at its read site.
-    "POLYKEY_PROFILE_N": "bench profiler sample count (bench.py only)",
-    "POLYKEY_PROFILE_QUANT":
-        "bench profiler quantization override (bench.py only)",
-    "POLYKEY_PROFILE_KV": "bench profiler KV override (bench.py only)",
     "POLYKEY_FAULTS":
         "chaos fault-injection spec (faults.py); test/soak harness "
         "surface, never an operator knob",
-    "POLYKEY_LOOKAHEAD":
-        "legacy alias for POLYKEY_DISPATCH_LOOKAHEAD, which holds the "
-        "DEPLOY.md row",
 }
 
 # ---------------------------------------------------------------------------
@@ -176,8 +162,6 @@ INTERNAL_KNOBS: dict[str, str] = {
 # ---------------------------------------------------------------------------
 
 WORKER_ENV_EXEMPT: dict[str, str] = {
-    "POLYKEY_LOOKAHEAD":
-        "legacy alias; the canonical POLYKEY_DISPATCH_LOOKAHEAD ships",
     "POLYKEY_DRAFT_MODEL":
         "validate() rejects draft models under disagg (spec decode is "
         "single-engine); a worker can never need it",
@@ -706,15 +690,6 @@ def deploy_documented_knobs(deploy_text: str) -> set[str]:
     return documented
 
 
-def _knob_internal(knob: str) -> Optional[str]:
-    if knob in INTERNAL_KNOBS:
-        return INTERNAL_KNOBS[knob]
-    for prefix, reason in INTERNAL_KNOB_PREFIXES.items():
-        if knob.startswith(prefix):
-            return reason
-    return None
-
-
 def check_knob_docs(env_reads: dict[str, list[tuple[str, int, str]]],
                     deploy_text: Optional[str],
                     ) -> list[Finding]:
@@ -735,7 +710,7 @@ def check_knob_docs(env_reads: dict[str, list[tuple[str, int, str]]],
         for knob, line, _fn in env_reads[rel]:
             first_site.setdefault(knob, (rel, line))
     for knob in sorted(first_site):
-        if knob in documented or _knob_internal(knob) is not None:
+        if knob in documented or knob in INTERNAL_KNOBS:
             continue
         rel, line = first_site[knob]
         findings.append(Finding(

@@ -50,7 +50,7 @@ def enable_persistent_compile_cache() -> Optional[str]:
     process places the cache from outside. Otherwise the cache lives in
     <checkout>/.jax_cache (git-ignored). JAX binds the directory at the
     first compile, so every process entry that compiles (gateway server,
-    disagg worker, chip_smoke.py, bench.py, the kernel check) calls this
+    disagg worker, chip_smoke.py, perfbench, the kernel check) calls this
     before its first jit.
 
     Every executable is persisted, however quick its compile: a serving
@@ -251,7 +251,7 @@ class EngineConfig:
     # Window widths (comma-separated seconds, "" → the env /
     # 60,300,3600 defaults) and the SLO policy spec (inline JSON,
     # "@/path.json", or "default"; "" → POLYKEY_SLO). Carried on the
-    # config so programmatic constructions (perf_gate, tests, embedded
+    # config so programmatic constructions (tests, embedded
     # engines) control them without mutating os.environ, and so a
     # supervised restart rebuilds the plane from the SAME spec the
     # original engine ran — engines built with the empty defaults fall
@@ -314,14 +314,6 @@ class EngineConfig:
     # forwards per round. Page/position slack always reserves for the
     # full spec_gamma, so adaptation never overflows a slot.
     adaptive_gamma: bool = True
-
-    # A/B instrumentation ONLY (scripts/occupancy_soak.py --ab-spec):
-    # emulate the pre-ISSUE-19 host-loop spec round by forcing three
-    # synchronous packed readbacks at dispatch time — the crossing
-    # schedule of the old path on the new path's math, so the A/B
-    # isolates the host tax. Never set in production; programmatic only
-    # (no env knob on purpose — it exists to measure a regression).
-    spec_host_sync: bool = False
 
     # Liveness. The watchdog window must comfortably exceed worst-case XLA
     # compile time (each new prefill bucket compiles on first use).
@@ -463,11 +455,9 @@ class EngineConfig:
             ).lower() in ("1", "true"),
             # POLYKEY_DISPATCH_LOOKAHEAD is the documented knob (DEPLOY.md;
             # the engine also honors it as a construction-time override so
-            # it works however the config was built); POLYKEY_LOOKAHEAD is
-            # the legacy alias and loses when both are set.
+            # it works however the config was built).
             lookahead_blocks=_env_int(
-                "POLYKEY_DISPATCH_LOOKAHEAD",
-                _env_int("POLYKEY_LOOKAHEAD", cls.lookahead_blocks),
+                "POLYKEY_DISPATCH_LOOKAHEAD", cls.lookahead_blocks
             ),
             timeline_capacity=_env_int(
                 "POLYKEY_TIMELINE_CAPACITY", cls.timeline_capacity

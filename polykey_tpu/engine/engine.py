@@ -1475,9 +1475,6 @@ class InferenceEngine:
                 "warmup_compiles": dict(self._warm_compiles),
                 "warmup_mosaic_calls": dict(self._warm_kernels),
                 "warmup_collectives": dict(self._warm_collectives),
-                "allocator": (
-                    "native" if self.allocator.is_native else "python"
-                ),
                 "slots_busy": sum(s is not None for s in self._slots),
                 "slots_total": self.config.max_decode_slots,
                 "pages_free": self.allocator.num_free,
@@ -3600,22 +3597,6 @@ class InferenceEngine:
             # Best-effort copy hint only: _process_spec's np.asarray syncs
             # regardless; backends without async copies lose overlap only.
             pass
-        if self.config.spec_host_sync:
-            # A/B instrumentation (scripts/occupancy_soak.py --ab-spec):
-            # emulate the pre-ISSUE-19 host-loop spec round — three
-            # synchronous readbacks per round on the device-resident
-            # math, so the A/B isolates the crossing schedule, not the
-            # arithmetic. Each timed read lands in the host-stall
-            # accounting (metrics.on_spec_host_sync). Never enabled in
-            # production.
-            for _ in range(3):
-                t_sync = time.monotonic()
-                with _host_crossing("spec-host-sync"):
-                    # polylint: disable=PL001(spec_host_sync A/B emulation of the pre-ISSUE-19 host-loop round; off in production), PL008(the blocking dispatch-side read IS the measured subject here)
-                    np.asarray(packed_dev)
-                self.metrics.on_spec_host_sync(
-                    (time.monotonic() - t_sync) * 1e3
-                )
         return packed_dev
 
     def _process_spec(self, data, reqs, lookahead: int = 0, seq: int = 0,

@@ -27,17 +27,14 @@ apart, [..., Hk, D]; `fold_pages` / `unfold_pages` convert page-sized host
 arrays at that boundary, so no byte of a host page or a wire blob follows
 the device's layout.
 
-The allocator is host-side bookkeeping: the C++ implementation
-(native/block_allocator.cc, loaded via ctypes) with a pure-Python fallback of
-identical semantics. Page 0 is reserved as the garbage page — inactive decode
+The allocator is host-side bookkeeping: a refcounted free list.
+Page 0 is reserved as the garbage page — inactive decode
 slots point at it so masked lanes always have a safe write target.
 """
 
 from __future__ import annotations
 
-import ctypes
 import json
-import os
 import struct as _struct
 import zlib
 from dataclasses import dataclass
@@ -49,32 +46,6 @@ import numpy as np
 from flax import struct
 
 from ..models.config import ModelConfig
-from .config import CHECKOUT
-
-# `make native` output, located from the package — never from the current
-# directory, where a library from some other checkout could ride along.
-_NATIVE_PATH = os.path.join(CHECKOUT, "build", "libblock_allocator.so")
-
-
-def _load_native() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_NATIVE_PATH):
-        return None
-    lib = ctypes.CDLL(_NATIVE_PATH)
-    lib.pk_allocator_new.restype = ctypes.c_void_p
-    lib.pk_allocator_new.argtypes = [ctypes.c_int32]
-    lib.pk_allocator_free.argtypes = [ctypes.c_void_p]
-    lib.pk_num_free.restype = ctypes.c_int32
-    lib.pk_num_free.argtypes = [ctypes.c_void_p]
-    lib.pk_alloc.restype = ctypes.c_int32
-    lib.pk_alloc.argtypes = [
-        ctypes.c_void_p, ctypes.c_int32,
-        ctypes.POINTER(ctypes.c_int32),
-    ]
-    lib.pk_retain.restype = ctypes.c_int32
-    lib.pk_retain.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-    lib.pk_release.restype = ctypes.c_int32
-    lib.pk_release.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-    return lib
 
 
 class AllocationError(RuntimeError):
@@ -82,44 +53,24 @@ class AllocationError(RuntimeError):
 
 
 class BlockAllocator:
-    """Refcounted free-list page allocator (native-backed when built)."""
+    """Refcounted free-list page allocator."""
 
-    def __init__(self, num_pages: int, prefer_native: bool = True):
+    def __init__(self, num_pages: int):
         if num_pages < 2:
             raise ValueError("need at least 2 pages (page 0 is reserved)")
         self.num_pages = num_pages
-        self._lib = _load_native() if prefer_native else None
-        if self._lib is not None:
-            self._handle = self._lib.pk_allocator_new(num_pages)
-        else:
-            self._free = list(range(num_pages - 1, 0, -1))
-            self._refcount = [0] * num_pages
-            self._refcount[0] = 1
-        self.is_native = self._lib is not None
-
-    def __del__(self):
-        lib = getattr(self, "_lib", None)
-        if lib is not None:
-            lib.pk_allocator_free(self._handle)
-            self._lib = None
+        self._free = list(range(num_pages - 1, 0, -1))
+        self._refcount = [0] * num_pages
+        self._refcount[0] = 1
 
     @property
     def num_free(self) -> int:
-        if self._lib is not None:
-            return self._lib.pk_num_free(self._handle)
         return len(self._free)
 
     def alloc(self, count: int) -> list[int]:
         """Allocate `count` pages; all-or-nothing."""
         if count == 0:
             return []
-        if self._lib is not None:
-            out = (ctypes.c_int32 * count)()
-            if not self._lib.pk_alloc(self._handle, count, out):
-                raise AllocationError(
-                    f"requested {count} pages, {self.num_free} free"
-                )
-            return list(out)
         if len(self._free) < count:
             raise AllocationError(
                 f"requested {count} pages, {len(self._free)} free"
@@ -130,19 +81,11 @@ class BlockAllocator:
         return pages
 
     def retain(self, page: int) -> None:
-        if self._lib is not None:
-            if self._lib.pk_retain(self._handle, page) < 0:
-                raise ValueError(f"retain of unallocated page {page}")
-            return
         if page <= 0 or page >= self.num_pages or self._refcount[page] == 0:
             raise ValueError(f"retain of unallocated page {page}")
         self._refcount[page] += 1
 
     def release(self, page: int) -> None:
-        if self._lib is not None:
-            if self._lib.pk_release(self._handle, page) < 0:
-                raise ValueError(f"release of unallocated page {page}")
-            return
         if page <= 0 or page >= self.num_pages or self._refcount[page] == 0:
             raise ValueError(f"release of unallocated page {page}")
         self._refcount[page] -= 1

@@ -497,7 +497,7 @@ class SignalPlane:
 
     def sample_now(self) -> None:
         """Force a ring sample regardless of the interval gate, then
-        evaluate. Harness hook (perf_gate, tests) for pinning a
+        evaluate. Harness hook (obs_smoke.py, tests) for pinning a
         measurement boundary exactly — the periodic path may lag a
         finish by up to `interval_s`."""
         now = time.monotonic()

@@ -220,12 +220,7 @@ def use_flash(T: int, S: int, head_dim: int) -> bool:
     """Dispatch policy: the kernel wins when the logits matrix is large
     enough that not materializing it matters; the reference path keeps tiny
     shapes (decode against short caches, unit tests), unusual head dims,
-    and non-TPU backends. POLYKEY_DISABLE_FLASH=1 is the operational
-    kill-switch (the reference path serves every shape)."""
-    import os
-
-    if os.environ.get("POLYKEY_DISABLE_FLASH", "").lower() in ("1", "true"):
-        return False
+    and non-TPU backends."""
     return (
         jax.default_backend() == "tpu"
         and T >= 128

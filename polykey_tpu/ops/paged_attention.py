@@ -138,7 +138,7 @@ def paged_write(
       (ops/paged_write_kernel.py) — per-lane page RMW into the aliased
       pool. The XLA scatter here lowers to a sequential per-row update
       loop that measured ~10 ms/step of a ~21 ms 1B decode step
-      (scripts/profile_block_device.py); the kernel makes it ~free.
+      — the kernel makes it ~free.
     - T > 1 with page-aligned consecutive rows (every engine prefill
       chunk: buckets and chunk starts are multiples of page_size): a
       page-granular scatter — T/ps big row updates per lane instead of
@@ -179,14 +179,10 @@ def paged_write(
         return tuple(pools) if quantized else pools[0]
 
     if T == 1:
-        from .paged_attention_kernel import (
-            use_paged_kernel,
-            use_quantized_paged_kernel,
-        )
+        from .paged_attention_kernel import use_paged_kernel
 
         pp = mesh.shape.get("pp", 1) if mesh is not None else 1
-        gate = use_quantized_paged_kernel if quantized else use_paged_kernel
-        if gate(Hk, D) and pp == 1:
+        if use_paged_kernel(Hk, D) and pp == 1:
             # A lane's rows as the kernel blends them into the entries of
             # its page: [B, 2, 1, Hk·D] for the two halves, [B, 1, 1, Hk].
             return repack(_write_decode_kernel(

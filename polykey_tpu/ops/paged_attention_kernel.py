@@ -59,8 +59,7 @@ via pmax/psum before normalizing (see paged_attention_decode).
 
 Covers GQA, logit soft-capping, and dynamic sliding windows; falls back to
 the gather implementation off-TPU (`use_kernel` dispatch in
-paged_attention_decode, with the POLYKEY_DISABLE_PAGED_KERNEL
-kill-switch).
+paged_attention_decode).
 """
 
 from __future__ import annotations
@@ -566,31 +565,9 @@ INT8_KV_MOSAIC_ERROR = (
 )
 
 
-def use_quantized_paged_kernel(num_kv_heads: int, head_dim: int) -> bool:
-    """Gate for the int8-KV kernel paths (read dequant stage + scale-page
-    writes): same geometry rule as the data pools, plus the dedicated
-    POLYKEY_DISABLE_KV_KERNEL kill-switch — the scale-page DMAs
-    ([ps, Hk], minor dim far below lane width) are a separate Mosaic
-    lowering surface, and a regression there must be containable without
-    taking the WORKING fp kernels down with it (the quantized fallback
-    is the int8 gather/scatter, still half the bf16 bytes)."""
-    import os
-
-    if os.environ.get("POLYKEY_DISABLE_KV_KERNEL", "").lower() in ("1", "true"):
-        return False
-    return use_paged_kernel(num_kv_heads, head_dim)
-
-
 def use_paged_kernel(num_kv_heads: int, head_dim: int) -> bool:
     """The DMA kernel needs TPU hardware; the folded head-lane dimension
-    (num_kv_heads · head_dim) must be 128-aligned for DMA tiling.
-    POLYKEY_DISABLE_PAGED_KERNEL=1 is the operational kill-switch: the
-    gather path serves every geometry, so a kernel-compile regression on
-    new hardware must never take the whole TPU path down."""
-    import os
-
-    if os.environ.get("POLYKEY_DISABLE_PAGED_KERNEL", "").lower() in ("1", "true"):
-        return False
+    (num_kv_heads · head_dim) must be 128-aligned for DMA tiling."""
     return jax.default_backend() == "tpu" and (num_kv_heads * head_dim) % 128 == 0
 
 
@@ -633,8 +610,7 @@ def paged_attention_decode(
     D = q.shape[3]
     Hk = data_pool.shape[2] // D
 
-    gate = use_quantized_paged_kernel if quantized else use_paged_kernel
-    if not (force_kernel or interpret or gate(Hk, D)):
+    if not (force_kernel or interpret or use_paged_kernel(Hk, D)):
         from .paged_attention import paged_attention
 
         return paged_attention(

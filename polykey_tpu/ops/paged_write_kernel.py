@@ -4,8 +4,8 @@ Why this exists: the XLA scatter in ops/paged_attention.paged_write
 (`k_pages.at[page_ids, offsets].set(k_new)`) lowers on TPU to a
 sequential per-row update loop — for a decode step that is
 2 (k,v) x num_layers x B tiny dynamic-update-slices, measured at ~10 ms
-of the ~21 ms step at 1B/B=32 geometry (scripts/profile_block_device.py,
-PERF.md). The write itself moves only B x Hk x D x 2 bytes per layer
+of the ~21 ms step at 1B/B=32 geometry
+(PERF.md). The write itself moves only B x Hk x D x 2 bytes per layer
 (~100 KB) — it is pure launch/serialization overhead.
 
 A row cannot be DMA'd directly into its page: pool pages are tiled
@@ -44,8 +44,8 @@ their full-page write-backs cannot clobber each other; rows that share
 a page must not be written as lanes of one wave.
 
 Hk*D must be 128-aligned for the folded data-pool DMA — the same
-`use_paged_kernel` gate as the read kernel. Off-TPU (and under
-POLYKEY_DISABLE_PAGED_KERNEL=1) callers keep the XLA scatter.
+`use_paged_kernel` gate as the read kernel. Off-TPU
+callers keep the XLA scatter.
 
 Reference obligation: none — the reference has no KV cache at all
 (SURVEY.md §2b "Paged KV cache" is north-star-owed); this is the

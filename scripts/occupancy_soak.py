@@ -64,10 +64,7 @@ def sched_witness_verdict():
         schedwitness.load_witness(os.path.dirname(path)))
 
 
-def build_engine(args, overrides: dict = None, params=None,
-                 draft_params=None):
-    import dataclasses
-
+def build_engine(args):
     from polykey_tpu.engine.config import EngineConfig
     from polykey_tpu.engine.engine import InferenceEngine
 
@@ -94,11 +91,7 @@ def build_engine(args, overrides: dict = None, params=None,
         max_queue_depth=0,
         supervise=False,
     )
-    if overrides:
-        # --ab-spec legs: draft model + gamma, and spec_host_sync on the
-        # emulated host-loop leg.
-        cfg = dataclasses.replace(cfg, **overrides)
-    return InferenceEngine(cfg, params=params, draft_params=draft_params)
+    return InferenceEngine(cfg)
 
 
 def main() -> int:
@@ -131,23 +124,6 @@ def main() -> int:
                     help="exit 1 when measured avg_lanes/slots is below")
     ap.add_argument("--seed", type=int, default=29)
     ap.add_argument("--out", default="")
-    ap.add_argument("--ab-spec", action="store_true",
-                    help="speculative-round A/B (ISSUE 19): train the "
-                         "sweep's Markov target+draft pair, then run the "
-                         "soak THREE times at the same seed — plain "
-                         "(no draft), spec under the emulated host-loop "
-                         "crossing schedule (spec_host_sync), and spec "
-                         "with device-resident rounds — and write ONE "
-                         "combined artifact with the host_stall, "
-                         "dispatch-gap, and tok/s deltas gated against "
-                         "the PR 4 break-even prediction at the "
-                         "measured alpha")
-    ap.add_argument("--spec-gamma", type=int, default=4,
-                    help="draft window for the --ab-spec legs")
-    ap.add_argument("--spec-train-steps", type=int, default=300,
-                    help="train steps for the --ab-spec target/draft "
-                         "pair (spec_acceptance_sweep.prepare_trained_"
-                         "pair)")
     ap.add_argument("--timeline", default="",
                     help="also export the engine's flight-deck timeline "
                          "as Perfetto JSON to this path (ISSUE 10: the "
@@ -178,8 +154,6 @@ def main() -> int:
 def run_main(args) -> int:
     if getattr(args, "host_kv", False):
         return run_hostkv_main(args)
-    if getattr(args, "ab_spec", False):
-        return run_spec_ab(args)
     result = run_soak(args)
     failures = result["failed_in_window"]
 
@@ -215,16 +189,13 @@ def run_main(args) -> int:
     return 0
 
 
-def run_soak(args, overrides: dict = None,
-             params=None, draft_params=None, corpus_fn=None) -> dict:
+def run_soak(args) -> dict:
     rng = np.random.default_rng(args.seed)
 
     def prompt() -> str:
         # Mixed lengths (in BYTE tokens ≈ chars): short bucket, full
         # bucket, and beyond-bucket prompts that chunk-prefill. Base-26
-        # letters keep the byte tokenizer in its dense range; --ab-spec
-        # passes the Markov corpus sampler instead so the trained pair's
-        # acceptance is measured on its own text distribution.
+        # letters keep the byte tokenizer in its dense range.
         r = rng.random()
         if r < args.long_frac:
             n = int(rng.integers(96, 160))     # > 64-bucket -> chunked
@@ -232,14 +203,11 @@ def run_soak(args, overrides: dict = None,
             n = int(rng.integers(8, 30))       # 32-bucket
         else:
             n = int(rng.integers(33, 62))      # 64-bucket
-        if corpus_fn is not None:
-            return corpus_fn(n, rng)
         return "".join(chr(c) for c in rng.integers(97, 123, n))
 
     from polykey_tpu.engine.engine import GenRequest
 
-    engine = build_engine(args, overrides=overrides,
-                          params=params, draft_params=draft_params)
+    engine = build_engine(args)
     try:
         def completed() -> int:
             return (engine.metrics.requests_completed
@@ -266,11 +234,7 @@ def run_soak(args, overrides: dict = None,
         burst(n_cal)                      # cold: compiles
         svc = max(0.05, burst(n_cal))     # warm: timed
         rate = args.rate or args.oversub * args.slots / svc
-        # --ab-spec sets rate_feedback: the leg starts from a GIVEN rate
-        # (shared across legs) but still tracks the backlog band, so a
-        # leg whose capacity differs from the donor rate converges to
-        # saturation instead of growing an unbounded queue.
-        feedback = (not args.rate) or getattr(args, "rate_feedback", False)
+        feedback = not args.rate
         log(f"calibration: warm burst of {n_cal} in {svc:.2f}s -> "
             f"Poisson rate {rate:.1f}/s"
             f" ({'given' if args.rate else 'auto'}"
@@ -367,7 +331,7 @@ def run_soak(args, overrides: dict = None,
                 "rate_initial_per_s": round(rate0, 2),
                 "rate_final_per_s": round(rate, 2),
                 "rate_source": (
-                    ("given+backlog-tracked" if feedback else "given")
+                    "given"
                     if args.rate else "auto-calibrated+backlog-tracked"),
                 "warm_burst_s": round(svc, 3),
                 "ramp_s": round(ramp, 1),
@@ -398,16 +362,6 @@ def run_soak(args, overrides: dict = None,
                 (snap1["host_stall_ms_total"] - snap0["host_stall_ms_total"])
                 / max(1, snap1["blocks_synced"]
                       - snap0["blocks_synced"]), 3),
-            # Same stall total NORMALIZED PER BLOCK (round) instead of
-            # per sync event: the --ab-spec gate metric. The host-loop
-            # leg takes several synchronous readbacks per round, so its
-            # per-EVENT mean is diluted by event count and can read
-            # LOWER than the device leg's while the per-round host tax
-            # is 2-3x higher — per-block is the apples-to-apples rate.
-            "host_stall_ms_per_block": round(
-                (snap1["host_stall_ms_total"] - snap0["host_stall_ms_total"])
-                / max(1, snap1["blocks_processed"]
-                      - snap0["blocks_processed"]), 3),
             "lookahead_observed_mean": round(
                 (snap1["lookahead_sum"] - snap0["lookahead_sum"])
                 / max(1, snap1["blocks_processed"]
@@ -424,9 +378,7 @@ def run_soak(args, overrides: dict = None,
                 / max(1e-9, snap1["dispatch_gap_ms_total"]
                       - snap0["dispatch_gap_ms_total"]), 4),
             # Mean host-side gap between consecutive dispatches over the
-            # window — the --ab-spec acceptance number alongside
-            # host_stall_ms_mean: per-round synchronous readbacks widen
-            # it, device-resident rounds shrink it (ISSUE 19).
+            # window.
             "dispatch_gap_ms_mean": round(
                 (snap1["dispatch_gap_ms_total"]
                  - snap0["dispatch_gap_ms_total"])
@@ -452,15 +404,6 @@ def run_soak(args, overrides: dict = None,
             "measured_at": time.strftime(
                 "%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
         }
-        if overrides and overrides.get("draft_model"):
-            result["spec"] = {
-                "gamma": overrides.get("spec_gamma"),
-                "host_sync": bool(overrides.get("spec_host_sync")),
-                "acceptance": stats1.get("spec_acceptance"),
-                "drafts_proposed": stats1.get("drafts_proposed"),
-                "drafts_accepted": stats1.get("drafts_accepted"),
-            }
-
         if args.timeline and engine.timeline is not None:
             from polykey_tpu.obs.timeline import engine_timelines, to_perfetto
 
@@ -484,221 +427,6 @@ def run_soak(args, overrides: dict = None,
         return result
     finally:
         engine.shutdown()
-
-
-# -- speculative-round A/B soak (ISSUE 19) ------------------------------------
-#
-# Shape: the soak's open-loop Poisson recipe, run three times at the
-# SAME seed with the spec_acceptance_sweep's trained Markov target+draft
-# pair (one alpha, two harnesses):
-#   1. plain      — trained target, no draft (the speedup denominator);
-#   2. host-sync  — speculative rounds under the emulated pre-ISSUE-19
-#                   host-loop crossing schedule (EngineConfig.
-#                   spec_host_sync forces three synchronous packed
-#                   readbacks per round on the SAME device-resident
-#                   math, so the A/B isolates the crossing schedule,
-#                   not the arithmetic);
-#   3. device     — device-resident rounds (the ISSUE 19 tentpole).
-# Gates: host_stall_ms_per_block and dispatch_gap_ms_mean must SHRINK
-# from leg 2 to leg 3, and the device leg's CPU speedup vs plain must
-# beat the PR 4 pre-registered prediction E[tok/round]/(gamma*c+1)
-# evaluated at the measured alpha AND the measured draft-cost ratio c
-# for THIS platform (a timed draft-vs-target single-step microbench;
-# see measure_draft_cost_ratio). PERF.md's c ≈ 0.1 is conditioned on
-# bandwidth-bound decode — the hardware regime — and is recorded in the
-# artifact as the hardware expectation, not used as the CPU gate.
-
-
-def measure_draft_cost_ratio(tcfg, dcfg, target_params, draft_params,
-                             slots: int) -> float:
-    """Measured c for the PR 4 model: draft/target cost ratio of ONE
-    single-token forward at the soak's lane width.
-
-    The pre-registered c ≈ 0.1 (PERF.md) is conditioned on
-    bandwidth-bound decode — the hardware regime, where a quarter-width
-    1-layer draft is nearly free. CPU decode at these tiny shapes is
-    DISPATCH-bound: a draft step costs almost as much as a target step
-    regardless of width, so evaluating the prediction with c = 0.1 on
-    CPU misapplies the model's own stated assumption. Both forwards are
-    jitted, compile-warmed, and timed (median of 30 reps) BEFORE any
-    soak leg runs, so the microbench neither contends with nor
-    contaminates the measured windows."""
-    import jax
-    import jax.numpy as jnp
-    from polykey_tpu.models.transformer import forward, unembed
-
-    def step_ms(cfg, params) -> float:
-        def one_step(p, toks, pos):
-            hidden, _ = forward(p, cfg, toks, pos)
-            return unembed(p, cfg, hidden)
-
-        fn = jax.jit(one_step)
-        toks = jnp.ones((slots, 1), dtype=jnp.int32)
-        pos = jnp.zeros((slots, 1), dtype=jnp.int32)
-        fn(params, toks, pos).block_until_ready()      # compile
-        samples = []
-        for _ in range(30):
-            t0 = time.perf_counter()
-            fn(params, toks, pos).block_until_ready()
-            samples.append(time.perf_counter() - t0)
-        return 1e3 * sorted(samples)[len(samples) // 2]
-
-    t_ms = step_ms(tcfg, target_params)
-    d_ms = step_ms(dcfg, draft_params)
-    return max(0.01, round(d_ms / max(t_ms, 1e-9), 3))
-
-
-def run_spec_ab(args) -> int:
-    if args.timeline:
-        log("--timeline is not supported with --ab-spec (three engines, "
-            "one path); run the modes separately for a Perfetto trace")
-        return 2
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    import spec_acceptance_sweep as sweep
-
-    log(f"=== --ab-spec: training the Markov target+draft pair "
-        f"({args.spec_train_steps} steps each) ===")
-    (tcfg, dcfg, target_params, draft_params,
-     corpus) = sweep.prepare_trained_pair(args.spec_train_steps)
-    c_cpu = measure_draft_cost_ratio(
-        tcfg, dcfg, target_params, draft_params, args.slots)
-    log(f"measured CPU draft-cost ratio c = {c_cpu} "
-        f"(hardware-regime pre-registration uses c = 0.1)")
-
-    spec_over = {
-        "draft_model": "tiny-llama-draft",
-        "spec_gamma": args.spec_gamma,
-    }
-    log("=== leg 1/3: plain (trained target, no draft) ===")
-    plain = run_soak(args, params=target_params, corpus_fn=corpus)
-    # The two SPEC legs share one initial rate — the plain leg's
-    # MEASURED completed throughput with 30% headroom — and then track
-    # the same 2-4x-slots backlog band the plain leg used. Stall/gap
-    # means are load-sensitive, so the A/B equalizes QUEUE PRESSURE
-    # rather than the raw Poisson knob: a fixed rate several times a
-    # leg's capacity looks stricter but grows a multi-thousand-request
-    # backlog whose per-iteration queue overhead dominates
-    # dispatch_gap_ms_mean — measuring queue pathology, not the
-    # crossing schedule under A/B. Both spec legs get the same initial
-    # rate, feedback law, tick cadence, and arrival seed; their offered
-    # loads diverge only as their capacities do, which is exactly the
-    # tok/s delta the artifact reports.
-    spec_args = argparse.Namespace(**vars(args))
-    plain_tput = plain["completed_in_window"] / max(plain["window_s"], 1e-9)
-    spec_args.rate = max(1.0, round(1.3 * plain_tput, 2))
-    spec_args.rate_feedback = True
-    log(f"spec legs start at {spec_args.rate:.1f} arrivals/s (1.3x the "
-        f"plain leg's completed throughput), tracking the plain leg's "
-        f"2-4x-slots backlog band")
-    log("=== leg 2/3: spec, host-loop crossing schedule (emulated) ===")
-    host = run_soak(
-        spec_args,
-        overrides={**spec_over, "spec_host_sync": True},
-        params=target_params, draft_params=draft_params, corpus_fn=corpus)
-    log("=== leg 3/3: spec, device-resident rounds ===")
-    dev = run_soak(spec_args, overrides=spec_over,
-                   params=target_params, draft_params=draft_params,
-                   corpus_fn=corpus)
-
-    alpha = dev["spec"]["acceptance"]
-    g = args.spec_gamma
-    expected_tok = (
-        (1 - alpha ** (g + 1)) / (1 - alpha)
-        if alpha is not None and alpha < 1.0 else float(g + 1)
-    )
-    # One model, two parameterizations: the pre-registered hardware
-    # expectation (c = 0.1, bandwidth-bound decode) goes in the artifact
-    # for the hardware window; the CPU gate evaluates the SAME formula
-    # at this platform's measured c, because PR 4's c ≈ 0.1 explicitly
-    # assumes a regime CPU dispatch does not live in.
-    predicted_hw = expected_tok / (g * 0.1 + 1)
-    predicted_cpu = expected_tok / (g * c_cpu + 1)
-    speedup = (
-        round(dev["tok_s"] / plain["tok_s"], 3)
-        if plain["tok_s"] else None
-    )
-    result = {
-        "mode": "ab_spec",
-        "spec_gamma": g,
-        "train_steps": args.spec_train_steps,
-        "plain": plain,
-        "spec_host_sync": host,
-        "spec_device_resident": dev,
-        "alpha": alpha,
-        # The acceptance numbers: the host tax the device-resident round
-        # removes, at equal offered load and seed ...
-        "host_stall_ms_per_block_host_sync": host["host_stall_ms_per_block"],
-        "host_stall_ms_per_block_device": dev["host_stall_ms_per_block"],
-        "host_stall_shrink_ms": round(
-            host["host_stall_ms_per_block"]
-            - dev["host_stall_ms_per_block"], 3),
-        "dispatch_gap_ms_mean_host_sync": host["dispatch_gap_ms_mean"],
-        "dispatch_gap_ms_mean_device": dev["dispatch_gap_ms_mean"],
-        "dispatch_gap_shrink_ms": round(
-            host["dispatch_gap_ms_mean"] - dev["dispatch_gap_ms_mean"],
-            3),
-        # ... and the speedup vs the PR 4 pre-registered model at the
-        # measured alpha (PERF.md: speedup = E[tok/round]/(gamma*c+1)).
-        # c = 0.1 is the bandwidth-bound hardware expectation;
-        # cpu_draft_cost_ratio is the microbenched c for THIS host, and
-        # the gate compares against the prediction evaluated there.
-        "tok_s_plain": plain["tok_s"],
-        "tok_s_spec_device": dev["tok_s"],
-        "cpu_speedup_vs_plain": speedup,
-        "expected_tokens_per_round": round(expected_tok, 3),
-        "pr4_predicted_speedup_at_alpha_hw": round(predicted_hw, 3),
-        "cpu_draft_cost_ratio": c_cpu,
-        "cpu_predicted_speedup_at_alpha": round(predicted_cpu, 3),
-        "break_even_alpha_at_gamma4": 0.45,
-    }
-
-    out_path = args.out or os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "perf",
-        f"spec_ab_soak_{time.strftime('%Y-%m-%d', time.gmtime())}.json",
-    )
-    with open(out_path, "w") as f:
-        json.dump(result, f, indent=1)
-        f.write("\n")
-    log(f"wrote {out_path}")
-    print(json.dumps(result))
-
-    ok = True
-    failures = sum(r["failed_in_window"] for r in (plain, host, dev))
-    if failures:
-        log(f"FAIL: {failures} requests errored inside the windows")
-        ok = False
-    for leg in (host, dev):
-        if not leg["spec"]["drafts_proposed"]:
-            log("FAIL: a spec leg proposed zero drafts — the rounds "
-                "were not speculative")
-            ok = False
-    if result["host_stall_shrink_ms"] <= 0:
-        log(f"FAIL: host_stall_ms_per_block did not shrink "
-            f"({host['host_stall_ms_per_block']} -> "
-            f"{dev['host_stall_ms_per_block']})")
-        ok = False
-    if result["dispatch_gap_shrink_ms"] <= 0:
-        log(f"FAIL: dispatch_gap_ms_mean did not shrink "
-            f"({host['dispatch_gap_ms_mean']} -> "
-            f"{dev['dispatch_gap_ms_mean']})")
-        ok = False
-    if speedup is None or speedup <= predicted_cpu:
-        log(f"FAIL: CPU speedup {speedup} did not beat the PR 4 "
-            f"prediction {predicted_cpu:.3f} at alpha={alpha} and "
-            f"measured c={c_cpu} (hardware-regime prediction at c=0.1 "
-            f"would be {predicted_hw:.3f})")
-        ok = False
-    if ok:
-        log(f"OK: alpha={alpha}, c={c_cpu} -> speedup {speedup}x vs "
-            f"plain (PR 4 prediction {predicted_cpu:.3f}x at measured "
-            f"c; {predicted_hw:.3f}x at hardware c=0.1); "
-            f"host_stall/block "
-            f"{host['host_stall_ms_per_block']} -> "
-            f"{dev['host_stall_ms_per_block']} ms, dispatch gap "
-            f"{host['dispatch_gap_ms_mean']} -> "
-            f"{dev['dispatch_gap_ms_mean']} ms")
-    return 0 if ok else 1
 
 
 # -- host-memory KV tier soak (ISSUE 15) --------------------------------------

@@ -170,9 +170,7 @@ def serve(config, params, draft_params, prompts, max_new, temperature,
 
 def prepare_trained_pair(steps: int):
     """Register `tiny-llama-draft` and train the correlated target/draft
-    pair on the Markov corpus. Shared with `occupancy_soak.py --ab-spec`
-    (ISSUE 19) so the 48-slot A/B measures the SAME pair this sweep
-    pre-registers — one alpha, two harnesses. Returns
+    pair on the Markov corpus. Returns
     (target_cfg, draft_cfg, target_params, draft_params, corpus_fn)."""
     from polykey_tpu.models.config import MODEL_REGISTRY, TINY_LLAMA
 

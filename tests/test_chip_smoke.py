@@ -64,19 +64,15 @@ def test_tiny_rehearsal_passes_and_a_failed_request_fails_it():
 
 
 def test_refuses_without_starting_a_server(tmp_path):
-    """The clauses decided before any child starts: a kill switch in the
-    environment, and a full-size run pinned to the CPU."""
-    for args, env in (
-        (["--tiny"], _env(POLYKEY_DISABLE_FLASH="1")),
-        ([], _env()),                       # JAX_PLATFORMS=cpu, no --tiny
-    ):
-        run = subprocess.run(
-            [sys.executable, SMOKE, *args], env=env, cwd=ROOT,
-            capture_output=True, text=True, timeout=120,
-        )
-        assert run.returncode != 0
-        assert _last_json(run.stdout) is None
-        assert "chip_smoke FAILED" in run.stderr
+    """The clause decided before any child starts: a full-size run pinned
+    to the CPU (JAX_PLATFORMS=cpu, no --tiny)."""
+    run = subprocess.run(
+        [sys.executable, SMOKE], env=_env(), cwd=ROOT,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert run.returncode != 0
+    assert _last_json(run.stdout) is None
+    assert "chip_smoke FAILED" in run.stderr
 
 
 def test_fails_alone_in_a_directory(tmp_path):

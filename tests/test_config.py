@@ -175,6 +175,33 @@ def test_engine_config_int4_env(monkeypatch):
     cfg.validate()
 
 
+def test_lookahead_has_one_environment_name(monkeypatch):
+    """POLYKEY_DISPATCH_LOOKAHEAD (DEPLOY.md's row) is the only name the
+    pipeline depth is read from; the alias that used to stand beside it
+    is not a knob."""
+    from polykey_tpu.engine.config import EngineConfig
+
+    monkeypatch.delenv("POLYKEY_DISPATCH_LOOKAHEAD", raising=False)
+    monkeypatch.setenv("POLYKEY_LOOKAHEAD", "5")
+    assert EngineConfig.from_env().lookahead_blocks == \
+        EngineConfig.lookahead_blocks
+    monkeypatch.setenv("POLYKEY_DISPATCH_LOOKAHEAD", "3")
+    assert EngineConfig.from_env().lookahead_blocks == 3
+
+
+def test_engine_config_has_no_host_sync_emulation():
+    """The speculative round has one crossing schedule, the device-
+    resident one; the field that emulated the older one for an A/B is not
+    part of the configuration."""
+    import dataclasses
+
+    from polykey_tpu.engine.config import EngineConfig
+
+    names = {f.name for f in dataclasses.fields(EngineConfig)}
+    assert "spec_host_sync" not in names
+    assert {"spec_gamma", "adaptive_gamma", "draft_model"} <= names
+
+
 def _record_config_updates(monkeypatch):
     """Swap jax.config.update for a recorder (the test process's real
     JAX config must not change under the other tests)."""

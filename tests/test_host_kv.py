@@ -118,7 +118,7 @@ def test_host_pool_alloc_release_balance():
 
 def test_cache_tier_moves_and_probe_weighting():
     cfg = get_config("tiny-llama")
-    alloc = BlockAllocator(32, prefer_native=False)
+    alloc = BlockAllocator(32)
     host = HostKVPool(cfg, capacity_pages=8, page_size=4,
                       dtype=np.float32, quantized=False)
     cache = PrefixCache(alloc, page_size=4, capacity_pages=16,
@@ -161,7 +161,7 @@ def test_cache_tier_moves_and_probe_weighting():
 
 def test_cache_host_lru_pressure_drops_oldest():
     cfg = get_config("tiny-llama")
-    alloc = BlockAllocator(64, prefer_native=False)
+    alloc = BlockAllocator(64)
     host = HostKVPool(cfg, capacity_pages=2, page_size=4,
                       dtype=np.float32, quantized=False)
     cache = PrefixCache(alloc, page_size=4, capacity_pages=32,
@@ -202,7 +202,7 @@ def test_evict_for_never_sacrifices_host_entries():
     host entry frees no device page, so an unsatisfiable demand must
     not wipe the warm host tier for nothing."""
     cfg = get_config("tiny-llama")
-    alloc = BlockAllocator(32, prefer_native=False)
+    alloc = BlockAllocator(32)
     host = HostKVPool(cfg, capacity_pages=4, page_size=4,
                       dtype=np.float32, quantized=False)
     cache = PrefixCache(alloc, page_size=4, capacity_pages=32,
@@ -256,7 +256,7 @@ def test_state_store_roundtrip_and_params_gate(tmp_path):
                              params_key="abc", quantized=False)
     store.save_batch(keys, k, v, None, None)
 
-    alloc = BlockAllocator(16, prefer_native=False)
+    alloc = BlockAllocator(16)
     host = HostKVPool(cfg, capacity_pages=4, page_size=8,
                       dtype=np.float32, quantized=False)
     cache = PrefixCache(alloc, page_size=8, capacity_pages=16,
@@ -299,7 +299,7 @@ def test_state_store_restart_does_not_clobber(tmp_path):
     blobs = [n for n in os.listdir(tmp_path) if n.endswith(".pkkv")]
     assert len(blobs) == 2, "second incarnation clobbered the first"
 
-    alloc = BlockAllocator(16, prefer_native=False)
+    alloc = BlockAllocator(16)
     host = HostKVPool(cfg, capacity_pages=4, page_size=8,
                       dtype=np.float32, quantized=False)
     cache = PrefixCache(alloc, page_size=8, capacity_pages=16,

@@ -584,27 +584,30 @@ def test_flash_kernel_shard_mapped_on_mesh():
     assert float(jnp.max(jnp.abs(ref - out))) < TOL
 
 
-def test_kernel_kill_switches(monkeypatch):
-    """POLYKEY_DISABLE_PAGED_KERNEL / POLYKEY_DISABLE_FLASH force the jnp
-    paths regardless of backend — the operational escape hatch if a
-    Mosaic compile regresses on new hardware. The backend is patched to
-    "tpu" so the env check is what flips the result (on CPU both
-    predicates are False anyway and the asserts would be vacuous)."""
+@pytest.mark.parametrize("name", [
+    "POLYKEY_DISABLE_FLASH",
+    "POLYKEY_DISABLE_PAGED_KERNEL",
+    "POLYKEY_DISABLE_KV_KERNEL",
+])
+def test_kernel_gates_ignore_the_environment(monkeypatch, name):
+    """The kernels' gates decide from the backend and the geometry alone:
+    the three kill switches that used to be read here are gone, and
+    setting one changes nothing. The backend is patched to "tpu" (on the
+    CPU every gate is False and the asserts would be vacuous)."""
     from polykey_tpu.ops import flash_attention as fa
     from polykey_tpu.ops import paged_attention_kernel as pak
 
     monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(pak.jax, "default_backend", lambda: "tpu")
-    assert pak.use_paged_kernel(8, 128)
-    assert fa.use_flash(512, 512, 128)
-    for v in ("1", "true"):
-        monkeypatch.setenv("POLYKEY_DISABLE_PAGED_KERNEL", v)
-        monkeypatch.setenv("POLYKEY_DISABLE_FLASH", v)
-        assert not pak.use_paged_kernel(8, 128)
-        assert not fa.use_flash(512, 512, 128)
-    monkeypatch.delenv("POLYKEY_DISABLE_PAGED_KERNEL")
-    monkeypatch.delenv("POLYKEY_DISABLE_FLASH")
-    assert pak.use_paged_kernel(8, 128)
+    # The int8-KV paths consult the same gate as the fp ones.
+    assert not hasattr(pak, "use_quantized_paged_kernel")
+    for value in ("1", "true"):
+        monkeypatch.setenv(name, value)
+        assert fa.use_flash(512, 512, 128)
+        assert pak.use_paged_kernel(8, 128)
+    # The geometry rule still holds with the name set.
+    assert not pak.use_paged_kernel(3, 40)
+    assert not fa.use_flash(64, 64, 128)
 
 
 def test_paged_decode_fallback_off_tpu():

@@ -16,15 +16,9 @@ from polykey_tpu.models.transformer import forward, forward_paged, init_params
 from polykey_tpu.ops.paged_attention import paged_gather_kv, paged_write
 
 
-@pytest.fixture(params=["python", "native"])
-def allocator_factory(request):
-    prefer_native = request.param == "native"
-    def make(num_pages):
-        alloc = BlockAllocator(num_pages, prefer_native=prefer_native)
-        if prefer_native and not alloc.is_native:
-            pytest.skip("native allocator not built (run `make native`)")
-        return alloc
-    return make
+@pytest.fixture
+def allocator_factory():
+    return BlockAllocator
 
 
 def test_alloc_release_cycle(allocator_factory):

@@ -609,6 +609,16 @@ def test_self_run_repo_is_clean_under_committed_baseline(capsys):
     assert rc == 0, f"polylint found blocking findings:\n{out}"
 
 
+def test_default_targets_all_exist():
+    """The tiers skip a default target the tree lacks, silently: a
+    target that names a deleted file would be linted by nobody."""
+    from polykey_tpu.analysis.core import DEFAULT_TARGETS
+
+    assert DEFAULT_TARGETS
+    missing = [t for t in DEFAULT_TARGETS if not (REPO_ROOT / t).exists()]
+    assert not missing, f"DEFAULT_TARGETS names what is not there: {missing}"
+
+
 def test_committed_baseline_is_empty_or_justified():
     data = load_baseline(REPO_ROOT / "polylint-baseline.json")
     # Growth contract: debt goes in with an explicit rule/path record,

@@ -72,7 +72,7 @@ def _serve(config, prompts, max_new=8):
 
 
 def test_cache_lookup_never_matches_full_prompt():
-    alloc = BlockAllocator(32, prefer_native=False)
+    alloc = BlockAllocator(32)
     cache = PrefixCache(alloc, page_size=4, capacity_pages=8)
     ids = np.arange(8, dtype=np.int32)          # exactly 2 pages
     pages = alloc.alloc(2)
@@ -89,7 +89,7 @@ def test_cache_lookup_never_matches_full_prompt():
 
 
 def test_cache_divergent_prefixes_do_not_collide():
-    alloc = BlockAllocator(32, prefer_native=False)
+    alloc = BlockAllocator(32)
     cache = PrefixCache(alloc, page_size=4, capacity_pages=8)
     a = np.array([1, 2, 3, 4, 5, 6, 7, 8, 9], dtype=np.int32)
     b = np.array([1, 2, 3, 4, 9, 9, 9, 9, 9], dtype=np.int32)  # page 1 differs
@@ -100,7 +100,7 @@ def test_cache_divergent_prefixes_do_not_collide():
 
 
 def test_cache_eviction_frees_pages():
-    alloc = BlockAllocator(16, prefer_native=False)
+    alloc = BlockAllocator(16)
     cache = PrefixCache(alloc, page_size=4, capacity_pages=4)
     free0 = alloc.num_free
     for seed in range(4):
