@@ -690,6 +690,55 @@ class EngineConfig:
                         f"and the prefill chunk (got {b})"
                     )
         self._refuse_for_recurrent_state()
+        self._refuse_for_latent_pool()
+
+    def _served_model(self):
+        """The ModelConfig this engine would serve, looked up when the
+        config is validated (a name the registry does not know cannot be
+        cleared by either refusal below and is refused itself)."""
+        from ..models.config import get_config
+
+        try:
+            return get_config(self.model)
+        except KeyError as e:
+            raise ValueError(e.args[0]) from None
+
+    def _refuse_for_latent_pool(self) -> None:
+        """A latent-attention model (ModelConfig.latent_kv: "A" layers)
+        keeps ONE row a token and layer in a one-part page pool
+        (engine/kv_cache.py) and is served by the plain prefill / decode
+        pair on one device, with or without the prefix cache (cached
+        pages are pages, whatever their parts). Everything that copies a
+        page out of the device pool, reads it in another form or splits
+        it over devices knows two-part K/V pages only and is refused
+        here, in one place, rather than half-ported."""
+        model = self._served_model()
+        if not model.latent_kv:
+            return
+        refused = {
+            "host_kv_bytes (the host tier holds K and V pages with the "
+            "heads apart)": self.host_kv_bytes > 0,
+            "disagg / disagg_tier (the KV handoff's wire format ships K "
+            "and V)": bool(self.disagg or self.disagg_tier),
+            "draft_model (the speculative pair verifies over K/V pools)":
+                self.draft_model is not None,
+            "kv_dtype=int8 (no quantized form of a latent row)":
+                self.kv_dtype == "int8",
+            "quantize (no quantized weights for a layer pattern yet)":
+                self.quantize,
+            "tp/dp/ep/sp/pp/num_slices > 1 (a latent row cannot be split "
+            "by heads, and its kernels run on one device)":
+                max(self.tp, self.dp, self.ep, self.sp, self.pp,
+                    self.num_slices) > 1,
+        }
+        for what, on in refused.items():
+            if on:
+                raise ValueError(
+                    f"{self.model} keeps a latent pool (one "
+                    f"{model.latent_width}-wide row a token and layer, "
+                    "engine/kv_cache.py: a one-part page); not supported "
+                    f"with it: {what}"
+                )
 
     def _refuse_for_recurrent_state(self) -> None:
         """A model with per-slot recurrent state (ModelConfig.stateful:
@@ -703,12 +752,7 @@ class EngineConfig:
         (InferenceEngine.__init__), so a ModelConfig registered after this
         EngineConfig was built is seen; a name the registry does not know
         cannot be cleared and is refused too."""
-        from ..models.config import get_config
-
-        try:
-            model = get_config(self.model)
-        except KeyError as e:
-            raise ValueError(e.args[0]) from None
+        model = self._served_model()
         if not model.stateful:
             return
         refused = {

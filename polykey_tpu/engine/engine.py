@@ -892,6 +892,12 @@ class InferenceEngine:
             )
 
         self.paged = new_pool(self.model_cfg)
+        # Host-known facts of the pool for `stats` (the arrays are donated
+        # dispatch by dispatch): its bytes, and one token's over all layers.
+        self._kv_pool_bytes = sum(
+            x.nbytes for x in jax.tree.leaves(self.paged))
+        self._kv_token_bytes = self._kv_pool_bytes // (
+            config.num_pages * config.page_size)
         # Expert layers of a layer pattern: a decode block of such a model
         # brings home the held experts its live lanes chose (_decode_fn).
         self._expert_layers = self.model_cfg.layer_pattern.count("E")
@@ -1482,6 +1488,10 @@ class InferenceEngine:
                 # Bytes of per-slot recurrent state beside the pool
                 # (kv_cache.SlotState; 0 for a model that has none).
                 "state_pool_bytes": self.state.nbytes,
+                # The page pool itself, and what ONE token holds in it
+                # over all layers: pages in use read in bytes.
+                "kv_pool_bytes": self._kv_pool_bytes,
+                "kv_token_bytes": self._kv_token_bytes,
                 "queued": self._submit.qsize(),
                 "inflight_blocks": len(self._inflight_q),
                 "prefill_budget": self._prefill_budget,

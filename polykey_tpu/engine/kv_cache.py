@@ -27,6 +27,19 @@ apart, [..., Hk, D]; `fold_pages` / `unfold_pages` convert page-sized host
 arrays at that boundary, so no byte of a host page or a wire blob follows
 the device's layout.
 
+A page's PARTS are a fact of the model (ModelConfig.kv_parts,
+kv_row_width): K and V, two parts of Hk·D columns, as above — or, for a
+latent-attention pattern ("A" layers, models/hybrid.py), ONE part: a page
+is [page_size, W], a token's single latent row (its normed latent beside
+the one rotary key all heads share, zero columns up to whole 128-lane
+tiles: W = 640 for the published 512 + 64), the array
+[num_layers, num_pages, 1, page_size, W], carried as [L·N, page_size, W]
+with page p at entry p. No per-head K or V of such a model is ever stored:
+the write paths take the one row, the decode kernel reads a page once for
+all heads (ops/paged_attention_kernel.py `mla_latent_decode`). The host
+tier and the wire format know two-part pages only, and a latent pool is
+refused with them (engine/config.py `_refuse_for_latent_pool`).
+
 The allocator is host-side bookkeeping: a refcounted free list.
 Page 0 is reserved as the garbage page — inactive decode
 slots point at it so masked lanes always have a safe write target.
@@ -99,9 +112,10 @@ class BlockAllocator:
 
 @struct.dataclass
 class PagedKV:
-    """Device-side page pool: kv [L, num_pages, 2, page_size, Hk·D] (K and V
-    of a page side by side, heads folded into lanes — the stored layout,
-    module docstring).
+    """Device-side page pool: kv [L, num_pages, parts, page_size, W] — K
+    and V of a page side by side, heads folded into lanes (parts 2,
+    W = Hk·D), or one latent row a token (parts 1): the stored layout,
+    module docstring.
 
     With int8 KV (EngineConfig.kv_dtype="int8") kv holds int8 values and
     ks/vs hold per-(token, head) bf16 scales [L, num_pages, page_size, Hk]
@@ -185,10 +199,14 @@ def init_paged_kv(
 ) -> PagedKV:
     """`kv_dtype=jnp.int8` builds quantized pools (+ bf16 scale pools);
     None keeps the full-precision layout in `dtype`. One pool layer for
-    each layer that attends (a hybrid stack's "*" layers)."""
-    shape = (cfg.kv_layers, num_pages, 2, page_size,
-             cfg.num_kv_heads * cfg.head_dim)
+    each layer that attends (a layer pattern's "*" or "A" layers); a
+    page's parts and a row's width are the model's (ModelConfig.kv_parts,
+    kv_row_width: K and V, or one latent row)."""
+    shape = (cfg.kv_layers, num_pages, cfg.kv_parts, page_size,
+             cfg.kv_row_width)
     if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
+        if cfg.latent_kv:
+            raise ValueError("a latent pool has no int8 form")
         sshape = (cfg.kv_layers, num_pages, page_size, cfg.num_kv_heads)
         return PagedKV(
             kv=jnp.zeros(shape, jnp.int8),
@@ -226,18 +244,20 @@ def kv_pool_bytes(
     cfg: ModelConfig, num_pages: int, page_size: int, dtype=jnp.bfloat16,
     kv_dtype=None,
 ) -> int:
+    """Bytes of the pool `init_paged_kv` allocates: every part of every
+    page of every pool layer (int8: values and a bf16 scale a head)."""
     if kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8:
-        per_slot = cfg.num_kv_heads * (cfg.head_dim * 1 + 2)  # values + scale
+        per_part = cfg.num_kv_heads * (cfg.head_dim * 1 + 2)  # values + scale
     else:
-        per_slot = cfg.num_kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize
-    return 2 * cfg.kv_layers * num_pages * page_size * per_slot
+        per_part = cfg.kv_row_width * jnp.dtype(dtype).itemsize
+    return cfg.kv_parts * cfg.kv_layers * num_pages * page_size * per_part
 
 
 def host_kv_page_bytes(
     cfg: ModelConfig, page_size: int, dtype=jnp.bfloat16, kv_dtype=None,
 ) -> int:
-    """Bytes ONE page occupies in the host tier (K + V across all layers,
-    plus the bf16 scale rows for int8 pools) — the unit
+    """Bytes ONE page occupies in the host tier (a page's parts across all
+    layers, plus the bf16 scale rows for int8 pools) — the unit
     POLYKEY_HOST_KV_BYTES divides into a page capacity."""
     return kv_pool_bytes(cfg, 1, page_size, dtype, kv_dtype)
 

@@ -155,8 +155,10 @@ def weight_resident_bytes(cfg: ModelConfig, dtype: str, quantize: bool,
 
 
 def kv_bytes_per_token(cfg: ModelConfig, kv_dtype: str) -> float:
-    """KV bytes one cached token occupies across all layers (K + V)."""
-    return (2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim
+    """Bytes one cached token occupies across the layers that attend: a
+    page's parts (K and V, or a latent model's one row) at the row's stored
+    width."""
+    return (cfg.kv_parts * cfg.kv_layers * cfg.kv_row_width
             * _bytes_per_el(kv_dtype))
 
 
@@ -166,14 +168,16 @@ def kv_pool_bytes_split(cfg: ModelConfig, num_pages: int, page_size: int,
     in HBM. Mirrors kv_cache.init_paged_kv's allocation exactly (a test
     pins the two byte-for-byte): int8 pools carry a bf16 scale per
     (k|v, head, token slot) alongside the int8 values; wider dtypes have
-    no scale plane. Pure model/geometry arithmetic — memlint's capacity
-    ledger calls this without importing jax."""
-    slots = 2.0 * cfg.num_layers * num_pages * page_size  # k + v planes
+    no scale plane. A page's parts, the layers that own a pool layer and a
+    row's width are the model's (ModelConfig.kv_parts, kv_layers,
+    kv_row_width), as the allocator reads them. Pure model/geometry
+    arithmetic — memlint's capacity ledger calls this without importing
+    jax."""
+    slots = float(cfg.kv_parts) * cfg.kv_layers * num_pages * page_size
     if kv_dtype == "int8":
         return (slots * cfg.num_kv_heads * cfg.head_dim * 1.0,
                 slots * cfg.num_kv_heads * 2.0)
-    return (slots * cfg.num_kv_heads * cfg.head_dim
-            * _bytes_per_el(kv_dtype), 0.0)
+    return slots * cfg.kv_row_width * _bytes_per_el(kv_dtype), 0.0
 
 
 def kv_pool_bytes_spec(cfg: ModelConfig, num_pages: int, page_size: int,

@@ -587,6 +587,36 @@ class TpuService(Service):
             )
         yield "done", timings
 
+    @staticmethod
+    def _queued_as_one(request: GenRequest, events):
+        """`_text_events` with the deltas of tokens the engine has ALREADY
+        queued joined into one: a decode block hands a stream its K
+        tokens at one instant, and a message a token is K messages (K
+        wake-ups of this thread, K of the client's) where one carries the
+        same text at the same instant — 3,600 messages a second on 64
+        lanes, most of a core on either side (PERF.md §6, PR 55). Text
+        waits for nothing: a delta goes out as soon as the request's
+        queue is empty, so a first token, or a stream decoded a token a
+        step, is a message a token as before. On an engine error the
+        text held here goes out first: the resume trailer counts it as
+        delivered."""
+        held: list[str] = []
+        try:
+            for kind, value in events:
+                if kind == "delta":
+                    held.append(value)
+                    if not request.out.empty():
+                        continue
+                if held:
+                    yield "delta", "".join(held)
+                    held.clear()
+                if kind != "delta":
+                    yield kind, value
+        except Exception:
+            if held:
+                yield "delta", "".join(held)
+            raise
+
     # -- Service interface --------------------------------------------------
 
     def _engine_profile(self, parameters) -> pk.ExecuteToolResponse:
@@ -745,7 +775,9 @@ class TpuService(Service):
 
         timings = None
         try:
-            for kind, value in self._text_events(request, stops, skip):
+            for kind, value in self._queued_as_one(
+                request, self._text_events(request, stops, skip)
+            ):
                 if kind == "delta":
                     yield pk.ExecuteToolStreamChunk(delta=value)
                 else:

@@ -45,8 +45,8 @@ class ModelConfig:
     # Hybrid stacks (models/hybrid.py): one character an entry, and an
     # entry is an operator OR a feed-forward part alone, under one norm —
     # "M" a Mamba-2 mixer, "C" a gated short convolution, "L" a gated
-    # delta-rule linear attention, "*" attention, "E" an expert layer,
-    # "D" a dense gated MLP. A published layer that
+    # delta-rule linear attention, "*" attention, "A" latent attention,
+    # "E" an expert layer, "D" a dense gated MLP. A published layer that
     # holds an operator AND a feed-forward part under two norms is two
     # entries ("CD", "*E"), and `num_layers` counts entries.
     # Empty: the homogeneous attention+MLP block above, scanned.
@@ -65,6 +65,22 @@ class ModelConfig:
     # Every RMSNorm of a pattern but the delta body's gated one: the gain
     # is `norm_offset + w` (1.0: the zero-centred norm, w starts at 0).
     norm_offset: float = 0.0
+    # A second RMSNorm of an entry, on the body's OUTPUT before the
+    # residual add: x ← x + Norm_post(f(Norm(x))), a gain of its own.
+    sandwich_norm: bool = False
+    # "A" (latent attention, MLA): the query through a rank-`q_lora_rank`
+    # bottleneck with a norm, heads of `qk_nope_head_dim` + 
+    # `qk_rope_head_dim` (the rotary embedding turns the second part);
+    # what a token leaves in the cache is ONE row a layer, its normed
+    # rank-`kv_lora_rank` latent beside ONE rotary key of
+    # `qk_rope_head_dim` shared by all heads; a head's key is its own
+    # expansion of the latent beside that key, its value an expansion of
+    # `v_head_dim`.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # "M": H heads x P dims, state [H, P, N] per sequence, G groups share
     # B and C, a causal depthwise conv of `conv_kernel` taps over x|B|C.
     mamba_num_heads: int = 0
@@ -132,10 +148,37 @@ class ModelConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that own a K/V pool."""
+        """Layers that own a layer of the page pool."""
         if self.layer_pattern:
-            return self.layer_pattern.count("*")
+            return (self.layer_pattern.count("*")
+                    + self.layer_pattern.count("A"))
         return self.num_layers
+
+    @property
+    def latent_kv(self) -> bool:
+        """The pool holds latent rows, not K and V ("A" layers)."""
+        return "A" in self.layer_pattern
+
+    @property
+    def kv_parts(self) -> int:
+        """Entries a page of the pool holds: K and V, or one latent row a
+        token."""
+        return 1 if self.latent_kv else 2
+
+    @property
+    def latent_width(self) -> int:
+        """A latent row as published: the latent beside the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def kv_row_width(self) -> int:
+        """Columns of a row of a page's part: the K (or V) heads folded, or
+        a latent row padded with zero columns to whole 128-lane tiles (the
+        TPU lays a narrower row out that wide in HBM anyway, and the
+        kernels' DMAs take whole tiles)."""
+        if self.latent_kv:
+            return -(-self.latent_width // 128) * 128
+        return self.num_kv_heads * self.head_dim
 
     @property
     def mamba_inner(self) -> int:
@@ -161,11 +204,16 @@ class ModelConfig:
         if not self.layer_pattern:
             return
         if len(self.layer_pattern) != self.num_layers or \
-                set(self.layer_pattern) - set("MCL*ED"):
+                set(self.layer_pattern) - set("MCL*AED"):
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r} must be num_layers="
-                f"{self.num_layers} characters of 'M', 'C', 'L', '*', 'E', "
-                f"'D'"
+                f"{self.num_layers} characters of 'M', 'C', 'L', '*', 'A', "
+                f"'E', 'D'"
+            )
+        if "A" in self.layer_pattern and "*" in self.layer_pattern:
+            raise ValueError(
+                f"layer_pattern {self.layer_pattern!r} mixes 'A' and '*': "
+                "the page pool has one geometry, latent rows or K and V"
             )
         if "E" in self.layer_pattern and not (
             0 < self.experts_held
@@ -181,6 +229,8 @@ class ModelConfig:
     def q_scale(self) -> float:
         if self.query_pre_attn_scalar is not None:
             return self.query_pre_attn_scalar**-0.5
+        if self.latent_kv:
+            return (self.qk_nope_head_dim + self.qk_rope_head_dim)**-0.5
         return self.head_dim**-0.5
 
     def num_params(self) -> int:
@@ -210,6 +260,13 @@ class ModelConfig:
                 "*": h * self.head_dim * (
                     (3 if self.attn_output_gate else 2) * self.num_heads
                     + 2 * self.num_kv_heads),
+                "A": h * (self.q_lora_rank + self.latent_width)
+                + self.num_heads * (
+                    self.q_lora_rank
+                    * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                    + self.kv_lora_rank
+                    * (self.qk_nope_head_dim + self.v_head_dim)
+                    + self.v_head_dim * h),
                 "E": h * self.n_routed_experts + experts,
                 "D": 3 * h * self.dense_intermediate_size,
             }
@@ -497,6 +554,35 @@ TINY_QWEN3_NEXT = ModelConfig(
     shared_expert_gate=True,
 )
 
+# A latent-attention (MLA) pattern at toy size: a leading dense layer, then
+# latent attention over sigmoid-routed gated experts with a plain shared
+# expert, half the routed experts held, a post-norm on every body.
+TINY_PANGU = ModelConfig(
+    name="tiny-pangu",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=32,
+    num_layers=6,
+    num_heads=4,
+    num_kv_heads=1,
+    head_dim=24,
+    max_seq_len=512,
+    rope_theta=25_600_000.0,
+    layer_pattern="ADAEAE",
+    sandwich_norm=True,
+    q_lora_rank=48,
+    kv_lora_rank=32,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    dense_intermediate_size=96,
+    n_routed_experts=16,
+    experts_held=8,
+    num_experts_per_tok=4,
+    moe_shared_intermediate=32,
+    routed_scaling_factor=2.5,
+)
+
 # A mid-size llama for single-chip benchmarking without 8B's 16 GiB of bf16
 # weights (v5e has 16 GiB HBM; 8B serves in int8 — see engine docs).
 LLAMA_1B_BENCH = replace(LLAMA32_1B, name="llama-1b-bench")
@@ -532,6 +618,7 @@ MODEL_REGISTRY = {
         TINY_HYBRID,
         TINY_LFM2,
         TINY_QWEN3_NEXT,
+        TINY_PANGU,
         LLAMA_1B_BENCH,
         MIXTRAL_BENCH,
     )

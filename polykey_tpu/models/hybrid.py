@@ -1,9 +1,11 @@
 """Hybrid stacks: a layer pattern that is data (ModelConfig.layer_pattern).
 
-Each entry is `x ← x + f(RMSNorm(x))` with ONE of six bodies, chosen by
+Each entry is `x ← x + f(RMSNorm(x))` with ONE of seven bodies, chosen by
 its character of the pattern; a published layer that is an operator and a
 feed-forward part under two norms is two entries (the norm's gain is
-`cfg.norm_offset + w`: 1 + w is the zero-centred norm):
+`cfg.norm_offset + w`: 1 + w is the zero-centred norm). Where
+`cfg.sandwich_norm`, an entry is `x ← x + RMSNorm_post(f(RMSNorm(x)))`: a
+second gain, on the body's output before the residual add:
 
 - "M", a Mamba-2 mixer: `[z | xBC | dt] = W_in u`; xBC through a causal
   depthwise conv and silu, split into x [H, P], B and C [G, N];
@@ -31,6 +33,20 @@ feed-forward part under two norms is two entries (the norm's gain is
   position embedding over the leading `cfg.rotary_dim` of a head (none
   where `cfg.use_rope` is off), and the context multiplied by the sigmoid
   of a gate that W_q yields beside the query where `cfg.attn_output_gate`.
+- "A", latent attention (MLA) over a pool of ONE row a token:
+  `c_q = RMSNorm(W_dq h)`, a head's query `[q_nope | q_rope] = W_uq,i c_q`
+  with the rotary embedding on q_rope; `[c | k_r] = W_dkv h`,
+  `c ← RMSNorm(c)`, `k_r ← RoPE(k_r)`, ONE rotary key for all heads. The
+  row `[c | k_r]` is all the pool keeps (engine/kv_cache.py: a one-part
+  page). A head's key is `[W_uk,i c | k_r]` and its value `W_uv,i c`, and
+  neither is ever built: the query absorbs W_uk
+  (`q̃_i = [W_uk,iᵀ q_nope,i | q_rope,i]`), the scores are `q̃_i · row /
+  √(nope + rope)`, the context is `W_uv,i Σ_s p_s c_s` — the same
+  function, with the token's one row read once for all heads
+  (ops/paged_attention_kernel.py `mla_latent_decode` at a decode step,
+  ops/paged_attention.py `latent_attention` over the gathered table for a
+  prefill window). A pattern attends through "A" or through "*", not both:
+  the pool has one geometry.
 - "E", an expert layer (ops/moe.py `moe_held`): latent un-gated experts
   with a shared expert, or gated experts on the full hidden, with or
   without a gated shared expert.
@@ -65,7 +81,7 @@ from .layers import init_mlp_params, mlp, qkv_project, rms_norm, rope
 from .quant import embed_lookup, qdot
 
 KINDS = {"M": "mamba", "C": "conv", "L": "delta", "*": "attention",
-         "E": "moe", "D": "dense"}
+         "A": "latent", "E": "moe", "D": "dense"}
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 # Where a prefill row's state starts (PrefillRows.source).
@@ -114,6 +130,9 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
     h = cfg.hidden_size
     # The effective gain is 1 (w = 0 under the zero-centred norm).
     gain = jnp.full((h,), 1.0 - cfg.norm_offset, dtype)
+    norms = {"norm": gain}
+    if cfg.sandwich_norm:
+        norms["post_norm"] = gain
     k = jax.random.split(key, 8)
 
     def step_and_decay(heads):
@@ -131,7 +150,7 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
     if kind == "mamba":
         inner, heads = cfg.mamba_inner, cfg.mamba_num_heads
         return {
-            "norm": gain,
+            **norms,
             "w_in": _normal(k[0], (h, inner + cfg.conv_dim + heads), dtype, h),
             "conv_w": _normal(k[1], (cfg.conv_kernel, cfg.conv_dim), dtype,
                               cfg.conv_kernel),
@@ -143,7 +162,7 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
         }
     if kind == "conv":
         return {
-            "norm": gain,
+            **norms,
             "w_in": _normal(k[0], (h, 3 * h), dtype, h),
             "conv_w": _normal(k[1], (cfg.conv_kernel, h), dtype,
                               cfg.conv_kernel),
@@ -153,7 +172,7 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
         channels = cfg.delta_conv_dim
         values = cfg.delta_value_heads * cfg.delta_value_dim
         return {
-            "norm": gain,
+            **norms,
             "w_qkvz": _normal(k[0], (h, channels + values), dtype, h),
             "w_ba": _normal(k[4], (h, 2 * cfg.delta_value_heads), dtype, h),
             "conv_w": _normal(k[1], (cfg.conv_kernel, channels), dtype,
@@ -165,7 +184,7 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
     if kind == "attention":
         q, kv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
         layer = {
-            "norm": gain,
+            **norms,
             "wq": _normal(k[0], (h, q * (2 if cfg.attn_output_gate else 1)),
                           dtype, h),
             "wk": _normal(k[1], (h, kv), dtype, h),
@@ -176,13 +195,34 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
             layer["q_norm"] = layer["k_norm"] = jnp.full(
                 (cfg.head_dim,), 1.0 - cfg.norm_offset, dtype)
         return layer
+    if kind == "latent":
+        heads, rank = cfg.num_heads, cfg.kv_lora_rank
+        nope, v = cfg.qk_nope_head_dim, cfg.v_head_dim
+        return {
+            **norms,
+            "w_dq": _normal(k[0], (h, cfg.q_lora_rank), dtype, h),
+            "q_norm": jnp.full((cfg.q_lora_rank,), 1.0 - cfg.norm_offset,
+                               dtype),
+            "w_uq": _normal(
+                k[1], (cfg.q_lora_rank,
+                       heads * (nope + cfg.qk_rope_head_dim)),
+                dtype, cfg.q_lora_rank),
+            "w_dkv": _normal(k[2], (h, cfg.latent_width), dtype, h),
+            "kv_norm": jnp.full((rank,), 1.0 - cfg.norm_offset, dtype),
+            # W_ukv's two halves, a head at a time, as the absorbed
+            # products take them: keys' [heads, nope, rank] (the query
+            # goes through its transpose), values' [heads, rank, v].
+            "w_uk": _normal(k[3], (heads, nope, rank), dtype, rank),
+            "w_uv": _normal(k[4], (heads, rank, v), dtype, rank),
+            "wo": _normal(k[5], (heads * v, h), dtype, heads * v),
+        }
     if kind == "dense":
-        return {"norm": gain,
+        return {**norms,
                 **init_mlp_params(k[0], h, cfg.dense_intermediate_size, dtype)}
     latent, inner = cfg.moe_latent_size, cfg.intermediate_size
     shared, held = cfg.moe_shared_intermediate, cfg.experts_held
     router = {
-        "norm": gain,
+        **norms,
         "router": _normal(k[0], (h, cfg.n_routed_experts), dtype, h),
     }
     if cfg.router_scoring == "sigmoid":
@@ -635,6 +675,35 @@ def attention_layer(p: dict, h, positions, cfg: ModelConfig, attend, idx,
     return out, pool
 
 
+def latent_attention_layer(p: dict, h, positions, cfg: ModelConfig, attend,
+                           idx, pool):
+    """The "A" body (module text) in its absorbed form: hands `attend` the
+    query heads [B, T, H, W] and the token's ONE row [B, T, 1, W] — W the
+    pool's row width, zero columns after the published latent + rotary
+    key — and no V; gets back Σ p · latent [B, T, H, rank]."""
+    B, T, _ = h.shape
+    heads, rank, nope = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    eps, offset = cfg.rms_norm_eps, cfg.norm_offset
+    c_q = rms_norm(qdot(h, p["w_dq"]), p["q_norm"], eps, offset)
+    # Flat until it is done, as models/layers.py `qkv_project` holds its
+    # products (a [B, 1, ·] step else has the head split folded into it).
+    q = jax.lax.optimization_barrier(qdot(c_q, p["w_uq"])).reshape(
+        B, T, heads, nope + cfg.qk_rope_head_dim)
+    down = qdot(h, p["w_dkv"])
+    c = rms_norm(down[..., :rank], p["kv_norm"], eps, offset)
+    k_r = rope(down[:, :, None, rank:], positions, cfg.rope_theta)
+    pad = cfg.kv_row_width - cfg.latent_width
+    row = jnp.concatenate(
+        [c[:, :, None], k_r, jnp.zeros((B, T, 1, pad), c.dtype)], axis=-1)
+    absorbed = jnp.einsum("bthn,hnc->bthc", q[..., :nope], p["w_uk"])
+    q = jnp.concatenate(
+        [absorbed, rope(q[..., nope:], positions, cfg.rope_theta),
+         jnp.zeros((B, T, heads, pad), q.dtype)], axis=-1)
+    u, pool = attend(jnp.int32(idx), q, row, None, pool)
+    ctx = jnp.einsum("bthc,hcv->bthv", u, p["w_uv"])
+    return qdot(ctx.reshape(B, T, heads * cfg.v_head_dim), p["wo"]), pool
+
+
 def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
               state=None, rows=None, active=None):
     """embed → the pattern's layers, unrolled → final norm. `pool` is the
@@ -676,9 +745,10 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
             else:
                 out, conv[held] = conv_prefill(p, h, cfg, conv[held], rows)
             held += 1
-        elif kind == "attention":
-            out, pool = attention_layer(
-                p, h, positions, cfg, attend, idx, pool)
+        elif kind in ("attention", "latent"):
+            layer = (attention_layer if kind == "attention"
+                     else latent_attention_layer)
+            out, pool = layer(p, h, positions, cfg, attend, idx, pool)
         elif kind == "dense":
             out = mlp(p, h, cfg.activation)
         elif active is not None:
@@ -688,6 +758,8 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
             out = moe_held(p, h, cfg, weights)
         else:
             out = moe_held(p, h, cfg)
+        if cfg.sandwich_norm:
+            out = rms_norm(out, p["post_norm"], eps, offset)
         x = x + out
     x = rms_norm(x, params["final_norm"], eps, offset)
     if state is not None:

@@ -229,7 +229,8 @@ def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged, attend)
     norm, with the WHOLE stacked pool in the scan carry; returns (hidden,
     updated paged).
 
-    The pool is stored [L, N, 2, page_size, Hk·D] (engine/kv_cache.py) and
+    The pool is stored [L, N, 2, page_size, Hk·D] (engine/kv_cache.py; a
+    latent pool [L, N, 1, page_size, W]) and
     carried as its page halves, [L·N·2, page_size, Hk·D] — a merge of
     leading dimensions, a bitcast under any tiling, made once outside the
     scan. `attend(layer_idx, q, k, v, cache)` gets that
@@ -265,7 +266,8 @@ def _stacked(paged):
     """The pool as the model step carries it and the ops take it: every
     leaf with its leading dimensions merged down to [·, page_size, ·] (a
     bitcast) — the kv array as page halves [L·N·2, page_size, Hk·D], page
-    p's K at 2p and its V at 2p + 1 — alone, or in the int8 (values,
+    p's K at 2p and its V at 2p + 1 (a one-part latent pool:
+    [L·N, page_size, W], page p at p) — alone, or in the int8 (values,
     k scales, v scales) triple the ops dispatch on."""
     def merge(p):
         return p.reshape(-1, *p.shape[-2:])
@@ -289,7 +291,8 @@ def _unstacked(paged, pool):
 def _layer_tables(paged, layer_idx, tables: jax.Array) -> jax.Array:
     """Page ids of one layer within the stack `_run_paged_stack` carries:
     page p of layer l lies at l · num_pages + p (the reserved garbage page 0
-    becomes the layer's own garbage page)."""
+    becomes the layer's own garbage page); the ops scale a page id by the
+    parts a page holds."""
     return tables + layer_idx * paged.num_pages
 
 
@@ -437,15 +440,30 @@ def forward_slots_counted(
     home, engine._decode_fn). The homogeneous families keep
     `_run_paged_stack`'s scan; a
     layer pattern walks its layers unrolled, its "*" layers on the same
-    write and attention kernels over their own pool layers."""
-    from ..ops.paged_attention import paged_attention, paged_write
-    from ..ops.paged_attention_kernel import paged_attention_decode
+    write and attention kernels over their own pool layers, its "A"
+    layers on the same write paths and the latent read over a one-part
+    pool."""
+    from ..ops.paged_attention import (
+        latent_attention,
+        paged_attention,
+        paged_write,
+    )
+    from ..ops.paged_attention_kernel import (
+        mla_latent_decode,
+        paged_attention_decode,
+    )
 
     decode = tokens.shape[1] == 1
 
     def attend(layer_idx, q, k, v, pool):
         tables = _layer_tables(paged, layer_idx, page_tables)
         pool = paged_write(pool, k, v, tables, positions, mesh=mesh)
+        if cfg.latent_kv:
+            # A one-part pool: `k` was the token's one row and `v` None;
+            # every head reads the row, its leading columns the value.
+            op = mla_latent_decode if decode else latent_attention
+            return op(q, pool, tables, positions, scale=cfg.q_scale,
+                      v_width=cfg.kv_lora_rank), pool
         # Single-token steps take the DMA decode kernel (reads only valid
         # pages); prefill buckets take the gather path (wide T amortizes
         # the window materialization, and flash covers contiguous prefill).

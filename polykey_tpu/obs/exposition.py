@@ -81,6 +81,12 @@ _ENGINE_FAMILIES: tuple = (
     ("gauge", "polykey_state_pool_bytes",
      "Bytes of per-slot recurrent state beside the KV pool (0: the model "
      "has none).", "state_pool_bytes"),
+    ("gauge", "polykey_kv_pool_bytes",
+     "Bytes of the paged pool on the device (K and V pages, or a latent "
+     "model's one-part pages).", "kv_pool_bytes"),
+    ("gauge", "polykey_kv_token_bytes",
+     "Bytes one cached token holds in the pool over all layers.",
+     "kv_token_bytes"),
     ("gauge", "polykey_tokens_per_sec",
      "Decode throughput over the last ~1s window.", "tokens_per_sec"),
     # Occupancy tracker (ISSUE 4): measured live-lane accounting — the

@@ -53,6 +53,7 @@ def _kernel(
     logit_softcap: Optional[float],
     kv_len: int,  # true (unpadded) S
     bk: int,
+    native: bool = False,  # products on the operands' own dtype
 ):
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -82,13 +83,20 @@ def _kernel(
 
     @pl.when(needed)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k.astype(jnp.float32),
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                     # [bq, bk]
+        if native:
+            s = scale * jax.lax.dot_general(
+                q_ref[0, 0], k_ref[0, 0],
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            q = q_ref[0, 0].astype(jnp.float32) * scale
+            k = k_ref[0, 0]
+            s = jax.lax.dot_general(
+                q, k.astype(jnp.float32),
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                 # [bq, bk]
         if logit_softcap is not None:
             s = logit_softcap * jnp.tanh(s / logit_softcap)
 
@@ -106,11 +114,18 @@ def _kernel(
         corr = jnp.exp(m_prev - m_new)                        # [bq, 1]
 
         l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v_ref[0, 0].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        if native:
+            acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0, 0],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        else:
+            acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+                p, v_ref[0, 0].astype(jnp.float32),
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -123,7 +138,8 @@ def _kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "scale", "logit_softcap", "kv_len", "block_q", "block_k", "interpret"
+        "scale", "logit_softcap", "kv_len", "block_q", "block_k", "interpret",
+        "native",
     ),
 )
 def _flash_bhsd(
@@ -139,6 +155,7 @@ def _flash_bhsd(
     block_q: int,
     block_k: int,
     interpret: bool,
+    native: bool = False,
 ) -> jax.Array:
     B, Hq, Tp, D = q.shape
     Hk, Sp = k.shape[1], k.shape[2]
@@ -152,6 +169,7 @@ def _flash_bhsd(
         logit_softcap=logit_softcap,
         kv_len=kv_len,
         bk=block_k,
+        native=native,
     )
     return pl.pallas_call(
         kernel,
@@ -243,9 +261,16 @@ def flash_attention(
     interpret: bool = False,
     force_kernel: bool = False,
     mesh=None,                # serving mesh → shard_map the kernel
+    native: bool = False,
 ) -> jax.Array:
     """Blockwise attention; same contract as the reference `attention` but
     masking is derived from positions in-kernel. Returns [B, T, Hq, D].
+
+    `native` (one device only): both products take their operands in the
+    dtype they arrive in (bf16 on the MXU, float32 sums) instead of
+    float32 copies, and the scale goes on the sums — for a caller whose
+    attention is arithmetic-bound at these widths (the latent layers'
+    absorbed form, ops/paged_attention.latent_attention).
 
     With a mesh whose sp/tp extents exceed 1 the kernel runs under
     shard_map: the query/time axis shards over sp (each shard computes
@@ -348,5 +373,6 @@ def flash_attention(
         block_q=block_q,
         block_k=block_k,
         interpret=interpret,
+        native=native,
     )
     return jnp.transpose(out[:, :, :T], (0, 2, 1, 3))

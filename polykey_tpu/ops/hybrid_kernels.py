@@ -80,6 +80,10 @@ MOE_GROUPED_ABOVE_ROWS = 256
 # Rows of one grouped call: they and their float32 result stay in VMEM,
 # and the one-hot products that gather and add them grow with the count.
 MOE_GROUP_ROWS = 1024
+# What those rows and their result may take of _VMEM_LIMIT, two buffers
+# each, beside the weights' blocks: rows wider than 2,730 bf16 columns run
+# as calls of fewer (`_group_rows`; 7,680 wide: 256).
+_GROUP_RESIDENT_BYTES = 32 * 1024 * 1024
 
 
 def use_kernels() -> bool:
@@ -278,14 +282,21 @@ def _moe_kernel(*refs, activation: str, gated: bool):
                             preferred_element_type=jnp.float32)
 
 
-def _inner_tile(inner: int) -> int:
+_WEIGHT_BLOCK_BYTES = 4 * 1024 * 1024
+
+
+def _inner_tile(inner: int, row_bytes: int = 0) -> int:
     """The widest 128-aligned divisor of the experts' width up to 1024
-    (2688 → 896, 1536 → 768): a block of each weight is then a few MiB."""
-    best = inner
+    (2688 → 896, 1536 → 768) whose block of a weight — `row_bytes` a
+    column of it: the width the experts read, in bytes — stays within
+    _WEIGHT_BLOCK_BYTES (7,680 wide: 2048 → 256; three matrices' blocks,
+    two buffers each, stand in VMEM beside the rows)."""
+    best = None
     for t in range(128, min(inner, 1024) + 1, 128):
-        if inner % t == 0:
+        if inner % t == 0 and (best is None
+                               or t * row_bytes <= _WEIGHT_BLOCK_BYTES):
             best = t
-    return best
+    return inner if best is None else best
 
 
 def moe_held_experts(v, up, down, weights, *, gate=None,
@@ -299,7 +310,7 @@ def moe_held_experts(v, up, down, weights, *, gate=None,
         v = jnp.pad(v, ((0, pad), (0, 0)))
         weights = jnp.pad(weights, ((0, pad), (0, 0)))
     rows = R + pad
-    it = _inner_tile(inner)
+    it = _inner_tile(inner, L * up.dtype.itemsize)
     # [E, rows, 1]: an expert's weights as a column that broadcasts along
     # the activation's lanes.
     wcol = weights.astype(jnp.float32).T[:, :, None]
@@ -449,16 +460,25 @@ def moe_held_experts_grouped(v, up, down, weights, *, chosen: int,
     for the whole call, and a tile's rows are gathered — its results added
     to their rows — by one-hot products on the MXU, under the weight
     stream (XLA's own gather of the sorted rows cost more than the
-    experts' bytes). More than MOE_GROUP_ROWS rows run as calls of that
+    experts' bytes). More than `_group_rows` rows run as calls of that
     many."""
+    width_bytes = v.shape[1] * v.dtype.itemsize
     call = functools.partial(
         _grouped_call, chosen=chosen, activation=activation,
-        tile=MOE_GROUP_TILE, inner_tile=_inner_tile(up.shape[2]),
+        tile=MOE_GROUP_TILE, inner_tile=_inner_tile(up.shape[2], width_bytes),
         interpret=interpret)
+    rows = _group_rows(v.shape[1], v.dtype.itemsize)
     return jnp.concatenate([
-        call(v[r:r + MOE_GROUP_ROWS], up, down, weights[r:r + MOE_GROUP_ROWS],
-             gate)
-        for r in range(0, v.shape[0], MOE_GROUP_ROWS)])
+        call(v[r:r + rows], up, down, weights[r:r + rows], gate)
+        for r in range(0, v.shape[0], rows)])
+
+
+def _group_rows(width: int, itemsize: int) -> int:
+    """Rows of one grouped call: MOE_GROUP_ROWS where rows of `width`
+    columns and their float32 result fit _GROUP_RESIDENT_BYTES, else the
+    whole tiles of 128 that do."""
+    fit = _GROUP_RESIDENT_BYTES // (2 * width * (itemsize + 4))
+    return min(MOE_GROUP_ROWS, max(fit // 128, 1) * 128)
 
 
 # Jitted by itself: a prefill module calls it once an expert layer (and once

@@ -95,6 +95,51 @@ def paged_attention(
     )
 
 
+# The blockwise kernel's blocks for `latent_attention`, query rows and key
+# rows alike: a 640-wide row makes the kernel's own 512 x 1024 pair too
+# large for the scoped VMEM, and a key block of 512 lets a first window
+# skip the table's later blocks.
+LATENT_BLOCK = 512
+
+
+def latent_attention(
+    q: jax.Array,             # [B, T, Hq, W]: absorbed query heads
+    rows: jax.Array,          # [N, page_size, W]: one-part pages
+    page_tables: jax.Array,   # [B, P]
+    q_positions: jax.Array,   # [B, T]
+    *,
+    scale: float,
+    v_width: int,
+) -> jax.Array:
+    """Attention of a latent (MLA) layer in its absorbed form over the
+    gathered table; returns [B, T, Hq, v_width]. A token's ONE row is the
+    key of every head and, in its leading `v_width` columns, the value:
+    the gathered window is handed to the blockwise kernel as K AND as V of
+    ONE head whose query rows are the (token, head) pairs — a query block
+    is a few tokens' heads, so a key block is fetched once for all of
+    them, and the blocks past those tokens' positions are skipped — and
+    the output's columns past `v_width` (the weighted sum of the rotary
+    key and the padding) are dropped. A prefill dispatch's form on the
+    chip (the products on bf16 operands: 128 heads against one row is
+    arithmetic-bound), and every shape's off it; a decode step on the chip
+    reads the pages where they lie
+    (paged_attention_kernel.mla_latent_decode)."""
+    from .flash_attention import flash_attention
+    from .paged_attention_kernel import use_paged_kernel
+
+    B, T, heads, width = q.shape
+    P = page_tables.shape[1]
+    table = rows[page_tables].reshape(B, P * rows.shape[1], 1, width)
+    on_chip = use_paged_kernel(1, width) and T >= 128
+    out = flash_attention(
+        q.reshape(B, T * heads, 1, width), table, table,
+        jnp.repeat(q_positions, heads, axis=1), scale=scale,
+        block_q=LATENT_BLOCK, block_k=LATENT_BLOCK,
+        force_kernel=on_chip, native=on_chip,
+    )
+    return out.reshape(B, T, heads, width)[..., :v_width]
+
+
 def quantize_kv_rows(rows: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Symmetric per-(token, head) int8 quantization of KV rows
     [..., Hk, D] → (int8 values, bf16 scales [..., Hk]).
@@ -121,13 +166,17 @@ def paged_write(
     kv_pages,                 # [2 · num_pages, page_size, Hk·D], or the
                               # int8 (values, k scales, v scales) triple
     k_new: jax.Array,         # [B, T, Hk, D]
-    v_new: jax.Array,
+    v_new: Optional[jax.Array],
     page_tables: jax.Array,   # [B, P]
     positions: jax.Array,     # [B, T] absolute position of each new token
     mesh=None,
 ):
     """Write new KV into their pages at (page_table[pos // ps], pos % ps);
     returns the pool in the form it came.
+
+    `v_new` None: a ONE-part page (a latent pool, [num_pages, page_size,
+    W]): `k_new` [B, T, 1, W] is the token's one row, and page p is entry
+    p — every path below indexes `parts · page + part`.
 
     With int8 KV (the triple — engine/kv_cache.py PagedKV.quantized) the
     rows quantize at write time and the scale pools [N, ps, Hk] take the
@@ -164,7 +213,9 @@ def paged_write(
             r.astype(p.dtype) for p, r in zip(scale_pools, (k_s, v_s)))
     else:
         data, scale_pools, scale_rows = kv_pages, (), ()
-        kv_rows = (fold(k_new), fold(v_new))
+        kv_rows = tuple(
+            fold(r) for r in (k_new, v_new) if r is not None)
+    parts = len(kv_rows)
 
     page_size = data.shape[1]
     B, T = positions.shape
@@ -184,7 +235,8 @@ def paged_write(
         pp = mesh.shape.get("pp", 1) if mesh is not None else 1
         if use_paged_kernel(Hk, D) and pp == 1:
             # A lane's rows as the kernel blends them into the entries of
-            # its page: [B, 2, 1, Hk·D] for the two halves, [B, 1, 1, Hk].
+            # its page: [B, parts, 1, Hk·D] (the two halves of a K/V page),
+            # [B, 1, 1, Hk].
             return repack(_write_decode_kernel(
                 list(pools_in),
                 [jnp.stack(kv_rows, axis=1), *(r[:, None] for r in scale_rows)],
@@ -197,7 +249,7 @@ def paged_write(
     def token_scatter(pools):
         data, *scales = pools
         for half, rows in enumerate(kv_rows):
-            data = data.at[2 * page_ids + half, offsets].set(rows)
+            data = data.at[parts * page_ids + half, offsets].set(rows)
         return (data, *(
             p.at[page_ids, offsets].set(r) for p, r in zip(scales, scale_rows)
         ))
@@ -220,7 +272,7 @@ def paged_write(
             )                                                    # [B, n_pg]
             data, *scales = pools
             for half, rows in enumerate(kv_rows):
-                data = data.at[2 * pg_ids + half].set(pages(rows))
+                data = data.at[parts * pg_ids + half].set(pages(rows))
             return (data, *(
                 p.at[pg_ids].set(pages(r)) for p, r in zip(scales, scale_rows)
             ))

@@ -11,6 +11,15 @@ HBM → VMEM while the pages before them accumulate into online-softmax state
 ops/flash_attention.py — and the program writes one normalised [S, Hq, D]
 block in the activations' dtype.
 
+A page is its PARTS, adjacent entries of the pool fetched under one
+descriptor: K and V (`parts` 2: what the text below says of K and V), or
+the ONE latent row a token of a latent-attention model (`parts` 1,
+`mla_latent_decode`: the row is every head's key and, in its leading
+`v_width` columns, every head's value, so a tile is one product of all
+query heads against the rows and one against their leading columns, on
+the pool's own dtype). The walk — lanes, blocks and tiles by bytes, runs
+of waits, the live blocks — is one and the same.
+
 Pages stream in BLOCKS of `pages_per_block` (G): each buffer slot holds G
 pages, whose DMAs all go out together, so per-page DMA latency amortizes
 G×. A block is awaited and computed by ROW TILES of Gt pages (`_tile_pages`),
@@ -118,9 +127,11 @@ def _kernel(
                             #   divides G
     quantized: bool = False,
     state: bool = False,
+    parts: int = 2,         # entries a page holds: K and V, or 1 latent row
+    v_width: int = 0,       # parts == 1: a row's leading columns are its "V"
 ):
-    def kv_halves(page):      # a page's K and V: two adjacent entries
-        return kv_pages_ref.at[pl.ds(2 * page, 2)]
+    def kv_halves(page):      # a page's parts: adjacent entries of the pool
+        return kv_pages_ref.at[pl.ds(parts * page, parts)]
 
     # A stream: (a page id → that page in HBM, its buffer, its semaphores).
     n_out = 3 if state else 1
@@ -139,6 +150,7 @@ def _kernel(
         streams = ((kv_halves, kv_buf, kv_sems),)
         ks_buf = vs_buf = None
     S, Hq, D = q_ref.shape
+    Dv = v_width if parts == 1 else D     # an output head's width
     first = pl.program_id(0) * S          # the program's first sequence
     B = pl.num_programs(0) * S
     window = win_ref[0]
@@ -236,7 +248,14 @@ def _kernel(
         nxt_lo, nxt_hi = page_span(nxt_seq)
         nxt_hi = jnp.where(nxt < B, nxt_hi, nxt_lo)  # no next: an empty span
 
-        q = q_ref[j].astype(jnp.float32) * scale              # [Hq, D]
+        if parts == 1:
+            # Every head reads the one row: the products run in the
+            # pool's own dtype (bf16 operands, float32 sums — 240 FLOP a
+            # byte of row at long contexts leaves no room for float32
+            # passes), and the scale goes on the sums.
+            q = q_ref[j].astype(kv_buf.dtype)                 # [Hq, D]
+        else:
+            q = q_ref[j].astype(jnp.float32) * scale          # [Hq, D]
 
         def block(i, carry):
             blk = blo + i
@@ -264,7 +283,10 @@ def _kernel(
                 wait_tile(slot, t, first_at, end_at)
                 page0 = t * Gt
                 k = tile_rows(kv_buf, slot, page0, 0)
-                v = tile_rows(kv_buf, slot, page0, 1)
+                # A one-part page's row is every head's key, and its
+                # leading v_width columns every head's value.
+                v = (k[:, :v_width] if parts == 1
+                     else tile_rows(kv_buf, slot, page0, 1))
                 num_kv = k.shape[1] // D
                 if quantized:
                     # Per-(position, head) dequant scales for this tile —
@@ -284,6 +306,8 @@ def _kernel(
                 # tile that straddles an end of the span); zero V there so
                 # masked-out weights cannot multiply NaN garbage.
                 v = jnp.where(valid1, v.astype(jnp.float32), 0.0)
+                if parts == 1:
+                    v = v.astype(k.dtype)
                 if quantized:
                     # The V-side matmul SUMS over rows, so stale scale
                     # rows must be zeroed like v itself — 0·NaN from a
@@ -301,18 +325,25 @@ def _kernel(
                         kk = kk * ks2[:, h:h + 1]
                     return kk
 
-                s = jnp.concatenate(
-                    [
-                        jax.lax.dot_general(
-                            q[h * groups:(h + 1) * groups],   # [g, D]
-                            k_head(h),
-                            dimension_numbers=(((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                        )
-                        for h in range(num_kv)
-                    ],
-                    axis=0,
-                )                                             # [Hq, T]
+                if parts == 1:
+                    s = scale * jax.lax.dot_general(
+                        q, k,
+                        dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                else:
+                    s = jnp.concatenate(
+                        [
+                            jax.lax.dot_general(
+                                q[h * groups:(h + 1) * groups],   # [g, D]
+                                k_head(h),
+                                dimension_numbers=(((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                            )
+                            for h in range(num_kv)
+                        ],
+                        axis=0,
+                    )                                         # [Hq, T]
                 if logit_softcap is not None:
                     s = logit_softcap * jnp.tanh(s / logit_softcap)
 
@@ -336,18 +367,25 @@ def _kernel(
                         vv = vv * vs2[:, h:h + 1]
                     return vv
 
-                pv = jnp.concatenate(
-                    [
-                        jax.lax.dot_general(
-                            pexp[h * groups:(h + 1) * groups],    # [g, T]
-                            v_head(h),
-                            dimension_numbers=(((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                        )
-                        for h in range(num_kv)
-                    ],
-                    axis=0,
-                )                                             # [Hq, D]
+                if parts == 1:
+                    pv = jax.lax.dot_general(
+                        pexp.astype(v.dtype), v,
+                        dimension_numbers=(((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                else:
+                    pv = jnp.concatenate(
+                        [
+                            jax.lax.dot_general(
+                                pexp[h * groups:(h + 1) * groups],  # [g, T]
+                                v_head(h),
+                                dimension_numbers=(((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                            )
+                            for h in range(num_kv)
+                        ],
+                        axis=0,
+                    )                                         # [Hq, Dv]
                 return m_new, l_new, acc * corr + pv
 
             # Only the tiles that hold a fetched page are computed: a lane
@@ -358,7 +396,7 @@ def _kernel(
 
         m0 = jnp.full((Hq, 1), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((Hq, 1), jnp.float32)
-        acc0 = jnp.zeros((Hq, D), jnp.float32)
+        acc0 = jnp.zeros((Hq, Dv), jnp.float32)
         # Only the live blocks are walked: ~4 turns at ~450-token contexts,
         # not one turn (and a branch) for each of the table's P // G groups.
         m, l, acc = jax.lax.fori_loop(0, n_blocks, block, (m0, l0, acc0))
@@ -448,7 +486,8 @@ def _wait_runs(pages_per_block: int) -> tuple:
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "scale", "logit_softcap", "interpret", "pages_per_block", "state"),
+        "scale", "logit_softcap", "interpret", "pages_per_block", "state",
+        "parts", "v_width", "name"),
 )
 def _decode_call(
     q: jax.Array,             # [B, Hq, D]
@@ -465,6 +504,9 @@ def _decode_call(
     interpret: bool,
     state: bool,
     pages_per_block: int = 0,   # 0 → auto
+    parts: int = 2,
+    v_width: int = 0,
+    name: str = "paged_attention_decode",
 ):
     """Attention over the pages in `page_range`: [B, Hq, D] in q's dtype,
     normalised inside the kernel — or, with `state`, the UNNORMALIZED
@@ -472,9 +514,10 @@ def _decode_call(
     caller that first merges partial states across context-parallel shards
     (acc/l scale by exp(m - m_global)).
 
-    The pool is taken as it is stored (engine/kv_cache.py: K and V of a
-    page side by side — entries 2p and 2p + 1 here — heads folded into
-    lanes, every page DMA 128-aligned for any head_dim) and stays in HBM
+    The pool is taken as it is stored (engine/kv_cache.py: a page's parts
+    side by side — K and V at entries 2p and 2p + 1 here, a latent row at
+    p — heads folded into lanes, every page DMA 128-aligned for any
+    head_dim) and stays in HBM
     (`pl.ANY`): nothing here reshapes or copies a pool."""
     quantized = isinstance(kv_pages, tuple)
     if quantized:
@@ -482,14 +525,15 @@ def _decode_call(
     B, Hq, D = q.shape
     _, ps, folded = kv_pages.shape
     Hk = folded // D
+    Dv = v_width if parts == 1 else D
     row_bytes = folded * kv_pages.dtype.itemsize
     G = _block_pages(pages_per_block, row_bytes, ps, page_tables.shape[1])
     Gt = _tile_pages(G, row_bytes, ps)
     if state:
-        out_shapes = [((Hq, D), jnp.float32), ((Hq, 1), jnp.float32),
+        out_shapes = [((Hq, Dv), jnp.float32), ((Hq, 1), jnp.float32),
                       ((Hq, 1), jnp.float32)]
     else:
-        out_shapes = [((Hq, D), q.dtype)]
+        out_shapes = [((Hq, Dv), q.dtype)]
     S = _program_lanes(B, Hq * D * q.dtype.itemsize + sum(
         math.prod(shape) * jnp.dtype(dtype).itemsize
         for shape, dtype in out_shapes))
@@ -504,6 +548,8 @@ def _decode_call(
         pages_per_tile=Gt,
         quantized=quantized,
         state=state,
+        parts=parts,
+        v_width=v_width,
     )
 
     def lanes_spec(shape):
@@ -511,7 +557,7 @@ def _decode_call(
 
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [lanes_spec((Hq, D)), any_spec]
-    scratch = [pltpu.VMEM((2, G, 2, ps, folded), kv_pages.dtype)]
+    scratch = [pltpu.VMEM((2, G, parts, ps, folded), kv_pages.dtype)]
     operands = [q, kv_pages]
     if quantized:
         in_specs += [any_spec, any_spec]
@@ -540,7 +586,7 @@ def _decode_call(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-        name="paged_attention_decode",
+        name=name,
     )(
         page_tables.astype(jnp.int32),
         positions.astype(jnp.int32),
@@ -566,8 +612,10 @@ INT8_KV_MOSAIC_ERROR = (
 
 
 def use_paged_kernel(num_kv_heads: int, head_dim: int) -> bool:
-    """The DMA kernel needs TPU hardware; the folded head-lane dimension
-    (num_kv_heads · head_dim) must be 128-aligned for DMA tiling."""
+    """The DMA kernels (the reads here, the page write) need TPU hardware,
+    and a row of a page's part — num_kv_heads · head_dim folded columns,
+    or a latent pool's one row (1, width) — must be whole 128-lane tiles
+    for DMA tiling."""
     return jax.default_backend() == "tpu" and (num_kv_heads * head_dim) % 128 == 0
 
 
@@ -710,4 +758,41 @@ def paged_attention_decode(
             q_positions[:, 0].astype(jnp.int32), win,
             jnp.array([0, P_tables], jnp.int32), state=False,
         )
+    return out[:, None]
+
+
+def mla_latent_decode(
+    q: jax.Array,             # [B, 1, Hq, W]: absorbed query heads
+    rows: jax.Array,          # [N, ps, W]: one-part pages, a latent row a token
+    page_tables: jax.Array,   # [B, P]
+    q_positions: jax.Array,   # [B, 1] absolute positions
+    *,
+    scale: float,
+    v_width: int,
+    interpret: bool = False,
+    pages_per_block: int = 0,   # 0 → auto (from the bytes a block moves)
+) -> jax.Array:
+    """Decode-step attention of a latent (MLA) layer in its absorbed form:
+    every query head reads the token's ONE row — the row is its key, the
+    row's leading `v_width` columns its value — so a page is one part and
+    is fetched once for all heads. Returns [B, 1, Hq, v_width].
+
+    The walk is `_kernel`'s (one program over the lanes, blocks and tiles
+    by bytes, runs of waits, the live blocks [blo, bhi)); only a tile's two
+    products differ. Off-TPU: the gather path
+    (ops/paged_attention.latent_attention). One device: the engine refuses
+    a mesh axis over a latent pool (engine/config.py)."""
+    if not (interpret or use_paged_kernel(1, rows.shape[-1])):
+        from .paged_attention import latent_attention
+
+        return latent_attention(
+            q, rows, page_tables, q_positions, scale=scale, v_width=v_width)
+    out = _decode_call(
+        q[:, 0], rows, page_tables, q_positions[:, 0].astype(jnp.int32),
+        jnp.zeros((1,), jnp.int32),
+        jnp.array([0, page_tables.shape[1]], jnp.int32),
+        scale=scale, logit_softcap=None, interpret=interpret, state=False,
+        pages_per_block=pages_per_block, parts=1, v_width=v_width,
+        name="mla_latent_decode",
+    )
     return out[:, None]

@@ -63,7 +63,7 @@ def check(rows: int, name: str, gated: bool) -> None:
     want = hybrid_kernels.moe_held_experts_jnp(v, up, down, dense, **how)
     # The experts' width streams as two blocks: the accumulation over
     # blocks and a long run's walk back over them are on the path.
-    with mock.patch.object(hybrid_kernels, "_inner_tile", lambda inner: 128):
+    with mock.patch.object(hybrid_kernels, "_inner_tile", lambda inner, *_: 128):
         got = hybrid_kernels.moe_held_experts_grouped(
             v, up, down, dense, chosen=min(k, held), interpret=True, **how)
     assert got.shape == want.shape and got.dtype == jnp.float32

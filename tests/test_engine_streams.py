@@ -738,16 +738,20 @@ def _aligned_like(array: np.ndarray) -> np.ndarray:
     return out
 
 
-@pytest.mark.parametrize("draft", [None, "tiny-llama"], ids=["plain", "spec"])
-def test_uploaded_slot_state_does_not_alias_host_mirrors(draft):
+@pytest.mark.parametrize("model,draft", [
+    ("tiny-llama", None), ("tiny-llama", "tiny-llama"), ("tiny-pangu", None),
+], ids=["plain", "spec", "latent"])
+def test_uploaded_slot_state_does_not_alias_host_mirrors(model, draft):
     """On the CPU backend `jax.device_put` of a 64-byte-aligned numpy
     array is zero-copy. The slot state the engine uploads must therefore
     not BE its host mirrors: a dispatch already issued would read a later
     merge's mirror writes (`_active[i] = True` beside a sequence length
     still 0) and its lane would emit one token of garbage — the stream
-    that gained a token in a warmed process (CHANGES.md PR 21, PR 35)."""
+    that gained a token in a warmed process (CHANGES.md PR 21, PR 35).
+    A latent-attention model's slots are the same slots: its pool is one
+    part a page, its tables, lengths and lanes what every model's are."""
     engine = InferenceEngine(dataclasses.replace(
-        GEOMETRIES["b16-32"].config, draft_model=draft,
+        GEOMETRIES["b16-32"].config, model=model, draft_model=draft,
     ))
     engine.shutdown()                   # the loop has ended: driven by hand
     mirrors = {

@@ -536,6 +536,64 @@ def test_delta_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
         assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
 
 
+@pytest.mark.parametrize("prefill", [None, (1, 128), (2, 512)],
+                         ids=["decode", "prefill-1x128", "prefill-2x512"])
+def test_latent_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
+    """The steps of a latent-attention (MLA) pattern with sandwich norms
+    compiled for a v5e at the published widths of what it adds — hidden
+    7680, 128 heads of 128 + 64 / 128 over ranks 1536 / 512, a pool of ONE
+    640-wide row a token and layer (576 published, in whole lane tiles),
+    experts of 7680 x 2048 — at toy depth and a short vocabulary. Decode:
+    the one-part pool is aliased input to output, every latent layer is ONE
+    `mla_latent_decode` call behind ONE page write, nothing gathers a
+    lane's table out of the pool, and the held experts' call fits its
+    weight blocks in VMEM (the inner tile follows bytes: 256 of 2048).
+    Prefill: the blockwise kernel over the gathered rows, once a latent
+    layer, and never the decode kernel; 1,024 rows of experts run grouped,
+    256 rows a call."""
+    from polykey_tpu.models.config import get_config
+
+    cfg = replace(
+        get_config("tiny-pangu"), name="latent-attention-probe",
+        vocab_size=4096, hidden_size=7680, layer_pattern="ADAE", num_layers=4,
+        num_heads=128, head_dim=192, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        dense_intermediate_size=18432, intermediate_size=2048,
+        moe_shared_intermediate=2048, n_routed_experts=256, experts_held=8,
+        num_experts_per_tok=8,
+    )
+    compiled, paged, state = _compile_pattern_step(
+        v5e, monkeypatch, cfg, prefill=prefill)
+    hlo = compiled.as_text()
+    assert head_window_products(hlo) == []
+    pool = "bf16[2,1024,1,16,640]"      # 2 layers x 1,024 pages, one part
+    assert jax.tree.leaves(paged)[0].shape == (2, 1024, 1, 16, 640)
+    assert jax.tree.leaves(state) == []
+    assert aliased_pool_parameters(hlo, pool) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * 1024 * 16 * 640 * 2)
+    calls = _kernel_calls(hlo)
+    reads = sum(c.startswith("%mla_latent_decode") for c in calls)
+    flash = sum(c.startswith("%flash_attention") for c in calls)
+    assert not any(c.startswith("%paged_attention_decode") for c in calls)
+    held = [c for c in calls if c.startswith("%moe_held_experts")]
+    if prefill is None:
+        assert (reads, flash) == (2, 0), calls
+        assert sum(c.startswith("%paged_kv_write") for c in calls) == 2
+        assert len(held) == 1 and "grouped" not in held[0], calls
+        # No lane's window is gathered out of the pool: the only gathers
+        # read the embedding's rows and the router's choices.
+        tables = [line for line in hlo.splitlines()
+                  if " gather(" in line and ",16,640]" in line]
+        assert tables == []
+    else:
+        assert (reads, flash) == (0, 2), calls
+        grouped = prefill == (2, 512)
+        assert len(held) == (4 if grouped else 1), calls
+        assert all(c.startswith("%moe_held_experts_grouped") == grouped
+                   for c in held), calls
+
+
 # -- the q / k / v projections in the compiled steps (ISSUE 44) ---------------
 #
 # Where a dimension of 1 stands beside the rows ([B, 1, H] in the decode

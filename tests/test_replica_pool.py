@@ -622,6 +622,70 @@ def test_stream_error_flushes_stop_hold_buffer():
     assert trailers[gw_errors.RESUME_TOKENS_KEY] == str(len(token_ids))
 
 
+def _stream_service():
+    import types as _types
+
+    from polykey_tpu.engine.tokenizer import ByteTokenizer
+
+    tokenizer = ByteTokenizer()
+    engine = _types.SimpleNamespace(
+        tokenizer=tokenizer,
+        config=_types.SimpleNamespace(request_timeout_s=5.0),
+    )
+    return TpuService(engine), tokenizer
+
+
+@pytest.mark.parametrize("stops", [[], ["ZZ"]], ids=["plain", "stop-armed"])
+def test_stream_sends_the_queued_tokens_as_one_delta(stops):
+    # A decode block hands a stream its tokens at one instant: what is
+    # queued goes out as ONE delta, then the terminal event; the text is
+    # the tokens' own, in order.
+    service, tokenizer = _stream_service()
+    request = GenRequest(prompt="x")
+    for tid in tokenizer.encode("abcdefgh"):
+        request.out.put(("token", tid))
+    request.out.put(("done", None))
+    events = list(service._queued_as_one(
+        request, service._text_events(request, stops)))
+    assert events == [("delta", "abcdefgh"), ("done", None)]
+
+
+def test_stream_sends_a_token_at_once_when_nothing_else_is_queued():
+    # Text waits for nothing: with the queue empty behind it a token is
+    # its own delta (a first token; a stream decoded a token a step).
+    service, tokenizer = _stream_service()
+    request = GenRequest(prompt="x")
+    a, b, c = tokenizer.encode("abc")[-3:]
+    request.out.put(("token", a))
+    stream = service._queued_as_one(request, service._text_events(request, []))
+    assert next(stream) == ("delta", "a")
+    request.out.put(("token", b))
+    request.out.put(("token", c))
+    request.out.put(("done", None))
+    assert list(stream) == [("delta", "bc"), ("done", None)]
+
+
+def test_stream_error_sends_the_joined_text_before_it_raises():
+    # The resume trailer counts every consumed token as delivered, so the
+    # text joined so far goes out before the engine's error does.
+    from polykey_tpu.gateway import errors as gw_errors
+
+    service, tokenizer = _stream_service()
+    request = GenRequest(prompt="x")
+    ids = tokenizer.encode("abc")
+    for tid in ids:
+        request.out.put(("token", tid))
+    request.out.put(("error", "engine restarting: test"))
+    deltas = []
+    with pytest.raises(gw_errors.UnavailableError) as err:
+        for kind, value in service._queued_as_one(
+                request, service._text_events(request, [])):
+            deltas.append(value)
+    assert deltas == ["abc"]
+    trailers = dict(err.value.trailing_metadata())
+    assert trailers[gw_errors.RESUME_TOKENS_KEY] == str(len(ids))
+
+
 def _struct(values: dict):
     from google.protobuf import struct_pb2
 
