@@ -752,7 +752,12 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
         elif kind == "dense":
             out = mlp(p, h, cfg.activation)
         elif active is not None:
-            weights = held_weights(p, h[:, 0], cfg)
+            # A lane that is not live routes its garbage token all the
+            # same, and its output is thrown away: with its weights zeroed
+            # it has no pairs, and the product reads the experts `hit`
+            # counts and no other.
+            weights = jnp.where(active[:, None],
+                                held_weights(p, h[:, 0], cfg), 0.0)
             hit = held_experts_hit(weights, active)
             hits = hit if hits is None else hits + hit
             out = moe_held(p, h, cfg, weights)

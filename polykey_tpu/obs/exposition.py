@@ -210,8 +210,8 @@ _ENGINE_FAMILIES: tuple = (
      "Real prompt tokens among those rows.", "prefill_rows_useful"),
     ("counter", "polykey_prefill_rows_grouped_experts_total",
      "Of those rows, the rows a layer pattern's expert layers computed "
-     "sorted by expert (the grouped product, from 512 rows up on the "
-     "chip).", "prefill_rows_grouped_experts"),
+     "sorted by expert (the grouped product: on the chip every row, off "
+     "it none).", "prefill_rows_grouped_experts"),
     ("counter", "polykey_prefill_windows_dispatched_total",
      "Prefill windows (real rows) among those dispatches.",
      "prefill_windows_dispatched"),

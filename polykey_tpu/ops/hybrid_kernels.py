@@ -23,32 +23,35 @@ transposed ([B, Dk, Hk]: a key head's vector is a [Dk, 1] column that
 broadcasts along the state's lanes, the value dims); the contractions over
 Dk are sums down the sublanes on the vector units.
 
-`moe_held_experts` — the held experts of an expert layer as ONE pass over
-their weights: for every held expert e, a(v, e) · w[:, e] · W_down[e],
-summed over e into a float32 [rows, width of v]. Two instances, chosen by
-static arguments: un-gated, a = relu(v · W_up[e])² (the experts of a
-latent layer), and gated, a = silu(v · W_gate[e]) ⊙ (v · W_up[e]) (experts
-on the full hidden: three matrices an expert). Every expert's weights are
-read whatever the routing chose (the combine weight of an expert a row did
-not choose is 0): a step's work is fixed by rows × experts held, not by
-the seed. Rows tile outermost, so the output tile stays resident while
-the experts stream past; a dispatch wider than one row tile re-reads the
-weights once per tile.
+`moe_held_experts_grouped` — the held experts of an expert layer as the
+chip runs them at EVERY row count (a decode step's 64 rows, a one-window
+prefill, the wide dispatches): for every held expert e some row chose,
+a(v, e) · w[:, e] · W_down[e], summed over e into a float32 [rows, width
+of v]. Two instances, chosen by static arguments: un-gated, a = relu(v ·
+W_up[e])² (the experts of a latent layer), and gated, a = silu(v ·
+W_gate[e]) ⊙ (v · W_up[e]) (experts on the full hidden: three matrices an
+expert). The (row, chosen held expert) pairs are put in order of expert (a
+counting sort over the dense combine weights), each expert's run padded
+to a row tile, and ONE call walks the tiles with the tile → expert map
+scalar-prefetched, gathering a tile's rows and adding its results back
+inside the kernel. What is read is the weights of the experts SOME ROW
+CHOSE, once each (an expert nobody chose has no tile; the grid's steps
+past the live tiles stay on the last live block and fetch nothing); every
+pair is computed once, no token is dropped, and a choice of an expert not
+held is left out as a zero weight leaves it out. A row whose weights are
+all zero has no pairs: the decode step zeroes an idle lane's, so the
+call reads exactly the experts `ops/moe.py` `held_experts_hit` counts. A
+step's work follows the routing it is given; no row count decides
+anything.
 
-`moe_held_experts_grouped` — the same sum over rows SORTED by expert, for
-row counts above the chip's ridge (197 TFLOP/s ÷ 819 GB/s = 240 rows),
-where the masked form is bound by arithmetic nobody asked for (rows ×
-experts held, 16 × the routed work at top-4 of 64): the (row, chosen held
-expert) pairs are put in order of expert (a counting sort over the same
-dense weights), each expert's run padded to a row tile, and ONE call
-walks the tiles with the tile → expert map scalar-prefetched, gathering
-a tile's rows and adding its results back inside the kernel — every held
-expert's weights read once, every pair computed once, no token dropped,
-a choice of an expert not held left out as the masked form's zero weight
-leaves it out.
-The rule is the static row count against MOE_GROUPED_ABOVE_ROWS
-(ops/moe.py `held_experts_grouped`): decode (64 rows) and a one-window
-prefill (128) stay masked.
+`moe_held_experts` — the same sum as ONE masked pass: every row against
+every held expert, the combine weight of an expert a row did not choose
+being 0, so every held expert's weights are read whatever the routing
+chose and the arithmetic is rows × experts held. Rows tile outermost; a
+dispatch wider than one row tile re-reads the weights once per tile. No
+step the chip serves calls it: where the lanes choose alike it reads half
+its bytes for nobody (PERF.md §5). It is the grouped form's yardstick in
+the interpret-mode tests and in scripts/tpu_kernel_check.py.
 
 Off-TPU all run the same mathematics in jax.numpy (`*_jnp`); the tests
 run the kernels in interpret mode against them.
@@ -71,12 +74,6 @@ MOE_ROW_TILE = 512
 # weight stream, so padding each expert's run to a tile costs nothing the
 # stream does not hide.
 MOE_GROUP_TILE = 128
-# Masked up to this many rows, grouped above: the chip's ridge is 240 rows,
-# and at 512 the masked call takes twice the grouped one's time, at 1,024
-# three and a half times; up to 256 the two are within a few percent of
-# each other and of the experts' bytes (scripts/tpu_kernel_check.py
-# --timing has the table; PERF.md §5).
-MOE_GROUPED_ABOVE_ROWS = 256
 # Rows of one grouped call: they and their float32 result stay in VMEM,
 # and the one-hot products that gather and add them grow with the count.
 MOE_GROUP_ROWS = 1024

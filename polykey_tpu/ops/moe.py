@@ -14,12 +14,11 @@ experts of a layer pattern's expert layer (`moe_held`), in two forms:
 - `moe_held` — one chip's share of an expert layer: the router over
   every published expert (`held_router_weights`: sigmoid scores chosen
   by score + bias, or a softmax over all of them, as the config states),
-  and the held experts' part of the sum as one pass over their weights
-  (ops/hybrid_kernels.py
-  `moe_held_experts`: every row against every held expert, the combine
-  weights masking) up to the chip's ridge, and over rows sorted by expert
-  above it (`moe_held_experts_grouped`: each chosen pair once). No
-  capacity, no drop, in either. `moe_latent_held`: un-gated
+  and the held experts' part of the sum over rows sorted by expert
+  (ops/hybrid_kernels.py `moe_held_experts_grouped`, on the chip at every
+  row count: each chosen pair once, the weights of the experts some row
+  chose read once, an expert nobody chose not read at all). No
+  capacity, no drop. `moe_latent_held`: un-gated
   relu² experts inside a latent, plus a shared expert; `moe_gated_held`:
   gated experts on the full hidden, plus a gated shared expert where the
   config states one.
@@ -165,11 +164,12 @@ def held_router_weights(p: dict, h: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 def held_experts_grouped(rows: int) -> bool:
     """Whether `moe_held` runs a call of `rows` rows (batch × window) as
-    the grouped product: on the chip, above the ridge — the whole rule,
-    on the call's static row count. The engine counts by the same
-    function (prefill_rows_grouped_experts)."""
-    return (hybrid_kernels.use_kernels()
-            and rows > hybrid_kernels.MOE_GROUPED_ABOVE_ROWS)
+    the grouped product: on the chip, whatever `rows` is — a decode step,
+    a one-window prefill and the wide dispatches alike; the grouped form
+    is the slower one at no width (PERF.md §5). The engine counts by the
+    same function (prefill_rows_grouped_experts)."""
+    del rows
+    return hybrid_kernels.use_kernels()
 
 
 def held_weights(p: dict, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -199,12 +199,12 @@ def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig,
     (`held_weights`, or `weights` where the caller has taken them
     already) reads `tokens` [rows, H]; the experts read
     v = `tokens` through `fc1` where the config states a latent, else
-    `tokens`, gated where the layer has a `gate`. Three forms of one sum
-    over the same combine weights, chosen by the backend and the static
-    row count alone: off the chip
-    `moe_held_experts_jnp`; on it the masked one-pass kernel up to the
-    ridge (a decode step, a one-window prefill) and the grouped kernel
-    above it."""
+    `tokens`, gated where the layer has a `gate`. Two forms of one sum
+    over the same combine weights, chosen by the backend alone: off the
+    chip `moe_held_experts_jnp`; on it the grouped kernel at every row
+    count, which reads the experts that some row has a non-zero weight
+    for and no other — a row whose weights are all zero (an idle decode
+    lane's, zeroed by `run_stack`) costs nothing."""
     if weights is None:
         weights = held_weights(p, tokens, cfg)
     v = qdot(tokens, p["fc1"]) if cfg.moe_latent_size else tokens
@@ -213,9 +213,8 @@ def _held_product(p: dict, tokens: jax.Array, cfg: ModelConfig,
         return hybrid_kernels.moe_held_experts_grouped(
             v, p["up"], p["down"], weights,
             chosen=min(cfg.num_experts_per_tok, cfg.experts_held), **how)
-    held = (hybrid_kernels.moe_held_experts if hybrid_kernels.use_kernels()
-            else hybrid_kernels.moe_held_experts_jnp)
-    return held(v, p["up"], p["down"], weights, **how)
+    return hybrid_kernels.moe_held_experts_jnp(
+        v, p["up"], p["down"], weights, **how)
 
 
 def moe_held(p: dict, h: jax.Array, cfg: ModelConfig,
@@ -245,8 +244,8 @@ def moe_gated_held(p: dict, h: jax.Array, cfg: ModelConfig,
     `[first_expert, first_expert + experts_held)` computes them:
     [B, T, H] → [B, T, H], Σ_e w_e · (act(h W_gate,e) ⊙ h W_up,e) W_down,e
     over the chosen experts that are held. The router and the product
-    are `moe_latent_held`'s (`_held_product`): every held expert's three
-    matrices are read once whatever the routing chose, and no token is
+    are `moe_latent_held`'s (`_held_product`): the three matrices of
+    every held expert some row chose are read once, and no token is
     dropped. Where the config states `moe_shared_intermediate`, a shared
     expert of the same gated form is added on every chip (it is
     replicated, not held in shares), weighed by the scalar
@@ -272,9 +271,10 @@ def moe_latent_held(p: dict, h: jax.Array, cfg: ModelConfig,
     HELD, `[first_expert, first_expert + experts_held)`, inside the latent
     (`fc1` down to it, `fc2` back); the shared expert runs on the full
     hidden. What the absent experts would add is left out, here and in
-    the reference alike, and no token is dropped at any width: every held
-    expert's weights are read once (`_held_product`: the combine weights
-    mask, or the rows are sorted by expert). The experts are not gated:
+    the reference alike, and no token is dropped at any width: the
+    weights of every held expert some row chose are read once
+    (`_held_product`: the rows are sorted by expert). The experts are not
+    gated:
     relu(up)² only."""
     if cfg.activation != "relu2":
         raise ValueError("the held-experts product computes relu(up)² only")

@@ -21,10 +21,14 @@ descriptors the call starts and awaits, so that a call's cost can be
 split into bytes ÷ bandwidth + the rest without a server; in no cell —
 what the users pay is the benchmark's to say). `--timing` also times the
 held experts' product (`held-time` rows: the masked one-pass kernel and
-the grouped one, both instances at their published shapes, 64 to 1,024
-rows, µs a call beside the held experts' bytes ÷ the bandwidth — the
-table the crossover constant `MOE_GROUPED_ABOVE_ROWS` is set from) and
-compares grouped against masked and the jnp twin there (`held-compare`).
+the grouped one the chip serves at every width, both instances at their
+published shapes, 64 to 1,024 rows at a balanced routing, where nearly
+every held expert is hit and nothing can be skipped, and the decode
+step's 64 rows of all four cells' shapes at a routing of a STATED hit
+share — the share of the held experts some row chose, which is all the
+grouped form reads; µs a call beside the held experts' bytes ÷ the
+bandwidth, whole and hit) and compares grouped against masked and the jnp
+twin there (`held-compare`).
 
 Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
      python scripts/tpu_kernel_check.py --timing   (the decode-time and
@@ -415,6 +419,19 @@ HELD_SHAPES = [
 ]
 HELD_ROWS = (64, 128, 256, 512, 1024)
 HELD_CALLS = 16
+# The decode step's call (64 rows) of each 64-slot cell's shape, with the
+# share of the held experts that the routing may choose from: the cells'
+# seeded routers make the lanes choose alike, and the ledger's PR 55 lines
+# read 96.7 / 47.6 / 52.3 / 61.9 % hit (nemotron 17.9 % at one seed, PR 54).
+HELD_DECODE = [
+    (HELD_SHAPES[0], 1.0),
+    (HELD_SHAPES[1], 0.48),
+    (HELD_SHAPES[1], 0.18),
+    (("qwen3-next 128x(2048x512) top-10/512", 2048, 2048, 512, 512, 128, 10,
+      "silu", True), 0.52),
+    (("openpangu 8x(7680x2048) top-8/256", 7680, 7680, 2048, 256, 8, 8,
+      "silu", True), 0.62),
+]
 
 
 def held_shape(shape):
@@ -425,11 +442,12 @@ def held_shape(shape):
     return (shape[0], 64, 64, 256, 8, 4, 2, *shape[7:])
 
 
-def held_inputs(shape, rows: int, calls: int):
+def held_inputs(shape, rows: int, calls: int, open_share: float = 1.0):
     """Seeded experts, and for each of `calls` calls seeded rows routed by
     a sigmoid router of the published width: (weights, v [calls, rows, L],
     the combine weights of the held experts [calls, rows, held], 0 off
-    the chosen)."""
+    the chosen). `open_share`: the leading share of the held experts a row
+    may choose; the rest are held and chosen by nobody."""
     _, H, L, inner, published, held, k, _, gated = shape
     key = jax.random.split(jax.random.PRNGKey(rows), 6)
     weights = {
@@ -445,6 +463,9 @@ def held_inputs(shape, rows: int, calls: int):
     router = jax.random.normal(key[4], (H, published), jnp.bfloat16) * H ** -0.5
     scores = jax.nn.sigmoid(jnp.einsum(
         "crh,he->cre", tokens, router, preferred_element_type=jnp.float32))
+    at = jnp.arange(published)
+    scores = jnp.where((at >= round(open_share * held)) & (at < held),
+                       -1.0, scores)
     chosen, idx = jax.lax.top_k(scores, k)
     chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     dense = jnp.sum(jax.nn.one_hot(idx, published) * chosen[..., None],
@@ -454,19 +475,22 @@ def held_inputs(shape, rows: int, calls: int):
     return weights, v, dense
 
 
-def time_held(shape, rows: int, grouped: bool) -> str:
+def time_held(shape, rows: int, grouped: bool,
+              open_share: float = 1.0) -> str:
     """µs a call of the held experts' product, masked or grouped (the
     grouped call with its sort, gather and combine), beside the least time
-    the held experts' bytes allow. Calls run back to back inside one
-    jitted scan, each on rows and a routing of its own (the grouped call
-    with its counting sort; the router's own work is in neither)."""
+    the held experts' bytes allow, all of them and the hit ones (those
+    some row of the call chose: the grouped form's whole read). Calls run
+    back to back inside one jitted scan, each on rows and a routing of its
+    own (the grouped call with its counting sort; the router's own work is
+    in neither)."""
     from polykey_tpu.ops import hybrid_kernels as hk
 
     shape = held_shape(shape)
     _, _, L, inner, _, held, k, activation, _ = shape
     interpret = "interpret" in KERNEL
     calls = 2 if interpret else HELD_CALLS
-    weights, v, dense = held_inputs(shape, rows, calls)
+    weights, v, dense = held_inputs(shape, rows, calls, open_share)
 
     def product(v, dense, up, down, gate):
         how = {"gate": gate, "activation": activation, "interpret": interpret}
@@ -496,6 +520,7 @@ def time_held(shape, rows: int, grouped: bool) -> str:
     us = (seconds(scan_of(product)) - seconds(empty)) / calls * 1e6
     nbytes = sum(w.size * w.dtype.itemsize for w in weights.values())
     pairs = float(jnp.mean(jnp.sum(dense > 0, axis=(1, 2))))
+    hit = float(jnp.mean(jnp.any(dense > 0, axis=1)))
     flops = 2 * L * inner * len(weights) * (
         pairs if grouped else rows * held)
     if interpret:
@@ -505,6 +530,8 @@ def time_held(shape, rows: int, grouped: bool) -> str:
     least = nbytes / peak * 1e6
     return (f"{us:.1f} us/call; experts {nbytes / 1e6:.1f} MB = {least:.1f} us "
             f"at {peak / 1e9:.0f} GB/s ({100 * least / us:.1f} %); "
+            f"hit {100 * hit:.1f} % of them = {hit * least:.1f} us "
+            f"({100 * hit * least / us:.1f} %); "
             f"{pairs:.0f} held pairs of {rows * k}, "
             f"{flops / 1e9:.1f} GFLOP computed")
 
@@ -551,20 +578,23 @@ def compare_held(shape, rows: int) -> str:
 
 
 def check_held_experts() -> None:
-    from polykey_tpu.ops.hybrid_kernels import MOE_GROUPED_ABOVE_ROWS
-
+    # (served): the form `ops/moe.py` `_held_product` takes on the chip —
+    # the grouped one at every row count.
+    forms = ((False, "masked"), (True, "grouped (served)"))
     for shape in HELD_SHAPES:
-        for rows in (512, 1024):
+        for rows in (64, 512, 1024):
             case("held-compare", f"{shape[0]} rows={rows}",
                  partial(compare_held, shape, rows))
         for rows in HELD_ROWS:
-            for grouped in (False, True):
-                served = grouped == (rows > MOE_GROUPED_ABOVE_ROWS)
-                case("held-time",
-                     f"{shape[0]} rows={rows} "
-                     f"{'grouped' if grouped else 'masked'}"
-                     f"{' (served)' if served else ''}",
+            for grouped, form in forms:
+                case("held-time", f"{shape[0]} rows={rows} {form}",
                      partial(time_held, shape, rows, grouped))
+    for shape, open_share in HELD_DECODE:
+        for grouped, form in forms:
+            case("held-time",
+                 f"{shape[0]} decode rows=64 open {100 * open_share:.0f} % "
+                 f"{form}",
+                 partial(time_held, shape, 64, grouped, open_share))
 
 
 # -- the gated delta rule's decode state update -------------------------------

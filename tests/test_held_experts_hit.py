@@ -5,7 +5,10 @@ row of the block's one packed download (engine `_decode_fn`), and kept as
 two monotone counters, `held_experts_hit` and `held_expert_calls`
 (engine/metrics.py). Every count here is compared with numpy's count of the
 same combine weights over the same live lanes; a model without an expert
-layer keeps its program's shapes and has neither counter."""
+layer keeps its program's shapes and has neither counter. Since ISSUE 56
+the count is also what the product READS: `run_stack` zeroes an idle
+lane's combine weights, the grouped kernel gives an expert nobody chose
+no tile, and a live lane's tokens are what they were."""
 
 import functools
 
@@ -28,10 +31,12 @@ from polykey_tpu.models.transformer import (
     init_params,
 )
 from polykey_tpu.obs.exposition import engine_collector
+from polykey_tpu.ops import hybrid_kernels, moe
 from polykey_tpu.ops.moe import held_experts_hit
+import grouped_experts
 from pattern_stack import PAGES_PER_SEQ, SLOTS, SlotBatch, served, text
 
-PATTERNS = ["tiny-hybrid", "tiny-lfm2", "tiny-qwen3-next"]
+PATTERNS = ["tiny-hybrid", "tiny-lfm2", "tiny-qwen3-next", "tiny-pangu"]
 STEPS = 4
 
 
@@ -98,19 +103,29 @@ def test_a_decode_step_counts_its_live_lanes_choices(name, monkeypatch):
     every expert layer, and `forward_slots` is the same step without it."""
     cfg, params, paged, state, last, seq, tables = lanes_after_prefill(name)
     active = jnp.asarray([True, False, False, True])
-    spy = Spy()
+    spy, routed = Spy(), []
     monkeypatch.setattr(hybrid, "held_experts_hit", spy)
+
+    def as_routed(p, tokens, cfg):
+        routed.append(np.asarray(moe.held_weights(p, tokens, cfg)))
+        return routed[-1]
+
+    monkeypatch.setattr(hybrid, "held_weights", as_routed)
     step = functools.partial(
         forward_slots_counted, params, cfg, jnp.asarray(last)[:, None],
         jnp.asarray(seq - 1)[:, None], paged, jnp.asarray(tables), state,
         active=active)
     hidden, _, _, hits = step()
-    assert len(spy.calls) == cfg.layer_pattern.count("E")
+    assert len(spy.calls) == len(routed) == cfg.layer_pattern.count("E")
     assert int(hits) == sum(counted_by_numpy(w, live) for w, live in spy.calls)
     assert all((live == np.asarray(active)).all() for _, live in spy.calls)
+    # What is counted is what the product is handed (ISSUE 56): the
+    # router's weights on the live lanes, zeros on the idle ones.
+    for (w, live), raw in zip(spy.calls, routed):
+        np.testing.assert_array_equal(w, np.where(live[:, None], raw, 0.0))
     # The case bites: the idle lanes chose held experts no live lane chose.
     everyone = np.ones((SLOTS,), bool)
-    assert int(hits) < sum(counted_by_numpy(w, everyone) for w, _ in spy.calls)
+    assert int(hits) < sum(counted_by_numpy(w, everyone) for w in routed)
     # Bounded by what a layer holds and by what the live lanes can choose.
     assert 0 < int(hits) <= len(spy.calls) * min(
         cfg.experts_held, 2 * cfg.num_experts_per_tok)
@@ -173,6 +188,56 @@ def test_a_block_sends_its_count_home_as_one_more_row(name, monkeypatch):
             assert (live == (tokens[k] >= 0)).all()
     want = sum(counted_by_numpy(w, live) for w, live in spy.calls)
     assert want > 0 and (row == want).all()
+
+
+def as_the_parent_routed(p, h, cfg, weights=None):
+    """`moe_held` as `run_stack` called it before ISSUE 56: every lane,
+    idle or not, with the weights its own token routed to."""
+    del weights
+    return moe.moe_held(p, h, cfg)
+
+
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+@pytest.mark.parametrize("name", PATTERNS)
+def test_live_lanes_stream_what_they_did_beside_idle_ones(
+        name, form, monkeypatch):
+    """A greedy block of `_decode_fn` with lanes 1 and 2 idle and lane 0
+    ending after two steps: every live lane's tokens, step by step, are
+    those of the block whose expert layers see the idle lanes' routing too
+    (the parent's `run_stack`) — off the chip, and with the grouped kernel
+    (interpret mode) as the chip runs it."""
+    cfg, params, paged, state, last, seq, tables = lanes_after_prefill(name)
+    active = np.asarray([True, False, False, True])
+    caps = seq + np.asarray([2, 0, 0, 100])
+    if form == "kernel":
+        monkeypatch.setattr(moe, "held_experts_grouped", lambda rows: True)
+        monkeypatch.setattr(
+            hybrid_kernels, "moe_held_experts_grouped", functools.partial(
+                hybrid_kernels.moe_held_experts_grouped, interpret=True))
+    block = decode_block(cfg, last, seq, tables, active, caps)
+    with jax.disable_jit():
+        got = np.asarray(block(params, paged, state)[0])
+        monkeypatch.setattr(hybrid, "moe_held", as_the_parent_routed)
+        want = np.asarray(block(params, paged, state)[0])
+    live = want[:-1] >= 0
+    assert live.sum() == 2 + STEPS
+    np.testing.assert_array_equal(got[:-1] >= 0, live)
+    np.testing.assert_array_equal(got[:-1][live], want[:-1][live])
+    # The count was always the live lanes': it is the same number.
+    np.testing.assert_array_equal(got[-1], want[-1])
+
+
+@pytest.mark.parametrize("live", list(grouped_experts.LIVE))
+@pytest.mark.parametrize("form", list(grouped_experts.DECODE_FORMS))
+def test_grouped_kernel_on_a_decode_call_matches_jnp(form, live):
+    """64 rows, the idle lanes' weights zeroed: two idle, ONE live, NONE."""
+    grouped_experts.check_decode_call(form, live)
+
+
+@pytest.mark.parametrize("live", list(grouped_experts.LIVE))
+@pytest.mark.parametrize("form", list(grouped_experts.DECODE_FORMS))
+def test_the_kernels_read_is_the_counters_count(form, live):
+    grouped_experts.check_read_is_the_count(form, live)
 
 
 @pytest.mark.parametrize("name, rows", [

@@ -132,10 +132,10 @@ def test_grouped_held_experts_match_jnp(rows, routing):
 
 
 @pytest.mark.parametrize("rows, form", grouped_experts.RULE)
-def test_the_row_count_alone_chooses_the_held_product(
+def test_the_backend_alone_chooses_the_held_product(
         rows, form, params, monkeypatch):
-    """`moe_held` of a latent layer: the jnp form off the chip; on
-    it the masked kernel up to 128 rows, the grouped one from 512."""
+    """`moe_held` of a latent layer: the jnp form off the chip; on it the
+    grouped kernel at every row count, a decode step's 64 included."""
     grouped_experts.check_rule(
         rows, form, params["layers"]["moe"][0], CFG, monkeypatch)
 
@@ -300,12 +300,16 @@ def test_engine_serves_what_the_reference_computes(
 
 
 @pytest.mark.parametrize("rule, want", [
-    # The rule answering from 64 rows up: a 150-token prompt rides two
-    # 64-wide chunks (counted) and two 16-rows in one dispatch (not).
+    # As it answers on the chip (ISSUE 56): yes, at any row count — the
+    # two 64-wide chunks a 150-token prompt rides and the two 16-rows in
+    # one dispatch after them.
+    (lambda rows: True, 160),
+    # A rule of rows would still be asked dispatch by dispatch: the
+    # chunks counted, the narrow dispatch not.
     (lambda rows: rows >= 64, 128),
     # As it answers off the chip: the jnp form ran every row.
     (None, 0),
-], ids=["wide-dispatches", "off-chip"])
+], ids=["on-chip", "by-dispatch", "off-chip"])
 def test_engine_counts_the_rows_its_expert_layers_ran_grouped(
         engine, rule, want, monkeypatch):
     """`prefill_rows_grouped_experts` (ISSUE 48): host-known, counted by

@@ -145,10 +145,11 @@ def test_grouped_held_experts_match_jnp(rows, routing):
 
 
 @pytest.mark.parametrize("rows, form", grouped_experts.RULE)
-def test_the_row_count_alone_chooses_the_held_product(
+def test_the_backend_alone_chooses_the_held_product(
         rows, form, params, monkeypatch):
-    """`moe_held` of gated experts on the full hidden: the jnp form off the chip; on
-    it the masked kernel up to 128 rows, the grouped one from 512."""
+    """`moe_held` of gated experts on the full hidden: the jnp form off
+    the chip; on it the grouped kernel at every row count, a decode step's
+    64 included."""
     grouped_experts.check_rule(
         rows, form, params["layers"]["moe"][0], CFG, monkeypatch)
 

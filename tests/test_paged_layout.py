@@ -387,17 +387,21 @@ def test_hybrid_decode_step_compiled_for_v5e_aliases_its_state(v5e, monkeypatch)
     for name in ("%ssm_state_update", "%moe_held_experts", "%paged_kv_write",
                  "%paged_attention_decode"):
         assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
-    # The decode program holds the masked call and no sort (ISSUE 48).
-    assert not any("grouped" in c for c in calls), calls
+    # The decode program holds the grouped call (ISSUE 56: the experts no
+    # live lane chose are not read), whose order is a counting sort: no
+    # sort by comparison.
+    assert sum(c.startswith("%moe_held_experts_grouped") for c in calls) == 1
     assert sorts(hlo) == []
 
 
-def test_latent_prefill_of_512_rows_compiled_for_v5e_runs_grouped(
-        v5e, monkeypatch):
-    """The same stack's prefill step at four 128-token windows: the held
-    experts' product is the grouped call (one kernel), at the published
-    widths of a latent expert layer; at one window it is the masked call
-    the decode step has."""
+@pytest.mark.parametrize("prefill", [(4, 128), (1, 128)],
+                         ids=["prefill-4x128", "prefill-1x128"])
+def test_latent_prefill_compiled_for_v5e_runs_grouped(
+        v5e, monkeypatch, prefill):
+    """The same stack's prefill step at four 128-token windows and at one:
+    the held experts' product is the grouped call (one kernel) the decode
+    step has, at the published widths of a latent expert layer — the row
+    count decides nothing (ISSUE 56)."""
     from polykey_tpu.models.config import get_config
 
     cfg = replace(
@@ -409,15 +413,14 @@ def test_latent_prefill_of_512_rows_compiled_for_v5e_runs_grouped(
         moe_shared_intermediate=256, n_routed_experts=32, experts_held=8,
         num_experts_per_tok=6,
     )
-    for prefill, name in (((4, 128), "%moe_held_experts_grouped"),
-                          ((1, 128), "%moe_held_experts.")):
-        compiled, _, _ = _compile_pattern_step(
-            v5e, monkeypatch, cfg, prefill=prefill)
-        hlo = compiled.as_text()
-        held = [c for c in _kernel_calls(hlo)
-                if c.startswith("%moe_held_experts")]
-        assert len(held) == 1 and (held[0] + ".").startswith(name), held
-        assert sorts(hlo) == []
+    compiled, _, _ = _compile_pattern_step(
+        v5e, monkeypatch, cfg, prefill=prefill)
+    hlo = compiled.as_text()
+    held = [c for c in _kernel_calls(hlo)
+            if c.startswith("%moe_held_experts")]
+    assert len(held) == 1 and held[0].startswith(
+        "%moe_held_experts_grouped"), held
+    assert sorts(hlo) == []
 
 
 @pytest.mark.parametrize("prefill", [None, (1, 128), (2, 512)],
@@ -451,11 +454,9 @@ def test_operator_ffn_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
     assert layer_weight_copies(hlo, "bf16", cfg.hidden_size) == []
     calls = _kernel_calls(hlo)
     assert sum(c.startswith("%moe_held_experts") for c in calls) == 1, calls
-    # 1,024 rows run sorted by expert; a one-window prefill and a decode
-    # step keep the masked call (ISSUE 48). No module sorts by comparison.
-    grouped = prefill == (2, 512)
-    assert any(c.startswith("%moe_held_experts_grouped")
-               for c in calls) == grouped, calls
+    # 1,024 rows, a one-window prefill and a decode step alike run sorted
+    # by expert (ISSUE 56). No module sorts by comparison.
+    assert any(c.startswith("%moe_held_experts_grouped") for c in calls), calls
     assert sorts(hlo) == []
     assert jax.tree.leaves(state.ssm) == []
     if prefill is not None:
@@ -512,8 +513,7 @@ def test_delta_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
     assert layer_weight_copies(hlo, "bf16", cfg.hidden_size) == []
     calls = _kernel_calls(hlo)
     assert sum(c.startswith("%moe_held_experts") for c in calls) == 2, calls
-    grouped = prefill == (2, 512)
-    assert all(c.startswith("%moe_held_experts_grouped") == grouped
+    assert all(c.startswith("%moe_held_experts_grouped")
                for c in calls if c.startswith("%moe_held_experts")), calls
     assert sorts(hlo) == []
     updates = sum(c.startswith("%gated_delta_state_update") for c in calls)
@@ -549,8 +549,8 @@ def test_latent_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
     lane's table out of the pool, and the held experts' call fits its
     weight blocks in VMEM (the inner tile follows bytes: 256 of 2048).
     Prefill: the blockwise kernel over the gathered rows, once a latent
-    layer, and never the decode kernel; 1,024 rows of experts run grouped,
-    256 rows a call."""
+    layer, and never the decode kernel; the experts run grouped at every
+    width, 256 rows a call."""
     from polykey_tpu.models.config import get_config
 
     cfg = replace(
@@ -580,7 +580,7 @@ def test_latent_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
     if prefill is None:
         assert (reads, flash) == (2, 0), calls
         assert sum(c.startswith("%paged_kv_write") for c in calls) == 2
-        assert len(held) == 1 and "grouped" not in held[0], calls
+        assert len(held) == 1 and "grouped" in held[0], calls
         # No lane's window is gathered out of the pool: the only gathers
         # read the embedding's rows and the router's choices.
         tables = [line for line in hlo.splitlines()
@@ -588,9 +588,8 @@ def test_latent_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
         assert tables == []
     else:
         assert (reads, flash) == (0, 2), calls
-        grouped = prefill == (2, 512)
-        assert len(held) == (4 if grouped else 1), calls
-        assert all(c.startswith("%moe_held_experts_grouped") == grouped
+        assert len(held) == (4 if prefill == (2, 512) else 1), calls
+        assert all(c.startswith("%moe_held_experts_grouped")
                    for c in held), calls
 
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import grouped_experts
 import reference_pangu_ultra_moe as ref
 from pattern_stack import SLOTS, SlotBatch, served, text, worst_margin
 from polykey_tpu.engine.config import EngineConfig
@@ -145,6 +146,16 @@ def test_absorbed_equals_expanded_at_one_position(params):
     probs = jax.nn.softmax(absorbed @ row.T * scale, axis=-1)
     got = jnp.einsum("hc,hcv->hv", probs @ row[:, :rank], p["w_uv"])
     np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+@pytest.mark.parametrize("rows, form", grouped_experts.RULE)
+def test_the_backend_alone_chooses_the_held_product(
+        rows, form, params, monkeypatch):
+    """`moe_held` of sigmoid-routed gated experts with a plain shared
+    expert: the jnp form off the chip; on it the grouped kernel at every
+    row count, a decode step's 64 included."""
+    grouped_experts.check_rule(
+        rows, form, params["layers"]["moe"][0], CFG, monkeypatch)
 
 
 def test_the_two_shares_add_up_to_the_uncut_layer(params):

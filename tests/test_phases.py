@@ -564,7 +564,8 @@ def test_a_model_without_expert_layers_counts_no_grouped_rows(
         live, monkeypatch):
     """`prefill_rows_grouped_experts` follows the rule `moe_held` decides
     by only where the model has an "E" layer: with the rule answering yes
-    for every dispatch, a plain decoder still counts none."""
+    for every dispatch, as it does on the chip, a plain decoder still
+    counts none."""
     from polykey_tpu.engine import engine as engine_mod
 
     monkeypatch.setattr(engine_mod, "held_experts_grouped", lambda rows: True)

@@ -262,11 +262,11 @@ def test_partial_rotary_turns_the_leading_dims_only():
 
 
 @pytest.mark.parametrize("rows, form", grouped_experts.RULE)
-def test_the_row_count_alone_chooses_the_held_product(
+def test_the_backend_alone_chooses_the_held_product(
         rows, form, params, monkeypatch):
     """`moe_held` of softmax-routed gated experts with a shared expert: the
-    jnp form off the chip; on it the masked kernel up to 128 rows, the
-    grouped one from 512 — the shared expert beside either."""
+    jnp form off the chip; on it the grouped kernel at every row count, a
+    decode step's 64 included — the shared expert beside either."""
     grouped_experts.check_rule(
         rows, form, params["layers"]["moe"][0], CFG, monkeypatch)
 
