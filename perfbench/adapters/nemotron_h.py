@@ -47,14 +47,20 @@ def model_config(spec: dict, tiny: bool):
 def weights(spec: dict, tiny: bool, engine_config, model_cfg, seed: int):
     """The package's own seeded init of a hybrid stack (one layer a jitted
     call on the device: no leaf ever exists in float32 or on the host),
-    keyed by `seed`."""
+    keyed by `seed`, its routers centred as training centres them over
+    the configuration's own plain reference (perfbench/router_fill.py)."""
     import jax
     import jax.numpy as jnp
 
+    import extension
+    import router_fill
     from polykey_tpu.models.hybrid import init_params
 
-    return init_params(jax.random.PRNGKey(seed), model_cfg,
-                       jnp.dtype(engine_config.dtype))
+    params = init_params(jax.random.PRNGKey(seed), model_cfg,
+                         jnp.dtype(engine_config.dtype))
+    return router_fill.centred(
+        params, model_cfg, seed,
+        extension.load("references", spec["reference"]["module"]))
 
 
 def replay_logits(params, paged, state, tokens, table, fed, n, *, cfg):

@@ -120,14 +120,26 @@ def gated_delta_state_update(spec: dict, lanes: float) -> dict:
             "flops": 6 * elements}
 
 
+def chosen_pairs(spec: dict, rows: float) -> float:
+    """The (row, held expert) pairs a call over `rows` tokens computes, in
+    EXPECTATION under a balanced router: each row chooses top-k of the
+    router's outputs, and the held are `held` of them — rows x top-k x
+    held / router width (a row's choices that fall on another chip's
+    experts are that chip's work)."""
+    d = _dims(spec)
+    return rows * spec["num_experts_per_tok"] * d["held"] / d["routed"]
+
+
 def moe_held_experts(spec: dict, rows: float, hit_share: float = 1.0) -> dict:
     """One call over `rows` tokens: the three matrices of every held
     expert that was hit once, the hidden rows in and the float32 sum out,
-    the combine weights; all three products for every (row, held expert)
-    pair — what the masked form computes, and far from binding at decode
-    widths."""
+    the combine weights; all three products for the CHOSEN (row, held
+    expert) pairs, in expectation (`chosen_pairs`) — what the sorted
+    kernel computes; the masked form's every-row-by-every-held-expert is
+    computed by nothing since PR 56. Memory-bound at decode widths at
+    every hit share."""
     d = _dims(spec)
     weights = hit_share * held_experts_params(spec) * WEIGHT_BYTES
     rows_io = rows * d["hidden"] * (ACT_BYTES + 4) + rows * d["held"] * 4
     return {"bytes": weights + rows_io,
-            "flops": rows * d["held"] * 6 * d["hidden"] * d["expert"]}
+            "flops": chosen_pairs(spec, rows) * 6 * d["hidden"] * d["expert"]}

@@ -28,9 +28,13 @@ CHANNEL_OPTIONS = [
 REQUEST_TIMEOUT_S = 300.0
 
 
-def generate_request(prompt: str, max_tokens: int):
+def generate_request(prompt: str, max_tokens: int, sampling=None):
+    """`sampling`: the request parameters of a sampled request
+    (traffic.Plan.sampling); None asks for the served API's default,
+    greedy."""
     request = pk.ExecuteToolRequest(tool_name="llm_generate")
-    request.parameters.update({"prompt": prompt, "max_tokens": max_tokens})
+    request.parameters.update(
+        {"prompt": prompt, "max_tokens": max_tokens, **(sampling or {})})
     return request
 
 
@@ -41,14 +45,15 @@ def new_record(client: int, index: int, prompt_tokens: int, asked: int) -> dict:
 
 
 def stream(stub, prompt: str, max_tokens: int, record: dict,
-           on_call=None, keep_text: bool = False) -> dict:
+           on_call=None, keep_text: bool = False, sampling=None) -> dict:
     """One streamed generation into `record` (times on time.monotonic);
     the streamed characters are kept only where the caller compares them."""
     if keep_text:
         record["text"] = []
     record["send"] = time.monotonic()
     call = stub.ExecuteToolStream(
-        generate_request(prompt, max_tokens), timeout=REQUEST_TIMEOUT_S
+        generate_request(prompt, max_tokens, sampling),
+        timeout=REQUEST_TIMEOUT_S
     )
     if on_call is not None:
         on_call(call)
@@ -121,7 +126,8 @@ class ClosedLoop:
             with self._lock:
                 self.records.append(record)
             stream(self.stub, req.prompt, req.output_tokens, record,
-                   on_call=lambda call: self._calls.__setitem__(i, call))
+                   on_call=lambda call: self._calls.__setitem__(i, call),
+                   sampling=req.sampling)
             if record["error"] and record["error"] != "cancelled":
                 # A failing server must not be hammered in a tight loop.
                 if self._stop.wait(0.5):

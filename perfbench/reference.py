@@ -186,6 +186,55 @@ def judge(margins: list, outside_head: int, limits: dict) -> dict:
     }
 
 
+def sampled_margins(rows, served: list, allowed, top_k: int) -> list:
+    """Per served token of a request SAMPLED under `top_k`: how far its
+    reference logit lies below the reference's `top_k`-th largest over the
+    ids the narrowed head allows (0 where it is among them). The greedy
+    margin above is this number at `top_k` 1."""
+    margins = []
+    for row, token in zip(rows, served):
+        kth = float(np.sort(row[allowed])[-top_k])
+        margins.append(max(0.0, kth - float(row[token])))
+    return margins
+
+
+def judge_sampled(forward_fn, params, cfg, sample: dict, limits: dict) -> dict:
+    """The verdict on the sampled companion of the served sample
+    (`sample["sampled"]`: its ids and the `top_k` it was drawn under; a
+    window of sampled requests runs the sampled variant of every step, and
+    a greedy token cannot show its truncation or its draw): the reference
+    teacher-forced with the companion's tokens, `sampled_margins`, and the
+    same limits as `judge` holds a greedy margin to — a served token that
+    the truncation should have cut reads the distance to the cut, an id
+    outside the narrowed head is counted. No clause on the exact share: a
+    draw is not an argmax."""
+    part = sample["sampled"]
+    prompt, served = part["prompt_ids"], part["output_ids"]
+    allowed = np.zeros(cfg.vocab_size, bool)
+    allowed[sample["allowed_first"]:sample["allowed_last"] + 1] = True
+    rows = forward_fn(params, cfg, prompt + served[:-1])[len(prompt) - 1:]
+    margins = sampled_margins(rows, served, allowed, int(part["top_k"]))
+    threshold = limits["max_margin"]
+    max_outliers = limits.get("max_outliers", 0)
+    max_mean = limits.get("max_mean_margin")
+    outliers = sum(m > threshold for m in margins)
+    mean = float(np.mean(margins))
+    outside = sum(not allowed[t] for t in served)
+    clauses = [
+        (outliers <= max_outliers,
+         f"sampled_outliers {outliers} (limit {max_outliers}, margins under "
+         f"the top-{part['top_k']} cut over {threshold:g}; largest "
+         f"{max(margins):.4g})"),
+        (max_mean is None or mean <= max_mean,
+         f"sampled_mean_margin {mean:.4g} (limit {max_mean})"),
+        (outside == 0, f"sampled_outside_head {outside} (limit 0)"),
+    ]
+    why = [text for held, text in clauses if not held]
+    return {"ok": not why, "why": why,
+            "checks": ", ".join(text for _, text in clauses),
+            "margins": margins, "tokens": len(margins)}
+
+
 def compare(params, cfg, sample: dict, limits: dict, forward_fn=None) -> dict:
     """Teacher-force the reference with the served tokens and judge the
     margins; see module doc. `forward_fn`: the `forward` of the

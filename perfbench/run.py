@@ -12,9 +12,11 @@ its standard output. `--tiny` rehearses the same code on the CPU at toy
 size and reports platform=cpu and no device metric.
 
 Set-up (process start -> window open) = engine construction and seeded
-weights, loading or compiling every executable the cell's shapes use, one
-sample request for the comparison with the plain reference, and the
-clients' ramp. The window opens once every client's first request streams.
+weights, loading or compiling every executable the cell's shapes use, the
+served sample for the comparison with the plain reference (one greedy
+request; among sampled companions on every slot where the configuration's
+requests are sampled: `serve_sample`), and the clients' ramp. The window
+opens once every client's first request streams.
 
 WHAT A CONFIGURATION MAY BRING (the extension contract). A model whose
 block is not the GQA decoder the harness was written around brings code
@@ -67,6 +69,12 @@ exactly as before these keys existed.
       `held_experts_hit` and `held_expert_calls` (counters.held_hit_share).
     It runs in this process, which never imports JAX: plain Python.
     Absent: perfbench/kernel_costs.py itself (kernel_costs.for_spec).
+  "sampling": {"temperature": .., "top_k": .., "top_p": ..} -> the request
+    parameters of the served API that every request of the window carries,
+    with a sampling seed of its own (traffic.Plan.sampling), under any
+    traffic file; the child warms the sampled variant of every step, and
+    `correct` also holds one sampled companion's draws to the reference's
+    top-k (reference.judge_sampled). Absent: greedy requests.
   "kernels": ["<name>", ...] -> device operations whose name starts with
     <name> are summed under trace["kernels"][<name>] by trace_reduce.py,
     after the four kernels it knows (first match wins, those four first,
@@ -207,9 +215,28 @@ class Tools:
         self.channel.close()
 
 
-def serve_sample(address: str, seed: int, out_dir: str) -> dict:
-    """One short request alone on the idle engine: the served greedy
-    sample the child later compares with the plain reference."""
+def sample_ids(prompt: str, text: str) -> dict:
+    return {"prompt_ids": [1] + [3 + b for b in prompt.encode()],
+            "output_ids": [3 + b for b in text.encode()]}
+
+
+def serve_sample(address: str, seed: int, out_dir: str, plan=None) -> list:
+    """The served greedy sample the child later compares with the plain
+    reference: one short request, alone on the idle engine where the
+    configuration's requests are greedy; the records of every request
+    sent, the sample's first.
+
+    Where they are sampled (`plan.sampled`) every step of the window runs
+    its SAMPLED variant (the engine keys the variant on the batch: one
+    sampled lane and all lanes take it, a greedy lane's argmax included),
+    so the sample is taken from those programs with every slot in use, as
+    the window has them: `plan.clients` - 2 sampled companions stream
+    first, four times as long; once each has its first token, the greedy
+    sample and one more sampled companion of its own size are sent
+    together. The greedy request's tokens then come from the sampled
+    decode step among 63 sampled lanes, and the last companion's tokens,
+    drawn under the configuration's own parameters, are kept beside them
+    (`sampled` in sample.json, for reference.judge_sampled)."""
     import random
 
     import grpc
@@ -218,22 +245,62 @@ def serve_sample(address: str, seed: int, out_dir: str) -> dict:
     import traffic
     from polykey_tpu.proto.polykey_v2_grpc import PolykeyServiceStub
 
-    rng = random.Random(f"{seed}/sample")
-    prompt = "".join(rng.choices(traffic.ALPHABET, k=SAMPLE_PROMPT_TOKENS - 1))
+    def prompt_of(key: str) -> str:
+        rng = random.Random(key)
+        return "".join(rng.choices(traffic.ALPHABET,
+                                   k=SAMPLE_PROMPT_TOKENS - 1))
+
+    prompt = prompt_of(f"{seed}/sample")
     record = loadgen.new_record(-1, 0, SAMPLE_PROMPT_TOKENS,
                                 SAMPLE_OUTPUT_TOKENS)
-    with grpc.insecure_channel(address) as channel:
-        loadgen.stream(PolykeyServiceStub(channel), prompt,
-                       SAMPLE_OUTPUT_TOKENS, record, keep_text=True)
-    text = "".join(record.pop("text"))
+    others, threads = [], []
+    with grpc.insecure_channel(
+            address, options=loadgen.CHANNEL_OPTIONS) as channel:
+        stub = PolykeyServiceStub(channel)
+
+        def send(rec, text, tokens, sampling):
+            thread = threading.Thread(
+                target=loadgen.stream, args=(stub, text, tokens, rec),
+                kwargs={"keep_text": True, "sampling": sampling}, daemon=True)
+            thread.start()
+            threads.append(thread)
+
+        if plan is not None and plan.sampled:
+            for i in range(1, plan.clients - 1):
+                rec = loadgen.new_record(-1, i, SAMPLE_PROMPT_TOKENS,
+                                         4 * SAMPLE_OUTPUT_TOKENS)
+                others.append(rec)
+                send(rec, prompt_of(f"{seed}/sample/{i}"), rec["asked"],
+                     plan.sampling(-1, i))
+            deadline = time.monotonic() + RAMP_TIMEOUT_S
+            while not all(r["times"] or r["error"] or r["final"]
+                          for r in others):
+                if time.monotonic() > deadline:
+                    raise BenchFailure("the sample's companions did not all "
+                                       "start streaming")
+                time.sleep(0.005)
+            drawn = loadgen.new_record(-1, plan.clients - 1,
+                                       SAMPLE_PROMPT_TOKENS,
+                                       SAMPLE_OUTPUT_TOKENS)
+            others.append(drawn)
+            drawn_prompt = prompt_of(f"{seed}/sample/drawn")
+            send(drawn, drawn_prompt, drawn["asked"],
+                 plan.sampling(-1, plan.clients - 1))
+        send(record, prompt, SAMPLE_OUTPUT_TOKENS, None)
+        for thread in threads:
+            thread.join(loadgen.REQUEST_TIMEOUT_S + 10.0)
+        if any(thread.is_alive() for thread in threads):
+            raise BenchFailure("a request of the served sample did not end")
+    sample = {**sample_ids(prompt, "".join(record.pop("text"))),
+              "allowed_first": traffic.FIRST_ID,
+              "allowed_last": traffic.LAST_ID}
+    texts = ["".join(rec.pop("text")) for rec in others]
+    if others and plan.sampled.get("top_k", 0) > 0:
+        sample["sampled"] = {**sample_ids(drawn_prompt, texts[-1]),
+                             "top_k": plan.sampled["top_k"]}
     with open(os.path.join(out_dir, "sample.json"), "w") as f:
-        json.dump({
-            "prompt_ids": [1] + [3 + b for b in prompt.encode()],
-            "output_ids": [3 + b for b in text.encode()],
-            "allowed_first": traffic.FIRST_ID,
-            "allowed_last": traffic.LAST_ID,
-        }, f)
-    return record
+        json.dump(sample, f)
+    return [record] + others
 
 
 def request_faults(record: dict) -> list:
@@ -371,7 +438,7 @@ def run(args) -> dict:
     with open(config_path) as f:
         spec = json.load(f)
     traffic = traffic_mod.scaled(traffic_mod.load(cell["traffic"]), args.tiny)
-    plan = traffic_mod.Plan(traffic, args.seed)
+    plan = traffic_mod.Plan(traffic, args.seed, spec.get("sampling"))
 
     out_dir = os.path.join(HERE, "out", args.workload)
     os.makedirs(out_dir, exist_ok=True)
@@ -406,7 +473,7 @@ def run(args) -> dict:
         used = len(stats_ready["devices"])
         peak_row = None if args.tiny else peaks.row(stats_ready["device_kind"])
 
-        sample = serve_sample(address, args.seed, out_dir)
+        sample = serve_sample(address, args.seed, out_dir, plan)
 
         loop = loadgen.ClosedLoop(address, plan)
         loop.start()
@@ -496,7 +563,7 @@ def run(args) -> dict:
         with open(ref_path) as f:
             reference = json.load(f)
 
-    requests = [sample] + loop.records
+    requests = sample + loop.records
     verdict = decide_correct(stats_ready, stats_close, stats_end, requests,
                              reference)
     checks = verdict["checks"]

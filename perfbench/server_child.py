@@ -105,9 +105,11 @@ def engine_config_from(spec: dict, tiny: bool):
         quantize_bits=4 if quantize == "int4" else 8,
         tp=eng.get("tp", 1),
         compile_warmup=True,
-        # These cells send greedy requests only: the sampled variants of
-        # every step would be compiled and loaded for nothing.
-        warm_sampled_variants=False,
+        # Greedy requests only: the sampled variants of every step would
+        # be compiled and loaded for nothing. A configuration whose
+        # requests are sampled (its "sampling" group) has them on the
+        # measured path.
+        warm_sampled_variants=bool(spec.get("sampling")),
     )
     geometry = {k: eng[k] for k in (
         "max_decode_slots", "page_size", "num_pages", "max_seq_len",
@@ -173,19 +175,33 @@ def release_pools(engine) -> None:
 def compare_with_reference(engine, sample: dict, limits: dict) -> dict:
     """The verdict of the plain reference: perfbench/reference.py, or the
     module the "reference" group names (its `compare`, or its `forward`
-    under the shared teacher forcing and `judge`)."""
+    under the shared teacher forcing and `judge`); for a configuration
+    whose requests are sampled, `reference.judge_sampled` on the sample's
+    sampled companion beside it."""
     import reference
 
+    forward = reference.forward
     if "module" not in limits:
-        return reference.compare(engine.params, engine.model_cfg, sample,
-                                 limits)
-    module = extension.load("references", limits["module"])
-    if hasattr(module, "compare"):
-        result = module.compare(engine.params, engine.model_cfg, sample, limits)
-    else:
         result = reference.compare(engine.params, engine.model_cfg, sample,
-                                   limits, forward_fn=module.forward)
-    return {**result, "module": limits["module"]}
+                                   limits)
+    else:
+        module = extension.load("references", limits["module"])
+        forward = module.forward
+        if hasattr(module, "compare"):
+            result = module.compare(engine.params, engine.model_cfg, sample,
+                                    limits)
+        else:
+            result = reference.compare(engine.params, engine.model_cfg,
+                                       sample, limits, forward_fn=forward)
+        result = {**result, "module": limits["module"]}
+    if "sampled" in sample:
+        drawn = reference.judge_sampled(forward, engine.params,
+                                        engine.model_cfg, sample, limits)
+        result = {**result, "ok": result["ok"] and drawn["ok"],
+                  "why": result["why"] + drawn["why"],
+                  "checks": result["checks"] + ", " + drawn["checks"],
+                  "sampled": drawn}
+    return result
 
 
 def run_reference(engine, out_dir: str, spec: dict) -> None:

@@ -7,12 +7,22 @@ The seed chooses the order of a client's rows, the order of its think
 times and the prompt characters. So every seed carries the same multiset of
 sizes and of waits.
 
+Whether the requests are sampled is the CONFIGURATION's to say, as a
+model's generation parameters are published with the model: a
+configuration file with a "sampling" group (temperature, top_k, top_p: the
+request parameters of the served API) is sent sampled requests under any
+traffic file. Every request then carries those parameters and a sampling
+seed of its own, derived from (seed, client, request index), so that the
+same `--seed` asks for the same streams. A configuration without the group
+is sent greedy requests, as before the group existed.
+
 Prompt characters are printable ASCII: with the package's ByteTokenizer a
 prompt of n tokens is BOS + (n - 1) characters.
 """
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import random
@@ -34,6 +44,7 @@ class Request:
     prompt_tokens: int    # BOS + characters
     output_tokens: int
     think_s: float        # wait before sending (after the previous reply)
+    sampling: dict | None = None   # request parameters of a sampled request
 
 
 def load(name: str) -> dict:
@@ -60,9 +71,10 @@ def scaled(traffic: dict, tiny: bool) -> dict:
 class Plan:
     """request(client, k) is a pure function of (traffic file, seed)."""
 
-    def __init__(self, traffic: dict, seed: int):
+    def __init__(self, traffic: dict, seed: int, sampling: dict | None = None):
         self.traffic = traffic
         self.seed = seed
+        self.sampled = dict(sampling) if sampling else None
         self.clients = traffic["clients"]
         rows = traffic["rows_by_client"]
         if len(rows) != self.clients:
@@ -90,6 +102,17 @@ class Plan:
         rng = random.Random(key)
         return "".join(rng.choices(ALPHABET, k=chars))
 
+    def sampling(self, client: int, k: int) -> dict | None:
+        """The configuration's sampling parameters with this request's own
+        seed (48 bits: a Struct number is a double), or None for greedy.
+        `client` -1 is the harness's own sample (run.serve_sample)."""
+        group = self.sampled
+        if not group:
+            return None
+        digest = hashlib.sha256(
+            f"{self.seed}/sampling/{client}/{k}".encode()).digest()
+        return {**group, "seed": int.from_bytes(digest[:6], "big")}
+
     def request(self, client: int, k: int) -> Request:
         mine = self.rows[client]
         prompt_tokens, output_tokens = mine[k % len(mine)]
@@ -104,7 +127,8 @@ class Plan:
         text = self.prefix + self._text(f"{self.seed}/p/{client}/{k}", own)
         think = self.thinks[client][k % len(mine)]
         return Request(client, k, text, len(text) + 1, output_tokens,
-                       0.0 if k == 0 else think / 1000.0)
+                       0.0 if k == 0 else think / 1000.0,
+                       self.sampling(client, k))
 
     def shape(self) -> list:
         """What must not depend on the seed: per client, the sorted rows

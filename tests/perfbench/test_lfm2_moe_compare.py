@@ -190,7 +190,8 @@ def test_costs_are_the_shapes():
     kv = 64 * 450 * 2 * 2 * 8 * 64 * 2
     assert step == weights + state + kv
     call = costs.moe_held_experts(spec, 64)
-    assert call["flops"] == 64 * 64 * 6 * 2048 * 1536
+    # The chosen pairs (ISSUE 58): 64 rows x top-4, every expert held.
+    assert call["flops"] == 64 * 4 * 6 * 2048 * 1536
     assert abs(call["bytes"] / (experts / 8) - 1) < 2e-3
     # The shared decode kernel's reader reckons one call from this file.
     one = kernel_costs.paged_decode_call(spec, 64 * 450, 64)

@@ -90,7 +90,7 @@ def test_manifest_entry_lists_the_hybrid_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     hybrid = hybrid_cells(manifest)
-    assert len(hybrid) == 3
+    assert len(hybrid) >= 3
     entries = [m for m in manifest["per_layer"] if m["name"] == NAME]
     assert entries == [{
         "name": NAME, "unit": "%", "better": "higher",

@@ -242,7 +242,8 @@ def test_costs_are_the_shapes():
     assert step == weights + 64 * 450 * 7 * 1152
     assert 0.02 < (step - weights) / step < 0.03
     call = costs.moe_held_experts(spec, 64)
-    assert call["flops"] == 64 * 8 * 6 * 7680 * 2048
+    # The chosen pairs (ISSUE 58): 64 rows x top-8 x 8 held of 256.
+    assert call["flops"] == 64 * 8 * 8 / 256 * 6 * 7680 * 2048
     assert abs(call["bytes"] / (experts / 6) - 1) < 1e-2
     assert costs.held_experts(spec) == 8
     # The latent read: 450 rows of 1,152 B and 278,528 B of heads a lane;

@@ -212,7 +212,8 @@ def test_costs_are_the_shapes():
     assert step == weights + state + kv
     assert 0.70 < experts / step < 0.74 and 0.17 < state / step < 0.19
     call = costs.moe_held_experts(spec, 64)
-    assert call["flops"] == 64 * 128 * 6 * 2048 * 512
+    # The chosen pairs (ISSUE 58): 64 rows x top-10 x 128 held of 512.
+    assert call["flops"] == 64 * 10 * 128 / 512 * 6 * 2048 * 512
     assert abs(call["bytes"] / (experts / 12) - 1) < 2e-3
     update = costs.gated_delta_state_update(spec, 64)
     assert update["flops"] == 64 * 32 * 6 * 128 * 128
