@@ -83,6 +83,7 @@ from ..models.transformer import (
     unembed,
 )
 from ..ops.moe import held_experts_grouped
+from ..ops.paged_attention import prefill_bounded, prefill_keys_read
 from ..parallel.mesh import MeshConfig, create_mesh
 from ..parallel.sharding import (
     init_sharded_params,
@@ -2202,9 +2203,22 @@ class InferenceEngine:
         # expert, not every row against every held expert: ops/moe.py
         # decides by the same function.
         grouped = self._expert_layers and held_experts_grouped(rows)
+        # The keys each table row's attention read a layer: the program
+        # chooses by the same functions of its furthest position.
+        table = cfg.pages_per_seq * cfg.page_size
+        keys = table
+        if prefill_bounded(
+            bucket, table, cfg.page_size, self.model_cfg.kv_row_width
+            if self.model_cfg.latent_kv else self.model_cfg.head_dim,
+            self.model_cfg.latent_kv, self.mesh,
+        ):
+            keys = int(prefill_keys_read(
+                int(starts.max()) + bucket, bucket, table, cfg.page_size))
         self.metrics.on_prefill_rows(
             rows, real, n, sum(c > 1 for c in rows_of.values()),
             grouped_experts=rows if grouped else 0,
+            keys_read=n_pad * keys,
+            keys_table=n_pad * table,
         )
         if stateful:
             sources = collections.Counter(state_rows[:n, 1].tolist())

@@ -201,6 +201,11 @@ class EngineMetrics:
         # Of the rows dispatched, those whose expert layers ran the
         # grouped product (ops/moe.py held_experts_grouped).
         self.prefill_rows_grouped_experts = 0
+        # Keys a prefill dispatch's attention gathered and streamed a
+        # layer, summed over its table rows, beside what whole tables
+        # would have been (ops/paged_attention.py prefill_keys_read).
+        self.prefill_keys_read_total = 0
+        self.prefill_keys_table_total = 0
         # The decode program's expert layers, over the blocks whose
         # readback has landed: expert layers × steps with a live lane,
         # and the held experts a live lane chose there — the part of the
@@ -332,18 +337,23 @@ class EngineMetrics:
 
     def on_prefill_rows(self, dispatched: int, useful: int,
                         windows: int, split: int,
-                        grouped_experts: int = 0) -> None:
+                        grouped_experts: int = 0,
+                        keys_read: int = 0, keys_table: int = 0) -> None:
         """One bucketed-group or chunk prefill dispatch: `dispatched`
         rows computed (n_pad x bucket, or the chunk width) for `useful`
         real prompt tokens in `windows` real rows, `split` of its
         prompts covered by more than one of them; `grouped_experts`:
         `dispatched` again where the model's expert layers ran them as
-        the grouped product, else 0. Feeds the prefill-only counters
-        and, as before, the mixed padding-waste pair."""
+        the grouped product, else 0; `keys_read` of the `keys_table`
+        positions its rows' page tables span were gathered for the
+        attention. Feeds the prefill-only counters and, as before, the
+        mixed padding-waste pair."""
         with self._lock:
             self.prefill_rows_dispatched += dispatched
             self.prefill_rows_useful += useful
             self.prefill_rows_grouped_experts += grouped_experts
+            self.prefill_keys_read_total += keys_read
+            self.prefill_keys_table_total += keys_table
             self.prefill_windows_dispatched += windows
             self.prefill_prompts_split += split
             self.tokens_dispatched_total += dispatched
@@ -683,6 +693,8 @@ class EngineMetrics:
                 "prefill_rows_useful": self.prefill_rows_useful,
                 "prefill_rows_grouped_experts":
                     self.prefill_rows_grouped_experts,
+                "prefill_keys_read_total": self.prefill_keys_read_total,
+                "prefill_keys_table_total": self.prefill_keys_table_total,
                 "first_token_poll_gap_seconds":
                     round(self.first_token_poll_gap_seconds, 6),
                 "first_token_poll_gap_count":

@@ -224,10 +224,13 @@ def _run_stack(params, cfg: ModelConfig, tokens, positions, kv_scanned, attend):
     return x, new_k, new_v
 
 
-def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged, attend):
+def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged,
+                     attend, stage=None):
     """The stack over the paged KV pool: embed → scan(layer body) → final
-    norm, with the WHOLE stacked pool in the scan carry; returns (hidden,
-    updated paged).
+    norm, with the WHOLE stacked pool in the scan carry — and, beside it,
+    a prefill dispatch's `stage` (ops/paged_attention.py `prefill_stage`:
+    `attend` then gets and returns the pair); returns (hidden, updated
+    paged).
 
     The pool is stored [L, N, 2, page_size, Hk·D] (engine/kv_cache.py; a
     latent pool [L, N, 1, page_size, W]) and
@@ -255,11 +258,13 @@ def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged, attend)
         ), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    pool = _stacked(paged)
     (x, pool), _ = jax.lax.scan(
-        body, (x, _stacked(paged)), (params["layers"], layer_ids)
+        body, (x, pool if stage is None else (pool, stage)),
+        (params["layers"], layer_ids)
     )
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, norm_offset)
-    return x, _unstacked(paged, pool)
+    return x, _unstacked(paged, pool if stage is None else pool[0])
 
 
 def _stacked(paged):
@@ -444,9 +449,10 @@ def forward_slots_counted(
     layers on the same write paths and the latent read over a one-part
     pool."""
     from ..ops.paged_attention import (
-        latent_attention,
-        paged_attention,
+        latent_prefill_attention,
+        paged_prefill_attention,
         paged_write,
+        prefill_stage,
     )
     from ..ops.paged_attention_kernel import (
         mla_latent_decode,
@@ -454,43 +460,73 @@ def forward_slots_counted(
     )
 
     decode = tokens.shape[1] == 1
+    # Single-token steps take the DMA decode kernels (they read only valid
+    # pages, where they lie). A prefill dispatch gathers pages and runs the
+    # blockwise kernel over them (wide T amortizes the materialization) —
+    # the leading pages that hold the positions its queries see, 0 ..
+    # `keys` - 1 (read from the input: one replicated scalar for all
+    # layers), not the whole max_seq_len table — into staging buffers made
+    # once a dispatch and threaded through the layers beside the pool.
+    keys = stage = None
+    if not decode:
+        keys = jnp.max(positions) + 1
+        latent = cfg.latent_kv
+        stage = prefill_stage(
+            tokens.shape[0], page_tables.shape[1] * paged.page_size,
+            tokens.shape[1], paged.page_size,
+            heads=1 if latent else cfg.num_kv_heads,
+            width=cfg.kv_row_width if latent else cfg.head_dim,
+            parts=1 if latent else 2,
+            dtype=(jax.eval_shape(
+                lambda: embed_tokens(params, cfg, tokens)).dtype
+                if paged.quantized else paged.kv.dtype),
+            mesh=mesh,
+        )
 
-    def attend(layer_idx, q, k, v, pool):
+    def attend(layer_idx, q, k, v, held):
+        # What the stack threads: the pool, a prefill's stage beside it.
+        pool, stage = (held, None) if decode else held
         tables = _layer_tables(paged, layer_idx, page_tables)
         pool = paged_write(pool, k, v, tables, positions, mesh=mesh)
         if cfg.latent_kv:
             # A one-part pool: `k` was the token's one row and `v` None;
             # every head reads the row, its leading columns the value.
-            op = mla_latent_decode if decode else latent_attention
-            return op(q, pool, tables, positions, scale=cfg.q_scale,
-                      v_width=cfg.kv_lora_rank), pool
-        # Single-token steps take the DMA decode kernel (reads only valid
-        # pages); prefill buckets take the gather path (wide T amortizes
-        # the window materialization, and flash covers contiguous prefill).
-        op = paged_attention_decode if decode else paged_attention
-        ctx = op(
-            q, pool, tables, positions,
+            args = dict(scale=cfg.q_scale, v_width=cfg.kv_lora_rank)
+            if decode:
+                return mla_latent_decode(
+                    q, pool, tables, positions, **args), pool
+            ctx, stage = latent_prefill_attention(
+                q, pool, stage, tables, positions, keys, **args)
+            return ctx, (pool, stage)
+        args = dict(
             scale=cfg.q_scale,
             logit_softcap=cfg.attn_logit_softcap,
             window=_layer_window(cfg, layer_idx),
             mesh=mesh,
         )
-        return ctx, pool
+        if decode:
+            return paged_attention_decode(
+                q, pool, tables, positions, **args), pool
+        ctx, stage = paged_prefill_attention(
+            q, pool, stage, tables, positions, keys, **args)
+        return ctx, (pool, stage)
 
     if not cfg.layer_pattern:
         hidden, paged = _run_paged_stack(
-            params, cfg, tokens, positions, paged, attend
+            params, cfg, tokens, positions, paged, attend, stage
         )
         return hidden, paged, state, None
     if paged.quantized:
         raise ValueError("a layer pattern has no int8-KV path")
     from .hybrid import run_stack
 
-    hidden, pool, state, hits = run_stack(
-        params, cfg, tokens, positions, _stacked(paged), attend, state,
-        rows, active,
+    pool = _stacked(paged)
+    hidden, held, state, hits = run_stack(
+        params, cfg, tokens, positions, pool if decode else (pool, stage),
+        attend, state, rows, active,
     )
-    return hidden, _unstacked(paged, pool), state, hits
+    return (hidden, _unstacked(paged, held if decode else held[0]), state,
+            hits)
 
 
 def make_sp_override(

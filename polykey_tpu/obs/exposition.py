@@ -212,6 +212,15 @@ _ENGINE_FAMILIES: tuple = (
      "Of those rows, the rows a layer pattern's expert layers computed "
      "sorted by expert (the grouped product: on the chip every row, off "
      "it none).", "prefill_rows_grouped_experts"),
+    ("counter", "polykey_prefill_keys_read_total",
+     "Keys (positions) a layer's prefill attention gathered from the "
+     "pool and streamed, summed over the dispatches' table rows: the "
+     "leading pages that hold the dispatch's furthest position, in whole "
+     "gather steps.",
+     "prefill_keys_read_total"),
+    ("counter", "polykey_prefill_keys_table_total",
+     "What those rows' whole page tables span (max_seq_len a row).",
+     "prefill_keys_table_total"),
     ("counter", "polykey_prefill_windows_dispatched_total",
      "Prefill windows (real rows) among those dispatches.",
      "prefill_windows_dispatched"),

@@ -36,6 +36,11 @@ _LANES = 128
 
 
 def _kernel(
+    # scalar prefetch: read by the grid and the K/V index maps, not by
+    # the body
+    steps_ref,    # [1] int32 (SMEM)
+    first_ref,    # [B * nq] int32 (SMEM)
+    last_ref,     # [B * nq] int32 (SMEM)
     # inputs (blocked)
     q_ref,        # [1, 1, bq, D]
     k_ref,        # [1, 1, bk, D]
@@ -66,37 +71,45 @@ def _kernel(
 
     bq = q_ref.shape[2]
     q_pos = qpos_ref[0, 0, 0][:, None]                        # [bq, 1]
-    kv_pos = j * bk + jax.lax.broadcasted_iota(
-        jnp.int32, (bq, bk), dimension=1
-    )                                                         # [bq, bk]
     window = win_ref[0, 0]
 
     # Skip blocks fully outside [q_pos - window, q_pos]: no query row in this
     # q block can see any key in this k block (saves MXU work; the causal
-    # upper-right triangle of blocks is ~half the grid).
+    # upper-right triangle of blocks is ~half the grid). The K/V index maps
+    # hold such a step on the nearest needed block (`_needed_blocks`), so
+    # it is not fetched either.
     max_qpos = jnp.max(q_pos)
     min_qpos = jnp.min(jnp.where(q_pos < 0, jnp.int32(2**30), q_pos))
     block_lo, block_hi = j * bk, j * bk + bk - 1
     needed = (block_lo <= max_qpos) & (
         (window <= 0) | (block_hi > min_qpos - window)
     )
+    # A block whose SECOND half no query can see — a window at the head of
+    # its table under a key block twice its size — is multiplied by its
+    # first half alone; every other needed block in one pass (wide blocks
+    # are what keep the MXU fed at long contexts).
+    half = bk // 2 if bk % 256 == 0 else bk
+    head_only = block_lo + half > max_qpos
 
-    @pl.when(needed)
-    def _block():
+    def attend(keys: int):
+        """One online-softmax step over the block's leading `keys` keys."""
+        kv_pos = block_lo + jax.lax.broadcasted_iota(
+            jnp.int32, (bq, keys), dimension=1
+        )                                                     # [bq, keys]
         if native:
             s = scale * jax.lax.dot_general(
-                q_ref[0, 0], k_ref[0, 0],
+                q_ref[0, 0], k_ref[0, 0, :keys],
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         else:
             q = q_ref[0, 0].astype(jnp.float32) * scale
-            k = k_ref[0, 0]
+            k = k_ref[0, 0, :keys]
             s = jax.lax.dot_general(
                 q, k.astype(jnp.float32),
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )                                                 # [bq, bk]
+            )                                                 # [bq, keys]
         if logit_softcap is not None:
             s = logit_softcap * jnp.tanh(s / logit_softcap)
 
@@ -110,24 +123,30 @@ def _kernel(
         m_new = jnp.maximum(m_prev, m_cur)
         # Explicit mask on p: when a block is fully masked, s - m_new == 0
         # everywhere and exp would contribute bk spurious units to l.
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)          # [bq, bk]
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)          # [bq, keys]
         corr = jnp.exp(m_prev - m_new)                        # [bq, 1]
 
         l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
         if native:
             acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, 0],
+                p.astype(v_ref.dtype), v_ref[0, 0, :keys],
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         else:
             acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-                p, v_ref[0, 0].astype(jnp.float32),
+                p, v_ref[0, 0, :keys].astype(jnp.float32),
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    if half == bk:
+        pl.when(needed)(lambda: attend(bk))
+    else:
+        pl.when(needed & head_only)(lambda: attend(half))
+        pl.when(needed & jnp.logical_not(head_only))(lambda: attend(bk))
 
     @pl.when(j == nk - 1)
     def _finish():
@@ -148,6 +167,9 @@ def _flash_bhsd(
     v: jax.Array,
     q_positions: jax.Array,   # [B, nq, 1, bq] int32 (padding rows = -1)
     window: jax.Array,        # [1, 1] int32 (<=0 → global)
+    steps: jax.Array,         # [1] int32: `_needed_blocks`
+    first: jax.Array,         # [B * nq] int32
+    last: jax.Array,
     *,
     scale: float,
     logit_softcap: Optional[float],
@@ -160,9 +182,13 @@ def _flash_bhsd(
     B, Hq, Tp, D = q.shape
     Hk, Sp = k.shape[1], k.shape[2]
     groups = Hq // Hk
-    nq, nk = Tp // block_q, Sp // block_k
+    nq = Tp // block_q
 
-    grid = (B * Hq, nq, nk)
+    # The key axis is walked as far as the furthest query of the CALL sees
+    # (a dynamic bound: a window at the head of a long table costs its own
+    # blocks' steps, not the table's); within that, each query block is
+    # held on the blocks it needs.
+    grid = (B * Hq, nq, steps[0])
     kernel = functools.partial(
         _kernel,
         scale=scale,
@@ -171,38 +197,47 @@ def _flash_bhsd(
         bk=block_k,
         native=native,
     )
+
+    def kv_block(bh, i, j, steps, first, last):
+        # A key block no query of this query block can see is not worth
+        # a fetch: the step is held on the nearest block that is needed,
+        # and a block index that does not change is not fetched again.
+        at = (bh // Hq) * nq + i
+        return (bh // Hq, (bh % Hq) // groups,
+                jnp.clip(j, first[at], last[at]), 0)
+
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec(
+                    (1, 1, block_q, D),
+                    lambda bh, i, j, *_: (bh // Hq, bh % Hq, i, 0),
+                ),
+                pl.BlockSpec((1, 1, block_k, D), kv_block),
+                pl.BlockSpec((1, 1, block_k, D), kv_block),
+                pl.BlockSpec(
+                    (1, 1, 1, block_q),
+                    lambda bh, i, j, *_: (bh // Hq, i, 0, 0),
+                ),
+                pl.BlockSpec(
+                    (1, 1), lambda bh, i, j, *_: (0, 0),
+                    memory_space=pltpu.SMEM,
+                ),
+            ],
+            out_specs=pl.BlockSpec(
                 (1, 1, block_q, D),
-                lambda bh, i, j: (bh // Hq, bh % Hq, i, 0),
+                lambda bh, i, j, *_: (bh // Hq, bh % Hq, i, 0),
             ),
-            pl.BlockSpec(
-                (1, 1, block_k, D),
-                lambda bh, i, j: (bh // Hq, (bh % Hq) // groups, j, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, D),
-                lambda bh, i, j: (bh // Hq, (bh % Hq) // groups, j, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, block_q), lambda bh, i, j: (bh // Hq, i, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1), lambda bh, i, j: (0, 0), memory_space=pltpu.SMEM
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_q, D), lambda bh, i, j: (bh // Hq, bh % Hq, i, 0)
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, D), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Tp, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -215,7 +250,27 @@ def _flash_bhsd(
         ),
         interpret=interpret,
         name="flash_attention",
-    )(q, k, v, q_positions, window)
+    )(steps, first, last, q, k, v, q_positions, window)
+
+
+def _needed_blocks(q_positions, window, block_k: int, nk: int):
+    """(steps, first, last): the key blocks the call walks, [1] int32, and
+    the first and last key block each query block needs, [B * nq] int32
+    each — the blocks that hold positions min_qpos - window + 1 ..
+    max_qpos, by the kernel's own `needed` rule (a padded query row, -1,
+    sees nothing; a query block of padding alone needs no block and is
+    held on block 0). `steps` is the furthest `last` + 1."""
+    B, nq = q_positions.shape[:2]
+    hi = jnp.max(q_positions, axis=(2, 3))
+    lo = jnp.min(
+        jnp.where(q_positions < 0, jnp.int32(2**30), q_positions), axis=(2, 3)
+    )
+    last = jnp.clip(hi // block_k, 0, nk - 1)
+    w = window[0, 0]
+    first = jnp.where(w > 0, jnp.maximum(lo - w + 1, 0) // block_k, 0)
+    first = jnp.minimum(first, last)
+    steps = (jnp.max(last) + 1).reshape(1)
+    return steps, first.reshape(B * nq), last.reshape(B * nq)
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
@@ -247,6 +302,21 @@ def use_flash(T: int, S: int, head_dim: int) -> bool:
     )
 
 
+def runs_kernel(T: int, S: int, head_dim: int, *, mesh=None,
+                force_kernel: bool = False, interpret: bool = False) -> bool:
+    """Whether `flash_attention` at these shapes runs the kernel or the
+    masked reference (`use_flash`, unless forced; a mesh that shards sp or
+    tp under pp > 1 takes the reference: per-layer activations are
+    stage-local there, not replicated, and the masked reference is
+    GSPMD-partitionable as it is). A caller that lays K and V out for
+    their reader asks this first (ops/paged_attention.py `prefill_stage`)."""
+    if not (force_kernel or interpret or use_flash(T, S, head_dim)):
+        return False
+    shape = mesh.shape if mesh is not None else {}
+    sharded = shape.get("sp", 1) > 1 or shape.get("tp", 1) > 1
+    return not (sharded and shape.get("pp", 1) > 1)
+
+
 def flash_attention(
     q: jax.Array,             # [B, T, Hq, D]
     k: jax.Array,             # [B, S, Hk, D]
@@ -262,9 +332,16 @@ def flash_attention(
     force_kernel: bool = False,
     mesh=None,                # serving mesh → shard_map the kernel
     native: bool = False,
+    kv_heads_major: bool = False,
 ) -> jax.Array:
     """Blockwise attention; same contract as the reference `attention` but
     masking is derived from positions in-kernel. Returns [B, T, Hq, D].
+
+    `kv_heads_major`: k and v arrive as [B, Hk, S, D], the layout the
+    kernel reads (a caller that fills them in place, block by block:
+    ops/paged_attention.py `gather_needed_pages`); what lies past the
+    queries' positions is masked, and past the furthest query's block not
+    even walked.
 
     `native` (one device only): both products take their operands in the
     dtype they arrive in (bf16 on the MXU, float32 sums) instead of
@@ -280,41 +357,34 @@ def flash_attention(
     and would otherwise all-gather the sharded operands.
     """
     B, T, Hq, D = q.shape
-    S = k.shape[1]
+    S, Hk = (k.shape[2], k.shape[1]) if kv_heads_major else k.shape[1:3]
 
-    if not (force_kernel or interpret or use_flash(T, S, D)):
+    def reference():
+        kr, vr = ((jnp.transpose(x, (0, 2, 1, 3)) for x in (k, v))
+                  if kv_heads_major else (k, v))
         mask = make_attention_mask(q_positions, S)
         if window is not None:
             kv_pos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
             w = jnp.asarray(window, jnp.int32)
             mask &= (w <= 0) | (kv_pos > q_positions[:, :, None] - w)
         return attention(
-            q, k, v, mask, scale=scale, logit_softcap=logit_softcap
+            q, kr, vr, mask, scale=scale, logit_softcap=logit_softcap
         )
+
+    if not runs_kernel(T, S, D, mesh=mesh, force_kernel=force_kernel,
+                       interpret=interpret):
+        return reference()
 
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-    if (sp > 1 or tp > 1) and mesh.shape.get("pp", 1) > 1:
-        # Per-layer activations are stage-local under pp, not replicated —
-        # the shard_map specs below would be wrong (and check_vma=False
-        # would hide it). The masked reference path is GSPMD-partitionable
-        # as-is, so pp>1 meshes take it.
-        mask = make_attention_mask(q_positions, S)
-        if window is not None:
-            kv_pos = jnp.arange(S, dtype=jnp.int32)[None, None, :]
-            w = jnp.asarray(window, jnp.int32)
-            mask &= (w <= 0) | (kv_pos > q_positions[:, :, None] - w)
-        return attention(
-            q, k, v, mask, scale=scale, logit_softcap=logit_softcap
-        )
     if sp > 1 or tp > 1:
-        if T % sp or Hq % tp or k.shape[2] % tp:
+        if T % sp or Hq % tp or Hk % tp:
             # Never fall through to an unwrapped pallas_call on sharded
             # operands — GSPMD would all-gather them (or fail to compile)
             # with no pointer at the real cause.
             raise ValueError(
                 f"flash kernel on mesh: T={T} %% sp={sp}, Hq={Hq} / "
-                f"Hk={k.shape[2]} %% tp={tp} must divide evenly"
+                f"Hk={Hk} %% tp={tp} must divide evenly"
             )
         from jax.sharding import PartitionSpec as P
 
@@ -327,17 +397,20 @@ def flash_attention(
                 scale=scale, logit_softcap=logit_softcap, window=w,
                 block_q=block_q, block_k=block_k, interpret=interpret,
                 force_kernel=True,  # dispatch decided here, global shapes
+                kv_heads_major=kv_heads_major,
             )
 
         w = (jnp.zeros((1,), jnp.int32) if window is None
              else jnp.asarray(window, jnp.int32).reshape(1))
+        kv_spec = (P(None, "tp", None, None) if kv_heads_major
+                   else P(None, None, "tp", None))
         sm = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(
                 P(None, "sp", "tp", None),    # q
-                P(None, None, "tp", None),    # k (full window per shard)
-                P(None, None, "tp", None),    # v
+                kv_spec,                      # k (full window per shard)
+                kv_spec,                      # v
                 P(None, "sp"),                # q_positions
                 P(None),                      # window
             ),
@@ -356,8 +429,11 @@ def flash_attention(
     block_k = _fit(block_k, S)
 
     qt = _pad_to(jnp.transpose(q, (0, 2, 1, 3)), 2, block_q)
-    kt = _pad_to(jnp.transpose(k, (0, 2, 1, 3)), 2, block_k)
-    vt = _pad_to(jnp.transpose(v, (0, 2, 1, 3)), 2, block_k)
+    kt, vt = (
+        _pad_to(x if kv_heads_major else jnp.transpose(x, (0, 2, 1, 3)),
+                2, block_k)
+        for x in (k, v)
+    )
     qpos = _pad_to(q_positions.astype(jnp.int32), 1, block_q, value=-1)
     qpos = qpos.reshape(B, -1, 1, block_q)
     if window is None:
@@ -367,6 +443,7 @@ def flash_attention(
 
     out = _flash_bhsd(
         qt, kt, vt, qpos, win,
+        *_needed_blocks(qpos, win, block_k, kt.shape[2] // block_k),
         scale=scale,
         logit_softcap=logit_softcap,
         kv_len=S,

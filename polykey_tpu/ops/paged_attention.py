@@ -1,11 +1,17 @@
 """Paged attention: read KV through page-table indirection.
 
 `paged_gather_kv` is the reference implementation (pure jnp): materialize the
-per-sequence KV window by gathering whole pages, then run the standard masked
-attention. Correct everywhere, but it streams the full gathered window
-through HBM every step — the Pallas decode kernel (paged_attention_decode
-with use_kernel=True, task: ops/paged_attention_kernel.py) replaces the
-gather with per-page DMA so only valid pages move.
+KV of the table it is HANDED by gathering whole pages, then run the standard
+masked attention. Correct everywhere, but every gathered position goes
+through HBM twice more — so a decode step takes the Pallas kernel instead
+(ops/paged_attention_kernel.py paged_attention_decode: per-page DMA, only
+valid pages move), and a prefill dispatch gathers no more of a table than
+its furthest query can see (`gather_needed_pages`: the leading pages that
+hold positions 0 .. max(start) + T − 1 — a window's worth a turn of a
+loop whose trip count is read from the positions — written in place into
+a staging buffer in the blockwise kernel's own layout; the kernel walks
+its key blocks as far as that position and no further, so the rest of a
+`max_seq_len` table is neither gathered nor streamed).
 
 Page-table convention (engine/kv_cache.py): page_tables[b, j] is the page id
 holding positions [j*page_size, (j+1)*page_size); unused tail entries point
@@ -31,6 +37,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from flax import struct
 
 
 def paged_gather_kv(
@@ -52,6 +59,154 @@ def paged_gather_kv(
     )
 
 
+# The fewest keys a piece of a prefill dispatch's gather moves a row (a
+# narrower window — a speculative verify's few tokens — gathers this
+# many).
+MIN_GATHER_KEYS = 128
+def prefill_gather_keys(window: int, table_keys: int, page_size: int) -> int:
+    """Keys a turn of a prefill dispatch's gather moves a row: the window's
+    own, at least MIN_GATHER_KEYS, in whole pages, at most the table's."""
+    keys = -(-max(window, MIN_GATHER_KEYS) // page_size) * page_size
+    return min(keys, table_keys)
+
+
+def prefill_gather_turns(keys, window: int, table_keys: int, page_size: int):
+    """Turns of the gather that cover positions 0 .. `keys` − 1, where
+    `keys` is max(start) + T over a dispatch's rows: a traced scalar in the
+    program (`gather_needed_pages`' trip count), an int on the host (the
+    engine's `prefill_keys_read_total`) — one rule in arithmetic both
+    take, at no device dispatch on the host, so the two cannot drift."""
+    piece = prefill_gather_keys(window, table_keys, page_size)
+    turns, whole = (keys + piece - 1) // piece, -(-table_keys // piece)
+    return whole + (turns - whole) * (turns < whole)       # min(turns, whole)
+
+
+def prefill_keys_read(keys, window: int, table_keys: int, page_size: int):
+    """Keys a row of a prefill dispatch gathers and streams a layer, where
+    the dispatch's furthest query sees positions 0 .. `keys` − 1."""
+    read = (prefill_gather_turns(keys, window, table_keys, page_size)
+            * prefill_gather_keys(window, table_keys, page_size))
+    return table_keys + (read - table_keys) * (read < table_keys)
+
+
+def prefill_bounded(window: int, table_keys: int, page_size: int, width: int,
+                    latent: bool, mesh=None) -> bool:
+    """Whether a prefill dispatch's attention reads the keys its queries
+    can see or its whole tables: the blockwise kernel walks what it is
+    handed, so it is handed no more than is seen; the masked reference
+    (off the chip, tiny windows, sp / tp under pp) multiplies every key of
+    the table whatever lies there, and gathers it in one piece as it
+    always did. The engine's `prefill_keys_read_total` asks the same."""
+    from .flash_attention import runs_kernel
+
+    if latent:
+        return _latent_on_chip(width, window)
+    piece = prefill_gather_keys(window, table_keys, page_size)
+    return runs_kernel(
+        window, -(-table_keys // piece) * piece, width, mesh=mesh)
+
+
+@struct.dataclass
+class PrefillStage:
+    """The staging buffers of one prefill dispatch: `parts` (K and V, or a
+    latent pool's one row), each the leading keys of every row's table.
+    `bounded` (`prefill_bounded`): [rows, heads, S, width], the blockwise
+    kernel's layout (its blocks are [keys, width] slabs of one head),
+    filled as far as the dispatch's queries see; else the gather's own
+    [rows, S, heads, width], the whole table, for the masked reference."""
+
+    parts: tuple
+    bounded: bool = struct.field(pytree_node=False)
+
+
+def prefill_stage(rows: int, table_keys: int, window: int, page_size: int,
+                  heads: int, width: int, parts: int, dtype,
+                  mesh=None) -> PrefillStage:
+    """A prefill dispatch's `PrefillStage`: `parts` buffers of zeros over
+    the table's keys (in whole turns of the gather). Made once a dispatch
+    and threaded through the layers beside the pool: every layer
+    overwrites the same leading keys in place, and what lies past them
+    stays zero (masked, never walked). One part is a latent pool's (its
+    reader: `latent_prefill_attention`), two are K and V
+    (`paged_prefill_attention`, under `mesh`)."""
+    bounded = prefill_bounded(
+        window, table_keys, page_size, width, parts == 1, mesh)
+    piece = prefill_gather_keys(window, table_keys, page_size)
+    keys = -(-table_keys // piece) * piece if bounded else table_keys
+    shape = ((rows, heads, keys, width) if bounded
+             else (rows, keys, heads, width))
+    return PrefillStage(
+        tuple(jnp.zeros(shape, dtype) for _ in range(parts)), bounded)
+
+
+def gather_needed_pages(stage: PrefillStage, gather, page_tables: jax.Array,
+                        keys, window: int, page_size: int) -> PrefillStage:
+    """Fill `stage` with the keys a prefill dispatch's queries can see:
+    positions 0 .. `keys` − 1 (`keys` = max position + 1, read from the
+    input inside the one program), a window's worth a turn of a loop whose
+    trip count is `prefill_gather_turns`'. `gather(tables)` materializes
+    the pages of a slice of the table as a tuple of [B, slice keys, heads,
+    width], one entry a part of `stage`; a turn writes it, heads major,
+    over its keys of the stage in place (a few µs a turn on a v5e,
+    whatever it moves). Positions past `keys` are masked for every row, so
+    leaving them out changes which masked keys are MOVED, not the sum; a
+    chunk that ends at the table's end gathers the whole table, as every
+    dispatch did — and as a stage that is not `bounded` still does, in one
+    piece."""
+    if not stage.bounded:
+        return stage.replace(parts=tuple(
+            rows.astype(part.dtype)
+            for part, rows in zip(stage.parts, gather(page_tables))))
+    P = page_tables.shape[1]
+    piece = prefill_gather_keys(window, P * page_size, page_size)
+    pages = piece // page_size
+    # A table that is no whole number of turns ends on the garbage page.
+    tables = jnp.pad(
+        page_tables, ((0, 0), (0, stage.parts[0].shape[2] // page_size - P)))
+
+    def turn(i, parts):
+        got = gather(
+            jax.lax.dynamic_slice_in_dim(tables, i * pages, pages, axis=1))
+        return tuple(
+            _row_major(jax.lax.dynamic_update_slice_in_dim(
+                part, jnp.transpose(rows, (0, 2, 1, 3)).astype(part.dtype),
+                i * piece, axis=2))
+            for part, rows in zip(parts, got)
+        )
+
+    turns = prefill_gather_turns(keys, window, P * page_size, page_size)
+    return stage.replace(
+        parts=jax.lax.fori_loop(0, turns, turn, stage.parts))
+
+
+def _row_major(x: jax.Array) -> jax.Array:
+    """`x`, laid out in memory as its shape reads. The stage is written a
+    turn's rows at a time and read by the blockwise kernel, which takes
+    its operands row-major: left to itself XLA lays the buffer out for the
+    writer (keys major) and re-lays the WHOLE of it out for the kernel,
+    every layer."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+
+
+def _gathered_kv(kv_pages, page_tables: jax.Array, head_dim: int, dtype):
+    """(k, v) [B, P·page_size, Hk, D] of the pages of `page_tables`, an
+    int8 pool's dequantized into `dtype`."""
+    if not isinstance(kv_pages, tuple):
+        return paged_gather_kv(kv_pages, page_tables, head_dim)
+    # int8 KV: gather values and scales, dequantize into the compute
+    # dtype — the dequant is an elementwise producer XLA fuses into
+    # the window consumers, and the pool-side HBM read stays int8.
+    values, ks_pool, vs_pool = kv_pages
+    k, v = paged_gather_kv(values, page_tables, head_dim)
+    B, P = page_tables.shape
+    ps, Hk = ks_pool.shape[1], ks_pool.shape[2]
+    ks = ks_pool[page_tables].reshape(B, P * ps, Hk)
+    vs = vs_pool[page_tables].reshape(B, P * ps, Hk)
+    return dequantize_kv(k, ks, dtype), dequantize_kv(v, vs, dtype)
+
+
 def paged_attention(
     q: jax.Array,             # [B, T, Hq, D]
     kv_pages,                 # [2 · num_pages, page_size, Hk·D], or the
@@ -64,35 +219,59 @@ def paged_attention(
     window: Optional[jax.Array] = None,
     mesh=None,
 ) -> jax.Array:
-    """Attention over paged KV; returns [B, T, Hq, D].
+    """Attention over the paged KV of the WHOLE table it is handed; returns
+    [B, T, Hq, D]. The reference form (tests, a decode step off the chip):
+    a prefill dispatch takes `paged_prefill_attention`, which gathers and
+    streams the keys its queries can see and no more.
 
-    Slot j of the gathered window holds position j, so the absolute-position
+    Slot j of the gathered pages holds position j, so the absolute-position
     causal mask simultaneously hides unwritten slots and garbage-page tails —
-    which also makes the gathered window a valid input for the blockwise
+    which also makes the gathered pages a valid input for the blockwise
     flash kernel (ops/flash_attention.py): on TPU at prefill widths it takes
     the O(T·D + S·D)-traffic path instead of materializing [.., T, S] logits;
     off-TPU / tiny shapes it falls back to the reference mask internally.
     """
     from .flash_attention import flash_attention
 
-    if isinstance(kv_pages, tuple):
-        # int8 KV: gather values and scales, dequantize into the compute
-        # dtype — the dequant is an elementwise producer XLA fuses into
-        # the window consumers, and the pool-side HBM read stays int8.
-        values, ks_pool, vs_pool = kv_pages
-        k, v = paged_gather_kv(values, page_tables, q.shape[-1])
-        B, P = page_tables.shape
-        ps, Hk = ks_pool.shape[1], ks_pool.shape[2]
-        ks = ks_pool[page_tables].reshape(B, P * ps, Hk)
-        vs = vs_pool[page_tables].reshape(B, P * ps, Hk)
-        k = dequantize_kv(k, ks, q.dtype)
-        v = dequantize_kv(v, vs, q.dtype)
-    else:
-        k, v = paged_gather_kv(kv_pages, page_tables, q.shape[-1])
+    k, v = _gathered_kv(kv_pages, page_tables, q.shape[-1], q.dtype)
     return flash_attention(
         q, k, v, q_positions,
         scale=scale, logit_softcap=logit_softcap, window=window, mesh=mesh,
     )
+
+
+def paged_prefill_attention(
+    q: jax.Array,             # [B, T, Hq, D]
+    kv_pages,                 # as `paged_attention`
+    stage: PrefillStage,      # `prefill_stage`: K and V
+    page_tables: jax.Array,   # [B, P]
+    q_positions: jax.Array,   # [B, T]
+    keys,                     # max position of the dispatch + 1
+    *,
+    scale: float,
+    logit_softcap: Optional[float] = None,
+    window: Optional[jax.Array] = None,
+    mesh=None,
+):
+    """`paged_attention` of a prefill dispatch over the keys its queries
+    can see: the needed pages gathered into `stage` (`gather_needed_pages`),
+    the blockwise kernel over the stage as far as the furthest query.
+    Returns ([B, T, Hq, D], the stage as this layer leaves it)."""
+    from .flash_attention import flash_attention
+
+    page_size = (kv_pages[0] if isinstance(kv_pages, tuple)
+                 else kv_pages).shape[1]
+    stage = gather_needed_pages(
+        stage,
+        lambda tables: _gathered_kv(kv_pages, tables, q.shape[-1], q.dtype),
+        page_tables, keys, q.shape[1], page_size,
+    )
+    ctx = flash_attention(
+        q, *stage.parts, q_positions,
+        scale=scale, logit_softcap=logit_softcap, window=window, mesh=mesh,
+        kv_heads_major=stage.bounded,
+    )
+    return ctx, stage
 
 
 # The blockwise kernel's blocks for `latent_attention`, query rows and key
@@ -112,30 +291,69 @@ def latent_attention(
     v_width: int,
 ) -> jax.Array:
     """Attention of a latent (MLA) layer in its absorbed form over the
-    gathered table; returns [B, T, Hq, v_width]. A token's ONE row is the
-    key of every head and, in its leading `v_width` columns, the value:
+    WHOLE gathered table; returns [B, T, Hq, v_width]. A token's ONE row is
+    the key of every head and, in its leading `v_width` columns, the value:
     the gathered window is handed to the blockwise kernel as K AND as V of
     ONE head whose query rows are the (token, head) pairs — a query block
     is a few tokens' heads, so a key block is fetched once for all of
     them, and the blocks past those tokens' positions are skipped — and
     the output's columns past `v_width` (the weighted sum of the rotary
-    key and the padding) are dropped. A prefill dispatch's form on the
-    chip (the products on bf16 operands: 128 heads against one row is
-    arithmetic-bound), and every shape's off it; a decode step on the chip
-    reads the pages where they lie
-    (paged_attention_kernel.mla_latent_decode)."""
-    from .flash_attention import flash_attention
+    key and the padding) are dropped. The reference form (tests, a decode
+    step off the chip); a prefill dispatch takes
+    `latent_prefill_attention`, and a decode step on the chip reads the
+    pages where they lie (paged_attention_kernel.mla_latent_decode)."""
+    B, _, _, width = q.shape
+    table = rows[page_tables].reshape(B, -1, 1, width)
+    return _latent_over(q, table, q_positions, scale, v_width, False)
+
+
+def latent_prefill_attention(
+    q: jax.Array,             # [B, T, Hq, W]
+    rows: jax.Array,          # [N, page_size, W]
+    stage: PrefillStage,      # `prefill_stage`: the rows, one part
+    page_tables: jax.Array,   # [B, P]
+    q_positions: jax.Array,   # [B, T]
+    keys,                     # max position of the dispatch + 1
+    *,
+    scale: float,
+    v_width: int,
+):
+    """`latent_attention` of a prefill dispatch over the keys its queries
+    can see: the needed pages' rows gathered into `stage`
+    (`gather_needed_pages`), the blockwise kernel over the stage as far as
+    the furthest query (on the chip on bf16 operands: 128 heads against
+    one row is arithmetic-bound). Returns ([B, T, Hq, v_width], the stage
+    as this layer leaves it)."""
+    B, T, _, width = q.shape
+    stage = gather_needed_pages(
+        stage, lambda tables: (rows[tables].reshape(B, -1, 1, width),),
+        page_tables, keys, T, rows.shape[1],
+    )
+    out = _latent_over(
+        q, stage.parts[0], q_positions, scale, v_width, stage.bounded)
+    return out, stage
+
+
+def _latent_on_chip(width: int, window: int) -> bool:
+    """Whether a latent prefill of `window` tokens takes the blockwise
+    kernel (on the chip, on bf16 operands) or the masked reference."""
     from .paged_attention_kernel import use_paged_kernel
 
+    return use_paged_kernel(1, width) and window >= 128
+
+
+def _latent_over(q, table, q_positions, scale, v_width, heads_major: bool):
+    """The absorbed heads of `q` against `table`, a lane's rows as ONE
+    head's keys and values ([B, S, 1, W], or `heads_major` [B, 1, S, W])."""
+    from .flash_attention import flash_attention
+
     B, T, heads, width = q.shape
-    P = page_tables.shape[1]
-    table = rows[page_tables].reshape(B, P * rows.shape[1], 1, width)
-    on_chip = use_paged_kernel(1, width) and T >= 128
+    on_chip = _latent_on_chip(width, T)
     out = flash_attention(
         q.reshape(B, T * heads, 1, width), table, table,
         jnp.repeat(q_positions, heads, axis=1), scale=scale,
         block_q=LATENT_BLOCK, block_k=LATENT_BLOCK,
-        force_kernel=on_chip, native=on_chip,
+        force_kernel=on_chip, native=on_chip, kv_heads_major=heads_major,
     )
     return out.reshape(B, T, heads, width)[..., :v_width]
 

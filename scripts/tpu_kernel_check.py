@@ -36,6 +36,10 @@ Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
        block width of the decode kernel varied one at a time)
      python scripts/tpu_kernel_check.py --held-experts   (the held-time and
        held-compare rows alone)
+     python scripts/tpu_kernel_check.py --prefill-read   (the blockwise
+       prefill kernel's checks and the `prefill-read` rows alone: µs a
+       layer of a prefill dispatch's attention over the whole table and
+       over the pages its queries can see)
      python scripts/tpu_kernel_check.py --delta-state   (the gated delta
        rule's decode update alone: `delta-compare` against its jnp form,
        `delta-time` µs a call beside the state's bytes ÷ the bandwidth)
@@ -192,6 +196,176 @@ def check_flash() -> None:
             return assert_close(got, want, 8e-2)
 
         case("flash", f"{label} T={T} S={S}", run)
+    check_flash_served()
+
+
+def prefill_read_inputs(B, T, heads, Hk, D, starts, latent: bool, seed=2):
+    """(q, pool, tables, positions) of a prefill dispatch's attention as
+    `forward_slots_counted` hands it over: B rows of T queries at `starts`
+    over whole TABLE-page tables on a pool of noise, a K/V pool of page
+    halves or, `latent`, the one-part pool of D-wide rows."""
+    kq, kp = jax.random.split(jax.random.PRNGKey(seed))
+    N = B * TABLE + 1
+    q = jax.random.normal(kq, (B, T, heads, D), jnp.bfloat16)
+    shape = (N, PS, D) if latent else (2 * N, PS, Hk * D)
+    pool = jax.random.normal(kp, shape, jnp.bfloat16)
+    tables = 1 + np.arange(B * TABLE, dtype=np.int32).reshape(B, TABLE)
+    positions = np.asarray(starts, np.int32)[:, None] + np.arange(T)
+    return q, pool, jnp.asarray(tables), jnp.asarray(positions)
+
+
+def prefill_read(latent: bool, bounded: bool, reference: bool = False):
+    """read(q, pool, positions, tables, stage) -> (attention, stage): one
+    layer's attention of a prefill dispatch — `bounded`, as the engine
+    dispatches it (`paged_prefill_attention` / `latent_prefill_attention`:
+    the keys the queries can see, gathered into `stage`); else over the
+    whole gathered table (`paged_attention` / `latent_attention`, every
+    dispatch's form before ISSUE 59; `stage` passes through) — by the
+    blockwise kernel (in interpret mode in a rehearsal), or by the jnp
+    `reference`."""
+    from polykey_tpu.ops import flash_attention as fa
+    from polykey_tpu.ops import paged_attention as pa
+    from polykey_tpu.ops.attention import attention, make_attention_mask
+
+    flash = fa.flash_attention
+
+    def masked(q, k, v, qpos, *, scale, **_):
+        return attention(q, k, v, make_attention_mask(qpos, k.shape[1]),
+                         scale=scale)
+
+    def interpreted(*args, **kw):
+        kw.pop("force_kernel", None)
+        return flash(*args, **{**kw, "interpret": True})
+
+    def read(q, pool, positions, tables, stage):
+        # The ops look `flash_attention` up when they are traced.
+        if reference:
+            fa.flash_attention = masked
+        elif "interpret" in KERNEL:
+            fa.flash_attention = interpreted
+        kw = (dict(scale=0.07, v_width=512) if latent
+              else dict(scale=q.shape[-1] ** -0.5))
+        try:
+            if bounded:
+                op = (pa.latent_prefill_attention if latent
+                      else pa.paged_prefill_attention)
+                return op(q, pool, stage, tables, positions,
+                          jnp.max(positions) + 1, **kw)
+            op = pa.latent_attention if latent else pa.paged_attention
+            return op(q, pool, tables, positions, **kw), stage
+        finally:
+            fa.flash_attention = flash
+
+    return read
+
+
+def prefill_read_stage(q, latent: bool, Hk: int):
+    from polykey_tpu.ops import paged_attention as pa
+
+    B, T, _, D = q.shape
+    return pa.prefill_stage(
+        B, TABLE * PS, T, PS, heads=1 if latent else Hk, width=D,
+        parts=1 if latent else 2, dtype=q.dtype)
+
+
+def check_flash_served() -> None:
+    """The shape the engine serves: a 4,096-position table, a dispatch's
+    queries at positions < 512 (one row at 0, one a window further), the
+    K/V pool in bf16 and the latent one-part pool in the `native` form.
+    The whole-table read (the kernel walks the table's key blocks as far
+    as the furthest query) and the bounded read (the needed pages alone,
+    gathered into the stage) against the jnp reference over the whole
+    table."""
+    T = 128
+    shapes = [("8b", False, 32, 8, 128), ("latent-native", True, 16, 1, 640)]
+    for label, latent, heads, Hk, D in shapes:
+        q, pool, tables, positions = prefill_read_inputs(
+            2, T, heads, Hk, D, (0, 512 - T), latent)
+        stage = prefill_read_stage(q, latent, Hk)
+        want, _ = jax.jit(prefill_read(latent, False, reference=True))(
+            q, pool, positions, tables, stage)
+        for bounded in (False, True):
+            def run(bounded=bounded, latent=latent, want=want,
+                    args=(q, pool, positions, tables, stage)):
+                got, _ = jax.jit(prefill_read(latent, bounded))(*args)
+                return assert_close(got, want, 8e-2)
+
+            case("flash-served",
+                 f"{label} T={T} table={TABLE * PS} keys<512 "
+                 f"{'bounded' if bounded else 'whole'}", run)
+
+
+# (label, latent, heads, Hk, D, rows, T, starts): the benchmark's prefill
+# dispatches, one layer's attention each.
+PREFILL_READS = [
+    ("mistral [2,512] start 0", False, 32, 8, 128, 2, 512, (0, 0)),
+    ("mistral [1,128] start 0", False, 32, 8, 128, 1, 128, (0,)),
+    ("mistral [8,128] cover", False, 32, 8, 128, 8, 128,
+     (0, 128, 256, 384, 0, 128, 256, 384)),
+    ("mistral [1,512] start 1536", False, 32, 8, 128, 1, 512, (1536,)),
+    ("mistral [1,512] start 3584", False, 32, 8, 128, 1, 512, (3584,)),
+    ("latent [2,512] start 0", True, 128, 1, 640, 2, 512, (0, 0)),
+    ("latent [4,128] start 0", True, 128, 1, 640, 4, 128, (0,) * 4),
+]
+
+
+def check_prefill_read_timing() -> None:
+    """`prefill-read` rows: µs a layer of a prefill dispatch's attention
+    (the gather of the table's pages and the blockwise kernel), over the
+    whole 4,096-position table and over the keys the queries can see (the
+    stage threaded from call to call, as from layer to layer), beside the
+    keys each moves."""
+    from polykey_tpu.ops import paged_attention as pa
+
+    interpret = "interpret" in KERNEL
+    calls = 2 if interpret else 16
+    for label, latent, heads, Hk, D, B, T, starts in PREFILL_READS:
+        if interpret:
+            heads, T = max(4, Hk), 128
+            starts = tuple(min(s, 128) for s in starts)
+
+        def run(latent=latent, heads=heads, Hk=Hk, D=D, B=B, T=T,
+                starts=starts):
+            q, pool, tables, positions = prefill_read_inputs(
+                B, T, heads, Hk, D, starts, latent)
+            stage = prefill_read_stage(q, latent, Hk)
+            shifts = jnp.arange(calls, dtype=jnp.bfloat16)
+
+            def scan_of(step):
+                def one(carry, shift):
+                    total, stage = carry
+                    out, stage = step(q + shift, pool, positions, tables,
+                                      stage)
+                    return (total + jnp.sum(out.astype(jnp.float32)),
+                            stage), None
+
+                return jax.jit(lambda q, pool, tables, positions, stage:
+                               jax.lax.scan(one, (jnp.float32(0), stage),
+                                            shifts)[0][0])
+
+            def seconds(fn):
+                args = (q, pool, tables, positions, stage)
+                jax.block_until_ready(fn(*args))
+                best = float("inf")
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(fn(*args))
+                    best = min(best, time.perf_counter() - t0)
+                return best
+
+            keys = pa.prefill_keys_read(max(starts) + T, T, TABLE * PS, PS)
+            times = [seconds(scan_of(step)) for step in (
+                lambda q, pool, positions, tables, stage:
+                    (q[..., :8], stage),
+                prefill_read(latent, False), prefill_read(latent, True))]
+            if interpret:
+                return (f"ran (interpret mode on the host: no device time); "
+                        f"{TABLE * PS} and {keys} keys a row")
+            us = [(s - times[0]) / calls * 1e6 for s in times[1:]]
+            return (f"whole table {us[0]:.0f} us/layer ({TABLE * PS} keys a "
+                    f"row), bounded {us[1]:.0f} us/layer ({keys} keys a row)")
+
+        case("prefill-read", label, run)
 
 
 def check_write(quantized: bool) -> None:
@@ -712,6 +886,10 @@ def main() -> int:
     # hardware last, so a hang there costs no other case its evidence.
     held_only = "--held-experts" in sys.argv[1:]
     delta_only = "--delta-state" in sys.argv[1:]
+    if "--prefill-read" in sys.argv[1:]:
+        check_flash()
+        check_prefill_read_timing()
+        return report(identity, interpret)
     timing_only = held_only or "--timing" in sys.argv[1:]
     check_block_until_ready()
     if delta_only:

@@ -269,6 +269,97 @@ def test_prefill_step_compiled_for_v5e_moves_no_pool(v5e, tp, rows):
     ) == 1
 
 
+def _reachable(computations, root: str) -> set[str]:
+    """`root` and every computation its instructions call, fused ones
+    included."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        for _, _, _, line in computations[name]:
+            todo += re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line)
+    return seen
+
+
+def page_gathers(computations, names, ps: int, folded: int) -> list[int]:
+    """Pages a row of each gather of pool pages in computations `names`
+    (result [rows, pages, ps, folded])."""
+    found = []
+    for name in names:
+        for _, result_type, op, _ in computations.get(name, []):
+            m = re.match(rf"bf16\[\d+,(\d+),{ps},{folded}\]", result_type)
+            if op == "gather" and m:
+                found.append(int(m.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("rows,width", [(2, 128), (2, 512)])
+def test_prefill_step_compiled_for_v5e_gathers_a_turn_at_a_time(
+        v5e, rows, width):
+    """A prefill dispatch over 4,096-position tables, compiled for a v5e:
+    every gather of pool pages moves ONE turn's pages a row (the window's
+    own: `prefill_gather_keys`) inside the loop whose trip count is read
+    from the positions, and writes them into the stage in place — the
+    stage is held row-major, as the kernel reads it, and nothing of its
+    size is copied, transposed or re-laid-out — and the layer body calls
+    the blockwise kernel ONCE, on the stage, its key axis a dynamic grid
+    bound. Before ISSUE 59 the layer body gathered the table whole, twice
+    (K and V)."""
+    from polykey_tpu.ops.paged_attention import prefill_gather_keys
+
+    cfg = replace(TINY_LLAMA, name="layout-probe", num_heads=2,
+                  num_kv_heads=2, head_dim=64)
+    pages, ps, table = 1024, 16, 4096
+    hlo = _CENSUS.compile_step(
+        cfg, list(v5e.devices), tp=1, pages=pages, page_size=ps,
+        max_seq_len=table, prefill=(rows, width),
+    )
+    Hk, D = cfg.num_kv_heads, cfg.head_dim
+    piece = prefill_gather_keys(width, table, ps)
+    assert piece == width
+    computations, fused = _module(hlo)
+    assert page_gathers(computations, computations, ps, Hk * D) == [
+        piece // ps, piece // ps]
+    # The gathers' loop: a while whose body (fusions included) holds both,
+    # beside the in-place write and away from the kernel, inside the layer
+    # scan.
+    loops = [
+        _reachable(computations, re.search(
+            r"body=%?([\w.\-]+)", line).group(1))
+        for instructions in computations.values()
+        for _, _, op, line in instructions if op == "while"
+    ]
+    holding = [body for body in loops
+               if page_gathers(computations, body, ps, Hk * D)]
+    assert len(holding) == 2
+    text = "\n".join(line for name in min(holding, key=len)
+                     for *_, line in computations[name])
+    assert "flash_attention" not in text and "dynamic-update-slice" in text
+    # The stage: [rows, Hk, table, D], row-major wherever it is held, and
+    # nothing of its size is made but the stage itself (zeros, once), the
+    # turns' in-place writes and what carries it or moves it between
+    # memories whole.
+    stage = f"bf16[{rows},{Hk},{table},{D}]"
+    whole = rows * table * D * Hk * 2
+    made = set()
+    for name, instructions in computations.items():
+        for _, result_type, op, line in instructions:
+            if stage in result_type:
+                assert f"{stage}{{3,2,1,0" in result_type, line
+            if name in fused or _largest(result_type) < whole:
+                continue
+            if op not in _HOLDERS:
+                made.add(op)
+    assert made <= {"broadcast", "fusion", "dynamic-update-slice",
+                    "copy-start", "copy-done", "slice-start"}, made
+    calls = [c for c in _kernel_calls(hlo) if c.startswith("%flash_attention")]
+    assert len(calls) == 1, calls
+    assert pool_sized_instructions(hlo, pages * ps * Hk * D * 2) == []
+
+
 def _compile_pattern_step(v5e, monkeypatch, cfg, lanes=64, prefill=None):
     """(compiled, paged, state): the engine's decode step — its prefill
     step at `prefill` = (rows, width) — of a layer pattern with per-slot

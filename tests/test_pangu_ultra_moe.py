@@ -533,11 +533,16 @@ def test_the_stack_walker_applies_a_post_norm_only_where_stated(params):
 # products at the sibling cells' widths. This PR gave the decode kernel a
 # `parts` argument, `paged_write` an optional V, the blockwise kernel a
 # `native` flag and the experts' tiles a byte bound: with the defaults every
-# one of them must trace to the program it was.
+# one of them must trace to the program it was. ("flash" re-recorded by PR 59,
+# 915df01329ca993c -> 7b0269fc2e46ba51: the grid's key axis is a dynamic bound
+# and the K/V index maps take the first and last needed key block of each
+# query block, all three scalar-prefetched, and a block whose second half no
+# query sees is multiplied by its first half alone; the mask, the sums and
+# the other eight programs are as they were.)
 _TRACED_AT_PR_54 = {
     "decode-bf16": "27a3f8227381dd04", "decode-int8": "091848e56f944cb7",
     "decode-bf16-state": "3d5e950c9b98ff01", "write-T1": "7b8f5182bc3cf86a",
-    "write-T32": "8dab4252880f4314", "flash": "915df01329ca993c",
+    "write-T32": "8dab4252880f4314", "flash": "7b0269fc2e46ba51",
     "held-masked": "1df9daf567ae6eeb", "held-grouped": "92f78f53fcf7711d",
     "held-latent": "798481ab2ea5b29d",
 }

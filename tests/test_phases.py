@@ -597,6 +597,8 @@ def test_exporter_renders_one_sample_per_phase_and_counter(live):
                    "polykey_prefill_rows_dispatched_total",
                    "polykey_prefill_rows_useful_total",
                    "polykey_prefill_rows_grouped_experts_total",
+                   "polykey_prefill_keys_read_total",
+                   "polykey_prefill_keys_table_total",
                    "polykey_ttft_phase_requests_total",
                    "polykey_first_token_poll_gap_seconds_total",
                    "polykey_first_token_reads_total"):
@@ -606,6 +608,7 @@ def test_exporter_renders_one_sample_per_phase_and_counter(live):
                 "ttft_phase_count", "ttft_queue_seconds", "admit_deferred",
                 "decode_lane_steps_delivered", "prefill_rows_useful",
                 "prefill_rows_grouped_experts",
+                "prefill_keys_read_total", "prefill_keys_table_total",
                 "first_token_poll_gap_seconds",
                 "first_token_poll_gap_count"):
         assert key in stats
