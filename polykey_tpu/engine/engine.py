@@ -913,6 +913,10 @@ class InferenceEngine:
                     lambda: init_slot_state(self.model_cfg, B, self._dtype)
                 ),
             )
+        # Host-known like the pool's (the leaves are donated dispatch by
+        # dispatch): the bytes the state takes on the device, its layout's
+        # padding included.
+        self._state_pool_bytes = self.state.resident_nbytes
         self.allocator = BlockAllocator(config.num_pages)
         # --- Host-memory KV tier (ISSUE 15): a second page pool in host
         # RAM for COLD pages (prefix-cache entries of finished sticky
@@ -1486,9 +1490,10 @@ class InferenceEngine:
                 "slots_total": self.config.max_decode_slots,
                 "pages_free": self.allocator.num_free,
                 "pages_total": self.config.num_pages,
-                # Bytes of per-slot recurrent state beside the pool
-                # (kv_cache.SlotState; 0 for a model that has none).
-                "state_pool_bytes": self.state.nbytes,
+                # Bytes of per-slot recurrent state beside the pool, as
+                # they lie on the device (kv_cache.SlotState
+                # `resident_nbytes`; 0 for a model that has none).
+                "state_pool_bytes": self._state_pool_bytes,
                 # The page pool itself, and what ONE token holds in it
                 # over all layers: pages in use read in bytes.
                 "kv_pool_bytes": self._kv_pool_bytes,

@@ -149,7 +149,10 @@ class SlotState:
     stateful model (ModelConfig.stateful), indexed by slot, one entry per
     layer that needs it, in pattern order. `ssm`, the recurrent matrix of
     a layer, one per layer that has a recurrence — a Mamba-2 mixer's h
-    [slots, H, P, N], a gated delta rule's S [slots, Hv, Dk, Dv] — in
+    [slots, H, P, N], a gated delta rule's S [slots, Hv ÷ n, Dk, n · Dv]
+    (n = ModelConfig.delta_heads_per_row value heads side by side, so
+    that a row is whole 128-lane tiles where the heads allow it;
+    ops/hybrid_kernels.py `pack_heads`) — in
     float32 (the recurrence is summed over thousands of steps). `conv`,
     one per layer with a causal conv — a mixer, a delta-rule layer or a
     gated short convolution: [slots, K−1, channels] in the activation
@@ -176,11 +179,33 @@ class SlotState:
     def nbytes(self) -> int:
         return sum(x.nbytes for x in jax.tree.leaves(self))
 
+    @property
+    def resident_nbytes(self) -> int:
+        """Bytes the leaves take on their device (`resident_nbytes`)."""
+        return sum(resident_nbytes(x) for x in jax.tree.leaves(self))
+
+
+def resident_nbytes(x: jax.Array) -> int:
+    """Bytes `x` takes where it lives: its minor dims rounded up to the
+    tile of the layout the device holds it in (a TPU holds a float32
+    matrix in tiles of 8 × 128, so a last dim of 192 takes 256), read from
+    the array's own layout. Where the layout has no tiles (the CPU), the
+    array's nominal bytes."""
+    layout = x.format.layout
+    if not layout.tiling:
+        return x.nbytes
+    dims, tile = list(x.shape), layout.tiling[0]
+    for axis, t in zip(layout.major_to_minor[-len(tile):], tile):
+        dims[axis] = -(-dims[axis] // t) * t
+    return int(np.prod(dims)) * x.dtype.itemsize
+
 
 def init_slot_state(cfg: ModelConfig, slots: int, dtype=jnp.bfloat16) -> SlotState:
+    per_row = cfg.delta_heads_per_row
     matrix = {
         "M": (cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size),
-        "L": (cfg.delta_value_heads, cfg.delta_key_dim, cfg.delta_value_dim),
+        "L": (cfg.delta_value_heads // per_row, cfg.delta_key_dim,
+              per_row * cfg.delta_value_dim),
     }
     channels = {"M": cfg.conv_dim, "C": cfg.hidden_size,
                 "L": cfg.delta_conv_dim}

@@ -52,9 +52,13 @@ class ModelConfig:
     # Empty: the homogeneous attention+MLP block above, scanned.
     layer_pattern: str = ""
     use_rope: bool = True                     # False: no position embedding
-    # "*": RMSNorm over each q head and each k head (one learned gain of
-    # head_dim each) before the position embedding.
+    # "*": an RMSNorm on q and on k before the position embedding. Its
+    # span is `qk_norm_span`: "head", over each head's head_dim (one gain
+    # of head_dim, shared by the heads); "projection", ONE norm over the
+    # whole projection before it is split into heads (the mean square over
+    # all heads · head_dim columns, one gain a column).
     qk_norm: bool = False
+    qk_norm_span: str = "head"
     # "*": the leading share of each head's dims that the rotary embedding
     # turns (rotate-half inside it); the rest pass.
     partial_rotary_factor: float = 1.0
@@ -65,8 +69,13 @@ class ModelConfig:
     # Every RMSNorm of a pattern but the delta body's gated one: the gain
     # is `norm_offset + w` (1.0: the zero-centred norm, w starts at 0).
     norm_offset: float = 0.0
-    # A second RMSNorm of an entry, on the body's OUTPUT before the
-    # residual add: x ← x + Norm_post(f(Norm(x))), a gain of its own.
+    # The norms of an entry. `pre_norm`: the body reads Norm(x), else x as
+    # it comes. `sandwich_norm`: an RMSNorm with a gain of its own on the
+    # body's OUTPUT before the residual add. Pre alone (the default):
+    # x ← x + f(Norm(x)); both: x ← x + Norm_post(f(Norm(x))); post alone
+    # (`pre_norm` off, `sandwich_norm` on): x ← x + Norm_post(f(x)). An
+    # entry with neither is refused.
+    pre_norm: bool = True
     sandwich_norm: bool = False
     # "A" (latent attention, MLA): the query through a rank-`q_lora_rank`
     # bottleneck with a norm, heads of `qk_nope_head_dim` + 
@@ -91,13 +100,18 @@ class ModelConfig:
     ssm_chunk: int = 128                      # prefill's chunked form
     # "L": `delta_key_heads` q and k heads of `delta_key_dim`, each read by
     # `delta_value_heads` ÷ `delta_key_heads` value heads of
-    # `delta_value_dim`; state [value heads, key dim, value dim] per
-    # sequence; a causal depthwise conv of `conv_kernel` taps over q|k|v;
-    # prefill in chunks of `delta_chunk`.
+    # `delta_value_dim`; a matrix S [key dim, value dim] a value head and
+    # sequence, stored `delta_heads_per_row` heads side by side
+    # (engine/kv_cache.py SlotState); a causal depthwise conv of
+    # `conv_kernel` taps over q|k|v; β = `delta_beta_scale` · sigmoid(b):
+    # 1, β in (0, 1); 2, β in (0, 2) — the transition e^g (I − β k̃k̃ᵀ)
+    # then has a NEGATIVE eigenvalue along k̃ where β > 1; prefill in
+    # chunks of `delta_chunk`.
     delta_key_heads: int = 0
     delta_value_heads: int = 0
     delta_key_dim: int = 0
     delta_value_dim: int = 0
+    delta_beta_scale: float = 1.0
     delta_chunk: int = 64
     # "C": [B | C | u] = W_in h, a causal depthwise conv of `conv_kernel`
     # taps over B ⊙ u (no bias, no activation), W_out (C ⊙ conv): what a
@@ -196,6 +210,20 @@ class ModelConfig:
                 + self.delta_value_heads * self.delta_value_dim)
 
     @property
+    def delta_heads_per_row(self) -> int:
+        """Value heads whose S lie side by side in one row of the stored
+        delta-rule state, [value heads ÷ this, key dim, this · value dim]:
+        the fewest that make the row whole 128-lane tiles (a value dim of
+        192 alone is laid out 256 wide on the TPU, in HBM and in VMEM: a
+        third more bytes moved than held; two heads are 384, three tiles),
+        where that many divide the heads; else 1, the heads apart."""
+        heads, dim = self.delta_value_heads, self.delta_value_dim
+        for n in range(1, heads + 1):
+            if heads % n == 0 and n * dim % 128 == 0:
+                return n
+        return 1
+
+    @property
     def rotary_dim(self) -> int:
         """The leading dims of a head that the rotary embedding turns."""
         return int(self.head_dim * self.partial_rotary_factor)
@@ -214,6 +242,28 @@ class ModelConfig:
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r} mixes 'A' and '*': "
                 "the page pool has one geometry, latent rows or K and V"
+            )
+        if not (self.pre_norm or self.sandwich_norm):
+            raise ValueError(
+                "an entry needs a norm: pre_norm, sandwich_norm (the norm "
+                "on the body's output) or both"
+            )
+        if self.qk_norm_span not in ("head", "projection"):
+            raise ValueError(
+                f"qk_norm_span {self.qk_norm_span!r} must be 'head' or "
+                "'projection'"
+            )
+        if self.qk_norm_span == "projection" and (
+                not self.qk_norm or self.attn_output_gate):
+            raise ValueError(
+                "qk_norm_span 'projection' is a q/k norm over W_q's whole "
+                "output: it needs qk_norm, and cannot be taken where "
+                "attn_output_gate interleaves a gate with each head's query"
+            )
+        if self.delta_beta_scale not in (1.0, 2.0):
+            raise ValueError(
+                f"delta_beta_scale {self.delta_beta_scale} must be 1 (β in "
+                "(0, 1)) or 2 (β in (0, 2): negative eigenvalues allowed)"
             )
         if "E" in self.layer_pattern and not (
             0 < self.experts_held
@@ -583,6 +633,40 @@ TINY_PANGU = ModelConfig(
     routed_scaling_factor=2.5,
 )
 
+# A gated delta-rule / NoPE attention pattern at toy size: two periods of
+# three linear-attention layers and one attending layer, each over a dense
+# gated MLP; every body post-normed and none pre-normed; β in (0, 2); as
+# many key heads as value heads, of widths that differ and are no power of
+# two (two heads' 192-wide S side by side in a 384-wide row of the stored
+# state); full multi-head attention without a position embedding, q and k
+# normed over the whole projection.
+TINY_OLMO_HYBRID = ModelConfig(
+    name="tiny-olmo-hybrid",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=96,
+    num_layers=16,
+    num_heads=4,
+    num_kv_heads=4,
+    head_dim=16,
+    max_seq_len=512,
+    rms_norm_eps=1e-6,
+    layer_pattern="LDLDLD*D" * 2,
+    use_rope=False,
+    qk_norm=True,
+    qk_norm_span="projection",
+    pre_norm=False,
+    sandwich_norm=True,
+    delta_key_heads=6,
+    delta_value_heads=6,
+    delta_key_dim=24,
+    delta_value_dim=192,
+    delta_beta_scale=2.0,
+    delta_chunk=8,
+    conv_kernel=4,
+    dense_intermediate_size=96,
+)
+
 # A mid-size llama for single-chip benchmarking without 8B's 16 GiB of bf16
 # weights (v5e has 16 GiB HBM; 8B serves in int8 — see engine docs).
 LLAMA_1B_BENCH = replace(LLAMA32_1B, name="llama-1b-bench")
@@ -619,6 +703,7 @@ MODEL_REGISTRY = {
         TINY_LFM2,
         TINY_QWEN3_NEXT,
         TINY_PANGU,
+        TINY_OLMO_HYBRID,
         LLAMA_1B_BENCH,
         MIXTRAL_BENCH,
     )

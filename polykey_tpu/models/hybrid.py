@@ -1,11 +1,14 @@
 """Hybrid stacks: a layer pattern that is data (ModelConfig.layer_pattern).
 
-Each entry is `x ← x + f(RMSNorm(x))` with ONE of seven bodies, chosen by
-its character of the pattern; a published layer that is an operator and a
-feed-forward part under two norms is two entries (the norm's gain is
-`cfg.norm_offset + w`: 1 + w is the zero-centred norm). Where
-`cfg.sandwich_norm`, an entry is `x ← x + RMSNorm_post(f(RMSNorm(x)))`: a
-second gain, on the body's output before the residual add:
+Each entry is `x ← x + f(x)` through the norms `cfg` gives it, with ONE of
+the seven bodies below, chosen by its character of the pattern; a published
+layer that is an operator and a feed-forward part under two norms is two
+entries. The norms (each gain is `cfg.norm_offset + w`: 1 + w is the
+zero-centred norm): `x ← x + f(RMSNorm(x))` by default;
+`x ← x + RMSNorm_post(f(RMSNorm(x)))` where `cfg.sandwich_norm` adds a
+second gain on the body's output before the residual add;
+`x ← x + RMSNorm_post(f(x))` where `cfg.pre_norm` is off beside it (the
+body reads the residual stream as it comes):
 
 - "M", a Mamba-2 mixer: `[z | xBC | dt] = W_in u`; xBC through a causal
   depthwise conv and silu, split into x [H, P], B and C [G, N];
@@ -22,14 +25,18 @@ second gain, on the body's output before the residual add:
 - "L", a gated delta-rule linear attention: `[q | k | v | z] = W_qkvz u`,
   `[b | a] = W_ba u`; q|k|v through a causal depthwise conv (no bias) and
   silu; per value head (its key head is shared by Hv / Hk of them)
-  q̃ = q / ‖q‖ / √Dk, k̃ = k / ‖k‖, β = sigmoid(b),
-  g = −exp(A_log) softplus(a + dt_bias); the recurrence over a matrix
-  S [Dk, Dv]: `S ← e^g S`, `S ← S + k̃ ⊗ β (v − Sᵀk̃)`, `o = Sᵀq̃`;
+  q̃ = q / ‖q‖ / √Dk, k̃ = k / ‖k‖, β = `cfg.delta_beta_scale` · sigmoid(b)
+  (scale 2: β in (0, 2), a negative eigenvalue of the transition along k̃
+  where β > 1), g = −exp(A_log) softplus(a + dt_bias); the recurrence over
+  a matrix S [Dk, Dv]: `S ← e^g S`, `S ← S + k̃ ⊗ β (v − Sᵀk̃)`, `o = Sᵀq̃`;
   `W_out (RMSNorm_Dv(o) · gain ⊙ silu(z))`. What a sequence carries is S
-  [Hv, Dk, Dv] (float32) and the conv's last K−1 columns.
+  of every value head (float32; `cfg.delta_heads_per_row` heads side by
+  side in a row of whole lane tiles, ops/hybrid_kernels.py `pack_heads`)
+  and the conv's last K−1 columns.
 - "*", attention over the paged K/V pool: the projections, the paged
   write and the kernels of models/transformer.py `forward_paged`, with
-  RMSNorm over each q and k head first where `cfg.qk_norm` says so, the
+  an RMSNorm on q and k first where `cfg.qk_norm` says so (over each head,
+  or over the whole projection before the split: `cfg.qk_norm_span`), the
   position embedding over the leading `cfg.rotary_dim` of a head (none
   where `cfg.use_rope` is off), and the context multiplied by the sigmoid
   of a gate that W_q yields beside the query where `cfg.attn_output_gate`.
@@ -130,7 +137,9 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
     h = cfg.hidden_size
     # The effective gain is 1 (w = 0 under the zero-centred norm).
     gain = jnp.full((h,), 1.0 - cfg.norm_offset, dtype)
-    norms = {"norm": gain}
+    norms = {}
+    if cfg.pre_norm:
+        norms["norm"] = gain
     if cfg.sandwich_norm:
         norms["post_norm"] = gain
     k = jax.random.split(key, 8)
@@ -192,8 +201,11 @@ def init_layer(key: jax.Array, kind: str, cfg: ModelConfig, dtype) -> dict:
             "wo": _normal(k[3], (q, h), dtype, q),
         }
         if cfg.qk_norm:
-            layer["q_norm"] = layer["k_norm"] = jnp.full(
-                (cfg.head_dim,), 1.0 - cfg.norm_offset, dtype)
+            whole = cfg.qk_norm_span == "projection"
+            for name, width in (("q_norm", q), ("k_norm", kv)):
+                layer[name] = jnp.full(
+                    (width if whole else cfg.head_dim,),
+                    1.0 - cfg.norm_offset, dtype)
         return layer
     if kind == "latent":
         heads, rank = cfg.num_heads, cfg.kv_lora_rank
@@ -502,7 +514,10 @@ def _delta_factors(p: dict, x, b, a, cfg: ModelConfig, live):
     k = unit(x[..., width:2 * width])
     v = x[..., 2 * width:].reshape(
         *lead, cfg.delta_value_heads, cfg.delta_value_dim)
-    beta = jnp.where(live, jax.nn.sigmoid(b.astype(jnp.float32)), 0.0)
+    beta = jax.nn.sigmoid(b.astype(jnp.float32))
+    if cfg.delta_beta_scale != 1.0:
+        beta = cfg.delta_beta_scale * beta
+    beta = jnp.where(live, beta, 0.0)
     g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
         a.astype(jnp.float32) + p["dt_bias"])
     return q, k, v, beta, jnp.where(live, g, 0.0)
@@ -519,8 +534,9 @@ def _delta_out(p: dict, o, z, cfg: ModelConfig):
 
 
 def delta_decode(p: dict, u, cfg: ModelConfig, S, conv, active):
-    """One token for every lane: u [B, hidden], S [B, Hv, Dk, Dv] f32,
-    conv [B, K−1, C]. A lane that is not `active` keeps both unchanged
+    """One token for every lane: u [B, hidden], S the stored state
+    (`pack_heads` of [B, Hv, Dk, Dv], float32), conv [B, K−1, C]. A lane
+    that is not `active` keeps both unchanged
     (g = 0 and β = 0 leave S as it is, bit for bit; the conv window does
     not shift). Returns (out [B, hidden], S, conv)."""
     qkv, z, b, a = _delta_in(p, u, cfg)
@@ -534,6 +550,32 @@ def delta_decode(p: dict, u, cfg: ModelConfig, S, conv, active):
     return _delta_out(p, o, z, cfg), S, conv
 
 
+def _unit_lower_inverse(X):
+    """(I − X)^−1 for strictly lower-triangular X [.., Q, Q], float32, by
+    forward substitution: row t of the inverse is e_t + X_t · (the rows
+    before t), one row a turn. (Not the product of I + X^{2^i}, which is
+    the same matrix on paper: X is nilpotent. Its terms are the powers of
+    X, and where a chunk's keys lie close to one another — X near −β on
+    the whole triangle, as the keys of a real prompt do — those powers
+    reach 1e8 and more before they cancel: in float32 the product was off
+    by 2.4 at β < 1 and overflowed to NaN at β < 2 at a correlation of 0.8
+    between a chunk's keys, where every entry of the true inverse is at
+    most 2. Forward substitution never forms them: 1e-7.)"""
+    Q = X.shape[-1]
+
+    def row(t, inverse):
+        x_t = jax.lax.dynamic_slice_in_dim(X, t, 1, axis=-2)     # [.., 1, Q]
+        return jax.lax.dynamic_update_slice_in_dim(
+            inverse,
+            jax.lax.dynamic_slice_in_dim(inverse, t, 1, axis=-2)
+            + jnp.einsum("...ts,...su->...tu", x_t, inverse,
+                         precision=_HIGHEST),
+            t, axis=-2)
+
+    return jax.lax.fori_loop(
+        1, Q, row, jnp.broadcast_to(jnp.eye(Q, dtype=X.dtype), X.shape))
+
+
 def delta_chunks(q, k, v, beta, g, S_first, kind, chunk: int):
     """The chunked form of the gated delta rule over N rows of T tokens,
     equal to it token by token. q / k [N, T, Hk, Dk] (as `_delta_factors`
@@ -545,10 +587,11 @@ def delta_chunks(q, k, v, beta, g, S_first, kind, chunk: int):
 
     Inside a chunk, with c the running sum of g and Γ_ts = e^{c_t − c_s}
     (s ≤ t): the tokens' corrections solve (I + A) D = β (V − e^c K̃ S₀),
-    A = strictLower(diag(β) (K̃K̃ᵀ ⊙ Γ)); A is nilpotent, so (I + A)^−1 is
-    the product of I + (−A)^{2^i}. Then o = e^c Q̃ S₀ + (Q̃K̃ᵀ ⊙ Γ) D and
-    the chunk leaves S = e^{c_Q} S₀ + (e^{c_Q − c} K̃)ᵀ D. Every product in
-    float32 at the highest precision."""
+    A = strictLower(diag(β) (K̃K̃ᵀ ⊙ Γ)), a unit lower-triangular system
+    whose inverse is taken row by row (`_unit_lower_inverse`: forward
+    substitution). Then o = e^c Q̃ S₀ + (Q̃K̃ᵀ ⊙ Γ) D and the chunk leaves
+    S = e^{c_Q} S₀ + (e^{c_Q − c} K̃)ᵀ D. Every product in float32 at the
+    highest precision."""
     N, T, Hk, Dk = q.shape
     Hv, Dv = v.shape[2:]
     Q, r = min(chunk, T), Hv // Hk
@@ -568,14 +611,9 @@ def delta_chunks(q, k, v, beta, g, S_first, kind, chunk: int):
     kk = jnp.einsum("cgtd,cgsd->cgts", kc, kc, precision=_HIGHEST)
     qk = jnp.einsum("cgtd,cgsd->cgts", qc, kc, precision=_HIGHEST)
 
-    # (I + A)^−1 = Π (I + X^{2^i}), X = −A, factors until 2^i reaches Q.
-    X = -(bc[..., None] * kk[:, :, None] * gamma) \
-        * jnp.tri(Q, k=-1, dtype=jnp.float32)
-    inverse = jnp.eye(Q, dtype=jnp.float32) + X
-    for _ in range((Q - 1).bit_length() - 1):
-        X = jnp.einsum("cgrts,cgrsu->cgrtu", X, X, precision=_HIGHEST)
-        inverse = inverse + jnp.einsum(
-            "cgrts,cgrsu->cgrtu", inverse, X, precision=_HIGHEST)
+    inverse = _unit_lower_inverse(
+        -(bc[..., None] * kk[:, :, None] * gamma)
+        * jnp.tri(Q, k=-1, dtype=jnp.float32))
     # D = U − W S₀: what the corrections are from zero state, and what a
     # start state takes from them.
     U = jnp.einsum("cgrts,cgrsv->cgrtv", inverse, bc[..., None] * vc,
@@ -611,17 +649,27 @@ def delta_chunks(q, k, v, beta, g, S_first, kind, chunk: int):
 def delta_prefill(p: dict, u, cfg: ModelConfig, S, conv, rows: PrefillRows):
     """N windows of T tokens: u [N, T, hidden]; S / conv the stored state
     of the WHOLE slot batch. Returns (out, S, conv) with the end state of
-    every row that `rows.store` keeps written to its slot."""
-    T = u.shape[1]
+    every row that `rows.store` keeps written to its slot. The chunked
+    form works on the heads apart: the rows' states are taken out of the
+    stored layout and put back into it here."""
+    T, per_row = u.shape[1], cfg.delta_heads_per_row
     qkv, z, b, a = _delta_in(p, u, cfg)
     ext, conv_end = _window_prefill(conv, qkv, rows, cfg.conv_kernel)
     real = jnp.arange(T)[None, :] < rows.length[:, None]
     q, k, v, beta, g = _delta_factors(
         p, _conv(ext, p, T), b, a, cfg, real[..., None])
-    o, S_end = delta_chunks(q, k, v, beta, g, S[rows.slot], rows.source,
-                            cfg.delta_chunk)
-    S = S.at[rows.store].set(S_end, mode="drop")
-    return _delta_out(p, o, z, cfg), S, _store_rows(conv, conv_end, rows)
+    o, S_end = delta_chunks(
+        q, k, v, beta, g, hybrid_kernels.unpack_heads(S[rows.slot], per_row),
+        rows.source, cfg.delta_chunk)
+    S = S.at[rows.store].set(
+        hybrid_kernels.pack_heads(S_end, per_row), mode="drop")
+    # The stored state is an output of the whole program: left to itself
+    # the compiler puts off the small writes above to the program's end and
+    # keeps every layer's q|k|v columns (90 MB a layer at 8 x 512 rows of
+    # 11,520) alive until then. Tied to the layer's output, they are done
+    # when the layer is.
+    return jax.lax.optimization_barrier(
+        (_delta_out(p, o, z, cfg), S, _store_rows(conv, conv_end, rows)))
 
 
 # -- the gated short convolution ---------------------------------------------
@@ -663,8 +711,15 @@ def attention_layer(p: dict, h, positions, cfg: ModelConfig, attend, idx,
     if cfg.attn_output_gate:
         q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps, cfg.norm_offset)
+        def normed(y, gain):
+            # Over a head's dims, or over the projection as it was before
+            # the split (the heads folded back: the same bytes).
+            whole = cfg.qk_norm_span == "projection"
+            flat = y.reshape(B, T, -1) if whole else y
+            return rms_norm(flat, gain, cfg.rms_norm_eps,
+                            cfg.norm_offset).reshape(y.shape)
+
+        q, k = normed(q, p["q_norm"]), normed(k, p["k_norm"])
     if cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
         k = rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
@@ -725,7 +780,7 @@ def run_stack(params, cfg: ModelConfig, tokens, positions, pool, attend,
     hits = None
     for kind, idx in layer_kinds(cfg):
         p = params["layers"][kind][idx]
-        h = rms_norm(x, p["norm"], eps, offset)
+        h = rms_norm(x, p["norm"], eps, offset) if cfg.pre_norm else x
         if kind in ("mamba", "delta"):
             one, many = ((mamba_decode, mamba_prefill) if kind == "mamba"
                          else (delta_decode, delta_prefill))

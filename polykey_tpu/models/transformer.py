@@ -521,10 +521,22 @@ def forward_slots_counted(
     from .hybrid import run_stack
 
     pool = _stacked(paged)
+    if not decode:
+        # A prefill's page write is a `lax.cond` over the pool
+        # (ops/paged_attention.py `paged_write`). Unrolled, nothing stands
+        # between the first and last of them and the reshapes on either
+        # side of the walk, and the compiler moves those INTO the
+        # conditional: its branches then return the pool in two shapes,
+        # which is a copy of the whole pool every dispatch (3.75 GB of a
+        # 4,096-page multi-head pool; tests/test_paged_layout.py). The
+        # scanned stack has the loop's edge there.
+        pool = jax.lax.optimization_barrier(pool)
     hidden, held, state, hits = run_stack(
         params, cfg, tokens, positions, pool if decode else (pool, stage),
         attend, state, rows, active,
     )
+    if not decode:
+        held = jax.lax.optimization_barrier(held)
     return (hidden, _unstacked(paged, held if decode else held[0]), state,
             hits)
 

@@ -15,13 +15,21 @@ on the vector units read 1.167, XLA's own fusion 0.871.
 
 `gated_delta_state_update` — one decode step of the gated delta rule for
 every lane: S ← e^g S + k̃ ⊗ β (v − e^g Sᵀk̃), o = Sᵀq̃ of the NEW state,
-with the float32 state [B, Hv, Dk, Dv] aliased in place. Both contractions
-are taken from the state as it was read (m = e^g Sᵀk̃; o = e^g Sᵀq̃ +
-(q̃·k̃) d), so the state is read once and written once (64 lanes × 2 MiB a
-layer) and everything else is kilobytes. k̃ and q̃ reach the kernel
-transposed ([B, Dk, Hk]: a key head's vector is a [Dk, 1] column that
-broadcasts along the state's lanes, the value dims); the contractions over
-Dk are sums down the sublanes on the vector units.
+with the float32 state aliased in place. Both contractions are taken from
+the state as it was read (m = e^g Sᵀk̃; o = e^g Sᵀq̃ + (q̃·k̃) d), so the
+state is read once and written once (64 lanes × 2 MiB a layer) and
+everything else is kilobytes. k̃ and q̃ reach the kernel transposed
+([B, Dk, Hk]: a key head's vector is a [Dk, 1] column that broadcasts
+along the state's lanes, the value dims); the contractions over Dk are
+sums down the sublanes on the vector units. The state is stored
+[B, Hv ÷ n, Dk, n · Dv] (`pack_heads`): n value heads side by side in a
+row, so that a value dim which is not whole 128-lane tiles (192: laid out
+256 wide, a third more bytes moved than held) makes rows that are (two
+heads: 384). n = 1 is [B, Hv, Dk, Dv] itself, and the kernel is then the
+one written for it, operation for operation; with n > 1 a row's per-head
+factors — decay, β, q̃·k̃, the k̃ and q̃ columns — are spread over the row's
+lanes by selects on the lane index, and v and o are [Hv ÷ n, n · Dv], the
+same bytes as [Hv, Dv].
 
 `moe_held_experts_grouped` — the held experts of an expert layer as the
 chip runs them at EVERY row count (a decode step's 64 rows, a one-window
@@ -171,11 +179,35 @@ def ssm_state_update(h, dA, xdt, Bm, Cm, *, interpret: bool = False):
 # -- gated delta rule decode state update ----------------------------------
 
 
+def pack_heads(S, per_row: int):
+    """[.., Hv, Dk, Dv] → the stored layout [.., Hv ÷ n, Dk, n · Dv]: row
+    r holds heads r·n .. r·n + n − 1 side by side, head j of them in
+    columns j·Dv .. (j + 1)·Dv − 1. n = 1: S itself."""
+    if per_row == 1:
+        return S
+    *lead, heads, Dk, Dv = S.shape
+    return S.reshape(*lead, heads // per_row, per_row, Dk, Dv).swapaxes(
+        -3, -2).reshape(*lead, heads // per_row, Dk, per_row * Dv)
+
+
+def unpack_heads(S, per_row: int):
+    """The inverse of `pack_heads`."""
+    if per_row == 1:
+        return S
+    *lead, rows, Dk, width = S.shape
+    return S.reshape(*lead, rows, Dk, per_row, width // per_row).swapaxes(
+        -3, -2).reshape(*lead, rows * per_row, Dk, width // per_row)
+
+
 def gated_delta_state_update_jnp(S, decay, beta, k, q, v):
-    """S [B, Hv, Dk, Dv], decay = e^g and beta [B, Hv], k / q [B, Hk, Dk]
-    (L2-normed, q scaled; value head j reads key head j div Hv/Hk),
-    v [B, Hv, Dv], all float32 → (S_new, o [B, Hv, Dv]). A lane with
-    decay 1 and beta 0 keeps its state bit for bit."""
+    """S the stored state (`pack_heads` of [B, Hv, Dk, Dv]; the heads a
+    row holds are read off its width against v's), decay = e^g and beta
+    [B, Hv], k / q [B, Hk, Dk] (L2-normed, q scaled; value head j reads
+    key head j div Hv/Hk), v [B, Hv, Dv], all float32 → (S_new in the
+    stored layout, o [B, Hv, Dv]). A lane with decay 1 and beta 0 keeps
+    its state bit for bit."""
+    per_row = S.shape[-1] // v.shape[-1]
+    S = unpack_heads(S, per_row)
     rep = S.shape[1] // k.shape[1]
     kh, qh = jnp.repeat(k, rep, axis=1), jnp.repeat(q, rep, axis=1)
     hi = jax.lax.Precision.HIGHEST
@@ -184,27 +216,44 @@ def gated_delta_state_update_jnp(S, decay, beta, k, q, v):
     o = (decay[..., None] * jnp.einsum("bhkv,bhk->bhv", S, qh, precision=hi)
          + jnp.sum(qh * kh, axis=-1, keepdims=True) * d)
     new = decay[..., None, None] * S + kh[..., :, None] * d[..., None, :]
-    return new, o
+    return pack_heads(new, per_row), o
 
 
 def _delta_kernel(decay_ref, beta_ref, qk_ref, kt_ref, qt_ref, v_ref, s_ref,
-                  out_ref, o_ref):
+                  out_ref, o_ref, *, per_row: int):
     # decay / beta [B, Hv] and qk = q̃·k̃ [B, Hk] whole, in SMEM (scalars of
-    # a head); blocks of one lane: kt / qt [Dk, Hk], v / o [Hv, Dv],
-    # s / out [Hv, Dk, Dv].
-    Hv, Hk = v_ref.shape[0], kt_ref.shape[1]
+    # a head); blocks of one lane: kt / qt [Dk, Hk], v / o [rows, n · Dv],
+    # s / out [rows, Dk, n · Dv], n = per_row heads side by side in a row.
+    rows, width = v_ref.shape
+    Hk = kt_ref.shape[1]
+    rep = rows * per_row // Hk
     lane = pl.program_id(0)
-    for head in range(Hv):
-        g = head // (Hv // Hk)
-        kc, qc = kt_ref[:, g:g + 1], qt_ref[:, g:g + 1]        # [Dk, 1]
-        decay = decay_ref[lane, head]
-        S = s_ref[head]
-        m = decay * jnp.sum(S * kc, axis=0, keepdims=True)     # [1, Dv]
-        d = beta_ref[lane, head] * (v_ref[head:head + 1, :] - m)
-        o_ref[head:head + 1, :] = (
+    # Where each head of a row but the first begins, as a mask of lanes.
+    starts = [
+        jax.lax.broadcasted_iota(jnp.int32, (1, width), 1) >= j * width // per_row
+        for j in range(1, per_row)]
+
+    def spread(of_head):
+        """One factor a head of the row → that factor on the head's own
+        columns (n = 1: the factor itself)."""
+        out = of_head[0]
+        for begins, mine in zip(starts, of_head[1:]):
+            out = jnp.where(begins, mine, out)
+        return out
+
+    for row in range(rows):
+        heads = range(row * per_row, (row + 1) * per_row)
+        kc = spread([kt_ref[:, h // rep:h // rep + 1] for h in heads])
+        qc = spread([qt_ref[:, h // rep:h // rep + 1] for h in heads])
+        decay = spread([decay_ref[lane, h] for h in heads])
+        S = s_ref[row]
+        m = decay * jnp.sum(S * kc, axis=0, keepdims=True)     # [1, n · Dv]
+        d = spread([beta_ref[lane, h] for h in heads]) \
+            * (v_ref[row:row + 1, :] - m)
+        o_ref[row:row + 1, :] = (
             decay * jnp.sum(S * qc, axis=0, keepdims=True)
-            + qk_ref[lane, g] * d)
-        out_ref[head] = decay * S + kc * d
+            + spread([qk_ref[lane, h // rep] for h in heads]) * d)
+        out_ref[row] = decay * S + kc * d
 
 
 def gated_delta_state_update(S, decay, beta, k, q, v, *,
@@ -212,19 +261,19 @@ def gated_delta_state_update(S, decay, beta, k, q, v, *,
     """The kernel form of `gated_delta_state_update_jnp`; `S` is updated
     in place (aliased), so the caller's donated state buffer is the
     output."""
-    B, Hv, Dk, Dv = S.shape
-    Hk = k.shape[1]
+    B, rows, Dk, width = S.shape
+    Hk, (Hv, Dv) = k.shape[1], v.shape[1:]
     scalars = pl.BlockSpec(memory_space=pltpu.SMEM)
     column = pl.BlockSpec((None, Dk, Hk), lambda b: (b, 0, 0))
-    row = pl.BlockSpec((None, Hv, Dv), lambda b: (b, 0, 0))
-    state = pl.BlockSpec((None, Hv, Dk, Dv), lambda b: (b, 0, 0, 0))
-    return pl.pallas_call(
-        _delta_kernel,
+    row = pl.BlockSpec((None, rows, width), lambda b: (b, 0, 0))
+    state = pl.BlockSpec((None, rows, Dk, width), lambda b: (b, 0, 0, 0))
+    new, o = pl.pallas_call(
+        functools.partial(_delta_kernel, per_row=width // Dv),
         grid=(B,),
         in_specs=[scalars, scalars, scalars, column, column, row, state],
         out_specs=[state, row],
         out_shape=[jax.ShapeDtypeStruct(S.shape, S.dtype),
-                   jax.ShapeDtypeStruct(v.shape, jnp.float32)],
+                   jax.ShapeDtypeStruct((B, rows, width), jnp.float32)],
         input_output_aliases={6: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
@@ -233,7 +282,8 @@ def gated_delta_state_update(S, decay, beta, k, q, v, *,
         interpret=interpret,
         name="gated_delta_state_update",
     )(decay, beta, jnp.sum(q * k, axis=-1), k.transpose(0, 2, 1),
-      q.transpose(0, 2, 1), v, S)
+      q.transpose(0, 2, 1), v.reshape(B, rows, width), S)
+    return new, o.reshape(B, Hv, Dv)
 
 
 # -- held experts of an expert layer ---------------------------------------

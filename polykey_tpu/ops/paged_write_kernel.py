@@ -43,6 +43,11 @@ decode write is one row per lane, each lane a different sequence), so
 their full-page write-backs cannot clobber each other; rows that share
 a page must not be written as lanes of one wave.
 
+Every lane's page stays in VMEM between the waves: the call raises its
+VMEM limit where that is more than the compiler's default allows
+(`_vmem_limit`; 64 lanes of 30 KV heads of 128) and is otherwise the call
+it always was.
+
 Hk*D must be 128-aligned for the folded data-pool DMA — the same
 `use_paged_kernel` gate as the read kernel. Off-TPU
 callers keep the XLA scatter.
@@ -58,6 +63,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+# What a kernel's VMEM may hold unless the call says otherwise (the
+# compiler's scoped default), and what this call asks for where its pages
+# in flight need more.
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
+
+
+def _vmem_limit(pools: list, rows: list):
+    """None — the call as it always was — while every lane's page and row
+    fit the compiler's default with room to spare (64 lanes of K and V
+    pages of 16 x 512 columns are 2 MB); twice their bytes where they do
+    not (64 lanes x 30 KV heads of 128: 15.7 MB of pages in flight)."""
+    held = sum(
+        (r.shape[0] * r.shape[1] * p.shape[1] * p.shape[2] + r.size)
+        * p.dtype.itemsize for p, r in zip(pools, rows))
+    if held <= _SCOPED_VMEM_DEFAULT // 2:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=2 * held)
 
 
 def _make_kernel(n_pools: int, B: int, ps: int):
@@ -153,6 +177,7 @@ def paged_write_rows_kernel(
         # Flattened input positions incl. the 2 scalar-prefetch args:
         # pids=0 offs=1 rows=2..2+n-1 pools=2+n..2+2n-1.
         input_output_aliases={2 + n + i: i for i in range(n)},
+        compiler_params=_vmem_limit(pools, rows),
         interpret=interpret,
         name="paged_kv_write",
     )(

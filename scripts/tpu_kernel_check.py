@@ -43,6 +43,10 @@ Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
      python scripts/tpu_kernel_check.py --delta-state   (the gated delta
        rule's decode update alone: `delta-compare` against its jnp form,
        `delta-time` µs a call beside the state's bytes ÷ the bandwidth)
+     python scripts/tpu_kernel_check.py --mha   (the paged decode read,
+       the page write and the blockwise prefill kernel at 30 query heads on
+       30 KV heads of 128 — groups of ONE — on 64 lanes: the attending
+       layers of olmo-hybrid-7b-pp2)
      JAX_PLATFORMS=cpu python scripts/tpu_kernel_check.py --interpret
        rehearses the script itself at small tables in Pallas interpret
        mode — it proves nothing about lowering and exits 2 like any run
@@ -773,8 +777,15 @@ def check_held_experts() -> None:
 
 # -- the gated delta rule's decode state update -------------------------------
 
-DELTA_SHAPE = (64, 16, 32, 128, 128)     # lanes, key heads, value heads, Dk, Dv
-DELTA_CALLS = 18                         # two steps of nine layers
+# lanes, key heads, value heads, Dk, Dv; the value heads side by side in a
+# row of the stored state (ops/hybrid_kernels.py `pack_heads`); the calls
+# timed back to back: two decode steps of the cell's linear layers.
+DELTA_SHAPES = (
+    ((64, 16, 32, 128, 128), 1, 18),    # qwen3-next-80b-a3b-ep4
+    ((64, 30, 30, 96, 192), 1, 24),     # olmo-hybrid-7b-pp2, heads apart:
+                                        # 192 is held 256 wide
+    ((64, 30, 30, 96, 192), 2, 24),     # ... as served: rows of 384
+)
 
 
 def delta_inputs(shape, seed: int = 0):
@@ -793,18 +804,24 @@ def delta_inputs(shape, seed: int = 0):
 
 
 def check_delta_state() -> None:
-    """`gated_delta_state_update` at the published shape: against its
-    jax.numpy form (an inactive lane bit for bit), and microseconds a call
-    — calls back to back inside one jitted scan that carries S, as the
-    decode step's nine layers do — beside the least time its bytes allow."""
+    """`gated_delta_state_update` at the published shapes, in the stored
+    layout and — where that puts heads side by side — with the heads apart
+    too: against its jax.numpy form (an inactive lane bit for bit), and
+    microseconds a call — calls back to back inside one jitted scan that
+    carries S, as the decode step's linear layers do — beside the least
+    time the PUBLISHED bytes allow, and the bytes the state takes on the
+    device."""
+    from polykey_tpu.engine.kv_cache import resident_nbytes
     from polykey_tpu.ops import hybrid_kernels as hk
 
     interpret = "interpret" in KERNEL
-    shape = (4, 2, 4, 8, 16) if interpret else DELTA_SHAPE
+    shapes = (((4, 2, 4, 8, 16), 1, 2), ((4, 2, 4, 8, 64), 2, 2)) \
+        if interpret else DELTA_SHAPES
     kernel = partial(hk.gated_delta_state_update, interpret=interpret)
 
-    def compare():
+    def compare(shape, per_row):
         S, decay, beta, k, q, v = delta_inputs(shape)
+        S = hk.pack_heads(S, per_row)
         decay, beta = decay.at[1].set(1.0), beta.at[1].set(0.0)
         want = jax.jit(hk.gated_delta_state_update_jnp)(S, decay, beta, k, q, v)
         got = jax.jit(kernel)(S, decay, beta, k, q, v)
@@ -813,9 +830,9 @@ def check_delta_state() -> None:
         return (f"S {assert_close(got[0], want[0], 1e-4)}, "
                 f"o {assert_close(got[1], want[1], 1e-4)}, lane 1 untouched")
 
-    def timed(update):
+    def timed(update, shape, per_row, calls):
         S, decay, beta, k, q, v = delta_inputs(shape)
-        calls = 2 if interpret else DELTA_CALLS
+        S = hk.pack_heads(S, per_row)
         run = jax.jit(lambda S, *rest: jax.lax.scan(
             lambda S, _: update(S, *rest), S, None, length=calls),
             donate_argnums=0)
@@ -829,13 +846,18 @@ def check_delta_state() -> None:
             return "rehearsed"
         least = 2 * S.nbytes / hbm_bytes_per_s()
         return (f"{best / calls * 1e6:.1f} us a call; state read + written "
-                f"{least * 1e6:.1f} us ({100 * least * calls / best:.1f} %)")
+                f"{least * 1e6:.1f} us ({100 * least * calls / best:.1f} %); "
+                f"{S.nbytes / 1e6:.1f} MB published, "
+                f"{resident_nbytes(S) / 1e6:.1f} MB on the device")
 
-    geometry = "x".join(map(str, shape))
-    case("delta-compare", geometry, compare)
-    case("delta-time", geometry + " kernel", partial(timed, kernel))
-    case("delta-time", geometry + " jnp", partial(
-        timed, hk.gated_delta_state_update_jnp))
+    for shape, per_row, calls in shapes:
+        geometry = "x".join(map(str, shape)) + (
+            f" {per_row} heads a row" if per_row > 1 else "")
+        case("delta-compare", geometry, partial(compare, shape, per_row))
+        case("delta-time", geometry + " kernel",
+             partial(timed, kernel, shape, per_row, calls))
+        case("delta-time", geometry + " jnp", partial(
+            timed, hk.gated_delta_state_update_jnp, shape, per_row, calls))
 
 
 def check_block_until_ready() -> None:
@@ -886,6 +908,13 @@ def main() -> int:
     # hardware last, so a hang there costs no other case its evidence.
     held_only = "--held-experts" in sys.argv[1:]
     delta_only = "--delta-state" in sys.argv[1:]
+    if "--mha" in sys.argv[1:]:
+        GEOMETRIES[:] = [("mha-30x128", 30, 30, 128, None, None)]
+        LANES = 8 if interpret else 64
+        check_decode(quantized=False)
+        check_write(quantized=False)
+        check_flash()
+        return report(identity, interpret)
     if "--prefill-read" in sys.argv[1:]:
         check_flash()
         check_prefill_read_timing()

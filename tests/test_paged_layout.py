@@ -629,6 +629,69 @@ def test_delta_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
 
 @pytest.mark.parametrize("prefill", [None, (1, 128), (2, 512)],
                          ids=["decode", "prefill-1x128", "prefill-2x512"])
+def test_delta_mha_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
+    """The steps of a gated delta-rule / NoPE multi-head attention pattern
+    over dense MLPs, post-normed alone, compiled for a v5e at the published
+    widths of what it adds — hidden 3840, 30 linear heads of 96 x 192 with
+    as many key heads (a state of [slots, 15, 96, 384] float32 a layer: two
+    heads side by side, rows of three whole lane tiles), 30 query heads on
+    30 KV heads of 128 (groups of ONE: 7,680 columns of K and V a token),
+    an MLP of 11008 — at toy depth and a short vocabulary. Decode: S in its
+    stored layout, the conv columns and the pool are aliased input to
+    output, no instruction copies a state-sized buffer, the delta update
+    is ONE call a linear layer under the name the benchmark's readers hold
+    fixed, the paged kernels take 30 KV heads as they take 2 or 8, and no
+    expert product is there. Prefill: the chunked form on the heads apart,
+    which never calls the decode kernel. Every module: q and k stay plain
+    matmuls under the full-width norm (ISSUE 44's three forms absent)."""
+    from polykey_tpu.models.config import get_config
+
+    cfg = replace(
+        get_config("tiny-olmo-hybrid"), name="delta-mha-probe",
+        vocab_size=4096, hidden_size=3840, layer_pattern="LD*D", num_layers=4,
+        num_heads=30, num_kv_heads=30, head_dim=128, delta_key_heads=30,
+        delta_value_heads=30, delta_key_dim=96, delta_value_dim=192,
+        delta_chunk=64, intermediate_size=11008,
+        dense_intermediate_size=11008,
+    )
+    compiled, paged, state = _compile_pattern_step(
+        v5e, monkeypatch, cfg, prefill=prefill)
+    hlo = compiled.as_text()
+    wk_bytes = cfg.hidden_size * cfg.num_kv_heads * cfg.head_dim * 2
+    assert head_window_products(hlo) == []
+    assert [r for r in staged_weight_slices(hlo, wk_bytes)
+            if not r.startswith("f32")] == []
+    assert layer_weight_copies(hlo, "bf16", cfg.hidden_size) == []
+    calls = _kernel_calls(hlo)
+    assert not any(c.startswith("%moe_held_experts") for c in calls), calls
+    updates = sum(c.startswith("%gated_delta_state_update") for c in calls)
+    assert updates == (1 if prefill is None else 0), calls
+    # No module copies the pool (1,024 pages of 16 x 7,680 columns: 252 MB;
+    # unbarred, the unrolled walk's prefill had the last layer's write
+    # return the pool in two shapes and copied all of it, 3.75 GB of a
+    # 4,096-page pool on a chip with 1.7 GB to spare), and it is aliased.
+    pool = f"bf16[1,1024,2,16,{cfg.num_kv_heads * cfg.head_dim}]"
+    assert [line for line in hlo.splitlines()
+            if pool in line and " copy(" in line] == []
+    assert aliased_pool_parameters(hlo, pool) == 1
+    if prefill is not None:
+        return
+    S, conv = "f32[64,15,96,384]", "bf16[64,3,11520]"
+    assert [s.shape for s in state.ssm] == [(64, 15, 96, 384)]
+    assert [r for r in weight_relayouts(hlo, wk_bytes) if conv not in r] == []
+    assert aliased_pool_parameters(hlo, S) == 1
+    assert aliased_pool_parameters(hlo, conv) == 1
+    assert [line for line in hlo.splitlines()
+            if S in line and " copy(" in line] == []
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((paged, state)))
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+    for name in ("%paged_kv_write", "%paged_attention_decode"):
+        assert sum(c.startswith(name) for c in calls) == 1, (name, calls)
+
+
+@pytest.mark.parametrize("prefill", [None, (1, 128), (2, 512)],
+                         ids=["decode", "prefill-1x128", "prefill-2x512"])
 def test_latent_attention_stack_compiled_for_v5e(v5e, monkeypatch, prefill):
     """The steps of a latent-attention (MLA) pattern with sandwich norms
     compiled for a v5e at the published widths of what it adds — hidden
