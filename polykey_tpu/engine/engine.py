@@ -115,7 +115,7 @@ from .kv_cache import (
 )
 from .metrics import EngineMetrics, RequestTimings
 from .prefix_cache import TIER_DEVICE, TIER_HOST
-from .sampling import sample_tail
+from .sampling import sample_tail, sample_tail_counted
 from .tokenizer import load_tokenizer
 
 
@@ -361,7 +361,7 @@ def _decode_fn(
     transfer per block instead of separate token/mask reads.
 
     `greedy` (static) selects the argmax-only tail when every active slot
-    is greedy, skipping sample_dynamic's [B, vocab] sort entirely.
+    is greedy, skipping the sampler entirely.
 
     `state` (the per-slot recurrent state of a stateful model; an empty
     pytree otherwise) rides the scan's carry beside the pool: a sub-step
@@ -372,6 +372,12 @@ def _decode_fn(
     as ONE MORE ROW of `packed` ([steps + 1, B], the sum in every
     column). A model without an expert layer compiles to what it compiled
     to without the count, and downloads [steps, B].
+
+    The sampled variant adds one row more, after that one: the sub-steps
+    of the block on which the exact sampler sorted the whole vocabulary
+    for a live sampled lane (sampling._trunc_thresholds; on every other
+    sub-step the rows' sorted heads answered). The greedy variant holds
+    no sampler and carries nothing for it.
     """
 
     def one(carry, _):
@@ -383,23 +389,25 @@ def _decode_fn(
         )
         logits = unembed(params, cfg, hidden[:, 0])        # [B, V]
         # The new token lands at index seq → that position keys its draw.
-        tokens = sample_tail(
-            logits, seeds, seq, temperature, top_p, top_k, greedy, candidates
+        tokens, full = sample_tail_counted(
+            logits, seeds, seq, temperature, top_p, top_k, greedy, candidates,
+            live=act,
         )
         tokens = jnp.where(act, tokens, 0)
         new_seq = seq + act.astype(jnp.int32)
         cont = act & (tokens != eos_id) & (new_seq < caps)
         packed = jnp.where(act, tokens, -1)
-        return (tokens, new_seq, cont, paged, state), (packed, hit)
+        return (tokens, new_seq, cont, paged, state), (packed, hit, full)
 
     carry = (last_tokens, seq_lens, active, paged, state)
-    (last, seq, act, paged, state), (packed, hits) = jax.lax.scan(
+    (last, seq, act, paged, state), (packed, hits, fulls) = jax.lax.scan(
         one, carry, None, length=steps
     )
-    if hits is not None:
-        packed = jnp.concatenate(
-            [packed, jnp.broadcast_to(jnp.sum(hits), (1, packed.shape[1]))]
-        )
+    sums = [jnp.sum(n, dtype=jnp.int32) for n in (hits, fulls) if n is not None]
+    if sums:
+        packed = jnp.concatenate([packed] + [
+            jnp.broadcast_to(n, (1, packed.shape[1])) for n in sums
+        ])
     return packed, last, seq, act, paged, state
 
 
@@ -571,7 +579,9 @@ class _InflightBlock(NamedTuple):
     this dispatch) and `live` (slot indices active at dispatch) carry
     the device-time attribution inputs to process time (ISSUE 10);
     `steps` is the block's device steps per lane, which the lane-step
-    outcome counters need for a dead block that is never read."""
+    outcome counters need for a dead block that is never read; `sampled`
+    says the block ran _decode_fn's sampled variant, whose `packed` ends
+    in the sampler's row."""
 
     kind: str
     data: object
@@ -580,6 +590,7 @@ class _InflightBlock(NamedTuple):
     gap_ms: float = 0.0
     live: tuple = ()
     steps: int = 0
+    sampled: bool = False
 
 
 @dataclass(eq=False)     # compared by identity: `toks_dev` is an array
@@ -3276,7 +3287,7 @@ class InferenceEngine:
                 gap_ms, live, self._gamma + 1,
             )
         # Static variant: an all-greedy batch (the benchmark mode) skips
-        # sample_dynamic's [B, vocab] sort and all RNG work. At most two
+        # the sampler's sorted head and all RNG work. At most two
         # compiled variants exist; the mix flips only at slot transitions.
         greedy = bool(np.all(self._temperature[self._active] == 0.0))
         # Load-adaptive K: one active stream → small blocks (per-token
@@ -3355,7 +3366,7 @@ class InferenceEngine:
             )
         return _InflightBlock(
             "plain", packed_dev, self._snapshot_requests(), self._dispatch_seq,
-            gap_ms, live, steps,
+            gap_ms, live, steps, not greedy,
         )
 
     def _eff_top_k(self, request: GenRequest) -> int:
@@ -3449,6 +3460,7 @@ class InferenceEngine:
         # steps they ran (every _InflightBlock the engine builds; a bare
         # legacy tuple's 0 steps count as nothing).
         steps = block[6] if len(block) > 6 else 0
+        sampled = block[7] if len(block) > 7 else False
         slots = len(self._slots)
         # Observed lookahead: blocks dispatched after this one, before its
         # readback — ≥1 is the overlap the pipeline exists for; 0 is the
@@ -3494,14 +3506,19 @@ class InferenceEngine:
         # ~roundtrip_ms when the host is on the critical path (the r03
         # signature this pipeline exists to erase).
         stall_ms = (time.monotonic() - t_sync) * 1e3
+        if sampled:
+            # The sampled variant's last row (_decode_fn): the sub-steps
+            # on which the sampler sorted the whole vocabulary.
+            packed, full_sorts = packed[:-1], int(packed[-1, 0])
         if self._expert_layers:
             # One more row (_decode_fn): the held experts the block's
             # expert layers hit, over its steps that had a live lane.
             packed, hit = packed[:-1], int(packed[-1, 0])
-            self.metrics.on_held_experts(
-                self._expert_layers * int((packed >= 0).any(axis=1).sum()),
-                hit,
-            )
+        live_steps = int((packed >= 0).any(axis=1).sum())
+        if sampled:
+            self.metrics.on_sampler_steps(live_steps, full_sorts)
+        if self._expert_layers:
+            self.metrics.on_held_experts(self._expert_layers * live_steps, hit)
         self.metrics.on_process_block(
             lookahead, stall_ms, trace_id=self._block_trace_id(reqs, live)
         )

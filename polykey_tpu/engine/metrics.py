@@ -213,6 +213,13 @@ class EngineMetrics:
         # snapshot once a block of a model with expert layers is in).
         self.held_expert_calls = 0
         self.held_experts_hit = 0
+        # The sampled decode program's steps with a live lane, over the
+        # blocks whose readback has landed, and those of them on which
+        # the exact sampler sorted the whole vocabulary — a live row's
+        # top_k or nucleus went past its sorted head (on_sampler_steps;
+        # in the snapshot once a sampled block is in).
+        self.sampler_steps_total = 0
+        self.sampler_full_sort_steps_total = 0
         # Prefill dispatches whose first tokens were read, and for each
         # the seconds since the engine last looked at it and found it
         # unfinished (or since its dispatch call returned): an upper
@@ -367,6 +374,15 @@ class EngineMetrics:
         with self._lock:
             self.held_expert_calls += calls
             self.held_experts_hit += hit
+
+    def on_sampler_steps(self, steps: int, full_sorts: int) -> None:
+        """One decode block of the sampled variant has landed: `steps` =
+        its steps with a live lane, `full_sorts` = those on which the
+        sampler sorted the whole vocabulary (counted on the device,
+        engine._decode_fn; sampling._trunc_thresholds says when)."""
+        with self._lock:
+            self.sampler_steps_total += steps
+            self.sampler_full_sort_steps_total += full_sorts
 
     def on_state_rows(self, reset: int, chained: int, resumed: int) -> None:
         """One prefill dispatch of a stateful model: real rows that
@@ -733,6 +749,11 @@ class EngineMetrics:
             if self.held_expert_calls:
                 snap["held_expert_calls"] = self.held_expert_calls
                 snap["held_experts_hit"] = self.held_experts_hit
+            if self.sampler_steps_total:
+                snap["sampler_steps_total"] = self.sampler_steps_total
+                snap["sampler_full_sort_steps_total"] = (
+                    self.sampler_full_sort_steps_total
+                )
         if self.ttft_hist.count:
             # TTFT tail percentiles — TTFT is half the north-star metric
             # and its tail, not its mean, is what operators chase. These

@@ -1,8 +1,10 @@
 """Token sampling: greedy, temperature, top-k, top-p.
 
 jit-friendly by construction: the sampling configuration is static (baked at
-trace time via SamplingParams), shapes never depend on data, and top-p uses a
-sort + cumulative-sum mask rather than dynamic truncation.
+trace time via SamplingParams), shapes never depend on data, and top-p is a
+cumulative-sum mask over each row's sorted head (the whole row sorted only
+when a nucleus runs past it: _trunc_thresholds) rather than dynamic
+truncation.
 """
 
 from __future__ import annotations
@@ -62,22 +64,101 @@ def _rank_keep_mask(width: int, top_k) -> jax.Array:
     return r < k[..., None]
 
 
-def _trunc_thresholds(scaled: jax.Array, top_p, top_k):
-    """THE exact-path truncation thresholds, from one descending sort:
-    (thr_p, thr_k) such that keeping `scaled >= thr_p` realizes the
-    shared top-p keep rule and `scaled >= thr_k` keeps the k largest
-    (ties keep all equal values). One implementation for the plain
-    sampler AND the speculative truncated dists — they must agree
-    token-for-token, so the rule lives in exactly one place."""
+# The sorted head's width: the exact path reads its truncation thresholds
+# out of each row's HEAD_WIDTH largest values and sorts the whole
+# vocabulary only when a live sampled row cannot be answered from them
+# (top_k wider than the head, or a nucleus that does not close inside
+# it). Sized on the chip (scripts/tpu_kernel_check.py --sampler-head;
+# PERF.md section 6, PR 61).
+HEAD_WIDTH = 64
+
+
+def _sorted_head(scaled: jax.Array, width: int) -> jax.Array:
+    """The `width` largest values of each row of `scaled` [N, V], in
+    descending order, duplicates included — exact. Two levels, so that
+    nothing the size of the row is ever sorted: the row is cut into
+    `groups` strided groups (element j belongs to group j mod groups),
+    one pass takes each group's maximum, and the `width` groups with the
+    largest maxima hold the row's `width` largest values (a value outside
+    them is below `width` distinct maxima), so the head is the top of
+    their members. A row too short to cut is taken whole."""
+    N, V = scaled.shape
+    per_group = 1
+    while width * (2 * per_group) ** 2 <= V:
+        per_group *= 2                    # ~sqrt(V / width): both top_k's
+    if per_group == 1:                    # are then about equally wide
+        return jax.lax.top_k(scaled, width)[0]
+    groups = -(-V // per_group)
+    padded = jnp.pad(
+        scaled, ((0, 0), (0, groups * per_group - V)),
+        constant_values=-jnp.inf,
+    ).reshape(N, per_group, groups)
+    _, chosen = jax.lax.top_k(jnp.max(padded, axis=1), width)   # [N, W]
+    members = jnp.take_along_axis(padded, chosen[:, None, :], axis=2)
+    return jax.lax.top_k(members.reshape(N, per_group * width), width)[0]
+
+
+def _full_sort_thresholds(scaled: jax.Array, top_p, top_k):
+    """(thr_p, thr_k) [N] from one descending sort of the whole row: the
+    rule the head stands in for, at any top_k and any nucleus."""
     V = scaled.shape[-1]
     sorted_desc = jnp.sort(scaled, axis=-1)[..., ::-1]
-    keep_p = _top_p_keep_mask(sorted_desc, top_p)
-    thr_p = jnp.min(
-        jnp.where(keep_p, sorted_desc, jnp.inf), axis=-1, keepdims=True
-    )
-    kidx = jnp.clip(jnp.where(top_k > 0, top_k, V) - 1, 0, V - 1)
-    thr_k = jnp.take_along_axis(sorted_desc, kidx[..., None], axis=-1)
+    keep = _top_p_keep_mask(sorted_desc, top_p[:, None])
+    thr_p = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1)
+    kidx = jnp.clip(top_k - 1, 0, V - 1)
+    thr_k = jnp.take_along_axis(sorted_desc, kidx[:, None], axis=-1)[:, 0]
     return thr_p, thr_k
+
+
+@jax.jit       # one executable for an eager caller too, branches and all
+def _trunc_thresholds(scaled: jax.Array, top_p, top_k, sampled=None):
+    """THE exact-path truncation thresholds: (thr_p, thr_k, full) such
+    that keeping `scaled >= thr_p` realizes the shared top-p keep rule
+    and `scaled >= thr_k` keeps the k largest (ties keep all equal
+    values); -inf where the row's top_p >= 1 / top_k <= 0 disables the
+    rule. One implementation for the plain sampler, the static top-p
+    filter AND the speculative truncated dists — they must agree
+    token-for-token, so the rule lives in exactly one place.
+
+    scaled [..., V]; top_p, top_k [...]; `sampled` [...] bool marks the
+    rows whose thresholds will be used (live, temperature > 0; None: every
+    row). Both thresholds come from the row's sorted head (_sorted_head,
+    HEAD_WIDTH values): thr_k is its k-th entry, thr_p the smallest entry
+    the keep rule keeps, on the head's TRUE probabilities
+    exp(head - logsumexp(row)) — the nucleus closes inside the head when
+    the head's last entry is not kept. A sampled row with top_k beyond
+    the head, or a nucleus still open at its end, needs the whole row
+    sorted: `full` (a scalar) says some row did, and then — only then —
+    the sort runs and answers those rows. A row answered from its head is
+    answered from it whatever the rest of the batch needs, so a request's
+    thresholds never depend on its neighbours."""
+    lead, V = scaled.shape[:-1], scaled.shape[-1]
+    W = min(V, HEAD_WIDTH)
+    scaled = scaled.reshape(-1, V)
+    top_p = jnp.broadcast_to(top_p, lead).reshape(-1)
+    top_k = jnp.broadcast_to(top_k, lead).reshape(-1)
+    head = _sorted_head(scaled, W)                         # [N, W] desc
+    lse = jax.scipy.special.logsumexp(scaled, axis=-1, keepdims=True)
+    keep = _prefix_keep_mask(jnp.exp(head - lse), top_p[:, None])
+    thr_p = jnp.min(jnp.where(keep, head, jnp.inf), axis=-1)
+    kidx = jnp.clip(top_k - 1, 0, W - 1)
+    thr_k = jnp.take_along_axis(head, kidx[:, None], axis=-1)[:, 0]
+    cut_p, cut_k = top_p < 1.0, top_k > 0
+    needs = (cut_p & keep[:, -1] & (W < V)) | (top_k > W)
+    if sampled is not None:
+        needs &= jnp.broadcast_to(sampled, lead).reshape(-1)
+    full = jnp.any(needs)
+
+    def sort_rows(_):
+        full_p, full_k = _full_sort_thresholds(scaled, top_p, top_k)
+        return jnp.where(needs, full_p, thr_p), jnp.where(needs, full_k, thr_k)
+
+    thr_p, thr_k = jax.lax.cond(
+        full, sort_rows, lambda _: (thr_p, thr_k), None
+    )
+    thr_p = jnp.where(cut_p, thr_p, -jnp.inf)
+    thr_k = jnp.where(cut_k, thr_k, -jnp.inf)
+    return thr_p.reshape(*lead, 1), thr_k.reshape(*lead, 1), full
 
 
 def truncated_dist(
@@ -110,7 +191,7 @@ def truncated_dist(
     else:
         # Exact full-vocab truncation (candidates disabled OR wider than
         # the vocabulary — never silently skip the requested nucleus).
-        thr_p, thr_k = _trunc_thresholds(scaled, top_p[..., None], top_k)
+        thr_p, thr_k, _ = _trunc_thresholds(scaled, top_p, top_k)
         trunc = jnp.where(
             (scaled >= thr_p) & (scaled >= thr_k), probs, 0.0
         )
@@ -122,15 +203,12 @@ def truncated_dist(
 
 
 def _top_p_threshold(scaled: jax.Array, p) -> jax.Array:
-    """Exact full-vocab top-p cut: the smallest kept logit (descending
-    sort + shared keep rule). ONE implementation — the exact sampler, the
-    static top-p filter, and the speculative truncated dists all cut at
-    this threshold, so tie handling cannot drift between paths."""
-    sorted_logits = jnp.sort(scaled, axis=-1)[..., ::-1]
-    keep = _top_p_keep_mask(sorted_logits, p)
-    return jnp.min(
-        jnp.where(keep, sorted_logits, jnp.inf), axis=-1, keepdims=True
-    )
+    """Exact full-vocab top-p cut: the smallest kept logit
+    (_trunc_thresholds with no top-k). ONE implementation — the exact
+    sampler, the static top-p filter, and the speculative truncated dists
+    all cut at this threshold, so tie handling cannot drift between
+    paths."""
+    return _trunc_thresholds(scaled, p, jnp.int32(0))[0]
 
 
 def _apply_top_p(logits: jax.Array, p: float) -> jax.Array:
@@ -138,12 +216,16 @@ def _apply_top_p(logits: jax.Array, p: float) -> jax.Array:
     return jnp.where(logits < threshold, -jnp.inf, logits)
 
 
-def _masked_rows(logits, temp, top_p, top_k, candidates: int):
+def _masked_rows(logits, temperature, top_p, top_k, candidates: int,
+                 live=None):
     """Shared top-p/top-k masking for the dynamic samplers. Returns
-    (greedy [B], masked [B, C or V], idx [B, C] | None, scaled_full):
-    categorical over `masked` (mapped through idx when present) realizes
-    the truncated distribution; `scaled_full` serves untruncated rows
-    (top_p >= 1 and top_k disabled)."""
+    (greedy [B], masked [B, C or V], idx [B, C] | None, scaled_full,
+    full): categorical over `masked` (mapped through idx when present)
+    realizes the truncated distribution; `scaled_full` serves untruncated
+    rows (top_p >= 1 and top_k disabled); `full` says the exact path
+    sorted the whole vocabulary for some sampled row among `live` [B]
+    (None: every row) — never, on the prefiltered path."""
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
     if candidates and candidates < logits.shape[-1]:
         scaled_full = logits / temp                       # [B, V]
         lse = jax.scipy.special.logsumexp(
@@ -154,15 +236,20 @@ def _masked_rows(logits, temp, top_p, top_k, candidates: int):
         probs = jnp.exp(vals - lse)       # true full-vocab probabilities
         keep = _prefix_keep_mask(probs, top_p[:, None])
         keep &= _rank_keep_mask(candidates, top_k)
-        return greedy, jnp.where(keep, vals, -jnp.inf), idx, scaled_full
+        return (greedy, jnp.where(keep, vals, -jnp.inf), idx, scaled_full,
+                jnp.bool_(False))
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits / temp
-    # Per-row top-p/top-k on the scaled logits (one sort; shared rules).
-    thr_p, thr_k = _trunc_thresholds(scaled, top_p[:, None], top_k)
+    # Per-row top-p/top-k on the scaled logits (shared rules): a greedy
+    # or idle row's thresholds are never read, whatever its stale top_p.
+    sampled = temperature > 0.0
+    if live is not None:
+        sampled &= live
+    thr_p, thr_k, full = _trunc_thresholds(scaled, top_p, top_k, sampled)
     masked = jnp.where(
         (scaled < thr_p) | (scaled < thr_k), -jnp.inf, scaled
     )
-    return greedy, masked, None, scaled
+    return greedy, masked, None, scaled, full
 
 
 def sample_dynamic(
@@ -194,9 +281,8 @@ def sample_dynamic(
     """
     if top_k is None:
         top_k = jnp.zeros(logits.shape[0], jnp.int32)
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    greedy, masked, idx, scaled_full = _masked_rows(
-        logits, temp, top_p, top_k, candidates
+    greedy, masked, idx, scaled_full, _ = _masked_rows(
+        logits, temperature, top_p, top_k, candidates
     )
     if idx is not None:
         k_pre, k_full = jax.random.split(key)
@@ -242,6 +328,26 @@ def fold_positions(base_keys: jax.Array, positions: jax.Array) -> jax.Array:
     return jax.vmap(jax.random.fold_in)(base_keys, positions)
 
 
+def _sample_rows(logits, keys, temperature, top_p, top_k, candidates: int,
+                 live=None):
+    """sample_dynamic_rows' draw and whether the exact path sorted the
+    whole vocabulary for it (_masked_rows' `full`): (tokens [B], full)."""
+    greedy, masked, idx, scaled_full, took_full = _masked_rows(
+        logits, temperature, top_p, top_k, candidates, live
+    )
+    if idx is not None:
+        keys2 = jax.vmap(lambda k: jax.random.fold_in(k, 1))(keys)
+        local = _row_categorical(keys, masked)
+        truncated = jnp.take_along_axis(
+            idx, local[:, None], axis=-1
+        )[:, 0].astype(jnp.int32)
+        full = _row_categorical(keys2, scaled_full)
+        sampled = jnp.where((top_p >= 1.0) & (top_k <= 0), full, truncated)
+    else:
+        sampled = _row_categorical(keys, masked)
+    return jnp.where(temperature == 0.0, greedy, sampled), took_full
+
+
 def sample_dynamic_rows(
     logits: jax.Array,            # [B, vocab] fp32
     keys: jax.Array,              # [B, 2] uint32 — per-row keys
@@ -255,21 +361,9 @@ def sample_dynamic_rows(
     granularity differs."""
     if top_k is None:
         top_k = jnp.zeros(logits.shape[0], jnp.int32)
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    greedy, masked, idx, scaled_full = _masked_rows(
-        logits, temp, top_p, top_k, candidates
-    )
-    if idx is not None:
-        keys2 = jax.vmap(lambda k: jax.random.fold_in(k, 1))(keys)
-        local = _row_categorical(keys, masked)
-        truncated = jnp.take_along_axis(
-            idx, local[:, None], axis=-1
-        )[:, 0].astype(jnp.int32)
-        full = _row_categorical(keys2, scaled_full)
-        sampled = jnp.where((top_p >= 1.0) & (top_k <= 0), full, truncated)
-    else:
-        sampled = _row_categorical(keys, masked)
-    return jnp.where(temperature == 0.0, greedy, sampled)
+    return _sample_rows(
+        logits, keys, temperature, top_p, top_k, candidates
+    )[0]
 
 
 def sample(
@@ -288,16 +382,29 @@ def sample(
     return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
 
 
-def sample_tail(logits, seeds, positions, temperature, top_p, top_k,
-                greedy: bool, candidates: int = 0):
+def sample_tail_counted(logits, seeds, positions, temperature, top_p, top_k,
+                        greedy: bool, candidates: int = 0, live=None):
     """THE shared sampling tail for prefill and decode (plain and
     speculative paths — one implementation so key derivation cannot
     drift): greedy takes pure argmax (no RNG); sampled rows draw
-    independently, each keyed by fold_in(lane seed key, positions[row])."""
+    independently, each keyed by fold_in(lane seed key, positions[row]).
+    Returns (tokens [B], full): `full` is a scalar bool, true when the
+    exact sampler sorted the whole vocabulary for a sampled row among
+    `live` [B] (None: every row; an idle decode lane's stale top_p asks
+    for nothing) — None from a greedy tail, which holds no sampler."""
     if greedy:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), None
     base = lane_keys(seeds[:, 0], seeds[:, 1])
     keys = fold_positions(base, positions)
-    return sample_dynamic_rows(
-        logits, keys, temperature, top_p, top_k, candidates
+    return _sample_rows(
+        logits, keys, temperature, top_p, top_k, candidates, live
     )
+
+
+def sample_tail(logits, seeds, positions, temperature, top_p, top_k,
+                greedy: bool, candidates: int = 0):
+    """sample_tail_counted's tokens, for a caller that counts nothing."""
+    return sample_tail_counted(
+        logits, seeds, positions, temperature, top_p, top_k, greedy,
+        candidates,
+    )[0]

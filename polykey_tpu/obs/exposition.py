@@ -257,6 +257,18 @@ _HELD_EXPERT_FAMILIES: tuple = (
      "held_experts_hit"),
 )
 
+# The sampled decode program's sampler (in a snapshot once a sampled
+# block has landed): full sorts / steps is how often a live row's top_k
+# or nucleus went past the sorted head (engine/sampling.py HEAD_WIDTH).
+_SAMPLER_FAMILIES: tuple = (
+    ("polykey_sampler_steps_total",
+     "Steps of the sampled decode program with a live lane.",
+     "sampler_steps_total"),
+    ("polykey_sampler_full_sort_steps_total",
+     "Those steps on which the sampler sorted the whole vocabulary.",
+     "sampler_full_sort_steps_total"),
+)
+
 
 def _labeled_lines(kind: str, name: str, help_text: str, key: str,
                    members: list) -> list[str]:
@@ -637,6 +649,7 @@ def engine_collector(engine_or_provider):
         for present, families in (
             ("drafts_proposed", _SPEC_FAMILIES),
             ("held_expert_calls", _HELD_EXPERT_FAMILIES),
+            ("sampler_steps_total", _SAMPLER_FAMILIES),
         ):
             if not any(snap.get(present) for _, _, snap in members):
                 continue

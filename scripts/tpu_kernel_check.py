@@ -47,6 +47,11 @@ Run: python scripts/tpu_kernel_check.py   (one chip; ~2-4 min cold)
        the page write and the blockwise prefill kernel at 30 query heads on
        30 KV heads of 128 — groups of ONE — on 64 lanes: the attending
        layers of olmo-hybrid-7b-pp2)
+     python scripts/tpu_kernel_check.py --sampler-head   (no Pallas
+       kernel: the exact sampler's full-vocabulary sort beside every exact
+       way to take a row's W largest values, at [64, 32768] and
+       [64, 65536] — `head-time` rows: device µs a call out of one
+       profiler capture and the operations it was made of, by name; ~2 min)
      JAX_PLATFORMS=cpu python scripts/tpu_kernel_check.py --interpret
        rehearses the script itself at small tables in Pallas interpret
        mode — it proves nothing about lowering and exits 2 like any run
@@ -860,6 +865,122 @@ def check_delta_state() -> None:
             timed, hk.gated_delta_state_update_jnp, shape, per_row, calls))
 
 
+def head_forms(V: int):
+    """(name, fn(x [B, V], top_p [B], top_k [B]) -> a small array,
+    ((label, top_p, top_k), ...)) for the sampler's full sort and each
+    exact form of a row's W largest values in descending order; the
+    settings are arguments, so that the compiler folds neither branch of
+    the sampler's away, and a form timed under several is ONE program."""
+    from polykey_tpu.engine import sampling
+
+    forms = []
+
+    def add(name, fn, settings=(("", 1.0, 8),)):
+        fn.__name__ = name        # the capture's program: a function each
+        forms.append((name, jax.jit(fn), settings))
+
+    def loop(W, x):
+        """W turns of row maximum + mask that one index."""
+        def turn(i, carry):
+            x, head = carry
+            at = jnp.argmax(x, axis=-1)
+            top = jnp.take_along_axis(x, at[:, None], axis=-1)
+            mask = jnp.arange(x.shape[-1])[None, :] == at[:, None]
+            return (jnp.where(mask, -jnp.inf, x),
+                    jax.lax.dynamic_update_slice(head, top, (0, i)))
+        head = jnp.zeros((x.shape[0], W), x.dtype)
+        return jax.lax.fori_loop(0, W, turn, (x, head))[1]
+
+    def tiles(W, tile, x):
+        """Top-W of each tile of the vocabulary, then of the survivors."""
+        rows = x.shape[0]
+        per_tile = jax.lax.top_k(x.reshape(rows, -1, tile), W)[0]
+        return jax.lax.top_k(per_tile.reshape(rows, -1), W)[0]
+
+    # The parent's rule: one sort of every row, two numbers read.
+    add(f"full_sort_v{V}", lambda *a: sampling._full_sort_thresholds(*a))
+    for W in (8, 32, 64, 128):
+        add(f"top_k_w{W}_v{V}", lambda x, *_, W=W: jax.lax.top_k(x, W)[0])
+    for W in (8, 32):
+        add(f"loop_w{W}_v{V}", lambda x, *_, W=W: loop(W, x))
+    for tile in (1024, 4096):
+        if tile < V:
+            add(f"tiles{tile}_w64_v{V}",
+                lambda x, *_, tile=tile: tiles(64, tile, x))
+    for W in (8, 32, 64, 128):
+        add(f"groups_w{W}_v{V}",
+            lambda x, *_, W=W: sampling._sorted_head(x, min(W, V)))
+    # The thresholds as the sampler takes them (head + logsumexp + the
+    # branch): answered from the head, and sorted for a top_k past it.
+    past = sampling.HEAD_WIDTH + 1
+    add(f"thresholds_v{V}", lambda *a: sampling._trunc_thresholds(*a),
+        (("top_k 8", 1.0, 8), ("top_p 0.5", 0.5, 0),
+         (f"top_k {past}", 1.0, past)))
+    return forms
+
+
+def check_sampler_head() -> None:
+    """`head-time` rows: the device time of each of head_forms' programs
+    (a capture's `XLA Modules` line, so no dispatch is counted) and the
+    three longest operations inside it by name — a `sort` over
+    [rows, vocabulary] under a `top_k` means nothing was gained."""
+    import tempfile
+
+    sys.path.insert(0, os.path.join(ROOT, "perfbench"))
+    import trace_reduce
+
+    interpret = "interpret" in KERNEL
+    rows, calls = (8, 2) if interpret else (64, 20)
+    for V in ((1024,) if interpret else (32768, 65536)):
+        # A served head's logits: a few far above a wide flat rest.
+        x = 3.0 * jax.random.normal(jax.random.PRNGKey(V), (rows, V))
+        x = x.at[:, :8].add(12.0)
+        forms = [(name, [(label, partial(fn, x, jnp.full((rows,), top_p),
+                                         jnp.full((rows,), top_k, jnp.int32)))
+                         for label, top_p, top_k in settings])
+                 for name, fn, settings in head_forms(V)]
+        want = jnp.sort(x, axis=-1)[:, ::-1]
+        wrong = set()
+        for name, runs in forms:
+            for _, fn in runs:
+                got = jax.block_until_ready(fn())           # compile
+                if "_w" in name and not bool(
+                        jnp.all(got == want[:, :got.shape[-1]])):
+                    wrong.add(name)
+        with tempfile.TemporaryDirectory() as trace_dir:
+            with jax.profiler.trace(trace_dir):
+                for _, runs in forms:
+                    for _, fn in runs:
+                        for _ in range(calls):
+                            out = fn()
+                        jax.block_until_ready(out)
+            reduced = trace_reduce.reduce(trace_reduce.extract(
+                trace_reduce.find_xplane(trace_dir)))
+        for name, runs in forms:
+            def row(name=name, runs=runs):
+                if name in wrong:
+                    raise AssertionError("not the row's sorted head")
+                if interpret:
+                    return "rehearsed"
+                # The program's executions in the order they were asked
+                # for: `calls` under each of its settings.
+                program = reduced["modules"][f"jit_{name}"]
+                took = program["durations_s"]
+                if len(took) != calls * len(runs):
+                    raise AssertionError(f"{len(took)} executions captured")
+                each = " / ".join(
+                    f"{label} {sum(took[i * calls:(i + 1) * calls]) / calls * 1e6:.1f}".strip()
+                    for i, (label, _) in enumerate(runs))
+                ops = sorted(
+                    ((k.split("/", 1)[1], v["total_s"] / v["count"], v["count"])
+                     for k, v in reduced["ops"].items()
+                     if k.startswith(f"jit_{name}/")),
+                    key=lambda kv: -kv[1] * kv[2])[:3]
+                return (f"{each} us a call ({calls} calls each): " + "; ".join(
+                    f"{op} {s * 1e6:.1f} us x {int(n)}" for op, s, n in ops))
+            case("head-time", f"{rows}x{V} {name}", row)
+
+
 def check_block_until_ready() -> None:
     """Does jax.block_until_ready block here? A long dependent matmul
     chain is dispatched; the call returning in a sliver of the time the
@@ -914,6 +1035,9 @@ def main() -> int:
         check_decode(quantized=False)
         check_write(quantized=False)
         check_flash()
+        return report(identity, interpret)
+    if "--sampler-head" in sys.argv[1:]:
+        check_sampler_head()
         return report(identity, interpret)
     if "--prefill-read" in sys.argv[1:]:
         check_flash()
