@@ -268,13 +268,21 @@ def report(args, device: dict, first: dict, last: dict, asked: int) -> None:
           f"replicas={args.replicas} slots={int(head['slots_total'])} "
           f"pages={int(head['pages_total'])}")
     for i, eng in enumerate(warm):
-        comp = _ints(eng["warmup_compiles"])
+        # The engine's own record of its construction (engine_stats
+        # `startup`; the server log's `engine started` line has it whole).
+        startup = eng["startup"]
+        comp, whole = startup["warmup_compile"], startup["compile"]
+        slowest = max(startup["executables"], key=lambda row: row["seconds"],
+                      default=None)
         print(f"engine[{i}] devices={[int(d) for d in eng['devices']]} "
-              f"warmup_executables={comp.get('executables', 0)} "
-              f"from_cache={comp.get('cache_hits', 0)} "
-              f"fresh={comp.get('fresh_compiles', 0)} "
+              f"warmup_executables={int(comp.get('executables', 0))} "
+              f"from_cache={int(comp.get('cache_hits', 0))} "
+              f"fresh={int(comp.get('fresh_compiles', 0))} "
               f"mosaic_calls={_kernels(eng['warmup_mosaic_calls'])} "
               f"collectives={_ints(eng['warmup_collectives'])}")
+        print(f"engine[{i}] startup_stage_seconds={startup['stages']} "
+              f"trace_s={whole['trace_s']} lower_s={whole['lower_s']} "
+              f"backend_s={whole['backend_s']} slowest_warm_call={slowest}")
     serving = (int(head["compiles"]["executables"])
                - int(warm[0]["compiles"]["executables"]))
     print(f"executables_built_while_serving={serving}")

@@ -70,7 +70,7 @@ import numpy as np
 from ..analysis import schedwitness as _schedwitness
 from ..faults import get_injector
 from ..models.config import ModelConfig, get_config
-from ..obs.timeline import TimelineRecorder, phase
+from ..obs.timeline import STARTUP_PHASES, TimelineRecorder, phase
 from ..models.hybrid import (
     FROM_PREVIOUS_ROW,
     FROM_SLOT,
@@ -95,6 +95,7 @@ from .config import EngineConfig
 from .device import (
     collective_ops,
     compile_counts,
+    compile_delta,
     device_identity,
     device_memory,
     install_compile_census,
@@ -648,614 +649,662 @@ class InferenceEngine:
         seed: int = 0,
         draft_params: Optional[dict] = None,
     ):
+        t_begin = time.monotonic()
         config.validate()
         self.config = config
-        install_compile_census()
-        # Platform, device_kind, device count, roofline row — fixed for
-        # the process; an unknown TPU kind raises here, at engine start.
-        self._identity = device_identity()
-        # Warm-up's evidence about what it built (stats()): executables
-        # compiled vs loaded from the persistent cache, Mosaic custom
-        # calls per served step, collectives per step on a mesh.
+        self.logger = logger
+        install_compile_census(logger)
+        compiles_before = compile_counts()
+        self.metrics = EngineMetrics()
+        # Warm-up's evidence about what it built (stats()): one row a
+        # warm-up dispatch (the `startup` record's `executables`), Mosaic
+        # custom calls per served step, collectives per step on a mesh.
+        self._warm_rows: list = []
         self._warm_compiles: dict = {}
         self._warm_kernels: dict = {}
         self._warm_collectives: dict = {}
-        # Constructor inputs AS PASSED (before checkpoint load / quantize /
-        # shard mutate the local): the supervisor's default restart factory
-        # replays them so a restarted engine is built from the same
-        # weights/seed, not a fresh random init (None → the checkpoint or
-        # random-init path reruns, which is already faithful). Pinning the
-        # raw params tree costs its host memory for the engine's lifetime,
-        # so it happens only when supervision can actually consume it.
-        self._ctor_args = {
-            "params": params if config.supervise else None,
-            "seed": seed,
-            "draft_params": draft_params if config.supervise else None,
-        }
-        # Whether weights came from the caller (vs checkpoint/seed
-        # derivation) — one input to the durable-KV params fingerprint.
-        self._params_explicit = params is not None
-        self.model_cfg = get_config(config.model)
-        self.tokenizer = load_tokenizer(config.tokenizer)
-        self.metrics = EngineMetrics()
-        self.health = health
-        self.logger = logger
-        # Fault injection (polykey_tpu/faults.py): None unless
-        # POLYKEY_FAULTS is set, so every injection point below is one
-        # attribute load + `is None` — nothing on the hot path when off.
-        self._faults = get_injector()
-        # Identity within a replica pool (engine/replica_pool.py): fault
-        # targeting (":replica=N") and per-replica metric labels key on
-        # it. A standalone engine is replica 0.
-        self.replica_id = config.replica
-        # Tier identity within a disaggregated worker (engine/worker.py):
-        # scopes ":tier=prefill|decode" fault targeting. None everywhere
-        # else, so tier-targeted faults can never fire in-process.
-        self._tier = config.disagg_tier or None
-        self._dtype = jnp.dtype(config.dtype)
+        # Start-up is measured where it happens (ISSUE 62): the whole
+        # constructor is the `init` phase, its stages phases inside it, on
+        # this thread — the engine thread starts after the last of them.
+        # No method of its own for the body: a Python frame between the
+        # process entry and the warm-up dispatches costs 1.7 s (17 %) of
+        # a warm 7B start on the chip — JAX's lowering pays for every
+        # user frame above an operation (PERF.md section 6, PR 62).
+        with self._phase("init"):
+            # Platform, device_kind, device count, roofline row — fixed for
+            # the process; an unknown TPU kind raises here, at engine start.
+            self._identity = device_identity()
+            # Constructor inputs AS PASSED (before checkpoint load / quantize /
+            # shard mutate the local): the supervisor's default restart factory
+            # replays them so a restarted engine is built from the same
+            # weights/seed, not a fresh random init (None → the checkpoint or
+            # random-init path reruns, which is already faithful). Pinning the
+            # raw params tree costs its host memory for the engine's lifetime,
+            # so it happens only when supervision can actually consume it.
+            self._ctor_args = {
+                "params": params if config.supervise else None,
+                "seed": seed,
+                "draft_params": draft_params if config.supervise else None,
+            }
+            # Whether weights came from the caller (vs checkpoint/seed
+            # derivation) — one input to the durable-KV params fingerprint.
+            self._params_explicit = params is not None
+            self.model_cfg = get_config(config.model)
+            self.tokenizer = load_tokenizer(config.tokenizer)
+            self.health = health
+            # Fault injection (polykey_tpu/faults.py): None unless
+            # POLYKEY_FAULTS is set, so every injection point below is one
+            # attribute load + `is None` — nothing on the hot path when off.
+            self._faults = get_injector()
+            # Identity within a replica pool (engine/replica_pool.py): fault
+            # targeting (":replica=N") and per-replica metric labels key on
+            # it. A standalone engine is replica 0.
+            self.replica_id = config.replica
+            # Tier identity within a disaggregated worker (engine/worker.py):
+            # scopes ":tier=prefill|decode" fault targeting. None everywhere
+            # else, so tier-targeted faults can never fire in-process.
+            self._tier = config.disagg_tier or None
+            self._dtype = jnp.dtype(config.dtype)
 
-        # --- Serving mesh: tp shards heads/hidden (Megatron specs,
-        # parallel/sharding.py), dp shards the decode-slot batch, ep shards
-        # MoE expert weights (token dispatch rides all-to-all over ep —
-        # measurement config 4). tp=dp=ep=1 degenerates to a single-device
-        # mesh with identical code paths (specs over size-1 axes are
-        # no-ops, so there is no unsharded special case to keep in sync).
-        n_devices = (
-            config.tp * config.dp * config.ep * config.sp * config.pp
-        ) * config.num_slices
-        all_devices = jax.devices()
-        if n_devices > len(all_devices):
-            raise ValueError(
-                f"tp={config.tp} x dp={config.dp} x ep={config.ep} x "
-                f"sp={config.sp} x pp={config.pp} x "
-                f"slices={config.num_slices} needs {n_devices} "
-                f"devices, have {len(all_devices)}"
-            )
-        # Replica placement: one of N pooled replicas (replicas > 1) owns
-        # device slice i when the host has a slice for every replica. With
-        # fewer devices the replicas share the first slice (several
-        # engines on one chip divide its HBM) — stated in DEPLOY.md and
-        # logged, never silent. An engine that is not in a pool takes the
-        # first slice whatever its `replica` says: a disagg worker's
-        # `replica` is its index within its tier (a fault-targeting and
-        # label identity, engine/worker.py), and that worker is the only
-        # engine in its process.
-        first = 0
-        if config.replicas > 1 and config.replica >= config.replicas:
-            raise ValueError(
-                f"replica={config.replica} is not one of "
-                f"replicas={config.replicas}"
-            )
-        if config.replicas > 1 and (
-            config.replicas * n_devices <= len(all_devices)
-        ):
-            first = config.replica * n_devices
-        elif config.replicas > 1 and logger is not None:
-            logger.warn(
-                "replicas share devices",
-                replica=config.replica, replicas=config.replicas,
-                devices_per_replica=n_devices, devices=len(all_devices),
-            )
-        devices = all_devices[first:first + n_devices]
-        if self.model_cfg.num_kv_heads % config.tp != 0:
-            raise ValueError(
-                f"tp={config.tp} must divide num_kv_heads="
-                f"{self.model_cfg.num_kv_heads} ({self.model_cfg.name})"
-            )
-        # dp is per-slice; the mesh's dp axis extent (what slots batch
-        # over) is num_slices × dp.
-        total_dp = config.dp * config.num_slices
-        if config.max_decode_slots % total_dp != 0:
-            raise ValueError(
-                f"dp={config.dp} x num_slices={config.num_slices} must "
-                f"divide max_decode_slots={config.max_decode_slots}"
-            )
-        if config.ep > 1:
-            if not self.model_cfg.is_moe:
+            # --- Serving mesh: tp shards heads/hidden (Megatron specs,
+            # parallel/sharding.py), dp shards the decode-slot batch, ep shards
+            # MoE expert weights (token dispatch rides all-to-all over ep —
+            # measurement config 4). tp=dp=ep=1 degenerates to a single-device
+            # mesh with identical code paths (specs over size-1 axes are
+            # no-ops, so there is no unsharded special case to keep in sync).
+            n_devices = (
+                config.tp * config.dp * config.ep * config.sp * config.pp
+            ) * config.num_slices
+            all_devices = jax.devices()
+            if n_devices > len(all_devices):
                 raise ValueError(
-                    f"ep={config.ep} requires an MoE model "
-                    f"({self.model_cfg.name} has no experts)"
+                    f"tp={config.tp} x dp={config.dp} x ep={config.ep} x "
+                    f"sp={config.sp} x pp={config.pp} x "
+                    f"slices={config.num_slices} needs {n_devices} "
+                    f"devices, have {len(all_devices)}"
                 )
-            if self.model_cfg.num_experts % config.ep != 0:
+            # Replica placement: one of N pooled replicas (replicas > 1) owns
+            # device slice i when the host has a slice for every replica. With
+            # fewer devices the replicas share the first slice (several
+            # engines on one chip divide its HBM) — stated in DEPLOY.md and
+            # logged, never silent. An engine that is not in a pool takes the
+            # first slice whatever its `replica` says: a disagg worker's
+            # `replica` is its index within its tier (a fault-targeting and
+            # label identity, engine/worker.py), and that worker is the only
+            # engine in its process.
+            first = 0
+            if config.replicas > 1 and config.replica >= config.replicas:
                 raise ValueError(
-                    f"ep={config.ep} must divide num_experts="
-                    f"{self.model_cfg.num_experts}"
+                    f"replica={config.replica} is not one of "
+                    f"replicas={config.replicas}"
                 )
-        if self.model_cfg.num_layers % config.pp != 0:
-            raise ValueError(
-                f"pp={config.pp} must divide num_layers="
-                f"{self.model_cfg.num_layers}"
-            )
-        mesh_config = MeshConfig(
-            dp=config.dp, pp=config.pp, sp=config.sp, ep=config.ep,
-            tp=config.tp,
-        )
-        if config.num_slices > 1:
-            # Hybrid DCN mesh: dp (the only axis whose collectives
-            # amortize DCN latency) spans the slices; everything else
-            # stays inside one ICI domain.
-            from ..parallel.distributed import create_hybrid_mesh
-
-            self.mesh = create_hybrid_mesh(
-                mesh_config, config.num_slices, devices
-            )
-        else:
-            self.mesh = create_mesh(mesh_config, devices=devices)
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        # int8 KV (config.kv_dtype): quantized pools + scale pools. The
-        # pool sharding then becomes a PagedKV-shaped pytree (the scale
-        # pools are 4-D — one broadcast NamedSharding can't serve both).
-        self._kv_quantized = config.kv_dtype == "int8"
-        if self._kv_quantized and self._identity["platform"] == "tpu":
-            from ..ops.paged_attention_kernel import INT8_KV_MOSAIC_ERROR
-
-            raise ValueError(
-                "kv_dtype=int8 (POLYKEY_KV_DTYPE) does not lower on TPU "
-                f"yet: {INT8_KV_MOSAIC_ERROR}"
-            )
-        scale_sh = kv_scale_sharding(self.mesh) if self._kv_quantized else None
-        self._pool_sharding = PagedKV(
-            kv=paged_kv_sharding(self.mesh), ks=scale_sh, vs=scale_sh
-        )
-        self._repl = NamedSharding(self.mesh, PartitionSpec())
-        # Sequence-parallel prefill: the window's token axis shards over
-        # sp, spreading prefill compute across chips; the page pools are
-        # sp-replicated, so GSPMD exchanges the KV writes (sp=1 → a no-op
-        # spec, same code path).
-        self._prefill_tok = NamedSharding(self.mesh, PartitionSpec(None, "sp"))
-        self._dp_vec = NamedSharding(self.mesh, PartitionSpec("dp"))
-        self._dp_mat = NamedSharding(self.mesh, PartitionSpec("dp", None))
-        # Pinned output shardings keep the donated pool's layout stable
-        # across steps (donation requires matching input/output shardings).
-        self._jit_prefill = jax.jit(
-            _prefill_fn,
-            static_argnames=("cfg", "greedy", "candidates", "mesh"),
-            donate_argnames=("paged", "state"),
-            out_shardings=(self._repl, self._pool_sharding, self._repl),
-        )
-        self._dp_steps = NamedSharding(self.mesh, PartitionSpec(None, "dp"))
-        # Double-buffered slot state: the three per-step-advancing vectors
-        # (last_tokens / seq_lens / active) are donated alongside the pool,
-        # so the decode chain updates them in place instead of allocating a
-        # fresh generation per block. With lookahead, the runtime keeps the
-        # in-flight block's buffers alive until it completes while the next
-        # dispatch writes the other generation — two device-resident copies
-        # that never alias (GL002 audits the aliasing). Read-only geometry
-        # (page_tables / caps / sampling params / seeds) is NOT donated:
-        # it has no corresponding output to alias into.
-        self._jit_decode = jax.jit(
-            _decode_fn,
-            static_argnames=(
-                "cfg", "greedy", "steps", "eos_id", "candidates", "mesh",
-            ),
-            donate_argnames=(
-                "paged", "last_tokens", "seq_lens", "active", "state",
-            ),
-            out_shardings=(
-                self._dp_steps, self._dp_vec, self._dp_vec,
-                self._dp_vec, self._pool_sharding, self._repl,
-            ),
-        )
-        # Lane merges: tiny functional updates of the device-resident decode
-        # state, chained between blocks so slot transitions never flush the
-        # lookahead pipeline (out shardings must match the decode inputs so
-        # the chain keeps stable layouts).
-        lane_out = (
-            self._dp_vec, self._dp_vec, self._dp_mat, self._dp_vec,
-            self._dp_vec, self._dp_vec, self._dp_vec, self._dp_vec,
-            self._dp_mat,
-        )
-        # Speculative engines carry two extra donated-state vectors (the
-        # per-lane acceptance EWMA + gamma dial, ISSUE 19) that the merge
-        # resets per admission.
-        merge_out = lane_out + (
-            (self._dp_vec, self._dp_vec)
-            if config.draft_model is not None else ()
-        )
-        self._jit_merge = jax.jit(
-            _merge_lane_fn, static_argnames=("eos_id", "spec"),
-            out_shardings=merge_out,
-        )
-        self._jit_retire = jax.jit(
-            _retire_lane_fn, out_shardings=lane_out[:5],
-        )
-        # KV handoff restore (ISSUE 13): scatter shipped pages into this
-        # pool at the receiving slot's page ids. Donates the pool like
-        # every other pool-touching dispatch; the fixed padded width
-        # (pages_per_seq) keeps it ONE executable per engine.
-        self._jit_kv_restore = jax.jit(
-            _kv_restore_fn,
-            donate_argnames=("paged",),
-            out_shardings=self._pool_sharding,
-        )
-        # Host-tier eviction gather (ISSUE 15): the read half of the
-        # gather/scatter pair (restore above is the write half). Same
-        # fixed width (pages_per_seq), one executable; outputs land
-        # replicated so the host copy is a straight np.asarray.
-        self._jit_kv_gather = jax.jit(
-            _kv_gather_fn, out_shardings=self._repl,
-        )
-        # Per-request RNG roots for seedless requests (GenRequest.seed
-        # None): drawn once per admission from the engine seed.
-        self._seed_rng = np.random.default_rng(seed + 3)
-
-        self.params = self._place_params(
-            params, self.model_cfg, config.checkpoint_path, seed
-        )
-
-        B, P = config.max_decode_slots, config.pages_per_seq
-        pool_fp_dtype = (
-            jnp.dtype(config.kv_dtype)
-            if config.kv_dtype in ("bfloat16", "float32") else self._dtype
-        )
-        kv_q = jnp.int8 if self._kv_quantized else None
-        # Pools are born sharded: zeros built on the default device and
-        # then moved would pass the whole pool through device 0, next to
-        # whatever another replica already holds there.
-        def new_pool(model_cfg: ModelConfig) -> PagedKV:
-            shape = jax.eval_shape(lambda: init_paged_kv(
-                model_cfg, config.num_pages, config.page_size,
-                pool_fp_dtype, kv_dtype=kv_q,
-            ))
-            return jax.tree.map(
-                lambda x, sh: jnp.zeros(x.shape, x.dtype, device=sh),
-                shape, self._pool_sharding,
-            )
-
-        self.paged = new_pool(self.model_cfg)
-        # Host-known facts of the pool for `stats` (the arrays are donated
-        # dispatch by dispatch): its bytes, and one token's over all layers.
-        self._kv_pool_bytes = sum(
-            x.nbytes for x in jax.tree.leaves(self.paged))
-        self._kv_token_bytes = self._kv_pool_bytes // (
-            config.num_pages * config.page_size)
-        # Expert layers of a layer pattern: a decode block of such a model
-        # brings home the held experts its live lanes chose (_decode_fn).
-        self._expert_layers = self.model_cfg.layer_pattern.count("E")
-        # What a slot holds beside its pages (kv_cache.SlotState): born
-        # on the device like the pools; an empty pytree for a model with
-        # no recurrent state.
-        self.state = SlotState()
-        if self.model_cfg.stateful:
-            self.state = jax.tree.map(
-                lambda x: jnp.zeros(x.shape, x.dtype, device=self._repl),
-                jax.eval_shape(
-                    lambda: init_slot_state(self.model_cfg, B, self._dtype)
-                ),
-            )
-        # Host-known like the pool's (the leaves are donated dispatch by
-        # dispatch): the bytes the state takes on the device, its layout's
-        # padding included.
-        self._state_pool_bytes = self.state.resident_nbytes
-        self.allocator = BlockAllocator(config.num_pages)
-        # --- Host-memory KV tier (ISSUE 15): a second page pool in host
-        # RAM for COLD pages (prefix-cache entries of finished sticky
-        # sessions, long-context middles). 0 bytes → no pool, no store,
-        # every existing path byte-identical.
-        self._host_kv = None
-        self._kv_state = None
-        self._kv_reloaded_pages = 0
-        if config.host_kv_bytes > 0:
-            from .kv_cache import HostKVPool, host_kv_page_bytes
-
-            page_b = host_kv_page_bytes(
-                self.model_cfg, config.page_size, pool_fp_dtype, kv_q
-            )
-            capacity = config.host_kv_bytes // max(1, page_b)
-            if capacity < 1:
+            if config.replicas > 1 and (
+                config.replicas * n_devices <= len(all_devices)
+            ):
+                first = config.replica * n_devices
+            elif config.replicas > 1 and logger is not None:
+                logger.warn(
+                    "replicas share devices",
+                    replica=config.replica, replicas=config.replicas,
+                    devices_per_replica=n_devices, devices=len(all_devices),
+                )
+            devices = all_devices[first:first + n_devices]
+            if self.model_cfg.num_kv_heads % config.tp != 0:
                 raise ValueError(
-                    f"POLYKEY_HOST_KV_BYTES={config.host_kv_bytes} is "
-                    f"smaller than one KV page ({page_b} bytes for "
-                    f"{self.model_cfg.name} at page_size "
-                    f"{config.page_size})"
+                    f"tp={config.tp} must divide num_kv_heads="
+                    f"{self.model_cfg.num_kv_heads} ({self.model_cfg.name})"
                 )
-            self._host_kv = HostKVPool(
-                self.model_cfg, capacity, config.page_size,
-                pool_fp_dtype, self._kv_quantized,
-            )
-        # Resident working set: _finish spills cold pages whenever a
-        # retirement leaves fewer free device pages than this floor.
-        # Live attribute (not a frozen-config read): the autopilot's
-        # set_resident_floor actuation must land mid-run.
-        self._resident_low = (
-            config.host_kv_resident_pages or config.num_pages // 8
-        )
-        # Per-iteration restore budget. Mirrors the frozen config field
-        # into a live attribute so _issue_restores reads THIS every
-        # iteration — a mid-run set_kv_restore_slots actuation takes
-        # effect on the next loop pass instead of being silently
-        # ignored (the knob-application audit, ISSUE 18).
-        # Clamped like set_kv_restore_slots: the restore frontier's
-        # progress floor (schedlint SL001) assumes a budget of at least
-        # one scatter per iteration.
-        self._restore_slots = max(1, config.host_kv_restore_slots)
-        # Restore-frontier round-robin cursor (the shared starved-first
-        # discipline for page faults).
-        self._restore_rr = _RRCursor()
-        # Durable-store gc cadence: gc() lists and parses the whole
-        # state dir — amortize it over batches instead of paying a
-        # directory scan per spill on the engine thread.
-        self._kv_gc_countdown = 0
-        self._prefix = None
-        if config.prefix_cache:
-            from .prefix_cache import PrefixCache
-
-            self._prefix = PrefixCache(
-                self.allocator, config.page_size,
-                config.prefix_cache_pages or config.num_pages // 2,
-                host_pool=self._host_kv,
-            )
-        if self._host_kv is not None and config.kv_state_dir:
-            # Restart-durable prefix cache: reload spilled pages
-            # persisted by a previous incarnation (same weights — the
-            # params_key gate) into the host tier, so the first sticky
-            # turn after a supervisor restart faults its prefix back in
-            # instead of recomputing it cold.
-            from .prefix_cache import PrefixStateStore
-
-            self._kv_state = PrefixStateStore(
-                config.kv_state_dir, self.model_cfg.name, config.page_size,
-                params_key=self._params_fingerprint(seed),
-                quantized=self._kv_quantized, logger=logger,
-            )
-            self._kv_reloaded_pages = self._kv_state.load_into(
-                self._prefix, self._host_kv,
-                expect_shape=(
-                    self.model_cfg.num_layers, 0, config.page_size,
-                    self.model_cfg.num_kv_heads, self.model_cfg.head_dim,
-                ),
-            )
-
-        self._chunk = config.prefill_chunk or max(config.prefill_buckets)
-        # What prefill_cover chooses from: the [N, bucket] shapes of an
-        # admission, and for a long prompt's tail the buckets narrower
-        # than the chunk beside the chunk itself.
-        self._group_sizes = prefill_group_sizes(config.max_decode_slots)
-        self._chunk_widths = tuple(
-            b for b in config.prefill_buckets if b < self._chunk
-        ) + (self._chunk,)
-        # Interleaved-prefill budget (config.prefill_budget; 0 → auto):
-        # prefill tokens allowed per loop iteration while decode lanes
-        # are live. Floored at one chunk so a budget below the dispatch
-        # granularity still makes progress (the knob bounds stall length,
-        # it must never deadlock a long prompt).
-        self._prefill_budget = max(
-            config.prefill_budget or 2 * self._chunk, self._chunk
-        )
-        # Round-robin cursor over slots with pending chunked prefill —
-        # budgeted chunk advancement must not starve the highest-index
-        # pending slot when the budget covers fewer chunks than slots.
-        self._chunk_rr = _RRCursor()
-        self._block_steps = config.decode_block_steps
-        # Load-adaptive block size (config.adaptive_block): the solo block
-        # is a distinct static `steps` value, so it gets its own compile —
-        # warmup covers it alongside the full block.
-        self._solo_steps = (
-            max(1, config.decode_block_steps // 8)
-            if config.adaptive_block else config.decode_block_steps
-        )
-        self._last_dispatch_steps = 0    # observability (bench step_costs)
-
-        # --- Speculative decoding: draft model + its own page pool, same
-        # page tables (position → (page, offset) is model-independent).
-        self._spec = config.draft_model is not None
-        # Adaptive gamma (VERDICT r2 #8, per-lane since ISSUE 19): each
-        # LANE carries its own dial on a two-level ladder {max(1, γ/2), γ}
-        # driven by a per-lane acceptance EWMA with hysteresis, updated
-        # INSIDE the jitted round (spec_decode._accept_merge) — the dial
-        # rides the donated slot state, so it costs no crossings. The
-        # host-side `self._gamma` is now only the DISPATCH WIDTH: the
-        # ladder rung covering the widest active lane dial (recomputed
-        # from the packed round stats in _process_spec), clamped by the
-        # autopilot's `_gamma_cap` (set_spec_gamma). Page/position SLACK
-        # always reserves for _gamma_max, so a mid-stream dial increase
-        # can never overflow a slot's pages. Each ladder rung is its own
-        # compile; warmup covers both.
-        self._gamma_max = config.spec_gamma if self._spec else 0
-        self._gamma = self._gamma_max
-        self._gamma_low = (
-            max(1, config.spec_gamma // 2)
-            if (self._spec and config.adaptive_gamma) else self._gamma_max
-        )
-        self._gamma_cap = self._gamma_max   # autopilot bound (rung-snapped)
-        # Batch-aggregate acceptance EWMA, kept for observability/back-
-        # compat (stats()["spec_accept_ewma"]); the per-lane EWMAs below
-        # are what drive the dial.
-        self._accept_ewma = 1.0          # optimistic start: full gamma
-        if self._spec:
-            from .spec_decode import spec_decode_fn, spec_prefill_fn
-
-            self.draft_cfg = get_config(config.draft_model)
-            if self.draft_cfg.vocab_size != self.model_cfg.vocab_size:
+            # dp is per-slice; the mesh's dp axis extent (what slots batch
+            # over) is num_slices × dp.
+            total_dp = config.dp * config.num_slices
+            if config.max_decode_slots % total_dp != 0:
                 raise ValueError(
-                    f"draft vocab {self.draft_cfg.vocab_size} != target "
-                    f"vocab {self.model_cfg.vocab_size}"
+                    f"dp={config.dp} x num_slices={config.num_slices} must "
+                    f"divide max_decode_slots={config.max_decode_slots}"
                 )
-            if self.draft_cfg.num_kv_heads % config.tp != 0:
+            if config.ep > 1:
+                if not self.model_cfg.is_moe:
+                    raise ValueError(
+                        f"ep={config.ep} requires an MoE model "
+                        f"({self.model_cfg.name} has no experts)"
+                    )
+                if self.model_cfg.num_experts % config.ep != 0:
+                    raise ValueError(
+                        f"ep={config.ep} must divide num_experts="
+                        f"{self.model_cfg.num_experts}"
+                    )
+            if self.model_cfg.num_layers % config.pp != 0:
                 raise ValueError(
-                    f"tp={config.tp} must divide draft num_kv_heads="
-                    f"{self.draft_cfg.num_kv_heads}"
+                    f"pp={config.pp} must divide num_layers="
+                    f"{self.model_cfg.num_layers}"
                 )
-            if self.draft_cfg.num_layers % config.pp != 0:
+            mesh_config = MeshConfig(
+                dp=config.dp, pp=config.pp, sp=config.sp, ep=config.ep,
+                tp=config.tp,
+            )
+            if config.num_slices > 1:
+                # Hybrid DCN mesh: dp (the only axis whose collectives
+                # amortize DCN latency) spans the slices; everything else
+                # stays inside one ICI domain.
+                from ..parallel.distributed import create_hybrid_mesh
+
+                self.mesh = create_hybrid_mesh(
+                    mesh_config, config.num_slices, devices
+                )
+            else:
+                self.mesh = create_mesh(mesh_config, devices=devices)
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            # int8 KV (config.kv_dtype): quantized pools + scale pools. The
+            # pool sharding then becomes a PagedKV-shaped pytree (the scale
+            # pools are 4-D — one broadcast NamedSharding can't serve both).
+            self._kv_quantized = config.kv_dtype == "int8"
+            if self._kv_quantized and self._identity["platform"] == "tpu":
+                from ..ops.paged_attention_kernel import INT8_KV_MOSAIC_ERROR
+
                 raise ValueError(
-                    f"pp={config.pp} must divide draft num_layers="
-                    f"{self.draft_cfg.num_layers} (the draft's params and "
-                    f"page pool shard the same pp axis)"
+                    "kv_dtype=int8 (POLYKEY_KV_DTYPE) does not lower on TPU "
+                    f"yet: {INT8_KV_MOSAIC_ERROR}"
                 )
-            # Caller-provided draft weights win (benchmarks pass the
-            # target tree itself to measure the acceptance-1.0 ceiling).
-            # The engine-wide quantize knob covers the draft too — the
-            # draft exists to save bandwidth, and an unquantized draft
-            # could push the HBM budget the flag exists to protect.
-            self.draft_params = self._place_params(
-                draft_params, self.draft_cfg,
-                config.draft_checkpoint_path, seed + 2,
+            scale_sh = kv_scale_sharding(self.mesh) if self._kv_quantized else None
+            self._pool_sharding = PagedKV(
+                kv=paged_kv_sharding(self.mesh), ks=scale_sh, vs=scale_sh
             )
-            self.d_paged = new_pool(self.draft_cfg)
-            self._jit_spec_prefill = jax.jit(
-                spec_prefill_fn,
-                static_argnames=("t_cfg", "d_cfg", "greedy", "candidates",
-                                 "mesh"),
-                donate_argnames=("t_paged", "d_paged"),
-                out_shardings=(
-                    self._repl, self._pool_sharding, self._pool_sharding,
-                ),
+            self._repl = NamedSharding(self.mesh, PartitionSpec())
+            # Sequence-parallel prefill: the window's token axis shards over
+            # sp, spreading prefill compute across chips; the page pools are
+            # sp-replicated, so GSPMD exchanges the KV writes (sp=1 → a no-op
+            # spec, same code path).
+            self._prefill_tok = NamedSharding(self.mesh, PartitionSpec(None, "sp"))
+            self._dp_vec = NamedSharding(self.mesh, PartitionSpec("dp"))
+            self._dp_mat = NamedSharding(self.mesh, PartitionSpec("dp", None))
+            # Pinned output shardings keep the donated pool's layout stable
+            # across steps (donation requires matching input/output shardings).
+            self._jit_prefill = jax.jit(
+                _prefill_fn,
+                static_argnames=("cfg", "greedy", "candidates", "mesh"),
+                donate_argnames=("paged", "state"),
+                out_shardings=(self._repl, self._pool_sharding, self._repl),
             )
-            self._jit_spec_decode = jax.jit(
-                spec_decode_fn,
+            self._dp_steps = NamedSharding(self.mesh, PartitionSpec(None, "dp"))
+            # Double-buffered slot state: the three per-step-advancing vectors
+            # (last_tokens / seq_lens / active) are donated alongside the pool,
+            # so the decode chain updates them in place instead of allocating a
+            # fresh generation per block. With lookahead, the runtime keeps the
+            # in-flight block's buffers alive until it completes while the next
+            # dispatch writes the other generation — two device-resident copies
+            # that never alias (GL002 audits the aliasing). Read-only geometry
+            # (page_tables / caps / sampling params / seeds) is NOT donated:
+            # it has no corresponding output to alias into.
+            self._jit_decode = jax.jit(
+                _decode_fn,
                 static_argnames=(
-                    "t_cfg", "d_cfg", "gamma", "eos_id", "gamma_low",
-                    "gamma_max", "candidates", "mesh",
+                    "cfg", "greedy", "steps", "eos_id", "candidates", "mesh",
                 ),
-                # Same double-buffered slot-state donation as the plain
-                # decode block — spec rounds ride the identical pipeline.
-                # The per-lane gamma dial (accept_ewma / gamma_lane,
-                # ISSUE 19) donates alongside: it advances on device
-                # every round like the rest of the slot state.
                 donate_argnames=(
-                    "t_paged", "d_paged",
-                    "last_tokens", "seq_lens", "active",
-                    "accept_ewma", "gamma_lane",
+                    "paged", "last_tokens", "seq_lens", "active", "state",
                 ),
                 out_shardings=(
-                    self._dp_mat, self._dp_vec, self._dp_vec, self._dp_vec,
-                    self._dp_vec, self._dp_vec,
-                    self._pool_sharding, self._pool_sharding,
+                    self._dp_steps, self._dp_vec, self._dp_vec,
+                    self._dp_vec, self._pool_sharding, self._repl,
                 ),
             )
-
-        # Host mirrors of per-slot device state (engine thread only). They
-        # are the source of truth at slot transitions (admit/finish mark
-        # `_dev_dirty` → re-upload); between transitions the decode state —
-        # RNG key included — stays device-resident (`_dev`) and advances
-        # on-device, so steady decode uploads nothing per block.
-        self._page_tables = np.zeros((B, P), dtype=np.int32)
-        self._seq_lens = np.zeros((B,), dtype=np.int32)
-        self._last_tokens = np.zeros((B,), dtype=np.int32)
-        self._active = np.zeros((B,), dtype=bool)
-        self._caps = np.zeros((B,), dtype=np.int32)
-        self._temperature = np.zeros((B,), dtype=np.float32)
-        self._top_p = np.ones((B,), dtype=np.float32)
-        self._top_k = np.zeros((B,), dtype=np.int32)
-        self._seeds = np.zeros((B, 2), dtype=np.int32)
-        # Per-lane gamma dial mirrors (spec engines, ISSUE 19): refreshed
-        # from each processed round's packed stat columns — the DEVICE
-        # copy is authoritative between slot transitions, exactly like
-        # the other mirrors.
-        self._lane_ewma = np.ones((B,), dtype=np.float32)
-        self._lane_gamma = np.full(
-            (B,), max(self._gamma_max, 1), dtype=np.int32
-        )
-        self._slots: list[Optional[_Slot]] = [None] * B
-        self._dev: dict = {}
-        self._dev_dirty = True
-
-        self._submit: queue.Queue[GenRequest] = queue.Queue()
-        # Lookahead pipeline: dispatched-but-unprocessed decode blocks,
-        # oldest first (_InflightBlock records). While dispatching, up to
-        # _depth_target - 1 blocks stay queued ACROSS iterations — depth
-        # counts device-resident slot-state generations including the
-        # block just dispatched, so depth 2 = double-buffered overlap
-        # (dispatch N+1 before reading N) and depth 1 = synchronous
-        # dispatch-then-read, exactly. POLYKEY_DISPATCH_LOOKAHEAD
-        # overrides the config depth regardless of how the config was
-        # built (serving env, bench, tests) — the operator knob for
-        # host-bound decode (DEPLOY.md runbook).
-        from collections import deque
-
-        self._inflight_q: deque = deque()
-        # Prefill dispatches whose first tokens wait to be read, oldest
-        # first (_FirstTokens; engine thread only).
-        self._first_tokens: list = []
-        # (end time, reason) of the `_admit` visits that left requests
-        # waiting, oldest first, and when the visit now running began:
-        # what a request's queue time is cut by when it is admitted
-        # (_stamp_admitted). Engine thread only. Requests leave the
-        # queue oldest first, so an admission drops the visits that
-        # ended before its request arrived; the bound only matters to a
-        # queue that nothing leaves.
-        self._admit_visits: deque = deque(maxlen=1024)
-        self._admit_began = 0.0
-        try:
-            # polylint: disable=ML004(documented operator override: env beats any programmatic config, see comment above)
-            self._depth = max(1, int(os.environ.get(
-                "POLYKEY_DISPATCH_LOOKAHEAD", config.lookahead_blocks
-            )))
-        except ValueError:
-            self._depth = config.lookahead_blocks
-        # Flight-deck timeline (ISSUE 10): the promoted pipeline ring —
-        # typed, bounded, always-on events for both frontiers plus slot
-        # lifecycle, exported as Perfetto JSON (/debug/timeline). The
-        # dispatch-order regression test asserts dispatch-N+1-before-
-        # process-N on it. timeline_capacity=0 disables it entirely:
-        # no ring allocated, every emission site one `is None` branch —
-        # obs-off engines pay nothing (the memory-discipline contract
-        # tests/test_timeline.py pins).
-        self.timeline: Optional[TimelineRecorder] = (
-            TimelineRecorder(config.timeline_capacity)
-            if config.timeline_capacity > 0 else None
-        )
-        # SLO signal plane (ISSUE 11): windowed rates/delta-quantiles
-        # over a ring of metrics snapshots, plus burn-rate evaluation of
-        # the declarative POLYKEY_SLO objectives. Attached to the
-        # METRICS object so the supervisor's adoption path carries the
-        # windows and budget state across restarts; the supervisor
-        # rebinds `timeline` to the fresh ring. signals_interval_s=0
-        # allocates nothing (`metrics.signals is None`) and the loop
-        # emission site below is one `is None` branch.
-        if config.signals_interval_s > 0 and self.metrics.signals is None:
-            from ..obs.signals import (
-                ENV_POLICY,
-                ENV_WINDOWS,
-                SignalPlane,
-                SloPolicy,
-                windows_from_spec,
+            # Lane merges: tiny functional updates of the device-resident decode
+            # state, chained between blocks so slot transitions never flush the
+            # lookahead pipeline (out shardings must match the decode inputs so
+            # the chain keeps stable layouts).
+            lane_out = (
+                self._dp_vec, self._dp_vec, self._dp_mat, self._dp_vec,
+                self._dp_vec, self._dp_vec, self._dp_vec, self._dp_vec,
+                self._dp_mat,
             )
-
-            # Config-first, env-fallback: an EngineConfig.from_env
-            # carries the boot-time specs (restart-stable); a
-            # programmatic config controls them without touching
-            # os.environ; the empty defaults read the env here.
-            self.metrics.signals = SignalPlane(
-                self.metrics,
-                windows=windows_from_spec(
-                    config.signals_windows
-                    or os.environ.get(ENV_WINDOWS, "")
-                ),
-                interval_s=config.signals_interval_s,
-                policy=SloPolicy.from_spec(
-                    config.slo_policy or os.environ.get(ENV_POLICY, "")
-                ),
-                timeline=self.timeline,
+            # Speculative engines carry two extra donated-state vectors (the
+            # per-lane acceptance EWMA + gamma dial, ISSUE 19) that the merge
+            # resets per admission.
+            merge_out = lane_out + (
+                (self._dp_vec, self._dp_vec)
+                if config.draft_model is not None else ()
             )
-        self._dispatch_seq = 0
-        # In-flight target for the CURRENT block size: when the adaptive
-        # dispatcher shrinks K, the LOOKAHEAD portion deepens by the
-        # same factor (1 + (depth-1) x (K/steps) — constant queued-ahead
-        # steps), because roundtrip hiding needs lookahead × block_time
-        # ≥ the host's sync roundtrip — a K/8 block at the configured
-        # depth would leave the host stalled on un-landed copies. Only the
-        # lookahead portion scales, so depth 1 stays exactly
-        # synchronous at every block size (the escape-hatch contract).
-        # The 64-block cap binds only for large lookahead_blocks (the
-        # scale factor itself tops out at block_steps // solo_steps).
-        self._depth_target = self._depth
-        if config.compile_warmup:
-            self._compile_warmup()
-            # What building or loading the executables left on the host
-            # heap goes back to the OS before the first request, not 2 s
-            # into serving (device.release_compile_heap).
-            release_compile_heap()
-        self._wake = threading.Event()
-        self._stop = threading.Event()
-        self.dead: Optional[str] = None
-        self.last_progress = time.monotonic()
+            self._jit_merge = jax.jit(
+                _merge_lane_fn, static_argnames=("eos_id", "spec"),
+                out_shardings=merge_out,
+            )
+            self._jit_retire = jax.jit(
+                _retire_lane_fn, out_shardings=lane_out[:5],
+            )
+            # KV handoff restore (ISSUE 13): scatter shipped pages into this
+            # pool at the receiving slot's page ids. Donates the pool like
+            # every other pool-touching dispatch; the fixed padded width
+            # (pages_per_seq) keeps it ONE executable per engine.
+            self._jit_kv_restore = jax.jit(
+                _kv_restore_fn,
+                donate_argnames=("paged",),
+                out_shardings=self._pool_sharding,
+            )
+            # Host-tier eviction gather (ISSUE 15): the read half of the
+            # gather/scatter pair (restore above is the write half). Same
+            # fixed width (pages_per_seq), one executable; outputs land
+            # replicated so the host copy is a straight np.asarray.
+            self._jit_kv_gather = jax.jit(
+                _kv_gather_fn, out_shardings=self._repl,
+            )
+            # Per-request RNG roots for seedless requests (GenRequest.seed
+            # None): drawn once per admission from the engine seed.
+            self._seed_rng = np.random.default_rng(seed + 3)
 
+            with self._phase("place_params"):
+                self.params = self._place_params(
+                    params, self.model_cfg, config.checkpoint_path, seed
+                )
+
+            with self._phase("pools"):
+                B, P = config.max_decode_slots, config.pages_per_seq
+                pool_fp_dtype = (
+                    jnp.dtype(config.kv_dtype)
+                    if config.kv_dtype in ("bfloat16", "float32") else self._dtype
+                )
+                kv_q = jnp.int8 if self._kv_quantized else None
+                # Pools are born sharded: zeros built on the default device and
+                # then moved would pass the whole pool through device 0, next to
+                # whatever another replica already holds there.
+                def new_pool(model_cfg: ModelConfig) -> PagedKV:
+                    shape = jax.eval_shape(lambda: init_paged_kv(
+                        model_cfg, config.num_pages, config.page_size,
+                        pool_fp_dtype, kv_dtype=kv_q,
+                    ))
+                    return jax.tree.map(
+                        lambda x, sh: jnp.zeros(x.shape, x.dtype, device=sh),
+                        shape, self._pool_sharding,
+                    )
+
+                self.paged = new_pool(self.model_cfg)
+                # Host-known facts of the pool for `stats` (the arrays are donated
+                # dispatch by dispatch): its bytes, and one token's over all layers.
+                self._kv_pool_bytes = sum(
+                    x.nbytes for x in jax.tree.leaves(self.paged))
+                self._kv_token_bytes = self._kv_pool_bytes // (
+                    config.num_pages * config.page_size)
+                # Expert layers of a layer pattern: a decode block of such a model
+                # brings home the held experts its live lanes chose (_decode_fn).
+                self._expert_layers = self.model_cfg.layer_pattern.count("E")
+                # What a slot holds beside its pages (kv_cache.SlotState): born
+                # on the device like the pools; an empty pytree for a model with
+                # no recurrent state.
+                self.state = SlotState()
+                if self.model_cfg.stateful:
+                    self.state = jax.tree.map(
+                        lambda x: jnp.zeros(x.shape, x.dtype, device=self._repl),
+                        jax.eval_shape(
+                            lambda: init_slot_state(self.model_cfg, B, self._dtype)
+                        ),
+                    )
+                # Host-known like the pool's (the leaves are donated dispatch by
+                # dispatch): the bytes the state takes on the device, its layout's
+                # padding included.
+                self._state_pool_bytes = self.state.resident_nbytes
+                self.allocator = BlockAllocator(config.num_pages)
+                # --- Host-memory KV tier (ISSUE 15): a second page pool in host
+                # RAM for COLD pages (prefix-cache entries of finished sticky
+                # sessions, long-context middles). 0 bytes → no pool, no store,
+                # every existing path byte-identical.
+                self._host_kv = None
+                self._kv_state = None
+                self._kv_reloaded_pages = 0
+                if config.host_kv_bytes > 0:
+                    from .kv_cache import HostKVPool, host_kv_page_bytes
+
+                    page_b = host_kv_page_bytes(
+                        self.model_cfg, config.page_size, pool_fp_dtype, kv_q
+                    )
+                    capacity = config.host_kv_bytes // max(1, page_b)
+                    if capacity < 1:
+                        raise ValueError(
+                            f"POLYKEY_HOST_KV_BYTES={config.host_kv_bytes} is "
+                            f"smaller than one KV page ({page_b} bytes for "
+                            f"{self.model_cfg.name} at page_size "
+                            f"{config.page_size})"
+                        )
+                    self._host_kv = HostKVPool(
+                        self.model_cfg, capacity, config.page_size,
+                        pool_fp_dtype, self._kv_quantized,
+                    )
+                # Resident working set: _finish spills cold pages whenever a
+                # retirement leaves fewer free device pages than this floor.
+                # Live attribute (not a frozen-config read): the autopilot's
+                # set_resident_floor actuation must land mid-run.
+                self._resident_low = (
+                    config.host_kv_resident_pages or config.num_pages // 8
+                )
+                # Per-iteration restore budget. Mirrors the frozen config field
+                # into a live attribute so _issue_restores reads THIS every
+                # iteration — a mid-run set_kv_restore_slots actuation takes
+                # effect on the next loop pass instead of being silently
+                # ignored (the knob-application audit, ISSUE 18).
+                # Clamped like set_kv_restore_slots: the restore frontier's
+                # progress floor (schedlint SL001) assumes a budget of at least
+                # one scatter per iteration.
+                self._restore_slots = max(1, config.host_kv_restore_slots)
+                # Restore-frontier round-robin cursor (the shared starved-first
+                # discipline for page faults).
+                self._restore_rr = _RRCursor()
+                # Durable-store gc cadence: gc() lists and parses the whole
+                # state dir — amortize it over batches instead of paying a
+                # directory scan per spill on the engine thread.
+                self._kv_gc_countdown = 0
+                self._prefix = None
+                if config.prefix_cache:
+                    from .prefix_cache import PrefixCache
+
+                    self._prefix = PrefixCache(
+                        self.allocator, config.page_size,
+                        config.prefix_cache_pages or config.num_pages // 2,
+                        host_pool=self._host_kv,
+                    )
+                if self._host_kv is not None and config.kv_state_dir:
+                    # Restart-durable prefix cache: reload spilled pages
+                    # persisted by a previous incarnation (same weights — the
+                    # params_key gate) into the host tier, so the first sticky
+                    # turn after a supervisor restart faults its prefix back in
+                    # instead of recomputing it cold.
+                    from .prefix_cache import PrefixStateStore
+
+                    self._kv_state = PrefixStateStore(
+                        config.kv_state_dir, self.model_cfg.name, config.page_size,
+                        params_key=self._params_fingerprint(seed),
+                        quantized=self._kv_quantized, logger=logger,
+                    )
+                    self._kv_reloaded_pages = self._kv_state.load_into(
+                        self._prefix, self._host_kv,
+                        expect_shape=(
+                            self.model_cfg.num_layers, 0, config.page_size,
+                            self.model_cfg.num_kv_heads, self.model_cfg.head_dim,
+                        ),
+                    )
+
+            self._chunk = config.prefill_chunk or max(config.prefill_buckets)
+            # What prefill_cover chooses from: the [N, bucket] shapes of an
+            # admission, and for a long prompt's tail the buckets narrower
+            # than the chunk beside the chunk itself.
+            self._group_sizes = prefill_group_sizes(config.max_decode_slots)
+            self._chunk_widths = tuple(
+                b for b in config.prefill_buckets if b < self._chunk
+            ) + (self._chunk,)
+            # Interleaved-prefill budget (config.prefill_budget; 0 → auto):
+            # prefill tokens allowed per loop iteration while decode lanes
+            # are live. Floored at one chunk so a budget below the dispatch
+            # granularity still makes progress (the knob bounds stall length,
+            # it must never deadlock a long prompt).
+            self._prefill_budget = max(
+                config.prefill_budget or 2 * self._chunk, self._chunk
+            )
+            # Round-robin cursor over slots with pending chunked prefill —
+            # budgeted chunk advancement must not starve the highest-index
+            # pending slot when the budget covers fewer chunks than slots.
+            self._chunk_rr = _RRCursor()
+            self._block_steps = config.decode_block_steps
+            # Load-adaptive block size (config.adaptive_block): the solo block
+            # is a distinct static `steps` value, so it gets its own compile —
+            # warmup covers it alongside the full block.
+            self._solo_steps = (
+                max(1, config.decode_block_steps // 8)
+                if config.adaptive_block else config.decode_block_steps
+            )
+            self._last_dispatch_steps = 0    # observability (bench step_costs)
+
+            # --- Speculative decoding: draft model + its own page pool, same
+            # page tables (position → (page, offset) is model-independent).
+            self._spec = config.draft_model is not None
+            # Adaptive gamma (VERDICT r2 #8, per-lane since ISSUE 19): each
+            # LANE carries its own dial on a two-level ladder {max(1, γ/2), γ}
+            # driven by a per-lane acceptance EWMA with hysteresis, updated
+            # INSIDE the jitted round (spec_decode._accept_merge) — the dial
+            # rides the donated slot state, so it costs no crossings. The
+            # host-side `self._gamma` is now only the DISPATCH WIDTH: the
+            # ladder rung covering the widest active lane dial (recomputed
+            # from the packed round stats in _process_spec), clamped by the
+            # autopilot's `_gamma_cap` (set_spec_gamma). Page/position SLACK
+            # always reserves for _gamma_max, so a mid-stream dial increase
+            # can never overflow a slot's pages. Each ladder rung is its own
+            # compile; warmup covers both.
+            self._gamma_max = config.spec_gamma if self._spec else 0
+            self._gamma = self._gamma_max
+            self._gamma_low = (
+                max(1, config.spec_gamma // 2)
+                if (self._spec and config.adaptive_gamma) else self._gamma_max
+            )
+            self._gamma_cap = self._gamma_max   # autopilot bound (rung-snapped)
+            # Batch-aggregate acceptance EWMA, kept for observability/back-
+            # compat (stats()["spec_accept_ewma"]); the per-lane EWMAs below
+            # are what drive the dial.
+            self._accept_ewma = 1.0          # optimistic start: full gamma
+            if self._spec:
+                from .spec_decode import spec_decode_fn, spec_prefill_fn
+
+                self.draft_cfg = get_config(config.draft_model)
+                if self.draft_cfg.vocab_size != self.model_cfg.vocab_size:
+                    raise ValueError(
+                        f"draft vocab {self.draft_cfg.vocab_size} != target "
+                        f"vocab {self.model_cfg.vocab_size}"
+                    )
+                if self.draft_cfg.num_kv_heads % config.tp != 0:
+                    raise ValueError(
+                        f"tp={config.tp} must divide draft num_kv_heads="
+                        f"{self.draft_cfg.num_kv_heads}"
+                    )
+                if self.draft_cfg.num_layers % config.pp != 0:
+                    raise ValueError(
+                        f"pp={config.pp} must divide draft num_layers="
+                        f"{self.draft_cfg.num_layers} (the draft's params and "
+                        f"page pool shard the same pp axis)"
+                    )
+                # Caller-provided draft weights win (benchmarks pass the
+                # target tree itself to measure the acceptance-1.0 ceiling).
+                # The engine-wide quantize knob covers the draft too — the
+                # draft exists to save bandwidth, and an unquantized draft
+                # could push the HBM budget the flag exists to protect.
+                with self._phase("place_params"):
+                    self.draft_params = self._place_params(
+                        draft_params, self.draft_cfg,
+                        config.draft_checkpoint_path, seed + 2,
+                    )
+                with self._phase("pools"):
+                    self.d_paged = new_pool(self.draft_cfg)
+                self._jit_spec_prefill = jax.jit(
+                    spec_prefill_fn,
+                    static_argnames=("t_cfg", "d_cfg", "greedy", "candidates",
+                                     "mesh"),
+                    donate_argnames=("t_paged", "d_paged"),
+                    out_shardings=(
+                        self._repl, self._pool_sharding, self._pool_sharding,
+                    ),
+                )
+                self._jit_spec_decode = jax.jit(
+                    spec_decode_fn,
+                    static_argnames=(
+                        "t_cfg", "d_cfg", "gamma", "eos_id", "gamma_low",
+                        "gamma_max", "candidates", "mesh",
+                    ),
+                    # Same double-buffered slot-state donation as the plain
+                    # decode block — spec rounds ride the identical pipeline.
+                    # The per-lane gamma dial (accept_ewma / gamma_lane,
+                    # ISSUE 19) donates alongside: it advances on device
+                    # every round like the rest of the slot state.
+                    donate_argnames=(
+                        "t_paged", "d_paged",
+                        "last_tokens", "seq_lens", "active",
+                        "accept_ewma", "gamma_lane",
+                    ),
+                    out_shardings=(
+                        self._dp_mat, self._dp_vec, self._dp_vec, self._dp_vec,
+                        self._dp_vec, self._dp_vec,
+                        self._pool_sharding, self._pool_sharding,
+                    ),
+                )
+
+            # Host mirrors of per-slot device state (engine thread only). They
+            # are the source of truth at slot transitions (admit/finish mark
+            # `_dev_dirty` → re-upload); between transitions the decode state —
+            # RNG key included — stays device-resident (`_dev`) and advances
+            # on-device, so steady decode uploads nothing per block.
+            self._page_tables = np.zeros((B, P), dtype=np.int32)
+            self._seq_lens = np.zeros((B,), dtype=np.int32)
+            self._last_tokens = np.zeros((B,), dtype=np.int32)
+            self._active = np.zeros((B,), dtype=bool)
+            self._caps = np.zeros((B,), dtype=np.int32)
+            self._temperature = np.zeros((B,), dtype=np.float32)
+            self._top_p = np.ones((B,), dtype=np.float32)
+            self._top_k = np.zeros((B,), dtype=np.int32)
+            self._seeds = np.zeros((B, 2), dtype=np.int32)
+            # Per-lane gamma dial mirrors (spec engines, ISSUE 19): refreshed
+            # from each processed round's packed stat columns — the DEVICE
+            # copy is authoritative between slot transitions, exactly like
+            # the other mirrors.
+            self._lane_ewma = np.ones((B,), dtype=np.float32)
+            self._lane_gamma = np.full(
+                (B,), max(self._gamma_max, 1), dtype=np.int32
+            )
+            self._slots: list[Optional[_Slot]] = [None] * B
+            self._dev: dict = {}
+            self._dev_dirty = True
+
+            self._submit: queue.Queue[GenRequest] = queue.Queue()
+            # Lookahead pipeline: dispatched-but-unprocessed decode blocks,
+            # oldest first (_InflightBlock records). While dispatching, up to
+            # _depth_target - 1 blocks stay queued ACROSS iterations — depth
+            # counts device-resident slot-state generations including the
+            # block just dispatched, so depth 2 = double-buffered overlap
+            # (dispatch N+1 before reading N) and depth 1 = synchronous
+            # dispatch-then-read, exactly. POLYKEY_DISPATCH_LOOKAHEAD
+            # overrides the config depth regardless of how the config was
+            # built (serving env, bench, tests) — the operator knob for
+            # host-bound decode (DEPLOY.md runbook).
+            from collections import deque
+
+            self._inflight_q: deque = deque()
+            # Prefill dispatches whose first tokens wait to be read, oldest
+            # first (_FirstTokens; engine thread only).
+            self._first_tokens: list = []
+            # (end time, reason) of the `_admit` visits that left requests
+            # waiting, oldest first, and when the visit now running began:
+            # what a request's queue time is cut by when it is admitted
+            # (_stamp_admitted). Engine thread only. Requests leave the
+            # queue oldest first, so an admission drops the visits that
+            # ended before its request arrived; the bound only matters to a
+            # queue that nothing leaves.
+            self._admit_visits: deque = deque(maxlen=1024)
+            self._admit_began = 0.0
+            try:
+                # polylint: disable=ML004(documented operator override: env beats any programmatic config, see comment above)
+                self._depth = max(1, int(os.environ.get(
+                    "POLYKEY_DISPATCH_LOOKAHEAD", config.lookahead_blocks
+                )))
+            except ValueError:
+                self._depth = config.lookahead_blocks
+            # Flight-deck timeline (ISSUE 10): the promoted pipeline ring —
+            # typed, bounded, always-on events for both frontiers plus slot
+            # lifecycle, exported as Perfetto JSON (/debug/timeline). The
+            # dispatch-order regression test asserts dispatch-N+1-before-
+            # process-N on it. timeline_capacity=0 disables it entirely:
+            # no ring allocated, every emission site one `is None` branch —
+            # obs-off engines pay nothing (the memory-discipline contract
+            # tests/test_timeline.py pins).
+            self.timeline: Optional[TimelineRecorder] = (
+                TimelineRecorder(config.timeline_capacity)
+                if config.timeline_capacity > 0 else None
+            )
+            # SLO signal plane (ISSUE 11): windowed rates/delta-quantiles
+            # over a ring of metrics snapshots, plus burn-rate evaluation of
+            # the declarative POLYKEY_SLO objectives. Attached to the
+            # METRICS object so the supervisor's adoption path carries the
+            # windows and budget state across restarts; the supervisor
+            # rebinds `timeline` to the fresh ring. signals_interval_s=0
+            # allocates nothing (`metrics.signals is None`) and the loop
+            # emission site below is one `is None` branch.
+            if config.signals_interval_s > 0 and self.metrics.signals is None:
+                from ..obs.signals import (
+                    ENV_POLICY,
+                    ENV_WINDOWS,
+                    SignalPlane,
+                    SloPolicy,
+                    windows_from_spec,
+                )
+
+                # Config-first, env-fallback: an EngineConfig.from_env
+                # carries the boot-time specs (restart-stable); a
+                # programmatic config controls them without touching
+                # os.environ; the empty defaults read the env here.
+                self.metrics.signals = SignalPlane(
+                    self.metrics,
+                    windows=windows_from_spec(
+                        config.signals_windows
+                        or os.environ.get(ENV_WINDOWS, "")
+                    ),
+                    interval_s=config.signals_interval_s,
+                    policy=SloPolicy.from_spec(
+                        config.slo_policy or os.environ.get(ENV_POLICY, "")
+                    ),
+                    timeline=self.timeline,
+                )
+            self._dispatch_seq = 0
+            # In-flight target for the CURRENT block size: when the adaptive
+            # dispatcher shrinks K, the LOOKAHEAD portion deepens by the
+            # same factor (1 + (depth-1) x (K/steps) — constant queued-ahead
+            # steps), because roundtrip hiding needs lookahead × block_time
+            # ≥ the host's sync roundtrip — a K/8 block at the configured
+            # depth would leave the host stalled on un-landed copies. Only the
+            # lookahead portion scales, so depth 1 stays exactly
+            # synchronous at every block size (the escape-hatch contract).
+            # The 64-block cap binds only for large lookahead_blocks (the
+            # scale factor itself tops out at block_steps // solo_steps).
+            self._depth_target = self._depth
+            if config.compile_warmup:
+                with self._phase("warmup"):
+                    self._compile_warmup()
+                # What building or loading the executables left on the host
+                # heap goes back to the OS before the first request, not 2 s
+                # into serving (device.release_compile_heap).
+                with self._phase("release_heap"):
+                    release_compile_heap()
+            self._wake = threading.Event()
+            self._stop = threading.Event()
+            self.dead: Optional[str] = None
+            self.last_progress = time.monotonic()
+        self._startup = self._startup_record(t_begin, compiles_before)
+        if logger is not None:
+            rows = self._startup["executables"]
+            logger.info(
+                "engine started", **self._startup,
+                slowest_executable=max(
+                    rows, key=lambda row: row["seconds"], default=None),
+            )
         self._thread = threading.Thread(
             target=self._run, name="polykey-engine", daemon=True
         )
         self._thread.start()
+
+    def _startup_record(self, t_begin: float, compiles_before: dict) -> dict:
+        """What the construction just finished cost (stats()["startup"],
+        the `engine started` line): `t_begin` / `t_end` on
+        time.monotonic() — CLOCK_MONOTONIC, one clock for every process
+        of the host, so a harness that started this process can place the
+        constructor inside its own wait; `stages`, the seconds of each
+        start-up phase entered (`init` is the whole, the others lie
+        inside it one after another); `compile`, what the compile census
+        gained over the constructor, and `warmup_compile`, over its
+        warm-up alone; `executables`, one row a warm-up dispatch, whose
+        `backend_s` add up to `warmup_compile`'s."""
+        seconds, entered = self.metrics.phase_seconds, self.metrics.phase_count
+        return {
+            "t_begin": t_begin,
+            "t_end": time.monotonic(),
+            "stages": {
+                name: round(seconds[name], 6) for name in STARTUP_PHASES
+                if name != "warm_call" and entered[name]
+            },
+            "compile": compile_delta(compiles_before, compile_counts()),
+            "warmup_compile": self._warm_compiles,
+            "executables": self._warm_rows,
+        }
 
     def _place_params(
         self, params: Optional[dict], model_cfg: ModelConfig,
@@ -1488,13 +1537,15 @@ class InferenceEngine:
                 "replica": self.replica_id,
                 # What this engine runs on (engine/device.py): platform
                 # as JAX reports it, the devices this engine's mesh owns
-                # and their allocator readings, compiles so far, and
-                # what warm-up found in the executables it built.
+                # and their allocator readings, compiles so far and their
+                # seconds, what this engine's construction cost
+                # (_startup_record), and what warm-up found in the
+                # executables it built.
                 **self._identity,
                 "devices": [int(d.id) for d in self.mesh.devices.flat],
                 "device_memory": device_memory(self.mesh.devices.flat),
                 "compiles": compile_counts(),
-                "warmup_compiles": dict(self._warm_compiles),
+                "startup": self._startup,
                 "warmup_mosaic_calls": dict(self._warm_kernels),
                 "warmup_collectives": dict(self._warm_collectives),
                 "slots_busy": sum(s is not None for s in self._slots),
@@ -2324,9 +2375,10 @@ class InferenceEngine:
                 # (warm_sampled_variants=False: greedy-only runs skip the
                 # sampled compiles entirely.)
                 for greedy in greedy_variants:
+                    named = {"bucket": bucket, "rows": n, "greedy": greedy}
                     if self._spec:
                         toks_dev, self.paged, self.d_paged = self._warm_call(
-                            "prefill", self._jit_spec_prefill,
+                            "prefill", named, self._jit_spec_prefill,
                             self.params, self.draft_params,
                             self.model_cfg, self.draft_cfg,
                             self.paged, self.d_paged,
@@ -2337,7 +2389,7 @@ class InferenceEngine:
                         )
                     else:
                         toks_dev, self.paged, self.state = self._warm_call(
-                            "prefill", self._jit_prefill,
+                            "prefill", named, self._jit_prefill,
                             self.params, self.model_cfg, self.paged,
                             *window, self.state, state_rows,
                             greedy=greedy,
@@ -2360,13 +2412,15 @@ class InferenceEngine:
                         np.zeros((2,), np.int32),
                     )
                     if self._spec:
-                        self._jit_merge(
+                        self._warm_call(
+                            "merge", {"rows": n}, self._jit_merge,
                             *merge_args, dev["accept_ewma"],
                             dev["gamma_lane"], np.int32(self._gamma_max),
                             eos_id=self.tokenizer.eos_id, spec=True,
                         )
                     else:
-                        self._jit_merge(
+                        self._warm_call(
+                            "merge", {"rows": n}, self._jit_merge,
                             *merge_args, eos_id=self.tokenizer.eos_id,
                         )
         if self._spec:
@@ -2384,7 +2438,8 @@ class InferenceEngine:
             for cand in warm_candidates:
                 for gamma in sorted({self._gamma_low, self._gamma_max}):
                     outs = self._warm_call(
-                        "decode", self._jit_spec_decode,
+                        "spec", {"steps": gamma + 1, "candidates": cand},
+                        self._jit_spec_decode,
                         self.params, self.draft_params,
                         self.model_cfg, self.draft_cfg,
                         self.paged, self.d_paged,
@@ -2414,7 +2469,8 @@ class InferenceEngine:
                 # non-greedy.
                 for steps in sorted({self._solo_steps, self._block_steps}):
                     outs = self._warm_call(
-                        "decode", self._jit_decode,
+                        "decode", {"steps": steps, "greedy": False},
+                        self._jit_decode,
                         self.params, self.model_cfg, self.paged,
                         dev["last_tokens"], dev["seq_lens"], dev["page_tables"],
                         dev["active"], dev["caps"], dev["seeds"],
@@ -2433,7 +2489,8 @@ class InferenceEngine:
             for greedy in greedy_variants:
                 for steps in sorted({self._solo_steps, self._block_steps}):
                     outs = self._warm_call(
-                        "decode", self._jit_decode,
+                        "decode", {"steps": steps, "greedy": greedy},
+                        self._jit_decode,
                         self.params, self.model_cfg, self.paged,
                         dev["last_tokens"], dev["seq_lens"], dev["page_tables"],
                         dev["active"], dev["caps"], dev["seeds"],
@@ -2447,7 +2504,8 @@ class InferenceEngine:
                     # would feed deleted buffers.
                     (_, dev["last_tokens"], dev["seq_lens"], dev["active"],
                      self.paged, self.state) = outs
-        self._jit_retire(
+        self._warm_call(
+            "retire", {}, self._jit_retire,
             dev["last_tokens"], dev["seq_lens"], dev["page_tables"],
             dev["active"], dev["caps"], np.int32(0),
         )
@@ -2459,41 +2517,67 @@ class InferenceEngine:
             # executable each way, warmed here, never again).
             P = cfg.pages_per_seq
             idx0 = np.zeros((P,), np.int32)
-            jax.block_until_ready(self._jit_kv_gather(self.paged, put(idx0)))
+            jax.block_until_ready(self._warm_call(
+                "kv_gather", {}, self._jit_kv_gather, self.paged, put(idx0)))
             zeros = jax.tree.map(
                 lambda pool: put(np.zeros(
                     (pool.shape[0], P, *pool.shape[2:]), pool.dtype)),
                 self.paged,
             )
-            self.paged = self._jit_kv_restore(self.paged, put(idx0), zeros)
+            self.paged = self._warm_call(
+                "kv_restore", {}, self._jit_kv_restore,
+                self.paged, put(idx0), zeros)
         jax.block_until_ready(self.paged)
-        self._warm_compiles = {
-            k: v - compiles_before[k] for k, v in compile_counts().items()
-        }
+        self._warm_compiles = compile_delta(compiles_before, compile_counts())
         # The dirty flag forces a fresh upload once real slots exist.
         self._dev_dirty = True
 
-    def _warm_call(self, step: str, fn, *args, **kwargs):
-        """One warm-up dispatch of a served step ("prefill" / "decode").
-        The first of each kind is also inspected, so stats() can say from
-        the executable itself — not from the gate functions — which
-        Mosaic kernels it carries and, on a mesh, how many collectives.
+    def _warm_call(self, step: str, named: dict, fn, *args, **kwargs):
+        """One warm-up dispatch, as a `warm_call` phase and a row of the
+        start-up record: `step` ("prefill" / "decode" / "spec" / "merge" /
+        "retire" / "kv_gather" / "kv_restore") and `named` (bucket, rows,
+        steps, greedy: what tells this executable from the step's others)
+        are the phase's attributes and the row's; beside them the row has
+        the host's `seconds` in the call (tracing, lowering, the cache
+        read or XLA's compile, the dispatch; nothing waits for the
+        device), the census's `backend_s` over it, and `cache_hit` (it
+        loaded what it built from the persistent cache).
+
+        The first prefill and the first decode or spec dispatch are also
+        inspected, so stats() can say from the executable itself — not
+        from the gate functions — which Mosaic kernels it carries and, on
+        a mesh, how many collectives.
 
         This builds nothing twice: `fn.lower(...)` and `.compile()` go
         through the jit's own lowering cache, so the dispatch below finds
         the executable already built and serves from the very object that
         was inspected (tests/test_device.py pins that on this JAX: one
         backend compile for lower + compile + call)."""
-        if step not in self._warm_kernels:
-            lowered = fn.lower(*args, **kwargs)
-            # polylint: disable=ML002(keyed by step kind: "prefill" / "decode", written at warm-up only)
-            self._warm_kernels[step] = mosaic_calls(lowered)
-            if self.mesh.size > 1:
+        served = "decode" if step == "spec" else step
+        spent = self.metrics.phase_seconds
+        seconds_before, compiles_before = spent["warm_call"], compile_counts()
+        with self._phase("warm_call", step=step, **named):
+            if (served in ("prefill", "decode")
+                    and served not in self._warm_kernels):
+                lowered = fn.lower(*args, **kwargs)
                 # polylint: disable=ML002(keyed by step kind: "prefill" / "decode", written at warm-up only)
-                self._warm_collectives[step] = collective_ops(
-                    lowered.compile()
-                )
-        return fn(*args, **kwargs)
+                self._warm_kernels[served] = mosaic_calls(lowered)
+                if self.mesh.size > 1:
+                    # polylint: disable=ML002(keyed by step kind: "prefill" / "decode", written at warm-up only)
+                    self._warm_collectives[served] = collective_ops(
+                        lowered.compile()
+                    )
+            out = fn(*args, **kwargs)
+        built = compile_delta(compiles_before, compile_counts())
+        # polylint: disable=ML002(one row a warm-up dispatch, written in the constructor only)
+        self._warm_rows.append({
+            "step": step, **named,
+            "seconds": round(spent["warm_call"] - seconds_before, 6),
+            "backend_s": built["backend_s"],
+            "cache_hit": built["cache_hits"] > 0
+            and not built["fresh_compiles"],
+        })
+        return out
 
     def _merge_slot(
         self, slot_idx: int, slot: _Slot, toks_dev: jax.Array, row: int

@@ -21,7 +21,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from ..obs.histogram import Histogram
-from ..obs.timeline import PHASES
+from ..obs.timeline import PHASES, STARTUP_PHASES
 
 # Bucket bounds for the live-lanes-per-block histogram: lane counts are
 # small integers bounded by max_decode_slots, so a fixed power-of-two-ish
@@ -168,7 +168,9 @@ class EngineMetrics:
         # phase_seconds / phase_count: time.monotonic() seconds and
         # entries per engine phase (obs.timeline.PHASES; one `with
         # phase(...)` site each). Loop phases never nest in one another,
-        # so their sum is the engine thread's time.
+        # so their sum is the engine thread's time. The "startup" ones
+        # are written by the thread that constructs the engine, before
+        # the engine thread exists (ISSUE 62).
         self.phase_seconds = dict.fromkeys(PHASES, 0.0)
         self.phase_count = dict.fromkeys(PHASES, 0)
         # Requests whose first token resolved: seconds in the three
@@ -399,6 +401,15 @@ class EngineMetrics:
         obs.timeline.PHASES is a KeyError: the table is the contract."""
         self.phase_seconds[name] += seconds
         self.phase_count[name] += 1
+
+    def adopt_startup(self, built: "EngineMetrics") -> None:
+        """A supervised restart hands this object to the engine it just
+        built: the start-up phases of that construction, which `built`
+        counted, come along, so `phase_seconds{warmup}` grows by every
+        restart's warm-up and not the first start's alone."""
+        for name in STARTUP_PHASES:
+            self.phase_seconds[name] += built.phase_seconds[name]
+            self.phase_count[name] += built.phase_count[name]
 
     def on_first_token(self, timings: RequestTimings) -> None:
         """A request's first token resolved: file its three TTFT phases,

@@ -201,6 +201,7 @@ class EngineSupervisor:
             # will run its own _fail_all concurrently with ours above,
             # and the double-counted failures must not pollute the live
             # engine's counters — a counter reset is the lesser evil.
+            old.metrics.adopt_startup(fresh.metrics)
             fresh.metrics = old.metrics
         # Signal-plane continuity (ISSUE 11): the plane rides the
         # adopted metrics object, so its window ring and SLO budget

@@ -166,8 +166,10 @@ _ENGINE_FAMILIES: tuple = (
     # "labeled" families render one sample per entry of the snapshot
     # dict `key`, under the label named after the family's kind suffix.
     ("labeled:phase", "polykey_engine_phase_seconds_total",
-     "Engine-thread seconds per loop phase (obs.timeline.PHASES; the "
-     "same spans a profiler capture holds as polykey/<phase>).",
+     "Seconds per engine phase (obs.timeline.PHASES; the same spans a "
+     "profiler capture holds as polykey/<phase>): the engine thread's "
+     "loop and nested phases, and the constructing thread's start-up "
+     "ones (init, place_params, pools, warmup, release_heap, warm_call).",
      "phase_seconds"),
     ("labeled:phase", "polykey_engine_phase_entries_total",
      "Times each engine phase ran.", "phase_count"),

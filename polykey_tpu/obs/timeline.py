@@ -32,6 +32,7 @@ the ≥2-deep overlap instead of trusting a ratio.
 
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 from typing import Iterable, Optional
@@ -72,10 +73,14 @@ EVENT_FIELDS: dict[str, tuple[str, ...]] = {
 # the engine loop itself and never inside one another, so their seconds
 # add up to the engine thread's time; "nested" ones run inside a loop
 # phase (a device dispatch call, the blocking readback) and are already
-# counted there. COMPONENTS.md §13 lists this table and the exporter
-# renders `polykey_engine_phase_seconds_total{phase=...}` from it, so a
-# new phase is one entry + one `with` site (EngineMetrics.on_phase
-# refuses a name that is not here).
+# counted there. "startup" ones (ISSUE 62) belong to the engine's
+# constructor, entered by the constructing thread before the engine thread
+# exists: `init` is the whole of it, the stages run inside `init` one after
+# another, and `warm_call` inside `warmup`. COMPONENTS.md §13 lists this
+# table and the exporter renders
+# `polykey_engine_phase_seconds_total{phase=...}` from it, so a new phase
+# is one entry + one `with` site (EngineMetrics.on_phase refuses a name
+# that is not here).
 PHASE_PREFIX = "polykey/"
 PHASES: dict[str, tuple[str, str]] = {
     "admit": ("loop", "dequeue, tokenize, allocate pages, dispatch the "
@@ -98,10 +103,36 @@ PHASES: dict[str, tuple[str, str]] = {
                     "first tokens, handed to their clients: all of them "
                     "inside resolve, or the first of them to come up in "
                     "a block's emit loop inside process"),
+    "init": ("startup", "InferenceEngine.__init__, first line to last"),
+    "place_params": ("startup", "a model's parameters onto the mesh: the "
+                     "caller's tree, the checkpoint or the seeded init, "
+                     "quantized and sharded (the draft's too)"),
+    "pools": ("startup", "the device page pool, the per-slot state, the "
+              "host tier and its durable reload, the draft's pool"),
+    "warmup": ("startup", "_compile_warmup: every served shape "
+               "dispatched once against the garbage page"),
+    "release_heap": ("startup", "the host heap that compiling left "
+                     "behind, handed back to the OS"),
+    "warm_call": ("startup", "one warm-up dispatch, inside warmup: "
+                  "tracing, lowering, loading or building its "
+                  "executable, and the dispatch call"),
 }
 LOOP_PHASES = tuple(n for n, (level, _) in PHASES.items() if level == "loop")
+STARTUP_PHASES = tuple(
+    n for n, (level, _) in PHASES.items() if level == "startup")
 
 _TraceAnnotation = None
+# The innermost phase each thread has open, for whoever must name what a
+# thread was doing and is not handed it (engine/device.py's compile
+# census: a compile while serving says which phase paid for it).
+_open = threading.local()
+
+
+def open_phase() -> Optional[tuple[str, dict]]:
+    """(name, attrs) of the innermost phase the CALLING thread has open,
+    or None."""
+    span = getattr(_open, "phase", None)
+    return None if span is None else (span._name, span._attrs)
 
 
 class phase:
@@ -112,9 +143,12 @@ class phase:
     device planes' clock; with no capture running the annotation is
     inert and `attrs` are never formatted — and on exit adds the elapsed
     ``time.monotonic()`` and 1 to the always-on per-phase accumulators
-    (`EngineMetrics.on_phase`). Engine thread only."""
+    (`EngineMetrics.on_phase`). While it is open it is what `open_phase`
+    answers on this thread. One owner at a time: the constructing thread
+    for the "startup" phases, the engine thread for the others."""
 
-    __slots__ = ("_metrics", "_name", "_annotation", "_t0")
+    __slots__ = ("_metrics", "_name", "_attrs", "_annotation", "_t0",
+                 "_outer")
 
     def __init__(self, metrics, name: str, **attrs):
         global _TraceAnnotation
@@ -124,9 +158,12 @@ class phase:
             from jax.profiler import TraceAnnotation as _TraceAnnotation
         self._metrics = metrics
         self._name = name
+        self._attrs = attrs
         self._annotation = _TraceAnnotation(PHASE_PREFIX + name, **attrs)
 
     def __enter__(self) -> "phase":
+        self._outer = getattr(_open, "phase", None)
+        _open.phase = self
         self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
@@ -134,6 +171,7 @@ class phase:
     def __exit__(self, *exc) -> None:
         elapsed = time.monotonic() - self._t0
         self._annotation.__exit__(*exc)
+        _open.phase = self._outer
         self._metrics.on_phase(self._name, elapsed)
 
 

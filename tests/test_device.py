@@ -89,6 +89,9 @@ def test_replica_i_takes_device_slice_i():
         def warn(self, msg, **fields):
             self.warned.append((msg, fields))
 
+        def info(self, msg, **fields):      # `engine started`
+            pass
+
     engine = InferenceEngine(replica(1, 5, tp=2), logger=Log())
     try:
         assert [d.id for d in engine.mesh.devices.flat] == [0, 1]

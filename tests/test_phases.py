@@ -75,7 +75,8 @@ def test_accumulators_hold_exactly_the_table():
     assert set(metrics.phase_count) == set(PHASES)
     assert set(LOOP_PHASES) == {n for n, (level, _) in PHASES.items()
                                 if level == "loop"}
-    assert {level for level, _ in PHASES.values()} == {"loop", "nested"}
+    assert {level for level, _ in PHASES.values()} == {
+        "loop", "nested", "startup"}
     snap = metrics.snapshot()
     assert set(snap["phase_seconds"]) == set(PHASES)
     assert snap["phase_count"] == dict.fromkeys(PHASES, 0)
@@ -642,6 +643,9 @@ def test_a_supervised_restart_hands_the_wait_accumulators_over():
         after = fresh.metrics.snapshot()
         assert after["ttft_phase_count"] == 3
         assert after["phase_count"]["first_token"] == 3
+        # Both constructions' start-up phases (ISSUE 62).
+        assert after["phase_count"]["init"] == 2
+        assert after["phase_seconds"]["init"] > before["phase_seconds"]["init"]
         assert after["first_token_poll_gap_count"] == 3
         assert after["first_token_poll_gap_seconds"] > \
             before["first_token_poll_gap_seconds"]
