@@ -283,6 +283,9 @@ def report(args, device: dict, first: dict, last: dict, asked: int) -> None:
         print(f"engine[{i}] startup_stage_seconds={startup['stages']} "
               f"trace_s={whole['trace_s']} lower_s={whole['lower_s']} "
               f"backend_s={whole['backend_s']} slowest_warm_call={slowest}")
+        # Loaded compiled from the executable store, or built and written
+        # to it (engine/executables.py); None with no cache directory.
+        print(f"engine[{i}] executable_store={startup['executable_store']}")
     serving = (int(head["compiles"]["executables"])
                - int(warm[0]["compiles"]["executables"]))
     print(f"executables_built_while_serving={serving}")

@@ -72,6 +72,20 @@ def enable_persistent_compile_cache() -> Optional[str]:
     return _CHECKOUT_CACHE
 
 
+def compile_cache_dir() -> Optional[str]:
+    """The directory this process's persistent compile cache is placed in
+    (what enable_persistent_compile_cache returned, or whoever set JAX's
+    own variable or option), None where there is none: the opt-out, or a
+    process that placed no cache. The engine keeps its executable store
+    (engine/executables.py) there, so whoever places the cache places
+    the store and POLYKEY_COMPILE_CACHE=0 turns both off."""
+    if os.environ.get("POLYKEY_COMPILE_CACHE", "1") == "0":
+        return None
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or None
+
+
 @dataclass(frozen=True)
 class EngineConfig:
     model: str = "tiny-llama"

@@ -91,7 +91,7 @@ from ..parallel.sharding import (
     paged_kv_sharding,
     shard_params,
 )
-from .config import EngineConfig
+from .config import EngineConfig, compile_cache_dir
 from .device import (
     collective_ops,
     compile_counts,
@@ -102,6 +102,7 @@ from .device import (
     mosaic_calls,
     release_compile_heap,
 )
+from .executables import ExecutableStore, StoredStep
 from .kv_cache import (
     AllocationError,
     BlockAllocator,
@@ -638,6 +639,24 @@ class EngineOverloadedError(RuntimeError):
 # begin with this map to gRPC DEADLINE_EXCEEDED (tpu_service).
 DEADLINE_MSG = "deadline exceeded"
 
+# The engine's jitted steps by the attribute that holds each, and the
+# static argument names each is jitted with: what jax.jit is told, and
+# what the executable store (engine/executables.py) takes out of a call
+# before it reaches a loaded executable.
+_STEP_STATICS = {
+    "_jit_prefill": ("cfg", "greedy", "candidates", "mesh"),
+    "_jit_decode": ("cfg", "greedy", "steps", "eos_id", "candidates", "mesh"),
+    "_jit_merge": ("eos_id", "spec"),
+    "_jit_retire": (),
+    "_jit_kv_restore": (),
+    "_jit_kv_gather": (),
+    "_jit_spec_prefill": ("t_cfg", "d_cfg", "greedy", "candidates", "mesh"),
+    "_jit_spec_decode": (
+        "t_cfg", "d_cfg", "gamma", "eos_id", "gamma_low", "gamma_max",
+        "candidates", "mesh",
+    ),
+}
+
 
 class InferenceEngine:
     def __init__(
@@ -822,7 +841,7 @@ class InferenceEngine:
             # across steps (donation requires matching input/output shardings).
             self._jit_prefill = jax.jit(
                 _prefill_fn,
-                static_argnames=("cfg", "greedy", "candidates", "mesh"),
+                static_argnames=_STEP_STATICS["_jit_prefill"],
                 donate_argnames=("paged", "state"),
                 out_shardings=(self._repl, self._pool_sharding, self._repl),
             )
@@ -838,9 +857,7 @@ class InferenceEngine:
             # it has no corresponding output to alias into.
             self._jit_decode = jax.jit(
                 _decode_fn,
-                static_argnames=(
-                    "cfg", "greedy", "steps", "eos_id", "candidates", "mesh",
-                ),
+                static_argnames=_STEP_STATICS["_jit_decode"],
                 donate_argnames=(
                     "paged", "last_tokens", "seq_lens", "active", "state",
                 ),
@@ -866,7 +883,8 @@ class InferenceEngine:
                 if config.draft_model is not None else ()
             )
             self._jit_merge = jax.jit(
-                _merge_lane_fn, static_argnames=("eos_id", "spec"),
+                _merge_lane_fn,
+                static_argnames=_STEP_STATICS["_jit_merge"],
                 out_shardings=merge_out,
             )
             self._jit_retire = jax.jit(
@@ -1111,8 +1129,7 @@ class InferenceEngine:
                     self.d_paged = new_pool(self.draft_cfg)
                 self._jit_spec_prefill = jax.jit(
                     spec_prefill_fn,
-                    static_argnames=("t_cfg", "d_cfg", "greedy", "candidates",
-                                     "mesh"),
+                    static_argnames=_STEP_STATICS["_jit_spec_prefill"],
                     donate_argnames=("t_paged", "d_paged"),
                     out_shardings=(
                         self._repl, self._pool_sharding, self._pool_sharding,
@@ -1120,10 +1137,7 @@ class InferenceEngine:
                 )
                 self._jit_spec_decode = jax.jit(
                     spec_decode_fn,
-                    static_argnames=(
-                        "t_cfg", "d_cfg", "gamma", "eos_id", "gamma_low",
-                        "gamma_max", "candidates", "mesh",
-                    ),
+                    static_argnames=_STEP_STATICS["_jit_spec_decode"],
                     # Same double-buffered slot-state donation as the plain
                     # decode block — spec rounds ride the identical pipeline.
                     # The per-lane gamma dial (accept_ewma / gamma_lane,
@@ -1257,7 +1271,27 @@ class InferenceEngine:
             # The 64-block cap binds only for large lookahead_blocks (the
             # scale factor itself tops out at block_steps // solo_steps).
             self._depth_target = self._depth
+            # The executable store (engine/executables.py): where the
+            # process has a compile cache directory, warm-up loads each
+            # step's COMPILED executable from beside it — no trace, no
+            # lowering — or builds and writes it, and every `_jit_*`
+            # handle becomes the step served from that table. No
+            # directory (POLYKEY_COMPILE_CACHE=0, or none placed), or no
+            # warm-up to fill a table: the handles stay the jitted
+            # functions themselves.
+            self._executables: Optional[ExecutableStore] = None
             if config.compile_warmup:
+                cache_dir = compile_cache_dir()
+                if cache_dir is not None:
+                    self._executables = ExecutableStore(
+                        cache_dir, self.mesh.devices.flat, logger)
+                    for handle, statics in _STEP_STATICS.items():
+                        if hasattr(self, handle):
+                            setattr(self, handle, StoredStep(
+                                self._executables,
+                                handle.removeprefix("_jit_"),
+                                getattr(self, handle), statics,
+                            ))
                 with self._phase("warmup"):
                     self._compile_warmup()
                 # What building or loading the executables left on the host
@@ -1292,7 +1326,8 @@ class InferenceEngine:
         inside it one after another); `compile`, what the compile census
         gained over the constructor, and `warmup_compile`, over its
         warm-up alone; `executables`, one row a warm-up dispatch, whose
-        `backend_s` add up to `warmup_compile`'s."""
+        `backend_s` add up to `warmup_compile`'s; `executable_store`,
+        what the store did for those rows (_store_counts)."""
         seconds, entered = self.metrics.phase_seconds, self.metrics.phase_count
         return {
             "t_begin": t_begin,
@@ -1304,7 +1339,15 @@ class InferenceEngine:
             "compile": compile_delta(compiles_before, compile_counts()),
             "warmup_compile": self._warm_compiles,
             "executables": self._warm_rows,
+            "executable_store": self._store_counts(),
         }
+
+    def _store_counts(self) -> Optional[dict]:
+        """What the executable store did for this engine so far
+        (ExecutableStore.counts), None where the engine has none."""
+        if self._executables is None:
+            return None
+        return self._executables.counts()
 
     def _place_params(
         self, params: Optional[dict], model_cfg: ModelConfig,
@@ -1545,7 +1588,12 @@ class InferenceEngine:
                 "devices": [int(d.id) for d in self.mesh.devices.flat],
                 "device_memory": device_memory(self.mesh.devices.flat),
                 "compiles": compile_counts(),
-                "startup": self._startup,
+                # The constructor's record; the store's counts as they
+                # stand NOW (`fallback_calls` moves while serving).
+                "startup": {
+                    **self._startup,
+                    "executable_store": self._store_counts(),
+                },
                 "warmup_mosaic_calls": dict(self._warm_kernels),
                 "warmup_collectives": dict(self._warm_collectives),
                 "slots_busy": sum(s is not None for s in self._slots),
@@ -2540,33 +2588,60 @@ class InferenceEngine:
         are the phase's attributes and the row's; beside them the row has
         the host's `seconds` in the call (tracing, lowering, the cache
         read or XLA's compile, the dispatch; nothing waits for the
-        device), the census's `backend_s` over it, and `cache_hit` (it
-        loaded what it built from the persistent cache).
+        device), the census's `backend_s` over it, `cache_hit` (it loaded
+        what it built from the persistent cache) and `loaded` (it built
+        nothing: the executable store held the compiled step).
 
         The first prefill and the first decode or spec dispatch are also
         inspected, so stats() can say from the executable itself — not
         from the gate functions — which Mosaic kernels it carries and, on
-        a mesh, how many collectives.
+        a mesh, how many collectives. An entry of the store carries the
+        inspection of the step it was built from, so a loaded start says
+        the same.
 
         This builds nothing twice: `fn.lower(...)` and `.compile()` go
-        through the jit's own lowering cache, so the dispatch below finds
-        the executable already built and serves from the very object that
-        was inspected (tests/test_device.py pins that on this JAX: one
-        backend compile for lower + compile + call)."""
+        through the jit's own lowering cache, so a dispatch through the
+        jitted function finds the executable already built and serves
+        from the very object that was inspected (tests/test_device.py
+        pins that on this JAX: one backend compile for lower + compile +
+        call); with a store the dispatch goes through that `Compiled`
+        itself. `fn.lower` is called from THIS frame either way: a frame
+        more above a lowering costs seconds of a cold start (PERF.md
+        section 6, PR 62 (1))."""
         served = "decode" if step == "spec" else step
+        inspected = (served in ("prefill", "decode")
+                     and served not in self._warm_kernels)
+        on_mesh, stored = self.mesh.size > 1, self._executables is not None
         spent = self.metrics.phase_seconds
         seconds_before, compiles_before = spent["warm_call"], compile_counts()
         with self._phase("warm_call", step=step, **named):
-            if (served in ("prefill", "decode")
-                    and served not in self._warm_kernels):
+            key, found = (
+                fn.load(args, kwargs, inspected) if stored else (None, None))
+            loaded = found is not None
+            if not loaded and (inspected or stored):
                 lowered = fn.lower(*args, **kwargs)
+                found = {
+                    "kernels": mosaic_calls(lowered) if inspected else None,
+                    "collectives": None,
+                }
+                if stored or (inspected and on_mesh):
+                    compiled = lowered.compile()
+                    if inspected and on_mesh:
+                        found["collectives"] = collective_ops(compiled)
+                    if stored:
+                        spent_here = compile_delta(
+                            compiles_before, compile_counts())
+                        fn.keep(
+                            key, compiled, **found,
+                            first_hand=spent_here["fresh_compiles"] > 0
+                            and not spent_here["cache_hits"],
+                        )
+            if inspected:
                 # polylint: disable=ML002(keyed by step kind: "prefill" / "decode", written at warm-up only)
-                self._warm_kernels[served] = mosaic_calls(lowered)
-                if self.mesh.size > 1:
+                self._warm_kernels[served] = found["kernels"]
+                if on_mesh:
                     # polylint: disable=ML002(keyed by step kind: "prefill" / "decode", written at warm-up only)
-                    self._warm_collectives[served] = collective_ops(
-                        lowered.compile()
-                    )
+                    self._warm_collectives[served] = found["collectives"]
             out = fn(*args, **kwargs)
         built = compile_delta(compiles_before, compile_counts())
         # polylint: disable=ML002(one row a warm-up dispatch, written in the constructor only)
@@ -2576,6 +2651,7 @@ class InferenceEngine:
             "backend_s": built["backend_s"],
             "cache_hit": built["cache_hits"] > 0
             and not built["fresh_compiles"],
+            "loaded": loaded,
         })
         return out
 
