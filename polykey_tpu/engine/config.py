@@ -705,6 +705,7 @@ class EngineConfig:
                     )
         self._refuse_for_recurrent_state()
         self._refuse_for_latent_pool()
+        self._refuse_for_looped_stack()
 
     def _served_model(self):
         """The ModelConfig this engine would serve, looked up when the
@@ -716,6 +717,40 @@ class EngineConfig:
             return get_config(self.model)
         except KeyError as e:
             raise ValueError(e.args[0]) from None
+
+    def _refuse_for_looped_stack(self) -> None:
+        """A looped stack (ModelConfig.loop_steps > 1) keeps one cache
+        layer a layer AND pass: its pool is `kv_layers` = num_layers ×
+        loop_steps deep. The plain prefill / decode pair, the prefix
+        cache, int8 K/V, quantized weights and tp follow `kv_layers` or
+        never count layers (tests/test_ouro.py holds each against the
+        reference). What counts `num_layers` cache layers, or runs the
+        stack once by construction, is refused here, in one place, rather
+        than half-ported."""
+        model = self._served_model()
+        if model.loop_steps <= 1:
+            return
+        refused = {
+            "pp > 1 (a pipeline stage runs its layers once and hands on: "
+            "parallel/pipeline.py has no loop over the stages)": self.pp > 1,
+            "host_kv_bytes (the host tier's pages are num_layers deep: "
+            "kv_cache.HostKVPool)": self.host_kv_bytes > 0,
+            "disagg / disagg_tier (the KV handoff ships and checks "
+            "num_layers cache layers: kv_cache.KVHandoffState)":
+                bool(self.disagg or self.disagg_tier),
+            "draft_model (the speculative pair verifies one pass over "
+            "num_layers-deep pools: engine/spec_decode.py)":
+                self.draft_model is not None,
+        }
+        for what, on in refused.items():
+            if on:
+                raise ValueError(
+                    f"{self.model} runs its {model.num_layers} layers "
+                    f"{model.loop_steps} times a token over "
+                    f"{model.kv_layers} cache layers "
+                    "(ModelConfig.loop_steps); not supported with it: "
+                    f"{what}"
+                )
 
     def _refuse_for_latent_pool(self) -> None:
         """A latent-attention model (ModelConfig.latent_kv: "A" layers)

@@ -380,15 +380,24 @@ def _decode_fn(
     for a live sampled lane (sampling._trunc_thresholds; on every other
     sub-step the rows' sorted heads answered). The greedy variant holds
     no sampler and carries nothing for it.
+
+    A looped stack (ModelConfig.loop_steps > 1) adds `loop_steps` rows
+    right after the tokens', before those: row u the block's live
+    lane-steps whose exit rule chose pass u (forward_slots_counted's
+    `exits`, counted here on the device).
     """
 
     def one(carry, _):
         last, seq, act, paged, state = carry
         positions = jnp.maximum(seq - 1, 0)[:, None]       # [B, 1]
-        hidden, paged, state, hit = forward_slots_counted(
+        hidden, paged, state, hit, exits = forward_slots_counted(
             params, cfg, last[:, None], positions, paged, page_tables,
             state, active=act, mesh=mesh,
         )
+        left = None if exits is None else jnp.sum(
+            act[None, :]
+            & (exits[:, 0][None, :] == jnp.arange(cfg.loop_steps)[:, None]),
+            axis=1, dtype=jnp.int32)                       # [loop_steps]
         logits = unembed(params, cfg, hidden[:, 0])        # [B, V]
         # The new token lands at index seq → that position keys its draw.
         tokens, full = sample_tail_counted(
@@ -399,16 +408,19 @@ def _decode_fn(
         new_seq = seq + act.astype(jnp.int32)
         cont = act & (tokens != eos_id) & (new_seq < caps)
         packed = jnp.where(act, tokens, -1)
-        return (tokens, new_seq, cont, paged, state), (packed, hit, full)
+        return (tokens, new_seq, cont, paged, state), (packed, left, hit, full)
 
     carry = (last_tokens, seq_lens, active, paged, state)
-    (last, seq, act, paged, state), (packed, hits, fulls) = jax.lax.scan(
+    (last, seq, act, paged, state), (packed, lefts, hits, fulls) = jax.lax.scan(
         one, carry, None, length=steps
     )
     sums = [jnp.sum(n, dtype=jnp.int32) for n in (hits, fulls) if n is not None]
+    if lefts is not None:
+        sums.insert(0, jnp.sum(lefts, axis=0, dtype=jnp.int32)[:, None])
     if sums:
         packed = jnp.concatenate([packed] + [
-            jnp.broadcast_to(n, (1, packed.shape[1])) for n in sums
+            jnp.broadcast_to(n, (n.size, packed.shape[1]))
+            for n in sums
         ])
     return packed, last, seq, act, paged, state
 
@@ -945,6 +957,20 @@ class InferenceEngine:
                 # Expert layers of a layer pattern: a decode block of such a model
                 # brings home the held experts its live lanes chose (_decode_fn).
                 self._expert_layers = self.model_cfg.layer_pattern.count("E")
+                # A looped stack's passes (1: none): its decode block brings
+                # home the exits by pass, and every dispatch counts
+                # `_layer_passes` layer applications a step.
+                self._loops = self.model_cfg.loop_steps
+                self._layer_passes = (
+                    self._loops * self.model_cfg.num_layers
+                    if self._loops > 1 else 0)
+                self._loop_attrs = (
+                    {"loops": self._loops} if self._loops > 1 else {})
+                self._loop_stats = {"loop": {
+                    "steps": self._loops,
+                    "kv_layers": self.model_cfg.kv_layers,
+                    "kv_bytes_per_token": self._kv_token_bytes,
+                }} if self._loops > 1 else {}
                 # What a slot holds beside its pages (kv_cache.SlotState): born
                 # on the device like the pools; an empty pytree for a model with
                 # no recurrent state.
@@ -1608,6 +1634,7 @@ class InferenceEngine:
                 # over all layers: pages in use read in bytes.
                 "kv_pool_bytes": self._kv_pool_bytes,
                 "kv_token_bytes": self._kv_token_bytes,
+                **self._loop_stats,
                 "queued": self._submit.qsize(),
                 "inflight_blocks": len(self._inflight_q),
                 "prefill_budget": self._prefill_budget,
@@ -2273,7 +2300,7 @@ class InferenceEngine:
             if self._faults is not None:
                 self._faults.maybe_raise("prefill-error", replica=self.replica_id, tier=self._tier)
             with self._phase("prefill", bucket=bucket, rows=n_pad * bucket,
-                             tokens=real):
+                             tokens=real, **self._loop_attrs):
                 # The last dispatch's stamp is the one that stays: the
                 # one that completes the prompt.
                 issued = time.monotonic()
@@ -3478,11 +3505,12 @@ class InferenceEngine:
         )
         lanes = int(act.sum())
         gap_ms = self.metrics.on_dispatch(
-            lanes, steps, slots=len(self._slots), depth=self._depth_target
+            lanes, steps, slots=len(self._slots), depth=self._depth_target,
+            layer_passes=self._layer_passes,
         )
         live = tuple(int(i) for i in np.flatnonzero(act))
         with self._phase("decode", seq=self._dispatch_seq + 1, lanes=lanes,
-                         steps=steps):
+                         steps=steps, **self._loop_attrs):
             (packed_dev, last_dev, seq_dev, act_dev,
              self.paged, self.state) = self._jit_decode(
                 self.params,
@@ -3674,6 +3702,11 @@ class InferenceEngine:
             # One more row (_decode_fn): the held experts the block's
             # expert layers hit, over its steps that had a live lane.
             packed, hit = packed[:-1], int(packed[-1, 0])
+        if self._loops > 1:
+            # A looped stack's rows (_decode_fn): the block's live
+            # lane-steps by the pass their exit rule chose.
+            packed, exits = packed[:-self._loops], packed[-self._loops:, 0]
+            self.metrics.on_loop_exits(exits)
         live_steps = int((packed >= 0).any(axis=1).sum())
         if sampled:
             self.metrics.on_sampler_steps(live_steps, full_sorts)

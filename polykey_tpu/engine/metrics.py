@@ -222,6 +222,14 @@ class EngineMetrics:
         # in the snapshot once a sampled block is in).
         self.sampler_steps_total = 0
         self.sampler_full_sort_steps_total = 0
+        # A looped stack's decode program (ModelConfig.loop_steps > 1; in
+        # the snapshot once such a block is dispatched): layer
+        # applications, loop_steps × num_layers a dispatched step
+        # (on_dispatch, host-known), and the live lane-steps by the pass
+        # the exit rule chose, over the blocks whose readback has landed
+        # (on_loop_exits; counted on the device, engine._decode_fn).
+        self.loop_layer_passes = 0
+        self.loop_exits_by_step: list = []
         # Prefill dispatches whose first tokens were read, and for each
         # the seconds since the engine last looked at it and found it
         # unfinished (or since its dispatch call returned): an upper
@@ -377,6 +385,16 @@ class EngineMetrics:
             self.held_expert_calls += calls
             self.held_experts_hit += hit
 
+    def on_loop_exits(self, exits) -> None:
+        """One decode block of a looped stack has landed: `exits[u]` =
+        its live lane-steps whose exit rule chose pass u (counted on the
+        device, engine._decode_fn)."""
+        with self._lock:
+            if not self.loop_exits_by_step:
+                self.loop_exits_by_step = [0] * len(exits)
+            for u, n in enumerate(exits):
+                self.loop_exits_by_step[u] += int(n)
+
     def on_sampler_steps(self, steps: int, full_sorts: int) -> None:
         """One decode block of the sampled variant has landed: `steps` =
         its steps with a live lane, `full_sorts` = those on which the
@@ -456,7 +474,8 @@ class EngineMetrics:
             self.decode_lane_steps_dead += (slots - live) * steps
 
     def on_dispatch(self, lanes: int, steps: int,
-                    slots: int = 0, depth: int = 0) -> float:
+                    slots: int = 0, depth: int = 0,
+                    layer_passes: int = 0) -> float:
         """One decode block (or spec round) dispatched with `lanes` live
         decode lanes for `steps` device steps. Returns the counted
         dispatch gap in ms (0.0 for the first dispatch or an idle-capped
@@ -464,10 +483,13 @@ class EngineMetrics:
         `slots` (the static batch width) feeds the padding-waste
         counters: the device computes slots×steps token rows of which
         lanes×steps are useful. `depth` is the in-flight target the
-        dispatch ran with (its running maximum is kept)."""
+        dispatch ran with (its running maximum is kept). `layer_passes`:
+        a looped stack's layer applications a step (loop_steps ×
+        num_layers; 0 for a stack of one pass)."""
         now = time.monotonic()
         counted_gap = 0.0
         with self._lock:
+            self.loop_layer_passes += layer_passes * steps
             if depth > self.depth_target_max:
                 self.depth_target_max = depth
             if slots > 0:
@@ -760,6 +782,9 @@ class EngineMetrics:
             if self.held_expert_calls:
                 snap["held_expert_calls"] = self.held_expert_calls
                 snap["held_experts_hit"] = self.held_experts_hit
+            if self.loop_layer_passes:
+                snap["loop_layer_passes"] = self.loop_layer_passes
+                snap["loop_exits_by_step"] = list(self.loop_exits_by_step)
             if self.sampler_steps_total:
                 snap["sampler_steps_total"] = self.sampler_steps_total
                 snap["sampler_full_sort_steps_total"] = (

@@ -34,7 +34,7 @@ a LOWER bound on kernel MFU (TTFT includes host tokenize/queue/dispatch).
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Optional
 
 from polykey_tpu.models.config import ModelConfig, get_config
@@ -99,12 +99,15 @@ def _weight_bytes_split(cfg: ModelConfig, dtype: str,
     vocab x hidden matmul per step). The embedding table contributes only
     a row gather (negligible). per_expert_bytes: ONE expert's MLP; the
     caller decides how many experts a step hits. int4 keeps embed/lm_head
-    at int8 (models/quant.py) — modeled as such."""
+    at int8 (models/quant.py) — modeled as such. A looped stack reads its
+    blocks `loop_steps` times a step (they do not stay on the chip between
+    passes); it HOLDS them once (`weight_resident_bytes`)."""
     embed = cfg.vocab_size * cfg.hidden_size
     head_params = embed  # lm head is read every step, tied or not
     total = cfg.num_params()
     table_params = embed + (0 if cfg.tie_embeddings else embed)
-    block_params = total - table_params  # blocks + final norm
+    # blocks + final norm, once a pass
+    block_params = (total - table_params) * cfg.loop_steps
     expert_params = 0.0
     if cfg.is_moe:
         expert_params = 3.0 * cfg.hidden_size * cfg.intermediate_size
@@ -142,7 +145,9 @@ def weight_resident_bytes(cfg: ModelConfig, dtype: str, quantize: bool,
     only row-gathers it. Feeds grade()'s hbm_weight_fraction — the
     headroom number that decides how many KV pages (decode slots) a chip
     has left."""
-    dense, per_expert = _weight_bytes_split(cfg, dtype, quantize, bits)
+    dense, per_expert = _weight_bytes_split(
+        replace(cfg, loop_steps=1) if cfg.loop_steps > 1 else cfg,
+        dtype, quantize, bits)
     resident = dense
     if cfg.is_moe:
         resident += cfg.num_experts * per_expert
@@ -189,14 +194,26 @@ def kv_pool_bytes_spec(cfg: ModelConfig, num_pages: int, page_size: int,
 
 def decode_flops_per_token(cfg: ModelConfig, ctx: float) -> float:
     """MatMul FLOPs to decode one token at context length ctx."""
-    attn_scores = 4.0 * cfg.num_layers * ctx * cfg.num_heads * cfg.head_dim
-    return 2.0 * cfg.num_active_params() + attn_scores
+    attn_scores = 4.0 * cfg.num_layers * cfg.loop_steps * ctx * cfg.num_heads * cfg.head_dim
+    return 2.0 * _active_params_a_token(cfg) + attn_scores
+
+
+def _active_params_a_token(cfg: ModelConfig) -> float:
+    """Parameters a token multiplies: a looped stack's blocks once a
+    pass, the tables once."""
+    active = cfg.num_active_params()
+    if cfg.loop_steps == 1:
+        return active
+    tables = cfg.vocab_size * cfg.hidden_size * (
+        1 if cfg.tie_embeddings else 2)
+    return (active - tables) * cfg.loop_steps + tables
 
 
 def prefill_flops(cfg: ModelConfig, prompt_len: int) -> float:
     """MatMul FLOPs to prefill a prompt (causal attention ~ P^2/2)."""
-    attn = 2.0 * cfg.num_layers * prompt_len**2 * cfg.num_heads * cfg.head_dim
-    return prompt_len * 2.0 * cfg.num_active_params() + attn
+    attn = (2.0 * cfg.num_layers * cfg.loop_steps * prompt_len**2
+            * cfg.num_heads * cfg.head_dim)
+    return prompt_len * 2.0 * _active_params_a_token(cfg) + attn
 
 
 def grade(model: str, dtype: str, quantize: bool, quantize_bits: int,

@@ -34,6 +34,17 @@ class ModelConfig:
     query_pre_attn_scalar: Optional[float] = None
     use_post_norms: bool = False              # post-attn/post-mlp RMSNorms
     scale_embeddings: bool = False            # multiply embeds by sqrt(hidden)
+    # A looped stack (the homogeneous block only): the SAME `num_layers`
+    # layers run `loop_steps` times a token, the final norm closing every
+    # pass and feeding the next; pass u of layer l owns cache layer
+    # u · num_layers + l. An exit gate (one Linear(hidden, 1) with bias,
+    # sigmoid) on each pass's normed output gives λ_u; a position leaves at
+    # the first pass whose cumulative exit probability (λ_u Π_{j<u}(1 − λ_j),
+    # the last pass taking the rest) reaches `early_exit_threshold`, else
+    # at the last, and the head reads that pass's output. Every pass always
+    # runs: later tokens need its K/V. 1: one pass, no gate.
+    loop_steps: int = 1
+    early_exit_threshold: float = 1.0
     # MoE (Mixtral) specifics
     num_experts: int = 0
     num_experts_per_tok: int = 0
@@ -162,11 +173,12 @@ class ModelConfig:
 
     @property
     def kv_layers(self) -> int:
-        """Layers that own a layer of the page pool."""
+        """Layers that own a layer of the page pool: every pass of a
+        looped stack has a cache of its own."""
         if self.layer_pattern:
             return (self.layer_pattern.count("*")
                     + self.layer_pattern.count("A"))
-        return self.num_layers
+        return self.num_layers * self.loop_steps
 
     @property
     def latent_kv(self) -> bool:
@@ -229,6 +241,24 @@ class ModelConfig:
         return int(self.head_dim * self.partial_rotary_factor)
 
     def __post_init__(self):
+        if self.loop_steps < 1:
+            raise ValueError(
+                f"loop_steps {self.loop_steps} must be >= 1 (the stack's "
+                "passes a token)"
+            )
+        if self.loop_steps > 1 and self.layer_pattern:
+            raise ValueError(
+                f"loop_steps {self.loop_steps} with layer_pattern "
+                f"{self.layer_pattern!r}: the unrolled walk of a pattern "
+                "(models/hybrid.py run_stack) has no loop over the stack; "
+                "only the homogeneous scanned block runs its layers again"
+            )
+        if self.loop_steps > 1 and self.sliding_window is not None:
+            raise ValueError(
+                f"loop_steps {self.loop_steps} with sliding_window: the "
+                "window interleaving goes by the cache layer's index, "
+                "which a later pass offsets"
+            )
         if not self.layer_pattern:
             return
         if len(self.layer_pattern) != self.num_layers or \
@@ -333,7 +363,9 @@ class ModelConfig:
         norms = self.hidden_size * (4 if self.use_post_norms else 2)
         block = attn + mlp + norms
         head = 0 if self.tie_embeddings else embed
-        return embed + self.num_layers * block + self.hidden_size + head
+        # A looped stack holds its layers ONCE; the exit gate's w and b.
+        gate = self.hidden_size + 1 if self.loop_steps > 1 else 0
+        return embed + self.num_layers * block + self.hidden_size + head + gate
 
     def num_active_params(self) -> int:
         """Parameters touched per token: for MoE, only the router plus the
@@ -667,6 +699,24 @@ TINY_OLMO_HYBRID = ModelConfig(
     dense_intermediate_size=96,
 )
 
+# A looped stack at toy size: three sandwich-normed multi-head layers run
+# twice a token with the same weights (six cache layers), an exit gate.
+TINY_OURO = ModelConfig(
+    name="tiny-ouro",
+    vocab_size=512,
+    hidden_size=64,
+    intermediate_size=128,
+    num_layers=3,
+    num_heads=4,
+    num_kv_heads=4,
+    head_dim=16,
+    max_seq_len=128,
+    rope_theta=1_000_000.0,
+    rms_norm_eps=1e-6,
+    use_post_norms=True,
+    loop_steps=2,
+)
+
 # A mid-size llama for single-chip benchmarking without 8B's 16 GiB of bf16
 # weights (v5e has 16 GiB HBM; 8B serves in int8 — see engine docs).
 LLAMA_1B_BENCH = replace(LLAMA32_1B, name="llama-1b-bench")
@@ -704,6 +754,7 @@ MODEL_REGISTRY = {
         TINY_QWEN3_NEXT,
         TINY_PANGU,
         TINY_OLMO_HYBRID,
+        TINY_OURO,
         LLAMA_1B_BENCH,
         MIXTRAL_BENCH,
     )

@@ -39,7 +39,8 @@ from .layers import (
 
 @struct.dataclass
 class KVCache:
-    """Contiguous per-layer KV cache: [num_layers, B, S, num_kv_heads, head_dim].
+    """Contiguous per-layer KV cache: [num_layers, B, S, num_kv_heads, head_dim]
+    (a looped stack: one cache layer a layer and pass, pass-major).
 
     The simple serving path (fixed-geometry batch, fixed max length). The
     continuous-batching engine replaces this with the paged cache
@@ -57,7 +58,8 @@ class KVCache:
 def init_cache(
     cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16
 ) -> KVCache:
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers * cfg.loop_steps, batch, max_len,
+             cfg.num_kv_heads, cfg.head_dim)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
@@ -117,6 +119,14 @@ def init_top_params(
             jax.random.normal(k_head, (cfg.hidden_size, cfg.vocab_size), dtype)
             * cfg.hidden_size**-0.5
         )
+    if cfg.loop_steps > 1:
+        # The exit gate of a looped stack: one Linear(hidden, 1) with bias.
+        top["exit_gate"] = {
+            "w": jax.random.normal(
+                jax.random.fold_in(k_head, 1), (cfg.hidden_size, 1), dtype
+            ) * cfg.hidden_size**-0.5,
+            "b": jnp.zeros((1,), dtype),
+        }
     return top
 
 
@@ -205,9 +215,6 @@ def apply_layer(layer_params, layer_idx, x, positions, cfg: ModelConfig, attend,
 
 def _run_stack(params, cfg: ModelConfig, tokens, positions, kv_scanned, attend):
     """Shared transformer stack: embed → scan(layer body) → final norm."""
-    norm_offset = 1.0 if cfg.scale_embeddings else 0.0
-    eps = cfg.rms_norm_eps
-
     x = embed_tokens(params, cfg, tokens)
 
     def body(x, scanned):
@@ -217,11 +224,101 @@ def _run_stack(params, cfg: ModelConfig, tokens, positions, kv_scanned, attend):
         )
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+    if cfg.loop_steps > 1:
+        # Pass u over cache layers u·L .. u·L + L − 1 of `kv_scanned`
+        # ([loop_steps · L, ...]: a contiguous cache, or the no-cache
+        # path's empty pair).
+        def layers(x, _, ids, cache):
+            x, cache = jax.lax.scan(body, x, (params["layers"], ids, cache))
+            return x, None, cache
+
+        rule, _, (new_k, new_v) = _run_passes(
+            params, cfg, x, None, layers, jax.tree.map(
+                lambda c: c.reshape(
+                    cfg.loop_steps, cfg.num_layers, *c.shape[1:]),
+                kv_scanned))
+        return (rule.chosen, new_k.reshape(kv_scanned[0].shape),
+                new_v.reshape(kv_scanned[1].shape))
     x, (new_k, new_v) = jax.lax.scan(
         body, x, (params["layers"], layer_ids, kv_scanned)
     )
-    x = rms_norm(x, params["final_norm"], eps, norm_offset)
-    return x, new_k, new_v
+    return _pass_norm(params, cfg, x), new_k, new_v
+
+
+@struct.dataclass
+class ExitRule:
+    """The exit rule of a looped stack as it runs beside the passes, one
+    entry a position: `stay` = Π_{j<u}(1 − λ_j), `cumulative` = Σ_{j<u} p_j,
+    `left` whether an earlier pass already reached the threshold, `chosen`
+    the hidden state of the pass it left at, `exits` that pass."""
+
+    stay: jax.Array          # [B, T] float32
+    cumulative: jax.Array    # [B, T] float32
+    left: jax.Array          # [B, T] bool
+    chosen: jax.Array        # [B, T, H]
+    exits: jax.Array         # [B, T] int32
+
+
+def _exit_rule_init(x: jax.Array) -> ExitRule:
+    shape = x.shape[:2]
+    return ExitRule(
+        stay=jnp.ones(shape, jnp.float32),
+        cumulative=jnp.zeros(shape, jnp.float32),
+        left=jnp.zeros(shape, bool),
+        chosen=jnp.zeros_like(x),
+        exits=jnp.zeros(shape, jnp.int32),
+    )
+
+
+def _pass_norm(params, cfg: ModelConfig, x):
+    norm_offset = 1.0 if cfg.scale_embeddings else 0.0
+    return rms_norm(x, params["final_norm"], cfg.rms_norm_eps, norm_offset)
+
+
+def _exit_rule(params, cfg: ModelConfig, rule: ExitRule, x, u) -> ExitRule:
+    """Pass `u` has ended in the normed `x` [B, T, H]: the gate's
+    λ_u = sigmoid(w · x + b) in float32 (a multiply and a row sum: no
+    matmul precision to choose), p_u = λ_u · stay — the last pass takes
+    all that is left — and a position that has not left yet leaves here
+    if its cumulative exit probability reaches `early_exit_threshold`, or
+    this is the last pass: `chosen` takes x there."""
+    gate = params["exit_gate"]
+    lam = jax.nn.sigmoid(
+        jnp.sum(x.astype(jnp.float32) * gate["w"][:, 0].astype(jnp.float32),
+                axis=-1)
+        + gate["b"].astype(jnp.float32)[0])
+    last = u == cfg.loop_steps - 1
+    cumulative = rule.cumulative + jnp.where(last, 1.0, lam) * rule.stay
+    leaves = ~rule.left & (last | (cumulative >= cfg.early_exit_threshold))
+    return ExitRule(
+        stay=rule.stay * (1.0 - lam),
+        cumulative=cumulative,
+        left=rule.left | leaves,
+        chosen=jnp.where(leaves[..., None], x, rule.chosen),
+        exits=jnp.where(leaves, u, rule.exits),
+    )
+
+
+def _run_passes(params, cfg: ModelConfig, x, carried, layers, per_pass=None):
+    """The passes of a looped stack: `layers(x, carried, layer_ids, xs)` →
+    (x, carried, ys) is one walk over the SAME weights, called under a
+    `lax.scan` over the passes with pass u's cache-layer ids (u·L + l) and
+    its slice of `per_pass`; the final norm closes every pass and feeds the
+    next, and the exit rule runs beside them. Returns (the rule as the
+    last pass left it, `carried`, the passes' ys)."""
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+
+    def one_pass(carry, scanned):
+        x, carried, rule = carry
+        u, xs = scanned
+        x, carried, ys = layers(x, carried, layer_ids + u * cfg.num_layers, xs)
+        x = _pass_norm(params, cfg, x)
+        return (x, carried, _exit_rule(params, cfg, rule, x, u)), ys
+
+    (_, carried, rule), ys = jax.lax.scan(
+        one_pass, (x, carried, _exit_rule_init(x)),
+        (jnp.arange(cfg.loop_steps, dtype=jnp.int32), per_pass))
+    return rule, carried, ys
 
 
 def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged,
@@ -246,8 +343,10 @@ def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged,
     (tests/test_paged_layout.py holds that in the compiled step).
 
     Not scanned as xs/ys (the way _run_stack scans a contiguous cache):
-    that makes XLA build the updated stack in a second full-size buffer."""
-    norm_offset = 1.0 if cfg.scale_embeddings else 0.0
+    that makes XLA build the updated stack in a second full-size buffer.
+
+    A third value comes back: each position's exit pass of a looped stack
+    (`_run_passes`), None for a stack of one pass."""
     x = embed_tokens(params, cfg, tokens)
 
     def body(carry, scanned):
@@ -259,12 +358,24 @@ def _run_paged_stack(params, cfg: ModelConfig, tokens, positions, paged,
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     pool = _stacked(paged)
-    (x, pool), _ = jax.lax.scan(
-        body, (x, pool if stage is None else (pool, stage)),
-        (params["layers"], layer_ids)
-    )
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, norm_offset)
-    return x, _unstacked(paged, pool if stage is None else pool[0])
+    held = pool if stage is None else (pool, stage)
+    exits = None
+    if cfg.loop_steps > 1:
+        # A looped stack: the layer scan inside the scan over the passes,
+        # the pool (and the stage) in the ONE carry of both, pass u on
+        # pool layers u·L + l.
+        def layers(x, held, ids, _):
+            (x, held), _ = jax.lax.scan(
+                body, (x, held), (params["layers"], ids))
+            return x, held, None
+
+        rule, held, _ = _run_passes(params, cfg, x, held, layers)
+        x, exits = rule.chosen, rule.exits
+    else:
+        (x, held), _ = jax.lax.scan(
+            body, (x, held), (params["layers"], layer_ids))
+        x = _pass_norm(params, cfg, x)
+    return x, _unstacked(paged, held if stage is None else held[0]), exits
 
 
 def _stacked(paged):
@@ -375,7 +486,8 @@ def forward(
                 return attn_override(layer_idx, q, k, v), cache
             return causal(layer_idx, q, k, v, cache)
 
-        empty = jnp.zeros((cfg.num_layers, 0), dtype=jnp.float32)
+        empty = jnp.zeros(
+            (cfg.num_layers * cfg.loop_steps, 0), dtype=jnp.float32)
         kv_scanned = (empty, empty)
 
     x, new_k, new_v = _run_stack(params, cfg, tokens, positions, kv_scanned, attend)
@@ -415,7 +527,7 @@ def forward_paged(
 
 def forward_slots(params, cfg, tokens, positions, paged, page_tables, state,
                   rows=None, active=None, mesh=None):
-    """`forward_slots_counted` without its count: (hidden, paged, state)."""
+    """`forward_slots_counted` without its counts: (hidden, paged, state)."""
     return forward_slots_counted(
         params, cfg, tokens, positions, paged, page_tables, state, rows,
         active, mesh,
@@ -438,11 +550,13 @@ def forward_slots_counted(
     for a stateful model, the per-slot recurrent state beside them, which
     a prefill's `rows` or a decode step's `active` lanes say how to use
     (models/hybrid.py `run_stack`). Returns
-    (hidden, paged, state, hits); a model without state hands `state` back
-    as it came, and `hits` is the held experts the live lanes of a decode
-    step chose over a layer pattern's expert layers (None where nothing
-    is counted: no expert layer, a prefill; the decode block sends it
-    home, engine._decode_fn). The homogeneous families keep
+    (hidden, paged, state, hits, exits); a model without state hands
+    `state` back as it came, `hits` is the held experts the live lanes of
+    a decode step chose over a layer pattern's expert layers (None where
+    nothing is counted: no expert layer, a prefill; the decode block sends
+    it home, engine._decode_fn), and `exits` [B, T] the pass of a looped
+    stack whose output each position's `hidden` is (None for a stack of
+    one pass). The homogeneous families keep
     `_run_paged_stack`'s scan; a
     layer pattern walks its layers unrolled, its "*" layers on the same
     write and attention kernels over their own pool layers, its "A"
@@ -512,10 +626,10 @@ def forward_slots_counted(
         return ctx, (pool, stage)
 
     if not cfg.layer_pattern:
-        hidden, paged = _run_paged_stack(
+        hidden, paged, exits = _run_paged_stack(
             params, cfg, tokens, positions, paged, attend, stage
         )
-        return hidden, paged, state, None
+        return hidden, paged, state, None, exits
     if paged.quantized:
         raise ValueError("a layer pattern has no int8-KV path")
     from .hybrid import run_stack
@@ -538,7 +652,7 @@ def forward_slots_counted(
     if not decode:
         held = jax.lax.optimization_barrier(held)
     return (hidden, _unstacked(paged, held if decode else held[0]), state,
-            hits)
+            hits, None)
 
 
 def make_sp_override(

@@ -259,6 +259,19 @@ _HELD_EXPERT_FAMILIES: tuple = (
      "held_experts_hit"),
 )
 
+# A looped stack's decode program (in a snapshot once such a model has
+# dispatched a block): layer applications, and the live lane-steps by the
+# pass the exit rule chose (label `step`, from 1).
+_LOOP_FAMILIES: tuple = (
+    ("polykey_loop_layer_passes_total",
+     "Layer applications of a looped stack's decode program: loop_steps "
+     "x num_layers a dispatched step.", "loop_layer_passes"),
+)
+_LOOP_EXITS = (
+    "polykey_loop_exits_total",
+    "Live decode lane-steps of a looped stack by the pass whose output "
+    "the exit rule chose.", "loop_exits_by_step")
+
 # The sampled decode program's sampler (in a snapshot once a sampled
 # block has landed): full sorts / steps is how often a live row's top_k
 # or nucleus went past the sorted head (engine/sampling.py HEAD_WIDTH).
@@ -652,6 +665,7 @@ def engine_collector(engine_or_provider):
             ("drafts_proposed", _SPEC_FAMILIES),
             ("held_expert_calls", _HELD_EXPERT_FAMILIES),
             ("sampler_steps_total", _SAMPLER_FAMILIES),
+            ("loop_layer_passes", _LOOP_FAMILIES),
         ):
             if not any(snap.get(present) for _, _, snap in members):
                 continue
@@ -660,6 +674,13 @@ def engine_collector(engine_or_provider):
                 for labels, _engine, snap in members:
                     if snap.get(present):
                         lines.append(render_sample(name, labels, snap[key]))
+        if any(snap.get(_LOOP_EXITS[2]) for _, _, snap in members):
+            name, help_text, key = _LOOP_EXITS
+            lines += render_header(name, help_text, "counter")
+            for labels, _engine, snap in members:
+                for step, count in enumerate(snap.get(key) or (), start=1):
+                    lines.append(render_sample(
+                        name, {**labels, "step": str(step)}, count))
         if any(snap.get("spec_gamma") is not None for _, _, snap in members):
             # Per-lane dial aggregates (ISSUE 19): gamma went per-lane,
             # so the families carry a `stat` label (mean/min/max over

@@ -50,6 +50,8 @@ _TOP_RULES: dict[tuple[str, ...], P] = {
     ("embed",): P("tp", None),     # vocab-sharded; lookup gathers over tp
     ("final_norm",): P(None),
     ("lm_head",): P(None, "tp"),   # logits shard over vocab on tp
+    ("exit_gate", "w"): P(None, None),   # a looped stack's exit gate
+    ("exit_gate", "b"): P(None),
 }
 
 

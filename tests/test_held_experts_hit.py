@@ -115,7 +115,7 @@ def test_a_decode_step_counts_its_live_lanes_choices(name, monkeypatch):
         forward_slots_counted, params, cfg, jnp.asarray(last)[:, None],
         jnp.asarray(seq - 1)[:, None], paged, jnp.asarray(tables), state,
         active=active)
-    hidden, _, _, hits = step()
+    hidden, _, _, hits, _ = step()
     assert len(spy.calls) == len(routed) == cfg.layer_pattern.count("E")
     assert int(hits) == sum(counted_by_numpy(w, live) for w, live in spy.calls)
     assert all((live == np.asarray(active)).all() for _, live in spy.calls)

@@ -177,6 +177,7 @@ def _engine_decode_hlo(model: str, tp: int) -> tuple[str, int, str]:
 
 @pytest.mark.parametrize("model,tp", [
     ("tiny-llama", 1), ("tiny-mixtral", 1), ("tiny-llama", 2),
+    ("tiny-ouro", 1),        # a looped stack: the pool through both loops
 ])
 def test_decode_step_moves_no_pool(model, tp):
     hlo, layer_bytes, pool_shape = _engine_decode_hlo(model, tp)
@@ -213,16 +214,26 @@ def v5e():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
+def _layout_probe(tp: int, loops: int):
+    """The toy decoder the compiled-step counts run on; `loops` > 1: the
+    same layers as a looped stack (sandwich norms, a pool `loops` deep)."""
+    return replace(TINY_LLAMA, name="layout-probe", num_heads=2 * tp,
+                   num_kv_heads=2 * tp, head_dim=128 if tp == 4 else 64,
+                   use_post_norms=loops > 1, loop_steps=loops)
+
+
+@pytest.mark.parametrize("loops", [1, 2], ids=["once", "looped"])
 @pytest.mark.parametrize("tp", [1, 2, 4])
-def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp):
+def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp, loops):
     """The same count in the step the chip runs — both Pallas kernels on the
     stacked pool, `paged_kv_write` aliasing it — compiled for a v5e by the
     compiler installed here. Toy depth, real page geometry; the folded
     dimension a tp shard sees is 128 lanes, and at tp = 4 the 256 lanes
     (2 KV heads of 128) of a mixtral-8x7b shard, where the decode kernel
-    takes a wider block than on 1024 lanes."""
-    cfg = replace(TINY_LLAMA, name="layout-probe", num_heads=2 * tp,
-                  num_kv_heads=2 * tp, head_dim=128 if tp == 4 else 64)
+    takes a wider block than on 1024 lanes. A looped stack carries the one
+    pool through the scan over its passes too: still ONE write and ONE
+    read kernel in the module (a loop, not an unrolling), no copy."""
+    cfg = _layout_probe(tp, loops)
     pages, ps = 1024, 16
     hlo = _CENSUS.compile_step(
         cfg, list(v5e.devices), tp=tp, lanes=8, pages=pages, page_size=ps,
@@ -231,11 +242,11 @@ def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp):
     folded = cfg.num_kv_heads * cfg.head_dim // tp
     assert pool_sized_instructions(hlo, pages * ps * folded * 2) == []
     assert aliased_pool_parameters(
-        hlo, f"bf16[{cfg.num_layers},{pages},2,{ps},{folded}]"
+        hlo, f"bf16[{cfg.kv_layers},{pages},2,{ps},{folded}]"
     ) == 1
     # One write and one read kernel per layer and step, under the names the
     # benchmark's readers hold fixed, on the whole stack.
-    stack = f"bf16[{cfg.num_layers * pages * 2},{ps},{folded}]"   # halves
+    stack = f"bf16[{cfg.kv_layers * pages * 2},{ps},{folded}]"   # halves
     calls = [line.split(" custom-call(")[0] for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     writes = [c for c in calls if c.lstrip().startswith("%paged_kv_write")]
@@ -245,8 +256,9 @@ def test_decode_step_compiled_for_v5e_moves_no_pool(v5e, tp):
     assert writes[0].count(stack) == 1
 
 
+@pytest.mark.parametrize("loops", [1, 2], ids=["once", "looped"])
 @pytest.mark.parametrize("tp,rows", [(1, 1), (1, 2), (4, 2)])
-def test_prefill_step_compiled_for_v5e_moves_no_pool(v5e, tp, rows):
+def test_prefill_step_compiled_for_v5e_moves_no_pool(v5e, tp, rows, loops):
     """A prefill dispatch of one and of two 128-token rows, compiled for a
     v5e: the page-aligned scatters write whole [ps, Hk·D] page halves into
     the donated stack in place and the window gathers read it where it lies
@@ -255,8 +267,7 @@ def test_prefill_step_compiled_for_v5e_moves_no_pool(v5e, tp, rows):
     the others, ISSUE 44: gathering through a [2N, ps, Hk·D] view of the
     pool passed at two rows and cost 1.25 s a dispatch at one, on the chip,
     PR 46.)"""
-    cfg = replace(TINY_LLAMA, name="layout-probe", num_heads=2 * tp,
-                  num_kv_heads=2 * tp, head_dim=128 if tp == 4 else 64)
+    cfg = _layout_probe(tp, loops)
     pages, ps = 1024, 16
     hlo = _CENSUS.compile_step(
         cfg, list(v5e.devices), tp=tp, pages=pages, page_size=ps,
@@ -265,7 +276,7 @@ def test_prefill_step_compiled_for_v5e_moves_no_pool(v5e, tp, rows):
     folded = cfg.num_kv_heads * cfg.head_dim // tp
     assert pool_sized_instructions(hlo, pages * ps * folded * 2) == []
     assert aliased_pool_parameters(
-        hlo, f"bf16[{cfg.num_layers},{pages},2,{ps},{folded}]"
+        hlo, f"bf16[{cfg.kv_layers},{pages},2,{ps},{folded}]"
     ) == 1
 
 
